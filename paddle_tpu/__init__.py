@@ -8,8 +8,10 @@ hand-written kernels are Pallas. The public surface mirrors `import paddle`.
 
 from __future__ import annotations
 
-from . import _jaxcompat  # noqa: F401  (backfills jax.shard_map & co. on 0.4.x)
 from .version import full_version as __version__  # noqa: E402  (single source)
+from .core.cache import configure_compile_cache as _configure_compile_cache
+
+_configure_compile_cache()
 
 from .core import (  # noqa: F401
     CPUPlace,

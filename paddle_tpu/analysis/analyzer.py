@@ -20,9 +20,10 @@ metrics registry (``analysis.*`` — see observability/README.md).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -70,7 +71,12 @@ class ProgramSpec:
     ``sharding`` (tier 2) declares the shardings the site's jit is built
     with: the flow rules judge against it and hlo_audit compiles with it —
     without it the partitioner sees unconstrained args and elides the very
-    collectives the audit exists to count."""
+    collectives the audit exists to count.
+
+    ``mesh`` is the mesh the product enters (``jax.set_mesh``) around its
+    own trace of the program, when what the trace builds depends on it;
+    every tracer of the spec enters it the same way (``trace_context``) —
+    around the trace, since jax rejects ``set_mesh`` inside one."""
 
     name: str
     fn: Callable
@@ -78,6 +84,11 @@ class ProgramSpec:
     contract: SiteContract = SiteContract()
     argnames: Optional[Tuple[str, ...]] = None
     sharding: Optional[ShardingContract] = None
+    mesh: Optional[Any] = None
+
+    def trace_context(self):
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
 
 
 @dataclass(frozen=True)
@@ -210,11 +221,13 @@ def analyze_fn(name: str, fn: Callable, args: Tuple,
                contract: SiteContract = SiteContract(),
                argnames: Optional[Tuple[str, ...]] = None,
                rules: Optional[Sequence[Rule]] = None,
-               sharding: Optional[ShardingContract] = None) -> Report:
+               sharding: Optional[ShardingContract] = None,
+               trace_context=contextlib.nullcontext) -> Report:
     """Trace fn(*args) abstractly and lint the resulting program. With a
     ShardingContract declared, the tier-2 sharding flow runs over the same
     trace (spmd-* rules)."""
-    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
+    with trace_context():
+        closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
     donated, names = _flat_donation(args, contract.donate_argnums, argnames)
     report = analyze_closed(name, closed, contract, donated=donated,
                             arg_names=names, rules=rules)
@@ -233,7 +246,8 @@ def analyze_spec(spec: ProgramSpec,
                  rules: Optional[Sequence[Rule]] = None) -> Report:
     return analyze_fn(spec.name, spec.fn, spec.args, spec.contract,
                       argnames=spec.argnames, rules=rules,
-                      sharding=spec.sharding)
+                      sharding=spec.sharding,
+                      trace_context=spec.trace_context)
 
 
 def collect_wire(closed: ClosedJaxpr) -> Dict[str, int]:

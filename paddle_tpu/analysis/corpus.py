@@ -128,21 +128,15 @@ def _train_step_moe_spec() -> ProgramSpec:
         else:
             _mesh.reset_global_mesh()
 
-    step_fn = st._compiled_step_fn
-
-    def fn(*a):
-        # the analyzer traces lazily, after the builder restored the global
-        # mesh — re-enter the mesh context so moe_route resolves the quant
-        # plan exactly as the product step does (utils.py traces under
-        # jax.set_mesh(self.mesh) too)
-        with jax.set_mesh(mesh):
-            return step_fn(*a)
-
+    # mesh=: the analyzer traces lazily, after the builder restored the
+    # global mesh — its tracers re-enter the mesh context so moe_route
+    # resolves the quant plan exactly as the product step does (utils.py
+    # traces under jax.set_mesh(self.mesh) too)
     return ProgramSpec(
-        "train_step_moe", fn, args,
+        "train_step_moe", st._compiled_step_fn, args,
         SiteContract(one_compile=True, donate_argnums=(0, 1, 2, 3),
                      expected_wire_bytes=plan.bytes_wire_train_step),
-        argnames=_STEP_ARGNAMES, sharding=st.sharding_contract())
+        argnames=_STEP_ARGNAMES, sharding=st.sharding_contract(), mesh=mesh)
 
 
 def _serving_specs() -> List[ProgramSpec]:

@@ -272,8 +272,9 @@ def audit_spec(spec: ProgramSpec) -> SiteAudit:
             # CPU declines donation aliasing with a warning; not the
             # audit's concern (tier-1 owns donation hygiene)
             warnings.simplefilter("ignore")
-            compiled = (jax.jit(spec.fn, **jit_kwargs)
-                        .lower(*spec.args).compile())
+            with spec.trace_context():
+                compiled = (jax.jit(spec.fn, **jit_kwargs)
+                            .lower(*spec.args).compile())
             text = compiled.as_text()
     except Exception as e:  # noqa: BLE001 - surfaced on the audit record
         audit.error = f"{type(e).__name__}: {e}"
@@ -290,7 +291,8 @@ def audit_spec(spec: ProgramSpec) -> SiteAudit:
     # static prediction: sharding-flow events + tier-1 manual-region wire
     predicted: Dict[str, int] = {}
     try:
-        closed = jax.make_jaxpr(spec.fn)(*spec.args)
+        with spec.trace_context():
+            closed = jax.make_jaxpr(spec.fn)(*spec.args)
         for prim, b in collect_wire(closed).items():
             fam = _PRIM_FAMILY.get(prim)
             if fam:

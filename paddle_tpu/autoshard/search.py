@@ -326,7 +326,7 @@ def search_train_step(model=None, optimizer=None, mesh=None,
             x, y, jnp.float32(1e-3), jnp.uint32(0))
 
     if hw is None:
-        hw = attribution.hardware_for_backend(jax.default_backend())
+        hw = attribution.hardware_for_device(jax.devices()[0].device_kind)
 
     flat = _anatomy.flat_costs(closed.jaxpr)
     flat_totals = {"flops": float(flat.get("flops", 0.0)),

@@ -171,7 +171,7 @@ def snapshot_array(arr) -> dict:
             if s.replica_id != 0:
                 continue
             data = np.ascontiguousarray(np.asarray(s.data))
-            # jax 0.4.x hands back (1,)-shaped shard data for 0-d arrays;
+            # ascontiguousarray promotes 0-d shard data to (1,);
             # normalize to the extent the shard index implies
             want = tuple(
                 (self_dim if sl.stop is None else sl.stop) - (sl.start or 0)

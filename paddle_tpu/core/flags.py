@@ -71,7 +71,6 @@ def flag_value(name: str):
 register_flag("check_nan_inf", False, "Check every op output for NaN/Inf (jax debug_nans analog)")
 register_flag("deterministic", False, "Force deterministic lowering where available")
 register_flag("use_pallas_kernels", True, "Use hand-written Pallas kernels on TPU where available")
-register_flag("pallas_interpret", False, "Force Pallas interpreter mode (debugging off-TPU)")
 register_flag("fraction_of_device_memory_to_use", 0.92, "Informational; XLA manages HBM")
 register_flag("allocator_strategy", "xla", "Kept for parity; allocation is XLA/PJRT-managed")
 register_flag("eager_delete_tensor_gb", 0.0, "Parity no-op; GC is host-side refcounting")

@@ -162,56 +162,24 @@ class DispatchPlan:
 
 
 def _resolve_context():
-    """(mesh, {axis: size}, manual_axes, known) of the ambient context.
+    """(mesh, {axis: size}, manual_axes) of the ambient context. The
+    abstract mesh a step is traced under (``jax.set_mesh``, and every
+    shard_map region) carries axis types, so the manual set is exact.
+    Outside any jax mesh context the process-global mesh (registered by
+    topology's HybridCommunicateGroup / fleet.init) stands in, with
+    nothing manual. No mesh at all -> (None, {}, ())."""
+    m = jax.sharding.get_abstract_mesh()
+    if not m.empty:
+        sizes = {a: int(s) for a, s in m.shape.items()}
+        manual = tuple(a for a, t in zip(m.axis_names, m.axis_types)
+                       if t == jax.sharding.AxisType.Manual and sizes[a] > 1)
+        return m, sizes, manual
+    from .....distributed.mesh import current_mesh
 
-    Modern jax: the abstract mesh carries axis types, so the manual set is
-    exact. This build's 0.4.x shim returns an empty abstract mesh, so fall
-    back to the process-global mesh (topology's HybridCommunicateGroup and
-    fleet.init register it) and detect "inside a shard_map region" by
-    probing the axis environment — legacy jax exposes every region axis
-    (manual AND auto) there, so the manual set is unknowable and `known`
-    is False: the caller must decide from mesh composition instead.
-    """
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        names = tuple(getattr(m, "axis_names", ()) or ())
-    except Exception:
-        m, names = None, ()
-    if names:
-        sizes = dict(zip(names, (int(s) for s in m.shape.values())))
-        types = dict(zip(names, m.axis_types))
-        manual = tuple(a for a, t in types.items()
-                       if t == jax.sharding.AxisType.Manual
-                       and sizes.get(a, 1) > 1)
-        return m, sizes, manual, True
-    mesh = None
-    try:
-        # legacy `with mesh:` thread context — ShardedTrainStep traces its
-        # step under jax.set_mesh(mesh), which 0.4.x lowers to this
-        from jax._src.mesh import thread_resources
-
-        pm = thread_resources.env.physical_mesh
-        if not getattr(pm, "empty", True):
-            mesh = pm
-    except Exception:
-        mesh = None
+    mesh = current_mesh()
     if mesh is None:
-        from .....distributed.mesh import current_mesh
-
-        mesh = current_mesh()
-    if mesh is None:
-        return None, {}, (), True
-    sizes = dict(zip(mesh.axis_names, (int(s) for s in mesh.devices.shape)))
-    in_region = False
-    for a in mesh.axis_names:
-        try:
-            jax.core.axis_frame(a)
-            in_region = True
-            break
-        except Exception:
-            continue
-    manual = tuple(a for a, s in sizes.items() if s > 1) if in_region else ()
-    return mesh, sizes, manual, not in_region
+        return None, {}, ()
+    return mesh, {a: int(s) for a, s in mesh.shape.items()}, ()
 
 
 def _downgrade(site: str, message: str, data: Tuple[str, ...]):
@@ -255,7 +223,7 @@ def plan_quant_dispatch(T: int, E: int, capacity: int, d: int,
     under partial-auto shard_map), experts indivisible by the ep degree,
     or a model dim whose best block (gcd with `block`) is below MIN_BLOCK.
     """
-    mesh, sizes, manual, manual_known = _resolve_context()
+    mesh, sizes, manual = _resolve_context()
     nep = sizes.get(EP_AXIS, 1)
     if mesh is None or nep <= 1:
         return None  # no exchange to compress; dense is exact, not a downgrade
@@ -269,26 +237,15 @@ def plan_quant_dispatch(T: int, E: int, capacity: int, d: int,
                           ("block", str(d), str(block)))
     active = {a for a, s in sizes.items() if s > 1}
     manual = set(manual)
-    if manual:
-        partial = manual != active
-        if not manual_known:
-            # legacy-jax in-region fallback: the manual set is unknowable
-            # (the axis env exposes auto axes too), so infer from mesh
-            # composition — with model/pipeline axes present, the only
-            # in-region hosts in this tree are partial-auto (the hybrid
-            # reducer's region A, pp/sep stages); data-axes-only meshes
-            # host fully-manual regions (the flat explicit-reduce step),
-            # where the direct path is safe
-            partial = bool(active - set(DATA_AXES))
-        if partial:
-            # partial-manual: the ep all-to-all cannot run while other
-            # axes stay GSPMD-auto — same build constraint that forces
-            # comm_opt's two-region schedule
-            return _downgrade(site, "ambient region is manual over "
-                              f"{sorted(manual)} with other mesh axes "
-                              "GSPMD-auto; the compressed all-to-all needs "
-                              "a fully-manual (or fully-auto) context",
-                              ("partial-manual", ",".join(sorted(manual))))
+    if manual and manual != active:
+        # partial-manual: the ep all-to-all cannot run while other
+        # axes stay GSPMD-auto — same build constraint that forces
+        # comm_opt's two-region schedule
+        return _downgrade(site, "ambient region is manual over "
+                          f"{sorted(manual)} with other mesh axes "
+                          "GSPMD-auto; the compressed all-to-all needs "
+                          "a fully-manual (or fully-auto) context",
+                          ("partial-manual", ",".join(sorted(manual))))
     dax = tuple(a for a in DATA_AXES if a in active)
     world = int(np.prod([sizes[a] for a in dax], dtype=np.int64))
     if not manual and T % world:

@@ -4,9 +4,14 @@ Where the reference hand-writes CUDA (flash_attn_kernel.cu, fused_adam,
 fused layer_norm in phi/kernels/gpu + fusion/), the TPU build hand-writes
 Pallas/Mosaic. Every kernel here:
 - computes in f32 on the MXU/VPU regardless of storage dtype,
-- has a jnp fallback + interpret mode so tests run on CPU,
+- has a jnp reference beside it that tests (and chip_smoke) compare it to,
+- runs compiled by Mosaic on TPU and in Pallas interpret mode on cpu ONLY —
+  one predicate decides (core/place.py); no other platform, no flag,
 - is wired behind the op-registry variant seam (ops use it when
-  FLAGS_use_pallas_kernels and the backend is TPU).
+  FLAGS_use_pallas_kernels and the platform is TPU),
+- carries a stable ``name=`` and, under a mesh, runs inside a shard_map
+  over all mesh axes (mesh.py: shard_kernel; kernel_sites reads back which
+  kernels a compiled program holds).
 """
 
 from .flash_attention import flash_attention_fwd  # noqa: F401

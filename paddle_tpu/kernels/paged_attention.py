@@ -35,9 +35,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from .flash_attention import (LANES, LOG2E, NEG_INF, _compiler_params,
-                              _interpret)
+from ..core.place import pallas_interpret
+from .flash_attention import LANES, LOG2E, NEG_INF
+from .mesh import shard_kernel
 
 
 def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -102,8 +105,7 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
 
 
-def paged_attention(q, k_pool, v_pool, page_table, positions,
-                    interpret: bool = None):
+def paged_attention(q, k_pool, v_pool, page_table, positions):
     """Ragged paged-decode attention over block-paged KV pools.
 
     q            ``[B, H_q, 1, D]`` — one query token per slot
@@ -114,20 +116,32 @@ def paged_attention(q, k_pool, v_pool, page_table, positions,
     Returns ``[B, H_q, 1, D]`` in v's dtype — drop-in for
     ``decode_attend(q, dense_k, dense_v, positions)`` when the dense caches
     hold the same bytes the table maps (tests pin this parity).
-    """
-    from jax.experimental.pallas import tpu as pltpu
 
+    Under a mesh the heads split over ``mp`` (query and KV heads together,
+    so each shard keeps whole GQA groups); pools, table and positions are
+    otherwise replicated, as the engine's sharding contract declares.
+    """
     B, Hq, T, D = q.shape
     if T != 1:
         raise ValueError(f"paged_attention decodes one token per slot, got T={T}")
-    P, Hkv, page_size, _ = k_pool.shape
-    num_blocks = page_table.shape[1]
-    rep = Hq // Hkv
+    Hkv = k_pool.shape[1]
     qs = (q[:, :, 0, :] * jnp.asarray(1.0 / np.sqrt(D), q.dtype))  # [B, Hq, D]
     table = page_table.astype(jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (B,))
+    mp = dict(jax.sharding.get_abstract_mesh().shape).get("mp", 1)
+    heads = P(None, "mp") if Hkv % mp == 0 else P()
+    out = shard_kernel(_decode_call, (table, pos, qs, k_pool, v_pool),
+                       (P(), P(), heads, heads, heads), lambda f: f[2])
+    return out[:, :, None, :]
+
+
+def _decode_call(table, pos, qs, k_pool, v_pool):
+    B, Hq, D = qs.shape
+    _, Hkv, page_size, _ = k_pool.shape
+    num_blocks = table.shape[1]
+    rep = Hq // Hkv
 
     def _page_map(b, i, tbl, _pos):
         # sentinel entries clamp to the reserved trash page so the fetch
@@ -149,13 +163,13 @@ def paged_attention(q, k_pool, v_pool, page_table, positions,
             pltpu.VMEM((Hq, D), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_decode_kernel, num_blocks=num_blocks,
                           page_size=page_size, num_kv_heads=Hkv, rep=rep),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), v_pool.dtype),
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret() if interpret is None else interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=pallas_interpret(),
+        name="paged_decode",
     )(table, pos, qs, k_pool, v_pool)
-    return out[:, :, None, :]

@@ -373,21 +373,24 @@ class GPTBlock(Layer):
         # anatomy scope convention: attn / mlp / moe nest under the
         # enclosing block_NN scope (observability/anatomy.py)
         mlp_scope = "moe" if isinstance(self.mlp, GPTMoEMLP) else "mlp"
-        x = maybe_shard(x, _seq_spec(self.cfg))
+        # the residual-stream layout doubles as the fused LN kernel's row
+        # spec: under sep / Megatron-SP the rows are sequence-sharded too
+        spec = _seq_spec(self.cfg)
+        x = maybe_shard(x, spec)
         if return_kv or kv_cache is not None:
             with jax.named_scope("attn"):
-                a, kv = self.attn(self.ln1(x), kv_cache=kv_cache,
+                a, kv = self.attn(self.ln1(x, spec=spec), kv_cache=kv_cache,
                                   cache_positions=cache_positions,
                                   return_kv=return_kv)
                 x = x + a
             with jax.named_scope(mlp_scope):
-                x = x + self.mlp(self.ln2(x))
-            return maybe_shard(x, _seq_spec(self.cfg)), kv
+                x = x + self.mlp(self.ln2(x, spec=spec))
+            return maybe_shard(x, spec), kv
         with jax.named_scope("attn"):
-            x = x + self.attn(self.ln1(x))
+            x = x + self.attn(self.ln1(x, spec=spec))
         with jax.named_scope(mlp_scope):
-            x = x + self.mlp(self.ln2(x))
-        return maybe_shard(x, _seq_spec(self.cfg))
+            x = x + self.mlp(self.ln2(x, spec=spec))
+        return maybe_shard(x, spec)
 
 
 class GPTEmbeddings(Layer):
@@ -457,7 +460,7 @@ class GPTModel(Layer):
                                   return_kv=return_kv)
                 kvs.append(kv)
             with jax.named_scope("final_ln"):
-                h = self.final_ln(h)
+                h = self.final_ln(h, spec=_seq_spec(self.cfg))
             return h, kvs
         with jax.named_scope("embed"):
             h = self.embeddings(input_ids, position_ids)
@@ -479,7 +482,7 @@ class GPTModel(Layer):
                 aux = block.mlp.aux_loss if aux is None else aux + block.mlp.aux_loss
         self.moe_aux_loss = aux
         with jax.named_scope("final_ln"):
-            return self.final_ln(h)
+            return self.final_ln(h, spec=_seq_spec(self.cfg))
 
 
 class GPTForCausalLM(Layer):
@@ -585,7 +588,8 @@ class GPTForCausalLM(Layer):
 
     def head_loss(self, h, labels):
         """Post-stage for pipeline parallelism: final LN + LM head + CE."""
-        return self.loss(self._logits(self.gpt.final_ln(h)), labels)
+        return self.loss(self._logits(
+            self.gpt.final_ln(h, spec=_seq_spec(self.cfg))), labels)
 
     def pipeline_spec(self):
         """PipelineSpec protocol consumed by make_sharded_train_step when the

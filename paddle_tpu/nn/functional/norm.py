@@ -15,7 +15,10 @@ from ...ops._dispatch import apply, as_tensor
 
 
 @register_op("nn.layer_norm")
-def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=None):
+def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=None, spec=None):
+    """``spec`` (optional PartitionSpec of ``x``): where the fused kernel's
+    rows live under a mesh when it is not the default batch-on-data-axes —
+    a model that shards the sequence dim passes its residual-stream spec."""
     x = as_tensor(x)
     nshape = (normalized_shape,) if isinstance(normalized_shape, int) else tuple(normalized_shape)
     axes = tuple(range(x.ndim - len(nshape), x.ndim))
@@ -32,7 +35,7 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
     if use_pallas() and len(nshape) == 1 and weight is not None and bias is not None:
         from ...kernels.norms import fused_layer_norm
 
-        return apply("layer_norm_pallas", lambda xv, wv, bv: fused_layer_norm(xv, wv, bv, epsilon), *tensors)
+        return apply("layer_norm_pallas", lambda xv, wv, bv: fused_layer_norm(xv, wv, bv, epsilon, spec), *tensors)
 
     def fn(xv, *rest):
         x32 = xv.astype(jnp.float32)
@@ -51,7 +54,7 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
 
 
 @register_op("nn.rms_norm")
-def rms_norm(x, weight=None, epsilon=1e-6, name=None):
+def rms_norm(x, weight=None, epsilon=1e-6, name=None, spec=None):
     x = as_tensor(x)
     tensors = [x] + ([as_tensor(weight)] if weight is not None else [])
 
@@ -60,7 +63,7 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None):
     if use_pallas() and weight is not None:
         from ...kernels.norms import fused_rms_norm
 
-        return apply("rms_norm_pallas", lambda xv, wv: fused_rms_norm(xv, wv, epsilon), *tensors)
+        return apply("rms_norm_pallas", lambda xv, wv: fused_rms_norm(xv, wv, epsilon, spec), *tensors)
 
     def fn(xv, *rest):
         x32 = xv.astype(jnp.float32)

@@ -24,8 +24,8 @@ class LayerNorm(Layer):
         else:
             self.bias = self.create_parameter(self.normalized_shape, attr=None if bias_attr in (None, True) else bias_attr, is_bias=True)
 
-    def forward(self, x):
-        return F.layer_norm(x, self.normalized_shape, self.weight, self.bias, self.epsilon)
+    def forward(self, x, spec=None):
+        return F.layer_norm(x, self.normalized_shape, self.weight, self.bias, self.epsilon, spec=spec)
 
     def extra_repr(self):
         return f"normalized_shape={list(self.normalized_shape)}, epsilon={self.epsilon}"

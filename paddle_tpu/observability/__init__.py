@@ -32,7 +32,7 @@ from .aggregate import fleet_report, render_report  # noqa: F401
 from .attribution import (  # noqa: F401
     HardwareSpec,
     attribute,
-    hardware_for_backend,
+    hardware_for_device,
     site_report,
 )
 from .export import (  # noqa: F401
@@ -105,5 +105,5 @@ __all__ = [
     "GoodputMonitor", "fleet_report", "render_report",
     "HealthMonitor", "HealthConfig", "EwmaDetector", "NonfiniteProvenance",
     "param_group",
-    "HardwareSpec", "attribute", "hardware_for_backend", "site_report",
+    "HardwareSpec", "attribute", "hardware_for_device", "site_report",
 ]

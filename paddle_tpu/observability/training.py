@@ -10,22 +10,20 @@ from __future__ import annotations
 from typing import Optional
 
 from . import metrics
-from .attribution import hardware_for_backend
+from .attribution import hardware_for_device
 
 
-def peak_flops(backend: Optional[str] = None) -> float:
+def peak_flops(device_kind: Optional[str] = None) -> float:
     """Per-chip peak FLOP/s the MFU denominator uses — read from
     ``attribution.HW_SPECS`` (the roofline table), so MFU and the
-    roofline floors can never quote different peaks for one backend
-    (a pin test in tests/test_attribution.py holds them equal)."""
-    if backend is None:
-        try:
-            import jax
+    roofline floors can never quote different peaks for one device
+    (a pin test in tests/test_attribution.py holds them equal). Defaults
+    to the device in use; a device without a row raises."""
+    if device_kind is None:
+        import jax
 
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    return hardware_for_backend(backend).peak_flops
+        device_kind = jax.devices()[0].device_kind
+    return hardware_for_device(device_kind).peak_flops
 
 
 def record_step(*, seconds: Optional[float] = None,

@@ -18,7 +18,7 @@ Public surface:
 - :func:`paged_write_kv` / :func:`paged_gather` /
   :func:`paged_decode_attend` — the paged twins of the primitives above;
   :func:`use_paged_attention_impl` pins the attend tier
-  (``oracle`` | ``interpret`` | ``pallas``) for traces entered under it.
+  (``oracle`` | ``pallas``) for traces entered under it.
 - :func:`cached_generate` — the static-shape decode loop
   ``models.gpt.GPTForCausalLM.generate`` delegates to.
 - :class:`PrefixCache` — radix trie from block-aligned token prefixes to

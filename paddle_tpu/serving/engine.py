@@ -231,8 +231,8 @@ class EngineConfig:
     kv_layout: str = "paged"
     page_size: int = 16          # tokens per KV page (shrunk to divide S_max)
     kv_pages: Optional[int] = None  # pool size; default = full budget + trash
-    # paged-attend tier override for tests ("oracle"|"interpret"|"pallas");
-    # None = pick by backend (kv_cache.default_paged_impl)
+    # paged-attend tier override for tests ("oracle"|"pallas");
+    # None = pick by platform (kv_cache.default_paged_impl)
     paged_attention_impl: Optional[str] = None
     # radix prefix cache (prefix_cache.py): finished prompts' full KV
     # blocks stay indexed by token content, and a new request whose prompt
@@ -674,6 +674,16 @@ class Engine:
 
         return ShardingContract(in_shardings=(P(),) * nargs,
                                 out_shardings=P(), axis_sizes={})
+
+    @property
+    def kernel_sites(self) -> Dict[Tuple, Dict[str, int]]:
+        """{program key: {kernel name: Mosaic calls}} over the executables
+        compiled so far (keys ``("prefill", T)``, ``("decode",)``,
+        ``("extend", T)``, ``("verify",)``) — which Pallas kernels actually
+        made it into what serves; empty dicts on CPU."""
+        from ..kernels.mesh import kernel_sites
+
+        return {key: kernel_sites(exe) for key, exe in self._exe.items()}
 
     def _prefill_exe(self, T: int):
         prefill_fn, args = self.prefill_program(T)

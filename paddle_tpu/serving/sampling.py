@@ -20,7 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-_NEG_INF = jnp.float32(-1e30)
+_NEG_INF = -1e30  # a plain float: a jnp constant here would initialize the backend at import
 
 
 @dataclass

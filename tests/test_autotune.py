@@ -65,12 +65,16 @@ class TestPickBest:
 
         got = autotune.pick_best("k", (5,), ["slow", "fast"], make_run, default="slow")
         assert got == "fast"
-        # second call: cache hit, no measuring even if disabled now
-        autotune.disable_autotune()
+        # second call: cache hit, nothing measured
         got2 = autotune.pick_best("k", (5,), ["slow", "fast"],
                                   lambda c: (_ for _ in ()).throw(AssertionError),
                                   default="slow")
         assert got2 == "fast"
+        # disabled: the cache is not consulted, so no file on disk can
+        # change a block size behind a run that did not ask for tuning
+        autotune.disable_autotune()
+        assert autotune.pick_best("k", (5,), ["slow", "fast"], make_run,
+                                  default="slow") == "slow"
 
     def test_failing_candidate_disqualified(self):
         autotune.enable_autotune()

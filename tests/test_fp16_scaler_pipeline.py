@@ -17,15 +17,6 @@ import pytest
 
 import paddle_tpu as paddle
 
-# The pp-composed scaler paths compile through shard_map and hit XLA:CPU's
-# "PartitionId instruction is not supported for SPMD partitioning" on
-# jaxlib 0.4.x; the eager scale-automaton test below still runs there.
-_LEGACY_JAX = tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
-_skip_legacy = pytest.mark.skipif(
-    _LEGACY_JAX, reason="XLA:CPU SPMD PartitionId unsupported on jax<0.5"
-)
-
-
 @pytest.fixture(autouse=True)
 def _fresh_world():
     from paddle_tpu.distributed import collective, mesh, topology
@@ -61,7 +52,6 @@ def _build(pp, dp, M, scaler, dtype="float16"):
     return step, x, y
 
 
-@_skip_legacy
 def test_fp16_pp_dp_trains_with_scaler():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 devices")
@@ -74,7 +64,6 @@ def test_fp16_pp_dp_trains_with_scaler():
     assert step.loss_scaling() == 2.0 ** 15  # no overflow, incr_every=2000
 
 
-@_skip_legacy
 def test_fp16_forced_overflow_skips_update():
     """A step whose scaled loss overflows must leave params AND optimizer
     state untouched, halve the scale, and training must resume after."""
@@ -144,7 +133,6 @@ def test_scale_automaton_matches_eager_gradscaler():
     assert scaler._bad_steps == eager._bad_steps
 
 
-@_skip_legacy
 def test_vpp_train_batch_accepts_scaler():
     """The interleaved pipeline driver no longer raises on scaler."""
     if len(jax.devices()) < 8:

@@ -21,10 +21,6 @@ import pytest
 
 import paddle_tpu as paddle
 
-# jaxlib 0.4.x's XLA:CPU aborts the whole process while compiling the
-# Ulysses all-to-all attention reshard (SIGABRT inside backend_compile, which
-# no pytest-level timeout can intercept). Gate only the affected test.
-_LEGACY_JAX = tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
 
 
 @pytest.fixture(autouse=True)
@@ -106,9 +102,6 @@ def test_tp_emits_all_reduce():
     assert "all-reduce" in ops, ops
 
 
-@pytest.mark.skipif(
-    _LEGACY_JAX, reason="ulysses all-to-all compile SIGABRTs XLA:CPU on jax<0.5"
-)
 def test_ulysses_emits_all_to_all():
     ops = _ops_in(_compiled_hlo(sep=4, dp=2, model_kw={"context_parallel": "ulysses"}))
     assert "all-to-all" in ops, ops

@@ -144,7 +144,7 @@ class TestPagedPrimitives:
         q = jnp.asarray(rng.randn(B, Hkv * rep, 1, D).astype(np.float32))
         pos = jnp.asarray([6, 3, 0], jnp.int32)  # mid-page, page-0-only, empty
         want = kvc.paged_decode_attend(q, kp, vp, tbl, pos, impl="oracle")
-        got = kvc.paged_decode_attend(q, kp, vp, tbl, pos, impl="interpret")
+        got = kvc.paged_decode_attend(q, kp, vp, tbl, pos, impl="pallas")
         assert got.shape == want.shape == (B, Hkv * rep, 1, D)
         assert np.allclose(np.asarray(got), np.asarray(want), atol=1e-5)
         # oracle == the dense decode_attend it wraps
@@ -153,8 +153,8 @@ class TestPagedPrimitives:
 
     def test_impl_dispatch_and_override(self):
         assert kvc.default_paged_impl() in ("oracle", "pallas")
-        with kvc.use_paged_attention_impl("interpret"):
-            assert kvc.default_paged_impl() == "interpret"
+        with kvc.use_paged_attention_impl("pallas"):
+            assert kvc.default_paged_impl() == "pallas"
         assert kvc.default_paged_impl() in ("oracle", "pallas")
         with pytest.raises(ValueError):
             kvc.use_paged_attention_impl("nope").__enter__()
@@ -193,7 +193,7 @@ class TestPagedEngine:
             paged_attention_impl="oracle")).generate(prompts, sp)
         kern = Engine(m, EngineConfig(
             max_batch_size=2, max_seq_len=32,
-            paged_attention_impl="interpret")).generate(prompts, sp)
+            paged_attention_impl="pallas")).generate(prompts, sp)
         assert kern == oracle
 
     def test_paged_decode_compiles_once(self, telemetry):

@@ -292,7 +292,7 @@ class TestEnginePrefixCache:
         oracle gather does."""
         m = _tiny()
         outs = []
-        for impl in ("oracle", "interpret"):
+        for impl in ("oracle", "pallas"):
             eng = Engine(m, EngineConfig(max_batch_size=1, max_seq_len=64,
                                          page_size=8, prefix_cache=True,
                                          paged_attention_impl=impl))

@@ -101,6 +101,7 @@ def build_report(baseline: dict, hlo_baseline: dict,
     telemetry dumps): per-config and per-site roofline rows plus the
     cross-ledger reconciliation against the HLO audit."""
     backend = baseline.get("backend", "tpu")
+    device_kind = baseline["device_kind"]  # the peaks row the floors use
     config_sites = {}
     for name, row in baseline.get("configs", {}).items():
         config_sites[name] = {
@@ -110,7 +111,7 @@ def build_report(baseline: dict, hlo_baseline: dict,
             "measured_s": (row["step_ms"] / 1e3
                            if row.get("step_ms") else None),
         }
-    configs_report = attribution.site_report(config_sites, backend=backend)
+    configs_report = attribution.site_report(config_sites, device_kind)
 
     measured = None
     if metrics_paths:
@@ -126,13 +127,14 @@ def build_report(baseline: dict, hlo_baseline: dict,
             "hbm_bytes": row.get("hbm_bytes"),
             "wire_bytes": row.get("wire_bytes"),
         }
-    sites_report = attribution.site_report(site_costs, backend=backend,
+    sites_report = attribution.site_report(site_costs, device_kind,
                                            measured=measured)
     mismatches = attribution.reconcile_sites(
         baseline.get("sites", {}), hlo_baseline.get("sites", {}))
     return {
         "schema": attribution.SCHEMA,
         "backend": backend,
+        "device_kind": device_kind,
         "hardware": configs_report["hardware"],
         "configs": configs_report["sites"],
         "sites": sites_report["sites"],
@@ -164,8 +166,6 @@ def diff_rows(rows: list, baseline: dict) -> dict:
             skipped.append({"config": name, "reason": "not in baseline"})
             continue
         row_backend = row.get("backend", "unknown")
-        if row_backend == "cpu_fallback":
-            row_backend = "cpu"
         if row_backend != backend:
             skipped.append({"config": name,
                             "reason": f"backend {row_backend} != baseline "
@@ -213,12 +213,12 @@ def inject_row(baseline: dict, config: str) -> dict:
 # ---------------------------------------------------------------- render
 
 def render_text(report: dict, diff: dict | None) -> str:
-    lines = [attribution.render({"backend": report["backend"],
+    lines = [attribution.render({"device_kind": report["device_kind"],
                                  "hardware": report["hardware"],
                                  "sites": report["configs"]}),
              "",
              "corpus sites (cost_analysis + hlo_baseline wire bytes):",
-             attribution.render({"backend": report["backend"],
+             attribution.render({"device_kind": report["device_kind"],
                                  "hardware": report["hardware"],
                                  "sites": report["sites"]})]
     rec = report["reconciliation"]
