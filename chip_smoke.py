@@ -20,9 +20,11 @@ heads, vocab 50304, S=2048; bf16; weights random from a seed):
 
 One process holds the chip for the whole run (nothing is spawned). A failed
 check raises: the exit code is non-zero and no result line is printed. Every
-line names the device; the last stdout line is one JSON object
-``{"ok": true, "device": {...}, ..., "claim": null}`` — this script claims
-no speed, it only shows the program is right where users run it.
+line names the device. The line before last is ``summary: {"legs": ...,
+"compile_cache": ..., "claim": null}`` — this script claims no speed, it only
+shows the program is right where users run it — and the last stdout line is
+exactly ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": N}}``
+with the device as jax reports it.
 """
 
 from __future__ import annotations
@@ -79,6 +81,17 @@ class CompileLog:
     def snapshot(self):
         return {"requests": self.requests, "cache_hits": self.hits,
                 "cache_misses": self.misses}
+
+
+def result_line(devices) -> str:
+    """The last stdout line of a passing run: exactly the keys ``ok`` and
+    ``device`` (``platform``, ``kind``, ``count``), the device as jax
+    reports it. Everything else the run learned goes on the summary line
+    before it — the driver's check refuses any other key here."""
+    d0 = devices[0]
+    return json.dumps({"ok": True, "device": {
+        "platform": d0.platform, "kind": d0.device_kind,
+        "count": len(devices)}})
 
 
 def close(got, want, tol: float) -> float:
@@ -459,8 +472,6 @@ def main(argv=None) -> int:
 
     devices = jax.devices()  # a backend that cannot start raises here
     d0 = devices[0]
-    device = {"platform": d0.platform, "kind": d0.device_kind,
-              "count": len(devices)}
     _DEV = f"{d0.platform} {d0.device_kind} x{len(devices)}"
     if d0.platform != "tpu":
         print(f"[{_DEV}] chip_smoke needs a TPU; jax found "
@@ -513,11 +524,12 @@ def main(argv=None) -> int:
 
     say(f"all legs passed in {time.perf_counter() - t_start:.0f} s; "
         f"compiles {log.snapshot()}")
-    print(json.dumps({"ok": True, "device": device, "legs": result,
-                      "compile_cache": {
-                          "dir": jax.config.jax_compilation_cache_dir,
+    say("summary: " + json.dumps({
+        "legs": result,
+        "compile_cache": {"dir": jax.config.jax_compilation_cache_dir,
                           **log.snapshot()},
-                      "claim": None}))
+        "claim": None}))
+    print(result_line(devices), flush=True)  # nothing after it on stdout
     return 0
 
 

@@ -57,7 +57,7 @@ def _run_elastic(args, cfg):
     from paddle_tpu.distributed.fleet.utils import make_sharded_train_step
     from paddle_tpu.models import GPTForCausalLM
 
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = paddle.core.place.on_tpu()
 
     def build_step(mesh):
         paddle.seed(0)
@@ -267,7 +267,7 @@ def main():
 
     paddle.seed(0)
     model = GPTForCausalLM(cfg)
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = paddle.core.place.on_tpu()
     if on_tpu:
         model = model.astype("bfloat16")
     opt = paddle.optimizer.AdamW(

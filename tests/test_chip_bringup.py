@@ -233,3 +233,26 @@ def test_import_starts_no_backend_and_places_the_compile_cache(tmp_path):
     # set from outside: the program sets no other
     probe = _import_probe({"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
     assert probe["backends"] == [] and probe["cache"] == str(tmp_path)
+
+
+def test_chip_smoke_fails_on_a_cpu_and_its_result_line_has_exact_keys():
+    """The driver's contract for chip_smoke.py: without an accelerator it
+    exits non-zero and prints no result; the result line of a passing run
+    holds exactly ``ok`` and ``device{platform, kind, count}``."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "chip_smoke.py"], env=env, cwd=REPO,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0 and r.stdout == "", (r.returncode, r.stdout)
+    assert "needs a TPU" in r.stderr
+
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    devices = jax.devices()
+    line = json.loads(chip_smoke.result_line(devices))
+    assert line == {"ok": True, "device": {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices)}}
+    assert isinstance(line["device"]["count"], int)

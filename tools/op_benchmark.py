@@ -26,18 +26,17 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 
 def build_cases():
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
+    import paddle_tpu as paddle
+
     rng = np.random.RandomState(0)
-    big = 1024 if jax.default_backend() == "tpu" else 256
+    big = 1024 if paddle.core.place.on_tpu() else 256
     a = jnp.asarray(rng.randn(big, big).astype(np.float32))
     v = jnp.asarray(rng.randn(4, big).astype(np.float32))
     img = jnp.asarray(rng.randn(8, 16, 32, 32).astype(np.float32))
     ker = jnp.asarray(rng.randn(16, 16, 3, 3).astype(np.float32))
-
-    import paddle_tpu as paddle
 
     t_a = paddle.to_tensor(a)
     t_v = paddle.to_tensor(v)
