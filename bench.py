@@ -142,10 +142,6 @@ def main():
     from paddle_tpu import observability
 
     if observability.enabled():
-        observability.record_window(
-            tokens=bsz * seq * iters, seconds=best_dt,
-            flops=flops_per_token * bsz * seq * iters, peak=peak,
-            config="headline")
         out["telemetry"] = observability.snapshot()
     print(json.dumps(out))
 
@@ -394,10 +390,6 @@ def _row(config, metric, value, unit, step_s, flops_per_step, host_frac,
         hw, measured_s=step_s, flops=flops_per_step,
         hbm_bytes=hbm_bytes, wire_bytes=wire_bytes)
     if observability.enabled():
-        observability.record_window(
-            tokens_per_sec=value if metric.endswith("tokens_per_sec") else None,
-            flops=flops_per_step, seconds=step_s, peak=_peak_flops(),
-            config=config)
         _attr.record_report({"sites": {config: out["attribution"]}})
         out["telemetry"] = observability.snapshot()
     print(json.dumps(out))

@@ -1,0 +1,4 @@
+"""Per-layer metric ``idle_in_admit_share.chat`` (layer, unit, source, moves and cells: its
+entry in BENCHMARK.json). Returns None where it finds nothing to read."""
+
+from harness.program_spans import idle_in_admit_share as read  # noqa: F401

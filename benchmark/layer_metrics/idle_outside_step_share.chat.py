@@ -1,0 +1,4 @@
+"""Per-layer metric ``idle_outside_step_share.chat`` (layer, unit, source, moves and cells: its
+entry in BENCHMARK.json). Returns None where it finds nothing to read."""
+
+from harness.program_spans import idle_outside_step_share as read  # noqa: F401

@@ -11,7 +11,6 @@ and the hapi/auto-parallel engines all compile through.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -23,6 +22,7 @@ from ...observability import goodput as _obs_goodput
 from ...observability import instrument as _obs_instr
 from ...observability import memory as _obs_memory
 from ...observability import metrics as _obs_metrics
+from ...observability.tracing import span as _span
 from ...core.autograd import no_grad
 from ...core.place import is_compile_only
 from ...core.tensor import Tensor
@@ -695,7 +695,8 @@ class ShardedTrainStep:
         exe = self._exe.get(key)
         if exe is not None:
             return exe, False
-        exe = self._exe[key] = jitted.lower(*args).compile()
+        with _span("compile", site=site, cache_hit=0):
+            exe = self._exe[key] = jitted.lower(*args).compile()
         _obs_memory.record_executable(site, exe)
         return exe, True
 
@@ -975,31 +976,33 @@ class ShardedTrainStep:
         K = xs.shape[0] if hasattr(xs, "shape") else len(xs)
         self._step_i += K
         ss_in = self.scaler_state if scaled else jnp.zeros((), jnp.float32)
-        obs = _obs_metrics.enabled()
-        t0 = time.perf_counter() if obs else 0.0
-        xg, yg = jnp.asarray(xs), jnp.asarray(ys)
-        if self._health:
-            self.health_flush()
-        args = (self.params, self.opt_state, self.buffers, ss_in,
-                self.ef_state, xg, yg,
-                # +1 so scanned step j draws seed (seed + prev_steps + 1 + j)
-                # — identical to the seeds K sequential __call__s would use
-                jnp.float32(lr), jnp.uint32(self._seed + self._step_i - K + 1))
-        if self._health:
-            args = args + (jnp.asarray(self._health_poison),)
-        with jax.set_mesh(self.mesh):
-            exe, first = self._executable(
-                "multi", "sharded_train_step.run_steps", self._multi, args,
-                xg, yg)
-            out = exe(*args)
-            (self.params, self.opt_state, self.buffers, ss_out,
-             self.ef_state, losses) = out[:6]
-        if obs:
+        with _span("train/step", step=self._step_i) as sp:
+            xg, yg = jnp.asarray(xs), jnp.asarray(ys)
+            if self._health:
+                self.health_flush()
+            args = (self.params, self.opt_state, self.buffers, ss_in,
+                    self.ef_state, xg, yg,
+                    # +1 so scanned step j draws seed (seed + prev_steps + 1
+                    # + j) — identical to the seeds K sequential __call__s
+                    # would use
+                    jnp.float32(lr),
+                    jnp.uint32(self._seed + self._step_i - K + 1))
+            if self._health:
+                args = args + (jnp.asarray(self._health_poison),)
+            with jax.set_mesh(self.mesh):
+                exe, first = self._executable(
+                    "multi", "sharded_train_step.run_steps", self._multi,
+                    args, xg, yg)
+                out = exe(*args)
+                (self.params, self.opt_state, self.buffers, ss_out,
+                 self.ef_state, losses) = out[:6]
+            sp.set(first=int(first), steps=K)
+        if _obs_metrics.enabled():
             samples = None
             if hasattr(xs, "shape") and len(getattr(xs, "shape", ())) >= 2:
                 samples = int(xs.shape[0]) * int(xs.shape[1])
             self._obs_record("sharded_train_step.run_steps", first,
-                             time.perf_counter() - t0, samples, steps=K)
+                             sp.seconds, samples, steps=K)
         if scaled:
             self.scaler_state = ss_out
         if self._health:
@@ -1007,48 +1010,53 @@ class ShardedTrainStep:
         return losses
 
     def __call__(self, x, y, lr: Optional[float] = None):
+        """One optimizer step handed to the device, under one ``train/step``
+        span: argument preparation and the executable call (the host's
+        time; the device finishes later)."""
         lr = self.optimizer.get_lr() if lr is None else lr
         self._step_i += 1
-        obs = _obs_metrics.enabled()
-        t0 = time.perf_counter() if obs else 0.0
-        xg, yg = self._to_global_batch(x), self._to_global_batch(y)
-        scaled = self.scaler_state is not None
-        if self._health:
-            # deliver the PREVIOUS step's stats first (they are already
-            # computed on device — observing one step behind costs no
-            # dispatch stall; detection latency is one step)
-            self.health_flush()
-        if scaled:
-            args = (self.params, self.opt_state, self.buffers,
-                    self.scaler_state, self.ef_state, xg, yg,
-                    jnp.float32(lr), jnp.uint32(self._seed + self._step_i))
-        else:
-            args = (self.params, self.opt_state, self.buffers,
-                    self.ef_state, xg, yg,
-                    jnp.float32(lr), jnp.uint32(self._seed + self._step_i))
-        if self._health:
-            args = args + (jnp.asarray(self._health_poison),)
-        with jax.set_mesh(self.mesh):
-            exe, first = self._executable("step", "sharded_train_step",
-                                          self._compiled, args, xg, yg)
-            out = exe(*args)
-            hstats = None
+        with _span("train/step", step=self._step_i) as sp:
+            xg, yg = self._to_global_batch(x), self._to_global_batch(y)
+            scaled = self.scaler_state is not None
             if self._health:
-                out, hstats = out[:-1], out[-1]
+                # deliver the PREVIOUS step's stats first (they are already
+                # computed on device — observing one step behind costs no
+                # dispatch stall; detection latency is one step)
+                self.health_flush()
             if scaled:
-                (self.params, self.opt_state, self.buffers, self.ef_state,
-                 self.scaler_state, loss) = out
+                args = (self.params, self.opt_state, self.buffers,
+                        self.scaler_state, self.ef_state, xg, yg,
+                        jnp.float32(lr),
+                        jnp.uint32(self._seed + self._step_i))
             else:
-                (self.params, self.opt_state, self.buffers, self.ef_state,
-                 loss) = out
-        if self._health:
-            self._health_observe(loss, hstats)
-        if obs:
+                args = (self.params, self.opt_state, self.buffers,
+                        self.ef_state, xg, yg,
+                        jnp.float32(lr),
+                        jnp.uint32(self._seed + self._step_i))
+            if self._health:
+                args = args + (jnp.asarray(self._health_poison),)
+            with jax.set_mesh(self.mesh):
+                exe, first = self._executable("step", "sharded_train_step",
+                                              self._compiled, args, xg, yg)
+                out = exe(*args)
+                hstats = None
+                if self._health:
+                    out, hstats = out[:-1], out[-1]
+                if scaled:
+                    (self.params, self.opt_state, self.buffers,
+                     self.ef_state, self.scaler_state, loss) = out
+                else:
+                    (self.params, self.opt_state, self.buffers,
+                     self.ef_state, loss) = out
+            if self._health:
+                self._health_observe(loss, hstats)
+            sp.set(first=int(first))
+        if _obs_metrics.enabled():
             samples = None
             if hasattr(x, "shape") and len(getattr(x, "shape", ())) >= 1:
                 samples = int(x.shape[0])
-            self._obs_record("sharded_train_step", first,
-                             time.perf_counter() - t0, samples)
+            self._obs_record("sharded_train_step", first, sp.seconds,
+                             samples)
         return loss
 
     step = __call__

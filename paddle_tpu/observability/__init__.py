@@ -82,21 +82,20 @@ from .metrics import (  # noqa: F401
 from .tracing import (  # noqa: F401
     add_span_sink,
     clear_spans,
-    export_chrome_trace,
     remove_span_sink,
     set_max_spans,
     span,
     spans,
 )
-from .training import record_step, record_window  # noqa: F401
+from .training import record_step  # noqa: F401
 
 __all__ = [
     "MetricsRegistry", "enabled", "enable", "disable",
     "counter", "gauge", "histogram", "snapshot", "reset", "get_registry",
     "summary", "dump_jsonl", "hist_totals",
-    "span", "spans", "clear_spans", "export_chrome_trace",
+    "span", "spans", "clear_spans",
     "add_span_sink", "remove_span_sink", "set_max_spans",
-    "record_collective", "record_compile", "record_step", "record_window",
+    "record_collective", "record_compile", "record_step",
     "MetricsExporter", "start_exporter", "stop_exporter", "get_exporter",
     "FlightRecorder", "start_flight_recorder", "stop_flight_recorder",
     "get_flight_recorder", "read_flight", "record_event",

@@ -21,9 +21,8 @@ hunting (dispatch, stalls, non-overlapped transfers).
 
 Stdlib-only BY CONTRACT, like ``aggregate.py``: ``tools/perf_report.py``
 imports this module through the synthetic-package trick with no jax
-installed, so hardware peaks are mirrored constants (a test pins the TPU
-peak equal to ``training.peak_flops``) and metric recording goes through
-a lazily imported, failure-tolerant hook.
+installed, so hardware peaks are mirrored constants and metric recording
+goes through a lazily imported, failure-tolerant hook.
 """
 
 from __future__ import annotations

@@ -12,7 +12,8 @@ Metric naming scheme (see observability/README.md):
     <layer>.<subject>.<measure>{label=value,...}
 
 e.g. ``ir.pass.seconds{pass=cse}``, ``dist.collective.bytes{op=ppermute}``,
-``jit.compile.cache_miss{site=sharded_train_step}``, ``train.mfu``.
+``jit.compile.cache_miss{site=sharded_train_step}``,
+``train.step.dispatch_seconds``.
 
 Thread safety: all mutation and the snapshot/reset API take one lock;
 snapshots are deep copies so a caller can never observe a half-updated

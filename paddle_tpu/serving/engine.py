@@ -41,6 +41,7 @@ from ..core.tensor import Tensor
 from ..observability import instrument as _obs
 from ..observability import memory as _obs_memory
 from ..observability import metrics as _metrics
+from ..observability.tracing import span as _span
 from . import sampling as _sampling
 from .kv_cache import (KVCache, PAGE_SENTINEL, PagedKVCache,
                        use_paged_attention_impl)
@@ -75,8 +76,9 @@ def _dummy_key():
 def _aot(cache: Dict, key, site: str, fn, args,
          donate_argnums: Tuple[int, ...] = ()) -> "jax.stages.Compiled":
     """AOT compile-or-fetch with observability accounting: a dict hit bumps
-    ``jit.compile.cache_hit{site=}``, a miss compiles (timed into
-    ``jit.compile.seconds{site=}``) and bumps the miss counter. The
+    ``jit.compile.cache_hit{site=}``, a miss compiles under a ``compile``
+    span (whose seconds feed ``jit.compile.seconds{site=}``) and bumps the
+    miss counter. The
     compiled executable is shape-locked — drifting shapes raise rather
     than recompile, which is what makes the one-compile guarantee
     testable. ``donate_argnums`` marks input buffers the caller never
@@ -85,16 +87,15 @@ def _aot(cache: Dict, key, site: str, fn, args,
     if exe is not None:
         _obs.record_compile(site, cache_hit=True)
         return exe
-    t0 = time.perf_counter()
-    with warnings.catch_warnings():
+    with _span("compile", site=site, cache_hit=0) as sp, \
+            warnings.catch_warnings():
         # CPU/interpreter backends may decline the aliasing; the donation
         # contract is still correct (and active on TPU) — keep logs quiet
         warnings.filterwarnings(
             "ignore", message=".*donated buffers.*", category=UserWarning)
         exe = jax.jit(fn, donate_argnums=tuple(donate_argnums)) \
             .lower(*args).compile()
-    _obs.record_compile(site, seconds=time.perf_counter() - t0,
-                        cache_hit=False)
+    _obs.record_compile(site, seconds=sp.seconds, cache_hit=False)
     _obs_memory.record_executable(site, exe)
     cache[key] = exe
     return exe
@@ -351,6 +352,8 @@ class Engine:
         self._top_ks = np.zeros((B,), np.int32)
         self._greedy = np.ones((B,), bool)
         self._exe: Dict = {}
+        self._step_i = 0  # engine steps so far: the spans' ``step``
+        self._cow_copies = 0  # copy-on-write page copies so far
         self.prefix_cache: Optional[PrefixCache] = None
         if self.config.prefix_cache:
             self.prefix_cache = PrefixCache(self.cache.page_size,
@@ -436,22 +439,25 @@ class Engine:
         if len(sampling) != len(prompts):
             raise ValueError("len(sampling) != len(prompts)")
         reqs = [self.add_request(p, sp) for p, sp in zip(prompts, sampling)]
-        t0 = time.perf_counter()
-        while self.scheduler.has_unfinished:
-            self.step()
-        elapsed = time.perf_counter() - t0
+        with _span("serving/generate", requests=len(reqs)) as drain:
+            while self.scheduler.has_unfinished:
+                self.step()
         total = sum(r.num_generated for r in reqs)
-        if elapsed > 0:
-            _metrics.gauge("serving.tokens_per_sec", total / elapsed)
+        if drain.seconds > 0:
+            _metrics.gauge("serving.tokens_per_sec", total / drain.seconds)
         return [r.output_ids for r in reqs]
 
     # -- engine loop --
     def step(self):
         """One scheduler iteration: admit waiting requests into free slots
         (bucketed prefill + first token each), then one batched decode step
-        over every running request."""
-        self._admit()
-        self._decode()
+        over every running request. The whole of it is one ``serving/step``
+        span whose children are the phases (serving/README.md lists them)."""
+        self._step_i += 1
+        with _span("serving/step", step=self._step_i,
+                   running=len(self.scheduler.running),
+                   waiting=len(self.scheduler.waiting)) as sp:
+            sp.set(emitted=self._admit() + self._decode())
 
     # -- internals --
     def _bucket(self, n: int) -> int:
@@ -718,46 +724,71 @@ class Engine:
         the first decode step writes into."""
         return prompt_len // self.cache.page_size + 1
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Admit waiting requests while slots are free; returns how many
+        (each emits its first token)."""
+        admitted = 0
         while self.cache.free_slots and self.scheduler.waiting:
             # PEEK before committing: paged admission can backpressure on
             # the page pool, leaving the head request queued until a finish
             # frees pages (dense admission never backpressures — a free
             # slot IS the whole reservation)
-            req = self.scheduler.waiting[0]
-            n = len(req.prompt_ids)
-            owner = f"req{req.request_id}"
+            if not self._admit_one(self.scheduler.waiting[0]):
+                break
+            admitted += 1
+        return admitted
+
+    def _admit_one(self, req: Request) -> bool:
+        """One admission, from the peek to the first token on the host, as
+        one ``serving/admit`` span over its phases; False (the span and its
+        ``alloc`` child say ``blocked=1``) when the page pool is short and
+        the request stays queued."""
+        n = len(req.prompt_ids)
+        owner = f"req{req.request_id}"
+        with _span("serving/admit", request_id=req.request_id,
+                   prompt_tokens=n) as adm:
             hit_blocks, hit_pages = 0, []
             if self.prefix_cache is not None:
-                hit_blocks, hit_pages = self.prefix_cache.match(req.prompt_ids)
-            pages = None
-            if self.page_alloc is not None:
-                need = self._pages_needed(n) - hit_blocks
-                pages = self.page_alloc.alloc(need, owner=owner)
-                if pages is None and self.prefix_cache is not None:
-                    # pool short: reclaim cold cached prefixes, then retry
-                    self.prefix_cache.evict_lru(need)
+                with _span("serving/admit/match",
+                           request_id=req.request_id):
+                    hit_blocks, hit_pages = \
+                        self.prefix_cache.match(req.prompt_ids)
+            with _span("serving/admit/alloc",
+                       request_id=req.request_id) as alloc:
+                pages, evicted = None, 0
+                if self.page_alloc is not None:
+                    need = self._pages_needed(n) - hit_blocks
                     pages = self.page_alloc.alloc(need, owner=owner)
-                if pages is None:
-                    break
-            self.scheduler.next_waiting()  # pops the peeked head
-            slot = self.cache.alloc_slot()
-            req.slot = slot
-            t0 = time.perf_counter()
-            if pages is not None:
-                if hit_pages:
-                    # the SPLICE: this request becomes one more sharer of
-                    # the matched blocks' physical pages — a refcount bump
-                    # and a table-row write, no device work for the prefix
-                    self.page_alloc.retain(hit_pages, owner=owner)
-                    self.cache.assign_pages(slot, hit_pages)
-                    req.prefix_hit_blocks = hit_blocks
-                self.cache.assign_pages(slot, pages, start_block=hit_blocks)
+                    if pages is None and self.prefix_cache is not None:
+                        # pool short: reclaim cold cached prefixes, retry
+                        evicted = self.prefix_cache.evict_lru(need)
+                        pages = self.page_alloc.alloc(need, owner=owner)
+                    if pages is None:
+                        alloc.set(pages=0, evicted=evicted, blocked=1)
+                        adm.set(blocked=1)
+                        return False
+                self.scheduler.next_waiting()  # pops the peeked head
+                slot = self.cache.alloc_slot()
+                req.slot = slot
+                if pages is not None:
+                    if hit_pages:
+                        # the SPLICE: this request becomes one more sharer
+                        # of the matched blocks' physical pages — a refcount
+                        # bump and a table-row write, no device work for the
+                        # prefix
+                        self.page_alloc.retain(hit_pages, owner=owner)
+                        self.cache.assign_pages(slot, hit_pages)
+                        req.prefix_hit_blocks = hit_blocks
+                    self.cache.assign_pages(slot, pages,
+                                            start_block=hit_blocks)
+                    alloc.set(pages=len(pages), evicted=evicted)
+            adm.set(queued_s=req.admit_time - req.arrival_time,
+                    hit_blocks=hit_blocks)
             if self.prefix_cache is not None:
                 if hit_blocks:
                     _metrics.counter("serving.prefix.hits", 1)
                     _metrics.histogram("serving.prefix.splice_seconds",
-                                       time.perf_counter() - t0)
+                                       alloc.seconds)
                 else:
                     _metrics.counter("serving.prefix.misses", 1)
             sp = req.sampling
@@ -769,53 +800,59 @@ class Engine:
                 start = hit_blocks * ps
                 m = n - start
                 T = self._bucket(m)
-                ids = np.zeros((1, T), np.int32)
-                ids[0, :m] = req.prompt_ids[start:]
-                exe = self._extend_exe(T)
-                logits, self.cache.k, self.cache.v = exe(
-                    self.params, self.cache.k, self.cache.v,
-                    jnp.asarray(ids), jnp.asarray(self.cache.page_table[slot]),
-                    jnp.int32(start), jnp.int32(m))
-            else:
-                T = self._bucket(n)
-                ids = np.zeros((1, T), np.int32)
-                ids[0, :n] = req.prompt_ids
-                exe = self._prefill_exe(T)
-                if self.page_alloc is not None:
+                with _span("serving/admit/extend",
+                           request_id=req.request_id, tokens=m, bucket=T):
+                    ids = np.zeros((1, T), np.int32)
+                    ids[0, :m] = req.prompt_ids[start:]
+                    exe = self._extend_exe(T)
                     logits, self.cache.k, self.cache.v = exe(
                         self.params, self.cache.k, self.cache.v,
                         jnp.asarray(ids),
                         jnp.asarray(self.cache.page_table[slot]),
-                        jnp.int32(n))
-                else:
+                        jnp.int32(start), jnp.int32(m))
+            else:
+                T = self._bucket(n)
+                with _span("serving/admit/prefill",
+                           request_id=req.request_id, tokens=n, bucket=T):
+                    ids = np.zeros((1, T), np.int32)
+                    ids[0, :n] = req.prompt_ids
+                    exe = self._prefill_exe(T)
+                    where = (jnp.asarray(self.cache.page_table[slot])
+                             if self.page_alloc is not None
+                             else jnp.int32(slot))
                     logits, self.cache.k, self.cache.v = exe(
                         self.params, self.cache.k, self.cache.v,
-                        jnp.asarray(ids), jnp.int32(slot), jnp.int32(n))
-            if self.prefix_cache is not None:
-                # index this prompt's FULL blocks (shared ones are already
-                # nodes; fresh ones take a trie-owned reference and become
-                # matchable the moment the next prompt agrees)
-                self.prefix_cache.insert(req.prompt_ids,
-                                         self.cache.slot_pages(slot)[:n // ps])
-            key = _random.next_key() if sp.do_sample else _dummy_key()
-            tok = int(np.asarray(_sampling.sample_static(
-                logits, key, do_sample=sp.do_sample,
-                temperature=sp.temperature, top_k=sp.top_k))[0])
-            now = time.perf_counter()
-            req.first_token_time = now
-            _metrics.histogram("serving.prefill.seconds", now - t0)
-            _metrics.histogram("serving.ttft.seconds", now - req.arrival_time)
-            _metrics.counter("serving.tokens.generated", 1)
-            if self.tracer is not None:
-                self.tracer.on_prefill(req, t0, now)
-            self._slots[slot].request = req
-            self._tokens[slot] = tok
-            self._positions[slot] = n  # first generated token's index
-            self._temps[slot] = sp.temperature
-            self._top_ks[slot] = sp.top_k
-            self._greedy[slot] = not sp.do_sample
-            req.output_ids.append(tok)
-            self._maybe_finish(req, tok)
+                        jnp.asarray(ids), where, jnp.int32(n))
+            with _span("serving/admit/sample", request_id=req.request_id):
+                if self.prefix_cache is not None:
+                    # index this prompt's FULL blocks (shared ones are
+                    # already nodes; fresh ones take a trie-owned reference
+                    # and become matchable the moment the next prompt
+                    # agrees)
+                    self.prefix_cache.insert(
+                        req.prompt_ids,
+                        self.cache.slot_pages(slot)[:n // ps])
+                key = _random.next_key() if sp.do_sample else _dummy_key()
+                tok = int(np.asarray(_sampling.sample_static(
+                    logits, key, do_sample=sp.do_sample,
+                    temperature=sp.temperature, top_k=sp.top_k))[0])
+        # the first token's time is the end of its serving/admit span
+        req.first_token_time = time.perf_counter()
+        _metrics.histogram("serving.prefill.seconds", adm.seconds)
+        _metrics.histogram("serving.ttft.seconds",
+                           req.first_token_time - req.arrival_time)
+        _metrics.counter("serving.tokens.generated", 1)
+        if self.tracer is not None:
+            self.tracer.on_prefill(req)
+        self._slots[slot].request = req
+        self._tokens[slot] = tok
+        self._positions[slot] = n  # first generated token's index
+        self._temps[slot] = sp.temperature
+        self._top_ks[slot] = sp.top_k
+        self._greedy[slot] = not sp.do_sample
+        req.output_ids.append(tok)
+        self._maybe_finish(req, tok)
+        return True
 
     def _ensure_writable(self, slot: int, block: int, owner: str) -> bool:
         """Copy-on-write guard: a slot about to WRITE ``block`` must own its
@@ -836,6 +873,7 @@ class Engine:
         if fresh is None:
             return False
         self.cache.copy_page(page, fresh[0])
+        self._cow_copies += 1
         self.cache.page_table[slot, block] = fresh[0]
         self.page_alloc.free([page], owner=owner)
         return True
@@ -849,71 +887,85 @@ class Engine:
         generated prefix is intact) — the pages it frees may already
         unblock the next waiting request."""
         ps, S_max = self.cache.page_size, self.config.max_seq_len
-        for slot, st in enumerate(self._slots):
-            req = st.request
-            if req is None:
-                continue
-            owner = f"req{req.request_id}"
-            p = int(self._positions[slot])
-            last = min(p + width - 1, S_max - 1)
-            ok = True
-            for block in range(p // ps, last // ps + 1):
-                if self.cache.page_table[slot, block] == PAGE_SENTINEL:
-                    pages = self.page_alloc.alloc(1, owner=owner)
-                    if pages is None and self.prefix_cache is not None:
-                        self.prefix_cache.evict_lru(1)
+        allocated = cache_full = 0
+        cow_before = self._cow_copies
+        with _span("serving/decode/grow_pages") as sp:
+            for slot, st in enumerate(self._slots):
+                req = st.request
+                if req is None:
+                    continue
+                owner = f"req{req.request_id}"
+                p = int(self._positions[slot])
+                last = min(p + width - 1, S_max - 1)
+                ok = True
+                for block in range(p // ps, last // ps + 1):
+                    if self.cache.page_table[slot, block] == PAGE_SENTINEL:
                         pages = self.page_alloc.alloc(1, owner=owner)
-                    if pages is None:
+                        if pages is None and self.prefix_cache is not None:
+                            self.prefix_cache.evict_lru(1)
+                            pages = self.page_alloc.alloc(1, owner=owner)
+                        if pages is None:
+                            ok = False
+                            break
+                        self.cache.assign_pages(slot, pages,
+                                                start_block=block)
+                        allocated += 1
+                    elif not self._ensure_writable(slot, block, owner):
                         ok = False
                         break
-                    self.cache.assign_pages(slot, pages, start_block=block)
-                elif not self._ensure_writable(slot, block, owner):
-                    ok = False
-                    break
-            if not ok:
-                self._finish(req, "cache_full")
+                if not ok:
+                    self._finish(req, "cache_full")
+                    cache_full += 1
+            sp.set(allocated=allocated, cache_full=cache_full,
+                   cow_copies=self._cow_copies - cow_before)
 
-    def _decode(self):
+    def _decode(self) -> int:
+        """One batched decode step, as one ``serving/decode`` span over its
+        phases; returns the tokens emitted."""
         if self.spec is not None:
             return self._decode_speculative()
-        if self.page_alloc is not None:
-            self._grow_pages()
-        running = [s.request for s in self._slots if s.request is not None]
-        if not running:
-            return
-        t0 = time.perf_counter()
-        any_sampled = not bool(self._greedy.all())
-        key = _random.next_key() if any_sampled else _dummy_key()
-        exe = self._decode_exe()
-        if self.page_alloc is not None:
-            nxt, self.cache.k, self.cache.v = exe(
-                self.params, self.cache.k, self.cache.v,
-                self.cache.table_device(),
-                jnp.asarray(self._tokens), jnp.asarray(self._positions),
-                jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                jnp.asarray(self._greedy), key)
-        else:
-            nxt, self.cache.k, self.cache.v = exe(
-                self.params, self.cache.k, self.cache.v,
-                jnp.asarray(self._tokens), jnp.asarray(self._positions),
-                jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                jnp.asarray(self._greedy), key)
-        nxt = np.asarray(nxt)
-        step_s = time.perf_counter() - t0
-        _metrics.histogram("serving.decode.step.seconds", step_s)
-        _metrics.counter("serving.tokens.generated", len(running))
-        for req in running:
-            slot = req.slot
-            tok = int(nxt[slot])
-            req.output_ids.append(tok)
-            self._tokens[slot] = tok
-            self._positions[slot] += 1
-            self.scheduler.observe_decode_step(req, step_s)
-            if self.tracer is not None:
-                self.tracer.on_decode_step(req, step_s)
-            self._maybe_finish(req, tok)
+        with _span("serving/decode", step=self._step_i) as sp:
+            if self.page_alloc is not None:
+                self._grow_pages()
+            running = [s.request for s in self._slots
+                       if s.request is not None]
+            sp.set(running=len(running))
+            if not running:
+                return 0
+            with _span("serving/decode/upload") as up:
+                any_sampled = not bool(self._greedy.all())
+                key = _random.next_key() if any_sampled else _dummy_key()
+                table = ((self.cache.table_device(),)
+                         if self.page_alloc is not None else ())
+                args = table + (
+                    jnp.asarray(self._tokens), jnp.asarray(self._positions),
+                    jnp.asarray(self._temps), jnp.asarray(self._top_ks),
+                    jnp.asarray(self._greedy), key)
+            with _span("serving/decode/dispatch") as disp:
+                exe = self._decode_exe()
+                nxt, self.cache.k, self.cache.v = exe(
+                    self.params, self.cache.k, self.cache.v, *args)
+            with _span("serving/decode/fetch") as fetch:
+                nxt = np.asarray(nxt)
+            step_s = up.seconds + disp.seconds + fetch.seconds
+            _metrics.histogram("serving.decode.step.seconds", step_s)
+            _metrics.counter("serving.tokens.generated", len(running))
+            with _span("serving/decode/settle") as settle:
+                for req in running:
+                    slot = req.slot
+                    tok = int(nxt[slot])
+                    req.output_ids.append(tok)
+                    self._tokens[slot] = tok
+                    self._positions[slot] += 1
+                    self.scheduler.observe_decode_step(req, step_s)
+                    if self.tracer is not None:
+                        self.tracer.on_decode_step(req)
+                    self._maybe_finish(req, tok)
+                settle.set(finished=len(running)
+                           - len(self.scheduler.running))
+            return len(running)
 
-    def _decode_speculative(self):
+    def _decode_speculative(self) -> int:
         """One verify-k step for every running slot: propose ``k`` n-gram
         drafts per row, run the ONE verify executable over the static
         ``[B, k+1]`` block, then settle per row on the host — greedy rows
@@ -923,70 +975,85 @@ class Engine:
         sampled token. Rejected drafts cost nothing: their K/V sits at
         positions the next verify step overwrites before attending, so
         rollback is just NOT advancing ``_positions`` past the kept
-        tokens."""
+        tokens. Same spans as ``_decode``, plus ``serving/decode/propose``."""
         spec = self.spec
         k = spec.k
-        self._grow_pages(width=k + 1)
-        running = [s.request for s in self._slots if s.request is not None]
-        if not running:
-            return
-        t0 = time.perf_counter()
-        B = self.config.max_batch_size
-        block = np.zeros((B, k + 1), np.int32)
-        drafts: Dict[int, List[int]] = {}
-        for req in running:
-            slot = req.slot
-            d = propose_ngram(req.prompt_ids + req.output_ids, k, spec.ngram)
-            drafts[slot] = d
-            block[slot, 0] = self._tokens[slot]
-            block[slot, 1:] = d
-        any_sampled = not bool(self._greedy.all())
-        key = _random.next_key() if any_sampled else _dummy_key()
-        exe = self._verify_exe()
-        targets, sampled0, self.cache.k, self.cache.v = exe(
-            self.params, self.cache.k, self.cache.v,
-            self.cache.table_device(), jnp.asarray(block),
-            jnp.asarray(self._positions), jnp.asarray(self._temps),
-            jnp.asarray(self._top_ks), jnp.asarray(self._greedy), key)
-        targets = np.asarray(targets)
-        sampled0 = np.asarray(sampled0)
-        step_s = time.perf_counter() - t0
-        _metrics.histogram("serving.decode.step.seconds", step_s)
-        emitted_total = 0
-        drafted_now = accepted_now = 0
-        for req in running:
-            slot = req.slot
-            if self._greedy[slot]:
-                a, emitted = accept_greedy(drafts[slot], targets[slot])
-                req.draft_tokens += k
-                req.accepted_tokens += a
-                drafted_now += k
-                accepted_now += a
-                self._spec_slots += k + 1
-                self._spec_emitted += len(emitted)
-            else:
-                emitted = [int(sampled0[slot])]
-            for tok in emitted:
-                tok = int(tok)
-                req.output_ids.append(tok)
-                self._tokens[slot] = tok
-                self._positions[slot] += 1
-                emitted_total += 1
-                self._maybe_finish(req, tok)
-                if req.state == FINISHED:
-                    break
-            self.scheduler.observe_decode_step(req, step_s)
-            if self.tracer is not None:
-                self.tracer.on_decode_step(req, step_s)
-        self._spec_drafted += drafted_now
-        self._spec_accepted += accepted_now
-        _metrics.counter("serving.tokens.generated", emitted_total)
-        if drafted_now:
-            _metrics.counter("serving.spec.draft_tokens", drafted_now)
-            _metrics.counter("serving.spec.accepted_tokens", accepted_now)
-        if self._spec_slots:
-            _metrics.gauge("serving.spec.accept_rate",
-                           self._spec_emitted / self._spec_slots)
+        with _span("serving/decode", step=self._step_i) as sp:
+            self._grow_pages(width=k + 1)
+            running = [s.request for s in self._slots
+                       if s.request is not None]
+            sp.set(running=len(running))
+            if not running:
+                return 0
+            with _span("serving/decode/propose") as prop:
+                B = self.config.max_batch_size
+                block = np.zeros((B, k + 1), np.int32)
+                drafts: Dict[int, List[int]] = {}
+                for req in running:
+                    slot = req.slot
+                    d = propose_ngram(req.prompt_ids + req.output_ids, k,
+                                      spec.ngram)
+                    drafts[slot] = d
+                    block[slot, 0] = self._tokens[slot]
+                    block[slot, 1:] = d
+            with _span("serving/decode/upload") as up:
+                any_sampled = not bool(self._greedy.all())
+                key = _random.next_key() if any_sampled else _dummy_key()
+                args = (self.cache.table_device(), jnp.asarray(block),
+                        jnp.asarray(self._positions),
+                        jnp.asarray(self._temps), jnp.asarray(self._top_ks),
+                        jnp.asarray(self._greedy), key)
+            with _span("serving/decode/dispatch") as disp:
+                exe = self._verify_exe()
+                targets, sampled0, self.cache.k, self.cache.v = exe(
+                    self.params, self.cache.k, self.cache.v, *args)
+            with _span("serving/decode/fetch") as fetch:
+                targets = np.asarray(targets)
+                sampled0 = np.asarray(sampled0)
+            step_s = (prop.seconds + up.seconds + disp.seconds
+                      + fetch.seconds)
+            _metrics.histogram("serving.decode.step.seconds", step_s)
+            emitted_total = 0
+            drafted_now = accepted_now = 0
+            with _span("serving/decode/settle") as settle:
+                for req in running:
+                    slot = req.slot
+                    if self._greedy[slot]:
+                        a, emitted = accept_greedy(drafts[slot],
+                                                   targets[slot])
+                        req.draft_tokens += k
+                        req.accepted_tokens += a
+                        drafted_now += k
+                        accepted_now += a
+                        self._spec_slots += k + 1
+                        self._spec_emitted += len(emitted)
+                    else:
+                        emitted = [int(sampled0[slot])]
+                    for tok in emitted:
+                        tok = int(tok)
+                        req.output_ids.append(tok)
+                        self._tokens[slot] = tok
+                        self._positions[slot] += 1
+                        emitted_total += 1
+                        self._maybe_finish(req, tok)
+                        if req.state == FINISHED:
+                            break
+                    self.scheduler.observe_decode_step(req, step_s)
+                    if self.tracer is not None:
+                        self.tracer.on_decode_step(req)
+                settle.set(finished=len(running)
+                           - len(self.scheduler.running))
+            self._spec_drafted += drafted_now
+            self._spec_accepted += accepted_now
+            _metrics.counter("serving.tokens.generated", emitted_total)
+            if drafted_now:
+                _metrics.counter("serving.spec.draft_tokens", drafted_now)
+                _metrics.counter("serving.spec.accepted_tokens",
+                                 accepted_now)
+            if self._spec_slots:
+                _metrics.gauge("serving.spec.accept_rate",
+                               self._spec_emitted / self._spec_slots)
+            return emitted_total
 
     def _maybe_finish(self, req: Request, tok: int):
         sp = req.sampling

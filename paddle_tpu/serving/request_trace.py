@@ -112,21 +112,28 @@ class RequestTracer:
             "violations": [],
         }
 
-    def on_prefill(self, req, admit_t: float, first_token_t: float) -> None:
+    def on_prefill(self, req) -> None:
+        """The first token is on the host: ``req.admit_time`` and
+        ``req.first_token_time`` are set."""
         tr = self._live.get(req.request_id)
         if tr is None:
             return
-        tr["admit"] = admit_t
-        tr["first_token"] = first_token_t
+        tr["admit"] = req.admit_time
+        tr["first_token"] = tr["last_token"] = req.first_token_time
         if self.slo is not None:
-            ttft = first_token_t - req.arrival_time
+            ttft = req.first_token_time - req.arrival_time
             if ttft > self.slo.ttft_target_s:
                 self._violate(req, tr, "ttft", ttft)
 
-    def on_decode_step(self, req, seconds: float) -> None:
+    def on_decode_step(self, req) -> None:
+        """A decode step's tokens for ``req`` are on the host. The step's
+        seconds are this request's own: since its previous tokens, so an
+        admission that held the batch between two steps counts."""
         tr = self._live.get(req.request_id)
         if tr is None:
             return
+        now = time.perf_counter()
+        seconds, tr["last_token"] = now - tr["last_token"], now
         tr["decode_steps"] += 1
         tr["decode_total_s"] += seconds
         if seconds > tr["decode_max_s"]:

@@ -47,6 +47,7 @@ class Request:
         self.accepted_tokens = 0
         # timing (host clocks; feed the ttft/tpot histograms)
         self.arrival_time = time.perf_counter()
+        self.admit_time: Optional[float] = None  # popped from the queue
         self.first_token_time: Optional[float] = None
         self.finish_time: Optional[float] = None
 
@@ -198,6 +199,7 @@ class Scheduler:
         if not self.waiting:
             return None
         req = self.waiting.popleft()
+        req.admit_time = time.perf_counter()
         req.state = RUNNING
         self.running.append(req)
         self._export_gauges()

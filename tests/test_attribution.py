@@ -71,22 +71,6 @@ def test_hardware_for_device():
             attribution.hardware_for_device(unknown)
 
 
-def test_tpu_peak_pinned_to_training_tier():
-    """The roofline's compute peak must stay in lockstep with the MFU
-    accounting's (observability/training.py) — two different 'peaks' would
-    make gap and MFU mutually inconsistent."""
-    from paddle_tpu.observability import training
-
-    # ...and it IS the Hardware table, for every device row, so the two
-    # can't drift again; an unknown device raises there too
-    for kind in attribution.HW_SPECS:
-        assert training.peak_flops(kind) == \
-            attribution.hardware_for_device(kind).peak_flops
-    assert training.peak_flops() == attribution.HW_SPECS["cpu"].peak_flops
-    with pytest.raises(KeyError):
-        training.peak_flops("???")
-
-
 def test_tolerances_pinned_to_hlo_audit():
     """reconcile_sites shares the HLO-audit gate's tolerances — the two
     ledgers cross-check the same bytes and must agree on 'close enough'."""

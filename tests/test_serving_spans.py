@@ -1,0 +1,359 @@
+"""Phase spans inside serving.Engine and the train step (ISSUE 24).
+
+Covers the one span primitive (ids, parents, attributes, the shared no-op),
+the engine's span tree per step (plain and speculative), what stays empty
+with everything off, the profiler-session path (ring fills with the flag
+off, registry stays empty, spans sit on the XPlane's clock), the ``compile``
+span, the documented ``serving.*.seconds`` histograms now fed from spans,
+the blocked-admission span, ``Request.admit_time`` and the ``train/step``
+span.
+"""
+
+import glob
+import os
+from collections import Counter, defaultdict
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import observability as obs
+from paddle_tpu.models.gpt import gpt_tiny
+from paddle_tpu.serving import Engine, EngineConfig, SamplingParams
+
+ADMIT_PHASES = {"serving/admit/match", "serving/admit/alloc",
+                "serving/admit/prefill", "serving/admit/extend",
+                "serving/admit/sample"}
+
+
+@pytest.fixture
+def telemetry():
+    obs.enable()
+    obs.reset()
+    obs.clear_spans()
+    yield obs
+    obs.disable()
+    obs.reset()
+    obs.clear_spans()
+
+
+@pytest.fixture
+def quiet():
+    """Flag off, registry and ring empty before and after."""
+    obs.disable()
+    obs.reset()
+    obs.clear_spans()
+    yield
+    obs.reset()
+    obs.clear_spans()
+
+
+def _tiny():
+    paddle.seed(0)
+    m = gpt_tiny(dropout=0.0, num_layers=2)
+    m.eval()
+    return m
+
+
+def _prompts():
+    rng = np.random.default_rng(3)
+    first = [int(t) for t in rng.integers(1, 100, (40,))]
+    other = [int(t) for t in rng.integers(1, 100, (20,))]
+    # shares two whole 16-token blocks with ``first``: a prefix hit
+    third = first[:32] + [int(t) for t in rng.integers(1, 100, (9,))]
+    return [first, other, third]
+
+
+def _engine(model, speculative=None, **kw):
+    cfg = dict(max_batch_size=2, max_seq_len=64, page_size=16,
+               prefix_cache=True, speculative=speculative)
+    cfg.update(kw)
+    return Engine(model, EngineConfig(**cfg))
+
+
+def _generate(eng, n=6):
+    return eng.generate(_prompts(), SamplingParams(max_new_tokens=n))
+
+
+def _children(spans):
+    kids = defaultdict(list)
+    for e in spans:
+        kids[e["parent"]].append(e)
+    return kids
+
+
+SPEC = pytest.mark.parametrize("speculative", [None, 2],
+                               ids=["plain", "speculative"])
+
+
+# ---------------- the primitive --------------------------------------------
+def test_span_ids_parents_and_attributes(telemetry):
+    with obs.span("outer", site="a", step=3) as outer:
+        with obs.span("inner", request_id=7) as inner:
+            inner.set(tokens=5)
+        outer.set(emitted=2)
+    inner_ev, outer_ev = obs.spans()
+    assert outer_ev["parent"] is None and inner_ev["parent"] == outer_ev["id"]
+    # strings label the span (name and histogram series); numbers do not
+    assert outer_ev["name"] == "outer{site=a}" and inner_ev["name"] == "inner"
+    assert outer_ev["attrs"] == {"site": "a", "step": 3, "emitted": 2}
+    assert inner_ev["attrs"] == {"request_id": 7, "tokens": 5}
+    assert outer_ev["ts"] <= inner_ev["ts"]
+    assert inner_ev["ts"] + inner_ev["dur"] <= outer_ev["ts"] + outer_ev["dur"]
+    hists = obs.snapshot()["histograms"]
+    assert hists["outer.seconds{site=a}"]["count"] == 1
+    assert hists["inner.seconds"]["count"] == 1
+    assert outer.seconds == pytest.approx(outer_ev["dur"] * 1e-6)
+
+
+def test_span_off_is_one_shared_noop(quiet):
+    a, b = obs.span("x", step=1), obs.span("y")
+    assert a is b
+    with a as sp:
+        sp.set(anything=1)
+    assert sp.seconds == 0.0
+    assert obs.spans() == [] and len(obs.get_registry()) == 0
+
+
+# ---------------- the engine's span tree -----------------------------------
+@SPEC
+def test_every_step_has_its_phases_in_order(telemetry, speculative):
+    eng = _engine(_tiny(), speculative)
+    obs.clear_spans()  # the verify program compiles at construction
+    _generate(eng)
+    spans = obs.spans()
+    by_id = {e["id"]: e for e in spans}
+    kids = _children(spans)
+    steps = [e for e in spans if e["name"] == "serving/step"]
+    assert len(steps) >= 3
+    assert [s["attrs"]["step"] for s in steps] == list(
+        range(1, len(steps) + 1))
+    decode_order = ["serving/decode/grow_pages"] + (
+        ["serving/decode/propose"] if speculative else []) + [
+        "serving/decode/upload", "serving/decode/dispatch",
+        "serving/decode/fetch", "serving/decode/settle"]
+    admitted = hits = 0
+    decode_s = covered_s = 0.0
+    for st in steps:
+        names = [c["name"] for c in kids[st["id"]]]
+        # admissions first, then the one decode step
+        assert names == ["serving/admit"] * (len(names) - 1) + [
+            "serving/decode"]
+        assert by_id[st["parent"]]["name"] == "serving/generate"
+        emitted = 0
+        for adm in kids[st["id"]][:-1]:
+            a = adm["attrs"]
+            phases = kids[adm["id"]]
+            kind = "extend" if a["hit_blocks"] else "prefill"
+            assert [p["name"] for p in phases] == [
+                "serving/admit/match", "serving/admit/alloc",
+                "serving/admit/" + kind, "serving/admit/sample"]
+            # spans of one admission share the request's id
+            assert {p["attrs"]["request_id"] for p in phases} == {
+                a["request_id"]}
+            assert a["queued_s"] >= 0 and a["prompt_tokens"] >= 20
+            work = phases[2]["attrs"]
+            assert 0 < work["tokens"] <= work["bucket"]
+            assert work["tokens"] == a["prompt_tokens"] - 16 * a["hit_blocks"]
+            assert phases[1]["attrs"]["pages"] >= 1
+            admitted += 1
+            hits += bool(a["hit_blocks"])
+            emitted += 1
+        dec = kids[st["id"]][-1]
+        assert dec["attrs"]["step"] == st["attrs"]["step"]
+        phases = kids[dec["id"]]
+        assert [p["name"] for p in phases] == decode_order
+        assert all(p["parent"] == dec["id"] for p in phases)
+        assert {"allocated", "cow_copies", "cache_full"} <= set(
+            phases[0]["attrs"])
+        assert 0 <= phases[-1]["attrs"]["finished"] <= dec["attrs"]["running"]
+        decode_s += dec["dur"]
+        covered_s += sum(p["dur"] for p in phases)
+        assert st["attrs"]["emitted"] >= emitted + dec["attrs"]["running"]
+    assert admitted == 3 and hits == 1
+    # the children cover the decode step: nothing sizeable runs between them
+    assert covered_s >= 0.95 * decode_s
+    # every recorded span of the engine is in some step's tree
+    assert {e["name"] for e in spans} <= {
+        "serving/generate", "serving/step", "serving/admit",
+        "serving/decode"} | ADMIT_PHASES | set(decode_order) | {
+        e["name"] for e in spans if e["name"].startswith("compile")}
+
+
+@SPEC
+def test_all_off_leaves_nothing_and_tokens_match(quiet, speculative):
+    off = _generate(_engine(_tiny(), speculative))
+    assert obs.spans() == [] and len(obs.get_registry()) == 0
+    obs.enable()
+    try:
+        on = _generate(_engine(_tiny(), speculative))
+        assert obs.spans() and len(obs.get_registry()) > 0
+    finally:
+        obs.disable()
+    assert on == off
+
+
+def test_profiler_session_fills_the_ring_on_the_trace_clock(quiet, tmp_path):
+    """Flag OFF, a jax profiler session recording: the ring fills, the
+    registry stays empty, and every ring span is the XPlane event of the
+    same name once moved by the offset of a surrounding annotation."""
+    import time
+
+    from jax.profiler import ProfileData
+
+    eng = _engine(_tiny())
+    for _ in range(2):      # compile outside the session: the second pass
+        _generate(eng)      # hits the prefixes the first one cached
+    assert obs.spans() == []
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("test/around"):
+            t_around = time.perf_counter()
+            _generate(eng)
+    finally:
+        jax.profiler.stop_trace()
+    ring = obs.spans()
+    assert len(ring) > 20 and len(obs.get_registry()) == 0
+    with obs.span("after"):
+        pass
+    assert len(obs.spans()) == len(ring)   # the session is over
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serving/", "test/", "compile")):
+                    events[e.name].append(
+                        (e.start_ns * 1e-9,
+                         (e.start_ns + e.duration_ns) * 1e-9))
+    (around,) = events.pop("test/around")
+    # perf_counter was read just inside the annotation
+    offset = around[0] - t_around
+    mine = defaultdict(list)
+    for e in ring:
+        mine[e["name"]].append((e["ts"] * 1e-6 + offset,
+                                (e["ts"] + e["dur"]) * 1e-6 + offset))
+    assert Counter({k: len(v) for k, v in mine.items()}) == Counter(
+        {k: len(v) for k, v in events.items()})
+    worst = max(abs(a - b) for name in mine
+                for m, x in zip(sorted(mine[name]), sorted(events[name]))
+                for a, b in zip(m, x))
+    assert worst < 0.2e-3, worst
+
+
+def test_compile_span_once_per_site_on_first_use(telemetry):
+    eng = _engine(_tiny())
+    _generate(eng)
+    compiles = [e for e in obs.spans() if e["name"].startswith("compile")]
+    by_parent = {e["id"]: e for e in obs.spans()}
+    got = Counter((e["attrs"]["site"], by_parent[e["parent"]]["name"])
+                  for e in compiles)
+    # prompts of 40 and 20 tokens: prefill buckets 64 and 32; the 9-token
+    # suffix: extend bucket 16 (site serving.prefill too); one decode
+    assert got == Counter({
+        ("serving.prefill", "serving/admit/prefill"): 2,
+        ("serving.prefill", "serving/admit/extend"): 1,
+        ("serving.decode", "serving/decode/dispatch"): 1})
+    assert all(e["attrs"]["cache_hit"] == 0 and e["name"] ==
+               "compile{site=%s}" % e["attrs"]["site"] for e in compiles)
+    obs.clear_spans()
+    hits = obs.snapshot()["counters"].get(
+        "jit.compile.cache_hit{site=serving.prefill}", 0)
+    eng._decode_exe(), eng._prefill_exe(64), eng._extend_exe(16)
+    assert obs.spans() == []
+    assert obs.snapshot()["counters"][
+        "jit.compile.cache_hit{site=serving.prefill}"] == hits + 2
+
+
+@SPEC
+def test_documented_histograms_are_fed_from_the_spans(telemetry, speculative):
+    eng = _engine(_tiny(), speculative)
+    obs.reset()
+    obs.clear_spans()
+    _generate(eng)
+    spans = obs.spans()
+    n = Counter(e["name"] for e in spans)
+    h = {k: v["count"] for k, v in obs.snapshot()["histograms"].items()}
+    assert h["serving.prefill.seconds"] == n["serving/admit"] == 3
+    assert h["serving.ttft.seconds"] == n["serving/admit"]
+    assert h["serving.decode.step.seconds"] == n["serving/decode/dispatch"]
+    assert h["serving.decode.token.seconds"] == sum(
+        e["attrs"]["running"] for e in spans if e["name"] == "serving/decode")
+    assert h["serving.prefix.splice_seconds"] == sum(
+        1 for e in spans if e["name"] == "serving/admit"
+        and e["attrs"]["hit_blocks"])
+    assert h["serving.tpot.seconds"] == 3
+    # and each span has its own <name>.seconds series
+    assert h["serving/step.seconds"] == n["serving/step"]
+    assert h["serving/decode/fetch.seconds"] == n["serving/decode/fetch"]
+
+
+def test_blocked_admission_is_a_span(telemetry):
+    # 6 allocatable pages: the 40-token prompt takes 3, the 20-token one 2,
+    # and the third (one slot is free again only later) finds the pool short
+    eng = _engine(_tiny(), kv_pages=7, max_batch_size=3, prefix_cache=False)
+    out = _generate(eng, n=4)
+    assert all(len(o) == 4 for o in out)
+    spans = obs.spans()
+    blocked = [e for e in spans if e["name"] == "serving/admit"
+               and e["attrs"].get("blocked")]
+    assert blocked and all("queued_s" not in e["attrs"] for e in blocked)
+    kids = _children(spans)
+    for adm in blocked:
+        (alloc,) = kids[adm["id"]]
+        assert alloc["name"] == "serving/admit/alloc"
+        assert alloc["attrs"]["blocked"] == 1 and alloc["attrs"]["pages"] == 0
+    done = [e for e in spans if e["name"] == "serving/admit"
+            and "queued_s" in e["attrs"]]
+    assert len(done) == 3
+    # the blocked request waited in the queue for at least one engine step
+    assert max(e["attrs"]["queued_s"] for e in done) > 0
+
+
+def test_admit_time_is_kept_with_everything_off(quiet):
+    eng = _engine(_tiny())
+    reqs = [eng.add_request(p, SamplingParams(max_new_tokens=3))
+            for p in _prompts()]
+    assert all(r.admit_time is None for r in reqs)
+    while eng.has_unfinished:
+        eng.step()
+    for r in reqs:
+        assert r.arrival_time <= r.admit_time <= r.first_token_time \
+            <= r.finish_time
+    # two slots, three requests: the third was admitted after a finish
+    assert reqs[2].admit_time > reqs[0].first_token_time
+    assert obs.spans() == []
+
+
+# ---------------- the train step -------------------------------------------
+def test_train_step_span_and_compile(telemetry):
+    from paddle_tpu.distributed.fleet.utils import make_sharded_train_step
+
+    paddle.seed(0)
+    model = gpt_tiny(dropout=0.0, num_layers=2)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step = make_sharded_train_step(model, opt)
+    x = np.random.RandomState(0).randint(0, 128, size=(4, 16))
+    y = np.roll(x, -1, axis=1)
+    obs.clear_spans()
+    for _ in range(3):
+        float(step(x, y))
+    spans = obs.spans()
+    steps = [e for e in spans if e["name"] == "train/step"]
+    assert [(e["attrs"]["step"], e["attrs"]["first"]) for e in steps] == [
+        (1, 1), (2, 0), (3, 0)]
+    (comp,) = [e for e in spans if e["name"].startswith("compile")]
+    assert comp["attrs"]["site"] == "sharded_train_step"
+    assert comp["parent"] == steps[0]["id"]
+    snap = obs.snapshot()["histograms"]
+    # warm steps feed the documented histogram from the span's own seconds
+    assert snap["train.step.dispatch_seconds"]["count"] == 2
+    assert snap["train.step.dispatch_seconds"]["sum"] == pytest.approx(
+        sum(e["dur"] for e in steps[1:]) * 1e-6)
+    assert snap["train/step.seconds"]["count"] == 3
