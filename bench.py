@@ -745,7 +745,7 @@ def bench_serving():
 
     pc = engine.cache
     ps = pc.page_size
-    itemsize = pc.k.dtype.itemsize
+    itemsize = pc.k[0].dtype.itemsize
     dense_bytes = (pc.num_layers * B * pc.num_kv_heads * cfg.max_seq_len
                    * pc.head_dim * itemsize * 2)
     page_bytes = pc.num_layers * pc.num_kv_heads * ps * pc.head_dim \

@@ -153,7 +153,9 @@ def _serving_specs() -> List[ProgramSpec]:
     # the engine defaults to the block-paged KV layout: prefill scatters
     # the prompt into the slot's pages (page_row replaces the dense slot
     # index) and decode carries the [B, num_blocks] page table as runtime
-    # data — same one-compile + donation contract as the dense layout had
+    # data — same one-compile + donation contract as the dense layout had.
+    # k_pages / v_pages are TUPLES of per-layer pools (one argname, one
+    # donated argnum, num_layers leaves each)
     pre_fn, pre_args = eng.prefill_program(8)
     dec_fn, dec_args = eng.decode_program()
     # speculative verify-k: the decode step widened to [B, k+1] — same

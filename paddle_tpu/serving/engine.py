@@ -43,7 +43,7 @@ from ..observability import memory as _obs_memory
 from ..observability import metrics as _metrics
 from ..observability.tracing import span as _span
 from . import sampling as _sampling
-from .kv_cache import (KVCache, PAGE_SENTINEL, PagedKVCache,
+from .kv_cache import (KVCache, PAGE_SENTINEL, PagedKVCache, paged_write_kv,
                        use_paged_attention_impl)
 from .prefix_cache import PrefixCache
 from .request_trace import RequestTracer, SLOConfig
@@ -51,13 +51,15 @@ from .sampling import SamplingParams
 from .scheduler import FINISHED, PageAllocator, Request, Scheduler
 from .speculative import SpeculativeConfig, accept_greedy, propose_ngram
 
-#: every serving executable takes (params, k_cache, v_cache, ...) and
-#: returns fresh caches its caller rebinds — so the KV cache args are
-#: donated at compile time. Without this each prefill/decode step held TWO
-#: copies of the cache live (input + output), the exact non-donated
-#: hot-loop buffer the analysis donation rule flags (rule donation-missing
-#: on serving_prefill/serving_decode; fixed in the PR that added
-#: paddle_tpu/analysis — see tools/analysis_baseline.json history).
+#: every serving executable takes (params, k_cache, v_cache, ...), where
+#: each cache is a TUPLE of per-layer buffers (kv_cache.KVCache /
+#: PagedKVCache), and returns the tuples of updated buffers its caller
+#: rebinds — so the KV cache args are donated at compile time (an argnum
+#: covers every leaf of its pytree). Layer ``l``'s program output is a
+#: scatter into layer ``l``'s donated parameter, which XLA aliases: the
+#: cache is written where it lies, and no program holds a second copy of
+#: it (the analysis donation rule, donation-missing on
+#: serving_prefill/serving_decode, checks the donation half).
 KV_DONATE_ARGNUMS = (1, 2)
 
 _DUMMY_KEY = None
@@ -108,6 +110,39 @@ def _param_dtype(params: Dict[str, jax.Array]):
     return jnp.float32
 
 
+def _updated(new) -> Tuple[Tuple[jax.Array, ...], Tuple[jax.Array, ...]]:
+    """The ``(k, v)`` buffer tuples a program returns, from the per-layer
+    ``(k, v)`` Tensor pairs ``decode_step`` / ``extend_step`` hand back."""
+    return (tuple(k._value for k, _ in new), tuple(v._value for _, v in new))
+
+
+def _write_prompt(write, kc, vc, kvs):
+    """``write(buffer, new)`` over every layer's K and V: the prompt's
+    per-layer K/V (the ``(k, v)`` Tensor pairs ``prefill_with_cache``
+    returns) into the cache's buffer tuples; returns the updated tuples."""
+    return (tuple(write(c, k._value) for c, (k, _) in zip(kc, kvs)),
+            tuple(write(c, v._value) for c, (_, v) in zip(vc, kvs)))
+
+
+def _write_prompt_dense(kc, vc, kvs, slot):
+    """Each layer's prompt K/V ``[1 or B, Hkv, T, D]`` into that layer's
+    dense buffer at batch row ``slot``, positions ``[0, T)``."""
+    zero = jnp.zeros((), jnp.int32)
+    return _write_prompt(
+        lambda c, new: lax.dynamic_update_slice(
+            c, new.astype(c.dtype), (slot, zero, zero, zero)), kc, vc, kvs)
+
+
+def _write_prompt_paged(kc, vc, kvs, page_row):
+    """Each layer's prompt K/V ``[1, Hkv, T, D]`` into that layer's page
+    pool at positions ``[0, T)``, routed by the slot's table row: one
+    scatter of the bucket's pages per pool (``paged_write_kv``). Blocks
+    past the allocated pages (sentinels) land on the trash page."""
+    table, zero = page_row[None, :], jnp.zeros((1,), jnp.int32)
+    return _write_prompt(
+        lambda c, new: paged_write_kv(c, new, table, zero), kc, vc, kvs)
+
+
 # ---------------------------------------------------------------------------
 # Batch decode loop: the static-shape core GPTForCausalLM.generate rides on.
 # ---------------------------------------------------------------------------
@@ -134,9 +169,9 @@ def cached_generate(model, input_ids, *, max_new_tokens: int = 32,
     S_max = S + max_new_tokens
     params, _ = model.functional_state()
     dt = _param_dtype(params)
-    L, Hkv, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    kc = jnp.zeros((L, B, Hkv, S_max, D), dt)
-    vc = jnp.zeros((L, B, Hkv, S_max, D), dt)
+    cache = KVCache(cfg.num_layers, B, cfg.num_kv_heads, S_max,
+                    cfg.head_dim, dt)
+    kc, vc = cache.k, cache.v  # per-layer buffer tuples
 
     exe_cache = _GEN_EXE_CACHE.setdefault(model, {})
     tok_dtype = idsv.dtype
@@ -145,13 +180,7 @@ def cached_generate(model, input_ids, *, max_new_tokens: int = 32,
         with no_grad():
             (logits, kvs), _ = model.functional_call(
                 p, {}, Tensor(ids), method="prefill_with_cache")
-        knew = jnp.stack([k._value for k, _ in kvs])   # [L, B, Hkv, S, D]
-        vnew = jnp.stack([v._value for _, v in kvs])
-        zero = jnp.zeros((), jnp.int32)
-        kc = lax.dynamic_update_slice(kc, knew.astype(kc.dtype),
-                                      (zero,) * 5)
-        vc = lax.dynamic_update_slice(vc, vnew.astype(vc.dtype),
-                                      (zero,) * 5)
+        kc, vc = _write_prompt_dense(kc, vc, kvs, jnp.zeros((), jnp.int32))
         return logits._value, kc, vc
 
     pkey = ("prefill", B, S, S_max, str(tok_dtype), str(dt))
@@ -160,17 +189,14 @@ def cached_generate(model, input_ids, *, max_new_tokens: int = 32,
                    donate_argnums=KV_DONATE_ARGNUMS)
 
     def decode_fn(p, kc, vc, tokens, positions, key):
-        caches = [(kc[l], vc[l]) for l in range(L)]
         with no_grad():
             (logits, new), _ = model.functional_call(
-                p, {}, Tensor(tokens), caches, Tensor(positions),
-                method="decode_step")
-        kc2 = jnp.stack([k._value for k, _ in new])
-        vc2 = jnp.stack([v._value for _, v in new])
+                p, {}, Tensor(tokens), list(zip(kc, vc)),
+                Tensor(positions), method="decode_step")
         nxt = _sampling.sample_static(
             logits._value, key, do_sample=do_sample,
             temperature=temperature, top_k=top_k)
-        return nxt.astype(tokens.dtype), kc2, vc2
+        return (nxt.astype(tokens.dtype),) + _updated(new)
 
     dkey = ("decode", B, S_max, str(tok_dtype), str(dt),
             do_sample, float(temperature), int(top_k))
@@ -228,7 +254,8 @@ class EngineConfig:
     # KV cache layout: "paged" (default) stores K/V in fixed-size pages
     # routed by a per-slot page table, so HBM scales with LIVE tokens and a
     # smaller ``kv_pages`` pool serves the same (B_max, S_max) envelope;
-    # "dense" keeps the legacy [L, B_max, H_kv, S_max, D] block for A/B.
+    # "dense" keeps the legacy [B_max, H_kv, S_max, D] block per layer for
+    # A/B.
     kv_layout: str = "paged"
     page_size: int = 16          # tokens per KV page (shrunk to divide S_max)
     kv_pages: Optional[int] = None  # pool size; default = full budget + trash
@@ -358,6 +385,9 @@ class Engine:
         if self.config.prefix_cache:
             self.prefix_cache = PrefixCache(self.cache.page_size,
                                             self.page_alloc)
+            # pages can be shared from here on: have the copy-on-write
+            # program compiled now, never between two decode steps
+            self.cache.copy_page_exe()
         self.spec: Optional[SpeculativeConfig] = self.config.speculative
         # cumulative speculation accounting (greedy rows only — sampled
         # rows ignore drafts and always emit 1 token from position 0)
@@ -474,14 +504,14 @@ class Engine:
         compile; callers must rebind from the outputs.
 
         Paged layout: the slot's table row (``page_row [num_blocks]``
-        int32, runtime data) replaces the dense slot index — the prompt's
-        K/V scatter page-by-page into the pools (a static loop over the
-        bucket's blocks; the bucket tail past the allocated pages clamps
-        to the trash page, exactly like bucket padding wrote garbage past
-        ``length`` in the dense layout)."""
+        int32, runtime data) replaces the dense slot index — each layer's
+        prompt K/V lands in that layer's pools as one scatter of the
+        bucket's pages (``_write_prompt_paged``; the bucket tail past the
+        allocated pages clamps to the trash page, exactly like bucket
+        padding wrote garbage past ``length`` in the dense layout)."""
         model = self.model
         if self.config.kv_layout == "paged":
-            ps, nb = self.cache.page_size, self.cache.num_blocks
+            nb = self.cache.num_blocks
 
             @jax.named_scope("serving/prefill")
             def paged_prefill_fn(p, kc, vc, ids, page_row, length):
@@ -489,20 +519,8 @@ class Engine:
                     (logits, kvs), _ = model.functional_call(
                         p, {}, Tensor(ids), method="prefill_with_cache",
                         lengths=Tensor(length[None]))
-                knew = jnp.stack([k._value for k, _ in kvs])  # [L,1,Hkv,T,D]
-                vnew = jnp.stack([v._value for _, v in kvs])
-                zero = jnp.zeros((), jnp.int32)
-                for j in range((T + ps - 1) // ps):
-                    w = min(ps, T - j * ps)  # last bucket block may be partial
-                    pid = jnp.maximum(page_row[j], 0)
-                    start = (zero, pid, zero, zero, zero)
-                    kc = lax.dynamic_update_slice(
-                        kc, knew[:, 0, :, j * ps:j * ps + w, :][:, None]
-                        .astype(kc.dtype), start)
-                    vc = lax.dynamic_update_slice(
-                        vc, vnew[:, 0, :, j * ps:j * ps + w, :][:, None]
-                        .astype(vc.dtype), start)
-                return logits._value, kc, vc
+                return (logits._value,) + _write_prompt_paged(
+                    kc, vc, kvs, page_row)
 
             args = (self.params, self.cache.k, self.cache.v,
                     jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
@@ -515,13 +533,7 @@ class Engine:
                 (logits, kvs), _ = model.functional_call(
                     p, {}, Tensor(ids), method="prefill_with_cache",
                     lengths=Tensor(length[None]))
-            knew = jnp.stack([k._value for k, _ in kvs])  # [L, 1, Hkv, T, D]
-            vnew = jnp.stack([v._value for _, v in kvs])
-            zero = jnp.zeros((), jnp.int32)
-            start = (zero, slot, zero, zero, zero)
-            kc = lax.dynamic_update_slice(kc, knew.astype(kc.dtype), start)
-            vc = lax.dynamic_update_slice(vc, vnew.astype(vc.dtype), start)
-            return logits._value, kc, vc
+            return (logits._value,) + _write_prompt_dense(kc, vc, kvs, slot)
 
         args = (self.params, self.cache.k, self.cache.v,
                 jnp.zeros((1, T), jnp.int32), jnp.int32(0), jnp.int32(1))
@@ -536,23 +548,21 @@ class Engine:
         shape never does — the decode executable stays ONE compile for the
         engine lifetime (tests pin the compile counter), and the paged
         attend gathers each slot's live pages out of the pools."""
-        model, L = self.model, self.cache.num_layers
+        model, cache = self.model, self.cache
         if self.config.kv_layout == "paged":
             B, nb = self.config.max_batch_size, self.cache.num_blocks
 
             @jax.named_scope("serving/decode")
             def paged_decode_fn(p, kc, vc, page_table, tokens, positions,
                                 temps, top_ks, greedy, key):
-                caches = [(kc[l], vc[l], page_table) for l in range(L)]
                 with no_grad():
                     (logits, new), _ = model.functional_call(
-                        p, {}, Tensor(tokens), caches, Tensor(positions),
-                        method="decode_step")
-                kc2 = jnp.stack([k._value for k, _ in new])
-                vc2 = jnp.stack([v._value for _, v in new])
+                        p, {}, Tensor(tokens),
+                        cache.layer_caches(kc, vc, page_table),
+                        Tensor(positions), method="decode_step")
                 nxt = _sampling.sample_batched(logits._value, key, temps,
                                                top_ks, greedy)
-                return nxt.astype(jnp.int32), kc2, vc2
+                return (nxt.astype(jnp.int32),) + _updated(new)
 
             args = (self.params, self.cache.k, self.cache.v,
                     jnp.zeros((B, nb), jnp.int32),
@@ -564,16 +574,13 @@ class Engine:
         @jax.named_scope("serving/decode")
         def decode_fn(p, kc, vc, tokens, positions, temps, top_ks, greedy,
                       key):
-            caches = [(kc[l], vc[l]) for l in range(L)]
             with no_grad():
                 (logits, new), _ = model.functional_call(
-                    p, {}, Tensor(tokens), caches, Tensor(positions),
-                    method="decode_step")
-            kc2 = jnp.stack([k._value for k, _ in new])
-            vc2 = jnp.stack([v._value for _, v in new])
+                    p, {}, Tensor(tokens), cache.layer_caches(kc, vc),
+                    Tensor(positions), method="decode_step")
             nxt = _sampling.sample_batched(logits._value, key, temps,
                                            top_ks, greedy)
-            return nxt.astype(jnp.int32), kc2, vc2
+            return (nxt.astype(jnp.int32),) + _updated(new)
 
         B = self.config.max_batch_size
         args = (self.params, self.cache.k, self.cache.v,
@@ -594,22 +601,20 @@ class Engine:
         Paged layout only."""
         if self.config.kv_layout != "paged":
             raise ValueError("extend_program requires kv_layout='paged'")
-        model, L = self.model, self.cache.num_layers
+        model, cache = self.model, self.cache
         nb = self.cache.num_blocks
 
         @jax.named_scope("serving/extend")
         def extend_fn(p, kc, vc, ids, page_row, start, length):
-            caches = [(kc[l], vc[l], page_row[None, :]) for l in range(L)]
             with no_grad():
                 (logits, new), _ = model.functional_call(
-                    p, {}, Tensor(ids), caches, Tensor(start[None]),
-                    method="extend_step")
-            kc2 = jnp.stack([k._value for k, _ in new])
-            vc2 = jnp.stack([v._value for _, v in new])
+                    p, {}, Tensor(ids),
+                    cache.layer_caches(kc, vc, page_row[None, :]),
+                    Tensor(start[None]), method="extend_step")
             lv = logits._value  # [1, T, V]
             idx = jnp.clip(length - 1, 0, T - 1)
             last = lax.dynamic_index_in_dim(lv[0], idx, keepdims=False)
-            return last[None], kc2, vc2  # [1, V], like prefill
+            return (last[None],) + _updated(new)  # [1, V], like prefill
 
         args = (self.params, self.cache.k, self.cache.v,
                 jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
@@ -639,24 +644,22 @@ class Engine:
                 raise ValueError("verify_program(k=None) needs "
                                  "EngineConfig(speculative=...)")
             k = self.spec.k
-        model, L = self.model, self.cache.num_layers
+        model, cache = self.model, self.cache
         B, nb = self.config.max_batch_size, self.cache.num_blocks
 
         @jax.named_scope("serving/verify")
         def verify_fn(p, kc, vc, page_table, tokens, positions, temps,
                       top_ks, greedy, key):
-            caches = [(kc[l], vc[l], page_table) for l in range(L)]
             with no_grad():
                 (logits, new), _ = model.functional_call(
-                    p, {}, Tensor(tokens), caches, Tensor(positions),
-                    method="extend_step")
-            kc2 = jnp.stack([kl._value for kl, _ in new])
-            vc2 = jnp.stack([vl._value for _, vl in new])
+                    p, {}, Tensor(tokens),
+                    cache.layer_caches(kc, vc, page_table),
+                    Tensor(positions), method="extend_step")
             lv = logits._value  # [B, k+1, V]
             targets = jnp.argmax(lv, axis=-1).astype(jnp.int32)
             sampled0 = _sampling.sample_batched(lv[:, 0], key, temps,
                                                 top_ks, greedy)
-            return targets, sampled0.astype(jnp.int32), kc2, vc2
+            return (targets, sampled0.astype(jnp.int32)) + _updated(new)
 
         args = (self.params, self.cache.k, self.cache.v,
                 jnp.zeros((B, nb), jnp.int32),

@@ -54,8 +54,16 @@ def write_kv(cache, new, positions):
     if positions.ndim == 0:
         zero = jnp.zeros((), positions.dtype)
         return lax.dynamic_update_slice(cache, new, (zero, zero, positions, zero))
-    B = cache.shape[0]
-    return cache.at[jnp.arange(B), :, positions, :].set(new[:, :, 0, :])
+    # one row per (slot, head), indexed on the two LEADING dimensions of the
+    # [B*H_kv, S_max, D] view: the form XLA scatters into a donated cache
+    # where it lies. Indexed on (slot, position) of the 4-D cache, around
+    # the head dimension, XLA transposes the whole cache to put the indexed
+    # dimensions first, and back, every step.
+    B, Hkv, S, D = cache.shape
+    flat = cache.reshape(B * Hkv, S, D)
+    flat = flat.at[jnp.arange(B * Hkv), jnp.repeat(positions, Hkv), :].set(
+        new[:, :, 0, :].reshape(B * Hkv, D))
+    return flat.reshape(B, Hkv, S, D)
 
 
 def _expand_kv_heads(t, rep: int):
@@ -98,15 +106,30 @@ def decode_attend(q, k_cache, v_cache, positions):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-class KVCache:
-    """Preallocated stacked K/V buffers ``[L, B_max, H_kv, S_max, D]`` plus
-    slot bookkeeping for the continuous-batching scheduler.
+def _layer_buffers(num_layers: int, shape, dtype) -> Tuple[jax.Array, ...]:
+    """One zeroed device buffer per layer. Separate buffers, not one
+    stacked array: a compiled program that takes the tuple donated and
+    returns each layer's updated buffer writes every layer where it lies
+    (XLA aliases a donated parameter to the output it is scattered into),
+    which a slice of a stacked array can never be."""
+    return tuple(jnp.zeros(shape, dtype) for _ in range(num_layers))
 
-    The arrays are plain device buffers handed in and out of the engine's
-    compiled prefill/decode executables (functional updates — the engine
-    reassigns ``.k``/``.v`` after every step). Slot allocation is host-side:
-    a freed slot is immediately reusable because its next prefill overwrites
-    positions ``[0, T)`` before any decode reads them.
+
+def _tuple_nbytes(*pools) -> int:
+    return int(sum(a.size * a.dtype.itemsize for p in pools for a in p))
+
+
+class KVCache:
+    """Preallocated K/V buffers, one ``[B_max, H_kv, S_max, D]`` device
+    buffer per layer (``.k`` / ``.v`` are tuples of ``num_layers`` arrays),
+    plus slot bookkeeping for the continuous-batching scheduler.
+
+    The buffers are handed in and out of the engine's compiled
+    prefill/decode executables, donated: each program returns the tuple of
+    updated buffers and the engine rebinds ``.k``/``.v`` after every step.
+    Slot allocation is host-side: a freed slot is immediately reusable
+    because its next prefill overwrites positions ``[0, T)`` before any
+    decode reads them.
     """
 
     def __init__(self, num_layers: int, max_batch_size: int,
@@ -117,14 +140,14 @@ class KVCache:
         self.num_kv_heads = num_kv_heads
         self.max_seq_len = max_seq_len
         self.head_dim = head_dim
-        shape = (num_layers, max_batch_size, num_kv_heads, max_seq_len, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        shape = (max_batch_size, num_kv_heads, max_seq_len, head_dim)
+        self.k = _layer_buffers(num_layers, shape, dtype)
+        self.v = _layer_buffers(num_layers, shape, dtype)
         self._free: List[int] = list(range(max_batch_size))[::-1]
 
     @property
     def nbytes(self) -> int:
-        return int(self.k.size * self.k.dtype.itemsize * 2)
+        return _tuple_nbytes(self.k, self.v)
 
     def alloc_slot(self) -> Optional[int]:
         """Lowest free slot index, or None when the batch is full."""
@@ -143,12 +166,11 @@ class KVCache:
         return self.max_batch_size - len(self._free)
 
     def layer_caches(self, k=None, v=None) -> List[Tuple[jax.Array, jax.Array]]:
-        """Per-layer (k, v) view of the stacked buffers — the pytree shape
-        GPTForCausalLM.decode_step consumes. Static python indexing, so it
-        is free under a trace."""
+        """Per-layer ``(k, v)`` pairs of the buffer tuples — the pytree
+        shape GPTForCausalLM.decode_step consumes."""
         k = self.k if k is None else k
         v = self.v if v is None else v
-        return [(k[l], v[l]) for l in range(self.num_layers)]
+        return list(zip(k, v))
 
 
 # ---------------------------------------------------------------------------
@@ -196,29 +218,44 @@ def use_paged_attention_impl(impl: Optional[str]):
 
 
 def paged_write_kv(pool, new, page_table, positions):
-    """Scatter ``T`` tokens' K (or V) per slot into a ``[P, H_kv, ps, D]``
+    """Write ``T`` tokens' K (or V) per slot into a ``[P, H_kv, ps, D]``
     page pool: token ``t`` of row ``b`` of ``new [B, H_kv, T, D]`` lands in
     page ``page_table[b, (positions[b]+t) // ps]`` at offset
     ``(positions[b]+t) % ps``. ``T`` is static (1 for plain decode, ``k+1``
-    for speculative verify) so the scatters unroll at trace time. Sentinel
-    entries clamp to the trash page (slots without a live request all write
-    identical token-0 state there, so the race is benign), and writes past
-    the table's capacity ``num_blocks * ps`` route to the trash page too —
-    a verify step near the end of a sequence can draft past ``S_max``
-    without going out of bounds; the host caps how many of those tokens it
-    accepts."""
+    for speculative verify, a bucket for suffix prefill).
+
+    The update is made a PAGE at a time: gather the pages the ``T``
+    positions of each row can touch, lay the new rows into them, scatter
+    whole pages back — one gather and one scatter whatever ``T`` is, both
+    indexed on the pool's leading dimension only. That is the form XLA
+    applies in place to a donated pool in the layout the pool is stored in
+    (a scatter indexed on page AND offset makes the TPU compiler transpose
+    the whole pool to a layout of its own and back, every step).
+
+    Sentinel entries clamp to the trash page (slots without a live request
+    all write identical token-0 state there, so the race is benign), and
+    writes past the table's capacity ``num_blocks * ps`` route to the trash
+    page too — a verify step near the end of a sequence can draft past
+    ``S_max`` without going out of bounds; the host caps how many of those
+    tokens it accepts. A touched page in which no token lands is written
+    back as it was read."""
     ps = pool.shape[2]
     nb = page_table.shape[1]
     pos = jnp.asarray(positions)
-    B, T = new.shape[0], new.shape[2]
+    T = new.shape[2]
     new = new.astype(pool.dtype)
-    for t in range(T):
-        p = pos + t
-        block = jnp.minimum(p // ps, nb - 1)
-        pages = jnp.maximum(page_table[jnp.arange(B), block], 0)
-        pages = jnp.where(p < nb * ps, pages, 0)
-        pool = pool.at[pages, :, p % ps, :].set(new[:, :, t, :])
-    return pool
+    nblk = (T + ps - 2) // ps + 1  # pages T consecutive positions can span
+    block = (pos // ps)[:, None] + jnp.arange(nblk)            # [B, nblk]
+    pages = jnp.take_along_axis(page_table, jnp.minimum(block, nb - 1),
+                                axis=1)
+    pages = jnp.where(block < nb, jnp.maximum(pages, 0), 0)
+    # which token, if any, lands in offset s of touched block j of row b
+    t = block[:, :, None] * ps + jnp.arange(ps) - pos[:, None, None]
+    rows = jnp.take_along_axis(                       # [B, nblk, H_kv, ps, D]
+        new[:, None], jnp.clip(t, 0, T - 1)[:, :, None, :, None], axis=3)
+    lands = ((t >= 0) & (t < T))[:, :, None, :, None]
+    merged = jnp.where(lands, rows, pool[pages])
+    return pool.at[pages].set(merged)
 
 
 def paged_gather(pool, page_table):
@@ -290,16 +327,19 @@ def paged_extend_attend(q, k_pool, v_pool, page_table, positions,
 
 
 class PagedKVCache:
-    """Block-paged K/V pools ``[L, num_pages, H_kv, page_size, D]`` plus the
-    per-slot page table and the same slot bookkeeping as ``KVCache``.
+    """Block-paged K/V pools, one ``[num_pages, H_kv, page_size, D]`` device
+    buffer per layer (``.k`` / ``.v`` are tuples of ``num_layers`` arrays),
+    plus the per-slot page table and the same slot bookkeeping as
+    ``KVCache``.
 
-    The pools are functional device buffers exactly like the dense cache's
-    (the engine rebinds ``.k``/``.v`` after every compiled step, donation
-    included). The page table is HOST state (numpy): the scheduler's
-    allocator mutates it between steps and the engine ships a snapshot
-    (``table_device()``) into each executable as runtime data — table
-    CONTENTS change every admission/finish, but its ``[B_max, num_blocks]``
-    int32 shape never does, which is what keeps decode at one compile.
+    The pools are donated device buffers exactly like the dense cache's
+    (the engine rebinds ``.k``/``.v`` to the tuples each compiled step
+    returns; every layer's pool is updated where it lies). The page table
+    is HOST state (numpy): the scheduler's allocator mutates it between
+    steps and the engine ships a snapshot (``table_device()``) into each
+    executable as runtime data — table CONTENTS change every
+    admission/finish, but its ``[B_max, num_blocks]`` int32 shape never
+    does, which is what keeps decode at one compile.
 
     Page 0 is reserved as the trash page (see ``PAGE_SENTINEL``); a
     default-sized pool therefore holds ``B_max * S_max/page_size + 1``
@@ -328,16 +368,17 @@ class PagedKVCache:
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (trash page + 1)")
         self.num_pages = num_pages
-        shape = (num_layers, num_pages, num_kv_heads, page_size, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        shape = (num_pages, num_kv_heads, page_size, head_dim)
+        self.k = _layer_buffers(num_layers, shape, dtype)
+        self.v = _layer_buffers(num_layers, shape, dtype)
         self.page_table = np.full((max_batch_size, self.num_blocks),
                                   PAGE_SENTINEL, np.int32)
         self._free: List[int] = list(range(max_batch_size))[::-1]
+        self._copy_exe = None
 
     @property
     def nbytes(self) -> int:
-        return int(self.k.size * self.k.dtype.itemsize * 2)
+        return _tuple_nbytes(self.k, self.v)
 
     def table_device(self) -> jax.Array:
         """Snapshot the host page table as the device operand the compiled
@@ -350,14 +391,35 @@ class PagedKVCache:
         for j, p in enumerate(pages):
             self.page_table[slot, start_block + j] = p
 
+    def copy_page_exe(self):
+        """The compiled copy-on-write program: ``(k, v, src, dst) -> (k,
+        v)`` over the donated pool tuples, page ids as runtime scalars, so
+        ONE executable serves every copy and each layer's page moves inside
+        its own buffer. Compiled on first use; a caller that must not
+        compile later (the engine, when pages can be shared) asks for it up
+        front."""
+        if self._copy_exe is None:
+            def copy_page_fn(kc, vc, src, dst):
+                def one(pool):
+                    zero = jnp.zeros((), jnp.int32)
+                    page = lax.dynamic_slice(
+                        pool, (src, zero, zero, zero), (1,) + pool.shape[1:])
+                    return lax.dynamic_update_slice(
+                        pool, page, (dst, zero, zero, zero))
+                return tuple(map(one, kc)), tuple(map(one, vc))
+
+            self._copy_exe = jax.jit(copy_page_fn, donate_argnums=(0, 1)) \
+                .lower(self.k, self.v, jnp.int32(0), jnp.int32(0)).compile()
+        return self._copy_exe
+
     def copy_page(self, src: int, dst: int):
         """Copy-on-write: duplicate page ``src``'s bytes into page ``dst``
-        across every layer of both pools (one sliced device update per
-        pool). The caller then repoints its table entry at ``dst`` and
-        drops its reference on ``src`` — the sharer still mapping ``src``
-        never observes the write that motivated the copy."""
-        self.k = self.k.at[:, dst].set(self.k[:, src])
-        self.v = self.v.at[:, dst].set(self.v[:, src])
+        in every layer of both pools. The caller then repoints its table
+        entry at ``dst`` and drops its reference on ``src`` — the sharer
+        still mapping ``src`` never observes the write that motivated the
+        copy."""
+        self.k, self.v = self.copy_page_exe()(
+            self.k, self.v, jnp.int32(src), jnp.int32(dst))
 
     def slot_pages(self, slot: int) -> List[int]:
         row = self.page_table[slot]
@@ -387,10 +449,10 @@ class PagedKVCache:
         return self.max_batch_size - len(self._free)
 
     def layer_caches(self, k=None, v=None, table=None):
-        """Per-layer ``(k_pool, v_pool, page_table)`` triples — the pytree
-        shape the paged ``decode_step`` consumes (the table is shared by
-        every layer; static indexing, free under a trace)."""
+        """Per-layer ``(k_pool, v_pool, page_table)`` triples of the pool
+        tuples — the pytree shape the paged ``decode_step`` consumes (the
+        table is shared by every layer)."""
         k = self.k if k is None else k
         v = self.v if v is None else v
         table = self.table_device() if table is None else table
-        return [(k[l], v[l], table) for l in range(self.num_layers)]
+        return [(kl, vl, table) for kl, vl in zip(k, v)]

@@ -133,6 +133,38 @@ class TestPagedPrimitives:
         # row 2 (empty slot) really did clamp to the trash page at offset 0
         assert np.allclose(got[2, :, 0, :], want[2, :, 0, :])
 
+    @pytest.mark.parametrize("T", [1, 3, 6])
+    def test_paged_write_multi_token_matches_per_token_loop(self, T):
+        """The page-at-a-time write lands every token where the per-token
+        scatter (one ``pool[page, :, offset] = row`` per token: the
+        reference, kept here) puts it — an unaligned start that crosses a
+        page boundary and an empty slot included — and leaves every other
+        live page as it was. Tokens past the row's pages (a sentinel) or
+        past the table's capacity go to the trash page."""
+        kp, _, tbl, _, _ = self._pool_and_dense()
+        B, nb = tbl.shape
+        Hkv, ps, D = kp.shape[1:]
+        new = jnp.asarray(np.random.RandomState(3).randn(B, Hkv, T, D)
+                          .astype(np.float32))
+
+        def per_token(pos):
+            want = np.asarray(kp).copy()
+            for b in range(B):
+                for t in range(T):
+                    p = pos[b] + t
+                    page = max(int(tbl[b, min(p // ps, nb - 1)]), 0)
+                    if p >= nb * ps:
+                        page = 0
+                    want[page, :, p % ps, :] = np.asarray(new)[b, :, t, :]
+            return want
+
+        for pos in ([3, 1, 0], [9, 2, 0]):  # row 0: pages 1->2, then past
+            got = kvc.paged_write_kv(kp, new, tbl,
+                                     jnp.asarray(pos, jnp.int32))
+            # page 0 is the trash page: several rows race there, by design
+            np.testing.assert_array_equal(np.asarray(got)[1:],
+                                          per_token(pos)[1:])
+
     @pytest.mark.parametrize("rep", [1, 2])
     def test_kernel_matches_oracle_ragged_gqa_empty(self, rep):
         """interpret-mode Pallas kernel vs the gather+einsum oracle on the
@@ -158,6 +190,119 @@ class TestPagedPrimitives:
         assert kvc.default_paged_impl() in ("oracle", "pallas")
         with pytest.raises(ValueError):
             kvc.use_paged_attention_impl("nope").__enter__()
+
+
+# ---------------- the programs update the pools in place ------------------
+def _program(eng, name):
+    return {"decode": eng.decode_program,
+            "verify": lambda: eng.verify_program(k=2),
+            "prefill": lambda: eng.prefill_program(16),
+            "extend": lambda: eng.extend_program(16)}[name]()
+
+
+class TestPoolsUpdatedInPlace:
+    """The KV cache is a tuple of per-layer donated buffers (ISSUE 25): every
+    serving program writes each layer where it lies and holds no second
+    copy — of one layer, let alone of the stack the parent rebuilt."""
+
+    @pytest.mark.parametrize("layout,name", [
+        ("paged", "decode"), ("paged", "prefill"), ("paged", "extend"),
+        ("paged", "verify"), ("dense", "decode"), ("dense", "prefill")])
+    def test_every_pool_leaf_aliased_and_no_pool_sized_temp(self, layout,
+                                                            name):
+        import re
+
+        import jax
+        from paddle_tpu.serving.engine import KV_DONATE_ARGNUMS
+
+        # pools far larger than anything else the tiny model holds, so a
+        # temporary the size of one layer's pool cannot hide
+        size = ({"kv_pages": 2048, "page_size": 8} if layout == "paged"
+                else {"max_batch_size": 16})
+        eng = Engine(_tiny(), EngineConfig(**{
+            "max_batch_size": 2, "max_seq_len": 64, "kv_layout": layout,
+            **size}))
+        fn, args = _program(eng, name)
+        exe = jax.jit(fn, donate_argnums=KV_DONATE_ARGNUMS) \
+            .lower(*args).compile()
+        L = eng.cache.num_layers
+        assert len(eng.cache.k) == len(eng.cache.v) == L
+        assert args[1] is eng.cache.k and args[2] is eng.cache.v
+        first = len(jax.tree_util.tree_leaves(args[0]))  # params come first
+        header = exe.as_text().split("\n", 1)[0]
+        aliased = {int(p) for p in re.findall(
+            r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+        assert aliased == set(range(first, first + 2 * L)), header[:400]
+        leaf = eng.cache.k[0]
+        leaf_bytes = leaf.size * leaf.dtype.itemsize
+        ma = exe.memory_analysis()
+        assert ma.alias_size_in_bytes == 2 * L * leaf_bytes
+        assert ma.temp_size_in_bytes < leaf_bytes, (
+            ma.temp_size_in_bytes, leaf_bytes)
+
+    def test_decode_jaxpr_rebuilds_no_stacked_cache(self):
+        """No concatenate / dynamic_update_slice in the decode program
+        puts out an array the size of all layers' pools (the parent's
+        ``jnp.stack`` of the per-layer pools did)."""
+        import jax
+
+        eng = Engine(_tiny(), EngineConfig(max_batch_size=2, max_seq_len=64,
+                                           page_size=8))
+        fn, args = eng.decode_program()
+        stack = sum(k.size for k in eng.cache.k)
+        seen = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                seen.append(eqn.primitive.name)
+                if eqn.primitive.name in ("concatenate",
+                                          "dynamic_update_slice"):
+                    assert all(v.aval.size < stack for v in eqn.outvars), eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        assert "scatter" in seen  # the walk did reach the cache writes
+
+    def test_prefix_hit_cow_and_speculation_match_plain_greedy(self):
+        """Ragged prompts through ONE engine with the prefix cache and
+        speculation on — a cold prompt, a prefix hit (suffix prefill
+        through the extend program) and a request whose shared page is
+        copied on write in mid-run — emit token for token what a plain
+        paged engine and the dense layout emit."""
+        m = _tiny()
+        warm = [int(t) for t in _prompt(1, 20, seed=5)[0]]
+        prompts = [warm, warm[:16] + [7, 9, 11], [3, 1, 4, 1, 5, 9, 2, 6]]
+        sp = SamplingParams(max_new_tokens=10)
+        want = Engine(m, EngineConfig(max_batch_size=2, max_seq_len=64,
+                                      page_size=8)).generate(prompts, sp)
+        dense = Engine(m, EngineConfig(max_batch_size=2, max_seq_len=64,
+                                       kv_layout="dense")).generate(
+            prompts, sp)
+        assert dense == want
+        eng = Engine(m, EngineConfig(max_batch_size=2, max_seq_len=64,
+                                     page_size=8, prefix_cache=True,
+                                     speculative=2))
+        # pages can be shared, so the copy program was compiled with the
+        # engine: a copy on write never compiles between two decode steps
+        copy_exe = eng.cache._copy_exe
+        assert copy_exe is not None
+        got = eng.generate(prompts[:1], sp)
+        reqs = [eng.add_request(p, sp) for p in prompts[1:]]
+        eng.step()                       # both admitted, first tokens out
+        assert reqs[0].prefix_hit_blocks == 2
+        slot = reqs[0].slot
+        shared = int(eng.cache.page_table[slot, 0])
+        assert eng.page_alloc.is_shared(shared)
+        assert eng._ensure_writable(slot, 0,
+                                    owner=f"req{reqs[0].request_id}")
+        assert int(eng.cache.page_table[slot, 0]) != shared
+        while eng.has_unfinished:
+            eng.step()
+        got += [r.output_ids for r in reqs]
+        assert got == want
+        assert eng._cow_copies == 1 and reqs[0].draft_tokens > 0
+        assert eng.cache.copy_page_exe() is copy_exe
 
 
 # ---------------- engine: paged layout ------------------------------------

@@ -49,6 +49,11 @@ def _toks(n, seed=0):
     return [int(t) for t in rng.integers(1, 50, (n,))]
 
 
+def _page(pools, page):
+    """One page's bytes in every layer of a pool tuple: [L, H_kv, ps, D]."""
+    return np.stack([np.asarray(pool[page]) for pool in pools])
+
+
 def _run(eng, prompt, **sp):
     """Queue one request, drain the engine, return the Request."""
     req = eng.add_request(prompt, SamplingParams(**sp))
@@ -230,11 +235,11 @@ class TestCopyOnWrite:
         c = PagedKVCache(2, 1, 1, 16, 4, page_size=8, num_pages=6)
         rng = np.random.default_rng(0)
         src_bytes = rng.normal(size=(2, 1, 8, 4)).astype(np.float32)
-        c.k = c.k.at[:, 3].set(src_bytes)
+        c.k = tuple(k.at[3].set(b) for k, b in zip(c.k, src_bytes))
         c.copy_page(3, 4)
-        np.testing.assert_array_equal(np.asarray(c.k[:, 4]), src_bytes)
-        c.k = c.k.at[:, 4].set(0.0)          # write the copy...
-        np.testing.assert_array_equal(np.asarray(c.k[:, 3]), src_bytes)
+        np.testing.assert_array_equal(_page(c.k, 4), src_bytes)
+        c.k = tuple(k.at[4].set(0.0) for k in c.k)   # write the copy...
+        np.testing.assert_array_equal(_page(c.k, 3), src_bytes)
 
     def test_clear_slot_idempotent(self):
         c = PagedKVCache(1, 2, 1, 16, 4, page_size=8)
@@ -258,12 +263,11 @@ class TestCopyOnWrite:
         slot = req.slot
         shared = int(eng.cache.page_table[slot, 0])
         assert eng.page_alloc.is_shared(shared)
-        before = np.asarray(eng.cache.k[:, shared])
+        before = _page(eng.cache.k, shared)
         assert eng._ensure_writable(slot, 0, owner="cow-test")
         fresh = int(eng.cache.page_table[slot, 0])
         assert fresh != shared
-        np.testing.assert_array_equal(np.asarray(eng.cache.k[:, fresh]),
-                                      before)
+        np.testing.assert_array_equal(_page(eng.cache.k, fresh), before)
         assert eng.page_alloc.refcount(shared) == 1  # trie's ref only
         # unshared pages are left alone
         assert eng._ensure_writable(slot, 0, owner="cow-test")
