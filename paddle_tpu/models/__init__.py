@@ -10,6 +10,7 @@ from .ernie import (  # noqa: F401
     ernie_base,
     ernie_tiny,
 )
+from .decoder import DecoderConfig, DecoderLM  # noqa: F401
 from .gpt import (  # noqa: F401
     GPT3_1p3B, GPT_TINY, GPTConfig, GPTForCausalLM, GPTModel, GPTMoEMLP,
     gpt_moe_tiny, gpt_tiny)

@@ -1,0 +1,561 @@
+"""A decoder-only LM built from a DESCRIPTION of its block.
+
+``DecoderConfig`` names the kind of each part of the block — norm
+(``rms`` | ``layer``), positions (``rope``), attention (``dense`` |
+``indexed_sparse``), FFN (``swiglu`` | ``moe_swiglu``), router
+(``softmax_topk``) — with their widths; the parts are looked up by kind in
+the tables at the bottom of each section, so the next architecture is a
+description (and at most a new entry in one table), not a third class tree
+beside ``models/gpt.py``. GPT-3's block (learned position table, LayerNorm
+with biases, GELU, fused qkv) stays where it is; its programs do not pass
+through here.
+
+The block, for ``x`` the residual stream::
+
+    h = norm(x);  q, k, v = h Wq, h Wk, h Wv        (no biases; GQA)
+    q, k = per-head RMSNorm (qk_norm), then rotary positions
+    indexed_sparse: I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]),  s <= t
+                    S_t = the index_topk positions of largest I[t, :]
+    a_t = softmax over s in S_t (s <= t for dense) of q_t . k_s / sqrt(D)
+    x += a Wo
+    g = norm(x);  moe_swiglu: p = softmax_f32(g Wr), top-k experts,
+                  renormalised; x += sum_e p_e (silu(g W1_e) * g W3_e) W2_e
+
+It speaks the serving engine's whole protocol (serving/README.md):
+``cache_pools()`` declares the per-layer pools (K and V token-major, one
+"head" of ``H_kv * D``, so that a token's K is one run of bytes for the
+sparse read; the indexer's keys, in whole 128-lane rows), ``prefill_with_cache`` / ``extend_step`` /
+``decode_step`` are pure functions of (parameters, pools, page table).
+ONE attention routine (``attend``) serves all three: queries at
+``start .. start + T - 1`` against views of the pools, in chunks of queries
+so that no ``[T, L]`` float32 array larger than a chunk exists; prefill is
+the case ``start = 0`` on the keys just computed, decode the case ``T = 1``
+(on the TPU its read of the selected rows is the Pallas kernel
+``kernels/sparse_attention.sparse_paged_decode``). The expert layer is
+drop-free (``kernels/grouped_matmul``): rows sorted by expert, a grouped
+matmul over the sorted rows, routing weights applied in float32 at the
+combine.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from ..core.tensor import Parameter, Tensor
+from ..nn.layer.layers import Layer
+
+_NEG_INF = -1e30
+
+
+@dataclass
+class DecoderConfig:
+    vocab_size: int = 256
+    hidden_size: int = 64
+    num_layers: int = 2
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 16            # its own width, not hidden / heads
+    max_context: int = 128        # longest sequence the positions serve
+    norm: str = "rms"             # rms | layer
+    norm_eps: float = 1e-6
+    position: str = "rope"
+    rope_theta: float = 1e7
+    qk_norm: bool = True          # RMSNorm over each q and k head
+    attention: str = "indexed_sparse"   # dense | indexed_sparse
+    index_heads: int = 4
+    index_head_dim: int = 8
+    index_topk: int = 16
+    ffn: str = "moe_swiglu"       # swiglu | moe_swiglu
+    intermediate_size: int = 128  # swiglu's width; an expert's in moe_swiglu
+    router: str = "softmax_topk"
+    num_experts: int = 8
+    experts_per_token: int = 2
+    norm_topk_prob: bool = True
+    tie_word_embeddings: bool = False
+    initializer_range: float = 0.02
+    dtype: str = "float32"
+    # "normal": N(0, initializer_range), norm scales 1. "zeros": nothing is
+    # drawn (a caller that installs its own weights, at a size whose float32
+    # initial values would not fit beside them).
+    init: str = "normal"
+    query_chunk: int = 128        # queries per chunk of ``attend``
+
+    def __post_init__(self):
+        for field, table in (("norm", NORMS), ("position", POSITIONS),
+                             ("attention", ATTENTIONS), ("ffn", FFNS),
+                             ("router", ROUTERS)):
+            if getattr(self, field) not in table:
+                raise ValueError(f"{field} {getattr(self, field)!r}; "
+                                 f"want one of {sorted(table)}")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("num_heads must be a multiple of num_kv_heads")
+
+
+# ------------------------------------------------------------------ norms
+
+def rms_norm(x, p, pre, eps):
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * p[pre + ".weight"].astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x, p, pre, eps):
+    xf = x.astype(jnp.float32)
+    mu = xf.mean(-1, keepdims=True)
+    y = (xf - mu) * lax.rsqrt(((xf - mu) ** 2).mean(-1, keepdims=True) + eps)
+    y = y * p[pre + ".weight"].astype(jnp.float32)
+    if pre + ".bias" in p:
+        y = y + p[pre + ".bias"].astype(jnp.float32)
+    return y.astype(x.dtype)
+
+
+NORMS = {"rms": (rms_norm, False), "layer": (layer_norm, True)}  # fn, bias
+
+
+def _norm_shapes(cfg, pre, width):
+    out = {pre + ".weight": (width,)}
+    if NORMS[cfg.norm][1]:
+        out[pre + ".bias"] = (width,)
+    return out
+
+
+def _norm(cfg, x, p, pre):
+    return NORMS[cfg.norm][0](x, p, pre, cfg.norm_eps)
+
+
+# -------------------------------------------------------------- positions
+
+def rope(x, pos, theta):
+    """Rotary positions on ``x [B, T, heads, D]`` at ``pos [B, T]``: the
+    half-split form (pair i is (x[i], x[i + D/2])), angles in float32."""
+    D = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) * 2.0 / D)
+    ang = pos.astype(jnp.float32)[:, :, None, None] * inv     # [B,T,1,D/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :D // 2].astype(jnp.float32), x[..., D // 2:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+POSITIONS = {"rope": rope}
+
+
+# -------------------------------------------------------------- attention
+
+def _mm(x, w):
+    # in the operands' type: the matrix unit accumulates a bfloat16 product
+    # in float32 either way, and an explicit float32 result would stand in
+    # memory whole (0.57 GB for a 34k-token prompt's q) before its cast
+    return jnp.dot(x, w)
+
+
+def _attn_shapes(cfg, pre):
+    H, D = cfg.hidden_size, cfg.head_dim
+    s = {pre + ".wq": (H, cfg.num_heads * D),
+         pre + ".wk": (H, cfg.num_kv_heads * D),
+         pre + ".wv": (H, cfg.num_kv_heads * D),
+         pre + ".wo": (cfg.num_heads * D, H)}
+    if cfg.qk_norm:
+        s[pre + ".q_norm.weight"] = (D,)
+        s[pre + ".k_norm.weight"] = (D,)
+    if cfg.attention == "indexed_sparse":
+        Hi, Di = cfg.index_heads, cfg.index_head_dim
+        s.update({pre + ".index.wq": (H, Hi * Di),
+                  pre + ".index.wk": (H, Di),
+                  pre + ".index.ww": (H, Hi),
+                  pre + ".index.k_norm.weight": (Di,),
+                  pre + ".index.k_norm.bias": (Di,)})
+    return s
+
+
+def attend(cfg, q, k_view, v_view, qpos, index=None):
+    """Queries ``q [B, T, Hq, D]`` at positions ``qpos [B, T]`` against key
+    and value views ``[B, L, Hkv, D]`` (view position = sequence position),
+    ``[B, T, Hq, D]`` out. ``index`` (indexed_sparse) is ``(qi [B, T, Hi,
+    Di], w [B, T, Hi] float32, ki_view [B, L, Di])``: a query attends to the
+    ``index_topk`` positions ``s <= qpos`` of largest indexer score; without
+    it, to every ``s <= qpos``. Computed in chunks of ``cfg.query_chunk``
+    queries. Numerics as ``serving.kv_cache.extend_attend``: q pre-scaled in
+    its own dtype, float32 scores, -1e30 mask, float32 softmax."""
+    from ..kernels.sparse_attention import topk_mask
+
+    B, T, Hq, D = q.shape
+    L, Hkv = k_view.shape[1], k_view.shape[2]
+    rep = Hq // Hkv
+    kpos = jnp.arange(L, dtype=jnp.int32)
+    qs = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+
+    def chunk(args):
+        qc, pc, ic = args           # [B, C, Hq, D], [B, C], index
+        C = qc.shape[1]
+        valid = kpos[None, None, :] <= pc[:, :, None]             # [B, C, L]
+        if index is not None:
+            qi, w = ic
+            s = jnp.einsum("bthd,bld->bthl", qi, index[2],
+                           preferred_element_type=jnp.float32)
+            score = jnp.sum(jnp.maximum(s, 0.0) * w[..., None], axis=2)
+            valid = topk_mask(score, valid, cfg.index_topk)
+        # a KV group's ``rep`` query heads stand side by side as rows of one
+        # matmul against the group's keys (K and V are read at their stored
+        # width); (rep, C) stay the two minor row dims, so nothing is padded
+        qg = qc.reshape(B, C, Hkv, rep, D).transpose(0, 2, 3, 1, 4) \
+            .reshape(B, Hkv, rep * C, D)
+        s = jnp.einsum("bgqd,blgd->bgql", qg, k_view,
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(valid[:, None, None], s.reshape(B, Hkv, rep, C, L),
+                      _NEG_INF)
+        probs = jax.nn.softmax(s, axis=-1).astype(v_view.dtype)
+        o = jnp.einsum("bgql,blgd->bgqd", probs.reshape(B, Hkv, rep * C, L),
+                       v_view)
+        return o.reshape(B, Hkv, rep, C, D).transpose(0, 3, 1, 2, 4) \
+            .reshape(B, C, Hq, D)
+
+    C = cfg.query_chunk if T % cfg.query_chunk == 0 else T
+    idx = None if index is None else (index[0], index[1])
+    if C == T:
+        return chunk((qs, qpos, idx))
+
+    # chunk by chunk, sliced out of (and written back into) the whole
+    # arrays where they lie: stacked per-chunk copies of q and of the output
+    # would each be another 0.3 GB at a 34k-token prompt
+    def body(i, out):
+        cut = lambda a: lax.dynamic_slice_in_dim(a, i * C, C, axis=1)
+        o = chunk((cut(qs), cut(qpos),
+                   None if idx is None else tuple(map(cut, idx))))
+        return lax.dynamic_update_slice_in_dim(out, o, i * C, axis=1)
+
+    return lax.fori_loop(0, T // C, body, jnp.zeros((B, T, Hq, D), q.dtype))
+
+
+def index_pool_width(cfg) -> int:
+    """Lanes a token's indexer key takes in its pool: ``index_head_dim``
+    rounded up to whole 128-lane rows. At 64 wide the chip keeps a
+    ``[pages, 1, 16, 64]`` pool pages-minor at rest (no half-empty lanes)
+    and every program that touches it re-lays the WHOLE pool in and out,
+    every layer, every step (on the chip: ``copy_bf16_19201_1_16_64_``,
+    0.95 s of a 20 s window, PERF.md section 6, PR 26); a 128-lane row is
+    stored as declared and costs 64 idle lanes a token."""
+    return -(-cfg.index_head_dim // 128) * 128
+
+
+def _indexer(cfg, p, pre, h, pos):
+    """(qi [B, T, Hi, Di], w [B, T, Hi] float32, ki [B, T, Di]) of the
+    normed hidden state ``h``: LayerNorm on the shared key, rotary positions
+    on both, the scales ``Di^-0.5`` (of the dot) and ``Hi^-0.5`` folded into
+    the head weights."""
+    B, T, _ = h.shape
+    Hi, Di = cfg.index_heads, cfg.index_head_dim
+    qi = _mm(h, p[pre + ".wq"]).reshape(B, T, Hi, Di)
+    ki = layer_norm(_mm(h, p[pre + ".wk"]), p, pre + ".k_norm", cfg.norm_eps)
+    qi = rope(qi, pos, cfg.rope_theta)
+    ki = rope(ki[:, :, None, :], pos, cfg.rope_theta)[:, :, 0]
+    w = jnp.dot(h, p[pre + ".ww"], preferred_element_type=jnp.float32)
+    return qi, w * jnp.float32(Di ** -0.5 * Hi ** -0.5), ki
+
+
+def attention(cfg, p, pre, h, start, cache=None, flash_ok=False):
+    """The attention part of a block over normed ``h [B, T, hidden]`` whose
+    tokens sit at ``start[b] .. start[b] + T - 1``. Without ``cache``
+    (prefill) the keys are the ones just computed; with ``cache`` (the
+    layer's pools and the page table) they are written into the pools first
+    and read back through the table. Returns (out [B, T, hidden], new):
+    ``new`` the per-pool entries, ``[B, 1, T, width]`` each without a cache,
+    the updated pools with one."""
+    from ..serving import kv_cache as _kvc
+
+    B, T, _ = h.shape
+    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    q = _mm(h, p[pre + ".wq"]).reshape(B, T, Hq, D)
+    k = _mm(h, p[pre + ".wk"]).reshape(B, T, Hkv, D)
+    v = _mm(h, p[pre + ".wv"]).reshape(B, T, Hkv, D)
+    if cfg.qk_norm:
+        q = rms_norm(q, p, pre + ".q_norm", cfg.norm_eps)
+        k = rms_norm(k, p, pre + ".k_norm", cfg.norm_eps)
+    turn = POSITIONS[cfg.position]
+    q, k = turn(q, pos, cfg.rope_theta), turn(k, pos, cfg.rope_theta)
+    sparse = cfg.attention == "indexed_sparse"
+    index = _indexer(cfg, p, pre + ".index", h, pos) if sparse else None
+    fresh = [k.reshape(B, 1, T, Hkv * D), v.reshape(B, 1, T, Hkv * D)]
+    Di = cfg.index_head_dim
+    if sparse:
+        fresh.append(jnp.pad(index[2][:, None], (
+            (0, 0), (0, 0), (0, 0), (0, index_pool_width(cfg) - Di))))
+
+    if cache is None:
+        if flash_ok and (not sparse or T <= cfg.index_topk):
+            # every position is selected: plain causal attention, through
+            # the seam the flash kernel sits behind
+            from ..nn import functional as F
+
+            expand = lambda t: jnp.broadcast_to(
+                t[:, :, :, None], (B, T, Hkv, Hq // Hkv, D)).reshape(B, T, Hq, D)
+            o = F.scaled_dot_product_attention(
+                Tensor(q), Tensor(expand(k)), Tensor(expand(v)),
+                is_causal=True, training=False)._value
+        else:
+            o = attend(cfg, q, k, v, pos, index)
+        return _mm(o.reshape(B, T, Hq * D), p[pre + ".wo"]), tuple(fresh)
+
+    *pools, table = cache
+    pools = [_kvc.paged_write_kv(pool, new, table, start)
+             for pool, new in zip(pools, fresh)]
+    L = table.shape[1] * pools[0].shape[2]
+    if sparse and T == 1 and _kvc.default_paged_impl() == "pallas":
+        from ..kernels.sparse_attention import (sparse_paged_decode,
+                                                topk_indices)
+
+        qi, w, _ = index
+        ki_view = _kvc.paged_gather(pools[2], table)[:, 0, :, :Di]  # [B,L,Di]
+        s = jnp.einsum("bhd,bld->bhl", qi[:, 0], ki_view,
+                       preferred_element_type=jnp.float32)
+        score = jnp.sum(jnp.maximum(s, 0.0) * w[:, 0, :, None], axis=1)
+        valid = jnp.arange(L, dtype=jnp.int32)[None, :] <= start[:, None]
+        idx, n = topk_indices(score, valid, cfg.index_topk)
+        o = sparse_paged_decode(q[:, 0], pools[0], pools[1], table, idx, n)
+        o = o[:, None]
+    else:
+        view = lambda pool, heads: _kvc.paged_gather(pool, table)[:, 0] \
+            .reshape(B, L, heads, -1)
+        idx_view = None if not sparse else (
+            index[0], index[1],
+            _kvc.paged_gather(pools[2], table)[:, 0, :, :Di])
+        o = attend(cfg, q, view(pools[0], Hkv), view(pools[1], Hkv), pos,
+                   idx_view)
+    return _mm(o.reshape(B, T, Hq * D), p[pre + ".wo"]), tuple(pools)
+
+
+ATTENTIONS = {"dense": attention, "indexed_sparse": attention}
+
+
+# -------------------------------------------------------------------- FFN
+
+def softmax_topk(cfg, g, wr):
+    """Router: softmax in float32 over ALL experts, the top-k of it,
+    renormalised over the chosen (``norm_topk_prob``). Returns (weights
+    [N, k] float32, experts [N, k] int32)."""
+    logits = jnp.dot(g, wr, preferred_element_type=jnp.float32)
+    pw, e = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        pw = pw / jnp.sum(pw, axis=-1, keepdims=True)
+    return pw, e.astype(jnp.int32)
+
+
+ROUTERS = {"softmax_topk": softmax_topk}
+
+
+def _swiglu_shapes(cfg, pre):
+    H, F = cfg.hidden_size, cfg.intermediate_size
+    return {pre + ".w1": (H, F), pre + ".w3": (H, F), pre + ".w2": (F, H)}
+
+
+def swiglu(cfg, p, pre, g):
+    """Dense gated FFN over ``g [N, hidden]``; no routing statistics."""
+    a = jax.nn.silu(_mm(g, p[pre + ".w1"])) * _mm(g, p[pre + ".w3"])
+    return _mm(a, p[pre + ".w2"]), jnp.zeros((2,), jnp.int32)
+
+
+def _moe_shapes(cfg, pre):
+    H, F, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+    return {pre + ".router": (H, E), pre + ".w1": (E, H, F),
+            pre + ".w3": (E, H, F), pre + ".w2": (E, F, H)}
+
+
+def moe_swiglu(cfg, p, pre, g):
+    """The drop-free expert layer over ``g [N, hidden]``: every (token,
+    chosen expert) row is computed, whatever the distribution. Returns
+    (y [N, hidden], [distinct experts routed to, largest expert's rows])."""
+    from ..kernels.grouped_matmul import grouped_matmul, plan_groups, row_tile
+
+    N, H = g.shape
+    E, k = cfg.num_experts, cfg.experts_per_token
+    pw, e = ROUTERS[cfg.router](cfg, g, p[pre + ".router"])
+    tm = row_tile(N * k, E)
+    src, dest, tile_group, n_tiles, counts = plan_groups(e.reshape(-1), E, tm)
+    x = g[src // k]                                        # [M_pad, H]
+    gmm = lambda a, w: grouped_matmul(a, w, tile_group, n_tiles, tm)
+    a = jax.nn.silu(gmm(x, p[pre + ".w1"])) * gmm(x, p[pre + ".w3"])
+    y = gmm(a, p[pre + ".w2"])[dest].reshape(N, k, H)
+    y = jnp.sum(y.astype(jnp.float32) * pw[:, :, None], axis=1)
+    stats = jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)
+    return y.astype(g.dtype), stats
+
+
+FFNS = {"swiglu": (swiglu, _swiglu_shapes),
+        "moe_swiglu": (moe_swiglu, _moe_shapes)}
+
+
+#: tokens per pass of the FFN over a long sequence
+_FFN_TOKEN_CHUNK = 2048
+
+
+def ffn(cfg, p, pre, g):
+    """``g [B, T, hidden]`` through the block's FFN, ``_FFN_TOKEN_CHUNK``
+    tokens at a time (a long prefill's sorted rows, eight a token, would
+    otherwise stand in memory whole). Statistics are the last chunk's."""
+    B, T, H = g.shape
+    fn = FFNS[cfg.ffn][0]
+    flat = g.reshape(B * T, H)
+    C = _FFN_TOKEN_CHUNK
+    if B * T <= C or (B * T) % C:
+        y, stats = fn(cfg, p, pre, flat)
+        return y.reshape(B, T, H), stats
+
+    def body(i, carry):
+        y, _ = carry
+        yc, stats = fn(cfg, p, pre, lax.dynamic_slice_in_dim(flat, i * C, C))
+        return lax.dynamic_update_slice_in_dim(y, yc, i * C, axis=0), stats
+
+    y, stats = lax.fori_loop(0, B * T // C, body,
+                             (jnp.zeros_like(flat), jnp.zeros((2,), jnp.int32)))
+    return y.reshape(B, T, H), stats
+
+
+# ------------------------------------------------------------------ model
+
+def param_shapes(cfg: DecoderConfig) -> dict:
+    """{name: shape} of every parameter, in the names the model uses."""
+    H = cfg.hidden_size
+    s = {"embed.weight": (cfg.vocab_size, H)}
+    for l in range(cfg.num_layers):
+        pre = f"layers.{l}"
+        s.update(_norm_shapes(cfg, pre + ".attn_norm", H))
+        s.update(_attn_shapes(cfg, pre + ".attn"))
+        s.update(_norm_shapes(cfg, pre + ".ffn_norm", H))
+        s.update(FFNS[cfg.ffn][1](cfg, pre + ".ffn"))
+    s.update(_norm_shapes(cfg, "final_norm", H))
+    if not cfg.tie_word_embeddings:
+        s["head.weight"] = (H, cfg.vocab_size)
+    return s
+
+
+def is_norm_scale(name: str) -> bool:
+    return name.endswith("norm.weight")
+
+
+def block(cfg, p, l, x, start, cache=None, flash_ok=False):
+    """One block over the residual stream ``x [B, T, hidden]``: returns
+    (x, the layer's new pool entries, its routing statistics)."""
+    pre = f"layers.{l}"
+    a, new = ATTENTIONS[cfg.attention](
+        cfg, p, pre + ".attn", _norm(cfg, x, p, pre + ".attn_norm"), start,
+        cache, flash_ok)
+    x = x + a
+    y, stats = ffn(cfg, p, pre + ".ffn", _norm(cfg, x, p, pre + ".ffn_norm"))
+    return x + y, new, stats
+
+
+class DecoderLM(Layer):
+    """The decoder of a ``DecoderConfig`` with the serving engine's model
+    protocol. Parameters are flat, named as ``param_shapes`` names them."""
+
+    def __init__(self, cfg: DecoderConfig):
+        super().__init__()
+        self.cfg = cfg
+        dt = jnp.dtype(cfg.dtype)
+        key = jax.random.PRNGKey(0)
+        for i, (name, shape) in enumerate(param_shapes(cfg).items()):
+            if cfg.init == "zeros":
+                v = jnp.zeros(shape, dt)
+            elif is_norm_scale(name):
+                v = jnp.ones(shape, dt)
+            elif name.endswith(".bias"):
+                v = jnp.zeros(shape, dt)
+            else:
+                v = (cfg.initializer_range * jax.random.normal(
+                    jax.random.fold_in(key, i), shape, jnp.float32)).astype(dt)
+            self.add_parameter(name, Parameter(v))
+
+    def _p(self):
+        return {n: p._value for n, p in self._parameters.items()}
+
+    # ---- the serving engine's protocol ----
+    @property
+    def max_context(self) -> int:
+        return self.cfg.max_context
+
+    def cache_pools(self):
+        """[(name, heads, width)] of the per-layer pools: K and V with a
+        token's heads side by side, and the indexer's keys."""
+        cfg = self.cfg
+        kv = cfg.num_kv_heads * cfg.head_dim
+        pools = [("k", 1, kv), ("v", 1, kv)]
+        if cfg.attention == "indexed_sparse":
+            pools.append(("index_k", 1, index_pool_width(cfg)))
+        return pools
+
+    def selected_tokens(self, ctx):
+        """Cached positions a decode step's attention reads for contexts
+        ``ctx`` (array of live tokens per slot)."""
+        if self.cfg.attention != "indexed_sparse":
+            return ctx
+        return np.minimum(ctx, self.cfg.index_topk)
+
+    step_stats = ("experts_touched", "expert_max_load")  # per layer
+
+    def _forward(self, ids, start, caches=None, flash_ok=False):
+        cfg, p = self.cfg, self._p()
+        x = p["embed.weight"][ids]
+        news, stats = [], []
+        for l in range(cfg.num_layers):
+            x, new, st = block(cfg, p, l, x, start,
+                               None if caches is None else caches[l], flash_ok)
+            news.append(tuple(Tensor(a) for a in new))
+            stats.append(st)
+        return x, news, jnp.stack(stats)
+
+    def _logits(self, h):
+        p = self._p()
+        h = _norm(self.cfg, h, p, "final_norm")
+        w = p["embed.weight"].T if self.cfg.tie_word_embeddings \
+            else p["head.weight"]
+        return jnp.dot(h, w, preferred_element_type=jnp.float32)
+
+    def forward(self, input_ids):
+        """Logits ``[B, T, vocab]`` (float32) of a full causal pass."""
+        ids = _ids(input_ids)
+        x, _, _ = self._forward(ids, jnp.zeros((ids.shape[0],), jnp.int32))
+        return Tensor(self._logits(x))
+
+    def prefill_with_cache(self, input_ids, lengths=None):
+        """(last real token's logits ``[B, V]``, per layer the pool entries
+        ``[B, 1, T, width]`` for the engine to install)."""
+        ids = _ids(input_ids)
+        B, T = ids.shape
+        x, news, _ = self._forward(ids, jnp.zeros((B,), jnp.int32),
+                                   flash_ok=True)
+        if lengths is None:
+            last = x[:, T - 1]
+        else:
+            idx = jnp.clip(_ids(lengths) - 1, 0, T - 1)
+            last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+        return Tensor(self._logits(last)), news
+
+    def extend_step(self, tokens, caches, positions):
+        """``tokens [B, T]`` at ``positions[b] + t`` over the paged pools:
+        (logits ``[B, T, V]``, per layer the updated pools)."""
+        ids = _ids(tokens)
+        ids = ids[:, None] if ids.ndim == 1 else ids
+        start = jnp.broadcast_to(_ids(positions), (ids.shape[0],))
+        entries = [tuple(map(_raw, e)) for e in caches]
+        x, news, stats = self._forward(ids, start, entries)
+        return Tensor(self._logits(x)), news, Tensor(stats)
+
+    def decode_step(self, tokens, caches, positions):
+        """One token per slot: (logits ``[B, V]``, per layer the updated
+        pools, routing statistics ``[layers, 2]``)."""
+        logits, news, stats = self.extend_step(tokens, caches, positions)
+        return Tensor(logits._value[:, -1]), news, stats
+
+
+def _raw(t):
+    return t._value if isinstance(t, Tensor) else t
+
+
+def _ids(t):
+    return jnp.asarray(_raw(t)).astype(jnp.int32)

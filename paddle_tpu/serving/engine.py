@@ -51,15 +51,17 @@ from .sampling import SamplingParams
 from .scheduler import FINISHED, PageAllocator, Request, Scheduler
 from .speculative import SpeculativeConfig, accept_greedy, propose_ngram
 
-#: every serving executable takes (params, k_cache, v_cache, ...), where
-#: each cache is a TUPLE of per-layer buffers (kv_cache.KVCache /
-#: PagedKVCache), and returns the tuples of updated buffers its caller
-#: rebinds — so the KV cache args are donated at compile time (an argnum
-#: covers every leaf of its pytree). Layer ``l``'s program output is a
-#: scatter into layer ``l``'s donated parameter, which XLA aliases: the
-#: cache is written where it lies, and no program holds a second copy of
-#: it (the analysis donation rule, donation-missing on
-#: serving_prefill/serving_decode, checks the donation half).
+#: every serving executable takes (params, *pools, ...), where each pool is
+#: a TUPLE of per-layer buffers (kv_cache.KVCache / PagedKVCache: ``k`` and
+#: ``v``, then whatever else the model declared), and returns the tuples of
+#: updated buffers its caller rebinds — so the pool args are donated at
+#: compile time (an argnum covers every leaf of its pytree). Layer ``l``'s
+#: program output is a scatter into layer ``l``'s donated parameter, which
+#: XLA aliases: the cache is written where it lies, and no program holds a
+#: second copy of it (the analysis donation rule, donation-missing on
+#: serving_prefill/serving_decode, checks the donation half). These are the
+#: argnums of the two pools every model has; ``Engine.donate_argnums``
+#: covers a model that declared more.
 KV_DONATE_ARGNUMS = (1, 2)
 
 _DUMMY_KEY = None
@@ -110,18 +112,20 @@ def _param_dtype(params: Dict[str, jax.Array]):
     return jnp.float32
 
 
-def _updated(new) -> Tuple[Tuple[jax.Array, ...], Tuple[jax.Array, ...]]:
-    """The ``(k, v)`` buffer tuples a program returns, from the per-layer
-    ``(k, v)`` Tensor pairs ``decode_step`` / ``extend_step`` hand back."""
-    return (tuple(k._value for k, _ in new), tuple(v._value for _, v in new))
+def _updated(new) -> Tuple[Tuple[jax.Array, ...], ...]:
+    """The pools' buffer tuples a program returns (by pool, then by layer),
+    from the per-layer entries (``(k, v)`` Tensor pairs, or one Tensor per
+    declared pool) ``decode_step`` / ``extend_step`` hand back."""
+    return tuple(tuple(t._value for t in pool) for pool in zip(*new))
 
 
-def _write_prompt(write, kc, vc, kvs):
-    """``write(buffer, new)`` over every layer's K and V: the prompt's
-    per-layer K/V (the ``(k, v)`` Tensor pairs ``prefill_with_cache``
-    returns) into the cache's buffer tuples; returns the updated tuples."""
-    return (tuple(write(c, k._value) for c, (k, _) in zip(kc, kvs)),
-            tuple(write(c, v._value) for c, (_, v) in zip(vc, kvs)))
+def _write_prompt(write, pools, kvs):
+    """``write(buffer, new)`` over every layer of every pool: the prompt's
+    per-layer entries (one Tensor per pool, as ``prefill_with_cache``
+    returns them) into the cache's buffer tuples; returns the updated
+    tuples."""
+    return tuple(tuple(write(c, e._value) for c, e in zip(pool, entries))
+                 for pool, entries in zip(pools, zip(*kvs)))
 
 
 def _write_prompt_dense(kc, vc, kvs, slot):
@@ -130,17 +134,27 @@ def _write_prompt_dense(kc, vc, kvs, slot):
     zero = jnp.zeros((), jnp.int32)
     return _write_prompt(
         lambda c, new: lax.dynamic_update_slice(
-            c, new.astype(c.dtype), (slot, zero, zero, zero)), kc, vc, kvs)
+            c, new.astype(c.dtype), (slot, zero, zero, zero)), (kc, vc), kvs)
 
 
-def _write_prompt_paged(kc, vc, kvs, page_row):
-    """Each layer's prompt K/V ``[1, Hkv, T, D]`` into that layer's page
-    pool at positions ``[0, T)``, routed by the slot's table row: one
-    scatter of the bucket's pages per pool (``paged_write_kv``). Blocks
-    past the allocated pages (sentinels) land on the trash page."""
+def _write_prompt_paged(pools, kvs, page_row):
+    """Each layer's prompt entries ``[1, heads, T, width]`` into that
+    layer's page pools at positions ``[0, T)``, routed by the slot's table
+    row: one scatter of the bucket's pages per pool (``paged_write_kv``).
+    Blocks past the allocated pages (sentinels) land on the trash page."""
     table, zero = page_row[None, :], jnp.zeros((1,), jnp.int32)
     return _write_prompt(
-        lambda c, new: paged_write_kv(c, new, table, zero), kc, vc, kvs)
+        lambda c, new: paged_write_kv(c, new, table, zero), pools, kvs)
+
+
+def _call(model, params, method, *args, **kw):
+    """``model.<method>`` on ``params``: (logits, per-layer new entries,
+    per-layer step statistics or None). A model that counts something in
+    its step (a routed FFN's expert loads) returns it third."""
+    with no_grad():
+        (logits, new, *more), _ = model.functional_call(
+            params, {}, *args, method=method, **kw)
+    return logits._value, new, (more[0]._value if more else None)
 
 
 # ---------------------------------------------------------------------------
@@ -319,10 +333,12 @@ class _SlotState:
 class Engine:
     """Offline/online LLM serving engine over a cache-aware causal LM.
 
-    The model must speak the decode protocol GPTForCausalLM implements:
-    ``cfg`` (num_layers / num_kv_heads / head_dim / max_seq_len),
-    ``functional_state()``, and the ``prefill_with_cache`` /
-    ``decode_step`` methods (callable through ``functional_call``).
+    The model must speak the protocol of serving/README.md ("The model's
+    side"): ``cfg.num_layers``, ``functional_state()``, the
+    ``prefill_with_cache`` / ``decode_step`` / ``extend_step`` methods
+    (callable through ``functional_call``), and either ``cache_pools()`` +
+    ``max_context`` or, as GPTForCausalLM has them, ``cfg.num_kv_heads`` /
+    ``cfg.head_dim`` / ``cfg.max_seq_len``.
 
         engine = Engine(model, max_batch_size=4, max_seq_len=128)
         outputs = engine.generate([[5, 17, 3], [9, 2]],
@@ -341,10 +357,20 @@ class Engine:
         model.eval()
         self.config = config or EngineConfig(**kw)
         cfg = model.cfg
-        if self.config.max_seq_len > cfg.max_seq_len:
+        # the longest sequence the model's positions serve: what it
+        # declares, else (GPT) the length of its position table
+        context = getattr(model, "max_context", None) or cfg.max_seq_len
+        if self.config.max_seq_len > context:
             raise ValueError(
                 f"engine max_seq_len {self.config.max_seq_len} exceeds the "
-                f"model's position table ({cfg.max_seq_len})")
+                f"model's declared context ({context})")
+        # the per-layer pools: the model's declaration, else K and V of
+        # cfg.num_kv_heads x cfg.head_dim
+        declared = getattr(model, "cache_pools", None)
+        pools = declared() if declared is not None else [
+            ("k", cfg.num_kv_heads, cfg.head_dim),
+            ("v", cfg.num_kv_heads, cfg.head_dim)]
+        self.donate_argnums = tuple(range(1, 1 + len(pools)))
         self.params, _ = model.functional_state()
         dt = (self.config.cache_dtype if self.config.cache_dtype is not None
               else _param_dtype(self.params))
@@ -354,13 +380,17 @@ class Engine:
             num_pages = self.config.kv_pages
             if num_pages is None:
                 num_pages = B * (S_max // ps) + 1  # full budget + trash page
-            self.cache = PagedKVCache(cfg.num_layers, B, cfg.num_kv_heads,
-                                      S_max, cfg.head_dim, dt,
-                                      page_size=ps, num_pages=num_pages)
+            self.cache = PagedKVCache(cfg.num_layers, B, pools[0][1],
+                                      S_max, pools[0][2], dt, page_size=ps,
+                                      num_pages=num_pages, pools=pools)
             self.page_alloc: Optional[PageAllocator] = PageAllocator(num_pages)
         else:
-            self.cache = KVCache(cfg.num_layers, B, cfg.num_kv_heads, S_max,
-                                 cfg.head_dim, dt)
+            if len(pools) != 2:
+                raise ValueError(
+                    f"the dense layout holds K and V only; this model "
+                    f"declares {[n for n, _, _ in pools]}")
+            self.cache = KVCache(cfg.num_layers, B, pools[0][1], S_max,
+                                 pools[0][2], dt)
             self.page_alloc = None
         _metrics.gauge("serving.kv_cache.bytes", self.cache.nbytes)
         _obs_memory.record_kv_cache(self.cache.nbytes)
@@ -509,31 +539,28 @@ class Engine:
         bucket's pages (``_write_prompt_paged``; the bucket tail past the
         allocated pages clamps to the trash page, exactly like bucket
         padding wrote garbage past ``length`` in the dense layout)."""
-        model = self.model
+        model, n = self.model, len(self.cache.pools)
         if self.config.kv_layout == "paged":
             nb = self.cache.num_blocks
 
             @jax.named_scope("serving/prefill")
-            def paged_prefill_fn(p, kc, vc, ids, page_row, length):
-                with no_grad():
-                    (logits, kvs), _ = model.functional_call(
-                        p, {}, Tensor(ids), method="prefill_with_cache",
-                        lengths=Tensor(length[None]))
-                return (logits._value,) + _write_prompt_paged(
-                    kc, vc, kvs, page_row)
+            def paged_prefill_fn(p, *a):
+                pools, (ids, page_row, length) = a[:n], a[n:]
+                logits, kvs, _ = _call(model, p, "prefill_with_cache",
+                                       Tensor(ids),
+                                       lengths=Tensor(length[None]))
+                return (logits,) + _write_prompt_paged(pools, kvs, page_row)
 
-            args = (self.params, self.cache.k, self.cache.v,
+            args = (self.params, *self.cache.pools,
                     jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
                     jnp.int32(1))
             return paged_prefill_fn, args
 
         @jax.named_scope("serving/prefill")
         def prefill_fn(p, kc, vc, ids, slot, length):
-            with no_grad():
-                (logits, kvs), _ = model.functional_call(
-                    p, {}, Tensor(ids), method="prefill_with_cache",
-                    lengths=Tensor(length[None]))
-            return (logits._value,) + _write_prompt_dense(kc, vc, kvs, slot)
+            logits, kvs, _ = _call(model, p, "prefill_with_cache",
+                                   Tensor(ids), lengths=Tensor(length[None]))
+            return (logits,) + _write_prompt_dense(kc, vc, kvs, slot)
 
         args = (self.params, self.cache.k, self.cache.v,
                 jnp.zeros((1, T), jnp.int32), jnp.int32(0), jnp.int32(1))
@@ -551,20 +578,26 @@ class Engine:
         model, cache = self.model, self.cache
         if self.config.kv_layout == "paged":
             B, nb = self.config.max_batch_size, self.cache.num_blocks
+            n = len(cache.pools)
 
             @jax.named_scope("serving/decode")
-            def paged_decode_fn(p, kc, vc, page_table, tokens, positions,
-                                temps, top_ks, greedy, key):
-                with no_grad():
-                    (logits, new), _ = model.functional_call(
-                        p, {}, Tensor(tokens),
-                        cache.layer_caches(kc, vc, page_table),
-                        Tensor(positions), method="decode_step")
-                nxt = _sampling.sample_batched(logits._value, key, temps,
-                                               top_ks, greedy)
-                return (nxt.astype(jnp.int32),) + _updated(new)
+            def paged_decode_fn(p, *a):
+                pools = a[:n]
+                page_table, tokens, positions, temps, top_ks, greedy, key = \
+                    a[n:]
+                logits, new, stats = _call(
+                    model, p, "decode_step", Tensor(tokens),
+                    cache.layer_entries(pools, page_table), Tensor(positions))
+                nxt = _sampling.sample_batched(logits, key, temps, top_ks,
+                                               greedy).astype(jnp.int32)
+                if stats is not None:
+                    # the step's statistics ride behind the tokens, in the
+                    # one array the host fetches
+                    nxt = jnp.concatenate(
+                        [nxt, stats.astype(jnp.int32).reshape(-1)])
+                return (nxt,) + _updated(new)
 
-            args = (self.params, self.cache.k, self.cache.v,
+            args = (self.params, *self.cache.pools,
                     jnp.zeros((B, nb), jnp.int32),
                     jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                     jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
@@ -574,12 +607,10 @@ class Engine:
         @jax.named_scope("serving/decode")
         def decode_fn(p, kc, vc, tokens, positions, temps, top_ks, greedy,
                       key):
-            with no_grad():
-                (logits, new), _ = model.functional_call(
-                    p, {}, Tensor(tokens), cache.layer_caches(kc, vc),
-                    Tensor(positions), method="decode_step")
-            nxt = _sampling.sample_batched(logits._value, key, temps,
-                                           top_ks, greedy)
+            logits, new, _ = _call(model, p, "decode_step", Tensor(tokens),
+                                   cache.layer_caches(kc, vc),
+                                   Tensor(positions))
+            nxt = _sampling.sample_batched(logits, key, temps, top_ks, greedy)
             return (nxt.astype(jnp.int32),) + _updated(new)
 
         B = self.config.max_batch_size
@@ -602,21 +633,20 @@ class Engine:
         if self.config.kv_layout != "paged":
             raise ValueError("extend_program requires kv_layout='paged'")
         model, cache = self.model, self.cache
-        nb = self.cache.num_blocks
+        nb, n = self.cache.num_blocks, len(self.cache.pools)
 
         @jax.named_scope("serving/extend")
-        def extend_fn(p, kc, vc, ids, page_row, start, length):
-            with no_grad():
-                (logits, new), _ = model.functional_call(
-                    p, {}, Tensor(ids),
-                    cache.layer_caches(kc, vc, page_row[None, :]),
-                    Tensor(start[None]), method="extend_step")
-            lv = logits._value  # [1, T, V]
+        def extend_fn(p, *a):
+            pools, (ids, page_row, start, length) = a[:n], a[n:]
+            lv, new, _ = _call(                     # logits [1, T, V]
+                model, p, "extend_step", Tensor(ids),
+                cache.layer_entries(pools, page_row[None, :]),
+                Tensor(start[None]))
             idx = jnp.clip(length - 1, 0, T - 1)
             last = lax.dynamic_index_in_dim(lv[0], idx, keepdims=False)
             return (last[None],) + _updated(new)  # [1, V], like prefill
 
-        args = (self.params, self.cache.k, self.cache.v,
+        args = (self.params, *self.cache.pools,
                 jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
                 jnp.int32(0), jnp.int32(1))
         return extend_fn, args
@@ -646,22 +676,21 @@ class Engine:
             k = self.spec.k
         model, cache = self.model, self.cache
         B, nb = self.config.max_batch_size, self.cache.num_blocks
+        n = len(cache.pools)
 
         @jax.named_scope("serving/verify")
-        def verify_fn(p, kc, vc, page_table, tokens, positions, temps,
-                      top_ks, greedy, key):
-            with no_grad():
-                (logits, new), _ = model.functional_call(
-                    p, {}, Tensor(tokens),
-                    cache.layer_caches(kc, vc, page_table),
-                    Tensor(positions), method="extend_step")
-            lv = logits._value  # [B, k+1, V]
+        def verify_fn(p, *a):
+            pools = a[:n]
+            page_table, tokens, positions, temps, top_ks, greedy, key = a[n:]
+            lv, new, _ = _call(                     # logits [B, k+1, V]
+                model, p, "extend_step", Tensor(tokens),
+                cache.layer_entries(pools, page_table), Tensor(positions))
             targets = jnp.argmax(lv, axis=-1).astype(jnp.int32)
             sampled0 = _sampling.sample_batched(lv[:, 0], key, temps,
                                                 top_ks, greedy)
             return (targets, sampled0.astype(jnp.int32)) + _updated(new)
 
-        args = (self.params, self.cache.k, self.cache.v,
+        args = (self.params, *self.cache.pools,
                 jnp.zeros((B, nb), jnp.int32),
                 jnp.zeros((B, k + 1), jnp.int32),
                 jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32),
@@ -697,7 +726,7 @@ class Engine:
     def _prefill_exe(self, T: int):
         prefill_fn, args = self.prefill_program(T)
         return _aot(self._exe, ("prefill", T), "serving.prefill",
-                    prefill_fn, args, donate_argnums=KV_DONATE_ARGNUMS)
+                    prefill_fn, args, donate_argnums=self.donate_argnums)
 
     def _decode_exe(self):
         decode_fn, args = self.decode_program()
@@ -705,13 +734,13 @@ class Engine:
         # executables never re-dispatch); no-op for the dense layout
         with use_paged_attention_impl(self.config.paged_attention_impl):
             return _aot(self._exe, ("decode",), "serving.decode", decode_fn,
-                        args, donate_argnums=KV_DONATE_ARGNUMS)
+                        args, donate_argnums=self.donate_argnums)
 
     def _extend_exe(self, T: int):
         extend_fn, args = self.extend_program(T)
         with use_paged_attention_impl(self.config.paged_attention_impl):
             return _aot(self._exe, ("extend", T), "serving.prefill",
-                        extend_fn, args, donate_argnums=KV_DONATE_ARGNUMS)
+                        extend_fn, args, donate_argnums=self.donate_argnums)
 
     def _verify_exe(self):
         verify_fn, args = self.verify_program()
@@ -720,7 +749,56 @@ class Engine:
         # site — the one-compile-per-lifetime counter covers both modes
         with use_paged_attention_impl(self.config.paged_attention_impl):
             return _aot(self._exe, ("verify",), "serving.decode", verify_fn,
-                        args, donate_argnums=KV_DONATE_ARGNUMS)
+                        args, donate_argnums=self.donate_argnums)
+
+    def compile_programs(self, prefill: Sequence[int] = (),
+                         extend: Sequence[int] = ()) -> List[Tuple]:
+        """Compile, before traffic arrives, the decode program (the verify
+        program under speculation) and the named prefill / extend buckets;
+        returns the keys of the programs it compiled (those the engine
+        already holds are left out). What ``_prefill_exe`` and its siblings
+        would compile one after another on first use is traced here one
+        program at a time (a trace swaps the model's parameters for its own,
+        in place) and then compiled by the backend side by side, one thread
+        a program: a big model's start-up is the backend's seconds, and they
+        do not depend on one another. The accounting is ``_aot``'s: one
+        ``compile`` span and one ``jit.compile.cache_miss{site=}`` a
+        program."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        keys = [("verify",) if self.spec is not None else ("decode",)]
+        keys += [("prefill", int(T)) for T in prefill]
+        keys += [("extend", int(T)) for T in extend]
+        keys = [k for k in dict.fromkeys(keys) if k not in self._exe]
+
+        def compile_one(item):
+            key, site, lowered, traced_s = item
+            with _span("compile", site=site, cache_hit=0) as sp:
+                exe = lowered.compile()
+            return key, site, exe, traced_s + sp.seconds
+
+        with warnings.catch_warnings():
+            warnings.filterwarnings(    # as in _aot
+                "ignore", message=".*donated buffers.*", category=UserWarning)
+            todo, done = [], []
+            with use_paged_attention_impl(self.config.paged_attention_impl):
+                for key in keys:
+                    t0 = time.perf_counter()
+                    fn, args = getattr(self, key[0] + "_program")(*key[1:])
+                    site = ("serving.decode" if key[0] in ("decode", "verify")
+                            else "serving.prefill")
+                    lowered = jax.jit(
+                        fn, donate_argnums=self.donate_argnums).lower(*args)
+                    todo.append((key, site, lowered,
+                                 time.perf_counter() - t0))
+            if todo:
+                with ThreadPoolExecutor(len(todo)) as pool:
+                    done = list(pool.map(compile_one, todo))
+        for key, site, exe, seconds in done:
+            _obs.record_compile(site, seconds=seconds, cache_hit=False)
+            _obs_memory.record_executable(site, exe)
+            self._exe[key] = exe
+        return keys
 
     def _pages_needed(self, prompt_len: int) -> int:
         """Pages covering positions [0, prompt_len] — prompt plus the slot
@@ -808,9 +886,8 @@ class Engine:
                     ids = np.zeros((1, T), np.int32)
                     ids[0, :m] = req.prompt_ids[start:]
                     exe = self._extend_exe(T)
-                    logits, self.cache.k, self.cache.v = exe(
-                        self.params, self.cache.k, self.cache.v,
-                        jnp.asarray(ids),
+                    logits, *self.cache.pools = exe(
+                        self.params, *self.cache.pools, jnp.asarray(ids),
                         jnp.asarray(self.cache.page_table[slot]),
                         jnp.int32(start), jnp.int32(m))
             else:
@@ -823,9 +900,9 @@ class Engine:
                     where = (jnp.asarray(self.cache.page_table[slot])
                              if self.page_alloc is not None
                              else jnp.int32(slot))
-                    logits, self.cache.k, self.cache.v = exe(
-                        self.params, self.cache.k, self.cache.v,
-                        jnp.asarray(ids), where, jnp.int32(n))
+                    logits, *self.cache.pools = exe(
+                        self.params, *self.cache.pools, jnp.asarray(ids),
+                        where, jnp.int32(n))
             with _span("serving/admit/sample", request_id=req.request_id):
                 if self.prefix_cache is not None:
                     # index this prompt's FULL blocks (shared ones are
@@ -935,6 +1012,14 @@ class Engine:
             sp.set(running=len(running))
             if not running:
                 return 0
+            # cached tokens the step's attention may read (each running
+            # slot's context, the token it writes included) and how many of
+            # them it does read, where the model selects
+            ctx = self._positions[[r.slot for r in running]] + 1
+            sel = getattr(self.model, "selected_tokens", None)
+            sp.set(ctx_tokens=int(ctx.sum()),
+                   selected_tokens=int((ctx if sel is None
+                                        else sel(ctx)).sum()))
             with _span("serving/decode/upload") as up:
                 any_sampled = not bool(self._greedy.all())
                 key = _random.next_key() if any_sampled else _dummy_key()
@@ -946,10 +1031,18 @@ class Engine:
                     jnp.asarray(self._greedy), key)
             with _span("serving/decode/dispatch") as disp:
                 exe = self._decode_exe()
-                nxt, self.cache.k, self.cache.v = exe(
-                    self.params, self.cache.k, self.cache.v, *args)
+                nxt, *self.cache.pools = exe(
+                    self.params, *self.cache.pools, *args)
             with _span("serving/decode/fetch") as fetch:
                 nxt = np.asarray(nxt)
+            if nxt.shape[0] > len(self._slots):
+                # a model that counts in its step (decoder.DecoderLM: per
+                # layer the distinct experts routed to, the largest
+                # expert's rows) sent its counts behind the tokens
+                stats = nxt[len(self._slots):].reshape(
+                    self.model.cfg.num_layers, -1)
+                sp.set(**{name: stats[:, i].tolist() for i, name in
+                          enumerate(self.model.step_stats)})
             step_s = up.seconds + disp.seconds + fetch.seconds
             _metrics.histogram("serving.decode.step.seconds", step_s)
             _metrics.counter("serving.tokens.generated", len(running))
@@ -1008,8 +1101,8 @@ class Engine:
                         jnp.asarray(self._greedy), key)
             with _span("serving/decode/dispatch") as disp:
                 exe = self._verify_exe()
-                targets, sampled0, self.cache.k, self.cache.v = exe(
-                    self.params, self.cache.k, self.cache.v, *args)
+                targets, sampled0, *self.cache.pools = exe(
+                    self.params, *self.cache.pools, *args)
             with _span("serving/decode/fetch") as fetch:
                 targets = np.asarray(targets)
                 sampled0 = np.asarray(sampled0)

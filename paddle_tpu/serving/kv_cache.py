@@ -149,6 +149,15 @@ class KVCache:
     def nbytes(self) -> int:
         return _tuple_nbytes(self.k, self.v)
 
+    @property
+    def pools(self):
+        """``(k, v)``: the buffer tuples by pool, as ``PagedKVCache.pools``."""
+        return (self.k, self.v)
+
+    @pools.setter
+    def pools(self, value):
+        self.k, self.v = value
+
     def alloc_slot(self) -> Optional[int]:
         """Lowest free slot index, or None when the batch is full."""
         return self._free.pop() if self._free else None
@@ -327,13 +336,22 @@ def paged_extend_attend(q, k_pool, v_pool, page_table, positions,
 
 
 class PagedKVCache:
-    """Block-paged K/V pools, one ``[num_pages, H_kv, page_size, D]`` device
-    buffer per layer (``.k`` / ``.v`` are tuples of ``num_layers`` arrays),
-    plus the per-slot page table and the same slot bookkeeping as
-    ``KVCache``.
+    """Block-paged pools, one ``[num_pages, heads, page_size, width]`` device
+    buffer per layer and pool, plus the per-slot page table and the same
+    slot bookkeeping as ``KVCache``.
+
+    Which pools there are is the MODEL's declaration (``pools``: ``[(name,
+    heads, width)]``); the default is the pair every attention needs, ``k``
+    and ``v`` of ``num_kv_heads x head_dim``. A model whose attention keeps
+    more per token (an indexer's keys) or lays a token's heads side by side
+    (``heads = 1``, ``width = H_kv * D``) declares that, and everything a
+    page lives through — the page-at-a-time write, the table row spliced on
+    a prefix hit, copy-on-write, freeing, eviction — covers every pool,
+    because it is all done by page id. ``.pools`` is the tuple (by pool) of
+    tuples (by layer) of buffers; ``.k`` / ``.v`` name the first two.
 
     The pools are donated device buffers exactly like the dense cache's
-    (the engine rebinds ``.k``/``.v`` to the tuples each compiled step
+    (the engine rebinds ``.pools`` to the tuples each compiled step
     returns; every layer's pool is updated where it lies). The page table
     is HOST state (numpy): the scheduler's allocator mutates it between
     steps and the engine ships a snapshot (``table_device()``) into each
@@ -351,7 +369,7 @@ class PagedKVCache:
     def __init__(self, num_layers: int, max_batch_size: int,
                  num_kv_heads: int, max_seq_len: int, head_dim: int,
                  dtype="float32", page_size: int = 16,
-                 num_pages: Optional[int] = None):
+                 num_pages: Optional[int] = None, pools=None):
         if max_seq_len % page_size:
             raise ValueError(
                 f"max_seq_len {max_seq_len} not divisible by page_size "
@@ -368,17 +386,44 @@ class PagedKVCache:
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (trash page + 1)")
         self.num_pages = num_pages
-        shape = (num_pages, num_kv_heads, page_size, head_dim)
-        self.k = _layer_buffers(num_layers, shape, dtype)
-        self.v = _layer_buffers(num_layers, shape, dtype)
+        if pools is None:
+            pools = [("k", num_kv_heads, head_dim), ("v", num_kv_heads, head_dim)]
+        self.pool_specs = [(str(n), int(h), int(w)) for n, h, w in pools]
+        self._pools = tuple(
+            _layer_buffers(num_layers, (num_pages, h, page_size, w), dtype)
+            for _, h, w in self.pool_specs)
         self.page_table = np.full((max_batch_size, self.num_blocks),
                                   PAGE_SENTINEL, np.int32)
         self._free: List[int] = list(range(max_batch_size))[::-1]
         self._copy_exe = None
 
     @property
+    def pools(self):
+        return self._pools
+
+    @pools.setter
+    def pools(self, value):
+        self._pools = tuple(value)
+
+    @property
+    def k(self):
+        return self.pools[0]
+
+    @k.setter
+    def k(self, value):
+        self.pools = (value,) + self.pools[1:]
+
+    @property
+    def v(self):
+        return self.pools[1]
+
+    @v.setter
+    def v(self, value):
+        self.pools = self.pools[:1] + (value,) + self.pools[2:]
+
+    @property
     def nbytes(self) -> int:
-        return _tuple_nbytes(self.k, self.v)
+        return _tuple_nbytes(*self.pools)
 
     def table_device(self) -> jax.Array:
         """Snapshot the host page table as the device operand the compiled
@@ -392,34 +437,39 @@ class PagedKVCache:
             self.page_table[slot, start_block + j] = p
 
     def copy_page_exe(self):
-        """The compiled copy-on-write program: ``(k, v, src, dst) -> (k,
-        v)`` over the donated pool tuples, page ids as runtime scalars, so
-        ONE executable serves every copy and each layer's page moves inside
-        its own buffer. Compiled on first use; a caller that must not
-        compile later (the engine, when pages can be shared) asks for it up
-        front."""
+        """The compiled copy-on-write program: ``(*pools, src, dst) ->
+        pools`` over the donated pool tuples, page ids as runtime scalars,
+        so ONE executable serves every copy and each layer's page moves
+        inside its own buffer, in every pool. Compiled on first use; a
+        caller that must not compile later (the engine, when pages can be
+        shared) asks for it up front."""
         if self._copy_exe is None:
-            def copy_page_fn(kc, vc, src, dst):
+            n = len(self.pools)
+
+            def copy_page_fn(*a):
+                src, dst = a[n:]
+
                 def one(pool):
                     zero = jnp.zeros((), jnp.int32)
                     page = lax.dynamic_slice(
                         pool, (src, zero, zero, zero), (1,) + pool.shape[1:])
                     return lax.dynamic_update_slice(
                         pool, page, (dst, zero, zero, zero))
-                return tuple(map(one, kc)), tuple(map(one, vc))
+                return tuple(tuple(map(one, pool)) for pool in a[:n])
 
-            self._copy_exe = jax.jit(copy_page_fn, donate_argnums=(0, 1)) \
-                .lower(self.k, self.v, jnp.int32(0), jnp.int32(0)).compile()
+            self._copy_exe = jax.jit(copy_page_fn,
+                                     donate_argnums=tuple(range(n))) \
+                .lower(*self.pools, jnp.int32(0), jnp.int32(0)).compile()
         return self._copy_exe
 
     def copy_page(self, src: int, dst: int):
         """Copy-on-write: duplicate page ``src``'s bytes into page ``dst``
-        in every layer of both pools. The caller then repoints its table
+        in every layer of every pool. The caller then repoints its table
         entry at ``dst`` and drops its reference on ``src`` — the sharer
         still mapping ``src`` never observes the write that motivated the
         copy."""
-        self.k, self.v = self.copy_page_exe()(
-            self.k, self.v, jnp.int32(src), jnp.int32(dst))
+        self.pools = tuple(self.copy_page_exe()(
+            *self.pools, jnp.int32(src), jnp.int32(dst)))
 
     def slot_pages(self, slot: int) -> List[int]:
         row = self.page_table[slot]
@@ -454,5 +504,11 @@ class PagedKVCache:
         table is shared by every layer)."""
         k = self.k if k is None else k
         v = self.v if v is None else v
+        return self.layer_entries((k, v), table)
+
+    def layer_entries(self, pools=None, table=None):
+        """Per-layer ``(pool_0, ..., pool_n, page_table)`` entries of the
+        pool tuples, in the order the model declared its pools."""
+        pools = self.pools if pools is None else pools
         table = self.table_device() if table is None else table
-        return [(kl, vl, table) for kl, vl in zip(k, v)]
+        return [tuple(layer) + (table,) for layer in zip(*pools)]
