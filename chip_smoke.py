@@ -208,25 +208,35 @@ def leg_kernels(S: int = 2048, H: int = 16, D: int = 128, hidden: int = 2048,
     say(f"ok: fused AdamW [{hidden},{hidden}] bf16 param + bf16 moments:"
         f" rel err p/m/v {errs['adamw']}")
 
-    # paged decode: ragged batch, page 16, MHA H/H
+    # paged decode: ragged batches, page 16, MHA H/H. The second has what
+    # the kernel's own page walk can get wrong: a dead slot between live
+    # ones, contexts of one chunk (128 tokens), one chunk and a token, and
+    # several chunks with a partial last one
     B, ps, nb = 8, 16, S // 16
     pool = lambda: bf(B * nb + 1, H, ps, D)
     kp, vp = pool(), pool()
-    table = np.full((B, nb), -1, np.int32)
-    pos = np.minimum([5, 17, 100, 511, 700, 1023, 1500, S - 1],
-                     S - 1).astype(np.int32)
-    for i in range(B):
-        live = pos[i] // ps + 1
-        table[i, :live] = 1 + i * nb + np.arange(live)
     qd = bf(B, H, 1, D)
-    args = (qd, kp, vp, jnp.asarray(table), jnp.asarray(pos))
-    got = _compiled(lambda *a: kvc.paged_decode_attend(*a, impl="pallas"),
-                    args, "paged_decode")(*args)
-    want = _compiled(lambda *a: kvc.paged_decode_attend(*a, impl="oracle"),
-                     args, "paged_decode", present=False)(*args)
-    errs["paged_decode"] = close(got, want, tol)
+    errs["paged_decode"] = []
+    for ctx in ([5, 17, 100, 511, 700, 1023, 1500, S - 1],
+                [300, None, 127, 128, None, 0, 1029, S - 1]):
+        table = np.full((B, nb), -1, np.int32)
+        pos = np.zeros(B, np.int32)
+        live = np.asarray([i for i, c in enumerate(ctx) if c is not None])
+        for i in live:
+            pos[i] = min(ctx[i], S - 1)
+            n = pos[i] // ps + 1
+            table[i, :n] = 1 + i * nb + np.arange(n)
+        args = (qd, kp, vp, jnp.asarray(table), jnp.asarray(pos))
+        got = _compiled(lambda *a: kvc.paged_decode_attend(*a, impl="pallas"),
+                        args, "paged_decode")(*args)
+        want = _compiled(lambda *a: kvc.paged_decode_attend(*a, impl="oracle"),
+                         args, "paged_decode", present=False)(*args)
+        errs["paged_decode"].append(close(got[live], want[live], tol))
+        if np.delete(np.asarray(got, np.float32), live, axis=0).any():
+            raise AssertionError("paged decode wrote into a dead slot's row")
     say(f"ok: paged decode B={B} H={H} D={D} page {ps} x{nb} bf16 vs "
-        f"oracle: rel err {errs['paged_decode']}")
+        f"oracle, ragged and ragged with dead slots: rel err "
+        f"{errs['paged_decode']}")
     return errs
 
 

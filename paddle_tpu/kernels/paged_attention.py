@@ -3,24 +3,33 @@
 One decode step attends each slot's single query token against that slot's
 live KV pages only. The pools are ``[num_pages, H_kv, page_size, D]`` (one
 per layer); routing is a ``[B, num_blocks]`` int32 page table whose entries
-are pool page ids (``-1`` sentinel pads unallocated blocks). Both the table
-and the per-slot positions ride as SCALAR-PREFETCH operands
-(``PrefetchScalarGridSpec``), so the grid's K/V ``index_map`` can gather the
-b-th slot's i-th page directly out of the pool — the kernel never touches a
-dense ``[B, S_max]`` view, and pages of finished requests are simply never
-fetched.
+are pool page ids (``-1`` sentinel pads unallocated blocks). The table and
+the per-slot positions ride as SCALAR-PREFETCH operands
+(``PrefetchScalarGridSpec``); the pools are handed over whole and stay in
+HBM (``memory_space=pl.ANY``).
 
-Grid is ``(B, num_blocks)`` with the block dim sequential: per slot a
-flash-style online softmax (exp2 domain, f32 stats in VMEM scratch —
-same scheme as flash_attention.py) streams the live pages, skipping blocks
-past ``positions[b] // page_size`` entirely and masking the tail of the
-last live page with ``token_pos <= positions[b]``. Sentinel entries clamp
-to page 0 — a reserved trash page the allocator never hands out — so the
-gather stays in-bounds for empty slots and the mask keeps the math right.
+The grid is one step a slot, whatever the table's width. Inside a step the
+kernel walks the slot's own live pages (``positions[b] // page_size + 1`` of
+them; none for a slot whose first table entry is the sentinel) in a
+``fori_loop`` over CHUNKS of several pages: each page of a chunk — a
+contiguous ``[H_kv, page_size, D]`` block of its pool — is fetched by the
+kernel's own ``make_async_copy`` into one of two VMEM buffers a pool, so
+chunk ``c + 1`` is in flight while chunk ``c`` is computed. Pages of a
+chunk past the live count are not fetched (their V rows in the buffer are
+zeroed, so whatever a buffer held before never reaches the sum); pages of
+finished requests and the rest of the table are never touched. The chunk
+width comes from the shapes alone (``_pages_per_chunk``): at least a full
+lane width of tokens, so the score tile is ``[H_q, >= 128]``.
+
+Per chunk a flash-style online softmax (exp2 domain, f32 statistics carried
+through the loop — same scheme as flash_attention.py) masks the tail of the
+last live page with ``token_pos <= positions[b]``. A dead slot runs no
+iteration, fetches nothing and yields a row of zeros, which the engine never
+reads.
 
 GQA runs as a static per-KV-head-group loop: each group is a
-``[rep, D] x [D, page]`` dot, so K/V are read once per group instead of
-being materialized at query-head width.
+``[rep, D] x [D, chunk_tokens]`` dot, so K/V are read once per group instead
+of being materialized at query-head width.
 
 Numerics mirror ``serving.kv_cache.decode_attend`` (the oracle): q
 pre-scaled in its own dtype, f32 scores/softmax, output cast to v's dtype —
@@ -43,66 +52,114 @@ from .flash_attention import LANES, LOG2E, NEG_INF
 from .mesh import shard_kernel
 
 
-def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, num_blocks: int, page_size: int,
-                   num_kv_heads: int, rep: int):
-    """Grid (B, num_blocks): pages STREAM through the trailing (sequential)
-    dim; running (max, sum, acc) live in VMEM scratch across page
-    iterations and the epilogue normalizes on the last block. Blocks at or
-    past the slot's live count contribute nothing and are skipped whole."""
+# Both buffers of both pools have to sit well inside Mosaic's scoped VMEM
+# (16 MiB on the smallest generation this runs on).
+_CHUNK_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def _pages_per_chunk(num_kv_heads: int, page_size: int, head_dim: int,
+                     itemsize: int) -> int:
+    """Pages fetched and computed together: enough for a full lane width of
+    tokens, fewer only where four buffers of that many pages (two a pool)
+    would not fit the VMEM budget."""
+    pages = -(-LANES // page_size)
+    page_bytes = num_kv_heads * page_size * head_dim * itemsize
+    return max(1, min(pages, _CHUNK_VMEM_BYTES // (4 * page_bytes)))
+
+
+def _decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, *, num_blocks: int, page_size: int,
+                   num_kv_heads: int, rep: int, chunk: int):
+    """Grid (B,): one step a slot. ``k_buf`` / ``v_buf`` are
+    ``[2, chunk, H_kv, page_size, D]`` VMEM buffers, ``sems`` ``[2, 2]`` DMA
+    semaphores (pool, buffer); the running (max, sum, acc) are the loop's
+    carry."""
     b = pl.program_id(0)
-    i = pl.program_id(1)
     pos = pos_ref[b]
     # pages [0, pos // page_size] hold written tokens (position pos is
-    # written before the attend — see paged_write_kv)
-    live_hi = pos // jnp.int32(page_size) + 1
+    # written before the attend — see paged_write_kv); a slot with no first
+    # page is dead
+    live = jnp.where(tbl_ref[b, 0] < 0, 0,
+                     jnp.minimum(pos // page_size + 1, num_blocks))
+    chunk_tokens = chunk * page_size
+    Hq, D = q_ref.shape[1:]
 
-    @pl.when(i == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def page_copies(i, buf, j):
+        # a sentinel inside the live range clamps to the reserved trash
+        # page, so the fetch stays in-bounds whatever the table holds
+        page = jnp.maximum(tbl_ref[b, i], 0)
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, j],
+                                      sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, j],
+                                      sems.at[1, buf]))
 
-    @pl.when(i < live_hi)
-    def _compute():
-        q = q_ref[0]  # [Hq, D], pre-scaled by 1/sqrt(D) in q's dtype
-        k = k_ref[0]  # [Hkv, page_size, D]
-        v = v_ref[0]
-        # GQA: one [rep, D] x [D, page] dot per KV-head group — K is read
-        # at its stored width, never expanded to Hq
-        s_groups = [
+    def fetch(c, buf):
+        for j in range(chunk):
+            i = c * chunk + j
+
+            @pl.when(i < live)
+            def _start():
+                for copy in page_copies(i, buf, j):
+                    copy.start()
+
+            @pl.when(i >= live)
+            def _blank():
+                # never fetched: p is exactly 0 there, but 0 x whatever the
+                # buffer held (it starts uninitialised) need not be
+                v_buf[buf, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+
+    def wait(c, buf):
+        for j in range(chunk):
+            @pl.when(c * chunk + j < live)
+            def _wait():
+                for copy in page_copies(c * chunk + j, buf, j):
+                    copy.wait()
+
+    q = q_ref[0]  # [Hq, D], pre-scaled by 1/sqrt(D) in q's dtype
+
+    def body(c, carry):
+        m, l, acc = carry
+        buf = c % 2
+
+        @pl.when((c + 1) * chunk < live)
+        def _prefetch():
+            fetch(c + 1, 1 - buf)
+
+        wait(c, buf)
+        # GQA: one [rep, D] x [D, chunk_tokens] dot per KV-head group — K is
+        # read at its stored width, never expanded to Hq
+        s = jnp.concatenate([
             jax.lax.dot_general(
-                q[g * rep:(g + 1) * rep], k[g], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                q[g * rep:(g + 1) * rep],
+                k_buf[buf, :, g].reshape(chunk_tokens, D),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             for g in range(num_kv_heads)
-        ]
-        s = jnp.concatenate(s_groups, axis=0) * jnp.float32(LOG2E)
-        Hq = s.shape[0]
-        tok = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (Hq, page_size), 1)
-        s = jnp.where(tok <= pos, s, NEG_INF)  # [Hq, page_size], log2-domain
-        m = m_scr[:, 0]
-        l = l_scr[:, 0]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp2(s - m_new[:, None])
+        ], axis=0) * jnp.float32(LOG2E)
+        tok = c * chunk_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (Hq, chunk_tokens), 1)
+        s = jnp.where(tok <= pos, s, NEG_INF)  # [Hq, chunk_tokens], log2
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pv = jnp.concatenate([
             jax.lax.dot_general(
-                p[g * rep:(g + 1) * rep].astype(v.dtype), v[g],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                p[g * rep:(g + 1) * rep].astype(v_buf.dtype),
+                v_buf[buf, :, g].reshape(chunk_tokens, D),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
             for g in range(num_kv_heads)
         ], axis=0)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
-        m_scr[...] = jax.lax.broadcast_in_dim(m_new, m_scr.shape, (0,))
-        l_scr[...] = jax.lax.broadcast_in_dim(l_new, l_scr.shape, (0,))
+        return m_new, l_new, acc * alpha + pv
 
-    @pl.when(i == num_blocks - 1)
-    def _epilogue():
-        l = l_scr[:, 0]
-        l_safe = jnp.where(l == 0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
+    @pl.when(live > 0)
+    def _first():
+        fetch(0, 0)
+
+    _, l, acc = jax.lax.fori_loop(
+        0, (live + chunk - 1) // chunk, body,
+        (jnp.full((Hq, 1), NEG_INF, jnp.float32),
+         jnp.zeros((Hq, 1), jnp.float32), jnp.zeros((Hq, D), jnp.float32)))
+    o_ref[0] = (acc / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, page_table, positions):
@@ -132,44 +189,44 @@ def paged_attention(q, k_pool, v_pool, page_table, positions):
         pos = jnp.broadcast_to(pos, (B,))
     mp = dict(jax.sharding.get_abstract_mesh().shape).get("mp", 1)
     heads = P(None, "mp") if Hkv % mp == 0 else P()
-    out = shard_kernel(_decode_call, (table, pos, qs, k_pool, v_pool),
+    call = functools.partial(_decode_call, interpret=pallas_interpret())
+    out = shard_kernel(call, (table, pos, qs, k_pool, v_pool),
                        (P(), P(), heads, heads, heads), lambda f: f[2])
     return out[:, :, None, :]
 
 
-def _decode_call(table, pos, qs, k_pool, v_pool):
+# jitted so that a model's layers, which call it with the same shapes, share
+# ONE trace and ONE Mosaic lowering in the program they are traced into;
+# ``interpret`` is the caller's reading of the platform, so it is part of the
+# cache's key
+@functools.partial(jax.jit, static_argnames="interpret")
+def _decode_call(table, pos, qs, k_pool, v_pool, *, interpret: bool):
     B, Hq, D = qs.shape
     _, Hkv, page_size, _ = k_pool.shape
-    num_blocks = table.shape[1]
-    rep = Hq // Hkv
-
-    def _page_map(b, i, tbl, _pos):
-        # sentinel entries clamp to the reserved trash page so the fetch
-        # stays in-bounds; the live_hi bound keeps them out of the math
-        return (jnp.maximum(tbl[b, i], 0), 0, 0, 0)
-
+    chunk = _pages_per_chunk(Hkv, page_size, D, k_pool.dtype.itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, num_blocks),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, i, tbl, _pos: (b, 0, 0)),
-            pl.BlockSpec((1, Hkv, page_size, D), _page_map),
-            pl.BlockSpec((1, Hkv, page_size, D), _page_map),
+            pl.BlockSpec((1, Hq, D), lambda b, _tbl, _pos: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, i, tbl, _pos: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, D), lambda b, _tbl, _pos: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Hq, LANES), jnp.float32),
-            pltpu.VMEM((Hq, LANES), jnp.float32),
-            pltpu.VMEM((Hq, D), jnp.float32),
+            pltpu.VMEM((2, chunk, Hkv, page_size, D), k_pool.dtype),
+            pltpu.VMEM((2, chunk, Hkv, page_size, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_decode_kernel, num_blocks=num_blocks,
-                          page_size=page_size, num_kv_heads=Hkv, rep=rep),
+        functools.partial(_decode_kernel, num_blocks=table.shape[1],
+                          page_size=page_size, num_kv_heads=Hkv,
+                          rep=Hq // Hkv, chunk=chunk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), v_pool.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=pallas_interpret(),
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
         name="paged_decode",
     )(table, pos, qs, k_pool, v_pool)

@@ -1020,6 +1020,12 @@ class Engine:
             sp.set(ctx_tokens=int(ctx.sum()),
                    selected_tokens=int((ctx if sel is None
                                         else sel(ctx)).sum()))
+            if self.page_alloc is not None:
+                # pages the paged-decode kernel's loops walk (a layer) this
+                # step, of the table entries a grid over the table would
+                ps = self.cache.page_size
+                sp.set(live_pages=int(((ctx - 1) // ps + 1).sum()),
+                       table_pages=len(self._slots) * self.cache.num_blocks)
             with _span("serving/decode/upload") as up:
                 any_sampled = not bool(self._greedy.all())
                 key = _random.next_key() if any_sampled else _dummy_key()

@@ -285,7 +285,9 @@ def paged_decode_attend(q, k_pool, v_pool, page_table, positions,
     oracle; ``pallas`` runs the Pallas ragged kernel
     (kernels/paged_attention.py) which touches only live pages. Both tiers
     read the identical pool bytes, so they agree within float tolerance on
-    ragged batches, GQA, and empty slots (tests/test_paged_kv.py)."""
+    ragged batches and GQA; an empty slot's row, which no caller reads, is
+    the trash page's first token here and zeros there
+    (tests/test_paged_kv.py)."""
     impl = impl or default_paged_impl()
     if impl == "oracle":
         k = paged_gather(k_pool, page_table)

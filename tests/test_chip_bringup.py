@@ -164,6 +164,9 @@ out["adamw"] = sites(lambda p, g, m, v: fused_adamw_update(
     beta1_pow=.9, beta2_pow=.999), p, p, p, p)
 out["paged"] = sites(paged_attention, sds((4, 4, 1, 128)), sds((17, 2, 16, 128)),
                      sds((17, 2, 16, 128)), sds((4, 4), jnp.int32), sds((4,), jnp.int32))
+# the serving cells' own widths: 32 slots, 16 heads of 128, a [32, 128] table
+out["paged_1p3b"] = sites(paged_attention, sds((32, 16, 1, 128)), sds((1400, 16, 16, 128)),
+                          sds((1400, 16, 16, 128)), sds((32, 128), jnp.int32), sds((32,), jnp.int32))
 f32 = sds((64, 256), jnp.float32)
 out["prim"] = {**sites(primitive.elementwise_kernel(lambda a, b: a + 2 * b), f32, f32),
                **sites(primitive.row_reduce_kernel(lambda acc, t: acc + t.sum(-1), 0.0), f32)}
@@ -204,7 +207,7 @@ def test_kernels_and_train_step_compile_for_a_tpu_topology():
     assert out["layer_norm"] == {"layer_norm_fwd": 1}
     assert out["rms_norm"] == {"rms_norm_fwd": 1}
     assert out["adamw"] == {"fused_adamw": 1}
-    assert out["paged"] == {"paged_decode": 1}
+    assert out["paged"] == out["paged_1p3b"] == {"paged_decode": 1}
     assert out["prim"] == {"prim_elementwise": 1, "prim_row_reduce": 1}
     for key in ("step_1", "step_dp2mp2"):  # the mesh must not lose a kernel
         assert set(out[key]) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",

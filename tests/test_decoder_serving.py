@@ -451,3 +451,25 @@ class TestEngineProtocol:
                if e["name"] == "serving/decode" and "ctx_tokens" in e["attrs"]]
         assert dec[0]["ctx_tokens"] == dec[0]["selected_tokens"] == 4
         assert "experts_touched" not in dec[0]
+        # page 16: the one running slot's 4 tokens sit in 1 page of a
+        # 2-slot x 4-block table; at the 17th token a second page is live
+        assert [d["live_pages"] for d in dec] == [1, 1]
+        assert dec[0]["table_pages"] == 2 * 4
+
+    def test_decode_span_counts_live_pages_across_a_page_boundary(
+            self, telemetry):
+        from paddle_tpu.observability import tracing
+
+        gpt = gpt_tiny(dropout=0.0, num_layers=2)
+        gpt.eval()
+        tracing.clear_spans()
+        Engine(gpt, EngineConfig(max_batch_size=2, max_seq_len=64,
+                                 page_size=8)).generate(
+            [_ids(14, seed=1), _ids(3, seed=2)],
+            SamplingParams(max_new_tokens=4))
+        dec = [e["attrs"] for e in tracing.spans()
+               if e["name"] == "serving/decode" and "live_pages" in e["attrs"]]
+        # contexts 15 + 4, 16 + 5, 17 + 6 tokens at page 8: 2 + 1, 2 + 1,
+        # then 3 + 1 pages
+        assert [d["live_pages"] for d in dec] == [3, 3, 4]
+        assert {d["table_pages"] for d in dec} == {2 * 8}

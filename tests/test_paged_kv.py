@@ -178,10 +178,132 @@ class TestPagedPrimitives:
         want = kvc.paged_decode_attend(q, kp, vp, tbl, pos, impl="oracle")
         got = kvc.paged_decode_attend(q, kp, vp, tbl, pos, impl="pallas")
         assert got.shape == want.shape == (B, Hkv * rep, 1, D)
-        assert np.allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+        assert np.allclose(np.asarray(got)[:2], np.asarray(want)[:2],
+                           atol=1e-5)
+        # the empty slot: the oracle attends the trash page's first row,
+        # the kernel walks no page and writes zeros; the engine reads
+        # neither
+        assert not np.asarray(got)[2].any()
         # oracle == the dense decode_attend it wraps
         ref = kvc.decode_attend(q, kd, vd, pos)
         assert np.allclose(np.asarray(want), np.asarray(ref), atol=1e-6)
+
+    # positions in units of the kernel's chunk (``ct`` tokens); None = a
+    # dead slot (all-sentinel row, position 0)
+    _WALKS = {
+        "one_chunk": lambda ct, cap: [ct - 1],
+        "chunk_plus_one_token": lambda ct, cap: [ct],
+        "partial_last_chunk": lambda ct, cap: [2 * ct + ct // 2 + 1, 3],
+        "full_table_width": lambda ct, cap: [cap - 1, ct // 2],
+        "dead_between_live": lambda ct, cap: [ct + 5, None, 2 * ct - 1],
+    }
+
+    @staticmethod
+    def _walk_case(positions, *, Hkv, rep, ps, nb, D, dtype, seed=0,
+                   poison=False):
+        """Pools, a shuffled page table covering each slot's live pages,
+        and q for ``positions``. With ``poison`` every page outside the
+        live sets (the trash page too) is NaN in the pools handed to the
+        kernel; the returned clean pools are what the oracle reads."""
+        rng = np.random.RandomState(seed)
+        B = len(positions)
+        P = B * nb + 1
+        kp = rng.randn(P, Hkv, ps, D).astype(np.float32)
+        vp = rng.randn(P, Hkv, ps, D).astype(np.float32)
+        ids = 1 + rng.permutation(P - 1)
+        table = np.full((B, nb), kvc.PAGE_SENTINEL, np.int32)
+        pos = np.zeros(B, np.int32)
+        used = 0
+        for b, p in enumerate(positions):
+            if p is None:
+                continue
+            n = p // ps + 1
+            table[b, :n] = ids[used:used + n]
+            used += n
+            pos[b] = p
+        live = [b for b, p in enumerate(positions) if p is not None]
+        dead_pages = np.setdiff1d(np.arange(P), table[table >= 0])
+        kq, vq = kp.copy(), vp.copy()
+        if poison:
+            kq[dead_pages] = vq[dead_pages] = np.nan
+        q = jnp.asarray(rng.randn(B, Hkv * rep, 1, D), dtype)
+        as_ = lambda a: jnp.asarray(a, dtype)
+        return (q, as_(kq), as_(vq), as_(kp), as_(vp), jnp.asarray(table),
+                jnp.asarray(pos), live)
+
+    def _assert_walk_matches_oracle(self, case, tol):
+        q, kq, vq, kp, vp, tbl, pos, live = case
+        want = np.asarray(kvc.paged_decode_attend(
+            q, kp, vp, tbl, pos, impl="oracle"), np.float32)
+        got = np.asarray(kvc.paged_decode_attend(
+            q, kq, vq, tbl, pos, impl="pallas"), np.float32)
+        assert np.isfinite(got).all()
+        err = np.abs(got[live] - want[live]).max() / np.abs(want[live]).max()
+        assert err <= tol, err
+        dead = [b for b in range(q.shape[0]) if b not in live]
+        assert not got[dead].any()
+
+    @pytest.mark.parametrize("rep", [1, 2])
+    @pytest.mark.parametrize("walk", sorted(_WALKS))
+    def test_kernel_walk_matches_oracle(self, walk, rep):
+        """The in-kernel page walk against the oracle where its loop and
+        its copies can go wrong: a context of exactly one chunk, one chunk
+        plus a token, several chunks with a partial last one, the table's
+        full width, and a dead slot between two live ones."""
+        from paddle_tpu.kernels.paged_attention import _pages_per_chunk
+
+        Hkv, ps, D = 2, 16, 8
+        ct = _pages_per_chunk(Hkv, ps, D, 4) * ps
+        nb = 3 * ct // ps
+        positions = self._WALKS[walk](ct, nb * ps)
+        self._assert_walk_matches_oracle(self._walk_case(
+            positions, Hkv=Hkv, rep=rep, ps=ps, nb=nb, D=D,
+            dtype=jnp.float32), tol=1e-5)
+
+    @pytest.mark.parametrize("rep", [1, 2])
+    def test_kernel_walk_at_serving_tile_shapes(self, rep):
+        """Page 16, D 128, bf16 pools (the serving cells' tiles): a chunk
+        is 8 pages, a [H_q, 128] score tile; tolerance as chip_smoke's for
+        bf16."""
+        self._assert_walk_matches_oracle(self._walk_case(
+            [300, None, 127, 128, 15], Hkv=2, rep=rep, ps=16, nb=24, D=128,
+            dtype=jnp.bfloat16), tol=5e-2)
+
+    def test_kernel_reads_only_live_pages(self):
+        """Every pool page outside the slots' live sets is NaN (tails of
+        live pages stay finite, as the allocator leaves them): the output
+        is finite and the oracle's on the live rows, so no dead page, no
+        trash page and no stale buffer reached the sum."""
+        self._assert_walk_matches_oracle(self._walk_case(
+            [140, None, 3, 400, None, 256], Hkv=2, rep=2, ps=8, nb=56, D=8,
+            dtype=jnp.float32, poison=True), tol=1e-5)
+
+    @pytest.mark.parametrize("nb", [8, 128])
+    def test_kernel_grid_does_not_depend_on_table_width(self, nb):
+        """One grid step a slot, whatever the table's width: the page walk
+        is the kernel's own loop."""
+        import jax
+        from paddle_tpu.kernels.paged_attention import paged_attention
+
+        B, Hkv, ps, D = 4, 2, 16, 128
+        sds = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(paged_attention)(
+            sds((B, Hkv, 1, D), jnp.bfloat16),
+            sds((9, Hkv, ps, D), jnp.bfloat16),
+            sds((9, Hkv, ps, D), jnp.bfloat16),
+            sds((B, nb), jnp.int32), sds((B,), jnp.int32))
+
+        def pallas_calls(jaxpr):
+            for e in jaxpr.eqns:
+                if e.primitive.name == "pallas_call":
+                    yield e
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    yield from pallas_calls(sub)
+
+        calls = list(pallas_calls(jaxpr.jaxpr))
+        assert len(calls) == 1
+        assert "paged_decode" in str(calls[0].params["name"])
+        assert tuple(calls[0].params["grid_mapping"].grid) == (B,)
 
     def test_impl_dispatch_and_override(self):
         assert kvc.default_paged_impl() in ("oracle", "pallas")
