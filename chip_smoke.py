@@ -136,6 +136,7 @@ def leg_kernels(S: int = 2048, H: int = 16, D: int = 128, hidden: int = 2048,
     import paddle_tpu as paddle
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.nn import functional as F
+    from paddle_tpu.kernels.paged_attention import paged_attention
     from paddle_tpu.serving import kv_cache as kvc
 
     rng = np.random.RandomState(0)
@@ -227,10 +228,11 @@ def leg_kernels(S: int = 2048, H: int = 16, D: int = 128, hidden: int = 2048,
             n = pos[i] // ps + 1
             table[i, :n] = 1 + i * nb + np.arange(n)
         args = (qd, kp, vp, jnp.asarray(table), jnp.asarray(pos))
-        got = _compiled(lambda *a: kvc.paged_decode_attend(*a, impl="pallas"),
-                        args, "paged_decode")(*args)
-        want = _compiled(lambda *a: kvc.paged_decode_attend(*a, impl="oracle"),
-                         args, "paged_decode", present=False)(*args)
+        got = _compiled(paged_attention, args, "paged_decode")(*args)
+        want = _compiled(
+            lambda q, k, v, table, pos: kvc.decode_attend(
+                q, kvc.paged_gather(k, table), kvc.paged_gather(v, table),
+                pos), args, "paged_decode", present=False)(*args)
         errs["paged_decode"].append(close(got[live], want[live], tol))
         if np.delete(np.asarray(got, np.float32), live, axis=0).any():
             raise AssertionError("paged decode wrote into a dead slot's row")
@@ -443,6 +445,7 @@ def leg_server_exact(new_tokens: int = 24):
 
     import paddle_tpu as paddle
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import use_paged_attention_impl
 
     cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=2,
                     num_heads=2, max_seq_len=256, dropout=0.0,
@@ -452,12 +455,18 @@ def leg_server_exact(new_tokens: int = 24):
     prompts = _prompts(cfg.vocab_size, (40, 44, 9, 70), shared=32)
     envelope = dict(max_batch_size=4, max_seq_len=256)
     with jax.default_matmul_precision("highest"):
-        kernel, eng = _serve(model, prompts, new_tokens, cfg.vocab_size,
-                             **envelope, paged_attention_impl="pallas")
+        # the tier is baked in as a program is traced: each context holds
+        # an engine's construction and its first generate
+        with use_paged_attention_impl("pallas"):
+            kernel, eng = _serve(model, prompts, new_tokens, cfg.vocab_size,
+                                 **envelope)
         check(eng.kernel_sites[("decode",)].get("paged_decode", 0) >= 1,
               "small-model decode runs the compiled paged kernel")
-        oracle, _ = _serve(model, prompts, new_tokens, cfg.vocab_size,
-                           **envelope, paged_attention_impl="oracle")
+        with use_paged_attention_impl("oracle"):
+            oracle, eng = _serve(model, prompts, new_tokens, cfg.vocab_size,
+                                 **envelope)
+        check(not eng.kernel_sites[("decode",)].get("paged_decode", 0),
+              "the oracle engine's decode holds no paged kernel")
         spec, _ = _serve(model, prompts, new_tokens, cfg.vocab_size,
                          **envelope, prefix_cache=True, speculative=4)
     check(kernel == oracle == spec, "f32 greedy output token-identical: "

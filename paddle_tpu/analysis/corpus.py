@@ -150,12 +150,10 @@ def _serving_specs() -> List[ProgramSpec]:
     contract = SiteContract(one_compile=True,
                             donate_argnums=KV_DONATE_ARGNUMS,
                             donation_threshold=4096)
-    # the engine defaults to the block-paged KV layout: prefill scatters
-    # the prompt into the slot's pages (page_row replaces the dense slot
-    # index) and decode carries the [B, num_blocks] page table as runtime
-    # data — same one-compile + donation contract as the dense layout had.
-    # k_pages / v_pages are TUPLES of per-layer pools (one argname, one
-    # donated argnum, num_layers leaves each)
+    # prefill scatters the prompt into the pages of the slot's table row
+    # (page_row) and decode carries the [B, num_blocks] page table as
+    # runtime data. k_pages / v_pages are TUPLES of per-layer pools (one
+    # argname, one donated argnum, num_layers leaves each)
     pre_fn, pre_args = eng.prefill_program(8)
     dec_fn, dec_args = eng.decode_program()
     # speculative verify-k: the decode step widened to [B, k+1] — same

@@ -3,24 +3,27 @@
 Public surface:
 
 - :class:`Engine` / :class:`EngineConfig` — offline/online serving engine
-  with slot-based continuous batching over a preallocated KV cache.
+  with slot-based continuous batching over preallocated block-paged pools.
 - :class:`SamplingParams` — per-request decoding controls.
 - :class:`Request` / :class:`Scheduler` — FIFO queue + slot table.
 - :class:`RequestTracer` / :class:`SLOConfig` — per-request span traces
   (queue→prefill→decode→finish, ``requests-host*.jsonl``) and the SLO
   monitor (``serving.slo.violations{phase}``, flight-recorder forensics).
-- :class:`KVCache`, :func:`write_kv`, :func:`decode_attend` — the shared
-  static-cache write/attend primitives (also used by
-  ``incubate.nn.FusedMultiTransformer``'s ``time_step`` decode).
-- :class:`PagedKVCache` / :class:`PageAllocator` — the block-paged cache
-  (fixed-size pages + per-slot page table, the engine's default layout)
-  and the exact-cover free-list allocator the scheduler drives.
+- :class:`PagedKVCache` / :class:`PageAllocator` — the engine's cache
+  (fixed-size pages + per-slot page table) and the exact-cover free-list
+  allocator the scheduler drives.
 - :func:`paged_write_kv` / :func:`paged_gather` /
-  :func:`paged_decode_attend` — the paged twins of the primitives above;
-  :func:`use_paged_attention_impl` pins the attend tier
+  :func:`paged_decode_attend` — the write and attend over it. The attend
+  is the Pallas kernel on a TPU and the oracle elsewhere;
+  :func:`use_paged_attention_impl` is the tests' seam to pin the tier
   (``oracle`` | ``pallas``) for traces entered under it.
-- :func:`cached_generate` — the static-shape decode loop
-  ``models.gpt.GPTForCausalLM.generate`` delegates to.
+- :func:`write_kv`, :func:`decode_attend` — the dense
+  ``[B, H_kv, S_max, D]`` write/attend primitives: the paged attend's
+  oracle, and the lockstep decode of :func:`cached_generate` and
+  ``incubate.nn.FusedMultiTransformer``'s ``time_step``.
+- :func:`cached_generate` — the static-shape lockstep decode loop
+  ``models.gpt.GPTForCausalLM.generate`` delegates to (its own dense
+  buffers, not the engine).
 - :class:`PrefixCache` — radix trie from block-aligned token prefixes to
   physical page ids: cache-hit prompts splice shared (refcounted,
   copy-on-write) pages and prefill only their suffix
@@ -39,7 +42,6 @@ from __future__ import annotations
 from .engine import Engine, EngineConfig, cached_generate  # noqa: F401
 from .kv_cache import (  # noqa: F401
     PAGE_SENTINEL,
-    KVCache,
     PagedKVCache,
     decode_attend,
     extend_attend,
@@ -68,7 +70,6 @@ from .speculative import (  # noqa: F401
 __all__ = [
     "Engine",
     "EngineConfig",
-    "KVCache",
     "PAGE_SENTINEL",
     "PageAllocator",
     "PagedKVCache",

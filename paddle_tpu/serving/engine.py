@@ -1,21 +1,34 @@
 """TPU-native LLM serving engine: static-shape decode + continuous batching.
 
-The engine composes three static-shape compiled executables over a
-preallocated KV cache (kv_cache.KVCache):
+One path, drawn in four boxes whose arrows point one way:
 
-- **bucketed prefill** — one AOT-compiled executable per prompt-length
-  bucket (powers of two up to ``max_seq_len``): the padded prompt runs the
-  causal forward once, its K/V land in the request's cache slot, and the
-  last real token's logits come back for the first sampled token (TTFT).
-- **decode step** — ONE executable for the whole engine lifetime: a
-  ``[B_max]`` batch of single tokens with per-row positions scatters into
-  the cache and attends over each row's valid prefix. Per-request
-  SamplingParams ride as device arrays (sampling.sample_batched), so an
-  arbitrary mix of greedy/sampled requests never triggers a recompile.
-- **cached_generate** — the batch decode loop ``GPTForCausalLM.generate``
-  now delegates to: same API/semantics as the old grown-prefix loop, but
-  one prefill compile + one decode compile total (asserted via the
-  ``jit.compile.cache_miss{site=serving.*}`` observability counters).
+``Engine`` (host: scheduler, ``PageAllocator``, ``PrefixCache``, the page
+table) -> four static-shape programs ``(params, *pools, ...)`` over the
+block-paged pools of ``kv_cache.PagedKVCache`` -> the model's protocol
+(``prefill_with_cache`` / ``decode_step`` / ``extend_step`` on paged
+entries) -> ``kv_cache.paged_write_kv`` and the attend, where
+``kv_cache.default_paged_impl`` alone says kernel or oracle.
+
+- **prefill/T** — one AOT-compiled executable per prompt-length bucket
+  (powers of two up to ``max_seq_len``): the padded prompt runs the causal
+  forward once, its K/V land in the pages of the request's table row, and
+  the last real token's logits come back for the first sampled token (TTFT).
+- **extend/T** — the suffix prefill a prefix-cache hit runs instead: the
+  matched blocks' pages are spliced into the table row and only the rest
+  of the prompt flows through the forward.
+- **decode** — ONE executable for the whole engine lifetime: a ``[B_max]``
+  batch of single tokens with per-row positions writes into the pools and
+  attends over each row's live pages. Per-request SamplingParams ride as
+  device arrays (sampling.sample_batched), so an arbitrary mix of
+  greedy/sampled requests never triggers a recompile.
+- **verify** — decode widened to ``[B_max, k+1]``: what the engine's one
+  host decode step (``Engine._decode``) runs instead under speculation.
+
+``cached_generate`` is a different job and not a fifth box: the lockstep
+batch loop ``GPTForCausalLM.generate`` delegates to, over its own dense
+``[B, H_kv, S_max, D]`` buffers, one prefill compile + one decode compile
+total (asserted via the ``jit.compile.cache_miss{site=serving.*}``
+observability counters). The engine's tests use it as their reference.
 
 Everything is AOT-compiled (``jax.jit(fn).lower(...).compile()``): a shape
 drift raises instead of silently recompiling per token — the property the
@@ -43,8 +56,8 @@ from ..observability import memory as _obs_memory
 from ..observability import metrics as _metrics
 from ..observability.tracing import span as _span
 from . import sampling as _sampling
-from .kv_cache import (KVCache, PAGE_SENTINEL, PagedKVCache, paged_write_kv,
-                       use_paged_attention_impl)
+from .kv_cache import (PAGE_SENTINEL, PagedKVCache, _layer_buffers,
+                       paged_write_kv, write_kv)
 from .prefix_cache import PrefixCache
 from .request_trace import RequestTracer, SLOConfig
 from .sampling import SamplingParams
@@ -52,8 +65,8 @@ from .scheduler import FINISHED, PageAllocator, Request, Scheduler
 from .speculative import SpeculativeConfig, accept_greedy, propose_ngram
 
 #: every serving executable takes (params, *pools, ...), where each pool is
-#: a TUPLE of per-layer buffers (kv_cache.KVCache / PagedKVCache: ``k`` and
-#: ``v``, then whatever else the model declared), and returns the tuples of
+#: a TUPLE of per-layer buffers (kv_cache.PagedKVCache: ``k`` and ``v``,
+#: then whatever else the model declared), and returns the tuples of
 #: updated buffers its caller rebinds — so the pool args are donated at
 #: compile time (an argnum covers every leaf of its pytree). Layer ``l``'s
 #: program output is a scatter into layer ``l``'s donated parameter, which
@@ -128,13 +141,12 @@ def _write_prompt(write, pools, kvs):
                  for pool, entries in zip(pools, zip(*kvs)))
 
 
-def _write_prompt_dense(kc, vc, kvs, slot):
-    """Each layer's prompt K/V ``[1 or B, Hkv, T, D]`` into that layer's
-    dense buffer at batch row ``slot``, positions ``[0, T)``."""
-    zero = jnp.zeros((), jnp.int32)
-    return _write_prompt(
-        lambda c, new: lax.dynamic_update_slice(
-            c, new.astype(c.dtype), (slot, zero, zero, zero)), (kc, vc), kvs)
+def _write_prompt_dense(kc, vc, kvs):
+    """Each layer's prompt K/V ``[B, Hkv, T, D]`` into that layer's dense
+    ``[B, Hkv, S_max, D]`` buffer (``cached_generate``'s), positions
+    ``[0, T)``."""
+    return _write_prompt(lambda c, new: write_kv(c, new, jnp.int32(0)),
+                         (kc, vc), kvs)
 
 
 def _write_prompt_paged(pools, kvs, page_row):
@@ -183,9 +195,9 @@ def cached_generate(model, input_ids, *, max_new_tokens: int = 32,
     S_max = S + max_new_tokens
     params, _ = model.functional_state()
     dt = _param_dtype(params)
-    cache = KVCache(cfg.num_layers, B, cfg.num_kv_heads, S_max,
-                    cfg.head_dim, dt)
-    kc, vc = cache.k, cache.v  # per-layer buffer tuples
+    shape = (B, cfg.num_kv_heads, S_max, cfg.head_dim)
+    kc = _layer_buffers(cfg.num_layers, shape, dt)
+    vc = _layer_buffers(cfg.num_layers, shape, dt)
 
     exe_cache = _GEN_EXE_CACHE.setdefault(model, {})
     tok_dtype = idsv.dtype
@@ -194,7 +206,7 @@ def cached_generate(model, input_ids, *, max_new_tokens: int = 32,
         with no_grad():
             (logits, kvs), _ = model.functional_call(
                 p, {}, Tensor(ids), method="prefill_with_cache")
-        kc, vc = _write_prompt_dense(kc, vc, kvs, jnp.zeros((), jnp.int32))
+        kc, vc = _write_prompt_dense(kc, vc, kvs)
         return logits._value, kc, vc
 
     pkey = ("prefill", B, S, S_max, str(tok_dtype), str(dt))
@@ -265,35 +277,24 @@ class EngineConfig:
     request_trace_dir: Optional[str] = None
     trace_sample_every: int = 1
     slo: Optional["SLOConfig"] = None
-    # KV cache layout: "paged" (default) stores K/V in fixed-size pages
-    # routed by a per-slot page table, so HBM scales with LIVE tokens and a
-    # smaller ``kv_pages`` pool serves the same (B_max, S_max) envelope;
-    # "dense" keeps the legacy [B_max, H_kv, S_max, D] block per layer for
-    # A/B.
-    kv_layout: str = "paged"
+    # K/V live in fixed-size pages routed by a per-slot page table, so HBM
+    # scales with LIVE tokens and a smaller ``kv_pages`` pool serves the
+    # same (B_max, S_max) envelope
     page_size: int = 16          # tokens per KV page (shrunk to divide S_max)
     kv_pages: Optional[int] = None  # pool size; default = full budget + trash
-    # paged-attend tier override for tests ("oracle"|"pallas");
-    # None = pick by platform (kv_cache.default_paged_impl)
-    paged_attention_impl: Optional[str] = None
     # radix prefix cache (prefix_cache.py): finished prompts' full KV
     # blocks stay indexed by token content, and a new request whose prompt
     # shares a block-aligned prefix splices the SAME physical pages into
     # its table (refcounted, copy-on-write) and prefills only the suffix.
-    # Requires the paged layout.
     prefix_cache: bool = False
     # speculative decoding (speculative.py): True / an int k / a
     # SpeculativeConfig. When on, the engine's decode step is the verify-k
     # program — [B, k+1] static shape, compiled ONCE at construction — fed
     # by the n-gram draft proposer; greedy rows emit up to k+1 tokens per
-    # step with output identical to one-at-a-time greedy decode. Requires
-    # the paged layout.
+    # step with output identical to one-at-a-time greedy decode.
     speculative: Optional[Union[bool, int, "SpeculativeConfig"]] = None
 
     def __post_init__(self):
-        if self.kv_layout not in ("paged", "dense"):
-            raise ValueError(f"kv_layout {self.kv_layout!r}; "
-                             "want 'paged' or 'dense'")
         if isinstance(self.speculative, bool):
             self.speculative = SpeculativeConfig() if self.speculative else None
         elif isinstance(self.speculative, int):
@@ -303,12 +304,6 @@ class EngineConfig:
             raise ValueError(
                 f"speculative={self.speculative!r}; want True, an int k, or "
                 "a SpeculativeConfig")
-        if ((self.prefix_cache or self.speculative is not None)
-                and self.kv_layout != "paged"):
-            raise ValueError(
-                "prefix_cache / speculative require kv_layout='paged' "
-                "(page-table splices and trash-routed draft writes have no "
-                "dense equivalent)")
         while self.page_size > 1 and self.max_seq_len % self.page_size:
             self.page_size //= 2
         if self.prefill_buckets is None:
@@ -375,23 +370,14 @@ class Engine:
         dt = (self.config.cache_dtype if self.config.cache_dtype is not None
               else _param_dtype(self.params))
         B, S_max = self.config.max_batch_size, self.config.max_seq_len
-        if self.config.kv_layout == "paged":
-            ps = self.config.page_size
-            num_pages = self.config.kv_pages
-            if num_pages is None:
-                num_pages = B * (S_max // ps) + 1  # full budget + trash page
-            self.cache = PagedKVCache(cfg.num_layers, B, pools[0][1],
-                                      S_max, pools[0][2], dt, page_size=ps,
-                                      num_pages=num_pages, pools=pools)
-            self.page_alloc: Optional[PageAllocator] = PageAllocator(num_pages)
-        else:
-            if len(pools) != 2:
-                raise ValueError(
-                    f"the dense layout holds K and V only; this model "
-                    f"declares {[n for n, _, _ in pools]}")
-            self.cache = KVCache(cfg.num_layers, B, pools[0][1], S_max,
-                                 pools[0][2], dt)
-            self.page_alloc = None
+        ps = self.config.page_size
+        num_pages = self.config.kv_pages
+        if num_pages is None:
+            num_pages = B * (S_max // ps) + 1  # full budget + trash page
+        self.cache = PagedKVCache(cfg.num_layers, B, pools[0][1], S_max,
+                                  pools[0][2], dt, page_size=ps,
+                                  num_pages=num_pages, pools=pools)
+        self.page_alloc = PageAllocator(num_pages)
         _metrics.gauge("serving.kv_cache.bytes", self.cache.nbytes)
         _obs_memory.record_kv_cache(self.cache.nbytes)
         self.scheduler = Scheduler(B)
@@ -530,95 +516,65 @@ class Engine:
         """(fn, example_args) for the T-token prefill bucket — the pure
         program ``_prefill_exe`` compiles, exposed so the static analyzer
         (paddle_tpu.analysis) can trace it without compiling/executing.
-        The KV-cache args (positions ``KV_DONATE_ARGNUMS``) are donated at
+        The pool args (positions ``self.donate_argnums``) are donated at
         compile; callers must rebind from the outputs.
 
-        Paged layout: the slot's table row (``page_row [num_blocks]``
-        int32, runtime data) replaces the dense slot index — each layer's
-        prompt K/V lands in that layer's pools as one scatter of the
-        bucket's pages (``_write_prompt_paged``; the bucket tail past the
-        allocated pages clamps to the trash page, exactly like bucket
-        padding wrote garbage past ``length`` in the dense layout)."""
+        The slot's table row (``page_row [num_blocks]`` int32, runtime
+        data) says where: each layer's prompt K/V lands in that layer's
+        pools as one scatter of the bucket's pages (``_write_prompt_paged``;
+        the bucket tail past the allocated pages clamps to the trash
+        page)."""
         model, n = self.model, len(self.cache.pools)
-        if self.config.kv_layout == "paged":
-            nb = self.cache.num_blocks
-
-            @jax.named_scope("serving/prefill")
-            def paged_prefill_fn(p, *a):
-                pools, (ids, page_row, length) = a[:n], a[n:]
-                logits, kvs, _ = _call(model, p, "prefill_with_cache",
-                                       Tensor(ids),
-                                       lengths=Tensor(length[None]))
-                return (logits,) + _write_prompt_paged(pools, kvs, page_row)
-
-            args = (self.params, *self.cache.pools,
-                    jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
-                    jnp.int32(1))
-            return paged_prefill_fn, args
+        nb = self.cache.num_blocks
 
         @jax.named_scope("serving/prefill")
-        def prefill_fn(p, kc, vc, ids, slot, length):
+        def paged_prefill_fn(p, *a):
+            pools, (ids, page_row, length) = a[:n], a[n:]
             logits, kvs, _ = _call(model, p, "prefill_with_cache",
-                                   Tensor(ids), lengths=Tensor(length[None]))
-            return (logits,) + _write_prompt_dense(kc, vc, kvs, slot)
+                                   Tensor(ids),
+                                   lengths=Tensor(length[None]))
+            return (logits,) + _write_prompt_paged(pools, kvs, page_row)
 
-        args = (self.params, self.cache.k, self.cache.v,
-                jnp.zeros((1, T), jnp.int32), jnp.int32(0), jnp.int32(1))
-        return prefill_fn, args
+        args = (self.params, *self.cache.pools,
+                jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
+                jnp.int32(1))
+        return paged_prefill_fn, args
 
     def decode_program(self):
         """(fn, example_args) for the batched decode step — see
         ``prefill_program`` for the donation contract.
 
-        Paged layout: the page table rides as one extra ``[B, num_blocks]``
-        int32 operand. Its CONTENTS change every admission/finish but the
-        shape never does — the decode executable stays ONE compile for the
-        engine lifetime (tests pin the compile counter), and the paged
-        attend gathers each slot's live pages out of the pools."""
+        The page table rides as one ``[B, num_blocks]`` int32 operand. Its
+        CONTENTS change every admission/finish but the shape never does —
+        the decode executable stays ONE compile for the engine lifetime
+        (tests pin the compile counter), and the paged attend reads each
+        slot's live pages out of the pools."""
         model, cache = self.model, self.cache
-        if self.config.kv_layout == "paged":
-            B, nb = self.config.max_batch_size, self.cache.num_blocks
-            n = len(cache.pools)
-
-            @jax.named_scope("serving/decode")
-            def paged_decode_fn(p, *a):
-                pools = a[:n]
-                page_table, tokens, positions, temps, top_ks, greedy, key = \
-                    a[n:]
-                logits, new, stats = _call(
-                    model, p, "decode_step", Tensor(tokens),
-                    cache.layer_entries(pools, page_table), Tensor(positions))
-                nxt = _sampling.sample_batched(logits, key, temps, top_ks,
-                                               greedy).astype(jnp.int32)
-                if stats is not None:
-                    # the step's statistics ride behind the tokens, in the
-                    # one array the host fetches
-                    nxt = jnp.concatenate(
-                        [nxt, stats.astype(jnp.int32).reshape(-1)])
-                return (nxt,) + _updated(new)
-
-            args = (self.params, *self.cache.pools,
-                    jnp.zeros((B, nb), jnp.int32),
-                    jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                    jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-                    jnp.ones((B,), bool), _dummy_key())
-            return paged_decode_fn, args
+        B, nb = self.config.max_batch_size, self.cache.num_blocks
+        n = len(cache.pools)
 
         @jax.named_scope("serving/decode")
-        def decode_fn(p, kc, vc, tokens, positions, temps, top_ks, greedy,
-                      key):
-            logits, new, _ = _call(model, p, "decode_step", Tensor(tokens),
-                                   cache.layer_caches(kc, vc),
-                                   Tensor(positions))
-            nxt = _sampling.sample_batched(logits, key, temps, top_ks, greedy)
-            return (nxt.astype(jnp.int32),) + _updated(new)
+        def paged_decode_fn(p, *a):
+            pools = a[:n]
+            page_table, tokens, positions, temps, top_ks, greedy, key = a[n:]
+            logits, new, stats = _call(
+                model, p, "decode_step", Tensor(tokens),
+                cache.layer_entries(pools, page_table), Tensor(positions))
+            nxt = _sampling.sample_batched(logits, key, temps, top_ks,
+                                           greedy).astype(jnp.int32)
+            if stats is not None:
+                # the step's statistics ride behind the tokens, in the one
+                # array the host fetches
+                nxt = jnp.concatenate(
+                    [nxt, stats.astype(jnp.int32).reshape(-1)])
+            return (nxt,) + _updated(new)
 
-        B = self.config.max_batch_size
-        args = (self.params, self.cache.k, self.cache.v,
+        args = (self.params, *self.cache.pools,
+                jnp.zeros((B, nb), jnp.int32),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                 jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
                 jnp.ones((B,), bool), _dummy_key())
-        return decode_fn, args
+        return paged_decode_fn, args
 
     def extend_program(self, T: int):
         """(fn, example_args) for the T-token suffix prefill a prefix-cache
@@ -628,10 +584,7 @@ class Engine:
         positions ``start..start+T-1`` through the SAME page-table routing
         as decode (bucket padding past the allocated pages lands on the
         trash page), attention covers cached prefix + suffix, and the last
-        real suffix token's logits come back for the first sampled token.
-        Paged layout only."""
-        if self.config.kv_layout != "paged":
-            raise ValueError("extend_program requires kv_layout='paged'")
+        real suffix token's logits come back for the first sampled token."""
         model, cache = self.model, self.cache
         nb, n = self.cache.num_blocks, len(self.cache.pools)
 
@@ -667,8 +620,6 @@ class Engine:
         ``k`` defaults to the engine's SpeculativeConfig; passing it
         explicitly lets the analyzer trace the program on an engine without
         speculation enabled (analysis/corpus.py's serving_verify entry)."""
-        if self.config.kv_layout != "paged":
-            raise ValueError("verify_program requires kv_layout='paged'")
         if k is None:
             if self.spec is None:
                 raise ValueError("verify_program(k=None) needs "
@@ -703,10 +654,9 @@ class Engine:
         the engine serves from device-local state, so every argument and
         every output must stay fully replicated — if sharding ever leaks
         into a serving program (a partitioned param tree wired in without
-        a serving-side mesh plan), spmd-contract-mismatch trips. Covers
-        both layouts: the paged programs' page pools and page table are
-        device-local replicated state exactly like the dense caches
-        (``nargs`` follows whichever program signature is active)."""
+        a serving-side mesh plan), spmd-contract-mismatch trips. The page
+        pools and the page table are device-local replicated state like
+        the rest (``nargs`` is the program's own count)."""
         from ..analysis.sharding_flow import ShardingContract
         from jax.sharding import PartitionSpec as P
 
@@ -730,26 +680,21 @@ class Engine:
 
     def _decode_exe(self):
         decode_fn, args = self.decode_program()
-        # the paged-attend tier is baked in while tracing (compiled
-        # executables never re-dispatch); no-op for the dense layout
-        with use_paged_attention_impl(self.config.paged_attention_impl):
-            return _aot(self._exe, ("decode",), "serving.decode", decode_fn,
-                        args, donate_argnums=self.donate_argnums)
+        return _aot(self._exe, ("decode",), "serving.decode", decode_fn,
+                    args, donate_argnums=self.donate_argnums)
 
     def _extend_exe(self, T: int):
         extend_fn, args = self.extend_program(T)
-        with use_paged_attention_impl(self.config.paged_attention_impl):
-            return _aot(self._exe, ("extend", T), "serving.prefill",
-                        extend_fn, args, donate_argnums=self.donate_argnums)
+        return _aot(self._exe, ("extend", T), "serving.prefill",
+                    extend_fn, args, donate_argnums=self.donate_argnums)
 
     def _verify_exe(self):
         verify_fn, args = self.verify_program()
         # the verify program REPLACES the plain decode step while
         # speculation is on, so it accounts under the same serving.decode
         # site — the one-compile-per-lifetime counter covers both modes
-        with use_paged_attention_impl(self.config.paged_attention_impl):
-            return _aot(self._exe, ("verify",), "serving.decode", verify_fn,
-                        args, donate_argnums=self.donate_argnums)
+        return _aot(self._exe, ("verify",), "serving.decode", verify_fn,
+                    args, donate_argnums=self.donate_argnums)
 
     def compile_programs(self, prefill: Sequence[int] = (),
                          extend: Sequence[int] = ()) -> List[Tuple]:
@@ -781,16 +726,14 @@ class Engine:
             warnings.filterwarnings(    # as in _aot
                 "ignore", message=".*donated buffers.*", category=UserWarning)
             todo, done = [], []
-            with use_paged_attention_impl(self.config.paged_attention_impl):
-                for key in keys:
-                    t0 = time.perf_counter()
-                    fn, args = getattr(self, key[0] + "_program")(*key[1:])
-                    site = ("serving.decode" if key[0] in ("decode", "verify")
-                            else "serving.prefill")
-                    lowered = jax.jit(
-                        fn, donate_argnums=self.donate_argnums).lower(*args)
-                    todo.append((key, site, lowered,
-                                 time.perf_counter() - t0))
+            for key in keys:
+                t0 = time.perf_counter()
+                fn, args = getattr(self, key[0] + "_program")(*key[1:])
+                site = ("serving.decode" if key[0] in ("decode", "verify")
+                        else "serving.prefill")
+                lowered = jax.jit(
+                    fn, donate_argnums=self.donate_argnums).lower(*args)
+                todo.append((key, site, lowered, time.perf_counter() - t0))
             if todo:
                 with ThreadPoolExecutor(len(todo)) as pool:
                     done = list(pool.map(compile_one, todo))
@@ -810,10 +753,9 @@ class Engine:
         (each emits its first token)."""
         admitted = 0
         while self.cache.free_slots and self.scheduler.waiting:
-            # PEEK before committing: paged admission can backpressure on
-            # the page pool, leaving the head request queued until a finish
-            # frees pages (dense admission never backpressures — a free
-            # slot IS the whole reservation)
+            # PEEK before committing: admission can backpressure on the
+            # page pool, leaving the head request queued until a finish
+            # frees pages
             if not self._admit_one(self.scheduler.waiting[0]):
                 break
             admitted += 1
@@ -836,33 +778,29 @@ class Engine:
                         self.prefix_cache.match(req.prompt_ids)
             with _span("serving/admit/alloc",
                        request_id=req.request_id) as alloc:
-                pages, evicted = None, 0
-                if self.page_alloc is not None:
-                    need = self._pages_needed(n) - hit_blocks
+                evicted = 0
+                need = self._pages_needed(n) - hit_blocks
+                pages = self.page_alloc.alloc(need, owner=owner)
+                if pages is None and self.prefix_cache is not None:
+                    # pool short: reclaim cold cached prefixes, retry
+                    evicted = self.prefix_cache.evict_lru(need)
                     pages = self.page_alloc.alloc(need, owner=owner)
-                    if pages is None and self.prefix_cache is not None:
-                        # pool short: reclaim cold cached prefixes, retry
-                        evicted = self.prefix_cache.evict_lru(need)
-                        pages = self.page_alloc.alloc(need, owner=owner)
-                    if pages is None:
-                        alloc.set(pages=0, evicted=evicted, blocked=1)
-                        adm.set(blocked=1)
-                        return False
+                if pages is None:
+                    alloc.set(pages=0, evicted=evicted, blocked=1)
+                    adm.set(blocked=1)
+                    return False
                 self.scheduler.next_waiting()  # pops the peeked head
                 slot = self.cache.alloc_slot()
                 req.slot = slot
-                if pages is not None:
-                    if hit_pages:
-                        # the SPLICE: this request becomes one more sharer
-                        # of the matched blocks' physical pages — a refcount
-                        # bump and a table-row write, no device work for the
-                        # prefix
-                        self.page_alloc.retain(hit_pages, owner=owner)
-                        self.cache.assign_pages(slot, hit_pages)
-                        req.prefix_hit_blocks = hit_blocks
-                    self.cache.assign_pages(slot, pages,
-                                            start_block=hit_blocks)
-                    alloc.set(pages=len(pages), evicted=evicted)
+                if hit_pages:
+                    # the SPLICE: this request becomes one more sharer of
+                    # the matched blocks' physical pages — a refcount bump
+                    # and a table-row write, no device work for the prefix
+                    self.page_alloc.retain(hit_pages, owner=owner)
+                    self.cache.assign_pages(slot, hit_pages)
+                    req.prefix_hit_blocks = hit_blocks
+                self.cache.assign_pages(slot, pages, start_block=hit_blocks)
+                alloc.set(pages=len(pages), evicted=evicted)
             adm.set(queued_s=req.admit_time - req.arrival_time,
                     hit_blocks=hit_blocks)
             if self.prefix_cache is not None:
@@ -873,7 +811,7 @@ class Engine:
                 else:
                     _metrics.counter("serving.prefix.misses", 1)
             sp = req.sampling
-            ps = self.cache.page_size if self.page_alloc is not None else 0
+            ps = self.cache.page_size
             if hit_blocks:
                 # suffix-only prefill through the bucketed extend program
                 # (>= 1 token by construction: matching is capped at
@@ -897,12 +835,10 @@ class Engine:
                     ids = np.zeros((1, T), np.int32)
                     ids[0, :n] = req.prompt_ids
                     exe = self._prefill_exe(T)
-                    where = (jnp.asarray(self.cache.page_table[slot])
-                             if self.page_alloc is not None
-                             else jnp.int32(slot))
                     logits, *self.cache.pools = exe(
                         self.params, *self.cache.pools, jnp.asarray(ids),
-                        where, jnp.int32(n))
+                        jnp.asarray(self.cache.page_table[slot]),
+                        jnp.int32(n))
             with _span("serving/admit/sample", request_id=req.request_id):
                 if self.prefix_cache is not None:
                     # index this prompt's FULL blocks (shared ones are
@@ -1001,85 +937,21 @@ class Engine:
 
     def _decode(self) -> int:
         """One batched decode step, as one ``serving/decode`` span over its
-        phases; returns the tokens emitted."""
-        if self.spec is not None:
-            return self._decode_speculative()
-        with _span("serving/decode", step=self._step_i) as sp:
-            if self.page_alloc is not None:
-                self._grow_pages()
-            running = [s.request for s in self._slots
-                       if s.request is not None]
-            sp.set(running=len(running))
-            if not running:
-                return 0
-            # cached tokens the step's attention may read (each running
-            # slot's context, the token it writes included) and how many of
-            # them it does read, where the model selects
-            ctx = self._positions[[r.slot for r in running]] + 1
-            sel = getattr(self.model, "selected_tokens", None)
-            sp.set(ctx_tokens=int(ctx.sum()),
-                   selected_tokens=int((ctx if sel is None
-                                        else sel(ctx)).sum()))
-            if self.page_alloc is not None:
-                # pages the paged-decode kernel's loops walk (a layer) this
-                # step, of the table entries a grid over the table would
-                ps = self.cache.page_size
-                sp.set(live_pages=int(((ctx - 1) // ps + 1).sum()),
-                       table_pages=len(self._slots) * self.cache.num_blocks)
-            with _span("serving/decode/upload") as up:
-                any_sampled = not bool(self._greedy.all())
-                key = _random.next_key() if any_sampled else _dummy_key()
-                table = ((self.cache.table_device(),)
-                         if self.page_alloc is not None else ())
-                args = table + (
-                    jnp.asarray(self._tokens), jnp.asarray(self._positions),
-                    jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                    jnp.asarray(self._greedy), key)
-            with _span("serving/decode/dispatch") as disp:
-                exe = self._decode_exe()
-                nxt, *self.cache.pools = exe(
-                    self.params, *self.cache.pools, *args)
-            with _span("serving/decode/fetch") as fetch:
-                nxt = np.asarray(nxt)
-            if nxt.shape[0] > len(self._slots):
-                # a model that counts in its step (decoder.DecoderLM: per
-                # layer the distinct experts routed to, the largest
-                # expert's rows) sent its counts behind the tokens
-                stats = nxt[len(self._slots):].reshape(
-                    self.model.cfg.num_layers, -1)
-                sp.set(**{name: stats[:, i].tolist() for i, name in
-                          enumerate(self.model.step_stats)})
-            step_s = up.seconds + disp.seconds + fetch.seconds
-            _metrics.histogram("serving.decode.step.seconds", step_s)
-            _metrics.counter("serving.tokens.generated", len(running))
-            with _span("serving/decode/settle") as settle:
-                for req in running:
-                    slot = req.slot
-                    tok = int(nxt[slot])
-                    req.output_ids.append(tok)
-                    self._tokens[slot] = tok
-                    self._positions[slot] += 1
-                    self.scheduler.observe_decode_step(req, step_s)
-                    if self.tracer is not None:
-                        self.tracer.on_decode_step(req)
-                    self._maybe_finish(req, tok)
-                settle.set(finished=len(running)
-                           - len(self.scheduler.running))
-            return len(running)
+        phases; returns the tokens emitted.
 
-    def _decode_speculative(self) -> int:
-        """One verify-k step for every running slot: propose ``k`` n-gram
-        drafts per row, run the ONE verify executable over the static
-        ``[B, k+1]`` block, then settle per row on the host — greedy rows
-        keep the longest draft prefix the model's argmax agrees with plus
-        the model's own token at the divergence (1..k+1 tokens, exactly
-        the one-at-a-time greedy stream), sampled rows emit position 0's
-        sampled token. Rejected drafts cost nothing: their K/V sits at
-        positions the next verify step overwrites before attending, so
-        rollback is just NOT advancing ``_positions`` past the kept
-        tokens. Same spans as ``_decode``, plus ``serving/decode/propose``."""
+        Under speculation the step is the verify-k program: propose ``k``
+        n-gram drafts per row (``serving/decode/propose``), run the ONE
+        verify executable over the static ``[B, k+1]`` block, then settle
+        per row on the host — greedy rows keep the longest draft prefix the
+        model's argmax agrees with plus the model's own token at the
+        divergence (1..k+1 tokens, exactly the one-at-a-time greedy
+        stream), sampled rows emit position 0's sampled token. Rejected
+        drafts cost nothing: their K/V sits at positions the next verify
+        step overwrites before attending, so rollback is just NOT advancing
+        ``_positions`` past the kept tokens."""
         spec = self.spec
-        k = spec.k
+        k = 0 if spec is None else spec.k
+        B = len(self._slots)
         with _span("serving/decode", step=self._step_i) as sp:
             self._grow_pages(width=k + 1)
             running = [s.request for s in self._slots
@@ -1087,50 +959,74 @@ class Engine:
             sp.set(running=len(running))
             if not running:
                 return 0
-            with _span("serving/decode/propose") as prop:
-                B = self.config.max_batch_size
-                block = np.zeros((B, k + 1), np.int32)
-                drafts: Dict[int, List[int]] = {}
-                for req in running:
-                    slot = req.slot
-                    d = propose_ngram(req.prompt_ids + req.output_ids, k,
-                                      spec.ngram)
-                    drafts[slot] = d
-                    block[slot, 0] = self._tokens[slot]
-                    block[slot, 1:] = d
+            # cached tokens the step's attention may read (each running
+            # slot's context, the token it writes included) and how many of
+            # them it does read, where the model selects; pages the
+            # paged-decode kernel's loops walk (a layer) this step, of the
+            # table entries a grid over the table would
+            ctx = self._positions[[r.slot for r in running]] + 1
+            sel = getattr(self.model, "selected_tokens", None)
+            sp.set(ctx_tokens=int(ctx.sum()),
+                   selected_tokens=int((ctx if sel is None
+                                        else sel(ctx)).sum()),
+                   live_pages=int(((ctx - 1) // self.cache.page_size
+                                   + 1).sum()),
+                   table_pages=B * self.cache.num_blocks)
+            tokens, step_s = self._tokens, 0.0
+            if spec is not None:
+                with _span("serving/decode/propose") as prop:
+                    tokens = np.zeros((B, k + 1), np.int32)
+                    drafts: Dict[int, List[int]] = {}
+                    for req in running:
+                        slot = req.slot
+                        drafts[slot] = propose_ngram(
+                            req.prompt_ids + req.output_ids, k, spec.ngram)
+                        tokens[slot, 0] = self._tokens[slot]
+                        tokens[slot, 1:] = drafts[slot]
+                step_s = prop.seconds
             with _span("serving/decode/upload") as up:
                 any_sampled = not bool(self._greedy.all())
                 key = _random.next_key() if any_sampled else _dummy_key()
-                args = (self.cache.table_device(), jnp.asarray(block),
+                args = (self.cache.table_device(), jnp.asarray(tokens),
                         jnp.asarray(self._positions),
                         jnp.asarray(self._temps), jnp.asarray(self._top_ks),
                         jnp.asarray(self._greedy), key)
             with _span("serving/decode/dispatch") as disp:
-                exe = self._verify_exe()
-                targets, sampled0, *self.cache.pools = exe(
-                    self.params, *self.cache.pools, *args)
+                exe = self._decode_exe() if spec is None \
+                    else self._verify_exe()
+                out = exe(self.params, *self.cache.pools, *args)
+                # ahead of the pools come the tokens, or (verify) the
+                # argmax targets and position 0's sample
+                self.cache.pools = out[1 if spec is None else 2:]
             with _span("serving/decode/fetch") as fetch:
-                targets = np.asarray(targets)
-                sampled0 = np.asarray(sampled0)
-            step_s = (prop.seconds + up.seconds + disp.seconds
-                      + fetch.seconds)
+                toks = np.asarray(out[0])
+                sampled0 = toks if spec is None else np.asarray(out[1])
+            if spec is None and toks.shape[0] > B:
+                # a model that counts in its step (decoder.DecoderLM: per
+                # layer the distinct experts routed to, the largest
+                # expert's rows) sent its counts behind the tokens
+                stats = toks[B:].reshape(self.model.cfg.num_layers, -1)
+                sp.set(**{name: stats[:, i].tolist() for i, name in
+                          enumerate(self.model.step_stats)})
+            step_s += up.seconds + disp.seconds + fetch.seconds
             _metrics.histogram("serving.decode.step.seconds", step_s)
-            emitted_total = 0
-            drafted_now = accepted_now = 0
+            emitted_total = drafted = accepted = 0
             with _span("serving/decode/settle") as settle:
                 for req in running:
                     slot = req.slot
-                    if self._greedy[slot]:
-                        a, emitted = accept_greedy(drafts[slot],
-                                                   targets[slot])
+                    if spec is not None and self._greedy[slot]:
+                        a, emitted = accept_greedy(drafts[slot], toks[slot])
                         req.draft_tokens += k
                         req.accepted_tokens += a
-                        drafted_now += k
-                        accepted_now += a
+                        drafted += k
+                        accepted += a
                         self._spec_slots += k + 1
                         self._spec_emitted += len(emitted)
                     else:
-                        emitted = [int(sampled0[slot])]
+                        emitted = [sampled0[slot]]
+                    self.scheduler.observe_decode_step(req, step_s)
+                    if self.tracer is not None:
+                        self.tracer.on_decode_step(req)
                     for tok in emitted:
                         tok = int(tok)
                         req.output_ids.append(tok)
@@ -1140,19 +1036,14 @@ class Engine:
                         self._maybe_finish(req, tok)
                         if req.state == FINISHED:
                             break
-                    self.scheduler.observe_decode_step(req, step_s)
-                    if self.tracer is not None:
-                        self.tracer.on_decode_step(req)
                 settle.set(finished=len(running)
                            - len(self.scheduler.running))
-            self._spec_drafted += drafted_now
-            self._spec_accepted += accepted_now
             _metrics.counter("serving.tokens.generated", emitted_total)
-            if drafted_now:
-                _metrics.counter("serving.spec.draft_tokens", drafted_now)
-                _metrics.counter("serving.spec.accepted_tokens",
-                                 accepted_now)
-            if self._spec_slots:
+            if drafted:
+                self._spec_drafted += drafted
+                self._spec_accepted += accepted
+                _metrics.counter("serving.spec.draft_tokens", drafted)
+                _metrics.counter("serving.spec.accepted_tokens", accepted)
                 _metrics.gauge("serving.spec.accept_rate",
                                self._spec_emitted / self._spec_slots)
             return emitted_total
@@ -1181,13 +1072,12 @@ class Engine:
         self._temps[slot] = 1.0
         self._top_ks[slot] = 0
         self._greedy[slot] = True
-        if self.page_alloc is not None:
-            # drop this request's reference on every page its slot mapped —
-            # pages the prefix cache (or another sharer) still references
-            # stay live; the rest return to the pool. The allocator raises
-            # on double-free (naming page ids and owners), so leaks and
-            # corruption can't pass silently. clear_slot is idempotent: a
-            # second call returns [] and frees nothing.
-            self.page_alloc.free(self.cache.clear_slot(slot),
-                                 owner=f"req{req.request_id}")
+        # drop this request's reference on every page its slot mapped —
+        # pages the prefix cache (or another sharer) still references stay
+        # live; the rest return to the pool. The allocator raises on
+        # double-free (naming page ids and owners), so leaks and corruption
+        # can't pass silently. clear_slot is idempotent: a second call
+        # returns [] and frees nothing.
+        self.page_alloc.free(self.cache.clear_slot(slot),
+                             owner=f"req{req.request_id}")
         self.cache.free_slot(slot)

@@ -1,13 +1,18 @@
 """Static-shape KV cache: the serving engine's HBM-resident decode state.
 
-The cache is preallocated at engine construction — per layer a
-``[B_max, H_kv, S_max, D]`` K and V buffer (GQA: ``H_kv < H_q`` shrinks it by
-the query/KV head ratio) — so every prefill and every decode step runs at a
-FIXED shape: XLA compiles the prefill once per prompt bucket and the decode
-step exactly once, no matter how many tokens or requests flow through.
+The engine's cache is ``PagedKVCache``: per layer and pool one
+``[num_pages, heads, page_size, width]`` buffer preallocated at engine
+construction, plus a host page table, so every prefill and every decode
+step runs at a FIXED shape: XLA compiles the prefill once per prompt bucket
+and the decode step exactly once, no matter how many tokens or requests
+flow through. ``paged_write_kv`` writes it a page at a time; the attend
+over it is the Pallas kernel or the oracle, and ``default_paged_impl`` is
+the one function that says which.
 
-The write/attend helpers here are the SHARED decode path: both the GPT
-serving engine (paddle_tpu/serving/engine.py) and
+The dense helpers (``write_kv`` / ``decode_attend`` / ``extend_attend``
+over ``[B, H_kv, S_max, D]`` buffers) are the oracle of the paged attends
+(over ``paged_gather``) and the SHARED lockstep decode path: both
+``GPTForCausalLM.generate`` (serving/engine.py ``cached_generate``) and
 ``incubate.nn.FusedMultiTransformer``'s ``time_step`` decode route through
 them, so the two cached-attention implementations cannot drift.
 
@@ -20,7 +25,6 @@ tests/test_serving.py.
 from __future__ import annotations
 
 import contextlib
-import os
 from typing import List, Optional, Tuple
 
 import jax
@@ -119,105 +123,35 @@ def _tuple_nbytes(*pools) -> int:
     return int(sum(a.size * a.dtype.itemsize for p in pools for a in p))
 
 
-class KVCache:
-    """Preallocated K/V buffers, one ``[B_max, H_kv, S_max, D]`` device
-    buffer per layer (``.k`` / ``.v`` are tuples of ``num_layers`` arrays),
-    plus slot bookkeeping for the continuous-batching scheduler.
-
-    The buffers are handed in and out of the engine's compiled
-    prefill/decode executables, donated: each program returns the tuple of
-    updated buffers and the engine rebinds ``.k``/``.v`` after every step.
-    Slot allocation is host-side: a freed slot is immediately reusable
-    because its next prefill overwrites positions ``[0, T)`` before any
-    decode reads them.
-    """
-
-    def __init__(self, num_layers: int, max_batch_size: int,
-                 num_kv_heads: int, max_seq_len: int, head_dim: int,
-                 dtype="float32"):
-        self.num_layers = num_layers
-        self.max_batch_size = max_batch_size
-        self.num_kv_heads = num_kv_heads
-        self.max_seq_len = max_seq_len
-        self.head_dim = head_dim
-        shape = (max_batch_size, num_kv_heads, max_seq_len, head_dim)
-        self.k = _layer_buffers(num_layers, shape, dtype)
-        self.v = _layer_buffers(num_layers, shape, dtype)
-        self._free: List[int] = list(range(max_batch_size))[::-1]
-
-    @property
-    def nbytes(self) -> int:
-        return _tuple_nbytes(self.k, self.v)
-
-    @property
-    def pools(self):
-        """``(k, v)``: the buffer tuples by pool, as ``PagedKVCache.pools``."""
-        return (self.k, self.v)
-
-    @pools.setter
-    def pools(self, value):
-        self.k, self.v = value
-
-    def alloc_slot(self) -> Optional[int]:
-        """Lowest free slot index, or None when the batch is full."""
-        return self._free.pop() if self._free else None
-
-    def free_slot(self, slot: int):
-        self._free.append(slot)
-        self._free.sort(reverse=True)
-
-    @property
-    def free_slots(self) -> int:
-        return len(self._free)
-
-    @property
-    def active_slots(self) -> int:
-        return self.max_batch_size - len(self._free)
-
-    def layer_caches(self, k=None, v=None) -> List[Tuple[jax.Array, jax.Array]]:
-        """Per-layer ``(k, v)`` pairs of the buffer tuples — the pytree
-        shape GPTForCausalLM.decode_step consumes."""
-        k = self.k if k is None else k
-        v = self.v if v is None else v
-        return list(zip(k, v))
-
-
 # ---------------------------------------------------------------------------
 # Block-paged cache (vLLM PagedAttention layout, static-shape edition)
 # ---------------------------------------------------------------------------
 
-_PAGED_IMPL = None  # process-wide override (use_paged_attention_impl)
+_PAGED_IMPL = None  # the tier a test pinned (use_paged_attention_impl)
 _PAGED_IMPLS = ("oracle", "pallas")
 
 
 def default_paged_impl() -> str:
-    """Which paged-attend implementation a trace should bake in: ``pallas``
-    (the ragged kernel — compiled Mosaic on TPU, the Pallas interpreter on
-    cpu, chosen by the platform) on TPU, the ``oracle`` (gather + dense
-    ``decode_attend`` einsum) elsewhere; the override lets CPU tests
-    exercise the kernel's numerics and TPU runs check against the oracle.
-    Resolution: ``use_paged_attention_impl`` context > the
-    ``PADDLE_TPU_PAGED_ATTENTION_IMPL`` env var > platform default."""
+    """Which paged-attend implementation a trace bakes in — the ONE place
+    that says: ``pallas`` (the ragged kernels — compiled Mosaic on TPU, the
+    Pallas interpreter on cpu) on TPU, the ``oracle`` (gather + dense
+    ``decode_attend`` einsum) elsewhere, unless a test pinned the tier with
+    ``use_paged_attention_impl``."""
     if _PAGED_IMPL is not None:
         return _PAGED_IMPL
-    env = os.environ.get("PADDLE_TPU_PAGED_ATTENTION_IMPL")
-    if env:
-        if env not in _PAGED_IMPLS:
-            raise ValueError(
-                f"PADDLE_TPU_PAGED_ATTENTION_IMPL={env!r}; want one of "
-                f"{_PAGED_IMPLS}")
-        return env
     return "pallas" if on_tpu() else "oracle"
 
 
 @contextlib.contextmanager
-def use_paged_attention_impl(impl: Optional[str]):
+def use_paged_attention_impl(impl: str):
     """Pin the paged-attend implementation for traces entered under the
-    context (``None`` = keep the backend default). The choice is baked in
-    at TRACE time — the serving engine wraps its AOT ``.lower().compile()``
-    in this, so already-compiled executables are unaffected."""
+    context: the seam by which a CPU test runs the kernels under the
+    interpreter and ``chip_smoke.py`` runs the oracle on the chip. The
+    choice is baked in at TRACE time, so wrap the engine's construction
+    and its first ``generate`` / ``compile_programs`` (programs already
+    compiled are unaffected)."""
     global _PAGED_IMPL
-    if impl is not None and impl not in _PAGED_IMPLS:
+    if impl not in _PAGED_IMPLS:
         raise ValueError(f"paged impl {impl!r}; want one of {_PAGED_IMPLS}")
     prev, _PAGED_IMPL = _PAGED_IMPL, impl
     try:
@@ -277,19 +211,17 @@ def paged_gather(pool, page_table):
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, nb * ps, D)
 
 
-def paged_decode_attend(q, k_pool, v_pool, page_table, positions,
-                        impl: Optional[str] = None):
+def paged_decode_attend(q, k_pool, v_pool, page_table, positions):
     """Single-position cached attention over block-paged pools — the paged
-    twin of ``decode_attend`` behind ONE dispatch switch. ``oracle``
-    reconstructs the dense caches (``paged_gather``) and runs the einsum
-    oracle; ``pallas`` runs the Pallas ragged kernel
+    twin of ``decode_attend``, in the tier ``default_paged_impl`` says.
+    ``oracle`` reconstructs the dense caches (``paged_gather``) and runs
+    the einsum oracle; ``pallas`` runs the Pallas ragged kernel
     (kernels/paged_attention.py) which touches only live pages. Both tiers
     read the identical pool bytes, so they agree within float tolerance on
     ragged batches and GQA; an empty slot's row, which no caller reads, is
     the trash page's first token here and zeros there
     (tests/test_paged_kv.py)."""
-    impl = impl or default_paged_impl()
-    if impl == "oracle":
+    if default_paged_impl() == "oracle":
         k = paged_gather(k_pool, page_table)
         v = paged_gather(v_pool, page_table)
         return decode_attend(q, k, v, positions)
@@ -321,17 +253,12 @@ def extend_attend(q, k_cache, v_cache, positions):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def paged_extend_attend(q, k_pool, v_pool, page_table, positions,
-                        impl: Optional[str] = None):
+def paged_extend_attend(q, k_pool, v_pool, page_table, positions):
     """Multi-query cached attention over block-paged pools — the paged twin
-    of ``extend_attend``. The Pallas ragged kernel is single-query, so ALL
-    impl tiers currently reconstruct the dense view (``paged_gather``) and
-    run the einsum path; the ``impl`` argument is accepted so call sites
-    stay uniform with ``paged_decode_attend`` and a future multi-query
-    kernel can slot in without touching them. Verify steps are rare next
-    to decode steps (one per k+1 emitted tokens), so the gather cost is
-    amortized."""
-    del impl  # single implementation today; see docstring
+    of ``extend_attend``. The Pallas ragged kernel is single-query, so
+    every tier reconstructs the dense view (``paged_gather``) and runs the
+    einsum path. Verify steps are rare next to decode steps (one per k+1
+    emitted tokens), so the gather cost is amortized."""
     k = paged_gather(k_pool, page_table)
     v = paged_gather(v_pool, page_table)
     return extend_attend(q, k, v, positions)
@@ -339,8 +266,8 @@ def paged_extend_attend(q, k_pool, v_pool, page_table, positions,
 
 class PagedKVCache:
     """Block-paged pools, one ``[num_pages, heads, page_size, width]`` device
-    buffer per layer and pool, plus the per-slot page table and the same
-    slot bookkeeping as ``KVCache``.
+    buffer per layer and pool, plus the per-slot page table and the slot
+    bookkeeping of the continuous-batching scheduler.
 
     Which pools there are is the MODEL's declaration (``pools``: ``[(name,
     heads, width)]``); the default is the pair every attention needs, ``k``
@@ -352,9 +279,11 @@ class PagedKVCache:
     because it is all done by page id. ``.pools`` is the tuple (by pool) of
     tuples (by layer) of buffers; ``.k`` / ``.v`` name the first two.
 
-    The pools are donated device buffers exactly like the dense cache's
-    (the engine rebinds ``.pools`` to the tuples each compiled step
-    returns; every layer's pool is updated where it lies). The page table
+    The pools are donated device buffers: the engine rebinds ``.pools`` to
+    the tuples each compiled step returns, and every layer's pool is
+    updated where it lies. Slot allocation is host-side: a freed slot is
+    immediately reusable because its next prefill maps fresh pages before
+    any decode reads them. The page table
     is HOST state (numpy): the scheduler's allocator mutates it between
     steps and the engine ships a snapshot (``table_device()``) into each
     executable as runtime data — table CONTENTS change every
@@ -363,7 +292,7 @@ class PagedKVCache:
 
     Page 0 is reserved as the trash page (see ``PAGE_SENTINEL``); a
     default-sized pool therefore holds ``B_max * S_max/page_size + 1``
-    pages — capacity identical to the dense cache. Serving the same
+    pages — capacity for every slot at full length. Serving the same
     envelope at a FRACTION of that HBM is the point: pass a smaller
     ``num_pages`` and admission backpressure + ragged allocation take over.
     """
@@ -484,8 +413,9 @@ class PagedKVCache:
         self.page_table[slot, :] = PAGE_SENTINEL
         return pages
 
-    # -- same slot free-list API as KVCache --
+    # -- slot free list --
     def alloc_slot(self) -> Optional[int]:
+        """Lowest free slot index, or None when the batch is full."""
         return self._free.pop() if self._free else None
 
     def free_slot(self, slot: int):
@@ -500,17 +430,8 @@ class PagedKVCache:
     def active_slots(self) -> int:
         return self.max_batch_size - len(self._free)
 
-    def layer_caches(self, k=None, v=None, table=None):
-        """Per-layer ``(k_pool, v_pool, page_table)`` triples of the pool
-        tuples — the pytree shape the paged ``decode_step`` consumes (the
-        table is shared by every layer)."""
-        k = self.k if k is None else k
-        v = self.v if v is None else v
-        return self.layer_entries((k, v), table)
-
-    def layer_entries(self, pools=None, table=None):
+    @staticmethod
+    def layer_entries(pools, table):
         """Per-layer ``(pool_0, ..., pool_n, page_table)`` entries of the
         pool tuples, in the order the model declared its pools."""
-        pools = self.pools if pools is None else pools
-        table = self.table_device() if table is None else table
         return [tuple(layer) + (table,) for layer in zip(*pools)]

@@ -391,9 +391,7 @@ def test_f64_fixture_respects_x64_mode(x64):
     """The dtype-f64 fixture only exists under x64 (off, the f64 input
     silently downcasts at construction) — pin that environment sensitivity
     in both directions so the lint gate's x64 requirement stays honest."""
-    from jax.experimental import disable_x64, enable_x64
-
-    with (enable_x64() if x64 else disable_x64()):
+    with jax.enable_x64(x64):
         spec, rule = next((s, r) for s, r in analysis.fixture_specs()
                           if r == "dtype-f64")
         report = analysis.analyze_spec(spec)

@@ -257,17 +257,18 @@ class TestAgainstReference:
         prefills, then a prompt that shares 40 tokens (5 pages) and goes
         through the splice and ``extend_step``. Every emitted token is the
         reference's greedy token of the full text."""
-        eng = Engine(model, EngineConfig(
-            max_batch_size=3, max_seq_len=128, page_size=8,
-            prefill_buckets=(32, 64, 128), prefix_cache=True,
-            paged_attention_impl=impl))
         shared = _ids(40, seed=5)
         prompts = [shared + _ids(17, seed=6), _ids(70, seed=7)]
-        outs = eng.generate(prompts, SamplingParams(max_new_tokens=12))
         p3 = shared + _ids(9, seed=8)
-        r3 = eng.add_request(p3, SamplingParams(max_new_tokens=12))
-        while eng.has_unfinished:
-            eng.step()
+        # the tier is baked in as each program is traced, on first use
+        with kvc.use_paged_attention_impl(impl):
+            eng = Engine(model, EngineConfig(
+                max_batch_size=3, max_seq_len=128, page_size=8,
+                prefill_buckets=(32, 64, 128), prefix_cache=True))
+            outs = eng.generate(prompts, SamplingParams(max_new_tokens=12))
+            r3 = eng.add_request(p3, SamplingParams(max_new_tokens=12))
+            while eng.has_unfinished:
+                eng.step()
         assert r3.prefix_hit_blocks == 5
         assert ("extend", 32) in eng._exe
         for prompt, out in zip(prompts + [p3], outs + [r3.output_ids]):
@@ -439,22 +440,34 @@ class TestEngineProtocol:
         assert all(1 <= x <= 4 for x in first["experts_touched"])
         assert all(1 <= x <= 2 for x in first["expert_max_load"])
 
-    def test_gpt_decode_span_has_context_and_no_expert_counts(self, telemetry):
+    @pytest.mark.parametrize("speculative", [None, 2],
+                             ids=["plain", "speculative"])
+    def test_gpt_decode_span_has_context_and_no_expert_counts(
+            self, telemetry, speculative):
+        """The engine has one host decode step: a verify step's span
+        carries the counts a plain step's does."""
         from paddle_tpu.observability import tracing
 
         gpt = gpt_tiny(dropout=0.0, num_layers=2)
         gpt.eval()
         tracing.clear_spans()
-        Engine(gpt, EngineConfig(max_batch_size=2, max_seq_len=64)).generate(
+        Engine(gpt, EngineConfig(max_batch_size=2, max_seq_len=64,
+                                 speculative=speculative)).generate(
             [[5, 17, 3]], SamplingParams(max_new_tokens=3))
         dec = [e["attrs"] for e in tracing.spans()
-               if e["name"] == "serving/decode" and "ctx_tokens" in e["attrs"]]
+               if e["name"] == "serving/decode" and "running" in e["attrs"]
+               and e["attrs"]["running"]]
         assert dec[0]["ctx_tokens"] == dec[0]["selected_tokens"] == 4
         assert "experts_touched" not in dec[0]
         # page 16: the one running slot's 4 tokens sit in 1 page of a
         # 2-slot x 4-block table; at the 17th token a second page is live
-        assert [d["live_pages"] for d in dec] == [1, 1]
+        steps = 2 if speculative is None else len(dec)  # drafts may land
+        assert 1 <= steps <= 2
+        assert [d["live_pages"] for d in dec] == [1] * steps
         assert dec[0]["table_pages"] == 2 * 4
+        proposed = [e for e in tracing.spans()
+                    if e["name"] == "serving/decode/propose"]
+        assert len(proposed) == (0 if speculative is None else steps)
 
     def test_decode_span_counts_live_pages_across_a_page_boundary(
             self, telemetry):

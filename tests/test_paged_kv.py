@@ -5,8 +5,9 @@ allocated, all-or-nothing allocation, double-free raises, trash page never
 handed out), paged write/gather parity with the dense cache primitives,
 paged-vs-oracle decode-attend parity across ragged lengths / GQA / empty
 slots (the Pallas kernel under ``interpret=True`` so CPU exercises its
-numerics), engine-level A/B parity (paged vs dense layout, oracle vs
-interpret tier, mid-run admission), page-pool admission backpressure and
+numerics), engine-level parity (the engine vs ``model.generate``'s dense
+lockstep loop, oracle vs interpret tier, mid-run admission), page-pool
+admission backpressure and
 decode-growth ``cache_full``, the one-compile decode guarantee with the
 page table riding as runtime data, and the new page-occupancy gauges.
 """
@@ -17,6 +18,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import observability as obs
+from paddle_tpu.kernels.paged_attention import paged_attention
 from paddle_tpu.models.gpt import gpt_tiny
 from paddle_tpu.serving import Engine, EngineConfig, SamplingParams
 from paddle_tpu.serving import kv_cache as kvc
@@ -41,6 +43,13 @@ def _tiny(**kw):
 def _prompt(b, t, seed=0):
     rng = np.random.default_rng(seed)
     return rng.integers(1, 50, (b, t)).astype(np.int32)
+
+
+def _oracle_attend(q, k_pool, v_pool, table, positions):
+    """The paged attend's oracle, by name: the dense attend over the
+    gathered pools."""
+    return kvc.decode_attend(q, kvc.paged_gather(k_pool, table),
+                             kvc.paged_gather(v_pool, table), positions)
 
 
 # ---------------- allocator invariants ------------------------------------
@@ -175,8 +184,8 @@ class TestPagedPrimitives:
         rng = np.random.RandomState(3)
         q = jnp.asarray(rng.randn(B, Hkv * rep, 1, D).astype(np.float32))
         pos = jnp.asarray([6, 3, 0], jnp.int32)  # mid-page, page-0-only, empty
-        want = kvc.paged_decode_attend(q, kp, vp, tbl, pos, impl="oracle")
-        got = kvc.paged_decode_attend(q, kp, vp, tbl, pos, impl="pallas")
+        want = _oracle_attend(q, kp, vp, tbl, pos)
+        got = paged_attention(q, kp, vp, tbl, pos)
         assert got.shape == want.shape == (B, Hkv * rep, 1, D)
         assert np.allclose(np.asarray(got)[:2], np.asarray(want)[:2],
                            atol=1e-5)
@@ -233,10 +242,8 @@ class TestPagedPrimitives:
 
     def _assert_walk_matches_oracle(self, case, tol):
         q, kq, vq, kp, vp, tbl, pos, live = case
-        want = np.asarray(kvc.paged_decode_attend(
-            q, kp, vp, tbl, pos, impl="oracle"), np.float32)
-        got = np.asarray(kvc.paged_decode_attend(
-            q, kq, vq, tbl, pos, impl="pallas"), np.float32)
+        want = np.asarray(_oracle_attend(q, kp, vp, tbl, pos), np.float32)
+        got = np.asarray(paged_attention(q, kq, vq, tbl, pos), np.float32)
         assert np.isfinite(got).all()
         err = np.abs(got[live] - want[live]).max() / np.abs(want[live]).max()
         assert err <= tol, err
@@ -283,7 +290,6 @@ class TestPagedPrimitives:
         """One grid step a slot, whatever the table's width: the page walk
         is the kernel's own loop."""
         import jax
-        from paddle_tpu.kernels.paged_attention import paged_attention
 
         B, Hkv, ps, D = 4, 2, 16, 128
         sds = jax.ShapeDtypeStruct
@@ -306,10 +312,28 @@ class TestPagedPrimitives:
         assert tuple(calls[0].params["grid_mapping"].grid) == (B,)
 
     def test_impl_dispatch_and_override(self):
-        assert kvc.default_paged_impl() in ("oracle", "pallas")
+        """One function says kernel or oracle: the platform (a CPU here, so
+        the oracle) unless the context manager pinned a tier, and
+        ``paged_decode_attend`` traces what it says."""
+        import jax
+
+        kp, vp, tbl, _, _ = self._pool_and_dense()
+        q = jnp.zeros((tbl.shape[0], kp.shape[1], 1, kp.shape[3]))
+        pos = jnp.zeros((tbl.shape[0],), jnp.int32)
+
+        def traces_kernel():
+            # a new function object per call: traces are cached by function
+            # identity, and the tier is chosen while tracing
+            return "pallas_call" in str(jax.make_jaxpr(
+                lambda *a: kvc.paged_decode_attend(*a))(q, kp, vp, tbl, pos))
+
+        assert kvc.default_paged_impl() == "oracle" and not traces_kernel()
         with kvc.use_paged_attention_impl("pallas"):
+            assert kvc.default_paged_impl() == "pallas" and traces_kernel()
+            with kvc.use_paged_attention_impl("oracle"):
+                assert not traces_kernel()
             assert kvc.default_paged_impl() == "pallas"
-        assert kvc.default_paged_impl() in ("oracle", "pallas")
+        assert kvc.default_paged_impl() == "oracle"
         with pytest.raises(ValueError):
             kvc.use_paged_attention_impl("nope").__enter__()
 
@@ -329,7 +353,7 @@ class TestPoolsUpdatedInPlace:
 
     @pytest.mark.parametrize("layout,name", [
         ("paged", "decode"), ("paged", "prefill"), ("paged", "extend"),
-        ("paged", "verify"), ("dense", "decode"), ("dense", "prefill")])
+        ("paged", "verify")])
     def test_every_pool_leaf_aliased_and_no_pool_sized_temp(self, layout,
                                                             name):
         import re
@@ -339,11 +363,8 @@ class TestPoolsUpdatedInPlace:
 
         # pools far larger than anything else the tiny model holds, so a
         # temporary the size of one layer's pool cannot hide
-        size = ({"kv_pages": 2048, "page_size": 8} if layout == "paged"
-                else {"max_batch_size": 16})
-        eng = Engine(_tiny(), EngineConfig(**{
-            "max_batch_size": 2, "max_seq_len": 64, "kv_layout": layout,
-            **size}))
+        eng = Engine(_tiny(), EngineConfig(
+            max_batch_size=2, max_seq_len=64, kv_pages=2048, page_size=8))
         fn, args = _program(eng, name)
         exe = jax.jit(fn, donate_argnums=KV_DONATE_ARGNUMS) \
             .lower(*args).compile()
@@ -391,17 +412,13 @@ class TestPoolsUpdatedInPlace:
         speculation on — a cold prompt, a prefix hit (suffix prefill
         through the extend program) and a request whose shared page is
         copied on write in mid-run — emit token for token what a plain
-        paged engine and the dense layout emit."""
+        engine emits."""
         m = _tiny()
         warm = [int(t) for t in _prompt(1, 20, seed=5)[0]]
         prompts = [warm, warm[:16] + [7, 9, 11], [3, 1, 4, 1, 5, 9, 2, 6]]
         sp = SamplingParams(max_new_tokens=10)
         want = Engine(m, EngineConfig(max_batch_size=2, max_seq_len=64,
                                       page_size=8)).generate(prompts, sp)
-        dense = Engine(m, EngineConfig(max_batch_size=2, max_seq_len=64,
-                                       kv_layout="dense")).generate(
-            prompts, sp)
-        assert dense == want
         eng = Engine(m, EngineConfig(max_batch_size=2, max_seq_len=64,
                                      page_size=8, prefix_cache=True,
                                      speculative=2))
@@ -430,20 +447,20 @@ class TestPoolsUpdatedInPlace:
 # ---------------- engine: paged layout ------------------------------------
 class TestPagedEngine:
     def test_paged_matches_dense_layout_with_midrun_admission(self):
-        """A/B at the engine level: 3 ragged greedy requests through 2
-        slots (so the third is admitted mid-run) produce identical tokens
-        under the paged and dense layouts — GQA model, page smaller than
-        the prefill bucket so prefill exercises partial/multi-page
-        scatter."""
+        """3 ragged greedy requests through 2 slots (so the third is
+        admitted mid-run) emit what ``model.generate`` — the lockstep loop
+        over dense ``[B, H_kv, S_max, D]`` buffers, the engine's
+        independent reference — emits a prompt at a time. GQA model, page
+        smaller than the prefill bucket so prefill exercises
+        partial/multi-page scatter."""
         prompts = [[5, 17, 3], [9, 2, 11, 4, 8, 1, 7, 12, 6], [7, 7, 7]]
         sp = SamplingParams(max_new_tokens=5)
         paddle.seed(0)
         m = _tiny(num_kv_heads=2)
-        dense = Engine(m, EngineConfig(max_batch_size=2, max_seq_len=32,
-                                       kv_layout="dense")).generate(
-            prompts, sp)
+        dense = [np.asarray(m.generate(
+            paddle.to_tensor(np.asarray([p], np.int32)),
+            max_new_tokens=5)._value)[0, len(p):].tolist() for p in prompts]
         paged = Engine(m, EngineConfig(max_batch_size=2, max_seq_len=32,
-                                       kv_layout="paged",
                                        page_size=4)).generate(prompts, sp)
         assert paged == dense
 
@@ -455,13 +472,16 @@ class TestPagedEngine:
         m = _tiny()
         prompts = [[5, 17, 3, 9, 2]]
         sp = SamplingParams(max_new_tokens=4)
-        oracle = Engine(m, EngineConfig(
-            max_batch_size=2, max_seq_len=32,
-            paged_attention_impl="oracle")).generate(prompts, sp)
-        kern = Engine(m, EngineConfig(
-            max_batch_size=2, max_seq_len=32,
-            paged_attention_impl="pallas")).generate(prompts, sp)
+        cfg = EngineConfig(max_batch_size=2, max_seq_len=32)
+        with kvc.use_paged_attention_impl("oracle"):
+            oracle = Engine(m, cfg).generate(prompts, sp)
+        with kvc.use_paged_attention_impl("pallas"):
+            eng = Engine(m, cfg)
+            kern = eng.generate(prompts, sp)
         assert kern == oracle
+        # the tier was baked in at trace time: the kernel engine's programs
+        # go on running the kernel outside the context
+        assert eng.generate(prompts, sp) == oracle
 
     def test_paged_decode_compiles_once(self, telemetry):
         """The page table is runtime data: admissions, finishes, and table
@@ -544,9 +564,6 @@ class TestPagedEngine:
 
     def test_config_validation(self):
         m = _tiny()
-        with pytest.raises(ValueError, match="kv_layout"):
-            Engine(m, EngineConfig(max_batch_size=2, max_seq_len=32,
-                                   kv_layout="sparse"))
         # page_size shrinks to divide S_max instead of failing
         eng = Engine(m, EngineConfig(max_batch_size=1, max_seq_len=24,
                                      page_size=16))

@@ -24,7 +24,8 @@ from paddle_tpu.models.gpt import gpt_tiny
 from paddle_tpu.serving import (Engine, EngineConfig, PrefixCache,
                                 SamplingParams, SpeculativeConfig,
                                 accept_greedy, propose_ngram,
-                                read_request_traces)
+                                read_request_traces,
+                                use_paged_attention_impl)
 from paddle_tpu.serving.kv_cache import PAGE_SENTINEL, PagedKVCache
 from paddle_tpu.serving.scheduler import FINISHED, PageAllocator
 
@@ -102,10 +103,6 @@ class TestSpeculativeHost:
         assert EngineConfig(speculative=True).speculative == SpeculativeConfig()
         assert EngineConfig(speculative=5).speculative.k == 5
         assert EngineConfig(speculative=None).speculative is None
-        with pytest.raises(ValueError, match="paged"):
-            EngineConfig(kv_layout="dense", prefix_cache=True)
-        with pytest.raises(ValueError, match="paged"):
-            EngineConfig(kv_layout="dense", speculative=2)
 
 
 # ---------------- refcounted allocator -------------------------------------
@@ -297,12 +294,13 @@ class TestEnginePrefixCache:
         m = _tiny()
         outs = []
         for impl in ("oracle", "pallas"):
-            eng = Engine(m, EngineConfig(max_batch_size=1, max_seq_len=64,
-                                         page_size=8, prefix_cache=True,
-                                         paged_attention_impl=impl))
-            warm = _toks(20, seed=1)
-            _run(eng, warm, max_new_tokens=3)
-            req = _run(eng, warm[:16] + _toks(4, seed=2), max_new_tokens=5)
+            with use_paged_attention_impl(impl):
+                eng = Engine(m, EngineConfig(max_batch_size=1, max_seq_len=64,
+                                             page_size=8, prefix_cache=True))
+                warm = _toks(20, seed=1)
+                _run(eng, warm, max_new_tokens=3)
+                req = _run(eng, warm[:16] + _toks(4, seed=2),
+                           max_new_tokens=5)
             assert req.prefix_hit_blocks == 2
             outs.append(req.output_ids)
         assert outs[0] == outs[1]

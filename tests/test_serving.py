@@ -101,9 +101,7 @@ class TestDecodeParity:
         f64 before the cast back (doubled decode flops and wire — caught by
         the analyzer's dtype-f64 rule, fixed by the jnp.asarray pin). Both
         x64 modes must trace an f64-free program with an f32 result."""
-        from jax.experimental import disable_x64, enable_x64
-
-        with (enable_x64() if x64 else disable_x64()):
+        with jax.enable_x64(x64):
             B, H, S_max, D = 2, 2, 8, 4
             q = jnp.ones((B, H, 1, D), jnp.float32)
             k = jnp.ones((B, H, S_max, D), jnp.float32)
