@@ -28,6 +28,7 @@ device count.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import List, Tuple
 
 import jax
@@ -166,7 +167,12 @@ def _serving_specs() -> List[ProgramSpec]:
                     argnames=("params", "k_pages", "v_pages", "ids",
                               "page_row", "length"),
                     sharding=eng.sharding_contract(len(pre_args))),
-        ProgramSpec("serving_decode", dec_fn, dec_args, contract,
+        ProgramSpec("serving_decode", dec_fn, dec_args,
+                    # decode also donates the tokens and positions it puts
+                    # out again for the next step
+                    dataclasses.replace(
+                        contract,
+                        donate_argnums=eng.donate_argnums_of("decode")),
                     argnames=("params", "k_pages", "v_pages", "page_table",
                               "tokens", "positions", "temps", "top_ks",
                               "greedy", "key"),

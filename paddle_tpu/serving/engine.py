@@ -20,7 +20,11 @@ entries) -> ``kv_cache.paged_write_kv`` and the attend, where
   batch of single tokens with per-row positions writes into the pools and
   attends over each row's live pages. Per-request SamplingParams ride as
   device arrays (sampling.sample_batched), so an arbitrary mix of
-  greedy/sampled requests never triggers a recompile.
+  greedy/sampled requests never triggers a recompile. Its operands stay
+  on the device from one step to the next: the program puts out the next
+  step's tokens and positions itself, and the host puts an operand again
+  only when something other than the step changed its host mirror
+  (``Engine._decode``).
 - **verify** — decode widened to ``[B_max, k+1]``: what the engine's one
   host decode step (``Engine._decode``) runs instead under speculation.
 
@@ -76,6 +80,20 @@ from .speculative import SpeculativeConfig, accept_greedy, propose_ngram
 #: argnums of the two pools every model has; ``Engine.donate_argnums``
 #: covers a model that declared more.
 KV_DONATE_ARGNUMS = (1, 2)
+
+#: the decode step's per-slot operands, in the programs' argument order
+#: behind the page table. Each has a host mirror (``Engine._<name>``, the
+#: authority) and a kept device array (``Engine._dev[<name>]``); a name in
+#: ``Engine._stale`` says the mirror was changed by something other than
+#: the decode program since the array was put.
+_OPERANDS = ("tokens", "positions", "temps", "top_ks", "greedy")
+
+#: the ``jit.compile.*{site=}`` each kind of program accounts under. The
+#: verify program REPLACES the plain decode step while speculation is on,
+#: so it shares serving.decode: the one-compile-per-lifetime counter covers
+#: both modes.
+_SITES = {"prefill": "serving.prefill", "extend": "serving.prefill",
+          "decode": "serving.decode", "verify": "serving.decode"}
 
 _DUMMY_KEY = None
 
@@ -388,12 +406,17 @@ class Engine:
                 sample_every=self.config.trace_sample_every,
                 slo=self.config.slo)
         self._slots: List[_SlotState] = [_SlotState() for _ in range(B)]
-        # vectorized per-slot decode state (device args rebuilt per step)
+        # vectorized per-slot decode state: the host mirrors of _OPERANDS,
+        # the arrays the device holds of them, and which mirrors changed
+        # since (admission and finish mark all five; the plain decode step
+        # advances tokens and positions on both sides and marks nothing)
         self._tokens = np.zeros((B,), np.int32)
         self._positions = np.zeros((B,), np.int32)
         self._temps = np.ones((B,), np.float32)
         self._top_ks = np.zeros((B,), np.int32)
         self._greedy = np.ones((B,), bool)
+        self._dev: Dict[str, jax.Array] = {}
+        self._stale = set(_OPERANDS)
         self._exe: Dict = {}
         self._step_i = 0  # engine steps so far: the spans' ``step``
         self._cow_copies = 0  # copy-on-write page copies so far
@@ -548,7 +571,13 @@ class Engine:
         CONTENTS change every admission/finish but the shape never does —
         the decode executable stays ONE compile for the engine lifetime
         (tests pin the compile counter), and the paged attend reads each
-        slot's live pages out of the pools."""
+        slot's live pages out of the pools.
+
+        Outputs: the array the host fetches (the sampled tokens, a model's
+        ``step_stats`` behind them), then the NEXT step's ``tokens`` and
+        ``positions`` (``Engine._decode`` keeps them on the device and hands
+        them back: their two argnums are donated too,
+        ``donate_argnums_of``), then the pools."""
         model, cache = self.model, self.cache
         B, nb = self.config.max_batch_size, self.cache.num_blocks
         n = len(cache.pools)
@@ -562,12 +591,20 @@ class Engine:
                 cache.layer_entries(pools, page_table), Tensor(positions))
             nxt = _sampling.sample_batched(logits, key, temps, top_ks,
                                            greedy).astype(jnp.int32)
+            # the next step's tokens and positions, kept on the device: a
+            # live slot (its first block is mapped) takes its new token and
+            # moves on one position; a dead slot stays at token 0, position
+            # 0, where the host's mirrors have it (and where the paged
+            # attend's loop over a slot's pages makes no trip)
+            live = page_table[:, 0] != PAGE_SENTINEL
+            next_tokens = jnp.where(live, nxt, 0).astype(tokens.dtype)
+            next_positions = positions + live.astype(positions.dtype)
             if stats is not None:
                 # the step's statistics ride behind the tokens, in the one
                 # array the host fetches
                 nxt = jnp.concatenate(
                     [nxt, stats.astype(jnp.int32).reshape(-1)])
-            return (nxt,) + _updated(new)
+            return (nxt, next_tokens, next_positions) + _updated(new)
 
         args = (self.params, *self.cache.pools,
                 jnp.zeros((B, nb), jnp.int32),
@@ -673,28 +710,42 @@ class Engine:
 
         return {key: kernel_sites(exe) for key, exe in self._exe.items()}
 
+    def donate_argnums_of(self, kind: str) -> Tuple[int, ...]:
+        """The argnums a program of this kind (``"prefill"``, ``"extend"``,
+        ``"decode"``, ``"verify"``) is compiled to donate: the pools, and
+        for decode the tokens and positions it puts out again for the next
+        step."""
+        if kind != "decode":
+            return self.donate_argnums
+        n = len(self.cache.pools)
+        return self.donate_argnums + (n + 2, n + 3)
+
+    def _held(self, *key):
+        """The executable of program ``key`` (``("decode",)``,
+        ``("prefill", T)``, ...): the one the engine holds, found before
+        anything is built (the hit still counts under the program's site);
+        only on a miss is ``<kind>_program()`` called for the function and
+        its example arguments, and the result compiled and kept."""
+        kind = key[0]
+        exe = self._exe.get(key)
+        if exe is not None:
+            _obs.record_compile(_SITES[kind], cache_hit=True)
+            return exe
+        fn, args = getattr(self, kind + "_program")(*key[1:])
+        return _aot(self._exe, key, _SITES[kind], fn, args,
+                    donate_argnums=self.donate_argnums_of(kind))
+
     def _prefill_exe(self, T: int):
-        prefill_fn, args = self.prefill_program(T)
-        return _aot(self._exe, ("prefill", T), "serving.prefill",
-                    prefill_fn, args, donate_argnums=self.donate_argnums)
+        return self._held("prefill", T)
 
     def _decode_exe(self):
-        decode_fn, args = self.decode_program()
-        return _aot(self._exe, ("decode",), "serving.decode", decode_fn,
-                    args, donate_argnums=self.donate_argnums)
+        return self._held("decode")
 
     def _extend_exe(self, T: int):
-        extend_fn, args = self.extend_program(T)
-        return _aot(self._exe, ("extend", T), "serving.prefill",
-                    extend_fn, args, donate_argnums=self.donate_argnums)
+        return self._held("extend", T)
 
     def _verify_exe(self):
-        verify_fn, args = self.verify_program()
-        # the verify program REPLACES the plain decode step while
-        # speculation is on, so it accounts under the same serving.decode
-        # site — the one-compile-per-lifetime counter covers both modes
-        return _aot(self._exe, ("verify",), "serving.decode", verify_fn,
-                    args, donate_argnums=self.donate_argnums)
+        return self._held("verify")
 
     def compile_programs(self, prefill: Sequence[int] = (),
                          extend: Sequence[int] = ()) -> List[Tuple]:
@@ -729,11 +780,10 @@ class Engine:
             for key in keys:
                 t0 = time.perf_counter()
                 fn, args = getattr(self, key[0] + "_program")(*key[1:])
-                site = ("serving.decode" if key[0] in ("decode", "verify")
-                        else "serving.prefill")
-                lowered = jax.jit(
-                    fn, donate_argnums=self.donate_argnums).lower(*args)
-                todo.append((key, site, lowered, time.perf_counter() - t0))
+                lowered = jax.jit(fn, donate_argnums=self.donate_argnums_of(
+                    key[0])).lower(*args)
+                todo.append((key, _SITES[key[0]], lowered,
+                             time.perf_counter() - t0))
             if todo:
                 with ThreadPoolExecutor(len(todo)) as pool:
                     done = list(pool.map(compile_one, todo))
@@ -861,6 +911,7 @@ class Engine:
         if self.tracer is not None:
             self.tracer.on_prefill(req)
         self._slots[slot].request = req
+        self._stale.update(_OPERANDS)
         self._tokens[slot] = tok
         self._positions[slot] = n  # first generated token's index
         self._temps[slot] = sp.temperature
@@ -890,7 +941,7 @@ class Engine:
             return False
         self.cache.copy_page(page, fresh[0])
         self._cow_copies += 1
-        self.cache.page_table[slot, block] = fresh[0]
+        self.cache.repoint(slot, block, fresh[0])
         self.page_alloc.free([page], owner=owner)
         return True
 
@@ -948,7 +999,19 @@ class Engine:
         stream), sampled rows emit position 0's sampled token. Rejected
         drafts cost nothing: their K/V sits at positions the next verify
         step overwrites before attending, so rollback is just NOT advancing
-        ``_positions`` past the kept tokens."""
+        ``_positions`` past the kept tokens.
+
+        The step's operands (``_OPERANDS`` and the page table) stay on the
+        device between steps. The host mirrors are the authority and
+        ``settle`` updates them as it always did; the decode program makes
+        the same update on the device (its ``next_tokens`` /
+        ``next_positions`` outputs, handed back as the next step's
+        arguments), so after every ``settle`` a kept array equals its
+        mirror unless the mirror's name is in ``_stale``: ``_admit_one`` and
+        ``_finish`` mark all five, the cache's table writers drop its kept
+        copy, and a verify step marks tokens and positions (the host
+        decides both from the accepted drafts). ``upload`` puts exactly
+        what is marked — on most plain steps nothing."""
         spec = self.spec
         k = 0 if spec is None else spec.k
         B = len(self._slots)
@@ -987,20 +1050,37 @@ class Engine:
             with _span("serving/decode/upload") as up:
                 any_sampled = not bool(self._greedy.all())
                 key = _random.next_key() if any_sampled else _dummy_key()
-                args = (self.cache.table_device(), jnp.asarray(tokens),
-                        jnp.asarray(self._positions),
-                        jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                        jnp.asarray(self._greedy), key)
+                # put what the host changed, in one call and from copies
+                # (a put may alias host memory the mirrors go on changing);
+                # everything else is the array the device already holds
+                table_put = int(self.cache.table_changed)
+                table = self.cache.table_device()
+                stale = {name: (tokens if name == "tokens"
+                                else getattr(self, "_" + name)).copy()
+                         for name in self._stale}
+                if stale:
+                    self._dev.update(jax.device_put(stale))
+                    self._stale.clear()
+                up.set(puts=len(stale) + table_put, table_put=table_put)
+                args = (table, *(self._dev[name] for name in _OPERANDS), key)
             with _span("serving/decode/dispatch") as disp:
                 exe = self._decode_exe() if spec is None \
                     else self._verify_exe()
                 out = exe(self.params, *self.cache.pools, *args)
-                # ahead of the pools come the tokens, or (verify) the
-                # argmax targets and position 0's sample
-                self.cache.pools = out[1 if spec is None else 2:]
+                if spec is None:
+                    # behind what the host fetches come the next step's
+                    # tokens and positions (their inputs were donated),
+                    # then the pools
+                    toks, self._dev["tokens"], self._dev["positions"], \
+                        *self.cache.pools = out
+                else:
+                    # the argmax targets and position 0's sample; the host
+                    # decides the next tokens and positions in settle
+                    toks, sampled0, *self.cache.pools = out
+                    self._stale.update(("tokens", "positions"))
             with _span("serving/decode/fetch") as fetch:
-                toks = np.asarray(out[0])
-                sampled0 = toks if spec is None else np.asarray(out[1])
+                toks = np.asarray(toks)
+                sampled0 = toks if spec is None else np.asarray(sampled0)
             if spec is None and toks.shape[0] > B:
                 # a model that counts in its step (decoder.DecoderLM: per
                 # layer the distinct experts routed to, the largest
@@ -1067,6 +1147,7 @@ class Engine:
         if self.tracer is not None:
             self.tracer.on_finish(req)
         self._slots[slot].request = None
+        self._stale.update(_OPERANDS)
         self._tokens[slot] = 0
         self._positions[slot] = 0
         self._temps[slot] = 1.0

@@ -284,11 +284,14 @@ class PagedKVCache:
     updated where it lies. Slot allocation is host-side: a freed slot is
     immediately reusable because its next prefill maps fresh pages before
     any decode reads them. The page table
-    is HOST state (numpy): the scheduler's allocator mutates it between
-    steps and the engine ships a snapshot (``table_device()``) into each
-    executable as runtime data — table CONTENTS change every
-    admission/finish, but its ``[B_max, num_blocks]`` int32 shape never
-    does, which is what keeps decode at one compile.
+    is HOST state (numpy) that only this class's methods write
+    (``assign_pages``, ``repoint``, ``clear_slot``; ``page_table`` is a
+    read-only view): each write marks the device's copy changed, and
+    ``table_device()`` puts the table again only then — between two writes
+    every executable is handed the SAME kept device array as runtime data.
+    Table CONTENTS change on an admission, a finish, a page crossing or a
+    copy-on-write, but its ``[B_max, num_blocks]`` int32 shape never does,
+    which is what keeps decode at one compile.
 
     Page 0 is reserved as the trash page (see ``PAGE_SENTINEL``); a
     default-sized pool therefore holds ``B_max * S_max/page_size + 1``
@@ -323,8 +326,13 @@ class PagedKVCache:
         self._pools = tuple(
             _layer_buffers(num_layers, (num_pages, h, page_size, w), dtype)
             for _, h, w in self.pool_specs)
-        self.page_table = np.full((max_batch_size, self.num_blocks),
-                                  PAGE_SENTINEL, np.int32)
+        self._table = np.full((max_batch_size, self.num_blocks),
+                              PAGE_SENTINEL, np.int32)
+        self.page_table = self._table.view()
+        self.page_table.flags.writeable = False
+        # the table as the device holds it; None once a writer below has
+        # changed the host's since it was put
+        self._table_dev: Optional[jax.Array] = None
         self._free: List[int] = list(range(max_batch_size))[::-1]
         self._copy_exe = None
 
@@ -356,16 +364,33 @@ class PagedKVCache:
     def nbytes(self) -> int:
         return _tuple_nbytes(*self.pools)
 
+    @property
+    def table_changed(self) -> bool:
+        """Whether the next ``table_device()`` transfers the table."""
+        return self._table_dev is None
+
     def table_device(self) -> jax.Array:
-        """Snapshot the host page table as the device operand the compiled
-        prefill/decode executables consume."""
-        return jnp.asarray(self.page_table)
+        """The page table as the device operand the compiled decode / verify
+        executables consume: the array kept from the last put while no
+        writer has changed the host table since. The put takes a copy, so a
+        later host write never reaches an array a program may still be
+        reading."""
+        if self._table_dev is None:
+            self._table_dev = jax.device_put(self._table.copy())
+        return self._table_dev
 
     # -- host-side table bookkeeping (the scheduler's allocator owns page
-    #    ids; the cache only records who maps where) --
+    #    ids; the cache only records who maps where). Every writer drops
+    #    the kept device copy --
     def assign_pages(self, slot: int, pages: List[int], start_block: int = 0):
-        for j, p in enumerate(pages):
-            self.page_table[slot, start_block + j] = p
+        self._table[slot, start_block:start_block + len(pages)] = pages
+        self._table_dev = None
+
+    def repoint(self, slot: int, block: int, page: int):
+        """Map ``block`` of ``slot`` to ``page`` instead (copy-on-write: the
+        slot's private copy replaces the shared page)."""
+        self._table[slot, block] = page
+        self._table_dev = None
 
     def copy_page_exe(self):
         """The compiled copy-on-write program: ``(*pools, src, dst) ->
@@ -410,7 +435,9 @@ class PagedKVCache:
         """Reset a slot's table row to sentinels; returns the page ids the
         caller must hand back to the allocator."""
         pages = self.slot_pages(slot)
-        self.page_table[slot, :] = PAGE_SENTINEL
+        if pages:
+            self._table[slot, :] = PAGE_SENTINEL
+            self._table_dev = None
         return pages
 
     # -- slot free list --

@@ -486,3 +486,213 @@ class TestEngineProtocol:
         # then 3 + 1 pages
         assert [d["live_pages"] for d in dec] == [3, 3, 4]
         assert {d["table_pages"] for d in dec} == {2 * 8}
+
+
+# ------------------------------------- the decode step's operands stay put
+FAMILIES = ["gpt", "decoder", "speculative"]
+
+
+def _toks(n, seed):
+    """Ids both families' vocabularies hold (gpt_tiny's is 128)."""
+    return np.random.RandomState(seed).randint(1, 100, size=(n,)).tolist()
+
+
+def _family_model(family, model):
+    import paddle_tpu as paddle
+
+    if family == "decoder":
+        return model
+    paddle.seed(0)
+    m = gpt_tiny(dropout=0.0, num_layers=2)
+    m.eval()
+    return m
+
+
+def _family_engine(family, m, **kw):
+    return Engine(m, EngineConfig(
+        max_batch_size=3, max_seq_len=64, page_size=8,
+        prefill_buckets=(16, 64),
+        speculative=2 if family == "speculative" else None, **kw))
+
+
+def _kept_against_mirrors(eng):
+    """What is wrong with the invariant after a ``settle``: a kept device
+    array differs from its host mirror although the mirror is not marked
+    changed, or a dead slot's position is not 0."""
+    from paddle_tpu.serving.engine import _OPERANDS
+
+    wrong = []
+    for name in _OPERANDS:
+        if name not in eng._stale and not np.array_equal(
+                np.asarray(eng._dev[name]), getattr(eng, "_" + name)):
+            wrong.append(name)
+    if not eng.cache.table_changed and not np.array_equal(
+            np.asarray(eng.cache.table_device()), eng.cache.page_table):
+        wrong.append("table")
+    dead = [i for i, s in enumerate(eng._slots) if s.request is None]
+    if eng._positions[dead].any() or (
+            "positions" not in eng._stale
+            and np.asarray(eng._dev["positions"])[dead].any()):
+        wrong.append("dead slot's position")
+    return wrong
+
+
+def _drive(family, m, second, eos, mark_all=False):
+    """One seeded run that admits (a prefix hit and a sampled request among
+    them, later than the first three: three slots), finishes by length and
+    by eos, crosses page boundaries (page 8), copies a shared page on
+    write, and ends one slot ``cache_full`` at the sequence budget.
+    ``mark_all`` marks every mirror changed before each step, which is what
+    the engine did before it kept its operands. Returns what the tests
+    below read."""
+    import paddle_tpu as paddle
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.serving.engine import _OPERANDS
+
+    paddle.seed(7)
+    eng = _family_engine(family, m, prefix_cache=True)
+    calls = []
+    name = "verify_program" if family == "speculative" else "decode_program"
+    program = getattr(eng, name)
+    setattr(eng, name, lambda *a, **k: calls.append(1) or program(*a, **k))
+    first = _toks(20, seed=3)
+    reqs = [eng.add_request(p, sp) for p, sp in [
+        (first, SamplingParams(max_new_tokens=30)),
+        (second, SamplingParams(max_new_tokens=25, eos_token_id=eos)),
+        (_toks(50, seed=6), SamplingParams(max_new_tokens=40)),
+        (first[:16] + _toks(5, seed=4), SamplingParams(max_new_tokens=10)),
+        (_toks(9, seed=8), SamplingParams(max_new_tokens=12, do_sample=True,
+                                          temperature=0.8, top_k=5))]]
+    before = obs.snapshot()["counters"]
+    tracing.clear_spans()
+    wrong, shared = [], None
+    while eng.has_unfinished:
+        if mark_all:
+            eng._stale.update(_OPERANDS)
+            eng.cache._table_dev = None
+        slot = reqs[3].slot
+        if shared is None and slot is not None:
+            # someone else takes a reference on the page the prefix-hit
+            # request writes next, once it stands inside a mapped page
+            # (21 prompt tokens: its third page): its next step has to
+            # copy on write
+            page = int(eng.cache.page_table[
+                slot, eng._positions[slot] // eng.cache.page_size])
+            if page != kvc.PAGE_SENTINEL:
+                shared = page
+                eng.page_alloc.retain([shared], owner="test")
+        eng.step()
+        wrong += [(eng._step_i, w) for w in _kept_against_mirrors(eng)]
+    eng.page_alloc.free([shared], owner="test")
+    after = obs.snapshot()["counters"]
+    site = "{site=serving.decode}"
+    return dict(
+        outputs=[r.output_ids for r in reqs],
+        reasons=[r.finish_reason for r in reqs], wrong=wrong,
+        spans=tracing.spans(), program_calls=len(calls),
+        steps=eng._step_i, cow_copies=eng._cow_copies,
+        compiles=after.get("jit.compile.cache_miss" + site, 0)
+        - before.get("jit.compile.cache_miss" + site, 0),
+        hits=after.get("jit.compile.cache_hit" + site, 0)
+        - before.get("jit.compile.cache_hit" + site, 0))
+
+
+@pytest.fixture(scope="module", params=FAMILIES)
+def kept_run(request, model):
+    """(family, the run, the same run with every mirror marked changed
+    before each step): driven once a family, read by the four tests."""
+    family = request.param
+    m = _family_model(family, model)
+    # the eos request: the first of a few prompts whose greedy answer
+    # holds, from its third token on, a token it has not held before; that
+    # token is its eos, so that it ends there and not earlier
+    prompts = [_toks(11, seed=s) for s in range(5, 13)]
+    outs = _family_engine("plain", m).generate(
+        prompts, SamplingParams(max_new_tokens=12))
+    second, eos = next(
+        (p, t) for p, out in zip(prompts, outs)
+        for j, t in enumerate(out) if j >= 2 and t not in out[:j])
+    obs.enable()
+    obs.reset()
+    try:
+        return family, _drive(family, m, second, eos), _drive(
+            family, m, second, eos, mark_all=True)
+    finally:
+        obs.disable()
+        obs.reset()
+
+
+class TestOperandsStayOnTheDevice:
+    """ISSUE 29: between decode steps the page table, tokens, positions and
+    the three sampling rows stay on the device; the host puts one again
+    only when something other than the step changed its host mirror."""
+
+    def test_kept_arrays_equal_the_host_mirrors_after_every_step(
+            self, kept_run):
+        family, run, _ = kept_run
+        assert run["wrong"] == []
+        # the run was what it set out to be
+        assert run["reasons"][:4] == ["length", "eos", "cache_full", "length"]
+        assert run["reasons"][4] == "length" and run["cow_copies"] == 1
+        admits = [e["attrs"] for e in run["spans"]
+                  if e["name"] == "serving/admit"
+                  and "blocked" not in e["attrs"]]
+        assert [a["hit_blocks"] for a in admits] == [0, 0, 0, 2, 0]
+        grown = sum(e["attrs"]["allocated"] for e in run["spans"]
+                    if e["name"] == "serving/decode/grow_pages")
+        assert grown >= 6                     # page boundaries were crossed
+
+    def test_tokens_are_those_of_putting_everything_every_step(
+            self, kept_run):
+        _, run, old = kept_run
+        assert run["outputs"] == old["outputs"]
+        assert run["reasons"] == old["reasons"]
+        assert run["steps"] == old["steps"]
+
+    def test_puts_only_what_an_admission_finish_or_new_page_changed(
+            self, kept_run):
+        """Told from the OTHER spans' attributes, not from the marks: the
+        table travels exactly on the steps after something wrote it, the
+        five rows on those after an admission or a finish (a verify step:
+        tokens and positions always), and nothing else ever."""
+        family, run, old = kept_run
+        quiet = uploads = 0
+        # spans are recorded as they END: an upload's record comes after
+        # the admissions and the grow_pages of its own step and before its
+        # step's settle, so one pass in order sees each upload with exactly
+        # what was written since the upload before it
+        rows = table = False
+        for e in run["spans"]:
+            a = e["attrs"]
+            if e["name"] == "serving/admit" and "blocked" not in a:
+                rows = table = True
+            elif e["name"] == "serving/decode/grow_pages":
+                table |= bool(a["allocated"] or a["cow_copies"]
+                              or a["cache_full"])
+                rows |= bool(a["cache_full"])
+            elif e["name"] == "serving/decode/settle" and a["finished"]:
+                rows = table = True
+            elif e["name"] == "serving/decode/upload":
+                uploads += 1
+                least = 2 if family == "speculative" else 0
+                assert a["table_put"] == int(table), (e, rows, table)
+                assert a["puts"] == int(table) + (5 if rows else least), e
+                quiet += a["puts"] == least
+                rows = table = False
+        assert uploads >= 12 and quiet >= uploads // 3
+        # the old behaviour put all six on every step
+        assert all(e["attrs"]["puts"] == 6 for e in old["spans"]
+                   if e["name"] == "serving/decode/upload")
+
+    def test_program_is_built_once_and_found_afterwards(self, kept_run):
+        """``decode_program()`` is called for the first step's compile and
+        never again (the verify program was compiled with the engine, so
+        not at all): every later step is a dictionary hit, which still
+        counts."""
+        family, run, _ = kept_run
+        first = 0 if family == "speculative" else 1
+        assert run["program_calls"] == first
+        assert run["compiles"] == first
+        uploads = sum(e["name"] == "serving/decode/upload"
+                      for e in run["spans"])
+        assert run["hits"] == uploads - first
