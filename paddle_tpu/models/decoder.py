@@ -1,9 +1,11 @@
 """A decoder-only LM built from a DESCRIPTION of its block.
 
 ``DecoderConfig`` names the kind of each part of the block — norm
-(``rms`` | ``layer``), positions (``rope``), attention (``dense`` |
-``indexed_sparse``), FFN (``swiglu`` | ``moe_swiglu``), router
-(``softmax_topk``) — with their widths; the parts are looked up by kind in
+(``rms`` | ``layer``) and where it stands (``pre`` | ``post``), positions
+(``rope`` | ``none``), the token mixer (``dense`` | ``indexed_sparse`` |
+``gated_delta``; one kind for all layers, or ``layer_types``, one a
+layer), FFN (``swiglu`` | ``moe_swiglu``), router (``softmax_topk``) — with
+their widths; the parts are looked up by kind in
 the tables at the bottom of each section, so the next architecture is a
 description (and at most a new entry in one table), not a third class tree
 beside ``models/gpt.py``. GPT-3's block (learned position table, LayerNorm
@@ -21,10 +23,31 @@ The block, for ``x`` the residual stream::
     g = norm(x);  moe_swiglu: p = softmax_f32(g Wr), top-k experts,
                   renormalised; x += sum_e p_e (silu(g W1_e) * g W3_e) W2_e
 
+A ``gated_delta`` layer (linear attention by the gated delta rule,
+arXiv:2412.06464) mixes tokens through a recurrent state instead of a
+cache of keys: with ``h`` the layer's input,
+
+    q~, k~, v~ = h Wq, h Wk, h Wv;  each channel through a causal depthwise
+        convolution of width ``linear_conv_kernel`` over time, then SiLU
+    q = q' / |q'| * dk^-1/2,  k = k' / |k'|              (per head, eps 1e-6)
+    b = 2 sigmoid(h Wb)  (the 2: ``linear_allow_neg_eigval``)
+    g = -exp(A_log) softplus(h Wa + dt_bias),  a = exp(g)       (float32)
+    S_t = a_t S_{t-1} + b_t (v_t - a_t S_{t-1} k_t) k_t^T,  o_t = S_t q_t
+    y = RMSNorm_dv(o) * silu(h Wg), then Wo
+
+It keeps per slot the float32 state ``S`` (packed, ``kernels/gated_delta``)
+and the last ``linear_conv_kernel - 1`` inputs of the convolution; prefill
+and extend run the chunked form from a given state and tail, decode the
+recurrent step (``kernels/gated_delta``).
+
 It speaks the serving engine's whole protocol (serving/README.md):
-``cache_pools()`` declares the per-layer pools (K and V token-major, one
+``cache_pools()`` declares the paged pools of the layers that keep keys (K
+and V token-major, one
 "head" of ``H_kv * D``, so that a token's K is one run of bytes for the
-sparse read; the indexer's keys, in whole 128-lane rows), ``prefill_with_cache`` / ``extend_step`` /
+sparse read, or head-major ``[pages, H_kv, page, D]`` for the paged-decode
+kernel, ``kv_layout``; the indexer's keys, in whole 128-lane rows),
+``state_pools()`` the slot-indexed state of the layers that keep a
+recurrence; ``prefill_with_cache`` / ``extend_step`` /
 ``decode_step`` are pure functions of (parameters, pools, page table).
 ONE attention routine (``attend``) serves all three: queries at
 ``start .. start + T - 1`` against views of the pools, in chunks of queries
@@ -39,7 +62,9 @@ combine.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -63,13 +88,29 @@ class DecoderConfig:
     max_context: int = 128        # longest sequence the positions serve
     norm: str = "rms"             # rms | layer
     norm_eps: float = 1e-6
-    position: str = "rope"
+    norm_placement: str = "pre"   # pre: x + f(norm(x)); post: x + norm(f(x))
+    position: str = "rope"        # rope | none
     rope_theta: float = 1e7
-    qk_norm: bool = True          # RMSNorm over each q and k head
-    attention: str = "indexed_sparse"   # dense | indexed_sparse
+    # RMSNorm on q and k: True / "head" over each head, "full" over all of a
+    # token's heads together, False none
+    qk_norm: Union[bool, str] = True
+    attention: str = "indexed_sparse"   # dense | indexed_sparse | gated_delta
+    # one kind a layer (keys of ATTENTIONS); None: ``attention`` for all
+    layer_types: Optional[Tuple[str, ...]] = None
+    # how a dense layer's K and V pages lie: "token" [pages, 1, page,
+    # H_kv * D], "head" [pages, H_kv, page, D] (what kernels/paged_attention
+    # reads: its decode goes through serving.kv_cache.paged_decode_attend)
+    kv_layout: str = "token"
     index_heads: int = 4
     index_head_dim: int = 8
     index_topk: int = 16
+    # a gated_delta layer's widths (key and value heads are as many)
+    linear_heads: int = 4
+    linear_key_head_dim: int = 8
+    linear_value_head_dim: int = 16
+    linear_conv_kernel: int = 4
+    linear_allow_neg_eigval: bool = True
+    gdn_chunk: int = 64           # tokens per chunk of the chunked form
     ffn: str = "moe_swiglu"       # swiglu | moe_swiglu
     intermediate_size: int = 128  # swiglu's width; an expert's in moe_swiglu
     router: str = "softmax_topk"
@@ -86,14 +127,34 @@ class DecoderConfig:
     query_chunk: int = 128        # queries per chunk of ``attend``
 
     def __post_init__(self):
+        if self.qk_norm is True:
+            self.qk_norm = "head"
         for field, table in (("norm", NORMS), ("position", POSITIONS),
                              ("attention", ATTENTIONS), ("ffn", FFNS),
-                             ("router", ROUTERS)):
+                             ("router", ROUTERS),
+                             ("norm_placement", NORM_PLACEMENTS),
+                             ("kv_layout", KV_LAYOUTS),
+                             ("qk_norm", QK_NORMS)):
             if getattr(self, field) not in table:
                 raise ValueError(f"{field} {getattr(self, field)!r}; "
-                                 f"want one of {sorted(table)}")
+                                 f"want one of {sorted(table, key=str)}")
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            unknown = sorted(set(self.layer_types) - set(ATTENTIONS))
+            if unknown:
+                raise ValueError(f"layer_types has {unknown}; want entries "
+                                 f"of {sorted(ATTENTIONS)}")
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"num_layers is {self.num_layers}")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The token mixer's kind, one a layer."""
+        return self.layer_types or (self.attention,) * self.num_layers
 
 
 # ------------------------------------------------------------------ norms
@@ -115,6 +176,9 @@ def layer_norm(x, p, pre, eps):
 
 
 NORMS = {"rms": (rms_norm, False), "layer": (layer_norm, True)}  # fn, bias
+NORM_PLACEMENTS = ("pre", "post")
+QK_NORMS = (False, "head", "full")
+KV_LAYOUTS = ("token", "head")
 
 
 def _norm_shapes(cfg, pre, width):
@@ -142,7 +206,7 @@ def rope(x, pos, theta):
                            axis=-1).astype(x.dtype)
 
 
-POSITIONS = {"rope": rope}
+POSITIONS = {"rope": rope, "none": lambda x, pos, theta: x}
 
 
 # -------------------------------------------------------------- attention
@@ -154,16 +218,17 @@ def _mm(x, w):
     return jnp.dot(x, w)
 
 
-def _attn_shapes(cfg, pre):
+def _attn_shapes(cfg, pre, sparse):
     H, D = cfg.hidden_size, cfg.head_dim
     s = {pre + ".wq": (H, cfg.num_heads * D),
          pre + ".wk": (H, cfg.num_kv_heads * D),
          pre + ".wv": (H, cfg.num_kv_heads * D),
          pre + ".wo": (cfg.num_heads * D, H)}
     if cfg.qk_norm:
-        s[pre + ".q_norm.weight"] = (D,)
-        s[pre + ".k_norm.weight"] = (D,)
-    if cfg.attention == "indexed_sparse":
+        full = cfg.qk_norm == "full"
+        s[pre + ".q_norm.weight"] = (cfg.num_heads * D if full else D,)
+        s[pre + ".k_norm.weight"] = (cfg.num_kv_heads * D if full else D,)
+    if sparse:
         Hi, Di = cfg.index_heads, cfg.index_head_dim
         s.update({pre + ".index.wq": (H, Hi * Di),
                   pre + ".index.wk": (H, Di),
@@ -258,30 +323,42 @@ def _indexer(cfg, p, pre, h, pos):
     return qi, w * jnp.float32(Di ** -0.5 * Hi ** -0.5), ki
 
 
-def attention(cfg, p, pre, h, start, cache=None, flash_ok=False):
-    """The attention part of a block over normed ``h [B, T, hidden]`` whose
+def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
+              lengths=None, sparse=False):
+    """The attention part of a block over ``h [B, T, hidden]`` whose
     tokens sit at ``start[b] .. start[b] + T - 1``. Without ``cache``
     (prefill) the keys are the ones just computed; with ``cache`` (the
     layer's pools and the page table) they are written into the pools first
     and read back through the table. Returns (out [B, T, hidden], new):
-    ``new`` the per-pool entries, ``[B, 1, T, width]`` each without a cache,
-    the updated pools with one."""
+    ``new`` the per-pool entries (``[B, 1, T, width]`` each, or ``[B, H_kv,
+    T, D]`` where ``kv_layout`` is "head") without a cache, the updated
+    pools with one. ``lengths`` is not its concern: a padded token's keys
+    lie behind every real query."""
     from ..serving import kv_cache as _kvc
 
     B, T, _ = h.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    q = _mm(h, p[pre + ".wq"]).reshape(B, T, Hq, D)
-    k = _mm(h, p[pre + ".wk"]).reshape(B, T, Hkv, D)
-    v = _mm(h, p[pre + ".wv"]).reshape(B, T, Hkv, D)
-    if cfg.qk_norm:
+
+    def heads(w, n, norm=None):
+        x = _mm(h, p[pre + w])
+        if norm and cfg.qk_norm == "full":   # over all of a token's heads
+            x = rms_norm(x, p, pre + norm, cfg.norm_eps)
+        return x.reshape(B, T, n, D)
+
+    q, k = heads(".wq", Hq, ".q_norm"), heads(".wk", Hkv, ".k_norm")
+    v = heads(".wv", Hkv)
+    if cfg.qk_norm == "head":
         q = rms_norm(q, p, pre + ".q_norm", cfg.norm_eps)
         k = rms_norm(k, p, pre + ".k_norm", cfg.norm_eps)
     turn = POSITIONS[cfg.position]
     q, k = turn(q, pos, cfg.rope_theta), turn(k, pos, cfg.rope_theta)
-    sparse = cfg.attention == "indexed_sparse"
     index = _indexer(cfg, p, pre + ".index", h, pos) if sparse else None
-    fresh = [k.reshape(B, 1, T, Hkv * D), v.reshape(B, 1, T, Hkv * D)]
+    head_major = cfg.kv_layout == "head"
+    if head_major:
+        fresh = [k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)]
+    else:
+        fresh = [k.reshape(B, 1, T, Hkv * D), v.reshape(B, 1, T, Hkv * D)]
     Di = cfg.index_head_dim
     if sparse:
         fresh.append(jnp.pad(index[2][:, None], (
@@ -306,7 +383,12 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False):
     pools = [_kvc.paged_write_kv(pool, new, table, start)
              for pool, new in zip(pools, fresh)]
     L = table.shape[1] * pools[0].shape[2]
-    if sparse and T == 1 and _kvc.default_paged_impl() == "pallas":
+    if head_major and T == 1:
+        # the paged attend, kernel or oracle as kv_cache says
+        o = _kvc.paged_decode_attend(q.transpose(0, 2, 1, 3), pools[0],
+                                     pools[1], table, start)
+        o = o.transpose(0, 2, 1, 3)
+    elif sparse and T == 1 and _kvc.default_paged_impl() == "pallas":
         from ..kernels.sparse_attention import (sparse_paged_decode,
                                                 topk_indices)
 
@@ -320,8 +402,12 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False):
         o = sparse_paged_decode(q[:, 0], pools[0], pools[1], table, idx, n)
         o = o[:, None]
     else:
-        view = lambda pool, heads: _kvc.paged_gather(pool, table)[:, 0] \
-            .reshape(B, L, heads, -1)
+        if head_major:
+            view = lambda pool, heads: _kvc.paged_gather(pool, table) \
+                .transpose(0, 2, 1, 3)
+        else:
+            view = lambda pool, heads: _kvc.paged_gather(pool, table)[:, 0] \
+                .reshape(B, L, heads, -1)
         idx_view = None if not sparse else (
             index[0], index[1],
             _kvc.paged_gather(pools[2], table)[:, 0, :, :Di])
@@ -330,7 +416,139 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False):
     return _mm(o.reshape(B, T, Hq * D), p[pre + ".wo"]), tuple(pools)
 
 
-ATTENTIONS = {"dense": attention, "indexed_sparse": attention}
+def _kv_pools(cfg, sparse):
+    """[(name, heads, width)] of an attention layer's paged pools."""
+    if cfg.kv_layout == "head":
+        pools = [("k", cfg.num_kv_heads, cfg.head_dim),
+                 ("v", cfg.num_kv_heads, cfg.head_dim)]
+    else:
+        kv = cfg.num_kv_heads * cfg.head_dim
+        pools = [("k", 1, kv), ("v", 1, kv)]
+    if sparse:
+        pools.append(("index_k", 1, index_pool_width(cfg)))
+    return pools
+
+
+# ------------------------------------------------- gated delta-rule layers
+
+def _gdn_widths(cfg):
+    """(heads, dk, dv, channels of the convolution)."""
+    H, dk, dv = (cfg.linear_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    return H, dk, dv, H * (2 * dk + dv)
+
+
+def _gdn_shapes(cfg, pre):
+    Hd = cfg.hidden_size
+    H, dk, dv, C = _gdn_widths(cfg)
+    return {pre + ".wq": (Hd, H * dk), pre + ".wk": (Hd, H * dk),
+            pre + ".wv": (Hd, H * dv), pre + ".wg": (Hd, H * dv),
+            pre + ".wa": (Hd, H), pre + ".wb": (Hd, H),
+            pre + ".A_log": (H,), pre + ".dt_bias": (H,),
+            pre + ".conv.weight": (C, cfg.linear_conv_kernel),
+            pre + ".o_norm.weight": (dv,),
+            pre + ".wo": (H * dv, Hd)}
+
+
+def _gdn_state_pools(cfg):
+    """[(name, per-slot shape, dtype)] of a gated_delta layer's state: the
+    packed float32 ``S`` and the convolution's last inputs."""
+    from ..kernels.gated_delta import packed_shape
+
+    H, dk, dv, C = _gdn_widths(cfg)
+    return [("gdn_state", packed_shape(H, dk, dv), "float32"),
+            ("gdn_conv", (cfg.linear_conv_kernel - 1, C), cfg.dtype)]
+
+
+def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
+                lengths=None):
+    """A gated delta-rule layer over ``h [B, T, hidden]`` (the module's
+    docstring has the equations). Only the first ``lengths[b]`` tokens of a
+    row are real: the rest neither move the state nor enter the tail.
+    ``cache`` is ``(state, conv, row)``, the layer's two state buffers
+    ``[rows, ...]`` and where this call's state lives in them: ``None`` for
+    rows ``[0, B)`` (decode, ``T = 1``), else the one row of a ``B = 1``
+    extend. Without ``cache`` the state starts at zero (prefill). Returns
+    (out [B, T, hidden], new): the updated buffers with a cache, else the
+    end state and tail ``[B, ...]`` for the engine to install."""
+    from ..kernels import gated_delta as _gdn
+
+    B, T, _ = h.shape
+    H, dk, dv, C = _gdn_widths(cfg)
+    Kc = cfg.linear_conv_kernel
+    f32 = jnp.float32
+    if cache is None:
+        row, tail = None, jnp.zeros((B, Kc - 1, C), h.dtype)
+    else:
+        state, conv, row = cache
+        if row is None and T != 1:
+            raise NotImplementedError(
+                "gated_delta: several tokens a slot over every slot (the "
+                "speculative verify step) would need the state of each "
+                "position kept to roll a rejected draft back")
+        tail = conv[:B] if row is None else \
+            lax.dynamic_index_in_dim(conv, row, keepdims=True)
+    x = jnp.concatenate([_mm(h, p[pre + w]) for w in (".wq", ".wk", ".wv")],
+                        axis=-1)
+    win = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # [B, T+Kc-1, C]
+    w = p[pre + ".conv.weight"].astype(f32)
+    y = jax.nn.silu(sum(win[:, j:j + T].astype(f32) * w[:, j]
+                        for j in range(Kc)))
+    n = jnp.full((B,), T, jnp.int32) if lengths is None else lengths
+    # the last Kc - 1 real inputs: real token t is row t + Kc - 1 of ``win``
+    tail = jax.vmap(lambda a, i: lax.dynamic_slice_in_dim(a, i, Kc - 1))(
+        win, n)
+    unit = lambda a: a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+    q = unit(y[..., :H * dk].reshape(B, T, H, dk)) * f32(dk ** -0.5)
+    k = unit(y[..., H * dk:2 * H * dk].reshape(B, T, H, dk))
+    v = y[..., 2 * H * dk:].reshape(B, T, H, dv)
+    beta = jax.nn.sigmoid(jnp.dot(h, p[pre + ".wb"],
+                                  preferred_element_type=f32))
+    if cfg.linear_allow_neg_eigval:
+        beta = 2.0 * beta
+    g = -jnp.exp(p[pre + ".A_log"].astype(f32)) * jax.nn.softplus(
+        jnp.dot(h, p[pre + ".wa"], preferred_element_type=f32)
+        + p[pre + ".dt_bias"].astype(f32))
+    real = (jnp.arange(T)[None, :] < n[:, None])[..., None]
+    beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
+
+    if cache is not None and row is None:
+        o, state = _gdn.gdn_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                 beta[:, 0], state)
+        o = o[:, None]
+        new = (state, lax.dynamic_update_slice_in_dim(
+            conv, tail.astype(conv.dtype), 0, axis=0))
+    else:
+        S0 = jnp.zeros((B, H, dv, dk), f32) if cache is None else \
+            _gdn.unpack_state(
+                lax.dynamic_index_in_dim(state, row, keepdims=True), H)
+        o, S = jax.vmap(lambda *a: _gdn.gdn_chunked(*a, cfg.gdn_chunk))(
+            q, k, v, g, beta, S0)
+        S = _gdn.pack_state(S)
+        if cache is None:
+            new = (S, tail)
+        else:
+            new = (lax.dynamic_update_slice_in_dim(state, S, row, axis=0),
+                   lax.dynamic_update_slice_in_dim(
+                       conv, tail.astype(conv.dtype), row, axis=0))
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) \
+        * p[pre + ".o_norm.weight"].astype(f32)
+    gate = jax.nn.silu(_mm(h, p[pre + ".wg"]).astype(f32))
+    y = (o.reshape(B, T, H * dv) * gate).astype(h.dtype)
+    return _mm(y, p[pre + ".wo"]), new
+
+
+#: kind -> (the layer's function, its parameters' shapes, its paged pools
+#: [(name, heads, width)], its slot state [(name, shape, dtype)])
+ATTENTIONS = {
+    "dense": (attention, functools.partial(_attn_shapes, sparse=False),
+              functools.partial(_kv_pools, sparse=False), lambda c: []),
+    "indexed_sparse": (functools.partial(attention, sparse=True),
+                       functools.partial(_attn_shapes, sparse=True),
+                       functools.partial(_kv_pools, sparse=True),
+                       lambda c: []),
+    "gated_delta": (gated_delta, _gdn_shapes, lambda c: [], _gdn_state_pools),
+}
 
 
 # -------------------------------------------------------------------- FFN
@@ -422,10 +640,10 @@ def param_shapes(cfg: DecoderConfig) -> dict:
     """{name: shape} of every parameter, in the names the model uses."""
     H = cfg.hidden_size
     s = {"embed.weight": (cfg.vocab_size, H)}
-    for l in range(cfg.num_layers):
+    for l, kind in enumerate(cfg.kinds):
         pre = f"layers.{l}"
         s.update(_norm_shapes(cfg, pre + ".attn_norm", H))
-        s.update(_attn_shapes(cfg, pre + ".attn"))
+        s.update(ATTENTIONS[kind][1](cfg, pre + ".attn"))
         s.update(_norm_shapes(cfg, pre + ".ffn_norm", H))
         s.update(FFNS[cfg.ffn][1](cfg, pre + ".ffn"))
     s.update(_norm_shapes(cfg, "final_norm", H))
@@ -438,13 +656,42 @@ def is_norm_scale(name: str) -> bool:
     return name.endswith("norm.weight")
 
 
-def block(cfg, p, l, x, start, cache=None, flash_ok=False):
+def initial_value(name: str, shape, key, std: float):
+    """A parameter's initial float32 value by the kind its name states:
+    norm scales 1, biases 0, a gated_delta layer's ``A_log`` = log U(0, 16),
+    ``dt_bias`` = softplus^-1 of a step drawn log-uniform in [0.001, 0.1]
+    and convolution U(-k^-1/2, k^-1/2) (the public implementation's), every
+    other leaf N(0, std)."""
+    if is_norm_scale(name):
+        return jnp.ones(shape, jnp.float32)
+    if name.endswith(".dt_bias"):
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                        np.log(1e-3), np.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    if name.endswith(".bias"):
+        return jnp.zeros(shape, jnp.float32)
+    if name.endswith(".A_log"):
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0))
+    if name.endswith(".conv.weight"):
+        r = shape[-1] ** -0.5
+        return jax.random.uniform(key, shape, jnp.float32, -r, r)
+    return std * jax.random.normal(key, shape, jnp.float32)
+
+
+def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None):
     """One block over the residual stream ``x [B, T, hidden]``: returns
     (x, the layer's new pool entries, its routing statistics)."""
     pre = f"layers.{l}"
-    a, new = ATTENTIONS[cfg.attention](
-        cfg, p, pre + ".attn", _norm(cfg, x, p, pre + ".attn_norm"), start,
-        cache, flash_ok)
+    mixer = ATTENTIONS[cfg.kinds[l]][0]
+    if cfg.norm_placement == "post":
+        a, new = mixer(cfg, p, pre + ".attn", x, start, cache, flash_ok,
+                       lengths)
+        x = x + _norm(cfg, a, p, pre + ".attn_norm")
+        y, stats = ffn(cfg, p, pre + ".ffn", x)
+        return x + _norm(cfg, y, p, pre + ".ffn_norm"), new, stats
+    a, new = mixer(cfg, p, pre + ".attn",
+                   _norm(cfg, x, p, pre + ".attn_norm"), start, cache,
+                   flash_ok, lengths)
     x = x + a
     y, stats = ffn(cfg, p, pre + ".ffn", _norm(cfg, x, p, pre + ".ffn_norm"))
     return x + y, new, stats
@@ -462,13 +709,9 @@ class DecoderLM(Layer):
         for i, (name, shape) in enumerate(param_shapes(cfg).items()):
             if cfg.init == "zeros":
                 v = jnp.zeros(shape, dt)
-            elif is_norm_scale(name):
-                v = jnp.ones(shape, dt)
-            elif name.endswith(".bias"):
-                v = jnp.zeros(shape, dt)
             else:
-                v = (cfg.initializer_range * jax.random.normal(
-                    jax.random.fold_in(key, i), shape, jnp.float32)).astype(dt)
+                v = initial_value(name, shape, jax.random.fold_in(key, i),
+                                  cfg.initializer_range).astype(dt)
             self.add_parameter(name, Parameter(v))
 
     def _p(self):
@@ -479,32 +722,47 @@ class DecoderLM(Layer):
     def max_context(self) -> int:
         return self.cfg.max_context
 
+    def _pools(self, which: int):
+        """The pools of every layer kind present, each with the layers that
+        hold it (layers of one kind declare the same pools)."""
+        kinds, out = self.cfg.kinds, []
+        for kind in dict.fromkeys(kinds):
+            layers = tuple(l for l, k in enumerate(kinds) if k == kind)
+            out += [spec + (layers,)
+                    for spec in ATTENTIONS[kind][which](self.cfg)]
+        return out
+
     def cache_pools(self):
-        """[(name, heads, width)] of the per-layer pools: K and V with a
-        token's heads side by side, and the indexer's keys."""
-        cfg = self.cfg
-        kv = cfg.num_kv_heads * cfg.head_dim
-        pools = [("k", 1, kv), ("v", 1, kv)]
-        if cfg.attention == "indexed_sparse":
-            pools.append(("index_k", 1, index_pool_width(cfg)))
-        return pools
+        """[(name, heads, width)] of the paged pools: K and V (a token's
+        heads side by side, or head-major), and the indexer's keys; with
+        the layers that hold them as a fourth entry where not every layer
+        does."""
+        L = self.cfg.num_layers
+        return [spec[:3] if len(spec[3]) == L else spec
+                for spec in self._pools(2)]
+
+    def state_pools(self):
+        """[(name, per-slot shape, dtype, layers)] of the slot-indexed
+        state: a gated_delta layer's packed ``S`` and convolution tail."""
+        return self._pools(3)
 
     def selected_tokens(self, ctx):
         """Cached positions a decode step's attention reads for contexts
         ``ctx`` (array of live tokens per slot)."""
-        if self.cfg.attention != "indexed_sparse":
+        if "indexed_sparse" not in self.cfg.kinds:
             return ctx
         return np.minimum(ctx, self.cfg.index_topk)
 
     step_stats = ("experts_touched", "expert_max_load")  # per layer
 
-    def _forward(self, ids, start, caches=None, flash_ok=False):
+    def _forward(self, ids, start, caches=None, flash_ok=False, lengths=None):
         cfg, p = self.cfg, self._p()
         x = p["embed.weight"][ids]
         news, stats = [], []
         for l in range(cfg.num_layers):
             x, new, st = block(cfg, p, l, x, start,
-                               None if caches is None else caches[l], flash_ok)
+                               None if caches is None else caches[l], flash_ok,
+                               lengths)
             news.append(tuple(Tensor(a) for a in new))
             stats.append(st)
         return x, news, jnp.stack(stats)
@@ -527,23 +785,28 @@ class DecoderLM(Layer):
         ``[B, 1, T, width]`` for the engine to install)."""
         ids = _ids(input_ids)
         B, T = ids.shape
+        lengths = None if lengths is None else _ids(lengths)
         x, news, _ = self._forward(ids, jnp.zeros((B,), jnp.int32),
-                                   flash_ok=True)
+                                   flash_ok=True, lengths=lengths)
         if lengths is None:
             last = x[:, T - 1]
         else:
-            idx = jnp.clip(_ids(lengths) - 1, 0, T - 1)
+            idx = jnp.clip(lengths - 1, 0, T - 1)
             last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
         return Tensor(self._logits(last)), news
 
-    def extend_step(self, tokens, caches, positions):
+    def extend_step(self, tokens, caches, positions, lengths=None):
         """``tokens [B, T]`` at ``positions[b] + t`` over the paged pools:
-        (logits ``[B, T, V]``, per layer the updated pools)."""
+        (logits ``[B, T, V]``, per layer the updated pools). ``lengths``
+        says how many of a row's tokens are real, which a layer with
+        recurrent state has to know."""
         ids = _ids(tokens)
         ids = ids[:, None] if ids.ndim == 1 else ids
         start = jnp.broadcast_to(_ids(positions), (ids.shape[0],))
         entries = [tuple(map(_raw, e)) for e in caches]
-        x, news, stats = self._forward(ids, start, entries)
+        x, news, stats = self._forward(
+            ids, start, entries,
+            lengths=None if lengths is None else _ids(lengths))
         return Tensor(self._logits(x)), news, Tensor(stats)
 
     def decode_step(self, tokens, caches, positions):

@@ -143,11 +143,15 @@ def _param_dtype(params: Dict[str, jax.Array]):
     return jnp.float32
 
 
-def _updated(new) -> Tuple[Tuple[jax.Array, ...], ...]:
-    """The pools' buffer tuples a program returns (by pool, then by layer),
-    from the per-layer entries (``(k, v)`` Tensor pairs, or one Tensor per
-    declared pool) ``decode_step`` / ``extend_step`` hand back."""
-    return tuple(tuple(t._value for t in pool) for pool in zip(*new))
+def _updated(cache, new) -> Tuple[Tuple[jax.Array, ...], ...]:
+    """The pools' buffer tuples a program returns (by pool, then by the
+    pool's layers), from the per-layer entries (``(k, v)`` Tensor pairs, or
+    one Tensor per pool the layer holds) ``decode_step`` / ``extend_step``
+    hand back; ``cache`` (a ``PagedKVCache``, None for ``cached_generate``'s
+    dense pair) knows which layers hold which pool."""
+    values = [tuple(t._value for t in layer) for layer in new]
+    return tuple(zip(*values)) if cache is None \
+        else cache.pools_from_layers(values)
 
 
 def _write_prompt(write, pools, kvs):
@@ -167,14 +171,21 @@ def _write_prompt_dense(kc, vc, kvs):
                          (kc, vc), kvs)
 
 
-def _write_prompt_paged(pools, kvs, page_row):
-    """Each layer's prompt entries ``[1, heads, T, width]`` into that
-    layer's page pools at positions ``[0, T)``, routed by the slot's table
-    row: one scatter of the bucket's pages per pool (``paged_write_kv``).
-    Blocks past the allocated pages (sentinels) land on the trash page."""
+def _write_prompt_paged(cache, pools, kvs, page_row, slot=None):
+    """Each layer's prompt entries into that layer's pools: a paged pool's
+    ``[1, heads, T, width]`` at positions ``[0, T)``, routed by the slot's
+    table row, one scatter of the bucket's pages per pool
+    (``paged_write_kv``; blocks past the allocated pages, sentinels, land
+    on the trash page); a state pool's end state ``[1, ...]`` onto row
+    ``slot``."""
     table, zero = page_row[None, :], jnp.zeros((1,), jnp.int32)
-    return _write_prompt(
-        lambda c, new: paged_write_kv(c, new, table, zero), pools, kvs)
+    paged = len(cache.pool_specs)
+    state_row = lambda c, new: lax.dynamic_update_slice_in_dim(
+        c, new.astype(c.dtype), slot, axis=0)
+    return tuple(
+        tuple((paged_write_kv(c, new, table, zero) if j < paged
+               else state_row(c, new)) for c, new in zip(pool, news))
+        for j, (pool, news) in enumerate(zip(pools, _updated(cache, kvs))))
 
 
 def _call(model, params, method, *args, **kw):
@@ -240,7 +251,7 @@ def cached_generate(model, input_ids, *, max_new_tokens: int = 32,
         nxt = _sampling.sample_static(
             logits._value, key, do_sample=do_sample,
             temperature=temperature, top_k=top_k)
-        return (nxt.astype(tokens.dtype),) + _updated(new)
+        return (nxt.astype(tokens.dtype),) + _updated(None, new)
 
     dkey = ("decode", B, S_max, str(tok_dtype), str(dt),
             do_sample, float(temperature), int(top_k))
@@ -311,6 +322,11 @@ class EngineConfig:
     # by the n-gram draft proposer; greedy rows emit up to k+1 tokens per
     # step with output identical to one-at-a-time greedy decode.
     speculative: Optional[Union[bool, int, "SpeculativeConfig"]] = None
+    # rows of the snapshot pool of a model that keeps recurrent state
+    # (kv_cache.PagedKVCache): how many block boundaries of cached prompts
+    # the prefix cache can resume such a model at. Default: two a slot with
+    # the prefix cache on, none without it.
+    state_snapshots: Optional[int] = None
 
     def __post_init__(self):
         if isinstance(self.speculative, bool):
@@ -383,7 +399,24 @@ class Engine:
         pools = declared() if declared is not None else [
             ("k", cfg.num_kv_heads, cfg.head_dim),
             ("v", cfg.num_kv_heads, cfg.head_dim)]
-        self.donate_argnums = tuple(range(1, 1 + len(pools)))
+        # the slot-indexed state of a model that keeps a recurrence
+        declared = getattr(model, "state_pools", None)
+        state = declared() if declared is not None else []
+        self._stateful = bool(state)
+        if state and self.config.speculative is not None:
+            raise ValueError(
+                "speculative decoding is refused for "
+                f"{type(model).__name__}: it declares recurrent state "
+                f"({', '.join(sp[0] for sp in state)}), and the verify step "
+                "rolls a rejected draft back by not advancing positions "
+                "over K/V it wrote, which a state that has absorbed the "
+                "draft cannot do")
+        snapshots = self.config.state_snapshots
+        if snapshots is None:
+            snapshots = 2 * self.config.max_batch_size
+        if not (state and self.config.prefix_cache):
+            snapshots = 0
+        self.donate_argnums = tuple(range(1, 1 + len(pools) + len(state)))
         self.params, _ = model.functional_state()
         dt = (self.config.cache_dtype if self.config.cache_dtype is not None
               else _param_dtype(self.params))
@@ -394,8 +427,14 @@ class Engine:
             num_pages = B * (S_max // ps) + 1  # full budget + trash page
         self.cache = PagedKVCache(cfg.num_layers, B, pools[0][1], S_max,
                                   pools[0][2], dt, page_size=ps,
-                                  num_pages=num_pages, pools=pools)
+                                  num_pages=num_pages, pools=pools,
+                                  state_pools=state, num_snapshots=snapshots)
         self.page_alloc = PageAllocator(num_pages)
+        # snapshot ids [1, snapshots], refcounted as pages are: the trie
+        # holds one reference a node that carries one, an admission one on
+        # the snapshot it resumes from until the restore is issued
+        self.snapshot_alloc: Optional[PageAllocator] = \
+            PageAllocator(snapshots + 1) if snapshots else None
         _metrics.gauge("serving.kv_cache.bytes", self.cache.nbytes)
         _obs_memory.record_kv_cache(self.cache.nbytes)
         self.scheduler = Scheduler(B)
@@ -423,10 +462,14 @@ class Engine:
         self.prefix_cache: Optional[PrefixCache] = None
         if self.config.prefix_cache:
             self.prefix_cache = PrefixCache(self.cache.page_size,
-                                            self.page_alloc)
+                                            self.page_alloc,
+                                            self.snapshot_alloc)
             # pages can be shared from here on: have the copy-on-write
-            # program compiled now, never between two decode steps
+            # program compiled now, never between two decode steps (and the
+            # program that takes and restores snapshots with it)
             self.cache.copy_page_exe()
+            if self.snapshot_alloc is not None:
+                self.cache.copy_state_exe()
         self.spec: Optional[SpeculativeConfig] = self.config.speculative
         # cumulative speculation accounting (greedy rows only — sampled
         # rows ignore drafts and always emit 1 token from position 0)
@@ -550,18 +593,28 @@ class Engine:
         model, n = self.model, len(self.cache.pools)
         nb = self.cache.num_blocks
 
+        cache = self.cache
+
         @jax.named_scope("serving/prefill")
         def paged_prefill_fn(p, *a):
-            pools, (ids, page_row, length) = a[:n], a[n:]
+            pools, (ids, page_row, length, *slot) = a[:n], a[n:]
             logits, kvs, _ = _call(model, p, "prefill_with_cache",
                                    Tensor(ids),
                                    lengths=Tensor(length[None]))
-            return (logits,) + _write_prompt_paged(pools, kvs, page_row)
+            return (logits,) + _write_prompt_paged(cache, pools, kvs,
+                                                   page_row, *slot)
 
         args = (self.params, *self.cache.pools,
                 jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
-                jnp.int32(1))
+                jnp.int32(1)) + self._slot_arg()
         return paged_prefill_fn, args
+
+    def _slot_arg(self, slot: int = 0) -> Tuple:
+        """What the prefill and extend programs of a model with recurrent
+        state take last: the slot whose state row they write (and, extend,
+        start from). Nothing for any other model: its programs are the ones
+        they were."""
+        return (jnp.int32(slot),) if self._stateful else ()
 
     def decode_program(self):
         """(fn, example_args) for the batched decode step — see
@@ -604,7 +657,7 @@ class Engine:
                 # array the host fetches
                 nxt = jnp.concatenate(
                     [nxt, stats.astype(jnp.int32).reshape(-1)])
-            return (nxt, next_tokens, next_positions) + _updated(new)
+            return (nxt, next_tokens, next_positions) + _updated(cache, new)
 
         args = (self.params, *self.cache.pools,
                 jnp.zeros((B, nb), jnp.int32),
@@ -627,18 +680,21 @@ class Engine:
 
         @jax.named_scope("serving/extend")
         def extend_fn(p, *a):
-            pools, (ids, page_row, start, length) = a[:n], a[n:]
+            pools, (ids, page_row, start, length, *slot) = a[:n], a[n:]
+            # a model with recurrent state starts from its slot's row and
+            # has to know which tokens are padding
+            more = {"lengths": Tensor(length[None])} if slot else {}
             lv, new, _ = _call(                     # logits [1, T, V]
                 model, p, "extend_step", Tensor(ids),
-                cache.layer_entries(pools, page_row[None, :]),
-                Tensor(start[None]))
+                cache.layer_entries(pools, page_row[None, :], *slot),
+                Tensor(start[None]), **more)
             idx = jnp.clip(length - 1, 0, T - 1)
             last = lax.dynamic_index_in_dim(lv[0], idx, keepdims=False)
-            return (last[None],) + _updated(new)  # [1, V], like prefill
+            return (last[None],) + _updated(cache, new)  # [1, V], like prefill
 
         args = (self.params, *self.cache.pools,
                 jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
-                jnp.int32(0), jnp.int32(1))
+                jnp.int32(0), jnp.int32(1)) + self._slot_arg()
         return extend_fn, args
 
     def verify_program(self, k: Optional[int] = None):
@@ -676,7 +732,8 @@ class Engine:
             targets = jnp.argmax(lv, axis=-1).astype(jnp.int32)
             sampled0 = _sampling.sample_batched(lv[:, 0], key, temps,
                                                 top_ks, greedy)
-            return (targets, sampled0.astype(jnp.int32)) + _updated(new)
+            return (targets, sampled0.astype(jnp.int32)) \
+                + _updated(cache, new)
 
         args = (self.params, *self.cache.pools,
                 jnp.zeros((B, nb), jnp.int32),
@@ -814,10 +871,22 @@ class Engine:
     def _admit_one(self, req: Request) -> bool:
         """One admission, from the peek to the first token on the host, as
         one ``serving/admit`` span over its phases; False (the span and its
-        ``alloc`` child say ``blocked=1``) when the page pool is short and
-        the request stays queued."""
+        ``alloc`` child say ``blocked=1``) when the page pool (or, for a
+        model with recurrent state, the snapshot pool) is short and the
+        request stays queued.
+
+        A model with recurrent state resumes where a SNAPSHOT lies, not
+        where the pages reach: of the ``hit_blocks`` the trie matched only
+        the first ``snapshot_blocks`` (the deepest node on the path that
+        carries a snapshot) are spliced, the snapshot is copied into the
+        slot's state row (``serving/admit/restore``), and every token behind
+        it runs again into pages of the request's own. It takes a snapshot
+        where the prompt left the cached path and at the prompt's last
+        whole block (``serving/snapshot``): the prompt is run in pieces
+        that end there."""
         n = len(req.prompt_ids)
         owner = f"req{req.request_id}"
+        ps = self.cache.page_size
         with _span("serving/admit", request_id=req.request_id,
                    prompt_tokens=n) as adm:
             hit_blocks, hit_pages = 0, []
@@ -826,16 +895,41 @@ class Engine:
                            request_id=req.request_id):
                     hit_blocks, hit_pages = \
                         self.prefix_cache.match(req.prompt_ids)
+            splice, source, cuts = hit_blocks, None, []
+            if self.snapshot_alloc is not None:
+                splice, source = self.prefix_cache.deepest_snapshot(
+                    req.prompt_ids, hit_blocks)
+                # (the deepest first to go where the whole pool is smaller
+                # than one admission's two)
+                cuts = sorted({hit_blocks, n // ps} - {0, splice})[
+                    -self.snapshot_alloc.num_allocatable:]
+            elif self._stateful:
+                splice = 0      # no snapshots: nothing to resume from
             with _span("serving/admit/alloc",
                        request_id=req.request_id) as alloc:
                 evicted = 0
-                need = self._pages_needed(n) - hit_blocks
+                if source is not None:
+                    # hold the snapshot to resume from through the
+                    # evictions below
+                    self.snapshot_alloc.retain([source], owner=owner)
+                need = self._pages_needed(n) - splice
                 pages = self.page_alloc.alloc(need, owner=owner)
                 if pages is None and self.prefix_cache is not None:
                     # pool short: reclaim cold cached prefixes, retry
                     evicted = self.prefix_cache.evict_lru(need)
                     pages = self.page_alloc.alloc(need, owner=owner)
-                if pages is None:
+                taken, dropped = [], 0
+                if pages is not None and cuts:
+                    before = self.prefix_cache.snapshots_dropped
+                    taken = self.prefix_cache.reserve_snapshots(len(cuts),
+                                                                owner)
+                    # snapshots that left their nodes to make room
+                    dropped = self.prefix_cache.snapshots_dropped - before
+                if pages is None or taken is None:
+                    if pages is not None:
+                        self.page_alloc.free(pages, owner=owner)
+                    if source is not None:
+                        self.snapshot_alloc.free([source], owner=owner)
                     alloc.set(pages=0, evicted=evicted, blocked=1)
                     adm.set(blocked=1)
                     return False
@@ -843,16 +937,20 @@ class Engine:
                 slot = self.cache.alloc_slot()
                 req.slot = slot
                 if hit_pages:
+                    req.prefix_hit_blocks = hit_blocks
+                if splice:
                     # the SPLICE: this request becomes one more sharer of
                     # the matched blocks' physical pages — a refcount bump
                     # and a table-row write, no device work for the prefix
-                    self.page_alloc.retain(hit_pages, owner=owner)
-                    self.cache.assign_pages(slot, hit_pages)
-                    req.prefix_hit_blocks = hit_blocks
-                self.cache.assign_pages(slot, pages, start_block=hit_blocks)
+                    self.page_alloc.retain(hit_pages[:splice], owner=owner)
+                    self.cache.assign_pages(slot, hit_pages[:splice])
+                self.cache.assign_pages(slot, pages, start_block=splice)
                 alloc.set(pages=len(pages), evicted=evicted)
             adm.set(queued_s=req.admit_time - req.arrival_time,
                     hit_blocks=hit_blocks)
+            if self._stateful:
+                adm.set(snapshot_blocks=splice,
+                        recomputed_tokens=(hit_blocks - splice) * ps)
             if self.prefix_cache is not None:
                 if hit_blocks:
                     _metrics.counter("serving.prefix.hits", 1)
@@ -860,44 +958,41 @@ class Engine:
                                        alloc.seconds)
                 else:
                     _metrics.counter("serving.prefix.misses", 1)
+            if source is not None:
+                with _span("serving/admit/restore",
+                           request_id=req.request_id, blocks=splice):
+                    self.cache.copy_state(self.cache.snapshot_row(source),
+                                          slot)
+                self.snapshot_alloc.free([source], owner=owner)
             sp = req.sampling
-            ps = self.cache.page_size
-            if hit_blocks:
-                # suffix-only prefill through the bucketed extend program
-                # (>= 1 token by construction: matching is capped at
-                # (n-1)//ps blocks)
-                start = hit_blocks * ps
-                m = n - start
-                T = self._bucket(m)
-                with _span("serving/admit/extend",
-                           request_id=req.request_id, tokens=m, bucket=T):
-                    ids = np.zeros((1, T), np.int32)
-                    ids[0, :m] = req.prompt_ids[start:]
-                    exe = self._extend_exe(T)
-                    logits, *self.cache.pools = exe(
-                        self.params, *self.cache.pools, jnp.asarray(ids),
-                        jnp.asarray(self.cache.page_table[slot]),
-                        jnp.int32(start), jnp.int32(m))
-            else:
-                T = self._bucket(n)
-                with _span("serving/admit/prefill",
-                           request_id=req.request_id, tokens=n, bucket=T):
-                    ids = np.zeros((1, T), np.int32)
-                    ids[0, :n] = req.prompt_ids
-                    exe = self._prefill_exe(T)
-                    logits, *self.cache.pools = exe(
-                        self.params, *self.cache.pools, jnp.asarray(ids),
-                        jnp.asarray(self.cache.page_table[slot]),
-                        jnp.int32(n))
+            # the prompt from the splice on, in pieces that end where a
+            # snapshot is taken (one piece for a model without state)
+            pos = splice * ps
+            snap_at = {c * ps: snap for c, snap in zip(cuts, taken)}
+            for end in sorted(set(snap_at) | {n}):
+                if end > pos:
+                    logits = self._run_prompt(req, slot, pos, end)
+                    pos = end
+                if end in snap_at:
+                    with _span("serving/snapshot", request_id=req.request_id,
+                               blocks=end // ps, evicted=dropped, reason=(
+                                   "prompt_end" if end // ps == n // ps
+                                   else "branch")):
+                        self.cache.copy_state(
+                            slot, self.cache.snapshot_row(snap_at[end]))
+                    dropped = 0     # counted once an admission
             with _span("serving/admit/sample", request_id=req.request_id):
                 if self.prefix_cache is not None:
                     # index this prompt's FULL blocks (shared ones are
                     # already nodes; fresh ones take a trie-owned reference
                     # and become matchable the moment the next prompt
-                    # agrees)
+                    # agrees), and hand their nodes the snapshots taken
                     self.prefix_cache.insert(
                         req.prompt_ids,
                         self.cache.slot_pages(slot)[:n // ps])
+                    for end, snap in snap_at.items():
+                        self.prefix_cache.attach_snapshot(
+                            req.prompt_ids, end // ps, snap, owner)
                 key = _random.next_key() if sp.do_sample else _dummy_key()
                 tok = int(np.asarray(_sampling.sample_static(
                     logits, key, do_sample=sp.do_sample,
@@ -920,6 +1015,27 @@ class Engine:
         req.output_ids.append(tok)
         self._maybe_finish(req, tok)
         return True
+
+    def _run_prompt(self, req: Request, slot: int, start: int, end: int):
+        """Tokens ``[start, end)`` of the request's prompt through the
+        bucketed prefill program (from position 0) or, behind what the slot
+        already holds, the extend program (the suffix-only prefill; >= 1
+        token by construction: matching is capped at (n-1)//ps blocks): the
+        last token's logits ``[1, V]``."""
+        m = end - start
+        T = self._bucket(m)
+        kind = "extend" if start else "prefill"
+        with _span("serving/admit/" + kind, request_id=req.request_id,
+                   tokens=m, bucket=T):
+            ids = np.zeros((1, T), np.int32)
+            ids[0, :m] = req.prompt_ids[start:end]
+            where = (jnp.int32(start), jnp.int32(m)) if start \
+                else (jnp.int32(m),)
+            logits, *self.cache.pools = self._held(kind, T)(
+                self.params, *self.cache.pools, jnp.asarray(ids),
+                jnp.asarray(self.cache.page_table[slot]), *where,
+                *self._slot_arg(slot))
+        return logits
 
     def _ensure_writable(self, slot: int, block: int, owner: str) -> bool:
         """Copy-on-write guard: a slot about to WRITE ``block`` must own its

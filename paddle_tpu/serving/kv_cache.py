@@ -298,12 +298,28 @@ class PagedKVCache:
     pages — capacity for every slot at full length. Serving the same
     envelope at a FRACTION of that HBM is the point: pass a smaller
     ``num_pages`` and admission backpressure + ragged allocation take over.
+
+    A pool may belong to SOME layers only (a fourth entry of its
+    declaration names them; a model whose layers are of several kinds), and
+    a layer may keep, instead of pages of keys, STATE that is not a
+    function of position: ``state_pools`` declares ``(name, per-slot shape,
+    dtype, layers)``, one ``[B_max + num_snapshots, *shape]`` buffer a
+    layer. Rows ``[0, B_max)`` are the slots' (the decode program advances
+    them all, prefill and extend write one), the rows behind them hold
+    SNAPSHOTS: a slot's state as it stood at a block boundary of its
+    prompt, which the prefix trie keeps beside that block's page
+    (``prefix_cache.py``). Taking and restoring one is ``copy_state``, a
+    row copied inside every state buffer by one compiled program, as
+    ``copy_page`` copies a page. ``.pools`` holds the state buffers behind
+    the paged ones, each pool a tuple over ITS layers; ``layer_entries`` /
+    ``pools_from_layers`` go between that and what one layer is handed.
     """
 
     def __init__(self, num_layers: int, max_batch_size: int,
                  num_kv_heads: int, max_seq_len: int, head_dim: int,
                  dtype="float32", page_size: int = 16,
-                 num_pages: Optional[int] = None, pools=None):
+                 num_pages: Optional[int] = None, pools=None,
+                 state_pools=(), num_snapshots: int = 0):
         if max_seq_len % page_size:
             raise ValueError(
                 f"max_seq_len {max_seq_len} not divisible by page_size "
@@ -322,10 +338,27 @@ class PagedKVCache:
         self.num_pages = num_pages
         if pools is None:
             pools = [("k", num_kv_heads, head_dim), ("v", num_kv_heads, head_dim)]
-        self.pool_specs = [(str(n), int(h), int(w)) for n, h, w in pools]
+        every = tuple(range(num_layers))
+        self.pool_specs = [(str(p[0]), int(p[1]), int(p[2])) for p in pools]
+        self.state_specs = [(str(n), tuple(shape), str(dt))
+                            for n, shape, dt, _ in state_pools]
+        #: the layers that hold each pool, paged pools first
+        self.pool_layers = [tuple(p[3]) if len(p) > 3 else every
+                            for p in list(pools) + list(state_pools)]
+        self.num_snapshots = int(num_snapshots)
+        rows = max_batch_size + self.num_snapshots
         self._pools = tuple(
-            _layer_buffers(num_layers, (num_pages, h, page_size, w), dtype)
-            for _, h, w in self.pool_specs)
+            _layer_buffers(len(layers), (num_pages, h, page_size, w), dtype)
+            for (_, h, w), layers in zip(self.pool_specs, self.pool_layers)
+        ) + tuple(
+            _layer_buffers(len(layers), (rows,) + shape, dt)
+            for (_, shape, dt), layers in zip(
+                self.state_specs, self.pool_layers[len(self.pool_specs):]))
+        # per layer, (pool, index of the layer's buffer in it) of the pools
+        # it holds, in the order it is handed them
+        self._of_layer = [
+            [(j, layers.index(l)) for j, layers in enumerate(self.pool_layers)
+             if l in layers] for l in range(num_layers)]
         self._table = np.full((max_batch_size, self.num_blocks),
                               PAGE_SENTINEL, np.int32)
         self.page_table = self._table.view()
@@ -335,6 +368,7 @@ class PagedKVCache:
         self._table_dev: Optional[jax.Array] = None
         self._free: List[int] = list(range(max_batch_size))[::-1]
         self._copy_exe = None
+        self._copy_state_exe = None
 
     @property
     def pools(self):
@@ -400,7 +434,7 @@ class PagedKVCache:
         caller that must not compile later (the engine, when pages can be
         shared) asks for it up front."""
         if self._copy_exe is None:
-            n = len(self.pools)
+            n = len(self.pool_specs)
 
             def copy_page_fn(*a):
                 src, dst = a[n:]
@@ -415,7 +449,7 @@ class PagedKVCache:
 
             self._copy_exe = jax.jit(copy_page_fn,
                                      donate_argnums=tuple(range(n))) \
-                .lower(*self.pools, jnp.int32(0), jnp.int32(0)).compile()
+                .lower(*self.pools[:n], jnp.int32(0), jnp.int32(0)).compile()
         return self._copy_exe
 
     def copy_page(self, src: int, dst: int):
@@ -424,8 +458,41 @@ class PagedKVCache:
         entry at ``dst`` and drops its reference on ``src`` — the sharer
         still mapping ``src`` never observes the write that motivated the
         copy."""
+        n = len(self.pool_specs)
         self.pools = tuple(self.copy_page_exe()(
-            *self.pools, jnp.int32(src), jnp.int32(dst)))
+            *self.pools[:n], jnp.int32(src), jnp.int32(dst))) + self.pools[n:]
+
+    # -- slot state and its snapshots --
+    def snapshot_row(self, snapshot: int) -> int:
+        """The state buffers' row of snapshot id ``snapshot`` (ids run
+        ``[1, num_snapshots]``, as a ``PageAllocator`` hands them out)."""
+        return self.max_batch_size + snapshot - 1
+
+    def copy_state_exe(self):
+        """The compiled program ``(*state pools, src, dst) -> state pools``
+        that copies row ``src`` onto row ``dst`` inside every state buffer
+        (donated; row ids are runtime scalars, so one executable takes every
+        snapshot and makes every restore). Compiled on first use."""
+        if self._copy_state_exe is None:
+            state = self.pools[len(self.pool_specs):]
+
+            def copy_state_fn(*a):
+                *bufs, src, dst = a
+                one = lambda b: lax.dynamic_update_slice_in_dim(
+                    b, lax.dynamic_slice_in_dim(b, src, 1), dst, axis=0)
+                return tuple(tuple(map(one, pool)) for pool in bufs)
+
+            self._copy_state_exe = jax.jit(
+                copy_state_fn, donate_argnums=tuple(range(len(state)))) \
+                .lower(*state, jnp.int32(0), jnp.int32(0)).compile()
+        return self._copy_state_exe
+
+    def copy_state(self, src: int, dst: int):
+        """Row ``src`` of every state buffer onto row ``dst``: a snapshot
+        taken (slot -> ``snapshot_row``) or restored (the other way)."""
+        n = len(self.pool_specs)
+        self.pools = self.pools[:n] + tuple(self.copy_state_exe()(
+            *self.pools[n:], jnp.int32(src), jnp.int32(dst)))
 
     def slot_pages(self, slot: int) -> List[int]:
         row = self.page_table[slot]
@@ -457,8 +524,21 @@ class PagedKVCache:
     def active_slots(self) -> int:
         return self.max_batch_size - len(self._free)
 
-    @staticmethod
-    def layer_entries(pools, table):
-        """Per-layer ``(pool_0, ..., pool_n, page_table)`` entries of the
-        pool tuples, in the order the model declared its pools."""
-        return [tuple(layer) + (table,) for layer in zip(*pools)]
+    def layer_entries(self, pools, table, row=None):
+        """Per-layer ``(pool_0, ..., pool_n, where)`` entries of the pool
+        tuples, in the order the model declared its pools: ``where`` is the
+        page ``table`` for a layer of paged pools, and for a layer of state
+        the ``row`` its state lives in (``None``: rows ``[0, B)``)."""
+        n = len(self.pool_specs)
+        return [tuple(pools[j][i] for j, i in held)
+                + ((table,) if held[0][0] < n else (row,))
+                for held in self._of_layer]
+
+    def pools_from_layers(self, per_layer):
+        """The pool tuples (by pool, then by its layers) of what every
+        layer handed back (its buffers, in ``layer_entries``' order)."""
+        out = [[None] * len(layers) for layers in self.pool_layers]
+        for held, bufs in zip(self._of_layer, per_layer):
+            for (j, i), buf in zip(held, bufs):
+                out[j][i] = buf
+        return tuple(map(tuple, out))

@@ -26,6 +26,17 @@ Eviction is LRU over trie leaves: releasing a leaf drops only the trie's
 reference, so a page still spliced into a live request survives eviction
 and is reclaimed when that request finishes.
 
+A model some of whose layers keep RECURRENT STATE (``PagedKVCache``'s state
+pools) cannot resume at any block the pages reach: the state exists only
+where a snapshot of it was taken. A node may therefore carry a snapshot id
+(a row of the state buffers, handed out by a second ``PageAllocator``):
+``deepest_snapshot`` finds the deepest one on a matched path, which is
+where the engine resumes; ``attach_snapshot`` hands a node the one the
+engine took at its block. A snapshot is freed with its node, and when the
+snapshot pool itself is short ``reserve_snapshots`` takes them from the
+nodes whose snapshot was least recently used (the nodes and their pages
+stay: they still say where prompts part).
+
 Flag-gated metrics: the engine counts ``serving.prefix.hits`` /
 ``serving.prefix.misses`` per ADMISSION (a blocked head request peeks the
 trie every step; counting in ``match`` would inflate hits), and this
@@ -49,11 +60,14 @@ class _Node:
     repr/debugging), the physical ``page`` holding that block's K/V, and an
     LRU stamp. Children are keyed by the NEXT block's token tuple."""
 
-    __slots__ = ("key", "page", "last_used", "children", "parent")
+    __slots__ = ("key", "page", "last_used", "children", "parent",
+                 "snapshot", "snapshot_used")
 
     def __init__(self, key: Tuple[int, ...], page: int, parent: "_Node"):
         self.key = key
         self.page = page
+        self.snapshot: Optional[int] = None  # id of the state as of this block
+        self.snapshot_used = 0  # when it was attached or last resumed from
         self.last_used = 0
         self.children: Dict[Tuple[int, ...], _Node] = {}
         self.parent = parent
@@ -70,11 +84,17 @@ class PrefixCache:
     match, which is exactly the unit the page table can splice.
     """
 
-    def __init__(self, page_size: int, allocator: PageAllocator):
+    def __init__(self, page_size: int, allocator: PageAllocator,
+                 snapshots: Optional[PageAllocator] = None):
         if page_size < 1:
             raise ValueError(f"page_size {page_size}")
         self.page_size = page_size
         self.allocator = allocator
+        #: the snapshot rows' allocator (a model with recurrent state), and
+        #: the nodes that carry one
+        self.snapshots = snapshots
+        self._with_snapshot = set()
+        self.snapshots_dropped = 0  # snapshots evicted so far
         self._root = _Node((), -1, None)  # sentinel; holds no page
         self._clock = itertools.count(1)
         self.num_nodes = 0
@@ -86,9 +106,14 @@ class PrefixCache:
 
     # ------------------------------------------------------------- lookup
 
-    def _blocks(self, tokens: Sequence[int]) -> List[Tuple[int, ...]]:
+    def _blocks(self, tokens: Sequence[int],
+                limit: Optional[int] = None) -> List[Tuple[int, ...]]:
+        """The whole blocks of ``tokens`` as keys, the first ``limit`` only
+        where one is given."""
         ps = self.page_size
         nfull = len(tokens) // ps
+        if limit is not None:
+            nfull = min(nfull, limit)
         return [tuple(int(t) for t in tokens[j * ps:(j + 1) * ps])
                 for j in range(nfull)]
 
@@ -105,7 +130,7 @@ class PrefixCache:
         cap = max(0, (len(prompt) - 1) // self.page_size)
         node, pages = self._root, []
         stamp = next(self._clock)
-        for key in self._blocks(prompt)[:cap]:
+        for key in self._blocks(prompt, cap):
             child = node.children.get(key)
             if child is None:
                 break
@@ -113,6 +138,81 @@ class PrefixCache:
             pages.append(child.page)
             node = child
         return len(pages), pages
+
+    def _path(self, prompt: Sequence[int], depth: int) -> List[_Node]:
+        """The nodes of ``prompt``'s first ``depth`` blocks, as far as the
+        trie has them."""
+        node, path = self._root, []
+        for key in self._blocks(prompt, depth):
+            node = node.children.get(key)
+            if node is None:
+                break
+            path.append(node)
+        return path
+
+    # ---------------------------------------------------------- snapshots
+
+    def deepest_snapshot(self, prompt: Sequence[int],
+                         hit_blocks: int) -> Tuple[int, Optional[int]]:
+        """``(blocks, snapshot id)`` of the deepest node within ``prompt``'s
+        first ``hit_blocks`` matched blocks that carries a snapshot;
+        ``(0, None)`` where none does (a cold prefill)."""
+        path = self._path(prompt, hit_blocks)
+        for depth in range(len(path), 0, -1):
+            node = path[depth - 1]
+            if node.snapshot is not None:
+                node.snapshot_used = next(self._clock)
+                return depth, node.snapshot
+        return 0, None
+
+    def reserve_snapshots(self, n: int,
+                          owner: Optional[str] = None) -> Optional[List[int]]:
+        """``n`` free snapshot ids for the caller to fill, taken from the
+        nodes whose snapshot was least recently USED (attached or resumed
+        from: a match stamps every node on its path, so a chat's earlier
+        prompt ends look as fresh as its last one by ``last_used``, while
+        only the deepest is ever resumed from again) where the pool is
+        short; None (and nothing allocated) where that is not enough — an
+        id somebody else still holds a reference on is not freed by
+        leaving its node."""
+        while self.snapshots.num_free < n and self._with_snapshot:
+            self._drop_snapshot(min(self._with_snapshot,
+                                    key=lambda nd: nd.snapshot_used), True)
+        return self.snapshots.alloc(n, owner=owner)
+
+    def attach_snapshot(self, prompt: Sequence[int], depth: int,
+                        snapshot: int, owner: Optional[str] = None) -> bool:
+        """Hand the node of ``prompt``'s block ``depth`` (1-based: the state
+        after ``depth`` whole blocks) the snapshot ``snapshot``, the
+        caller's reference with it. Where there is no such node, or it
+        carries a snapshot already, the caller's is freed; False then. The
+        snapshot it supersedes, if any, is freed (not counted as
+        evicted)."""
+        path = self._path(prompt, depth)
+        ok = len(path) == depth and path[-1].snapshot is None
+        if ok:
+            self.snapshots.retain([snapshot], owner=_OWNER)
+            path[-1].snapshot = snapshot
+            path[-1].snapshot_used = next(self._clock)
+            self._with_snapshot.add(path[-1])
+            # the nearest snapshot above it on an UNBRANCHED chain is
+            # superseded: whatever matches that far matches down to here
+            # (a chat's earlier prompt end). Where prompts part (a shared
+            # system prompt's last block) the node has several children
+            # and its snapshot stays
+            for above in reversed(path[:-1]):
+                if above.snapshot is not None:
+                    if len(above.children) == 1:
+                        self._drop_snapshot(above)
+                    break
+        self.snapshots.free([snapshot], owner=owner)
+        return ok
+
+    def _drop_snapshot(self, node: _Node, evicted: bool = False):
+        self.snapshots.free([node.snapshot], owner=_OWNER)
+        self.snapshots_dropped += evicted
+        node.snapshot = None
+        self._with_snapshot.discard(node)
 
     # ------------------------------------------------------------- insert
 
@@ -156,6 +256,8 @@ class PrefixCache:
             self._leaf_set.add(parent)
         self.num_nodes -= 1
         self.allocator.free([node.page], owner=_OWNER)
+        if node.snapshot is not None:
+            self._drop_snapshot(node, True)
 
     def evict_lru(self, need_free: int) -> int:
         """Release least-recently-used leaves until the allocator has

@@ -167,6 +167,17 @@ out["paged"] = sites(paged_attention, sds((4, 4, 1, 128)), sds((17, 2, 16, 128))
 # the serving cells' own widths: 32 slots, 16 heads of 128, a [32, 128] table
 out["paged_1p3b"] = sites(paged_attention, sds((32, 16, 1, 128)), sds((1400, 16, 16, 128)),
                           sds((1400, 16, 16, 128)), sds((32, 128), jnp.int32), sds((32,), jnp.int32))
+# the hybrid cell's widths: paged_decode at 30 MHA heads over a [32, 208]
+# table, and the gated delta rule's recurrent step on rows [0, 32) of a
+# packed state buffer that holds 64 snapshot rows behind them
+out["paged_30h"] = sites(paged_attention, sds((32, 30, 1, 128)), sds((4097, 30, 16, 128)),
+                         sds((4097, 30, 16, 128)), sds((32, 208), jnp.int32), sds((32,), jnp.int32))
+from paddle_tpu.kernels import gated_delta
+fs = lambda *shape: sds(shape, jnp.float32)
+out["gdn_step"] = sites(
+    lambda *a: gated_delta._step_call(*a, interpret=False),
+    fs(32, 30, 96), fs(32, 30, 96), fs(32, 30, 192), fs(32, 30), fs(32, 30),
+    fs(96, *gated_delta.packed_shape(30, 96, 192)))
 f32 = sds((64, 256), jnp.float32)
 out["prim"] = {**sites(primitive.elementwise_kernel(lambda a, b: a + 2 * b), f32, f32),
                **sites(primitive.row_reduce_kernel(lambda acc, t: acc + t.sum(-1), 0.0), f32)}
@@ -207,7 +218,9 @@ def test_kernels_and_train_step_compile_for_a_tpu_topology():
     assert out["layer_norm"] == {"layer_norm_fwd": 1}
     assert out["rms_norm"] == {"rms_norm_fwd": 1}
     assert out["adamw"] == {"fused_adamw": 1}
-    assert out["paged"] == out["paged_1p3b"] == {"paged_decode": 1}
+    assert out["paged"] == out["paged_1p3b"] == out["paged_30h"] \
+        == {"paged_decode": 1}
+    assert out["gdn_step"] == {"gdn_decode_step": 1}
     assert out["prim"] == {"prim_elementwise": 1, "prim_row_reduce": 1}
     for key in ("step_1", "step_dp2mp2"):  # the mesh must not lose a kernel
         assert set(out[key]) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
