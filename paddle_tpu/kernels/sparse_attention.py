@@ -7,18 +7,22 @@ largest score by bisection over the score's bits (32 compare-and-count
 passes over the row, where a sort of a 34k-wide row per query would cost a
 long prefill about a second a layer); ``topk_mask`` turns it into the set
 ``lax.top_k`` would return (ties at the threshold go to the LOWER position,
-as ``lax.top_k`` breaks them), and ``topk_indices`` compacts that set into
-``k`` ascending positions with dense vector work only (block counts, one
-gather of 128-wide mask rows; no scatter, which a TPU serialises).
+as ``lax.top_k`` breaks them), and ``selected_rows`` compacts that set into
+the ``k`` pool rows it lies in, in ascending order of position. The
+compaction carries each position's row address through the page table from
+the start and is dense vector work around ONE gather, of 128-wide rows of
+addresses: no scatter, which a TPU serialises, and no gather of single
+elements, which costs this chip what a gather of as many 1 KB rows costs
+(9.4 ns an element against 11 ns a row; PERF.md section 7).
 
 The decode read gathers the selected rows, not pages: at 16-token pages a
 walk over a 33k-token slot's 2,176 pages is 2,176 grid steps a slot (about
 0.35 us each: 12 ms a layer at 16 slots) whatever it then skips, and 63% of
 the pages hold a selected row anyway. The pools are token-major
 (``[pages, 1, page, H_kv * D]``), so a row is one run of 1 KB; the rows come
-out of each pool through the page table (``row_index``) as one XLA row
-gather (``gather_rows``) and ``sparse_paged_decode`` streams them in blocks
-of 512 with the same online softmax as ``paged_attention._decode_kernel``.
+out of each pool as one XLA row gather (``gather_rows``) and
+``sparse_paged_decode`` streams them in blocks of 512 with the same online
+softmax as ``paged_attention._decode_kernel``.
 """
 
 from __future__ import annotations
@@ -80,44 +84,50 @@ def topk_mask(scores, valid, k: int):
     return above | (at & (jnp.cumsum(at, axis=-1, dtype=jnp.int32) <= room))
 
 
-def topk_indices(scores, valid, k: int):
-    """(idx ``[B, k]`` int32 ascending, n ``[B]``): the positions
-    ``topk_mask`` selects, compacted; entries past ``n[b]`` are 0."""
+def selected_rows(scores, valid, page_table, page_size: int, k: int):
+    """(rows ``[B, K]`` int32, n ``[B]``), ``K = min(k, L)``: where the
+    positions ``topk_mask`` selects lie in a pool seen as rows
+    ``[pages * page_size, W]``, through ``page_table [B, blocks]``, in
+    ascending order of position; of each slot's rows the first ``n[b]``
+    count, the rest point at its position 0. Sentinel table entries clamp
+    to the trash page.
+
+    The compaction carries addresses, not positions: every position's pool
+    row is laid out first (the table repeated along lanes), the block of
+    the j-th selected row and the count before that block both come from
+    one compare of the inclusive block counts with j, ONE gather fetches
+    that block's 128 addresses (-1 where not selected), and the lane is the
+    one whose running count of selected entries reaches the rest."""
     B, L = scores.shape
     sel = topk_mask(scores, valid, k)
+    page = jnp.repeat(jnp.maximum(page_table, 0), page_size, axis=1)[:, :L]
+    addr = page * page_size + jnp.arange(L, dtype=jnp.int32) % page_size
+    a3 = jnp.where(sel, addr, -1)
     pad = (-L) % _LANE_BLOCK
     if pad:
-        sel = jnp.pad(sel, ((0, 0), (0, pad)))
-    m3 = sel.reshape(B, -1, _LANE_BLOCK).astype(jnp.int32)
-    cnt = m3.sum(-1)                                   # [B, nblk]
-    cum = jnp.cumsum(cnt, axis=-1)                     # inclusive
+        a3 = jnp.pad(a3, ((0, 0), (0, pad)), constant_values=-1)
+    a3 = a3.reshape(B, -1, _LANE_BLOCK)
+    cnt = jnp.sum(a3 >= 0, axis=-1, dtype=jnp.int32)   # [B, nblk]
+    cum = jnp.cumsum(cnt, axis=-1, dtype=jnp.int32)    # inclusive
     n = cum[:, -1]
-    k = min(k, L)
-    j = jnp.arange(k, dtype=jnp.int32)
-    # block holding the j-th selected position: first block whose inclusive
-    # count passes j
-    blk = jnp.sum(cum[:, None, :] <= j[None, :, None], axis=-1)
-    blk = jnp.minimum(blk, m3.shape[1] - 1)            # [B, k]
-    before = jnp.take_along_axis(cum - cnt, blk, axis=1)
-    rows = jnp.take_along_axis(m3, blk[:, :, None], axis=1)  # [B, k, 128]
-    within = (j[None, :] - before)[:, :, None]
-    # offset of the (within+1)-th set bit: how many inclusive counts are
-    # still <= within
-    off = jnp.sum(jnp.cumsum(rows, axis=-1) <= within, axis=-1)
-    idx = blk * _LANE_BLOCK + jnp.minimum(off, _LANE_BLOCK - 1)
-    return jnp.where(j[None, :] < n[:, None], idx, 0).astype(jnp.int32), n
-
-
-def row_index(page_table, idx, page_size: int):
-    """Where sequence positions ``idx [B, K]`` lie in a pool seen as rows
-    ``[pages * page_size, W]``, through ``page_table [B, blocks]``.
-    Sentinel table entries clamp to the trash page."""
-    page = jnp.take_along_axis(page_table, idx // page_size, axis=1)
-    return jnp.maximum(page, 0) * page_size + idx % page_size
+    j = jnp.arange(min(k, L), dtype=jnp.int32)
+    # the blocks wholly before the j-th selected row: those whose inclusive
+    # count does not pass j
+    passed = cum[:, None, :] <= j[None, :, None]       # [B, K, nblk]
+    blk = jnp.minimum(jnp.sum(passed, axis=-1, dtype=jnp.int32),
+                      a3.shape[1] - 1)
+    before = jnp.sum(jnp.where(passed, cnt[:, None, :], 0), axis=-1,
+                     dtype=jnp.int32)
+    lanes = jnp.take_along_axis(a3, blk[:, :, None], axis=1)  # [B, K, 128]
+    live = lanes >= 0
+    nth = jnp.cumsum(live, axis=-1, dtype=jnp.int32)
+    hit = live & (nth == (j[None, :] - before + 1)[:, :, None])
+    rows = jnp.sum(jnp.where(hit, lanes, 0), axis=-1, dtype=jnp.int32)
+    return jnp.where(j[None, :] < n[:, None], rows, addr[:, :1]), n
 
 
 def gather_rows(pool, rows):
-    """Rows ``rows [B, K]`` (``row_index``) of a token-major pool
+    """Rows ``rows [B, K]`` (``selected_rows``) of a token-major pool
     ``[pages, 1, page, W]``: ``[B, K, W]``."""
     return pool.reshape(-1, pool.shape[-1])[rows]
 
@@ -207,14 +217,13 @@ def _sparse_decode_call(n, qs, k_rows, v_rows):
     )(n, qs, k_rows, v_rows)
 
 
-def sparse_paged_decode(q, k_pool, v_pool, page_table, idx, n):
+def sparse_paged_decode(q, k_pool, v_pool, rows, n):
     """One query token per slot over the selected positions of its cache.
 
     q            ``[B, H_q, D]``
     k/v_pool     ``[pages, 1, page_size, H_kv * D]`` token-major pools
-    page_table   ``[B, blocks]`` int32 (-1 = unallocated)
-    idx, n       ``[B, K]`` selected positions (``topk_indices``), of which
-                 the first ``n[b]`` count
+    rows, n      ``[B, K]`` pool rows of the selected positions
+                 (``selected_rows``), of which the first ``n[b]`` count
 
     Returns ``[B, H_q, D]`` in v's dtype: softmax over the selected
     positions only, numerics as ``paged_attention`` (q pre-scaled in its
@@ -222,7 +231,6 @@ def sparse_paged_decode(q, k_pool, v_pool, page_table, idx, n):
     """
     D = q.shape[-1]
     qs = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
-    rows = row_index(page_table, idx, k_pool.shape[2])
     k_rows = gather_rows(k_pool, rows)
     v_rows = gather_rows(v_pool, rows)
     return shard_kernel(_sparse_decode_call,

@@ -389,8 +389,8 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
                                      pools[1], table, start)
         o = o.transpose(0, 2, 1, 3)
     elif sparse and T == 1 and _kvc.default_paged_impl() == "pallas":
-        from ..kernels.sparse_attention import (sparse_paged_decode,
-                                                topk_indices)
+        from ..kernels.sparse_attention import (selected_rows,
+                                                sparse_paged_decode)
 
         qi, w, _ = index
         ki_view = _kvc.paged_gather(pools[2], table)[:, 0, :, :Di]  # [B,L,Di]
@@ -398,8 +398,9 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
                        preferred_element_type=jnp.float32)
         score = jnp.sum(jnp.maximum(s, 0.0) * w[:, 0, :, None], axis=1)
         valid = jnp.arange(L, dtype=jnp.int32)[None, :] <= start[:, None]
-        idx, n = topk_indices(score, valid, cfg.index_topk)
-        o = sparse_paged_decode(q[:, 0], pools[0], pools[1], table, idx, n)
+        rows, n = selected_rows(score, valid, table, pools[0].shape[2],
+                                cfg.index_topk)
+        o = sparse_paged_decode(q[:, 0], pools[0], pools[1], rows, n)
         o = o[:, None]
     else:
         if head_major:

@@ -14,6 +14,7 @@ reference's greedy token at each position is what the engine must emit.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -110,17 +111,82 @@ class TestSelection:
         np.put_along_axis(want, np.asarray(idx), True, axis=1)
         np.testing.assert_array_equal(got, want & valid)
 
-    @pytest.mark.parametrize("L", [96, 200, 384])
-    def test_topk_indices_compacts_the_mask(self, L):
-        rs = np.random.RandomState(L)
-        s = jnp.asarray(rs.randn(5, L).astype(np.float32))
-        valid = jnp.asarray(np.arange(L)[None, :] <= rs.randint(0, L, (5, 1)))
-        idx, n = sa.topk_indices(s, valid, 32)
-        mask = np.asarray(sa.topk_mask(s, valid, 32))
-        for b in range(5):
-            assert int(n[b]) == mask[b].sum()
-            np.testing.assert_array_equal(
-                np.asarray(idx[b, :int(n[b])]), np.nonzero(mask[b])[0])
+    # (L, page, k, ties, first slot's last position, table entries past it)
+    CASES = {
+        "L96": (96, 8, 32, False, None, "mapped"),
+        "L200_not_a_multiple_of_128": (200, 8, 32, False, None, "mapped"),
+        "L384": (384, 8, 32, False, None, "mapped"),
+        "L100_ends_inside_a_page": (100, 8, 32, False, None, "mapped"),
+        "ties_at_the_threshold": (200, 8, 32, True, None, "mapped"),
+        "context_shorter_than_k": (24, 8, 32, False, None, "mapped"),
+        "slot_with_n_under_k": (200, 8, 32, False, 5, "mapped"),
+        "unallocated_table_entries": (200, 8, 32, True, 40, "unmapped"),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_selected_rows_are_lax_top_k_through_the_table(self, case):
+        """``selected_rows`` against an independent reference: the
+        ascending positions of ``lax.top_k``'s set on the masked row,
+        looked up in the page table with numpy (sentinels clamp to the
+        trash page); past ``n`` the slot's position 0."""
+        L, ps, k, ties, first, rest = self.CASES[case]
+        rs = np.random.RandomState(L + k)
+        B, nb = 5, -(-L // ps)
+        s = rs.randn(B, L).astype(np.float32)
+        if ties:
+            s = np.round(s * 2) / 2
+        last = rs.randint(0, L, (B,))
+        if first is not None:
+            last[0] = first
+        table = (rs.permutation(B * nb).reshape(B, nb) + 1).astype(np.int32)
+        if rest == "unmapped":
+            table[np.arange(nb)[None, :] > last[:, None] // ps] = -1
+        valid = np.arange(L)[None, :] <= last[:, None]
+        rows, n = sa.selected_rows(jnp.asarray(s), jnp.asarray(valid),
+                                   jnp.asarray(table), ps, k)
+        rows, n = np.asarray(rows), np.asarray(n)
+        assert rows.shape == (B, min(k, L)) and rows.dtype == np.int32
+        _, top = lax.top_k(jnp.where(valid, s, -jnp.inf), min(k, L))
+        for b in range(B):
+            pos = np.sort([t for t in np.asarray(top[b]) if valid[b, t]])
+            assert int(n[b]) == len(pos) == min(k, last[b] + 1)
+            want = np.maximum(table[b, pos // ps], 0) * ps + pos % ps
+            np.testing.assert_array_equal(rows[b, :len(pos)], want)
+            assert (rows[b, len(pos):] == max(table[b, 0], 0) * ps).all()
+
+    def test_decode_lowers_to_three_row_gathers_a_sparse_layer(self):
+        """The tiny model's decode program (StableHLO text, every call site
+        counted): what a sparse layer gathers by the ``[B, K]`` selection
+        is K rows, V rows and the compaction's address rows — nothing of
+        one element a slice, which costs this chip what a 1 KB row costs
+        (PERF.md section 7)."""
+        cfg = DecoderConfig(**SIZES)
+        with kvc.use_paged_attention_impl("pallas"):
+            eng = Engine(DecoderLM(cfg), EngineConfig(
+                max_batch_size=2, max_seq_len=96, page_size=8))
+            fn, args = eng.decode_program()
+            text = jax.jit(fn).lower(*args).as_text()
+        funcs = {}
+        for body in re.split(r"\n  func\.func ", text)[1:]:
+            name = re.match(r"(?:public |private )?@([\w.]+)", body).group(1)
+            funcs[name] = (
+                re.findall(r'"stablehlo\.gather".*slice_sizes = array<i64: '
+                           r'([\d, ]+)>.*-> tensor<([\w]+)>', body),
+                re.findall(r"call @([\w.]+)\(", body))
+
+        def gathers(name):
+            own, calls = funcs[name]
+            return own + [g for c in calls for g in gathers(c)]
+
+        B, K, W = 2, cfg.index_topk, cfg.num_kv_heads * cfg.head_dim
+        # every gather indexed by the selection, an element gather's
+        # ``BxKxi32`` result included
+        by_selection = sorted(g for g in gathers("main")
+                              if g[1].startswith(f"{B}x{K}x"))
+        want = [("1, 1, 128", f"{B}x{K}x128xi32"),
+                ("1, %d" % W, f"{B}x{K}x{W}xf32"),
+                ("1, %d" % W, f"{B}x{K}x{W}xf32")] * cfg.num_layers
+        assert by_selection == sorted(want)
 
     def test_sparse_decode_kernel_matches_masked_softmax(self):
         """``sparse_paged_decode`` (Pallas, interpreted here) over rows
@@ -136,8 +202,8 @@ class TestSelection:
         pos = jnp.asarray([95, 40, 7])
         score = jnp.asarray(rs.randn(B, nb * ps).astype(np.float32))
         valid = jnp.arange(nb * ps)[None, :] <= pos[:, None]
-        idx, n = sa.topk_indices(score, valid, 16)
-        got = sa.sparse_paged_decode(q, kp, vp, table, idx, n)
+        rows, n = sa.selected_rows(score, valid, table, ps, 16)
+        got = sa.sparse_paged_decode(q, kp, vp, rows, n)
         mask = sa.topk_mask(score, valid, 16)
         view = lambda pool: kvc.paged_gather(pool, table)[:, 0].reshape(
             B, nb * ps, Hkv, D)
