@@ -1,0 +1,6 @@
+"""Per-layer metric ``extend_busy_share.agents``: device time in extend and
+prefill programs over all program time of the stretch (layer, unit, source,
+moves and cells: its entry in BENCHMARK.json). Returns None where it finds
+nothing to read."""
+
+from harness.readers import prefill_busy_share as read  # noqa: F401

@@ -31,6 +31,18 @@ one grid step a (slot, block of head groups), the state block read once and
 written once where it lies (``input_output_aliases``); elsewhere the same
 arithmetic in ``jax.numpy``. ``serving.kv_cache.default_paged_impl`` says
 which, as for the paged attend.
+
+Both forms take the decay either as ONE value a head (``g [..., H]``, the
+gated delta rule as published) or as one a KEY CHANNEL (``g [..., H, dk]``,
+Kimi Delta Attention, arXiv:2510.26692): ``S_t = S_{t-1} diag(a_t) + b_t
+(v_t - S_{t-1} diag(a_t) k_t) k_t^T``, column ``c`` of the state decaying by
+its own ``a_t[c]``. In the step that is one more column operand beside ``k``
+and ``q`` (the packed state's ``dk`` rows each take their own factor). In
+the chunked form the decay no longer factors out of ``k_t . k_s``: the
+scores become ``sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c])``, contracted
+directly over ``[C, C, dk]`` (so ``C`` is kept small, 16-32), every exponent
+still a difference <= 0; the rest of the algebra is the same with ``G`` a
+vector.
 """
 
 from __future__ import annotations
@@ -90,8 +102,9 @@ def unpack_state(P, num_heads: int):
 # ------------------------------------------------------------ chunked form
 
 def gdn_chunked(q, k, v, g, beta, S0, chunk: int = 64):
-    """``q, k [T, H, dk]``, ``v [T, H, dv]``, ``g, beta [T, H]``, start
-    state ``S0 [H, dv, dk]``, all float32: ``(o [T, H, dv], S_T)``."""
+    """``q, k [T, H, dk]``, ``v [T, H, dv]``, ``beta [T, H]``, ``g [T, H]``
+    (a decay a head) or ``[T, H, dk]`` (one a key channel), start state
+    ``S0 [H, dv, dk]``, all float32: ``(o [T, H, dv], S_T)``."""
     T, H, dk = q.shape
     C = min(chunk, T)
     pad = -T % C
@@ -126,7 +139,31 @@ def gdn_chunked(q, k, v, g, beta, S0, chunk: int = 64):
             "hcv,hck->hvk", U, kc * jnp.exp(gC[:, None] - gam)[..., None])
         return S, O
 
-    S, O = lax.scan(step, S0, tuple(map(split, (q, k, v, g, beta))))
+    def step_channel(S, xs):
+        qc, kc, vc, gc, bc = xs          # gc [H, C, dk]: a decay a channel
+        gam = jnp.cumsum(gc, axis=1)
+        # R[t, s, c] = G_t[c] / G_s[c] for s <= t, 0 behind t
+        R = jnp.exp(jnp.where(incl[None, :, :, None],
+                              gam[:, :, None, :] - gam[:, None, :, :],
+                              -jnp.inf))
+        KK = jnp.sum(kc[:, :, None, :] * kc[:, None, :, :] * R, axis=-1)
+        QK = jnp.sum(qc[:, :, None, :] * kc[:, None, :, :] * R, axis=-1)
+        A = jnp.where(strict, bc[:, :, None] * KK, 0.0)
+        eg = jnp.exp(gam)
+        rhs = jnp.concatenate([bc[..., None] * vc,
+                               bc[..., None] * eg * kc], axis=-1)
+        sol = lax.linalg.triangular_solve(
+            A + jnp.eye(C, dtype=A.dtype), rhs, left_side=True, lower=True,
+            unit_diagonal=True)
+        dv = vc.shape[-1]
+        U = sol[..., :dv] - mm("hck,hvk->hcv", sol[..., dv:], S)
+        O = mm("hck,hvk->hcv", qc * eg, S) + mm("hcs,hsv->hcv", QK, U)
+        S = eg[:, -1][:, None, :] * S + mm(
+            "hcv,hck->hvk", U, kc * jnp.exp(gam[:, -1:] - gam))
+        return S, O
+
+    S, O = lax.scan(step_channel if g.ndim == 3 else step, S0,
+                    tuple(map(split, (q, k, v, g, beta))))
     return jnp.moveaxis(O, 1, 2).reshape(n * C, H, -1)[:T], S
 
 
@@ -135,7 +172,8 @@ def gdn_chunked(q, k, v, g, beta, S0, chunk: int = 64):
 def _step_oracle(q, k, v, g, beta, state):
     B, H, dk = q.shape
     S = unpack_state(state[:B], H)                         # [B, H, dv, dk]
-    S = S * jnp.exp(g)[:, :, None, None]
+    S = S * (jnp.exp(g)[:, :, None, :] if g.ndim == 3
+             else jnp.exp(g)[:, :, None, None])
     u = beta[..., None] * (v - jnp.einsum("bhvk,bhk->bhv", S, k,
                                           precision=_HI))
     S = S + u[..., None] * k[:, :, None, :]
@@ -149,12 +187,13 @@ def _groups_per_block(G: int, dk: int, lanes: int) -> int:
 
 
 def _step_kernel(qT_ref, kT_ref, v_ref, a_ref, b_ref, s_ref, o_ref, so_ref,
-                 *, gb: int, hg: int, dv: int):
+                 *, gb: int, hg: int, dv: int, channel: bool):
     """Grid (slot, block of ``gb`` head groups). ``s_ref [1, gb, dk, hg*dv]``
     the packed state block; ``qT / kT [1, 1, dk, gb*hg]`` the block's heads'
     q and k as columns; ``v / a / b [1, 1, gb, hg*dv]`` the values, decays
     and write strengths with a head's scalar repeated over its ``dv``
-    lanes."""
+    lanes; with ``channel`` the decays come as columns like q and k,
+    ``a [1, 1, dk, gb*hg]``, one factor a state row."""
     dk, L = s_ref.shape[2], s_ref.shape[3]
     lane = lax.broadcasted_iota(jnp.int32, (1, L), 1)
 
@@ -168,7 +207,8 @@ def _step_kernel(qT_ref, kT_ref, v_ref, a_ref, b_ref, s_ref, o_ref, so_ref,
 
     for grp in range(gb):
         K, Q = columns(kT_ref, grp), columns(qT_ref, grp)
-        S = s_ref[0, grp] * a_ref[0, 0, grp:grp + 1, :]
+        S = s_ref[0, grp] * (columns(a_ref, grp) if channel
+                             else a_ref[0, 0, grp:grp + 1, :])
         u = b_ref[0, 0, grp:grp + 1, :] * (
             v_ref[0, 0, grp:grp + 1, :]
             - jnp.sum(S * K, axis=0, keepdims=True))
@@ -193,10 +233,11 @@ def _step_call(q, k, v, g, beta, state, *, interpret: bool):
     row = pl.BlockSpec((1, 1, gb, L), lambda b, i: (b, i, 0, 0))
     col = pl.BlockSpec((1, 1, dk, gb * hg), lambda b, i: (b, i, 0, 0))
     blk = pl.BlockSpec((1, gb, dk, L), lambda b, i: (b, i, 0, 0))
+    channel = g.ndim == 3
     o, state = pl.pallas_call(
-        functools.partial(_step_kernel, gb=gb, hg=hg, dv=dv),
+        functools.partial(_step_kernel, gb=gb, hg=hg, dv=dv, channel=channel),
         grid=(B, nb),
-        in_specs=[col, col, row, row, row, blk],
+        in_specs=[col, col, row, col if channel else row, row, blk],
         out_specs=[row, blk],
         out_shape=[jax.ShapeDtypeStruct((B, nb, gb, L), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -205,16 +246,17 @@ def _step_call(q, k, v, g, beta, state, *, interpret: bool):
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="gdn_decode_step",
-    )(cols(q), cols(k), v.reshape(B, nb, gb, L), lanes(jnp.exp(g)),
-      lanes(beta), state)
+    )(cols(q), cols(k), v.reshape(B, nb, gb, L),
+      cols(jnp.exp(g)) if channel else lanes(jnp.exp(g)), lanes(beta), state)
     return o.reshape(B, H, dv), state
 
 
 def gdn_step(q, k, v, g, beta, state):
-    """One token a slot: ``q, k [B, H, dk]``, ``v [B, H, dv]``, ``g, beta
-    [B, H]`` (float32) against rows ``[0, B)`` of the packed ``state [rows,
-    H / hg, dk, hg * dv]``: ``(o [B, H, dv], state)`` with those rows
-    advanced and every other row as it was."""
+    """One token a slot: ``q, k [B, H, dk]``, ``v [B, H, dv]``, ``beta
+    [B, H]``, ``g [B, H]`` or (a decay a key channel) ``[B, H, dk]``
+    (float32) against rows ``[0, B)`` of the packed ``state [rows, H / hg,
+    dk, hg * dv]``: ``(o [B, H, dv], state)`` with those rows advanced and
+    every other row as it was."""
     from ..serving.kv_cache import default_paged_impl
 
     if default_paged_impl() == "oracle":

@@ -44,7 +44,12 @@ def plan_groups(expert, G: int, tm: int):
     the row that padded row ``r`` holds (0 for padding), ``dest [M]`` where
     row ``m`` went, ``tile_group [tiles]`` the expert of each tile (tiles
     past ``n_tiles`` repeat the last live tile's, so they fetch nothing
-    new), ``counts [G]`` rows per expert."""
+    new), ``counts [G]`` rows per expert.
+
+    An id of ``G`` marks a row of an expert that is NOT among the ``G`` laid
+    out (a program that holds some of a layer's experts): such rows sort
+    behind every group, lie in no tile and in no count, and their ``dest``
+    means nothing (the caller reads none)."""
     M = expert.shape[0]
     M_pad = -(-(M + G * (tm - 1)) // tm) * tm
     order = jnp.argsort(expert, stable=True).astype(jnp.int32)

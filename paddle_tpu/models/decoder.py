@@ -5,7 +5,9 @@
 (``rope`` | ``none``), the token mixer (``dense`` | ``indexed_sparse`` |
 ``gated_delta``; one kind for all layers, or ``layer_types``, one a
 layer), FFN (``swiglu`` | ``moe_swiglu``), router (``softmax_topk``) — with
-their widths; the parts are looked up by kind in
+their widths and what varies within a kind (a dense layer's output gate,
+the width of a gated_delta layer's decay, which of a layer's experts are
+held here, shared experts); the parts are looked up by kind in
 the tables at the bottom of each section, so the next architecture is a
 description (and at most a new entry in one table), not a third class tree
 beside ``models/gpt.py``. GPT-3's block (learned position table, LayerNorm
@@ -19,9 +21,11 @@ The block, for ``x`` the residual stream::
     indexed_sparse: I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]),  s <= t
                     S_t = the index_topk positions of largest I[t, :]
     a_t = softmax over s in S_t (s <= t for dense) of q_t . k_s / sqrt(D)
-    x += a Wo
+    x += a Wo                 (attn_output_gate: (a * sigmoid(h Wg)) Wo)
     g = norm(x);  moe_swiglu: p = softmax_f32(g Wr), top-k experts,
                   renormalised; x += sum_e p_e (silu(g W1_e) * g W3_e) W2_e
+                  (experts_held: the sum runs over the held e alone;
+                  shared_experts: + one dense SwiGLU of g)
 
 A ``gated_delta`` layer (linear attention by the gated delta rule,
 arXiv:2412.06464) mixes tokens through a recurrent state instead of a
@@ -34,6 +38,10 @@ cache of keys: with ``h`` the layer's input,
     g = -exp(A_log) softplus(h Wa + dt_bias),  a = exp(g)       (float32)
     S_t = a_t S_{t-1} + b_t (v_t - a_t S_{t-1} k_t) k_t^T,  o_t = S_t q_t
     y = RMSNorm_dv(o) * silu(h Wg), then Wo
+    linear_gate "channel" (Kimi Delta Attention, arXiv:2510.26692):
+        g = -exp(A_log) softplus((h Wf_a) Wf_b + dt_bias), one a key channel:
+        S_{t-1} diag(a_t) for a_t S_{t-1};  y = RMSNorm_dv(o) * sigmoid((h
+        Wg_a) Wg_b)
 
 It keeps per slot the float32 state ``S`` (packed, ``kernels/gated_delta``)
 and the last ``linear_conv_kernel - 1`` inputs of the convolution; prefill
@@ -101,6 +109,8 @@ class DecoderConfig:
     # H_kv * D], "head" [pages, H_kv, page, D] (what kernels/paged_attention
     # reads: its decode goes through serving.kv_cache.paged_decode_attend)
     kv_layout: str = "token"
+    # a dense layer's output gated element-wise by sigmoid(h Wg) before Wo
+    attn_output_gate: bool = False
     index_heads: int = 4
     index_head_dim: int = 8
     index_topk: int = 16
@@ -110,6 +120,12 @@ class DecoderConfig:
     linear_value_head_dim: int = 16
     linear_conv_kernel: int = 4
     linear_allow_neg_eigval: bool = True
+    # the decay's width: "head" one value a head (arXiv:2412.06464, a dense
+    # Wa, output gate silu(h Wg)); "channel" one a key channel (Kimi Delta
+    # Attention, arXiv:2510.26692: decay and output gate each through a
+    # low-rank pair of rank ``linear_gate_rank``, the output gate a sigmoid)
+    linear_gate: str = "head"
+    linear_gate_rank: int = 8
     gdn_chunk: int = 64           # tokens per chunk of the chunked form
     ffn: str = "moe_swiglu"       # swiglu | moe_swiglu
     intermediate_size: int = 128  # swiglu's width; an expert's in moe_swiglu
@@ -117,6 +133,14 @@ class DecoderConfig:
     num_experts: int = 8
     experts_per_token: int = 2
     norm_topk_prob: bool = True
+    # (how many, from which index) of the ``num_experts`` experts this
+    # program HOLDS, where a layer is shared by several chips: the router
+    # keeps its width, the rows routed to the others are left out (no
+    # exchange, nothing standing in for it). None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    # shared experts: one dense SwiGLU of width ``intermediate_size`` x this
+    # beside the routed ones, ungated, on every token
+    shared_experts: int = 0
     tie_word_embeddings: bool = False
     initializer_range: float = 0.02
     dtype: str = "float32"
@@ -134,7 +158,8 @@ class DecoderConfig:
                              ("router", ROUTERS),
                              ("norm_placement", NORM_PLACEMENTS),
                              ("kv_layout", KV_LAYOUTS),
-                             ("qk_norm", QK_NORMS)):
+                             ("qk_norm", QK_NORMS),
+                             ("linear_gate", LINEAR_GATES)):
             if getattr(self, field) not in table:
                 raise ValueError(f"{field} {getattr(self, field)!r}; "
                                  f"want one of {sorted(table, key=str)}")
@@ -150,6 +175,12 @@ class DecoderConfig:
                     f"num_layers is {self.num_layers}")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
+        if self.experts_held is not None:
+            n, first = self.experts_held = tuple(self.experts_held)
+            if not (n >= 1 and first >= 0 and first + n <= self.num_experts):
+                raise ValueError(
+                    f"experts_held {self.experts_held}: want (how many, "
+                    f"from which index) within {self.num_experts} experts")
 
     @property
     def kinds(self) -> Tuple[str, ...]:
@@ -179,6 +210,7 @@ NORMS = {"rms": (rms_norm, False), "layer": (layer_norm, True)}  # fn, bias
 NORM_PLACEMENTS = ("pre", "post")
 QK_NORMS = (False, "head", "full")
 KV_LAYOUTS = ("token", "head")
+LINEAR_GATES = ("head", "channel")
 
 
 def _norm_shapes(cfg, pre, width):
@@ -228,6 +260,8 @@ def _attn_shapes(cfg, pre, sparse):
         full = cfg.qk_norm == "full"
         s[pre + ".q_norm.weight"] = (cfg.num_heads * D if full else D,)
         s[pre + ".k_norm.weight"] = (cfg.num_kv_heads * D if full else D,)
+    if cfg.attn_output_gate:
+        s[pre + ".wg"] = (H, cfg.num_heads * D)
     if sparse:
         Hi, Di = cfg.index_heads, cfg.index_head_dim
         s.update({pre + ".index.wq": (H, Hi * Di),
@@ -364,6 +398,13 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
         fresh.append(jnp.pad(index[2][:, None], (
             (0, 0), (0, 0), (0, 0), (0, index_pool_width(cfg) - Di))))
 
+    def out(o):
+        o = o.reshape(B, T, Hq * D)
+        if cfg.attn_output_gate:
+            gate = jax.nn.sigmoid(_mm(h, p[pre + ".wg"]).astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(h.dtype)
+        return _mm(o, p[pre + ".wo"])
+
     if cache is None:
         if flash_ok and (not sparse or T <= cfg.index_topk):
             # every position is selected: plain causal attention, through
@@ -377,7 +418,7 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
                 is_causal=True, training=False)._value
         else:
             o = attend(cfg, q, k, v, pos, index)
-        return _mm(o.reshape(B, T, Hq * D), p[pre + ".wo"]), tuple(fresh)
+        return out(o), tuple(fresh)
 
     *pools, table = cache
     pools = [_kvc.paged_write_kv(pool, new, table, start)
@@ -414,7 +455,7 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
             _kvc.paged_gather(pools[2], table)[:, 0, :, :Di])
         o = attend(cfg, q, view(pools[0], Hkv), view(pools[1], Hkv), pos,
                    idx_view)
-    return _mm(o.reshape(B, T, Hq * D), p[pre + ".wo"]), tuple(pools)
+    return out(o), tuple(pools)
 
 
 def _kv_pools(cfg, sparse):
@@ -442,13 +483,21 @@ def _gdn_widths(cfg):
 def _gdn_shapes(cfg, pre):
     Hd = cfg.hidden_size
     H, dk, dv, C = _gdn_widths(cfg)
-    return {pre + ".wq": (Hd, H * dk), pre + ".wk": (Hd, H * dk),
-            pre + ".wv": (Hd, H * dv), pre + ".wg": (Hd, H * dv),
-            pre + ".wa": (Hd, H), pre + ".wb": (Hd, H),
-            pre + ".A_log": (H,), pre + ".dt_bias": (H,),
-            pre + ".conv.weight": (C, cfg.linear_conv_kernel),
-            pre + ".o_norm.weight": (dv,),
-            pre + ".wo": (H * dv, Hd)}
+    s = {pre + ".wq": (Hd, H * dk), pre + ".wk": (Hd, H * dk),
+         pre + ".wv": (Hd, H * dv), pre + ".wb": (Hd, H),
+         pre + ".A_log": (H,),
+         pre + ".conv.weight": (C, cfg.linear_conv_kernel),
+         pre + ".o_norm.weight": (dv,),
+         pre + ".wo": (H * dv, Hd)}
+    if cfg.linear_gate == "channel":
+        r = cfg.linear_gate_rank
+        s.update({pre + ".wf_a": (Hd, r), pre + ".wf_b": (r, H * dk),
+                  pre + ".wg_a": (Hd, r), pre + ".wg_b": (r, H * dv),
+                  pre + ".dt_bias": (H * dk,)})
+    else:
+        s.update({pre + ".wg": (Hd, H * dv), pre + ".wa": (Hd, H),
+                  pre + ".dt_bias": (H,)})
+    return s
 
 
 def _gdn_state_pools(cfg):
@@ -507,11 +556,19 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
                                   preferred_element_type=f32))
     if cfg.linear_allow_neg_eigval:
         beta = 2.0 * beta
-    g = -jnp.exp(p[pre + ".A_log"].astype(f32)) * jax.nn.softplus(
-        jnp.dot(h, p[pre + ".wa"], preferred_element_type=f32)
-        + p[pre + ".dt_bias"].astype(f32))
+    channel = cfg.linear_gate == "channel"
+    if channel:     # a decay a key channel, through the low-rank pair
+        g = -jnp.exp(p[pre + ".A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            jnp.dot(_mm(h, p[pre + ".wf_a"]), p[pre + ".wf_b"],
+                    preferred_element_type=f32)
+            + p[pre + ".dt_bias"].astype(f32)).reshape(B, T, H, dk)
+    else:
+        g = -jnp.exp(p[pre + ".A_log"].astype(f32)) * jax.nn.softplus(
+            jnp.dot(h, p[pre + ".wa"], preferred_element_type=f32)
+            + p[pre + ".dt_bias"].astype(f32))
     real = (jnp.arange(T)[None, :] < n[:, None])[..., None]
-    beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
+    beta = jnp.where(real, beta, 0.0)
+    g = jnp.where(real[..., None] if channel else real, g, 0.0)
 
     if cache is not None and row is None:
         o, state = _gdn.gdn_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
@@ -534,7 +591,11 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
                        conv, tail.astype(conv.dtype), row, axis=0))
     o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) \
         * p[pre + ".o_norm.weight"].astype(f32)
-    gate = jax.nn.silu(_mm(h, p[pre + ".wg"]).astype(f32))
+    if channel:
+        gate = jax.nn.sigmoid(_mm(_mm(h, p[pre + ".wg_a"]),
+                                  p[pre + ".wg_b"]).astype(f32))
+    else:
+        gate = jax.nn.silu(_mm(h, p[pre + ".wg"]).astype(f32))
     y = (o.reshape(B, T, H * dv) * gate).astype(h.dtype)
     return _mm(y, p[pre + ".wo"]), new
 
@@ -573,35 +634,82 @@ def _swiglu_shapes(cfg, pre):
     return {pre + ".w1": (H, F), pre + ".w3": (H, F), pre + ".w2": (F, H)}
 
 
+def _dense_swiglu(p, pre, g):
+    a = jax.nn.silu(_mm(g, p[pre + ".w1"])) * _mm(g, p[pre + ".w3"])
+    return _mm(a, p[pre + ".w2"])
+
+
 def swiglu(cfg, p, pre, g):
     """Dense gated FFN over ``g [N, hidden]``; no routing statistics."""
-    a = jax.nn.silu(_mm(g, p[pre + ".w1"])) * _mm(g, p[pre + ".w3"])
-    return _mm(a, p[pre + ".w2"]), jnp.zeros((2,), jnp.int32)
+    return _dense_swiglu(p, pre, g), jnp.zeros((2,), jnp.int32)
 
 
 def _moe_shapes(cfg, pre):
     H, F, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
-    return {pre + ".router": (H, E), pre + ".w1": (E, H, F),
-            pre + ".w3": (E, H, F), pre + ".w2": (E, F, H)}
+    G = E if cfg.experts_held is None else cfg.experts_held[0]
+    s = {pre + ".router": (H, E), pre + ".w1": (G, H, F),
+         pre + ".w3": (G, H, F), pre + ".w2": (G, F, H)}
+    if cfg.shared_experts:
+        Fs = F * cfg.shared_experts
+        s.update({pre + ".shared.w1": (H, Fs), pre + ".shared.w3": (H, Fs),
+                  pre + ".shared.w2": (Fs, H)})
+    return s
 
 
-def moe_swiglu(cfg, p, pre, g):
-    """The drop-free expert layer over ``g [N, hidden]``: every (token,
-    chosen expert) row is computed, whatever the distribution. Returns
-    (y [N, hidden], [distinct experts routed to, largest expert's rows])."""
+def step_stats(cfg) -> Tuple[str, ...]:
+    """What a layer's FFN counts in a step (``moe_routed``'s statistics): a
+    program told which experts it holds also counts the rows that landed on
+    them, beside all the rows it routed."""
+    names = ("experts_touched", "expert_max_load")
+    if cfg.experts_held is not None:
+        names += ("local_rows", "routed_rows")
+    return names
+
+
+def moe_routed(cfg, p, pre, g):
+    """The routed experts' part of the layer over ``g [N, hidden]``,
+    drop-free: every (token, chosen expert) row of an expert held here is
+    computed, whatever the distribution. With ``experts_held`` the router
+    still chooses among ALL experts; a row routed to an expert that is not
+    held sorts behind the held groups, in no tile of the grouped matmul (no
+    matmul, no weight read), and adds nothing: what the chips that hold it
+    would add is left out. Returns (y [N, hidden] float32, [held experts
+    with a row, largest expert's rows(, rows on held experts, rows
+    routed)])."""
     from ..kernels.grouped_matmul import grouped_matmul, plan_groups, row_tile
 
     N, H = g.shape
     E, k = cfg.num_experts, cfg.experts_per_token
     pw, e = ROUTERS[cfg.router](cfg, g, p[pre + ".router"])
-    tm = row_tile(N * k, E)
-    src, dest, tile_group, n_tiles, counts = plan_groups(e.reshape(-1), E, tm)
+    G, local = E, None
+    if cfg.experts_held is not None:
+        G, first = cfg.experts_held
+        local = (e >= first) & (e < first + G)
+        e = jnp.where(local, e - first, G)
+    tm = row_tile(N * k * G // E, G)     # by the rows expected HERE
+    src, dest, tile_group, n_tiles, counts = plan_groups(e.reshape(-1), G, tm)
     x = g[src // k]                                        # [M_pad, H]
     gmm = lambda a, w: grouped_matmul(a, w, tile_group, n_tiles, tm)
     a = jax.nn.silu(gmm(x, p[pre + ".w1"])) * gmm(x, p[pre + ".w3"])
+    if local is not None:   # an absent expert's row has no place in the
+        dest = jnp.where(local.reshape(-1), dest, 0)    # layout: read none
     y = gmm(a, p[pre + ".w2"])[dest].reshape(N, k, H)
+    if local is not None:
+        y = jnp.where(local[:, :, None], y, 0)
     y = jnp.sum(y.astype(jnp.float32) * pw[:, :, None], axis=1)
-    stats = jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)
+    stats = [jnp.sum(counts > 0), jnp.max(counts)]
+    if local is not None:
+        stats += [jnp.sum(counts), jnp.asarray(N * k)]
+    return y, jnp.stack(stats).astype(jnp.int32)
+
+
+def moe_swiglu(cfg, p, pre, g):
+    """The expert layer over ``g [N, hidden]``: the routed experts'
+    part (``moe_routed``) plus, with ``shared_experts``, one dense SwiGLU
+    over every token. Returns (y [N, hidden], the routing statistics)."""
+    y, stats = moe_routed(cfg, p, pre, g)
+    if cfg.shared_experts:
+        y = y + _dense_swiglu(p, pre + ".shared", g).astype(jnp.float32)
     return y.astype(g.dtype), stats
 
 
@@ -630,8 +738,9 @@ def ffn(cfg, p, pre, g):
         yc, stats = fn(cfg, p, pre, lax.dynamic_slice_in_dim(flat, i * C, C))
         return lax.dynamic_update_slice_in_dim(y, yc, i * C, axis=0), stats
 
-    y, stats = lax.fori_loop(0, B * T // C, body,
-                             (jnp.zeros_like(flat), jnp.zeros((2,), jnp.int32)))
+    y, stats = lax.fori_loop(
+        0, B * T // C, body,
+        (jnp.zeros_like(flat), jnp.zeros((len(step_stats(cfg)),), jnp.int32)))
     return y.reshape(B, T, H), stats
 
 
@@ -754,7 +863,10 @@ class DecoderLM(Layer):
             return ctx
         return np.minimum(ctx, self.cfg.index_topk)
 
-    step_stats = ("experts_touched", "expert_max_load")  # per layer
+    @property
+    def step_stats(self) -> Tuple[str, ...]:
+        """Names of what a decode step counts, per layer."""
+        return step_stats(self.cfg)
 
     def _forward(self, ids, start, caches=None, flash_ok=False, lengths=None):
         cfg, p = self.cfg, self._p()
@@ -812,7 +924,7 @@ class DecoderLM(Layer):
 
     def decode_step(self, tokens, caches, positions):
         """One token per slot: (logits ``[B, V]``, per layer the updated
-        pools, routing statistics ``[layers, 2]``)."""
+        pools, routing statistics ``[layers, len(step_stats)]``)."""
         logits, news, stats = self.extend_step(tokens, caches, positions)
         return Tensor(logits._value[:, -1]), news, stats
 

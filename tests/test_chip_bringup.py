@@ -178,6 +178,21 @@ out["gdn_step"] = sites(
     lambda *a: gated_delta._step_call(*a, interpret=False),
     fs(32, 30, 96), fs(32, 30, 96), fs(32, 30, 192), fs(32, 30), fs(32, 30),
     fs(96, *gated_delta.packed_shape(30, 96, 192)))
+# the Solar share's widths: the step with a decay a KEY CHANNEL on rows
+# [0, 128) of 160 (64 unpacked heads of 128 x 128), paged_decode at 64 query
+# heads on 8 K/V heads over a [128, 208] table, and the grouped matmul over
+# 40 HELD experts of 4,096 x 1,280 (rows of the absent 280 in no tile)
+out["kda_step"] = sites(
+    lambda *a: gated_delta._step_call(*a, interpret=False),
+    fs(128, 64, 128), fs(128, 64, 128), fs(128, 64, 128), fs(128, 64, 128),
+    fs(128, 64), fs(160, *gated_delta.packed_shape(64, 128, 128)))
+out["paged_gqa"] = sites(paged_attention, sds((128, 64, 1, 128)), sds((26625, 8, 16, 128)),
+                         sds((26625, 8, 16, 128)), sds((128, 208), jnp.int32), sds((128,), jnp.int32))
+from paddle_tpu.kernels.grouped_matmul import grouped_matmul, plan_groups
+def held(ids, x, w):
+    src, dest, tg, nt, counts = plan_groups(ids, 40, 16)
+    return grouped_matmul(x[src // 8], w, tg, nt, 16)
+out["gmm_held"] = sites(held, sds((1024,), jnp.int32), sds((128, 4096)), sds((40, 4096, 1280)))
 f32 = sds((64, 256), jnp.float32)
 out["prim"] = {**sites(primitive.elementwise_kernel(lambda a, b: a + 2 * b), f32, f32),
                **sites(primitive.row_reduce_kernel(lambda acc, t: acc + t.sum(-1), 0.0), f32)}
@@ -220,7 +235,9 @@ def test_kernels_and_train_step_compile_for_a_tpu_topology():
     assert out["adamw"] == {"fused_adamw": 1}
     assert out["paged"] == out["paged_1p3b"] == out["paged_30h"] \
         == {"paged_decode": 1}
-    assert out["gdn_step"] == {"gdn_decode_step": 1}
+    assert out["gdn_step"] == out["kda_step"] == {"gdn_decode_step": 1}
+    assert out["paged_gqa"] == {"paged_decode": 1}
+    assert out["gmm_held"] == {"moe_grouped_matmul": 1}
     assert out["prim"] == {"prim_elementwise": 1, "prim_row_reduce": 1}
     for key in ("step_1", "step_dp2mp2"):  # the mesh must not lose a kernel
         assert set(out[key]) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
