@@ -101,10 +101,23 @@ def unpack_state(P, num_heads: int):
 
 # ------------------------------------------------------------ chunked form
 
-def gdn_chunked(q, k, v, g, beta, S0, chunk: int = 64):
+# jitted so that a model's layers share one trace and one lowering (as
+# ``_step_call`` below: a program's text and the seconds to lower it would
+# otherwise grow with every layer's copy of the scan's body)
+@functools.partial(jax.jit, static_argnames="chunk")
+def gdn_chunked(q, k, v, g, beta, S0, chunk: int = 64, cuts=None):
     """``q, k [T, H, dk]``, ``v [T, H, dv]``, ``beta [T, H]``, ``g [T, H]``
     (a decay a head) or ``[T, H, dk]`` (one a key channel), start state
-    ``S0 [H, dv, dk]``, all float32: ``(o [T, H, dv], S_T)``."""
+    ``S0 [H, dv, dk]``, all float32: ``(o [T, H, dv], S_T)``.
+
+    With ``cuts [n]`` (int32, run-time values in ``[0, T]``) also, third,
+    the state after the last token before each cut, ``[n, H, dv, dk]``: the
+    scan hands it out from inside the chunk that holds the cut. Nothing new
+    is solved there: the rows ``U`` are causal, so the state after ``j``
+    tokens of a chunk is the chunk-end formula over the rows before ``j``,
+    ``S_j = G_j S_0 + U[:j]^T ((G_j / G[:j]) K[:j])`` (``j = 0``: ``S_0``;
+    ``j = C``: the chunk's end state), one more ``[C, dv] x [C, dk]``
+    product a cut and chunk."""
     T, H, dk = q.shape
     C = min(chunk, T)
     pad = -T % C
@@ -117,9 +130,37 @@ def gdn_chunked(q, k, v, g, beta, S0, chunk: int = 64):
     incl = t[:, None] >= t[None, :]
     strict = t[:, None] > t[None, :]
     mm = functools.partial(jnp.einsum, precision=_HI)
+    channel = g.ndim == 3
+    wanted = cuts is not None
+    cuts = jnp.clip(cuts, 0, T).astype(jnp.int32) if wanted \
+        else jnp.zeros((0,), jnp.int32)
+    # the chunk that holds each cut and the tokens of it before the cut
+    # (in [1, C]; 0 only for a cut at the call's first token)
+    cut_chunk = jnp.maximum(cuts - 1, 0) // C
+    cut_local = cuts - cut_chunk * C
+    over = lambda a, like: a.reshape(a.shape + (1,) * (like.ndim - a.ndim))
 
-    def step(S, xs):
-        qc, kc, vc, gc, bc = xs          # [H, C, dk|dv], [H, C]
+    def at_cuts(i, S, Sc, U, kc, gam):
+        """``Sc [n, H, dv, dk]`` with the state at every cut that lies in
+        chunk ``i`` (start state ``S``, solved rows ``U``, decays summed to
+        ``gam [H, C]`` or ``[H, C, dk]``). Computed in every chunk and kept
+        by a select: a ``lax.cond`` on "a cut lies in this chunk" read 1.6
+        ms an admission SLOWER on the chip at ``C = 64`` (PERF.md section 6,
+        PR 35), and under ``vmap`` it is a select anyway."""
+        # G_j: the decay summed over the j tokens before the cut
+        gj = jnp.moveaxis(jnp.take(gam, jnp.maximum(cut_local - 1, 0),
+                                   axis=1), 1, 0)         # [n, H(, dk)]
+        gj = jnp.where(over(cut_local > 0, gj), gj, 0.0)
+        gj = gj[:, :, None] if channel else gj[:, :, None, None]
+        w = jnp.exp(jnp.where(                  # G_j / G_s, rows s < j alone
+            (t[None, :] < cut_local[:, None])[:, None, :, None],
+            gj - (gam if channel else gam[..., None]), -jnp.inf))
+        Sj = jnp.exp(gj) * S + mm("hcv,nhck->nhvk", U, kc * w)
+        return jnp.where(over(i == cut_chunk, Sj), Sj, Sc)
+
+    def step(carry, xs):
+        S, Sc = carry
+        i, qc, kc, vc, gc, bc = xs       # [H, C, dk|dv], [H, C]
         gam = jnp.cumsum(gc, axis=-1)
         D = jnp.exp(jnp.where(incl, gam[:, :, None] - gam[:, None, :],
                               -jnp.inf))                        # [H, t, s]
@@ -134,13 +175,16 @@ def gdn_chunked(q, k, v, g, beta, S0, chunk: int = 64):
         U = sol[..., :dv] - mm("hck,hvk->hcv", sol[..., dv:], S)
         O = mm("hck,hvk->hcv", qc * jnp.exp(gam)[..., None], S) \
             + mm("hcs,hsv->hcv", D * mm("hck,hsk->hcs", qc, kc), U)
+        if wanted:
+            Sc = at_cuts(i, S, Sc, U, kc, gam)
         gC = gam[:, -1]
         S = jnp.exp(gC)[:, None, None] * S + mm(
             "hcv,hck->hvk", U, kc * jnp.exp(gC[:, None] - gam)[..., None])
-        return S, O
+        return (S, Sc), O
 
-    def step_channel(S, xs):
-        qc, kc, vc, gc, bc = xs          # gc [H, C, dk]: a decay a channel
+    def step_channel(carry, xs):
+        S, Sc = carry
+        i, qc, kc, vc, gc, bc = xs       # gc [H, C, dk]: a decay a channel
         gam = jnp.cumsum(gc, axis=1)
         # R[t, s, c] = G_t[c] / G_s[c] for s <= t, 0 behind t
         R = jnp.exp(jnp.where(incl[None, :, :, None],
@@ -158,13 +202,18 @@ def gdn_chunked(q, k, v, g, beta, S0, chunk: int = 64):
         dv = vc.shape[-1]
         U = sol[..., :dv] - mm("hck,hvk->hcv", sol[..., dv:], S)
         O = mm("hck,hvk->hcv", qc * eg, S) + mm("hcs,hsv->hcv", QK, U)
+        if wanted:
+            Sc = at_cuts(i, S, Sc, U, kc, gam)
         S = eg[:, -1][:, None, :] * S + mm(
             "hcv,hck->hvk", U, kc * jnp.exp(gam[:, -1:] - gam))
-        return S, O
+        return (S, Sc), O
 
-    S, O = lax.scan(step_channel if g.ndim == 3 else step, S0,
-                    tuple(map(split, (q, k, v, g, beta))))
-    return jnp.moveaxis(O, 1, 2).reshape(n * C, H, -1)[:T], S
+    Sc0 = jnp.zeros((cuts.shape[0],) + S0.shape, S0.dtype)
+    (S, Sc), O = lax.scan(
+        step_channel if channel else step, (S0, Sc0),
+        (jnp.arange(n),) + tuple(map(split, (q, k, v, g, beta))))
+    o = jnp.moveaxis(O, 1, 2).reshape(n * C, H, -1)[:T]
+    return (o, S, Sc) if wanted else (o, S)
 
 
 # ------------------------------------------------------- the recurrent step

@@ -358,7 +358,7 @@ def _indexer(cfg, p, pre, h, pos):
 
 
 def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
-              lengths=None, sparse=False):
+              lengths=None, cuts=None, sparse=False):
     """The attention part of a block over ``h [B, T, hidden]`` whose
     tokens sit at ``start[b] .. start[b] + T - 1``. Without ``cache``
     (prefill) the keys are the ones just computed; with ``cache`` (the
@@ -366,8 +366,9 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     and read back through the table. Returns (out [B, T, hidden], new):
     ``new`` the per-pool entries (``[B, 1, T, width]`` each, or ``[B, H_kv,
     T, D]`` where ``kv_layout`` is "head") without a cache, the updated
-    pools with one. ``lengths`` is not its concern: a padded token's keys
-    lie behind every real query."""
+    pools with one. ``lengths`` and ``cuts`` are not its concern: a padded
+    token's keys lie behind every real query, and its keys are a function of
+    position."""
     from ..serving import kv_cache as _kvc
 
     B, T, _ = h.shape
@@ -511,43 +512,62 @@ def _gdn_state_pools(cfg):
 
 
 def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
-                lengths=None):
+                lengths=None, cuts=None):
     """A gated delta-rule layer over ``h [B, T, hidden]`` (the module's
     docstring has the equations). Only the first ``lengths[b]`` tokens of a
     row are real: the rest neither move the state nor enter the tail.
-    ``cache`` is ``(state, conv, row)``, the layer's two state buffers
-    ``[rows, ...]`` and where this call's state lives in them: ``None`` for
-    rows ``[0, B)`` (decode, ``T = 1``), else the one row of a ``B = 1``
-    extend. Without ``cache`` the state starts at zero (prefill). Returns
-    (out [B, T, hidden], new): the updated buffers with a cache, else the
-    end state and tail ``[B, ...]`` for the engine to install."""
+    ``cuts [B, n]`` (tokens from the call's first) asks besides for the
+    state and tail as they stand BEFORE each cut's token: the chunked form
+    hands them out from inside its scan (a snapshot needs no program to end
+    where it is taken). ``cache`` is ``(state, conv, where)``, the layer's
+    two state buffers ``[rows, ...]`` and where this call's state lives in
+    them: ``None`` for rows ``[0, B)`` (decode, ``T = 1``), else ``(source,
+    rows)`` of a ``B = 1`` extend: it starts from row ``source`` and writes
+    what each cut asked for, then the end state and tail, to ``rows [n +
+    1]`` in that order (a later write wins: a cut that is not wanted names
+    the end's row). Without ``cache`` the state starts at zero (prefill).
+    Returns (out [B, T, hidden], new): the updated buffers with a cache, else
+    the same states and tails ``[B * (n + 1), ...]``, a row's cuts before
+    its end, for the engine to install."""
     from ..kernels import gated_delta as _gdn
+    from ..serving import kv_cache as _kvc
 
     B, T, _ = h.shape
     H, dk, dv, C = _gdn_widths(cfg)
     Kc = cfg.linear_conv_kernel
     f32 = jnp.float32
     if cache is None:
-        row, tail = None, jnp.zeros((B, Kc - 1, C), h.dtype)
+        where, tail = None, jnp.zeros((B, Kc - 1, C), h.dtype)
     else:
-        state, conv, row = cache
-        if row is None and T != 1:
+        state, conv, where = cache
+        if where is None and T != 1:
             raise NotImplementedError(
                 "gated_delta: several tokens a slot over every slot (the "
                 "speculative verify step) would need the state of each "
                 "position kept to roll a rejected draft back")
-        tail = conv[:B] if row is None else \
-            lax.dynamic_index_in_dim(conv, row, keepdims=True)
+        tail = conv[:B] if where is None else \
+            lax.dynamic_index_in_dim(conv, where[0], keepdims=True)
     x = jnp.concatenate([_mm(h, p[pre + w]) for w in (".wq", ".wk", ".wv")],
                         axis=-1)
     win = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # [B, T+Kc-1, C]
+    n = jnp.full((B,), T, jnp.int32) if lengths is None else lengths
+    # the last Kc - 1 real inputs before each cut and before the end: real
+    # token t is row t + Kc - 1 of ``win``
+    ends = n[:, None] if cuts is None else jnp.concatenate(
+        [jnp.minimum(cuts, n[:, None]), n[:, None]], axis=1)
+    tails = jnp.stack([jax.vmap(
+        lambda a, i: lax.dynamic_slice_in_dim(a, i, Kc - 1))(win, ends[:, j])
+        for j in range(ends.shape[1])], axis=1)
     w = p[pre + ".conv.weight"].astype(f32)
     y = jax.nn.silu(sum(win[:, j:j + T].astype(f32) * w[:, j]
                         for j in range(Kc)))
-    n = jnp.full((B,), T, jnp.int32) if lengths is None else lengths
-    # the last Kc - 1 real inputs: real token t is row t + Kc - 1 of ``win``
-    tail = jax.vmap(lambda a, i: lax.dynamic_slice_in_dim(a, i, Kc - 1))(
-        win, n)
+    if cache is not None and cuts is not None:
+        # an extend's tails are ready when its convolution is, and ``win``
+        # (T x C) dies there: left to itself the compiler takes the cuts'
+        # tails with the row writes at the program's end and keeps every
+        # layer's ``win`` until then (0.34 GiB more of extend/3328's
+        # temporaries at the hybrid cell's widths; TPU compiler, PR 35)
+        y, tails = lax.optimization_barrier((y, tails))
     unit = lambda a: a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
     q = unit(y[..., :H * dk].reshape(B, T, H, dk)) * f32(dk ** -0.5)
     k = unit(y[..., H * dk:2 * H * dk].reshape(B, T, H, dk))
@@ -570,25 +590,26 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
     beta = jnp.where(real, beta, 0.0)
     g = jnp.where(real[..., None] if channel else real, g, 0.0)
 
-    if cache is not None and row is None:
+    if cache is not None and where is None:
         o, state = _gdn.gdn_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                  beta[:, 0], state)
         o = o[:, None]
         new = (state, lax.dynamic_update_slice_in_dim(
-            conv, tail.astype(conv.dtype), 0, axis=0))
+            conv, tails[:, 0].astype(conv.dtype), 0, axis=0))
     else:
         S0 = jnp.zeros((B, H, dv, dk), f32) if cache is None else \
             _gdn.unpack_state(
-                lax.dynamic_index_in_dim(state, row, keepdims=True), H)
-        o, S = jax.vmap(lambda *a: _gdn.gdn_chunked(*a, cfg.gdn_chunk))(
-            q, k, v, g, beta, S0)
-        S = _gdn.pack_state(S)
+                lax.dynamic_index_in_dim(state, where[0], keepdims=True), H)
+        o, S, *at_cuts = jax.vmap(functools.partial(
+            _gdn.gdn_chunked, chunk=cfg.gdn_chunk))(q, k, v, g, beta, S0,
+                                                    cuts=cuts)
+        S = _gdn.pack_state(jnp.concatenate(at_cuts + [S[:, None]], axis=1))
         if cache is None:
-            new = (S, tail)
-        else:
-            new = (lax.dynamic_update_slice_in_dim(state, S, row, axis=0),
-                   lax.dynamic_update_slice_in_dim(
-                       conv, tail.astype(conv.dtype), row, axis=0))
+            new = (S.reshape((-1,) + S.shape[2:]),
+                   tails.reshape((-1,) + tails.shape[2:]))
+        else:               # B = 1: the cuts' rows, then the end's
+            new = (_kvc.write_state_rows(state, S[0], where[1]),
+                   _kvc.write_state_rows(conv, tails[0], where[1]))
     o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) \
         * p[pre + ".o_norm.weight"].astype(f32)
     if channel:
@@ -788,20 +809,21 @@ def initial_value(name: str, shape, key, std: float):
     return std * jax.random.normal(key, shape, jnp.float32)
 
 
-def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None):
+def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
+          cuts=None):
     """One block over the residual stream ``x [B, T, hidden]``: returns
     (x, the layer's new pool entries, its routing statistics)."""
     pre = f"layers.{l}"
     mixer = ATTENTIONS[cfg.kinds[l]][0]
     if cfg.norm_placement == "post":
         a, new = mixer(cfg, p, pre + ".attn", x, start, cache, flash_ok,
-                       lengths)
+                       lengths, cuts)
         x = x + _norm(cfg, a, p, pre + ".attn_norm")
         y, stats = ffn(cfg, p, pre + ".ffn", x)
         return x + _norm(cfg, y, p, pre + ".ffn_norm"), new, stats
     a, new = mixer(cfg, p, pre + ".attn",
                    _norm(cfg, x, p, pre + ".attn_norm"), start, cache,
-                   flash_ok, lengths)
+                   flash_ok, lengths, cuts)
     x = x + a
     y, stats = ffn(cfg, p, pre + ".ffn", _norm(cfg, x, p, pre + ".ffn_norm"))
     return x + y, new, stats
@@ -868,14 +890,15 @@ class DecoderLM(Layer):
         """Names of what a decode step counts, per layer."""
         return step_stats(self.cfg)
 
-    def _forward(self, ids, start, caches=None, flash_ok=False, lengths=None):
+    def _forward(self, ids, start, caches=None, flash_ok=False, lengths=None,
+                 cuts=None):
         cfg, p = self.cfg, self._p()
         x = p["embed.weight"][ids]
         news, stats = [], []
         for l in range(cfg.num_layers):
             x, new, st = block(cfg, p, l, x, start,
                                None if caches is None else caches[l], flash_ok,
-                               lengths)
+                               lengths, cuts)
             news.append(tuple(Tensor(a) for a in new))
             stats.append(st)
         return x, news, jnp.stack(stats)
@@ -893,14 +916,17 @@ class DecoderLM(Layer):
         x, _, _ = self._forward(ids, jnp.zeros((ids.shape[0],), jnp.int32))
         return Tensor(self._logits(x))
 
-    def prefill_with_cache(self, input_ids, lengths=None):
+    def prefill_with_cache(self, input_ids, lengths=None, cuts=None):
         """(last real token's logits ``[B, V]``, per layer the pool entries
-        ``[B, 1, T, width]`` for the engine to install)."""
+        ``[B, 1, T, width]`` for the engine to install; of a layer with
+        recurrent state its state and tail before each of ``cuts [B, n]``,
+        then at the end, ``[B * (n + 1), ...]``)."""
         ids = _ids(input_ids)
         B, T = ids.shape
         lengths = None if lengths is None else _ids(lengths)
         x, news, _ = self._forward(ids, jnp.zeros((B,), jnp.int32),
-                                   flash_ok=True, lengths=lengths)
+                                   flash_ok=True, lengths=lengths,
+                                   cuts=None if cuts is None else _ids(cuts))
         if lengths is None:
             last = x[:, T - 1]
         else:
@@ -908,18 +934,20 @@ class DecoderLM(Layer):
             last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
         return Tensor(self._logits(last)), news
 
-    def extend_step(self, tokens, caches, positions, lengths=None):
+    def extend_step(self, tokens, caches, positions, lengths=None, cuts=None):
         """``tokens [B, T]`` at ``positions[b] + t`` over the paged pools:
         (logits ``[B, T, V]``, per layer the updated pools). ``lengths``
         says how many of a row's tokens are real, which a layer with
-        recurrent state has to know."""
+        recurrent state has to know; ``cuts [B, n]`` before which tokens it
+        writes its state to the rows its cache entry names besides."""
         ids = _ids(tokens)
         ids = ids[:, None] if ids.ndim == 1 else ids
         start = jnp.broadcast_to(_ids(positions), (ids.shape[0],))
         entries = [tuple(map(_raw, e)) for e in caches]
         x, news, stats = self._forward(
             ids, start, entries,
-            lengths=None if lengths is None else _ids(lengths))
+            lengths=None if lengths is None else _ids(lengths),
+            cuts=None if cuts is None else _ids(cuts))
         return Tensor(self._logits(x)), news, Tensor(stats)
 
     def decode_step(self, tokens, caches, positions):

@@ -61,7 +61,7 @@ from ..observability import metrics as _metrics
 from ..observability.tracing import span as _span
 from . import sampling as _sampling
 from .kv_cache import (PAGE_SENTINEL, PagedKVCache, _layer_buffers,
-                       paged_write_kv, write_kv)
+                       paged_write_kv, write_kv, write_state_rows)
 from .prefix_cache import PrefixCache
 from .request_trace import RequestTracer, SLOConfig
 from .sampling import SamplingParams
@@ -171,20 +171,19 @@ def _write_prompt_dense(kc, vc, kvs):
                          (kc, vc), kvs)
 
 
-def _write_prompt_paged(cache, pools, kvs, page_row, slot=None):
+def _write_prompt_paged(cache, pools, kvs, page_row, rows=None):
     """Each layer's prompt entries into that layer's pools: a paged pool's
     ``[1, heads, T, width]`` at positions ``[0, T)``, routed by the slot's
     table row, one scatter of the bucket's pages per pool
     (``paged_write_kv``; blocks past the allocated pages, sentinels, land
-    on the trash page); a state pool's end state ``[1, ...]`` onto row
-    ``slot``."""
+    on the trash page); a state pool's ``[len(rows), ...]`` (the state at
+    each cut, then at the end) onto ``rows`` (``write_state_rows``)."""
     table, zero = page_row[None, :], jnp.zeros((1,), jnp.int32)
     paged = len(cache.pool_specs)
-    state_row = lambda c, new: lax.dynamic_update_slice_in_dim(
-        c, new.astype(c.dtype), slot, axis=0)
     return tuple(
         tuple((paged_write_kv(c, new, table, zero) if j < paged
-               else state_row(c, new)) for c, new in zip(pool, news))
+               else write_state_rows(c, new, rows))
+              for c, new in zip(pool, news))
         for j, (pool, news) in enumerate(zip(pools, _updated(cache, kvs))))
 
 
@@ -432,7 +431,7 @@ class Engine:
         self.page_alloc = PageAllocator(num_pages)
         # snapshot ids [1, snapshots], refcounted as pages are: the trie
         # holds one reference a node that carries one, an admission one on
-        # the snapshot it resumes from until the restore is issued
+        # the snapshot it resumes from until its program is enqueued
         self.snapshot_alloc: Optional[PageAllocator] = \
             PageAllocator(snapshots + 1) if snapshots else None
         _metrics.gauge("serving.kv_cache.bytes", self.cache.nbytes)
@@ -465,11 +464,8 @@ class Engine:
                                             self.page_alloc,
                                             self.snapshot_alloc)
             # pages can be shared from here on: have the copy-on-write
-            # program compiled now, never between two decode steps (and the
-            # program that takes and restores snapshots with it)
+            # program compiled now, never between two decode steps
             self.cache.copy_page_exe()
-            if self.snapshot_alloc is not None:
-                self.cache.copy_state_exe()
         self.spec: Optional[SpeculativeConfig] = self.config.speculative
         # cumulative speculation accounting (greedy rows only — sampled
         # rows ignore drafts and always emit 1 token from position 0)
@@ -597,24 +593,39 @@ class Engine:
 
         @jax.named_scope("serving/prefill")
         def paged_prefill_fn(p, *a):
-            pools, (ids, page_row, length, *slot) = a[:n], a[n:]
+            pools, (ids, page_row, length, *state) = a[:n], a[n:]
+            # (a model with recurrent state hands out its state before each
+            # cut too, for the rows ``_state_arg`` names)
+            more = {"cuts": Tensor(state[0][None, 1:3])} if state else {}
             logits, kvs, _ = _call(model, p, "prefill_with_cache",
                                    Tensor(ids),
-                                   lengths=Tensor(length[None]))
-            return (logits,) + _write_prompt_paged(cache, pools, kvs,
-                                                   page_row, *slot)
+                                   lengths=Tensor(length[None]), **more)
+            return (logits,) + _write_prompt_paged(
+                cache, pools, kvs, page_row, *(s[3:] for s in state))
 
         args = (self.params, *self.cache.pools,
                 jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
-                jnp.int32(1)) + self._slot_arg()
+                jnp.int32(1)) + self._state_arg()
         return paged_prefill_fn, args
 
-    def _slot_arg(self, slot: int = 0) -> Tuple:
+    def _state_arg(self, slot: int = 0, source: Optional[int] = None,
+                   cuts: Sequence[Tuple[int, int]] = ()) -> Tuple:
         """What the prefill and extend programs of a model with recurrent
-        state take last: the slot whose state row they write (and, extend,
-        start from). Nothing for any other model: its programs are the ones
-        they were."""
-        return (jnp.int32(slot),) if self._stateful else ()
+        state take last, one int32 array ``[source row, cut, cut, row, row,
+        slot]``: the row an extend starts from (snapshot ``source``'s; a
+        prefill starts from zero), the state BEFORE each cut (``cuts``:
+        ``[(tokens from the run's first, snapshot id)]``, two at most) goes
+        to that snapshot's row, the end state to the slot's, written last:
+        a cut that is not wanted names the slot's row too. Nothing for any
+        other model: its programs are the ones they were."""
+        if not self._stateful:
+            return ()
+        row = self.cache.snapshot_row
+        (c0, r0), (c1, r1) = [(0, slot)] * (2 - len(cuts)) + [
+            (tokens, row(snap)) for tokens, snap in cuts]
+        return (jnp.asarray(np.array(
+            [slot if source is None else row(source), c0, c1, r0, r1, slot],
+            np.int32)),)
 
     def decode_program(self):
         """(fn, example_args) for the batched decode step — see
@@ -680,13 +691,16 @@ class Engine:
 
         @jax.named_scope("serving/extend")
         def extend_fn(p, *a):
-            pools, (ids, page_row, start, length, *slot) = a[:n], a[n:]
-            # a model with recurrent state starts from its slot's row and
-            # has to know which tokens are padding
-            more = {"lengths": Tensor(length[None])} if slot else {}
+            pools, (ids, page_row, start, length, *state) = a[:n], a[n:]
+            # a model with recurrent state starts from the row it is told,
+            # has to know which tokens are padding, and writes its state
+            # before each cut and at the end where told (``_state_arg``)
+            more = {"lengths": Tensor(length[None]),
+                    "cuts": Tensor(state[0][None, 1:3])} if state else {}
             lv, new, _ = _call(                     # logits [1, T, V]
                 model, p, "extend_step", Tensor(ids),
-                cache.layer_entries(pools, page_row[None, :], *slot),
+                cache.layer_entries(pools, page_row[None, :],
+                                    *((s[0], s[3:]) for s in state)),
                 Tensor(start[None]), **more)
             idx = jnp.clip(length - 1, 0, T - 1)
             last = lax.dynamic_index_in_dim(lv[0], idx, keepdims=False)
@@ -694,7 +708,7 @@ class Engine:
 
         args = (self.params, *self.cache.pools,
                 jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
-                jnp.int32(0), jnp.int32(1)) + self._slot_arg()
+                jnp.int32(0), jnp.int32(1)) + self._state_arg()
         return extend_fn, args
 
     def verify_program(self, k: Optional[int] = None):
@@ -878,12 +892,13 @@ class Engine:
         A model with recurrent state resumes where a SNAPSHOT lies, not
         where the pages reach: of the ``hit_blocks`` the trie matched only
         the first ``snapshot_blocks`` (the deepest node on the path that
-        carries a snapshot) are spliced, the snapshot is copied into the
-        slot's state row (``serving/admit/restore``), and every token behind
-        it runs again into pages of the request's own. It takes a snapshot
-        where the prompt left the cached path and at the prompt's last
-        whole block (``serving/snapshot``): the prompt is run in pieces
-        that end there."""
+        carries a snapshot) are spliced, and every token behind it runs
+        again into pages of the request's own. It takes a snapshot where
+        the prompt left the cached path and at the prompt's last whole
+        block (``serving/snapshot``). All of it is the ONE program an
+        admission launches (``programs``): the extend starts from the
+        snapshot's row and writes the new snapshots' rows from inside its
+        scan (``_state_arg``); no row is copied."""
         n = len(req.prompt_ids)
         owner = f"req{req.request_id}"
         ps = self.cache.page_size
@@ -958,41 +973,37 @@ class Engine:
                                        alloc.seconds)
                 else:
                     _metrics.counter("serving.prefix.misses", 1)
+            sp = req.sampling
+            # the prompt from the splice on: one program, whatever it
+            # resumes from and whichever snapshots it takes
+            snaps = list(zip(cuts, taken))
+            logits = self._run_prompt(req, slot, splice * ps, n, source,
+                                      snaps)
+            adm.set(programs=1)
             if source is not None:
+                # its reader is enqueued: the hold on the snapshot goes
                 with _span("serving/admit/restore",
                            request_id=req.request_id, blocks=splice):
-                    self.cache.copy_state(self.cache.snapshot_row(source),
-                                          slot)
-                self.snapshot_alloc.free([source], owner=owner)
-            sp = req.sampling
-            # the prompt from the splice on, in pieces that end where a
-            # snapshot is taken (one piece for a model without state)
-            pos = splice * ps
-            snap_at = {c * ps: snap for c, snap in zip(cuts, taken)}
-            for end in sorted(set(snap_at) | {n}):
-                if end > pos:
-                    logits = self._run_prompt(req, slot, pos, end)
-                    pos = end
-                if end in snap_at:
-                    with _span("serving/snapshot", request_id=req.request_id,
-                               blocks=end // ps, evicted=dropped, reason=(
-                                   "prompt_end" if end // ps == n // ps
-                                   else "branch")):
-                        self.cache.copy_state(
-                            slot, self.cache.snapshot_row(snap_at[end]))
-                    dropped = 0     # counted once an admission
+                    self.snapshot_alloc.free([source], owner=owner)
             with _span("serving/admit/sample", request_id=req.request_id):
                 if self.prefix_cache is not None:
                     # index this prompt's FULL blocks (shared ones are
                     # already nodes; fresh ones take a trie-owned reference
                     # and become matchable the moment the next prompt
-                    # agrees), and hand their nodes the snapshots taken
+                    # agrees), and hand their nodes the snapshots the
+                    # program wrote
                     self.prefix_cache.insert(
                         req.prompt_ids,
                         self.cache.slot_pages(slot)[:n // ps])
-                    for end, snap in snap_at.items():
-                        self.prefix_cache.attach_snapshot(
-                            req.prompt_ids, end // ps, snap, owner)
+                    for block, snap in snaps:
+                        with _span("serving/snapshot",
+                                   request_id=req.request_id, blocks=block,
+                                   evicted=dropped, reason=(
+                                       "prompt_end" if block == n // ps
+                                       else "branch")):
+                            self.prefix_cache.attach_snapshot(
+                                req.prompt_ids, block, snap, owner)
+                        dropped = 0     # counted once an admission
                 key = _random.next_key() if sp.do_sample else _dummy_key()
                 tok = int(np.asarray(_sampling.sample_static(
                     logits, key, do_sample=sp.do_sample,
@@ -1016,12 +1027,17 @@ class Engine:
         self._maybe_finish(req, tok)
         return True
 
-    def _run_prompt(self, req: Request, slot: int, start: int, end: int):
+    def _run_prompt(self, req: Request, slot: int, start: int, end: int,
+                    source: Optional[int] = None,
+                    snaps: Sequence[Tuple[int, int]] = ()):
         """Tokens ``[start, end)`` of the request's prompt through the
         bucketed prefill program (from position 0) or, behind what the slot
         already holds, the extend program (the suffix-only prefill; >= 1
         token by construction: matching is capped at (n-1)//ps blocks): the
-        last token's logits ``[1, V]``."""
+        last token's logits ``[1, V]``. A model with recurrent state starts
+        from snapshot ``source`` and writes its state after ``block`` whole
+        blocks to snapshot ``id``'s row, for each ``(block, id)`` of
+        ``snaps``."""
         m = end - start
         T = self._bucket(m)
         kind = "extend" if start else "prefill"
@@ -1034,7 +1050,9 @@ class Engine:
             logits, *self.cache.pools = self._held(kind, T)(
                 self.params, *self.cache.pools, jnp.asarray(ids),
                 jnp.asarray(self.cache.page_table[slot]), *where,
-                *self._slot_arg(slot))
+                *self._state_arg(slot, source, [
+                    (block * self.cache.page_size - start, snap)
+                    for block, snap in snaps]))
         return logits
 
     def _ensure_writable(self, slot: int, block: int, owner: str) -> bool:

@@ -201,6 +201,16 @@ def paged_write_kv(pool, new, page_table, positions):
     return pool.at[pages].set(merged)
 
 
+def write_state_rows(buf, new, rows):
+    """``new [n, ...]`` onto rows ``rows [n]`` (run-time values) of the state
+    buffer ``buf [rows, ...]``, one after the other where it lies: where two
+    name the same row, the later one stands."""
+    for i in range(new.shape[0]):
+        buf = lax.dynamic_update_slice_in_dim(
+            buf, new[i:i + 1].astype(buf.dtype), rows[i], axis=0)
+    return buf
+
+
 def paged_gather(pool, page_table):
     """Materialize the dense ``[B, H_kv, num_blocks*ps, D]`` view of a page
     pool under a table — the oracle path's cache reconstruction (sentinels
@@ -308,9 +318,10 @@ class PagedKVCache:
     them all, prefill and extend write one), the rows behind them hold
     SNAPSHOTS: a slot's state as it stood at a block boundary of its
     prompt, which the prefix trie keeps beside that block's page
-    (``prefix_cache.py``). Taking and restoring one is ``copy_state``, a
-    row copied inside every state buffer by one compiled program, as
-    ``copy_page`` copies a page. ``.pools`` holds the state buffers behind
+    (``prefix_cache.py``). No row is ever copied: the extend program of an
+    admission reads its start state from the snapshot's row and writes the
+    snapshots it takes to their rows itself, beside the slot's
+    (``Engine._state_arg``). ``.pools`` holds the state buffers behind
     the paged ones, each pool a tuple over ITS layers; ``layer_entries`` /
     ``pools_from_layers`` go between that and what one layer is handed.
     """
@@ -368,7 +379,6 @@ class PagedKVCache:
         self._table_dev: Optional[jax.Array] = None
         self._free: List[int] = list(range(max_batch_size))[::-1]
         self._copy_exe = None
-        self._copy_state_exe = None
 
     @property
     def pools(self):
@@ -468,32 +478,6 @@ class PagedKVCache:
         ``[1, num_snapshots]``, as a ``PageAllocator`` hands them out)."""
         return self.max_batch_size + snapshot - 1
 
-    def copy_state_exe(self):
-        """The compiled program ``(*state pools, src, dst) -> state pools``
-        that copies row ``src`` onto row ``dst`` inside every state buffer
-        (donated; row ids are runtime scalars, so one executable takes every
-        snapshot and makes every restore). Compiled on first use."""
-        if self._copy_state_exe is None:
-            state = self.pools[len(self.pool_specs):]
-
-            def copy_state_fn(*a):
-                *bufs, src, dst = a
-                one = lambda b: lax.dynamic_update_slice_in_dim(
-                    b, lax.dynamic_slice_in_dim(b, src, 1), dst, axis=0)
-                return tuple(tuple(map(one, pool)) for pool in bufs)
-
-            self._copy_state_exe = jax.jit(
-                copy_state_fn, donate_argnums=tuple(range(len(state)))) \
-                .lower(*state, jnp.int32(0), jnp.int32(0)).compile()
-        return self._copy_state_exe
-
-    def copy_state(self, src: int, dst: int):
-        """Row ``src`` of every state buffer onto row ``dst``: a snapshot
-        taken (slot -> ``snapshot_row``) or restored (the other way)."""
-        n = len(self.pool_specs)
-        self.pools = self.pools[:n] + tuple(self.copy_state_exe()(
-            *self.pools[n:], jnp.int32(src), jnp.int32(dst)))
-
     def slot_pages(self, slot: int) -> List[int]:
         row = self.page_table[slot]
         return [int(p) for p in row if p != PAGE_SENTINEL]
@@ -524,14 +508,15 @@ class PagedKVCache:
     def active_slots(self) -> int:
         return self.max_batch_size - len(self._free)
 
-    def layer_entries(self, pools, table, row=None):
+    def layer_entries(self, pools, table, rows=None):
         """Per-layer ``(pool_0, ..., pool_n, where)`` entries of the pool
         tuples, in the order the model declared its pools: ``where`` is the
         page ``table`` for a layer of paged pools, and for a layer of state
-        the ``row`` its state lives in (``None``: rows ``[0, B)``)."""
+        the ``rows`` its state lives in (``None``: rows ``[0, B)``; else
+        ``(the row read, the rows written)`` of a one-slot extend)."""
         n = len(self.pool_specs)
         return [tuple(pools[j][i] for j, i in held)
-                + ((table,) if held[0][0] < n else (row,))
+                + ((table,) if held[0][0] < n else (rows,))
                 for held in self._of_layer]
 
     def pools_from_layers(self, per_layer):

@@ -196,6 +196,12 @@ def _inputs(T, H, dk, dv, seed, gate):
             jax.random.normal(ks[5], (H, dv, dk), f32))
 
 
+#: where a state is asked for among 100 tokens in chunks of 16
+CUTS = {"at_0": (0,), "at_a_chunks_edge": (32,), "inside_a_chunk": (37,),
+        "inside_the_last_chunk": (98,), "at_T": (100,),
+        "two_cuts": (37, 96), "two_in_one_chunk": (33, 47)}
+
+
 @pytest.mark.parametrize("gate", GATES)
 class TestTwoFormsOfOneRecurrence:
     @pytest.mark.parametrize("T,chunk", [(1, 8), (7, 4), (64, 16), (100, 32),
@@ -230,6 +236,73 @@ class TestTwoFormsOfOneRecurrence:
             jnp.where(real[:, None], beta, 0.0), S0, 8)
         _, want = _recurrence(q[:29], k[:29], v[:29], g[:29], beta[:29], S0)
         np.testing.assert_allclose(S, want, atol=2e-5)
+
+    @pytest.mark.parametrize("cuts", CUTS.values(), ids=CUTS)
+    def test_state_at_a_cut_is_the_recurrence_stopped_there(self, gate, cuts):
+        """100 tokens in chunks of 16 (the last holds 4 real ones), the cuts
+        run-time values: the state handed out at each is what the token-by-
+        token recurrence holds after the tokens before it; the outputs and
+        the end state are what they are without cuts."""
+        *x, S0 = _inputs(100, 4, 24, 48, 7, gate)
+        o, S, at = jax.jit(lambda *a: gd.gdn_chunked(*a[:6], 16, a[6]))(
+            *x, S0, jnp.asarray(cuts, jnp.int32))
+        want_o, want_S = _recurrence(*x, S0)
+        np.testing.assert_allclose(o, want_o, atol=2e-5)
+        np.testing.assert_allclose(S, want_S, atol=2e-5)
+        assert at.shape == (len(cuts),) + S0.shape
+        for c, got in zip(cuts, at):
+            _, want = _recurrence(*(a[:c] for a in x), S0)
+            np.testing.assert_allclose(got, want, atol=2e-5)
+        # the comparison can fail: a token further on the state is another
+        _, near = _recurrence(*(a[:cuts[-1] - 1] for a in x), S0)
+        assert np.abs(at[-1] - near).max() > 1e-2 or cuts[-1] == 0
+
+    def test_a_cut_in_the_padding_is_the_end(self, gate):
+        """Padding behind ``lengths`` (b = 0, g = 0) moves nothing: a cut
+        inside it, or past it, hands out the state after the last real
+        token; one before it the state there. Under a steep decay too (30
+        nats a token): every exponent at a cut is a difference <= 0."""
+        q, k, v, g, beta, S0 = _inputs(40, 4, 8, 16, 3, gate)
+        g = 15.0 * g
+        real = jnp.arange(40) < 29
+        _, S, at = gd.gdn_chunked(
+            q, k, v, jnp.where(real.reshape((40,) + (1,) * (g.ndim - 1)), g,
+                               0.0),
+            jnp.where(real[:, None], beta, 0.0), S0, 8,
+            jnp.asarray([21, 29, 35, 40], jnp.int32))
+        assert np.isfinite(np.asarray(at)).all()
+        for c, got in zip((21, 29, 29, 29), at):
+            _, want = _recurrence(q[:c], k[:c], v[:c], g[:c], beta[:c], S0)
+            np.testing.assert_allclose(got, want, atol=2e-5)
+        np.testing.assert_allclose(S, at[-1], atol=2e-5)
+
+    @pytest.mark.parametrize("cuts", [(8, 24), (0, 29), (13, 35)])
+    def test_layer_hands_out_state_and_tail_at_the_cuts(self, gate, cuts):
+        """``decoder.gated_delta`` over 40 tokens of which 29 are real: at
+        each cut the packed state and the convolution's tail are those the
+        same layer ends with over the tokens before the cut alone (a cut in
+        the padding: over the real ones), and the tail is the last ``Kc -
+        1`` inputs of the convolution before it, zeros before the first."""
+        model = _model(linear_gate=gate)
+        p, pre = _params(model), "layers.1.attn"
+        h = jax.random.normal(jax.random.PRNGKey(5), (1, 40, 32), jnp.float32)
+        layer = lambda h, **kw: dec.gated_delta(
+            model.cfg, p, pre, h, jnp.zeros((1,), jnp.int32), **kw)[1]
+        S, tails = layer(h, lengths=jnp.asarray([29]),
+                         cuts=jnp.asarray([cuts]))
+        assert S.shape[0] == tails.shape[0] == len(cuts) + 1
+        x = jnp.concatenate([h[0] @ p[pre + w] for w in (".wq", ".wk", ".wv")],
+                            axis=-1)
+        x = jnp.concatenate([jnp.zeros((3, x.shape[1])), x])
+        for i, c in enumerate(cuts + (29,)):
+            c = min(c, 29)
+            np.testing.assert_allclose(tails[i], x[c:c + 3], atol=1e-6)
+            if c:
+                want_S, want_tail = layer(h[:, :c])
+                np.testing.assert_allclose(S[i], want_S[0], atol=2e-5)
+                np.testing.assert_allclose(tails[i], want_tail[0], atol=1e-6)
+            else:
+                assert not np.asarray(S[i]).any()
 
     @pytest.mark.parametrize("impl", ["oracle", "pallas"])
     @pytest.mark.parametrize("H,dk,dv", [(4, 8, 16), (2, 128, 128),
@@ -347,6 +420,122 @@ class TestAgainstReference:
         want = ref.full_attention(None, h[0], lp, RCFG, ref.mm_highest, 40)
         np.testing.assert_allclose(got[0], want, atol=1e-5)
         assert "layers.0.attn.wg" in p and "layers.0.attn.q_norm.weight" not in p
+
+
+# ------------------------------------------- one program an admission
+
+def _admit_watched(eng, prompt):
+    """Admit ``prompt`` (telemetry on): (the request, its ``serving/admit``
+    span, the programs looked up to be run while it was admitted)."""
+    keys, held = [], eng._held
+    eng._held = lambda *key: keys.append(key) or held(*key)
+    req = eng.add_request(prompt, SamplingParams(max_new_tokens=4))
+    assert eng._admit() == 1
+    eng._held = held
+    adm = [e for e in tracing.spans() if e["name"] == "serving/admit"][-1]
+    return req, adm, keys
+
+
+def _state_rows(eng, row):
+    """Row ``row`` of every state buffer, over the layers that keep one."""
+    return [np.asarray(buf[row]) for pool in
+            eng.cache.pools[len(eng.cache.pool_specs):] for buf in pool]
+
+
+def _scenario(eng, name):
+    """Serve what comes before, then return the prompt whose admission is
+    under test and what its span has to say: (prompt, kind of program,
+    hit_blocks, snapshot_blocks, blocks snapshotted). This model's traffic:
+    single-turn requests over one shared prefix."""
+    shared = _ids(20, seed=1)                       # 2 whole blocks + 4
+    serve = lambda ps: eng.generate(ps, SamplingParams(max_new_tokens=4))
+    if name == "cold":
+        return shared + _ids(9, seed=9), "prefill", 0, 0, [3]
+    if name == "cold_two_cuts":         # leaves the cached path at a block
+        serve([shared + _ids(9, seed=9)])           # no snapshot lies at
+        return shared + _ids(13, seed=13), "prefill", 2, 0, [2, 4]
+    if name == "at_a_branch":
+        serve([shared + _ids(9, seed=9), shared + _ids(13, seed=13)])
+        return shared + _ids(30, seed=30), "extend", 2, 2, [6]
+    if name == "behind_a_branch":       # pages match deeper than a snapshot
+        second = shared + _ids(13, seed=13)
+        serve([shared + _ids(9, seed=9), second])
+        return second[:30] + _ids(13, seed=4), "extend", 3, 2, [3, 5]
+    if name == "at_a_prompts_end":
+        first = shared + _ids(9, seed=9)
+        out = serve([first])[0]
+        return first + out + _ids(6, seed=3), "extend", 3, 3, [4]
+    assert name == "at_a_pages_edge"    # a prompt of three whole pages
+    first = _ids(3 * PS, seed=2)
+    out = serve([first])[0]
+    return first + out + _ids(7, seed=3), "extend", 3, 3, [4]
+
+
+SCENARIOS = ["cold", "cold_two_cuts", "at_a_branch", "behind_a_branch",
+             "at_a_prompts_end", "at_a_pages_edge"]
+
+
+class TestOneProgramAnAdmission:
+    @pytest.mark.parametrize("name", SCENARIOS)
+    def test_every_admission_is_one_program(self, model, telemetry, name):
+        """Cold, resumed at a branch, behind one, at a prompt's end and at
+        one that lies on a page's edge: the admission looks up ONE program
+        and says so (``programs``), under one ``serving/admit/extend`` or
+        ``/prefill`` span over all the tokens behind the snapshot; the
+        snapshots it owes are taken, the tokens are a cold engine's."""
+        eng = _engine(model)
+        prompt, kind, hit, resumed, snaps = _scenario(eng, name)
+        before = [e["attrs"]["programs"] for e in tracing.spans()
+                  if e["name"] == "serving/admit"]
+        assert before == [1] * len(before)
+        req, adm, keys = _admit_watched(eng, prompt)
+        n = len(prompt)
+        assert keys == [(kind, eng._bucket(n - resumed * PS))]
+        a = adm["attrs"]
+        assert (a["programs"], a["hit_blocks"], a["snapshot_blocks"]) == (
+            1, hit, resumed)
+        runs = [e for e in tracing.spans() if e["parent"] == adm["id"]
+                and e["name"] in ("serving/admit/prefill",
+                                  "serving/admit/extend")]
+        assert [(e["name"], e["attrs"]["tokens"]) for e in runs] == [
+            ("serving/admit/" + kind, n - resumed * PS)]
+        taken = [e["attrs"]["blocks"] for e in tracing.spans()
+                 if e["name"].startswith("serving/snapshot{")
+                 and e["attrs"]["request_id"] == a["request_id"]]
+        assert taken == snaps
+        while eng.has_unfinished:
+            eng.step()
+        cold = _engine(model, prefix_cache=False).generate(
+            [prompt], SamplingParams(max_new_tokens=4))[0]
+        assert req.output_ids == cold
+
+    @pytest.mark.parametrize("name", SCENARIOS[1:4])
+    def test_snapshot_rows_are_those_of_a_program_that_ends_there(
+            self, model, telemetry, name):
+        """What the one program wrote to each snapshot's row from inside
+        its scan (state and tail, every layer) is what lies in the slot's
+        row of an engine whose program ENDED at that block (the same prompt
+        cut off there, served cold): the rows the path in pieces copied."""
+        eng = _engine(model)
+        prompt, _, _, _, snaps = _scenario(eng, name)
+        _admit_watched(eng, prompt)
+        ends = {}
+        for block in snaps:
+            at, snap = eng.prefix_cache.deepest_snapshot(prompt + [0], block)
+            assert at == block
+            cut = _engine(model, prefix_cache=False)
+            req = cut.add_request(prompt[:block * PS],
+                                  SamplingParams(max_new_tokens=4))
+            assert cut._admit() == 1
+            got = _state_rows(eng, eng.cache.snapshot_row(snap))
+            ends[block] = _state_rows(cut, req.slot)
+            assert len(got) == 6                    # S and tail, 3 layers
+            for a, b in zip(got, ends[block]):
+                np.testing.assert_allclose(a, b, atol=1e-4)
+        # the comparison can fail: another block's rows are far from these
+        if len(snaps) == 2:
+            assert max(np.abs(a - b).max() for a, b in
+                       zip(*ends.values())) > 1e-2
 
 
 # ------------------------------------------------ the share of the experts
