@@ -121,12 +121,14 @@ def stop_trace(run: Run):
     jax.profiler.stop_trace()
     run.trace_host = (run._trace_t0, t1)
     d = trace_dir(run)
-    t0 = time.perf_counter()
+    t2 = time.perf_counter()
     run.trace = trace.reduce_file(trace.find_xplane(d))
     n_ops = sum(len(v) for v in run.trace["devices"].values())
-    run.say(f"trace: {n_ops} device ops on {len(run.trace['devices'])} "
-            f"device(s), {len(run.trace['spans'])} benchmark spans, reduced "
-            f"in {time.perf_counter() - t0:.1f} s")
+    run.say(f"trace: closed at {t1 - run.t_start:.1f} s from process start, "
+            f"profiler stopped in {t2 - t1:.1f} s; {n_ops} device ops on "
+            f"{len(run.trace['devices'])} device(s), "
+            f"{len(run.trace['spans'])} benchmark spans, reduced in "
+            f"{time.perf_counter() - t2:.1f} s")
     if os.environ.get("BENCH_KEEP_TRACE"):
         run.say(f"trace kept at {d}")
     else:

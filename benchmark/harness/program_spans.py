@@ -13,7 +13,8 @@ records no such span (the parent of the PR that added them) gives None too.
 Idle by program phase puts the moved spans in place of the benchmark's own in
 a copy of the reduced trace and calls ``trace.idle_gaps_by_span`` (innermost
 span wins, uncovered time goes to its catch-all) — slice by slice of the
-window, because that function looks at every span for every gap.
+window: the one sweep of that function needs no slices, but the order in which
+the pieces are summed, and so every share's last digits, follows them.
 """
 
 from __future__ import annotations

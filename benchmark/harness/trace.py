@@ -17,8 +17,10 @@ profiler types:
 from __future__ import annotations
 
 import glob
+import heapq
 import os
 import re
+from bisect import bisect_left, bisect_right
 from collections import defaultdict
 
 from . import stats
@@ -60,9 +62,9 @@ def base_name(name: str) -> str:
     return _SUFFIX.sub("", n) or n
 
 
-def _shape_of(event) -> str:
+def _shape_of(event_name: str) -> str:
     """First output shape in the event's HLO line, '' where there is none."""
-    m = _SHAPE.search(event.name)
+    m = _SHAPE.search(event_name)
     return m.group(1) if m else ""
 
 
@@ -75,6 +77,9 @@ def reduce_file(path: str, device_plane=DEVICE_PLANE, ops_line=OPS_LINE):
 
 def reduce_planes(planes, device_plane=DEVICE_PLANE, ops_line=OPS_LINE):
     devices, modules, spans = {}, {}, []
+    # an op event is named by its whole HLO line, and millions of events
+    # hold a few thousand distinct lines: each is parsed once
+    parsed = {}  # event name -> (is a container, instruction name, shape)
     for plane in planes:
         if device_plane.match(plane.name):
             ops = []
@@ -87,11 +92,15 @@ def reduce_planes(planes, device_plane=DEVICE_PLANE, ops_line=OPS_LINE):
                 if line.name != ops_line:
                     continue
                 for e in line.events:
-                    if base_name(e.name) in CONTAINERS:
+                    name = e.name
+                    if name not in parsed:
+                        parsed[name] = (base_name(name) in CONTAINERS,
+                                        instr_name(name), _shape_of(name))
+                    container, instr, shape = parsed[name]
+                    if container:
                         continue
                     s = e.start_ns * 1e-9
-                    ops.append((s, s + e.duration_ns * 1e-9,
-                                instr_name(e.name), _shape_of(e)))
+                    ops.append((s, s + e.duration_ns * 1e-9, instr, shape))
             devices[plane.name] = sorted(ops)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -162,7 +171,11 @@ def idle_gaps_by_span(tr, k: int = 10, device=None):
     """Idle time of one device (the first by name unless given) split by the
     benchmark span that covers it: for every gap between device ops, the
     part under each ``bench/...`` span goes to that span (innermost wins),
-    the rest to ``_no_benchmark_span_``."""
+    the rest to ``_no_benchmark_span_``.
+
+    One sweep in time order over the gaps, the span edges and a heap of the
+    spans that are open: (gaps + spans) log spans, whatever the engine's
+    speed."""
     if not tr["devices"]:
         return []
     name = device or sorted(tr["devices"])[0]
@@ -176,16 +189,22 @@ def idle_gaps_by_span(tr, k: int = 10, device=None):
     if t1 > cur:
         gaps.append((cur, t1))
     acc = defaultdict(float)
-    spans = tr["spans"]
+    spans = sorted(tr["spans"], key=lambda sp: sp[0])
+    edges = sorted({x for s, e, _ in spans for x in (s, e)})
+    entered, opened = 0, []  # spans[:entered] are in the heap of open spans
     for gs, ge in gaps:
         # cut the gap at every span edge; each piece goes to the shortest
-        # span covering it
-        cuts = sorted({gs, ge} | {x for s, e, _ in spans for x in (s, e)
-                                  if gs < x < ge})
+        # span covering it (ties by name)
+        cuts = [gs, *edges[bisect_right(edges, gs):bisect_left(edges, ge)], ge]
         for a, b in zip(cuts, cuts[1:]):
-            mid = (a + b) / 2
-            cover = [(e - s, n) for s, e, n in spans if s <= mid < e]
-            acc[min(cover)[1] if cover else "_no_benchmark_span_"] += b - a
+            mid = (a + b) / 2  # never falls from piece to piece
+            while entered < len(spans) and spans[entered][0] <= mid:
+                s, e, n = spans[entered]
+                heapq.heappush(opened, (e - s, n, e))
+                entered += 1
+            while opened and opened[0][2] <= mid:  # a span that has ended
+                heapq.heappop(opened)              # leaves when it is on top
+            acc[opened[0][1] if opened else "_no_benchmark_span_"] += b - a
     return [[n, v] for n, v in sorted(acc.items(), key=lambda kv: -kv[1])[:k]]
 
 

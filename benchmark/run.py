@@ -94,16 +94,22 @@ def drive(run, man: dict) -> dict:
                 out["metrics"][m["name"]] = {"value": values[m["name"]],
                                              "unit": m["unit"]}
     else:
+        t0 = time.perf_counter()
         for m in metrics_of(man, run.cell_name, "per_layer"):
             v = reader(m["name"])(run)
             if v is not None:
                 out["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
+        t1 = time.perf_counter()
         if run.trace is not None:
             dev["busy_s"] = trace.busy_seconds(run.trace)
             dev["window_s"] = trace.window_seconds(run.trace)
             out["breakdown"] = {
                 "device_ops": trace.top_ops(run.trace, 10),
                 "idle_gaps": trace.idle_gaps_by_span(run.trace, 10)}
+        t2 = time.perf_counter()
+        run.say(f"after the window: per-layer readers {t1 - t0:.1f} s, "
+                f"breakdown {t2 - t1:.1f} s; result line at "
+                f"{t2 - run.t_start:.1f} s from process start")
     return out
 
 
