@@ -87,10 +87,15 @@ def _span_name(name: str, labels: Dict[str, Any]) -> str:
 
 class _Off:
     """What ``span()`` returns when nothing records: enters, exits and
-    ``set``s for free, and reports zero seconds."""
+    ``set``s for free, reports zero seconds, and is false (a recording
+    ``Span`` is true), so ``if sp:`` guards an attribute whose value costs
+    something to compute."""
 
     __slots__ = ()
     seconds = 0.0
+
+    def __bool__(self):
+        return False
 
     def __enter__(self):
         return self

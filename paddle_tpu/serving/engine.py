@@ -457,6 +457,10 @@ class Engine:
         self._stale = set(_OPERANDS)
         self._exe: Dict = {}
         self._step_i = 0  # engine steps so far: the spans' ``step``
+        # calls of a compiled executable so far (an eager stretch counts
+        # one): the spans' ``launch`` / ``waits_for``, which a reader of the
+        # device's trace joins to the program runs, in order
+        self._launch_i = 0
         self._cow_copies = 0  # copy-on-write page copies so far
         self.prefix_cache: Optional[PrefixCache] = None
         if self.config.prefix_cache:
@@ -896,9 +900,11 @@ class Engine:
         again into pages of the request's own. It takes a snapshot where
         the prompt left the cached path and at the prompt's last whole
         block (``serving/snapshot``). All of it is the ONE program an
-        admission launches (``programs``): the extend starts from the
-        snapshot's row and writes the new snapshots' rows from inside its
-        scan (``_state_arg``); no row is copied."""
+        admission launches (the ``launch`` of its prefill or extend span):
+        the extend starts from the snapshot's row and writes the new
+        snapshots' rows from inside its scan (``_state_arg``); no row is
+        copied. The sampler's eager ops behind it take the next number, on
+        the ``serving/admit/sample`` span that also waits for them."""
         n = len(req.prompt_ids)
         owner = f"req{req.request_id}"
         ps = self.cache.page_size
@@ -979,13 +985,17 @@ class Engine:
             snaps = list(zip(cuts, taken))
             logits = self._run_prompt(req, slot, splice * ps, n, source,
                                       snaps)
-            adm.set(programs=1)
             if source is not None:
                 # its reader is enqueued: the hold on the snapshot goes
                 with _span("serving/admit/restore",
                            request_id=req.request_id, blocks=splice):
                     self.snapshot_alloc.free([source], owner=owner)
-            with _span("serving/admit/sample", request_id=req.request_id):
+            # eager ops (a greedy request's one argmax, a sampled one's key
+            # and draw): one launch number for the stretch
+            self._launch_i += 1
+            with _span("serving/admit/sample", request_id=req.request_id,
+                       launch=self._launch_i, eager=1,
+                       waits_for=self._launch_i):
                 if self.prefix_cache is not None:
                     # index this prompt's FULL blocks (shared ones are
                     # already nodes; fresh ones take a trie-owned reference
@@ -1041,12 +1051,15 @@ class Engine:
         m = end - start
         T = self._bucket(m)
         kind = "extend" if start else "prefill"
+        self._launch_i += 1
         with _span("serving/admit/" + kind, request_id=req.request_id,
-                   tokens=m, bucket=T):
+                   tokens=m, bucket=T, launch=self._launch_i):
             ids = np.zeros((1, T), np.int32)
             ids[0, :m] = req.prompt_ids[start:end]
-            where = (jnp.int32(start), jnp.int32(m)) if start \
-                else (jnp.int32(m),)
+            # host scalars: ``jnp.int32(m)`` is a program of its own on the
+            # device (a ``convert_element_type`` run an operand)
+            where = (np.int32(start), np.int32(m)) if start \
+                else (np.int32(m),)
             logits, *self.cache.pools = self._held(kind, T)(
                 self.params, *self.cache.pools, jnp.asarray(ids),
                 jnp.asarray(self.cache.page_table[slot]), *where,
@@ -1073,6 +1086,7 @@ class Engine:
             fresh = self.page_alloc.alloc(1, owner=owner)
         if fresh is None:
             return False
+        self._launch_i += 1
         self.cache.copy_page(page, fresh[0])
         self._cow_copies += 1
         self.cache.repoint(slot, block, fresh[0])
@@ -1090,6 +1104,7 @@ class Engine:
         ps, S_max = self.cache.page_size, self.config.max_seq_len
         allocated = cache_full = 0
         cow_before = self._cow_copies
+        first = self._launch_i + 1  # of the copies' launches, if any
         with _span("serving/decode/grow_pages") as sp:
             for slot, st in enumerate(self._slots):
                 req = st.request
@@ -1119,6 +1134,8 @@ class Engine:
                     cache_full += 1
             sp.set(allocated=allocated, cache_full=cache_full,
                    cow_copies=self._cow_copies - cow_before)
+            if self._launch_i >= first:
+                sp.set(launch=first, launches=self._launch_i - first + 1)
 
     def _decode(self) -> int:
         """One batched decode step, as one ``serving/decode`` span over its
@@ -1156,19 +1173,20 @@ class Engine:
             sp.set(running=len(running))
             if not running:
                 return 0
-            # cached tokens the step's attention may read (each running
-            # slot's context, the token it writes included) and how many of
-            # them it does read, where the model selects; pages the
-            # paged-decode kernel's loops walk (a layer) this step, of the
-            # table entries a grid over the table would
-            ctx = self._positions[[r.slot for r in running]] + 1
-            sel = getattr(self.model, "selected_tokens", None)
-            sp.set(ctx_tokens=int(ctx.sum()),
-                   selected_tokens=int((ctx if sel is None
-                                        else sel(ctx)).sum()),
-                   live_pages=int(((ctx - 1) // self.cache.page_size
-                                   + 1).sum()),
-                   table_pages=B * self.cache.num_blocks)
+            if sp:
+                # cached tokens the step's attention may read (each running
+                # slot's context, the token it writes included) and how many
+                # of them it does read, where the model selects; pages the
+                # paged-decode kernel's loops walk (a layer) this step, of
+                # the table entries a grid over the table would
+                ctx = self._positions[[r.slot for r in running]] + 1
+                sel = getattr(self.model, "selected_tokens", None)
+                sp.set(ctx_tokens=int(ctx.sum()),
+                       selected_tokens=int((ctx if sel is None
+                                            else sel(ctx)).sum()),
+                       live_pages=int(((ctx - 1) // self.cache.page_size
+                                       + 1).sum()),
+                       table_pages=B * self.cache.num_blocks)
             tokens, step_s = self._tokens, 0.0
             if spec is not None:
                 with _span("serving/decode/propose") as prop:
@@ -1182,8 +1200,13 @@ class Engine:
                         tokens[slot, 1:] = drafts[slot]
                 step_s = prop.seconds
             with _span("serving/decode/upload") as up:
-                any_sampled = not bool(self._greedy.all())
-                key = _random.next_key() if any_sampled else _dummy_key()
+                if bool(self._greedy.all()):
+                    key = _dummy_key()
+                else:
+                    # the key's eager ops: one launch number
+                    self._launch_i += 1
+                    up.set(launch=self._launch_i, eager=1)
+                    key = _random.next_key()
                 # put what the host changed, in one call and from copies
                 # (a put may alias host memory the mirrors go on changing);
                 # everything else is the array the device already holds
@@ -1195,9 +1218,12 @@ class Engine:
                 if stale:
                     self._dev.update(jax.device_put(stale))
                     self._stale.clear()
-                up.set(puts=len(stale) + table_put, table_put=table_put)
+                if up:
+                    up.set(puts=len(stale) + table_put, table_put=table_put)
                 args = (table, *(self._dev[name] for name in _OPERANDS), key)
-            with _span("serving/decode/dispatch") as disp:
+            self._launch_i += 1
+            with _span("serving/decode/dispatch",
+                       launch=self._launch_i) as disp:
                 exe = self._decode_exe() if spec is None \
                     else self._verify_exe()
                 out = exe(self.params, *self.cache.pools, *args)
@@ -1212,10 +1238,11 @@ class Engine:
                     # decides the next tokens and positions in settle
                     toks, sampled0, *self.cache.pools = out
                     self._stale.update(("tokens", "positions"))
-            with _span("serving/decode/fetch") as fetch:
+            with _span("serving/decode/fetch",
+                       waits_for=self._launch_i) as fetch:
                 toks = np.asarray(toks)
                 sampled0 = toks if spec is None else np.asarray(sampled0)
-            if spec is None and toks.shape[0] > B:
+            if sp and spec is None and toks.shape[0] > B:
                 # a model that counts in its step (decoder.DecoderLM: per
                 # layer the distinct experts routed to, the largest
                 # expert's rows) sent its counts behind the tokens

@@ -470,7 +470,7 @@ class PagedKVCache:
         copy."""
         n = len(self.pool_specs)
         self.pools = tuple(self.copy_page_exe()(
-            *self.pools[:n], jnp.int32(src), jnp.int32(dst))) + self.pools[n:]
+            *self.pools[:n], np.int32(src), np.int32(dst))) + self.pools[n:]
 
     # -- slot state and its snapshots --
     def snapshot_row(self, snapshot: int) -> int:

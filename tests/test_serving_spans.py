@@ -330,6 +330,106 @@ def test_admit_time_is_kept_with_everything_off(quiet):
     assert obs.spans() == []
 
 
+# ---------------- launch numbers -------------------------------------------
+#: every span name of serving/README.md's table (``compile{site=}`` and
+#: ``serving/snapshot{reason=}`` carry their one label each)
+DOCUMENTED = {"serving/generate", "serving/step", "serving/admit",
+              "serving/decode", "serving/decode/grow_pages",
+              "serving/decode/propose", "serving/decode/upload",
+              "serving/decode/dispatch", "serving/decode/fetch",
+              "serving/decode/settle", "compile{site=serving.prefill}",
+              "compile{site=serving.decode}"} | ADMIT_PHASES
+
+
+def _numbered(spans):
+    """[(number, span)] of the spans that carry a launch, in number order."""
+    return sorted(((e["attrs"]["launch"], e) for e in spans
+                   if "launch" in e["attrs"]), key=lambda ne: ne[0])
+
+
+@SPEC
+def test_every_launch_has_the_next_number_and_fetches_wait_for_their_own(
+        telemetry, speculative):
+    eng = _engine(_tiny(), speculative)
+    assert eng._launch_i == 0       # construction compiles, launches nothing
+    _generate(eng)
+    spans = obs.spans()
+    numbered = _numbered(spans)
+    # decode (or verify), prefill, extend and the sampler's eager stretch:
+    # each takes the next number, none twice, none left out
+    assert [n for n, _ in numbered] == list(range(1, eng._launch_i + 1))
+    steps = sum(1 for e in spans if e["name"] == "serving/decode/fetch")
+    assert Counter(e["name"] for _, e in numbered) == Counter({
+        "serving/decode/dispatch": steps, "serving/admit/prefill": 2,
+        "serving/admit/extend": 1, "serving/admit/sample": 3})
+    # numbers are facts of one span: no attribute became a label
+    assert {e["name"] for e in spans} <= DOCUMENTED
+    for e in spans:
+        for k in ("launch", "launches", "waits_for", "eager"):
+            assert isinstance(e["attrs"].get(k, 0), int)
+    kids = _children(spans)
+    for dec in (e for e in spans if e["name"] == "serving/decode"):
+        by = {c["name"]: c["attrs"] for c in kids[dec["id"]]}
+        assert by["serving/decode/fetch"]["waits_for"] == \
+            by["serving/decode/dispatch"]["launch"]
+        assert "launch" not in by["serving/decode/upload"]  # all greedy
+        assert "launch" not in by["serving/decode/grow_pages"]
+    admits = [e for e in spans if e["name"] == "serving/admit"]
+    assert len(admits) == 3
+    for adm in admits:
+        assert "programs" not in adm["attrs"]
+        mine = [c["attrs"] for c in kids[adm["id"]] if "launch" in c["attrs"]]
+        work, sample = mine
+        # what ``programs`` counted: the compiled launches under the span
+        assert sum(a.get("launches", 1) for a in mine
+                   if not a.get("eager")) == 1
+        assert "eager" not in work and "bucket" in work
+        assert (sample["launch"], sample["waits_for"], sample["eager"]) == (
+            work["launch"] + 1, work["launch"] + 1, 1)
+
+
+@SPEC
+def test_the_counter_advances_with_everything_off(quiet, speculative):
+    off = _engine(_tiny(), speculative)
+    _generate(off)
+    assert obs.spans() == [] and len(obs.get_registry()) == 0
+    obs.enable()
+    try:
+        on = _engine(_tiny(), speculative)
+        _generate(on)
+        fetches = sum(1 for e in obs.spans()
+                      if e["name"] == "serving/decode/fetch")
+    finally:
+        obs.disable()
+    # three admissions of two launches each and one a decode step
+    assert off._launch_i == on._launch_i == 6 + fetches
+
+
+def test_a_sampled_row_and_a_page_copy_take_numbers_too(telemetry):
+    eng = _engine(_tiny())
+    prompt = _prompts()[1]
+    req = eng.add_request(prompt, SamplingParams(
+        max_new_tokens=4, do_sample=True, temperature=0.8, top_k=5))
+    eng.step()
+    # another sharer of the page the next step writes: copy-on-write
+    page = int(eng.cache.page_table[req.slot, len(prompt) // 16])
+    eng.page_alloc.retain([page], owner="test")
+    eng.step()
+    spans = obs.spans()
+    numbered = _numbered(spans)
+    assert [(e["name"].rsplit("/", 1)[-1], n, e["attrs"].get("launches", 1),
+             e["attrs"].get("eager", 0)) for n, e in numbered] == [
+        ("prefill", 1, 1, 0), ("sample", 2, 1, 1),
+        ("upload", 3, 1, 1), ("dispatch", 4, 1, 0),     # the step's key
+        ("grow_pages", 5, 1, 0),                        # the one copy
+        ("upload", 6, 1, 1), ("dispatch", 7, 1, 0)]
+    assert eng._launch_i == 7
+    grow = [e["attrs"] for e in spans
+            if e["name"] == "serving/decode/grow_pages"]
+    assert "launch" not in grow[0] and grow[1]["cow_copies"] == 1
+    assert {e["name"] for e in spans} <= DOCUMENTED
+
+
 # ---------------- the train step -------------------------------------------
 def test_train_step_span_and_compile(telemetry):
     from paddle_tpu.distributed.fleet.utils import make_sharded_train_step

@@ -475,24 +475,33 @@ SCENARIOS = ["cold", "cold_two_cuts", "at_a_branch", "behind_a_branch",
              "at_a_prompts_end", "at_a_pages_edge"]
 
 
+def _programs(adm) -> int:
+    """Compiled executables launched under one ``serving/admit`` span (the
+    sampler's eager stretch is none)."""
+    return sum(e["attrs"].get("launches", 1) for e in tracing.spans()
+               if e["parent"] == adm["id"] and "launch" in e["attrs"]
+               and not e["attrs"].get("eager"))
+
+
 class TestOneProgramAnAdmission:
     @pytest.mark.parametrize("name", SCENARIOS)
     def test_every_admission_is_one_program(self, model, telemetry, name):
         """Cold, resumed at a branch, behind one, at a prompt's end and at
         one that lies on a page's edge: the admission looks up ONE program
-        and says so (``programs``), under one ``serving/admit/extend`` or
-        ``/prefill`` span over all the tokens behind the snapshot; the
+        (the one compiled ``launch`` under its span), on one
+        ``serving/admit/extend`` or ``/prefill`` span over all the tokens
+        behind the snapshot; the
         snapshots it owes are taken, the tokens are a cold engine's."""
         eng = _engine(model)
         prompt, kind, hit, resumed, snaps = _scenario(eng, name)
-        before = [e["attrs"]["programs"] for e in tracing.spans()
+        before = [_programs(e) for e in tracing.spans()
                   if e["name"] == "serving/admit"]
         assert before == [1] * len(before)
         req, adm, keys = _admit_watched(eng, prompt)
         n = len(prompt)
         assert keys == [(kind, eng._bucket(n - resumed * PS))]
         a = adm["attrs"]
-        assert (a["programs"], a["hit_blocks"], a["snapshot_blocks"]) == (
+        assert (_programs(adm), a["hit_blocks"], a["snapshot_blocks"]) == (
             1, hit, resumed)
         runs = [e for e in tracing.spans() if e["parent"] == adm["id"]
                 and e["name"] in ("serving/admit/prefill",
