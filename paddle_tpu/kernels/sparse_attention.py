@@ -2,7 +2,7 @@
 exact top-k selection of cached positions by an indexer's scores, and a
 Pallas kernel that attends over the selected rows of the paged K / V pools.
 
-Selection is exact and sort-free. ``kth_largest`` finds each row's k-th
+Selection is exact and sort-free. ``order_stat.kth_largest`` finds each row's k-th
 largest score by bisection over the score's bits (32 compare-and-count
 passes over the row, where a sort of a 34k-wide row per query would cost a
 long prefill about a second a layer); ``topk_mask`` turns it into the set
@@ -40,34 +40,12 @@ from jax.sharding import PartitionSpec as P
 from ..core.place import pallas_interpret
 from .flash_attention import LANES, LOG2E, NEG_INF
 from .mesh import shard_kernel
+from .order_stat import kth_largest, ordered_bits
 
 _LANE_BLOCK = 128   # positions per block of the compaction
 
 
 # ------------------------------------------------------------- selection
-
-def _ordered_bits(scores, valid):
-    """float32 scores -> uint32 keys in the same order (larger score, larger
-    key); positions that are not ``valid`` get key 0, below every real
-    score's key (a real key has its top bit set or flipped, never all
-    zero except for -NaN payloads, which scores are not)."""
-    b = lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.uint32)
-    neg = (b >> 31) == 1
-    u = jnp.where(neg, ~b, b | jnp.uint32(0x80000000))
-    return jnp.where(valid, u, jnp.uint32(0))
-
-
-def kth_largest(u, k: int):
-    """The k-th largest uint32 key of each row of ``u [..., L]`` (0 where a
-    row has fewer than k non-zero keys): built bit by bit from the top, each
-    bit one compare-and-count pass."""
-    def body(i, t):
-        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
-        n = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
-        return jnp.where(n >= k, cand, t)
-
-    return lax.fori_loop(0, 32, body, jnp.zeros(u.shape[:-1], jnp.uint32))
-
 
 def topk_mask(scores, valid, k: int):
     """bool ``[..., L]``: the k valid positions of largest score in each row
@@ -76,7 +54,7 @@ def topk_mask(scores, valid, k: int):
     with invalid positions at -inf."""
     if scores.shape[-1] <= k:
         return valid
-    u = _ordered_bits(scores, valid)
+    u = ordered_bits(scores, valid)
     t = kth_largest(u, k)[..., None]
     above = u > t
     at = (u == t) & valid

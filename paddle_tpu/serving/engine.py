@@ -462,6 +462,10 @@ class Engine:
         # device's trace joins to the program runs, in order
         self._launch_i = 0
         self._cow_copies = 0  # copy-on-write page copies so far
+        # decode steps whose program ran the sampler's argmax alone (every
+        # row greedy) / drew as well (``sampling.sample_batched``)
+        self.sampler_steps_argmax = 0
+        self.sampler_steps_draw = 0
         self.prefix_cache: Optional[PrefixCache] = None
         if self.config.prefix_cache:
             self.prefix_cache = PrefixCache(self.cache.page_size,
@@ -1173,6 +1177,13 @@ class Engine:
             sp.set(running=len(running))
             if not running:
                 return 0
+            # live rows that are not greedy (a dead slot's row reads greedy):
+            # with none the program's sampler runs its argmax alone
+            draws = B - int(np.count_nonzero(self._greedy))
+            if draws:
+                self.sampler_steps_draw += 1
+            else:
+                self.sampler_steps_argmax += 1
             if sp:
                 # cached tokens the step's attention may read (each running
                 # slot's context, the token it writes included) and how many
@@ -1181,7 +1192,7 @@ class Engine:
                 # the table entries a grid over the table would
                 ctx = self._positions[[r.slot for r in running]] + 1
                 sel = getattr(self.model, "selected_tokens", None)
-                sp.set(ctx_tokens=int(ctx.sum()),
+                sp.set(draws=draws, ctx_tokens=int(ctx.sum()),
                        selected_tokens=int((ctx if sel is None
                                             else sel(ctx)).sum()),
                        live_pages=int(((ctx - 1) // self.cache.page_size
@@ -1200,7 +1211,7 @@ class Engine:
                         tokens[slot, 1:] = drafts[slot]
                 step_s = prop.seconds
             with _span("serving/decode/upload") as up:
-                if bool(self._greedy.all()):
+                if not draws:
                     key = _dummy_key()
                 else:
                     # the key's eager ops: one launch number

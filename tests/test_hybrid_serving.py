@@ -610,16 +610,17 @@ class TestOneProgramAnAdmission:
 #: parent of the PR that gave the programs of a model WITH state their
 #: state operand (f556f11; jax 0.9.0, x64 on as in these tests). A PR that
 #: means to change these programs records them again: print
-#: ``_lowered(...)`` of each below.
+#: ``_lowered(...)`` of each below. The four decode programs were recorded
+#: again at PR 39 (the sampler's conditional in place of its sort).
 WITHOUT_STATE = {
     "gpt/prefill/oracle": (6, "3baaa1868e5f7615"),
     "gpt/extend/oracle": (7, "24143a35f6d0155c"),
-    "gpt/decode/oracle": (10, "ffd243edb8e8e881"),
-    "gpt/decode/pallas": (10, "6295af5d162870e5"),
+    "gpt/decode/oracle": (10, "b4ef0e96c4294d47"),
+    "gpt/decode/pallas": (10, "d1c27414629100fa"),
     "decoder/prefill/oracle": (7, "0024ab6d3c6af9d9"),
     "decoder/extend/oracle": (8, "539dd0b7ee3df441"),
-    "decoder/decode/oracle": (11, "0cbca59a96c1871c"),
-    "decoder/decode/pallas": (11, "8a71409af0e14bc8"),
+    "decoder/decode/oracle": (11, "abe326ead78fb9e8"),
+    "decoder/decode/pallas": (11, "3a2a30328dd3ee6f"),
 }
 
 
