@@ -2,9 +2,11 @@
 
 ``DecoderConfig`` names the kind of each part of the block — norm
 (``rms`` | ``layer``) and where it stands (``pre`` | ``post``), positions
-(``rope`` | ``none``), the token mixer (``dense`` | ``indexed_sparse`` |
-``gated_delta``; one kind for all layers, or ``layer_types``, one a
-layer), FFN (``swiglu`` | ``moe_swiglu``), router (``softmax_topk``) — with
+(``rope`` | ``rope_yarn`` | ``none``), the token mixer (``dense`` |
+``indexed_sparse`` | ``gated_delta`` | ``latent``; one kind for all layers,
+or ``layer_types``, one a layer), FFN (``swiglu`` | ``moe_swiglu``; the
+first ``first_dense_layers`` layers a dense swiglu of a width of their
+own), router (``softmax_topk`` | ``sigmoid_group_topk``) — with
 their widths and what varies within a kind (a dense layer's output gate,
 the width of a gated_delta layer's decay, which of a layer's experts are
 held here, shared experts); the parts are looked up by kind in
@@ -48,12 +50,47 @@ and the last ``linear_conv_kernel - 1`` inputs of the convolution; prefill
 and extend run the chunked form from a given state and tail, decode the
 recurrent step (``kernels/gated_delta``).
 
+A ``latent`` layer (multi-head latent attention, arXiv:2412.19437 section
+2.1; ``H`` heads, ranks ``rq`` / ``rkv``, a head's lanes ``dn`` without
+positions + ``dr`` rotary, values ``dv``), with ``h`` the layer's input::
+
+    c_q = RMSNorm(h wq_a)                                  [rq]
+    q   = c_q wq_b -> H x (dn | dr);   q_pe = yarn(q_pe)
+    [c | k_pe] = h wkv_a                                   [rkv | dr]
+    c   = RMSNorm(c);  k_pe = yarn(k_pe)        (ONE k_pe for all heads)
+    k_nope = c wk_b,  v = c wv_b                           H x dn, H x dv
+    s   = (q_nope . k_nope + q_pe . k_pe) * (dn + dr)^-1/2 * mscale^2
+    o   = softmax_f32(s) v -> wo       (causal; ``softmax_scale``)
+
+``rope_yarn`` turns pair i at ``inv_freq = theta^(-2i/dr)`` blended with the
+same ``/ factor`` by a linear ramp between the pairs that make
+``beta_fast`` and ``beta_slow`` turns over the original context
+(``yarn_inv_freq``); ``mscale = 0.1 ln(factor) + 1``. What the layer keeps
+a token is ONE row ``[c | k_pe | idle lanes]`` (``latent_pool_width``), keys
+and values the same bytes, in a pool of its own. Two forms of one layer:
+prefill and extend EXPAND ``k_nope`` and ``v`` from the cached rows
+(``latent_attend``: on the TPU a group of heads at a time into the Pallas
+kernel ``kernels/latent_attention.latent_flash``, elsewhere a block of
+keys at a time under a float32 online softmax in
+``jax.numpy``); decode never does: ``q~_h = q_nope_h wk_b_h^T``,
+``s_h = ([q~_h | q_pe_h] . [c | k_pe]) * scale``, ``o_h = (softmax(s_h) c)
+wv_b_h`` (the absorbed form; on the TPU the Pallas kernel
+``kernels/latent_attention.latent_paged_decode``, which reads a row once
+for scores and values).
+
+``sigmoid_group_topk`` (DeepSeek-V3's ``noaux_tc``), float32: ``s =
+sigmoid(g Wr)`` over all experts; the CHOICE on ``s + bias``: a group's
+score is the sum of its two largest, the ``topk_group`` best of ``n_group``
+groups are kept, the top-k taken inside them; the WEIGHTS are ``s`` of the
+chosen over their sum, times ``routed_scaling_factor``.
+
 It speaks the serving engine's whole protocol (serving/README.md):
 ``cache_pools()`` declares the paged pools of the layers that keep keys (K
 and V token-major, one
 "head" of ``H_kv * D``, so that a token's K is one run of bytes for the
 sparse read, or head-major ``[pages, H_kv, page, D]`` for the paged-decode
-kernel, ``kv_layout``; the indexer's keys, in whole 128-lane rows),
+kernel, ``kv_layout``; the indexer's keys, in whole 128-lane rows; a latent
+layer's one row a token),
 ``state_pools()`` the slot-indexed state of the layers that keep a
 recurrence; ``prefill_with_cache`` / ``extend_step`` /
 ``decode_step`` are pure functions of (parameters, pools, page table).
@@ -97,12 +134,17 @@ class DecoderConfig:
     norm: str = "rms"             # rms | layer
     norm_eps: float = 1e-6
     norm_placement: str = "pre"   # pre: x + f(norm(x)); post: x + norm(f(x))
-    position: str = "rope"        # rope | none
+    position: str = "rope"        # rope | rope_yarn | none
     rope_theta: float = 1e7
+    # rope_yarn's parameters, under the published names: factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim (``yarn_inv_freq``, ``softmax_scale``)
+    rope_scaling: Optional[dict] = None
     # RMSNorm on q and k: True / "head" over each head, "full" over all of a
     # token's heads together, False none
     qk_norm: Union[bool, str] = True
-    attention: str = "indexed_sparse"   # dense | indexed_sparse | gated_delta
+    # dense | indexed_sparse | gated_delta | latent
+    attention: str = "indexed_sparse"
     # one kind a layer (keys of ATTENTIONS); None: ``attention`` for all
     layer_types: Optional[Tuple[str, ...]] = None
     # how a dense layer's K and V pages lie: "token" [pages, 1, page,
@@ -127,9 +169,26 @@ class DecoderConfig:
     linear_gate: str = "head"
     linear_gate_rank: int = 8
     gdn_chunk: int = 64           # tokens per chunk of the chunked form
+    # a latent layer's widths (arXiv:2412.19437 section 2.1): the ranks of
+    # the query's and the keys-and-values' latents, a head's query/key lanes
+    # without and with positions, a head's value lanes
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
     ffn: str = "moe_swiglu"       # swiglu | moe_swiglu
     intermediate_size: int = 128  # swiglu's width; an expert's in moe_swiglu
-    router: str = "softmax_topk"
+    # the FFN by layer: the first ``first_dense_layers`` layers are a dense
+    # swiglu of width ``dense_intermediate_size`` whatever ``ffn`` says
+    first_dense_layers: int = 0
+    dense_intermediate_size: int = 256
+    router: str = "softmax_topk"  # softmax_topk | sigmoid_group_topk
+    # sigmoid_group_topk: the experts stand in ``n_group`` groups of which a
+    # token keeps ``topk_group``; the chosen weights times the factor
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
     num_experts: int = 8
     experts_per_token: int = 2
     norm_topk_prob: bool = True
@@ -175,6 +234,18 @@ class DecoderConfig:
                     f"num_layers is {self.num_layers}")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
+        if self.position == "rope_yarn" and not (
+                self.rope_scaling and set(self.kinds) == {"latent"}):
+            # the other mixers' decode and flash paths scale by width^-1/2
+            raise ValueError("position rope_yarn wants rope_scaling, and "
+                             "serves latent layers alone")
+        if self.num_experts % self.n_group or self.topk_group > self.n_group:
+            raise ValueError(
+                f"n_group {self.n_group} / topk_group {self.topk_group}: want "
+                f"groups that divide {self.num_experts} experts")
+        if not 0 <= self.first_dense_layers <= self.num_layers:
+            raise ValueError(f"first_dense_layers {self.first_dense_layers} "
+                             f"of {self.num_layers} layers")
         if self.experts_held is not None:
             n, first = self.experts_held = tuple(self.experts_held)
             if not (n >= 1 and first >= 0 and first + n <= self.num_experts):
@@ -186,6 +257,13 @@ class DecoderConfig:
     def kinds(self) -> Tuple[str, ...]:
         """The token mixer's kind, one a layer."""
         return self.layer_types or (self.attention,) * self.num_layers
+
+    @property
+    def ffns(self) -> Tuple[Tuple[str, int], ...]:
+        """The FFN's (kind, width), one a layer."""
+        d = self.first_dense_layers
+        return (("swiglu", self.dense_intermediate_size),) * d \
+            + ((self.ffn, self.intermediate_size),) * (self.num_layers - d)
 
 
 # ------------------------------------------------------------------ norms
@@ -226,11 +304,11 @@ def _norm(cfg, x, p, pre):
 
 # -------------------------------------------------------------- positions
 
-def rope(x, pos, theta):
-    """Rotary positions on ``x [B, T, heads, D]`` at ``pos [B, T]``: the
-    half-split form (pair i is (x[i], x[i + D/2])), angles in float32."""
+def _rotate(x, pos, inv):
+    """``x [B, T, heads, D]`` turned by the angles ``pos [B, T]`` x ``inv
+    [D/2]``: the half-split form (pair i is (x[i], x[i + D/2])), angles in
+    float32."""
     D = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) * 2.0 / D)
     ang = pos.astype(jnp.float32)[:, :, None, None] * inv     # [B,T,1,D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :D // 2].astype(jnp.float32), x[..., D // 2:].astype(jnp.float32)
@@ -238,7 +316,63 @@ def rope(x, pos, theta):
                            axis=-1).astype(x.dtype)
 
 
-POSITIONS = {"rope": rope, "none": lambda x, pos, theta: x}
+def rope(x, pos, theta):
+    """Rotary positions on ``x [B, T, heads, D]`` at ``pos [B, T]``."""
+    D = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) * 2.0 / D)
+    return _rotate(x, pos, inv)
+
+
+def yarn_inv_freq(D: int, theta: float, scaling: dict) -> np.ndarray:
+    """YaRN's frequencies of the ``D / 2`` rotary pairs (arXiv:2309.00071, as
+    DeepSeek-V3's rotary class computes them): pair i keeps its frequency
+    ``theta^(-2i/D)`` below the ramp, turns ``factor`` times slower above
+    it, and is blended linearly between; the ramp rises from
+    ``floor(d(beta_fast))`` to ``ceil(d(beta_slow))``, ``d(n) = D ln(original
+    / (2 pi n)) / (2 ln theta)`` the pair that makes n turns over the
+    original context. float32."""
+    half = np.arange(0, D, 2, dtype=np.float64) / D
+    extra = theta ** -half
+    inter = extra / scaling["factor"]
+    orig = scaling["original_max_position_embeddings"]
+    at = lambda n: D * np.log(orig / (n * 2 * np.pi)) / (2 * np.log(theta))
+    low = max(np.floor(at(scaling["beta_fast"])), 0)
+    high = min(np.ceil(at(scaling["beta_slow"])), D - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(D // 2) - low) / (high - low), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def rope_yarn(cfg, x, pos):
+    """YaRN rotary positions; cos and sin carry ``mscale / mscale_all_dim``
+    (1 where the two are equal)."""
+    sc = cfg.rope_scaling
+    inv = jnp.asarray(yarn_inv_freq(x.shape[-1], cfg.rope_theta, sc))
+    m = _yarn_mscale(sc["factor"], sc.get("mscale", 1)) \
+        / _yarn_mscale(sc["factor"], sc.get("mscale_all_dim", 0))
+    y = _rotate(x.astype(jnp.float32), pos, inv)
+    return (y if m == 1.0 else y * jnp.float32(m)).astype(x.dtype)
+
+
+def softmax_scale(cfg, width: int) -> float:
+    """A latent layer's softmax scale for queries and keys ``width`` wide:
+    ``width^-1/2``, under rope_yarn times ``mscale(mscale_all_dim)^2``."""
+    scale = float(width) ** -0.5
+    if cfg.position == "rope_yarn":
+        sc = cfg.rope_scaling
+        scale *= _yarn_mscale(sc["factor"], sc.get("mscale_all_dim", 0)) ** 2
+    return scale
+
+
+#: kind -> positions on ``x [B, T, heads, D]`` at ``pos [B, T]``
+POSITIONS = {"rope": lambda cfg, x, pos: rope(x, pos, cfg.rope_theta),
+             "rope_yarn": rope_yarn,
+             "none": lambda cfg, x, pos: x}
 
 
 # -------------------------------------------------------------- attention
@@ -387,7 +521,7 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
         q = rms_norm(q, p, pre + ".q_norm", cfg.norm_eps)
         k = rms_norm(k, p, pre + ".k_norm", cfg.norm_eps)
     turn = POSITIONS[cfg.position]
-    q, k = turn(q, pos, cfg.rope_theta), turn(k, pos, cfg.rope_theta)
+    q, k = turn(cfg, q, pos), turn(cfg, k, pos)
     index = _indexer(cfg, p, pre + ".index", h, pos) if sparse else None
     head_major = cfg.kv_layout == "head"
     if head_major:
@@ -621,6 +755,214 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
     return _mm(y, p[pre + ".wo"]), new
 
 
+# ------------------------------------------------ latent (MLA) attention
+
+def latent_pool_width(cfg) -> int:
+    """Lanes a token's latent row takes in its pool: ``[c (kv_lora_rank) |
+    k_pe (qk_rope_head_dim)]`` rounded up to whole 128-lane rows (576 -> 640
+    at the published widths), for ``index_pool_width``'s reason: the chip
+    stores a minor dimension in whole 128-lane tiles either way, and a pool
+    that is not declared so is re-laid whole by every program that touches
+    it. The idle lanes are written as zeros and read as such."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _latent_shapes(cfg, pre):
+    Hd, H = cfg.hidden_size, cfg.num_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {pre + ".wq_a": (Hd, rq), pre + ".q_norm.weight": (rq,),
+            pre + ".wq_b": (rq, H * (dn + dr)),
+            pre + ".wkv_a": (Hd, rkv + dr), pre + ".kv_norm.weight": (rkv,),
+            pre + ".wk_b": (rkv, H * dn), pre + ".wv_b": (rkv, H * dv),
+            pre + ".wo": (H * dv, Hd)}
+
+
+def _latent_queries(cfg, p, pre, cq, pos):
+    """(q_nope [B, T, H, dn], q_pe [B, T, H, dr], rotated) of the query's
+    normed latent ``cq [B, T, rq]``."""
+    B, T, _ = cq.shape
+    dn = cfg.qk_nope_head_dim
+    q = _mm(cq, p[pre + ".wq_b"]).reshape(B, T, cfg.num_heads, -1)
+    return q[..., :dn], POSITIONS[cfg.position](cfg, q[..., dn:], pos)
+
+
+#: heads whose keys and values the kernel path expands at a time
+_LATENT_HEAD_GROUP = 8
+#: keys the ``jax.numpy`` path expands at a time
+_LATENT_KEY_BLOCK = 512
+
+
+def _latent_attend_flash(cfg, p, pre, cq, c_view, kpe_view, qpos):
+    """``latent_attend`` on the TPU: a group of heads at a time, the group's
+    queries projected and its keys and values expanded from ALL the cached
+    rows once (``mla/expand``; ``[L, 8 heads, 192 + 128]`` is 0.18 GB at
+    35k tokens where all 128 heads' would be 2.9), then the Pallas kernel
+    ``kernels/latent_attention.latent_flash`` (causal behind the cached
+    context, the one rotary key shared by the group's heads, a value width
+    of its own), whose score tiles never leave VMEM."""
+    from ..kernels.latent_attention import latent_flash
+
+    B, T, _ = cq.shape
+    L = c_view.shape[1]
+    H, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    G = np.gcd(H, _LATENT_HEAD_GROUP)
+    by_head = lambda w, d: p[pre + w].reshape(-1, H, d)
+    wq, wk, wv = by_head(".wq_b", dn + dr), by_head(".wk_b", dn), \
+        by_head(".wv_b", dv)
+    scale = jnp.asarray(softmax_scale(cfg, dn + dr), cq.dtype)
+    # [B, G, rows, d] -> the kernel's [B * G, rows, d]
+    fold = lambda a: a.reshape((B * G,) + a.shape[2:])
+
+    def group(i, out):
+        cut = lambda w: lax.dynamic_slice_in_dim(w, i * G, G, axis=1)
+        q = jnp.einsum("btr,rhd->bthd", cq, cut(wq))
+        qn = (q[..., :dn] * scale).transpose(0, 2, 1, 3)
+        qp = (POSITIONS[cfg.position](cfg, q[..., dn:], qpos)
+              * scale).transpose(0, 2, 1, 3)
+        with jax.named_scope("mla/expand"):
+            kn = jnp.einsum("blr,rhd->bhld", c_view, cut(wk))
+            v = jnp.einsum("blr,rhd->bhld", c_view, cut(wv))
+        o = latent_flash(fold(qn), fold(qp), fold(kn), kpe_view, fold(v),
+                         qpos[:, 0], G)
+        o = o.reshape(B, G, T, dv).transpose(0, 2, 1, 3).reshape(B, T, G * dv)
+        return lax.dynamic_update_slice_in_dim(out, o, i * G * dv, axis=2)
+
+    o = lax.fori_loop(0, H // G, group, jnp.zeros((B, T, H * dv), cq.dtype))
+    return _mm(o, p[pre + ".wo"])
+
+
+def latent_attend(cfg, p, pre, cq, c_view, kpe_view, qpos):
+    """The expanded form of latent attention, for prefill and extend:
+    queries from the normed latents ``cq [B, T, rq]`` at ``qpos [B, T]``
+    against the views ``c_view [B, L, rkv]`` / ``kpe_view [B, L, dr]`` of
+    the cached latents (view position = sequence position), through ``wo``:
+    ``[B, T, hidden]`` out. On the TPU ``_latent_attend_flash``
+    (``serving.kv_cache.default_paged_impl`` says which); elsewhere, and as
+    its oracle, plain ``jax.numpy``: queries go in chunks of
+    ``cfg.query_chunk`` (projected from ``cq``, attended and put through
+    ``wo`` chunk by chunk: a 34k-token prompt's q alone would be 1.7 GB),
+    and each chunk walks the keys in blocks of ``_LATENT_KEY_BLOCK``, as far as
+    its last query sees: a block's keys and values are expanded from its
+    latents (``mla/expand``), scored, and folded into a float32 online
+    softmax, so no expanded key outlives its block. Numerics as ``attend``:
+    q pre-scaled in its own dtype, float32 scores, -1e30 mask."""
+    from ..serving import kv_cache as _kvc
+
+    if _kvc.default_paged_impl() == "pallas":
+        return _latent_attend_flash(cfg, p, pre, cq, c_view, kpe_view, qpos)
+    B, T, _ = cq.shape
+    L = c_view.shape[1]
+    H, dn, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    rkv = cfg.kv_lora_rank
+    wk = p[pre + ".wk_b"].reshape(rkv, H, dn)
+    wv = p[pre + ".wv_b"].reshape(rkv, H, dv)
+    Kb = _LATENT_KEY_BLOCK if L % _LATENT_KEY_BLOCK == 0 else L
+    f32 = jnp.float32
+
+    def chunk(cqc, pc):
+        C = cqc.shape[1]
+        qn, qp = _latent_queries(cfg, p, pre, cqc, pc)
+        scale = jnp.asarray(softmax_scale(cfg, dn + cfg.qk_rope_head_dim),
+                            cqc.dtype)
+        qn, qp = qn * scale, qp * scale
+
+        def block(j, carry):
+            m, l, acc = carry
+            cut = lambda a: lax.dynamic_slice_in_dim(a, j * Kb, Kb, axis=1)
+            cb, kp = cut(c_view), cut(kpe_view)
+            with jax.named_scope("mla/expand"):
+                kn = jnp.einsum("bkr,rhd->bkhd", cb, wk)
+                v = jnp.einsum("bkr,rhd->bkhd", cb, wv)
+            s = jnp.einsum("bqhd,bkhd->bhqk", qn, kn,
+                           preferred_element_type=f32) \
+                + jnp.einsum("bqhd,bkd->bhqk", qp, kp,
+                             preferred_element_type=f32)
+            kpos = j * Kb + jnp.arange(Kb, dtype=jnp.int32)
+            s = jnp.where((kpos[None, None, :] <= pc[:, :, None])[:, None],
+                          s, _NEG_INF)
+            m_new = jnp.maximum(m, s.max(-1))
+            w = jnp.exp(s - m_new[..., None])
+            a = jnp.exp(m - m_new)
+            pv = jnp.einsum("bhqk,bkhd->bhqd", w.astype(v.dtype), v,
+                            preferred_element_type=f32)
+            return m_new, l * a + w.sum(-1), acc * a[..., None] + pv
+
+        # key 0 is behind every query, so no row of a walked block's
+        # running maximum stays at the mask's value
+        blocks = jnp.minimum(jnp.max(pc) // Kb + 1, L // Kb)
+        _, l, acc = lax.fori_loop(
+            0, blocks, block,
+            (jnp.full((B, H, C), _NEG_INF, f32), jnp.zeros((B, H, C), f32),
+             jnp.zeros((B, H, C, dv), f32)))
+        o = (acc / l[..., None]).astype(cqc.dtype)
+        return _mm(o.transpose(0, 2, 1, 3).reshape(B, C, H * dv),
+                   p[pre + ".wo"])
+
+    C = cfg.query_chunk if T % cfg.query_chunk == 0 else T
+    if C == T:
+        return chunk(cq, qpos)
+
+    def body(i, out):
+        cut = lambda a: lax.dynamic_slice_in_dim(a, i * C, C, axis=1)
+        return lax.dynamic_update_slice_in_dim(
+            out, chunk(cut(cq), cut(qpos)), i * C, axis=1)
+
+    return lax.fori_loop(0, T // C, body,
+                         jnp.zeros((B, T, cfg.hidden_size), cq.dtype))
+
+
+def latent_attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
+                     lengths=None, cuts=None):
+    """A latent-attention layer (MLA; the module's docstring has the
+    equations) over ``h [B, T, hidden]`` whose tokens sit at ``start[b] ..
+    start[b] + T - 1``. What it keeps a token is ONE row, ``[c | k_pe]``
+    (``latent_pool_width`` lanes), keys and values alike. Prefill and
+    extend run the expanded form over the rows (``latent_attend``); decode
+    (``T = 1`` over a cache) never expands: the absorbed form, ``wk_b``
+    folded into the query and ``wv_b`` applied to the attended latents
+    (``serving.kv_cache.latent_decode_attend``: the Pallas kernel
+    ``kernels/latent_attention.latent_paged_decode`` or its oracle).
+    Returns (out, new) as ``attention`` does."""
+    from ..serving import kv_cache as _kvc
+
+    B, T, _ = h.shape
+    H, rkv = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    W = latent_pool_width(cfg)
+    pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    cq = rms_norm(_mm(h, p[pre + ".wq_a"]), p, pre + ".q_norm", cfg.norm_eps)
+    ckv = _mm(h, p[pre + ".wkv_a"])
+    c = rms_norm(ckv[..., :rkv], p, pre + ".kv_norm", cfg.norm_eps)
+    kpe = POSITIONS[cfg.position](cfg, ckv[..., None, rkv:], pos)[:, :, 0]
+    fresh = jnp.concatenate(
+        [c, kpe, jnp.zeros((B, T, W - rkv - dr), c.dtype)], axis=-1)[:, None]
+    if cache is None:
+        return latent_attend(cfg, p, pre, cq, c, kpe, pos), (fresh,)
+
+    pool, table = cache
+    pool = _kvc.paged_write_kv(pool, fresh, table, start)
+    if T > 1:
+        rows = _kvc.paged_gather(pool, table)[:, 0]            # [B, L, W]
+        return latent_attend(cfg, p, pre, cq, rows[..., :rkv],
+                             rows[..., rkv:rkv + dr], pos), (pool,)
+    qn, qp = _latent_queries(cfg, p, pre, cq, pos)
+    with jax.named_scope("mla/absorb"):
+        ql = jnp.einsum("bhd,rhd->bhr", qn[:, 0],
+                        p[pre + ".wk_b"].reshape(rkv, H, dn))
+    q = jnp.concatenate(
+        [ql, qp[:, 0], jnp.zeros((B, H, W - rkv - dr), ql.dtype)], axis=-1)
+    with jax.named_scope("mla/decode"):
+        ol = _kvc.latent_decode_attend(
+            q * jnp.asarray(softmax_scale(cfg, dn + dr), q.dtype), pool,
+            table, start, rkv)                                 # [B, H, rkv]
+    with jax.named_scope("mla/absorb"):
+        o = jnp.einsum("bhr,rhd->bhd", ol,
+                       p[pre + ".wv_b"].reshape(rkv, H, dv))
+    return _mm(o.reshape(B, 1, H * dv), p[pre + ".wo"]), (pool,)
+
+
 #: kind -> (the layer's function, its parameters' shapes, its paged pools
 #: [(name, heads, width)], its slot state [(name, shape, dtype)])
 ATTENTIONS = {
@@ -631,6 +973,8 @@ ATTENTIONS = {
                        functools.partial(_kv_pools, sparse=True),
                        lambda c: []),
     "gated_delta": (gated_delta, _gdn_shapes, lambda c: [], _gdn_state_pools),
+    "latent": (latent_attention, _latent_shapes,
+               lambda c: [("latent", 1, latent_pool_width(c))], lambda c: []),
 }
 
 
@@ -647,11 +991,38 @@ def softmax_topk(cfg, g, wr):
     return pw, e.astype(jnp.int32)
 
 
-ROUTERS = {"softmax_topk": softmax_topk}
+@jax.named_scope("router/group_topk")
+def sigmoid_group_topk(cfg, g, wr, bias):
+    """Router of DeepSeek-V3 (``noaux_tc``), float32: scores ``s =
+    sigmoid(g Wr)`` over ALL experts; the CHOICE is made on ``s + bias``
+    (the bias balances the load and weighs nothing): a group's score is the
+    sum of its two largest, the ``topk_group`` best of ``n_group`` groups
+    are kept, and the top-k taken inside them; the WEIGHTS are ``s`` of the
+    chosen, over their sum (``norm_topk_prob``), times
+    ``routed_scaling_factor``."""
+    f32 = jnp.float32
+    s = jax.nn.sigmoid(jnp.dot(g, wr, preferred_element_type=f32))
+    N, E = s.shape
+    G = cfg.n_group
+    choose = (s + bias.astype(f32)).reshape(N, G, E // G)
+    _, keep = lax.top_k(lax.top_k(choose, 2)[0].sum(-1), cfg.topk_group)
+    kept = jnp.any(keep[:, :, None] == jnp.arange(G)[None, None, :], axis=1)
+    _, e = lax.top_k(jnp.where(kept[:, :, None], choose, _NEG_INF)
+                     .reshape(N, E), cfg.experts_per_token)
+    pw = jnp.take_along_axis(s, e, axis=1)
+    if cfg.norm_topk_prob:
+        pw = pw / (jnp.sum(pw, axis=-1, keepdims=True) + 1e-20)
+    return pw * f32(cfg.routed_scaling_factor), e.astype(jnp.int32)
 
 
-def _swiglu_shapes(cfg, pre):
-    H, F = cfg.hidden_size, cfg.intermediate_size
+#: kind -> (the router's function of (cfg, g, the ``[hidden, experts]``
+#: matrix, *its other leaves), those leaves' names behind ``.router``)
+ROUTERS = {"softmax_topk": (softmax_topk, ()),
+           "sigmoid_group_topk": (sigmoid_group_topk, (".bias",))}
+
+
+def _swiglu_shapes(cfg, pre, F):
+    H = cfg.hidden_size
     return {pre + ".w1": (H, F), pre + ".w3": (H, F), pre + ".w2": (F, H)}
 
 
@@ -662,14 +1033,17 @@ def _dense_swiglu(p, pre, g):
 
 def swiglu(cfg, p, pre, g):
     """Dense gated FFN over ``g [N, hidden]``; no routing statistics."""
-    return _dense_swiglu(p, pre, g), jnp.zeros((2,), jnp.int32)
+    return _dense_swiglu(p, pre, g), jnp.zeros((len(ffn_stats(cfg)),),
+                                               jnp.int32)
 
 
-def _moe_shapes(cfg, pre):
-    H, F, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+def _moe_shapes(cfg, pre, F):
+    H, E = cfg.hidden_size, cfg.num_experts
     G = E if cfg.experts_held is None else cfg.experts_held[0]
     s = {pre + ".router": (H, E), pre + ".w1": (G, H, F),
          pre + ".w3": (G, H, F), pre + ".w2": (G, F, H)}
+    s.update({pre + ".router" + leaf: (E,)          # one value an expert
+              for leaf in ROUTERS[cfg.router][1]})
     if cfg.shared_experts:
         Fs = F * cfg.shared_experts
         s.update({pre + ".shared.w1": (H, Fs), pre + ".shared.w3": (H, Fs),
@@ -677,7 +1051,7 @@ def _moe_shapes(cfg, pre):
     return s
 
 
-def step_stats(cfg) -> Tuple[str, ...]:
+def ffn_stats(cfg) -> Tuple[str, ...]:
     """What a layer's FFN counts in a step (``moe_routed``'s statistics): a
     program told which experts it holds also counts the rows that landed on
     them, beside all the rows it routed."""
@@ -685,6 +1059,15 @@ def step_stats(cfg) -> Tuple[str, ...]:
     if cfg.experts_held is not None:
         names += ("local_rows", "routed_rows")
     return names
+
+
+def step_stats(cfg) -> Tuple[str, ...]:
+    """What a layer counts in a step: its FFN's statistics and, of a model
+    with latent layers, ``latent_tokens_read``: the cached tokens the step's
+    attention read, summed over the live slots (0 in a layer of another
+    kind)."""
+    return ffn_stats(cfg) + (("latent_tokens_read",)
+                             if "latent" in cfg.kinds else ())
 
 
 def moe_routed(cfg, p, pre, g):
@@ -701,7 +1084,9 @@ def moe_routed(cfg, p, pre, g):
 
     N, H = g.shape
     E, k = cfg.num_experts, cfg.experts_per_token
-    pw, e = ROUTERS[cfg.router](cfg, g, p[pre + ".router"])
+    route, leaves = ROUTERS[cfg.router]
+    pw, e = route(cfg, g, p[pre + ".router"],
+                  *(p[pre + ".router" + leaf] for leaf in leaves))
     G, local = E, None
     if cfg.experts_held is not None:
         G, first = cfg.experts_held
@@ -742,12 +1127,13 @@ FFNS = {"swiglu": (swiglu, _swiglu_shapes),
 _FFN_TOKEN_CHUNK = 2048
 
 
-def ffn(cfg, p, pre, g):
-    """``g [B, T, hidden]`` through the block's FFN, ``_FFN_TOKEN_CHUNK``
-    tokens at a time (a long prefill's sorted rows, eight a token, would
-    otherwise stand in memory whole). Statistics are the last chunk's."""
+def ffn(cfg, p, pre, g, kind):
+    """``g [B, T, hidden]`` through a block's FFN of ``kind``,
+    ``_FFN_TOKEN_CHUNK`` tokens at a time (a long prefill's sorted rows,
+    eight a token, would otherwise stand in memory whole). Statistics are
+    the last chunk's."""
     B, T, H = g.shape
-    fn = FFNS[cfg.ffn][0]
+    fn = FFNS[kind][0]
     flat = g.reshape(B * T, H)
     C = _FFN_TOKEN_CHUNK
     if B * T <= C or (B * T) % C:
@@ -761,7 +1147,7 @@ def ffn(cfg, p, pre, g):
 
     y, stats = lax.fori_loop(
         0, B * T // C, body,
-        (jnp.zeros_like(flat), jnp.zeros((len(step_stats(cfg)),), jnp.int32)))
+        (jnp.zeros_like(flat), jnp.zeros((len(ffn_stats(cfg)),), jnp.int32)))
     return y.reshape(B, T, H), stats
 
 
@@ -776,7 +1162,8 @@ def param_shapes(cfg: DecoderConfig) -> dict:
         s.update(_norm_shapes(cfg, pre + ".attn_norm", H))
         s.update(ATTENTIONS[kind][1](cfg, pre + ".attn"))
         s.update(_norm_shapes(cfg, pre + ".ffn_norm", H))
-        s.update(FFNS[cfg.ffn][1](cfg, pre + ".ffn"))
+        kind, width = cfg.ffns[l]
+        s.update(FFNS[kind][1](cfg, pre + ".ffn", width))
     s.update(_norm_shapes(cfg, "final_norm", H))
     if not cfg.tie_word_embeddings:
         s["head.weight"] = (H, cfg.vocab_size)
@@ -815,18 +1202,30 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
     (x, the layer's new pool entries, its routing statistics)."""
     pre = f"layers.{l}"
     mixer = ATTENTIONS[cfg.kinds[l]][0]
+    kind = cfg.ffns[l][0]
     if cfg.norm_placement == "post":
         a, new = mixer(cfg, p, pre + ".attn", x, start, cache, flash_ok,
                        lengths, cuts)
         x = x + _norm(cfg, a, p, pre + ".attn_norm")
-        y, stats = ffn(cfg, p, pre + ".ffn", x)
-        return x + _norm(cfg, y, p, pre + ".ffn_norm"), new, stats
-    a, new = mixer(cfg, p, pre + ".attn",
-                   _norm(cfg, x, p, pre + ".attn_norm"), start, cache,
-                   flash_ok, lengths, cuts)
-    x = x + a
-    y, stats = ffn(cfg, p, pre + ".ffn", _norm(cfg, x, p, pre + ".ffn_norm"))
-    return x + y, new, stats
+        y, stats = ffn(cfg, p, pre + ".ffn", x, kind)
+        x = x + _norm(cfg, y, p, pre + ".ffn_norm")
+    else:
+        a, new = mixer(cfg, p, pre + ".attn",
+                       _norm(cfg, x, p, pre + ".attn_norm"), start, cache,
+                       flash_ok, lengths, cuts)
+        x = x + a
+        y, stats = ffn(cfg, p, pre + ".ffn",
+                       _norm(cfg, x, p, pre + ".ffn_norm"), kind)
+        x = x + y
+    if "latent" in cfg.kinds:
+        read = jnp.zeros((), jnp.int32)
+        if cfg.kinds[l] == "latent" and cache is not None:
+            # a live slot (its first block is mapped) attends every cached
+            # token up to the last it wrote
+            live = cache[-1][:, 0] >= 0
+            read = jnp.sum(jnp.where(live, start + x.shape[1], 0))
+        stats = jnp.concatenate([stats, read[None].astype(jnp.int32)])
+    return x, new, stats
 
 
 class DecoderLM(Layer):

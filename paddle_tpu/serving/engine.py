@@ -1057,7 +1057,7 @@ class Engine:
         kind = "extend" if start else "prefill"
         self._launch_i += 1
         with _span("serving/admit/" + kind, request_id=req.request_id,
-                   tokens=m, bucket=T, launch=self._launch_i):
+                   tokens=m, bucket=T, start=start, launch=self._launch_i):
             ids = np.zeros((1, T), np.int32)
             ids[0, :m] = req.prompt_ids[start:end]
             # host scalars: ``jnp.int32(m)`` is a program of its own on the
@@ -1192,12 +1192,22 @@ class Engine:
                 # the table entries a grid over the table would
                 ctx = self._positions[[r.slot for r in running]] + 1
                 sel = getattr(self.model, "selected_tokens", None)
+                last = (ctx - 1) // self.cache.page_size   # a slot's last page
                 sp.set(draws=draws, ctx_tokens=int(ctx.sum()),
                        selected_tokens=int((ctx if sel is None
                                             else sel(ctx)).sum()),
-                       live_pages=int(((ctx - 1) // self.cache.page_size
-                                       + 1).sum()),
+                       live_pages=int((last + 1).sum()),
                        table_pages=B * self.cache.num_blocks)
+                if "latent_tokens_read" in getattr(self.model, "step_stats",
+                                                   ()):
+                    # the live pages counted ONCE each, however many slots
+                    # map them (sessions on one document): what a step's
+                    # attention has to bring in, a layer
+                    rows = self.cache.page_table[[r.slot for r in running]]
+                    live = np.arange(rows.shape[1])[None, :] <= last[:, None]
+                    seen = np.zeros((self.cache.num_pages,), bool)
+                    seen[rows[live]] = True
+                    sp.set(distinct_pages=int(seen.sum()))
             tokens, step_s = self._tokens, 0.0
             if spec is not None:
                 with _span("serving/decode/propose") as prop:

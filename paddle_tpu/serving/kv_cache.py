@@ -240,6 +240,31 @@ def paged_decode_attend(q, k_pool, v_pool, page_table, positions):
     return paged_attention(q, k_pool, v_pool, page_table, positions)
 
 
+def latent_decode_attend(q, pool, page_table, positions, value_width: int):
+    """Single-position attention over a pool of LATENT rows (one row a
+    token, keys and values the same bytes: ``models/decoder``'s latent
+    layer in its absorbed form), in the tier ``default_paged_impl`` says.
+    ``q [B, H, W]`` is pre-scaled and as wide as the pool's rows ``[P, 1,
+    ps, W]``; a row's first ``value_width`` lanes are what is attended:
+    ``[B, H, value_width]`` out, in the pool's dtype. ``oracle`` gathers the
+    dense view and runs the einsums (float32 scores, -1e30 mask, float32
+    softmax); ``pallas`` is ``kernels/latent_attention.latent_paged_decode``,
+    which reads each live page once. An empty slot's row, which no caller
+    reads, is the trash page's first token here and zeros there."""
+    if default_paged_impl() == "pallas":
+        from ..kernels.latent_attention import latent_paged_decode
+
+        return latent_paged_decode(q, pool, page_table, positions,
+                                   value_width)
+    rows = paged_gather(pool, page_table)[:, 0]                # [B, L, W]
+    s = jnp.einsum("bhw,blw->bhl", q, rows,
+                   preferred_element_type=jnp.float32)
+    valid = jnp.arange(rows.shape[1])[None, :] <= positions[:, None]
+    s = jnp.where(valid[:, None, :], s, _NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1).astype(rows.dtype)
+    return jnp.einsum("bhl,blv->bhv", probs, rows[..., :value_width])
+
+
 def extend_attend(q, k_cache, v_cache, positions):
     """Multi-query cached attention: q ``[B, H_q, T, D]`` where query ``t``
     of row ``b`` sits at absolute position ``positions[b] + t`` and may
