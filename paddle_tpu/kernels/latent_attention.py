@@ -15,16 +15,42 @@ result by the caller. So every head reads the SAME bytes: the ``H`` query
 heads are the rows of one ``[H, W] x [W, tokens]`` matmul a chunk, and a row
 is fetched once for scores and values.
 
-The walk is ``paged_attention.py``'s: the pool ``[P, 1, page_size, W]``
-stays in HBM, the page table and the positions ride as scalar-prefetch
-operands, the grid is one step a slot, and inside it a ``fori_loop`` over
-CHUNKS of the slot's own live pages, each page fetched by the kernel's own
+The walk is ``paged_attention.py``'s, a tile of slots at a time: the pool
+``[P, 1, page_size, W]`` stays in HBM, the page table, the positions and a
+PLAN ride as scalar-prefetch operands, and pages come in by the kernel's own
 ``make_async_copy`` into one of two VMEM buffers, chunk ``c + 1`` in flight
 while chunk ``c`` is computed. A chunk is ``_CHUNK_TOKENS`` tokens (not one
 lane width as there: with one row a token the per-chunk overhead would lead,
-and a 512 x 640 bfloat16 buffer is 0.6 MiB). Pages of a chunk past the live
-count are not fetched and their rows zeroed; a dead slot makes no trip and
-yields zeros.
+and a 512 x 640 bfloat16 buffer is 0.6 MiB).
+
+Slots whose tables begin with the same physical pages (sessions on one
+document that the prefix cache holds once) need not each fetch those pages
+and multiply them by 128 rows of their own. ``shared_walk_plan`` reads from
+the table and the positions alone which slots those are: slots with the same
+first page are a group, a group is cut into TILES of at most
+``_TILE_MEMBERS`` slots, and a tile's ``shared`` pages are the leading table
+entries that are equal for all its members and lie wholly at or below every
+member's position, in whole chunks. The grid has one step a PLACE of the
+plan's order (a tile's members stand side by side in it); the step at a
+tile's first place does the tile's whole walk, in two phases on ONE running
+float32 (max, sum, acc) a row, kept in VMEM scratch:
+
+1. the shared pages, each fetched once: a chunk's rows are scored by
+   ``[_BLOCK_MEMBERS x H, W] x [W, chunk]`` matmuls, a block of members'
+   heads the rows of one, with no mask (every member sees every token) and
+   no branch around a copy (every page is live);
+2. each member's own pages behind them, on that member's ``H`` rows: a chunk
+   whose pages are all live is fetched as in phase one, the member's last
+   one page by page (pages past its live count are not fetched and their
+   rows zeroed), every chunk masked at the member's position.
+
+So there is no partial softmax to merge and nothing of the attention outside
+the kernel; a row's chunks are the ones a walk of its own would have made.
+Every step then hands its own slot's rows to its output block. With nothing
+shared every tile has one member and phase one makes no trip; a dead slot is
+in no tile and yields zeros. The plan is a few dozen small XLA ops: a model
+makes it once a decode step for all its layers
+(``serving.kv_cache.latent_decode_plan``) and hands it in.
 
 Numerics mirror ``serving.kv_cache.latent_decode_attend``'s oracle: q
 pre-scaled in its own dtype, float32 scores (exp2 domain), float32 online
@@ -64,86 +90,253 @@ from .mesh import shard_kernel
 
 #: tokens fetched and computed together
 _CHUNK_TOKENS = 512
+#: slots a tile holds at most: the members whose shared pages are walked once
+_TILE_MEMBERS = 16
+#: members a matmul of the shared walk scores together (their heads its rows)
+_BLOCK_MEMBERS = 4
+
+#: the rows of a plan (``shared_walk_plan``)
+_ORDER, _TILE, _COUNT, _SHARED = range(4)
 
 
-def _kernel(tbl_ref, pos_ref, q_ref, pool_hbm, o_ref, buf, sems, *,
-            num_blocks: int, page_size: int, chunk: int, value_width: int):
-    """Grid (B,): one step a slot. ``buf`` is ``[2, chunk, page_size, W]``
-    VMEM, ``sems`` two DMA semaphores (one a buffer); the running (max, sum,
-    acc) are the loop's carry."""
-    b = pl.program_id(0)
-    pos = pos_ref[b]
-    live = jnp.where(tbl_ref[b, 0] < 0, 0,
-                     jnp.minimum(pos // page_size + 1, num_blocks))
+def shared_walk_plan(page_table, positions, page_size: int):
+    """Which slots walk which leading pages TOGETHER, from the page table
+    and the positions alone: ``[4, B]`` int32, indexed by a slot's place
+    ``r`` in the ORDER the kernel's grid runs.
+
+    Slots whose tables begin with the same physical page form a group (the
+    prefix cache maps a shared prefix to the same page ids from entry 0);
+    the group's slots stand side by side in the order, those that share
+    most first, and every ``_TILE_MEMBERS`` of them are a TILE. Rows:
+    ``order`` (the slot at place r; live slots first), ``tile`` (the place
+    of r's tile's first member), ``count`` (the tile's members; 0 for a
+    dead slot) and ``shared`` (pages, in whole chunks: the leading table
+    entries that are the same for all the tile's members and lie wholly at
+    or below every member's position, so the shared walk needs no mask; 0
+    for a tile of one)."""
+    table = page_table.astype(jnp.int32)
+    B, nb = table.shape
+    chunk = max(1, _CHUNK_TOKENS // page_size)
+    idx = jnp.arange(B, dtype=jnp.int32)
+    alive = table[:, 0] >= 0
+    same = (table[:, :1] == table[None, :, 0]) & alive[:, None] & alive[None]
+    # a group's leader is its lowest slot; a slot may share with the others
+    # what it shares with the leader, whole pages at or below its position
+    leader = jnp.where(alive, jnp.argmax(same, axis=1).astype(jnp.int32), idx)
+    entry = jnp.arange(nb, dtype=jnp.int32)[None]
+    agree = jnp.min(jnp.where(table != table[leader], entry, nb), axis=1)
+    lim = jnp.minimum(agree,
+                      (jnp.asarray(positions, jnp.int32) + 1) // page_size)
+    lim = jnp.where(alive, lim // chunk * chunk, 0)
+    # places: by group (dead slots behind all), then most shared first, then
+    # by slot; counted, not sorted (B x B compares)
+    group = jnp.where(alive, leader, B)
+    before = (group[None] < group[:, None]) | (
+        (group[None] == group[:, None]) & (
+            (lim[None] > lim[:, None]) | (
+                (lim[None] == lim[:, None]) & (idx[None] < idx[:, None]))))
+    place = jnp.sum(before, axis=1, dtype=jnp.int32)
+    order = jnp.argmax(place[None] == idx[:, None], axis=1).astype(jnp.int32)
+    group, lim, alive = group[order], lim[order], alive[order]
+    first = jnp.argmax(group[None] == group[:, None], axis=1).astype(jnp.int32)
+    tile = jnp.where(alive, idx - (idx - first) % _TILE_MEMBERS, idx)
+    mates = (tile[None] == tile[:, None]) & alive[None] & alive[:, None]
+    count = jnp.sum(mates, axis=1, dtype=jnp.int32)
+    shared = jnp.min(jnp.where(mates, lim[None], nb), axis=1)
+    shared = jnp.where(count > 1, shared, 0)
+    return jnp.stack([order, tile, count, shared])
+
+
+def shared_walk_tokens(plan, page_size: int):
+    """Context tokens a plan's tiles of two members or more score in their
+    shared walk, summed over the members (one layer)."""
+    return jnp.sum(plan[_SHARED]) * page_size
+
+
+def _kernel(tbl_ref, pos_ref, plan_ref, q_hbm, pool_hbm, o_ref, qbuf, buf,
+            m_scr, l_scr, acc_scr, out_scr, sems, qsem, *, num_blocks: int,
+            page_size: int, chunk: int, value_width: int, block: int):
+    """Grid (B,): one step a PLACE of the plan's order, run one after the
+    other. The step at a tile's first place does the tile's whole walk into
+    ``out_scr [_TILE_MEMBERS, H, value_width]``; every step hands its own
+    slot's rows to the output block (``order[r]``). ``qbuf [_TILE_MEMBERS *
+    H, W]`` holds the members' queries, ``buf [2, chunk, page_size, W]`` the
+    pages in flight, ``sems`` two DMA semaphores (one a buffer), ``qsem``
+    the queries'; the running (max, sum, acc) of all the members' rows are
+    ``m_scr``, ``l_scr``, ``acc_scr``, updated in place by both phases."""
+    r = pl.program_id(0)
+    tile, count = plan_ref[_TILE, r], plan_ref[_COUNT, r]
+    H, W = q_hbm.shape[1:]
     chunk_tokens = chunk * page_size
-    H, W = q_ref.shape[1:]
 
-    def page_copy(i, slot, j):
+    def page_copy(b, i, slot, k):
         # a sentinel inside the live range clamps to the trash page
         page = jnp.maximum(tbl_ref[b, i], 0)
-        return pltpu.make_async_copy(pool_hbm.at[page, 0], buf.at[slot, j],
+        return pltpu.make_async_copy(pool_hbm.at[page, 0], buf.at[slot, k],
                                      sems.at[slot])
 
-    def fetch(c, slot):
-        for j in range(chunk):
-            i = c * chunk + j
+    def whole_chunk(b, c, slot, do: str):
+        """Start (or wait for) the copy of every page of slot b's chunk c,
+        all of them live: one basic block, so the copies' scalar work is
+        scheduled together."""
+        for k in range(chunk):
+            getattr(page_copy(b, c * chunk + k, slot, k), do)()
 
-            @pl.when(i < live)
-            def _start():
-                page_copy(i, slot, j).start()
+    def q_copies(do: str):
+        def one(j, done):
+            getattr(pltpu.make_async_copy(
+                q_hbm.at[plan_ref[_ORDER, tile + j]],
+                qbuf.at[pl.ds(pl.multiple_of(j * H, H), H)], qsem), do)()
+            return done
 
-            @pl.when(i >= live)
-            def _blank():
-                # never fetched: its weight is exactly 0, but 0 x whatever
-                # the buffer held (it starts uninitialised) need not be
-                buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
+        jax.lax.fori_loop(0, count, one, 0)
 
-    def wait(c, slot):
-        for j in range(chunk):
-            @pl.when(c * chunk + j < live)
-            def _wait():
-                page_copy(c * chunk + j, slot, j).wait()
-
-    q = q_ref[0]                                   # [H, W], pre-scaled
-
-    def body(c, carry):
-        m, l, acc = carry
-        slot = c % 2
-
-        @pl.when((c + 1) * chunk < live)
-        def _prefetch():
-            fetch(c + 1, 1 - slot)
-
-        wait(c, slot)
-        rows = buf[slot].reshape(chunk_tokens, W)
-        s = jax.lax.dot_general(
+    def scores(q, rows):
+        return jax.lax.dot_general(
             q, rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * jnp.float32(LOG2E)
-        tok = c * chunk_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, (H, chunk_tokens), 1)
-        s = jnp.where(tok <= pos, s, NEG_INF)      # [H, chunk_tokens], log2
+
+    def fold(rs, s, rows):
+        """One chunk's scores ``s`` (log2 domain) of the state's rows ``rs``
+        into their running (max, sum, acc)."""
+        m = m_scr[rs]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
         pv = jax.lax.dot_general(
             p.astype(rows.dtype), rows[:, :value_width],
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
-                acc * alpha + pv)
+        m_scr[rs] = m_new
+        l_scr[rs] = l_scr[rs] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[rs] = acc_scr[rs] * alpha + pv
 
-    @pl.when(live > 0)
-    def _first():
-        fetch(0, 0)
+    @pl.when((tile == r) & (count > 0))
+    def _walk():
+        q_copies("start")
 
-    _, l, acc = jax.lax.fori_loop(
-        0, (live + chunk - 1) // chunk, body,
-        (jnp.full((H, 1), NEG_INF, jnp.float32),
-         jnp.zeros((H, 1), jnp.float32),
-         jnp.zeros((H, value_width), jnp.float32)))
-    o_ref[0] = (acc / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
+        def reset(i, done):
+            rs = pl.ds(pl.multiple_of(i * H, H), H)
+            m_scr[rs] = jnp.full((H, 1), NEG_INF, jnp.float32)
+            l_scr[rs] = jnp.zeros((H, 1), jnp.float32)
+            acc_scr[rs] = jnp.zeros((H, value_width), jnp.float32)
+            return done
+
+        # whole blocks: a block's rows past the tile's members are computed
+        # and never read
+        jax.lax.fori_loop(0, (count + block - 1) // block * block, reset, 0)
+        q_copies("wait")
+
+        # ---- phase one, the shared pages: each fetched once (through the
+        # first member's table), a block of members' heads the rows of one
+        # matmul
+        lead = plan_ref[_ORDER, tile]
+        shared = plan_ref[_SHARED, r] // chunk              # chunks
+
+        @pl.when(shared > 0)
+        def _first_shared():
+            whole_chunk(lead, 0, 0, "start")
+
+        def shared_body(c, done):
+            slot = c % 2
+
+            @pl.when(c + 1 < shared)
+            def _prefetch():
+                whole_chunk(lead, c + 1, 1 - slot, "start")
+
+            whole_chunk(lead, c, slot, "wait")
+
+            def rows_block(i, done):
+                rs = pl.ds(pl.multiple_of(i * block * H, block * H),
+                           block * H)
+                rows = buf[slot].reshape(chunk_tokens, W)
+                fold(rs, scores(qbuf[rs], rows), rows)
+                return done
+
+            return jax.lax.fori_loop(0, (count + block - 1) // block,
+                                     rows_block, done)
+
+        jax.lax.fori_loop(0, shared, shared_body, 0)
+
+        # ---- phase two, each member's own pages behind them, on its rows
+        # of the same running state
+        def member(j, done):
+            b = plan_ref[_ORDER, tile + j]
+            pos = pos_ref[b]
+            live = jnp.minimum(pos // page_size + 1, num_blocks)
+            rs = pl.ds(pl.multiple_of(j * H, H), H)
+            q = qbuf[rs]                                   # [H, W], pre-scaled
+
+            def fetch(c, slot):
+                @pl.when((c + 1) * chunk <= live)
+                def _whole():
+                    whole_chunk(b, c, slot, "start")
+
+                @pl.when((c + 1) * chunk > live)
+                def _last():
+                    for k in range(chunk):
+                        i = c * chunk + k
+
+                        @pl.when(i < live)
+                        def _start():
+                            page_copy(b, i, slot, k).start()
+
+                        @pl.when(i >= live)
+                        def _blank():
+                            # never fetched: its weight is exactly 0, but
+                            # 0 x whatever the buffer held need not be
+                            buf[slot, k] = jnp.zeros(buf.shape[2:],
+                                                     buf.dtype)
+
+            def wait(c, slot):
+                @pl.when((c + 1) * chunk <= live)
+                def _whole():
+                    whole_chunk(b, c, slot, "wait")
+
+                @pl.when((c + 1) * chunk > live)
+                def _last():
+                    for k in range(chunk):
+                        @pl.when(c * chunk + k < live)
+                        def _wait():
+                            page_copy(b, c * chunk + k, slot, k).wait()
+
+            def body(c, done):
+                slot = c % 2
+
+                @pl.when((c + 1) * chunk < live)
+                def _prefetch():
+                    fetch(c + 1, 1 - slot)
+
+                wait(c, slot)
+                rows = buf[slot].reshape(chunk_tokens, W)
+                s = scores(q, rows)
+                tok = c * chunk_tokens + jax.lax.broadcasted_iota(
+                    jnp.int32, (H, chunk_tokens), 1)
+                fold(rs, jnp.where(tok <= pos, s, NEG_INF), rows)
+                return done
+
+            @pl.when(shared * chunk < live)
+            def _first():
+                fetch(shared, shared % 2)
+
+            jax.lax.fori_loop(shared, (live + chunk - 1) // chunk, body, 0)
+            l = l_scr[rs]
+            out_scr[j] = (acc_scr[rs] / jnp.where(l == 0, 1.0, l)).astype(
+                out_scr.dtype)
+            return done
+
+        jax.lax.fori_loop(0, count, member, 0)
+
+    @pl.when(count > 0)
+    def _live():
+        o_ref[0] = out_scr[r - tile]
+
+    @pl.when(count == 0)
+    def _dead():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
 
-def latent_paged_decode(q, pool, page_table, positions, value_width: int):
+def latent_paged_decode(q, pool, page_table, positions, value_width: int,
+                        plan=None):
     """One query token a slot against the slot's live latent rows.
 
     q            ``[B, H, W]``, pre-scaled, as wide as a row (zeros in the
@@ -151,49 +344,67 @@ def latent_paged_decode(q, pool, page_table, positions, value_width: int):
     pool         ``[P, 1, page_size, W]``: this layer's latent pool
     page_table   ``[B, num_blocks]`` int32 pool page ids (-1 = unallocated)
     positions    ``[B]`` int32: each slot's current token index
+    plan         ``shared_walk_plan`` of the table and the positions, where
+                 the caller has it already (a model computes it once a step
+                 for all its layers)
 
     Returns ``[B, H, value_width]`` in the pool's dtype: per head the
     softmax-weighted sum of the rows' first ``value_width`` lanes."""
+    table = page_table.astype(jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    if plan is None:
+        plan = shared_walk_plan(table, pos, pool.shape[2])
     call = functools.partial(_decode_call, value_width=value_width,
                              interpret=pallas_interpret())
     # every head reads the same rows: nothing to divide over a mesh
-    return shard_kernel(call, (page_table.astype(jnp.int32),
-                               jnp.asarray(positions, jnp.int32), q, pool),
-                        (P(),) * 4, lambda fitted: P())
+    return shard_kernel(call, (table, pos, plan, q, pool), (P(),) * 5,
+                        lambda fitted: P())
 
 
 # jitted so that a model's layers share ONE trace and ONE Mosaic lowering in
 # the program they are traced into (as ``paged_attention._decode_call``)
 @functools.partial(jax.jit, static_argnames=("value_width", "interpret"))
-def _decode_call(table, pos, q, pool, *, value_width: int, interpret: bool):
+def _decode_call(table, pos, plan, q, pool, *, value_width: int,
+                 interpret: bool):
     B, H, W = q.shape
     _, _, page_size, _ = pool.shape
     chunk = max(1, _CHUNK_TOKENS // page_size)
+    members = _TILE_MEMBERS
+    block = min(_BLOCK_MEMBERS, members)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, W), lambda b, _tbl, _pos: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, value_width),
-                               lambda b, _tbl, _pos: (b, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, H, value_width),
+            lambda r, _tbl, _pos, plan: (plan[_ORDER, r], 0, 0)),
         scratch_shapes=[
+            pltpu.VMEM((members * H, W), q.dtype),
             pltpu.VMEM((2, chunk, page_size, W), pool.dtype),
+            pltpu.VMEM((members * H, 1), jnp.float32),
+            pltpu.VMEM((members * H, 1), jnp.float32),
+            pltpu.VMEM((members * H, value_width), jnp.float32),
+            pltpu.VMEM((members, H, value_width), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
         ],
     )
     return pl.pallas_call(
         functools.partial(_kernel, num_blocks=table.shape[1],
                           page_size=page_size, chunk=chunk,
-                          value_width=value_width),
+                          value_width=value_width, block=block),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, value_width), pool.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            # a tile's later places read what its first one left in scratch
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="latent_paged_decode",
-    )(table, pos, q, pool)
+    )(table, pos, plan, q, pool)
 
 
 # ------------------------------------------------- the expanded form

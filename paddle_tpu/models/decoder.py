@@ -941,7 +941,7 @@ def latent_attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     if cache is None:
         return latent_attend(cfg, p, pre, cq, c, kpe, pos), (fresh,)
 
-    pool, table = cache
+    pool, table, *plan = cache      # a decode step's shared-walk plan
     pool = _kvc.paged_write_kv(pool, fresh, table, start)
     if T > 1:
         rows = _kvc.paged_gather(pool, table)[:, 0]            # [B, L, W]
@@ -956,7 +956,7 @@ def latent_attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     with jax.named_scope("mla/decode"):
         ol = _kvc.latent_decode_attend(
             q * jnp.asarray(softmax_scale(cfg, dn + dr), q.dtype), pool,
-            table, start, rkv)                                 # [B, H, rkv]
+            table, start, rkv, *plan)                          # [B, H, rkv]
     with jax.named_scope("mla/absorb"):
         o = jnp.einsum("bhr,rhd->bhd", ol,
                        p[pre + ".wv_b"].reshape(rkv, H, dv))
@@ -1064,9 +1064,12 @@ def ffn_stats(cfg) -> Tuple[str, ...]:
 def step_stats(cfg) -> Tuple[str, ...]:
     """What a layer counts in a step: its FFN's statistics and, of a model
     with latent layers, ``latent_tokens_read``: the cached tokens the step's
-    attention read, summed over the live slots (0 in a layer of another
-    kind)."""
-    return ffn_stats(cfg) + (("latent_tokens_read",)
+    attention read, summed over the live slots, and ``shared_walk_tokens``:
+    those of them that slots on one document scored TOGETHER, each page
+    fetched once for all of them (the sum over the step's plan,
+    ``serving.kv_cache.latent_decode_plan``: 0 in the oracle tier, which
+    has none); both 0 in a layer of another kind."""
+    return ffn_stats(cfg) + (("latent_tokens_read", "shared_walk_tokens")
                              if "latent" in cfg.kinds else ())
 
 
@@ -1218,13 +1221,19 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
                        _norm(cfg, x, p, pre + ".ffn_norm"), kind)
         x = x + y
     if "latent" in cfg.kinds:
-        read = jnp.zeros((), jnp.int32)
+        read = walk = jnp.zeros((), jnp.int32)
         if cfg.kinds[l] == "latent" and cache is not None:
             # a live slot (its first block is mapped) attends every cached
             # token up to the last it wrote
-            live = cache[-1][:, 0] >= 0
+            pool, table, *plan = cache
+            live = table[:, 0] >= 0
             read = jnp.sum(jnp.where(live, start + x.shape[1], 0))
-        stats = jnp.concatenate([stats, read[None].astype(jnp.int32)])
+            if plan:
+                from ..kernels.latent_attention import shared_walk_tokens
+
+                walk = shared_walk_tokens(plan[0], pool.shape[2])
+        stats = jnp.concatenate(
+            [stats, jnp.stack([read, walk]).astype(jnp.int32)])
     return x, new, stats
 
 
@@ -1294,6 +1303,17 @@ class DecoderLM(Layer):
         cfg, p = self.cfg, self._p()
         x = p["embed.weight"][ids]
         news, stats = [], []
+        latent = [l for l, k in enumerate(cfg.kinds) if k == "latent"]
+        if caches is not None and latent and ids.shape[1] == 1:
+            # a decode step over latent pools: which slots walk which pages
+            # together is read from the table ONCE, for every layer
+            from ..serving.kv_cache import latent_decode_plan
+
+            pool, table = caches[latent[0]]
+            plan = latent_decode_plan(table, start, pool.shape[2])
+            if plan is not None:
+                caches = [e + (plan,) if l in latent else e
+                          for l, e in enumerate(caches)]
         for l in range(cfg.num_layers):
             x, new, st = block(cfg, p, l, x, start,
                                None if caches is None else caches[l], flash_ok,

@@ -240,7 +240,22 @@ def paged_decode_attend(q, k_pool, v_pool, page_table, positions):
     return paged_attention(q, k_pool, v_pool, page_table, positions)
 
 
-def latent_decode_attend(q, pool, page_table, positions, value_width: int):
+def latent_decode_plan(page_table, positions, page_size: int):
+    """What a decode step's ``latent_decode_attend`` calls share, computed
+    once for all the model's layers: in the ``pallas`` tier the kernel's
+    shared-walk plan (``kernels/latent_attention.shared_walk_plan``: which
+    slots map the same leading pages and score them together), read from
+    the table and the positions alone; None in the ``oracle`` tier, which
+    gathers every slot's own view."""
+    if default_paged_impl() != "pallas":
+        return None
+    from ..kernels.latent_attention import shared_walk_plan
+
+    return shared_walk_plan(page_table, positions, page_size)
+
+
+def latent_decode_attend(q, pool, page_table, positions, value_width: int,
+                         plan=None):
     """Single-position attention over a pool of LATENT rows (one row a
     token, keys and values the same bytes: ``models/decoder``'s latent
     layer in its absorbed form), in the tier ``default_paged_impl`` says.
@@ -249,13 +264,16 @@ def latent_decode_attend(q, pool, page_table, positions, value_width: int):
     ``[B, H, value_width]`` out, in the pool's dtype. ``oracle`` gathers the
     dense view and runs the einsums (float32 scores, -1e30 mask, float32
     softmax); ``pallas`` is ``kernels/latent_attention.latent_paged_decode``,
-    which reads each live page once. An empty slot's row, which no caller
-    reads, is the trash page's first token here and zeros there."""
+    which fetches the leading pages that slots share ONCE for all of them
+    and scores them in one matmul (``plan``: ``latent_decode_plan``'s, where
+    the caller has it; the kernel's wrapper computes it otherwise), then each
+    slot's own pages. An empty slot's row, which no caller reads, is the
+    trash page's first token here and zeros there."""
     if default_paged_impl() == "pallas":
         from ..kernels.latent_attention import latent_paged_decode
 
         return latent_paged_decode(q, pool, page_table, positions,
-                                   value_width)
+                                   value_width, plan)
     rows = paged_gather(pool, page_table)[:, 0]                # [B, L, W]
     s = jnp.einsum("bhw,blw->bhl", q, rows,
                    preferred_element_type=jnp.float32)

@@ -193,6 +193,14 @@ def held(ids, x, w):
     src, dest, tg, nt, counts = plan_groups(ids, 40, 16)
     return grouped_matmul(x[src // 8], w, tg, nt, 16)
 out["gmm_held"] = sites(held, sds((1024,), jnp.int32), sds((128, 4096)), sds((40, 4096, 1280)))
+# the latent cell's widths: 64 slots of 128 heads over rows of 640 lanes, a
+# [64, 2240] table: the absorbed decode's shared walk (tiles of 16, 4 x 128
+# rows a matmul, 64 MiB of VMEM asked for)
+from paddle_tpu.kernels.latent_attention import latent_paged_decode
+out["latent_decode"] = sites(
+    lambda q, pool, t, p: latent_paged_decode(q, pool, t, p, 512),
+    sds((64, 128, 640)), sds((20481, 1, 16, 640)),
+    sds((64, 2240), jnp.int32), sds((64,), jnp.int32))
 f32 = sds((64, 256), jnp.float32)
 out["prim"] = {**sites(primitive.elementwise_kernel(lambda a, b: a + 2 * b), f32, f32),
                **sites(primitive.row_reduce_kernel(lambda acc, t: acc + t.sum(-1), 0.0), f32)}
@@ -238,6 +246,7 @@ def test_kernels_and_train_step_compile_for_a_tpu_topology():
     assert out["gdn_step"] == out["kda_step"] == {"gdn_decode_step": 1}
     assert out["paged_gqa"] == {"paged_decode": 1}
     assert out["gmm_held"] == {"moe_grouped_matmul": 1}
+    assert out["latent_decode"] == {"latent_paged_decode": 1}
     assert out["prim"] == {"prim_elementwise": 1, "prim_row_reduce": 1}
     for key in ("step_1", "step_dp2mp2"):  # the mesh must not lose a kernel
         assert set(out[key]) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",

@@ -377,6 +377,256 @@ class TestLatentPagedDecode:
         np.testing.assert_array_equal(got[3], got[4])
 
 
+# --------------------------------------- the shared walk and its plan
+
+def _walk_case(name):
+    """(q, pool, table, positions, value, the plan's (members, shared pages)
+    of every tile of two or more, by its first slot) for one shape of
+    sharing, at pages of 8 tokens, chunks of 2 pages and tiles of at most 4
+    (``small_walk``). A slot's pages behind what it shares are its own."""
+    B, nb = {"r_plus_one_and_one": 7}.get(name, 8), 10
+    table = np.full((B, nb), -1, np.int32)
+    pos = np.zeros((B,), np.int32)
+    own = iter(range(40, 200))
+
+    def slot(b, shared, total, last):
+        """``shared`` leading page ids, then pages of its own up to
+        ``total``; its position ``last`` tokens into its last page."""
+        table[b, :total] = list(shared) + [next(own) for _ in
+                                           range(total - len(shared))]
+        pos[b] = (total - 1) * PS + last
+
+    doc_a, doc_b = list(range(1, 7)), list(range(10, 14))
+    if name == "two_groups_scattered":
+        for b, total, last in ((6, 8, 3), (0, 7, 0), (3, 10, 7)):
+            slot(b, doc_a, total, last)
+        for b, total, last in ((5, 5, 2), (1, 6, 5)):
+            slot(b, doc_b, total, last)
+        slot(2, [], 3, 4)
+        slot(7, [], 1, 0)
+        want = {0: (3, 6), 1: (2, 4)}
+    elif name == "r_plus_one_and_one":
+        for b, total in ((1, 7), (2, 8), (3, 9), (5, 10), (6, 7)):
+            slot(b, doc_a, total, b)
+        slot(0, [], 4, 6)
+        want = {1: (4, 6)}        # the fifth walks alone: a tile of one
+    elif name == "dead_slot_and_sentinel":
+        for b, total in ((0, 7), (2, 8), (5, 9)):
+            slot(b, doc_b, total, 1)
+        table[2, 5] = -1          # inside slot 2's live range, behind the
+        slot(3, [], 5, 5)         # shared pages: the trash page's rows
+        table[3, 1] = -1
+        want = {0: (3, 4)}        # slots 1, 4, 6, 7 are dead
+    elif name == "shared_not_a_whole_chunk":
+        for b, total in ((1, 6), (4, 9), (7, 7)):
+            slot(b, doc_a[:5], total, 4)
+        want = {1: (3, 4)}        # the fifth page each walks for itself
+    elif name == "member_inside_the_shared_pages":
+        slot(0, doc_a, 8, 2)
+        slot(3, doc_a, 9, 7)
+        slot(5, doc_a[:4], 4, 1)  # 26 tokens into what the others share
+        want = {0: (3, 2)}        # 3 whole pages under it: one chunk
+    elif name == "copy_on_write":
+        slot(2, doc_a, 8, 3)
+        slot(4, doc_a[:4], 8, 3)  # the same document up to a copied page
+        slot(6, doc_a[:4], 7, 0)
+        want = {2: (3, 4)}
+    elif name == "nothing_shared":
+        for b in range(B):
+            slot(b, [], 1 + b, b)
+        want = {}
+    else:
+        raise KeyError(name)
+    k = jax.random.split(jax.random.PRNGKey(len(name)), 2)
+    pool = jax.random.normal(k[0], (200, 1, PS, 128), jnp.float32)
+    q = 0.3 * jax.random.normal(k[1], (B, 4, 128), jnp.float32)
+    return q, pool, jnp.asarray(table), jnp.asarray(pos), 64, want
+
+
+WALKS = ["two_groups_scattered", "r_plus_one_and_one",
+         "dead_slot_and_sentinel", "shared_not_a_whole_chunk",
+         "member_inside_the_shared_pages", "copy_on_write", "nothing_shared"]
+
+
+@pytest.fixture
+def small_walk(monkeypatch):
+    """Chunks of two pages, tiles of at most four members, two members a
+    matmul: the shapes of sharing fit a pool of 8-token pages."""
+    from paddle_tpu.kernels import latent_attention as la
+
+    monkeypatch.setattr(la, "_CHUNK_TOKENS", 2 * PS)
+    monkeypatch.setattr(la, "_TILE_MEMBERS", 4)
+    monkeypatch.setattr(la, "_BLOCK_MEMBERS", 2)
+    la._decode_call.clear_cache()
+    yield la
+    la._decode_call.clear_cache()
+
+
+class TestSharedWalk:
+    @pytest.mark.parametrize("name", WALKS)
+    def test_kernel_is_the_oracle(self, small_walk, name):
+        q, pool, table, pos, value, _ = _walk_case(name)
+        with kvc.use_paged_attention_impl("oracle"):
+            want = np.asarray(kvc.latent_decode_attend(q, pool, table, pos,
+                                                       value))
+        with kvc.use_paged_attention_impl("pallas"):
+            got = np.asarray(kvc.latent_decode_attend(q, pool, table, pos,
+                                                      value))
+        live = np.asarray(table[:, 0]) >= 0
+        np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+        assert not got[~live].any()
+
+    @pytest.mark.parametrize("name", WALKS)
+    def test_plan_is_read_from_the_table_and_the_positions(self, small_walk,
+                                                           name):
+        la = small_walk
+        _, _, table, pos, _, want = _walk_case(name)
+        order, tile, count, shared = np.asarray(
+            la.shared_walk_plan(table, pos, PS))
+        B = table.shape[0]
+        live = np.asarray(table[:, 0]) >= 0
+        assert sorted(order) == list(range(B))      # every slot, once
+        assert (count[~live[order]] == 0).all()
+        assert (count[live[order]] > 0).all()
+        tiles = {}
+        for r in range(B):
+            if count[r] > 1:
+                tiles.setdefault(int(tile[r]), []).append(r)
+        got = {}
+        for first, places in tiles.items():
+            assert places == list(range(first, first + count[first]))
+            assert len({int(shared[r]) for r in places}) == 1
+            assert count[first] <= la._TILE_MEMBERS
+            slots = order[places]
+            n = int(shared[first])
+            # what is walked together is the same pages, wholly under
+            # every member's position
+            assert (np.asarray(table)[slots, :n]
+                    == np.asarray(table)[slots[0], :n]).all()
+            assert (n * PS <= np.asarray(pos)[slots] + 1).all()
+            got[int(slots.min())] = (len(places), n)
+        assert got == want
+        assert (shared[count <= 1] == 0).all()
+        assert int(la.shared_walk_tokens(jnp.asarray(
+            [order, tile, count, shared]), PS)) \
+            == sum(m * n * PS for m, n in want.values())
+
+    def test_nothing_shared_is_a_tile_of_one_bit_for_bit(self, small_walk,
+                                                         monkeypatch):
+        """With disjoint tables every tile has one member and no shared
+        page: the rows are those of a kernel whose tiles cannot hold two,
+        and those of slots that DO share are their own walks' to 2e-5."""
+        la = small_walk
+
+        def run(name):
+            q, pool, table, pos, value, _ = _walk_case(name)
+            with kvc.use_paged_attention_impl("pallas"):
+                return np.asarray(kvc.latent_decode_attend(q, pool, table,
+                                                           pos, value))
+
+        apart, together = run("nothing_shared"), run("two_groups_scattered")
+        monkeypatch.setattr(la, "_TILE_MEMBERS", 1)
+        monkeypatch.setattr(la, "_BLOCK_MEMBERS", 1)
+        la._decode_call.clear_cache()
+        np.testing.assert_array_equal(run("nothing_shared"), apart)
+        np.testing.assert_allclose(run("two_groups_scattered"), together,
+                                   atol=2e-5)
+
+
+class TestSharedWalkThroughTheEngine:
+    """Two documents of four pages, three sessions on each, all six in the
+    batch at once with the prefix cache on."""
+
+    def _serve(self, m, impl, docs=2):
+        """(the six requests, the decode spans' attributes, pallas_calls of
+        the kernel's name in the decode program as traced)."""
+        from paddle_tpu import observability as obs
+
+        obs.enable()
+        obs.reset()
+        obs.clear_spans()
+        try:
+            with kvc.use_paged_attention_impl(impl):
+                eng = _engine(m, max_batch_size=6)
+                reqs = []
+                for s in range(6):
+                    prompt = _ids(32, seed=50 + s % docs) \
+                        + _ids(5 + s, seed=60 + s)
+                    reqs.append(eng.add_request(
+                        prompt, SamplingParams(max_new_tokens=7)))
+                    eng.step()          # admitted: its pages are in the trie
+                while eng.has_unfinished:
+                    eng.step()
+                fn, args = eng.decode_program()
+                calls = str(jax.make_jaxpr(fn)(*args)).count(
+                    "name=latent_paged_decode")
+            steps = [e["attrs"] for e in obs.spans()
+                     if e["name"] == "serving/decode"
+                     and "ctx_tokens" in e["attrs"]]
+            compiles = obs.snapshot()["counters"][
+                "jit.compile.cache_miss{site=serving.decode}"]
+        finally:
+            obs.disable()
+            obs.reset()
+            obs.clear_spans()
+        assert compiles == 1
+        assert [k for k in eng._exe if k[0] == "decode"] == [("decode",)]
+        return reqs, steps, calls
+
+    def test_tokens_are_the_oracles_and_the_span_counts_the_walk(
+            self, model, small_walk, monkeypatch):
+        plans = []
+        plan = small_walk.shared_walk_plan
+        monkeypatch.setattr(
+            small_walk, "shared_walk_plan",
+            lambda *a: plans.append(None) or plan(*a))
+        want, plain, none = self._serve(model, "oracle")
+        assert not plans and none == 0
+        got, steps, calls = self._serve(model, "pallas")
+        assert [r.output_ids for r in got] == [r.output_ids for r in want]
+        assert [r.prefix_hit_blocks for r in got] == [0, 0, 4, 4, 4, 4]
+        # ONE kernel does the absorbed attention, the layers share its
+        # trace, and the plan is made once a TRACE of the step, not once a
+        # layer (the engine's compile and ``_serve``'s look at the program)
+        assert calls == 1 and len(plans) == 2
+        layers = model.cfg.num_layers
+        walked = [a["shared_walk_tokens"] for a in steps]
+        # all six running: three members x 32 shared tokens x two documents
+        assert max(walked) == [192] * layers
+        for a in steps:
+            assert a["shared_walk_tokens"][0] <= a["ctx_tokens"]
+            assert a["latent_tokens_read"] == [a["ctx_tokens"]] * layers
+        # the oracle tier gathers every slot's own view: nothing walked
+        assert {tuple(a["shared_walk_tokens"]) for a in plain} \
+            == {(0,) * layers}
+
+    def test_documents_of_their_own_walk_nothing_together(self, model,
+                                                          small_walk):
+        reqs, steps, _ = self._serve(model, "pallas", docs=6)
+        assert [r.prefix_hit_blocks for r in reqs] == [0] * 6
+        assert {tuple(a["shared_walk_tokens"]) for a in steps} \
+            == {(0,) * model.cfg.num_layers}
+
+    def test_a_model_without_latent_layers_has_no_such_count(self):
+        from paddle_tpu import observability as obs
+
+        obs.enable()
+        obs.clear_spans()
+        try:
+            eng = Engine(DecoderLM(DecoderConfig()), EngineConfig(
+                max_batch_size=2, max_seq_len=64, page_size=PS))
+            eng.generate([_ids(9)], SamplingParams(max_new_tokens=3))
+            steps = [e["attrs"] for e in obs.spans()
+                     if e["name"] == "serving/decode"
+                     and "ctx_tokens" in e["attrs"]]
+        finally:
+            obs.disable()
+            obs.reset()
+            obs.clear_spans()
+        assert steps and not any("shared_walk_tokens" in a for a in steps)
+        assert "shared_walk_tokens" not in eng.model.step_stats
+
+
 class TestLatentFlash:
     """The expanded form's kernel: queries behind a cached context, a value
     width of its own."""
@@ -547,7 +797,7 @@ def test_ffn_kind_and_width_by_layer(model):
                               ("moe_swiglu", 16))
     assert model.step_stats == ("experts_touched", "expert_max_load",
                                 "local_rows", "routed_rows",
-                                "latent_tokens_read")
+                                "latent_tokens_read", "shared_walk_tokens")
     p = _params(model)
     x = jax.random.normal(jax.random.PRNGKey(8), (1, 24, 32), jnp.float32)
     zero = jnp.zeros((1,), jnp.int32)
