@@ -69,25 +69,37 @@ def _pages_per_chunk(num_kv_heads: int, page_size: int, head_dim: int,
 
 def _decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                    k_buf, v_buf, sems, *, num_blocks: int, page_size: int,
-                   num_kv_heads: int, rep: int, chunk: int):
+                   num_kv_heads: int, rep: int, chunk: int, window=None):
     """Grid (B,): one step a slot. ``k_buf`` / ``v_buf`` are
     ``[2, chunk, H_kv, page_size, D]`` VMEM buffers, ``sems`` ``[2, 2]`` DMA
     semaphores (pool, buffer); the running (max, sum, acc) are the loop's
-    carry."""
+    carry. With ``window`` the walk starts at the block that holds the
+    window's first token, ``pos - window + 1`` (``live`` then counts the
+    pages from there), and that block's older tokens are masked: a page
+    behind the window is never fetched."""
     b = pl.program_id(0)
     pos = pos_ref[b]
-    # pages [0, pos // page_size] hold written tokens (position pos is
-    # written before the attend — see paged_write_kv); a slot with no first
-    # page is dead
-    live = jnp.where(tbl_ref[b, 0] < 0, 0,
-                     jnp.minimum(pos // page_size + 1, num_blocks))
+    if window is None:
+        # pages [0, pos // page_size] hold written tokens (position pos is
+        # written before the attend — see paged_write_kv); a slot with no
+        # first page is dead
+        live = jnp.where(tbl_ref[b, 0] < 0, 0,
+                         jnp.minimum(pos // page_size + 1, num_blocks))
+    else:
+        # the blocks behind a live slot's window are sentinels, its first
+        # among them: the block of its LAST token says whether it lives
+        last = jnp.minimum(pos // page_size, num_blocks - 1)
+        lo = jnp.maximum(pos - (window - 1), 0)
+        first = lo // page_size
+        live = jnp.where(tbl_ref[b, last] < 0, 0, last + 1 - first)
     chunk_tokens = chunk * page_size
     Hq, D = q_ref.shape[1:]
 
     def page_copies(i, buf, j):
         # a sentinel inside the live range clamps to the reserved trash
         # page, so the fetch stays in-bounds whatever the table holds
-        page = jnp.maximum(tbl_ref[b, i], 0)
+        page = jnp.maximum(
+            tbl_ref[b, i if window is None else first + i], 0)
         return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, j],
                                       sems.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, j],
@@ -137,7 +149,11 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         ], axis=0) * jnp.float32(LOG2E)
         tok = c * chunk_tokens + jax.lax.broadcasted_iota(
             jnp.int32, (Hq, chunk_tokens), 1)
-        s = jnp.where(tok <= pos, s, NEG_INF)  # [Hq, chunk_tokens], log2
+        if window is None:
+            s = jnp.where(tok <= pos, s, NEG_INF)  # [Hq, chunk_tokens], log2
+        else:
+            tok = tok + first * page_size
+            s = jnp.where((tok <= pos) & (tok >= lo), s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
@@ -162,13 +178,20 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
 
 
-def paged_attention(q, k_pool, v_pool, page_table, positions):
+def paged_attention(q, k_pool, v_pool, page_table, positions, window=None):
     """Ragged paged-decode attention over block-paged KV pools.
 
     q            ``[B, H_q, 1, D]`` — one query token per slot
     k/v_pool     ``[P, H_kv, page_size, D]`` — this layer's page pools
     page_table   ``[B, num_blocks]`` int32 pool page ids (-1 = unallocated)
     positions    ``[B]`` int32 — each slot's current token index
+
+    window       static; None: every token up to ``positions[b]``. An
+                 int: the last ``window`` of them alone (a sliding layer):
+                 the walk starts at the window's first block, whatever the
+                 table holds before it (sentinels, once the engine has
+                 freed those pages). A kernel of its own by NAME
+                 (``window_decode``; ``paged_decode`` is the full one's)
 
     Returns ``[B, H_q, 1, D]`` in v's dtype — drop-in for
     ``decode_attend(q, dense_k, dense_v, positions)`` when the dense caches
@@ -190,6 +213,8 @@ def paged_attention(q, k_pool, v_pool, page_table, positions):
     mp = dict(jax.sharding.get_abstract_mesh().shape).get("mp", 1)
     heads = P(None, "mp") if Hkv % mp == 0 else P()
     call = functools.partial(_decode_call, interpret=pallas_interpret())
+    if window is not None:
+        call = functools.partial(call, window=int(window))
     out = shard_kernel(call, (table, pos, qs, k_pool, v_pool),
                        (P(), P(), heads, heads, heads), lambda f: f[2])
     return out[:, :, None, :]
@@ -199,8 +224,9 @@ def paged_attention(q, k_pool, v_pool, page_table, positions):
 # ONE trace and ONE Mosaic lowering in the program they are traced into;
 # ``interpret`` is the caller's reading of the platform, so it is part of the
 # cache's key
-@functools.partial(jax.jit, static_argnames="interpret")
-def _decode_call(table, pos, qs, k_pool, v_pool, *, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+def _decode_call(table, pos, qs, k_pool, v_pool, *, interpret: bool,
+                 window=None):
     B, Hq, D = qs.shape
     _, Hkv, page_size, _ = k_pool.shape
     chunk = _pages_per_chunk(Hkv, page_size, D, k_pool.dtype.itemsize)
@@ -219,14 +245,17 @@ def _decode_call(table, pos, qs, k_pool, v_pool, *, interpret: bool):
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
+    kernel = functools.partial(_decode_kernel, num_blocks=table.shape[1],
+                               page_size=page_size, num_kv_heads=Hkv,
+                               rep=Hq // Hkv, chunk=chunk)
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
     return pl.pallas_call(
-        functools.partial(_decode_kernel, num_blocks=table.shape[1],
-                          page_size=page_size, num_kv_heads=Hkv,
-                          rep=Hq // Hkv, chunk=chunk),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), v_pool.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-        name="paged_decode",
+        name="paged_decode" if window is None else "window_decode",
     )(table, pos, qs, k_pool, v_pool)

@@ -1,15 +1,19 @@
 """A decoder-only LM built from a DESCRIPTION of its block.
 
 ``DecoderConfig`` names the kind of each part of the block — norm
-(``rms`` | ``layer``) and where it stands (``pre`` | ``post``), positions
-(``rope`` | ``rope_yarn`` | ``none``), the token mixer (``dense`` |
-``indexed_sparse`` | ``gated_delta`` | ``latent``; one kind for all layers,
-or ``layer_types``, one a layer), FFN (``swiglu`` | ``moe_swiglu``; the
+(``rms`` | ``layer`` | ``layer_nobias``) and where it stands (``pre`` |
+``post`` | ``parallel``), positions (``rope`` | ``rope_gptj`` |
+``rope_yarn`` | ``none``; ``position_by_kind`` where layers of one kind
+have others), the token mixer (``dense`` | ``sliding`` | ``indexed_sparse``
+| ``gated_delta`` | ``latent``; one kind for all layers, or
+``layer_types``, one a layer), FFN (``swiglu`` | ``moe_swiglu``; the
 first ``first_dense_layers`` layers a dense swiglu of a width of their
-own), router (``softmax_topk`` | ``sigmoid_group_topk``) — with
+own), router (``softmax_topk`` | ``sigmoid_topk`` |
+``sigmoid_group_topk``) — with
 their widths and what varies within a kind (a dense layer's output gate,
-the width of a gated_delta layer's decay, which of a layer's experts are
-held here, shared experts); the parts are looked up by kind in
+a sliding layer's window, the width of a gated_delta layer's decay, which
+of a layer's experts are held here, shared experts and how they are
+combined, a scale on the logits); the parts are looked up by kind in
 the tables at the bottom of each section, so the next architecture is a
 description (and at most a new entry in one table), not a third class tree
 beside ``models/gpt.py``. GPT-3's block (learned position table, LayerNorm
@@ -28,6 +32,35 @@ The block, for ``x`` the residual stream::
                   renormalised; x += sum_e p_e (silu(g W1_e) * g W3_e) W2_e
                   (experts_held: the sum runs over the held e alone;
                   shared_experts: + one dense SwiGLU of g)
+
+The PARALLEL block (``norm_placement`` "parallel": Command A+,
+``use_parallel_block``) has ONE norm and feeds it to both parts, the
+router included::
+
+    h = LayerNorm(x) = (x - mean) / sqrt(var + eps) * gamma   (layer_nobias:
+        float32 statistics, a scale, NO bias)
+    x = x + attention(h) + ffn(h)
+    sliding: q, k turned by rotary positions in INTERLEAVED pairs (x[2i],
+        x[2i + 1]) (``rope_gptj``); query t sees the keys s with
+        t - sliding_window < s <= t (its own among them)
+    dense beside it (``position_by_kind`` {"dense": "none"}): no positions;
+        every s <= t
+    sigmoid_topk: p = sigmoid_f32(h Wr) over ALL experts, the top-k of it,
+        w_e = p_e / sum of the chosen (no groups, no bias, no factor)
+    shared_combine "mean": + (1 / n) sum_s FFN'_s(h), computed as ONE
+        SwiGLU n times as wide, times 1 / n (the sum of n is the
+        concatenation of their columns)
+    logits = logit_scale * norm(x) . Emb^T
+
+A ``sliding`` layer keeps pages of its window alone: its two pools stand in
+a page GROUP of their own (``cache_pools``' fifth entry, ``("window",
+sliding_window)``), with a page count, an allocator and a page table that
+the engine slides (serving/README.md). Three forms of one layer: prefill a
+causal BAND (``attend``'s ``band``: a chunk of queries is handed the keys
+its band reaches, and turned a chunk at a time), extend over a view of the
+window and the new tokens (``kv_cache.window_blocks``), decode the paged
+kernel with a ``window`` (``kernels/paged_attention``: the walk starts at
+the window's first block).
 
 A ``gated_delta`` layer (linear attention by the gated delta rule,
 arXiv:2412.06464) mixes tokens through a recurrent state instead of a
@@ -107,6 +140,7 @@ combine.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple, Union
@@ -131,10 +165,16 @@ class DecoderConfig:
     num_kv_heads: int = 2
     head_dim: int = 16            # its own width, not hidden / heads
     max_context: int = 128        # longest sequence the positions serve
-    norm: str = "rms"             # rms | layer
+    norm: str = "rms"             # rms | layer | layer_nobias
     norm_eps: float = 1e-6
-    norm_placement: str = "pre"   # pre: x + f(norm(x)); post: x + norm(f(x))
-    position: str = "rope"        # rope | rope_yarn | none
+    # pre: x + f(norm(x)); post: x + norm(f(x)); parallel: ONE norm,
+    # x + attn(norm(x)) + ffn(norm(x))
+    norm_placement: str = "pre"
+    position: str = "rope"        # rope | rope_gptj | rope_yarn | none
+    # positions of the layers of some kinds, where they differ from
+    # ``position``: {"dense": "none"} for full layers without positions
+    # beside rotary ``sliding`` ones
+    position_by_kind: Optional[dict] = None
     rope_theta: float = 1e7
     # rope_yarn's parameters, under the published names: factor,
     # original_max_position_embeddings, beta_fast, beta_slow, mscale,
@@ -143,8 +183,12 @@ class DecoderConfig:
     # RMSNorm on q and k: True / "head" over each head, "full" over all of a
     # token's heads together, False none
     qk_norm: Union[bool, str] = True
-    # dense | indexed_sparse | gated_delta | latent
+    # dense | sliding | indexed_sparse | gated_delta | latent
     attention: str = "indexed_sparse"
+    # a ``sliding`` layer's window: query t sees the keys s with
+    # t - sliding_window < s <= t (its own among them), and keeps pages of
+    # that many tokens only (a page group of its own: ``cache_pools``)
+    sliding_window: Optional[int] = None
     # one kind a layer (keys of ATTENTIONS); None: ``attention`` for all
     layer_types: Optional[Tuple[str, ...]] = None
     # how a dense layer's K and V pages lie: "token" [pages, 1, page,
@@ -183,7 +227,8 @@ class DecoderConfig:
     # swiglu of width ``dense_intermediate_size`` whatever ``ffn`` says
     first_dense_layers: int = 0
     dense_intermediate_size: int = 256
-    router: str = "softmax_topk"  # softmax_topk | sigmoid_group_topk
+    # softmax_topk | sigmoid_topk | sigmoid_group_topk
+    router: str = "softmax_topk"
     # sigmoid_group_topk: the experts stand in ``n_group`` groups of which a
     # token keeps ``topk_group``; the chosen weights times the factor
     n_group: int = 1
@@ -200,7 +245,11 @@ class DecoderConfig:
     # shared experts: one dense SwiGLU of width ``intermediate_size`` x this
     # beside the routed ones, ungated, on every token
     shared_experts: int = 0
+    # how the shared experts' outputs join the routed sum: "sum", or "mean"
+    # (their mean: the one wide SwiGLU times 1 / shared_experts)
+    shared_combine: str = "sum"
     tie_word_embeddings: bool = False
+    logit_scale: float = 1.0      # the head's logits times this
     initializer_range: float = 0.02
     dtype: str = "float32"
     # "normal": N(0, initializer_range), norm scales 1. "zeros": nothing is
@@ -208,6 +257,11 @@ class DecoderConfig:
     # initial values would not fit beside them).
     init: str = "normal"
     query_chunk: int = 128        # queries per chunk of ``attend``
+    # a dense layer's flash prefill one KEY/VALUE head at a time (its group
+    # of query heads beside it): what stands in memory is one group's keys,
+    # values and row statistics, not all heads' (at 128 query heads over 8
+    # and 18k tokens: 0.3 GB where the whole is 2.3)
+    flash_by_kv_head: bool = False
 
     def __post_init__(self):
         if self.qk_norm is True:
@@ -218,7 +272,8 @@ class DecoderConfig:
                              ("norm_placement", NORM_PLACEMENTS),
                              ("kv_layout", KV_LAYOUTS),
                              ("qk_norm", QK_NORMS),
-                             ("linear_gate", LINEAR_GATES)):
+                             ("linear_gate", LINEAR_GATES),
+                             ("shared_combine", SHARED_COMBINES)):
             if getattr(self, field) not in table:
                 raise ValueError(f"{field} {getattr(self, field)!r}; "
                                  f"want one of {sorted(table, key=str)}")
@@ -234,6 +289,14 @@ class DecoderConfig:
                     f"num_layers is {self.num_layers}")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
+        for kind, pos in (self.position_by_kind or {}).items():
+            if kind not in ATTENTIONS or pos not in POSITIONS:
+                raise ValueError(f"position_by_kind {kind!r}: {pos!r}")
+        if "sliding" in self.kinds and not (
+                self.sliding_window and self.sliding_window >= 1
+                and self.kv_layout == "head"):
+            raise ValueError("a sliding layer wants sliding_window >= 1 and "
+                             "kv_layout 'head' (the paged-decode kernel's)")
         if self.position == "rope_yarn" and not (
                 self.rope_scaling and set(self.kinds) == {"latent"}):
             # the other mixers' decode and flash paths scale by width^-1/2
@@ -284,8 +347,11 @@ def layer_norm(x, p, pre, eps):
     return y.astype(x.dtype)
 
 
-NORMS = {"rms": (rms_norm, False), "layer": (layer_norm, True)}  # fn, bias
-NORM_PLACEMENTS = ("pre", "post")
+#: kind -> (fn, whether it has a bias)
+NORMS = {"rms": (rms_norm, False), "layer": (layer_norm, True),
+         "layer_nobias": (layer_norm, False)}
+NORM_PLACEMENTS = ("pre", "post", "parallel")
+SHARED_COMBINES = ("sum", "mean")
 QK_NORMS = (False, "head", "full")
 KV_LAYOUTS = ("token", "head")
 LINEAR_GATES = ("head", "channel")
@@ -321,6 +387,22 @@ def rope(x, pos, theta):
     D = x.shape[-1]
     inv = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) * 2.0 / D)
     return _rotate(x, pos, inv)
+
+
+def rope_gptj(x, pos, theta):
+    """Rotary positions in INTERLEAVED pairs (pair i is (x[2i], x[2i + 1]),
+    at ``theta^(-2i/D)``) on ``x [B, T, heads, D]`` at ``pos [B, T]``: ``x *
+    cos + swap(x) * sin`` with ``swap(x)[2i] = -x[2i + 1]``, ``swap(x)[2i +
+    1] = x[2i]``, made of two rotations along the lanes (no ``[.., D/2, 2]``
+    reshape, which the chip would re-lay); angles in float32."""
+    D = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) * 2.0 / D)
+    ang = jnp.repeat(pos.astype(jnp.float32)[:, :, None, None] * inv, 2,
+                     axis=-1)                                  # [B,T,1,D]
+    xf = x.astype(jnp.float32)
+    even = (jnp.arange(D) % 2) == 0
+    swap = jnp.where(even, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
+    return (xf * jnp.cos(ang) + swap * jnp.sin(ang)).astype(x.dtype)
 
 
 def yarn_inv_freq(D: int, theta: float, scaling: dict) -> np.ndarray:
@@ -371,8 +453,14 @@ def softmax_scale(cfg, width: int) -> float:
 
 #: kind -> positions on ``x [B, T, heads, D]`` at ``pos [B, T]``
 POSITIONS = {"rope": lambda cfg, x, pos: rope(x, pos, cfg.rope_theta),
+             "rope_gptj": lambda cfg, x, pos: rope_gptj(x, pos, cfg.rope_theta),
              "rope_yarn": rope_yarn,
              "none": lambda cfg, x, pos: x}
+
+
+def _position_of(cfg, kind: str):
+    """The positions of a layer of ``kind``."""
+    return POSITIONS[(cfg.position_by_kind or {}).get(kind, cfg.position)]
 
 
 # -------------------------------------------------------------- attention
@@ -406,27 +494,46 @@ def _attn_shapes(cfg, pre, sparse):
     return s
 
 
-def attend(cfg, q, k_view, v_view, qpos, index=None):
+def attend(cfg, q, k_view, v_view, qpos, index=None, window=None,
+           first=None, band=False, turn=None):
     """Queries ``q [B, T, Hq, D]`` at positions ``qpos [B, T]`` against key
-    and value views ``[B, L, Hkv, D]`` (view position = sequence position),
-    ``[B, T, Hq, D]`` out. ``index`` (indexed_sparse) is ``(qi [B, T, Hi,
-    Di], w [B, T, Hi] float32, ki_view [B, L, Di])``: a query attends to the
-    ``index_topk`` positions ``s <= qpos`` of largest indexer score; without
-    it, to every ``s <= qpos``. Computed in chunks of ``cfg.query_chunk``
-    queries. Numerics as ``serving.kv_cache.extend_attend``: q pre-scaled in
-    its own dtype, float32 scores, -1e30 mask, float32 softmax."""
+    and value views ``[B, L, Hkv, D]`` (view position = sequence position,
+    or ``first[b]`` + view position where ``first [B]`` is given: a view of
+    a slot's window), ``[B, T, Hq, D]`` out. ``index`` (indexed_sparse) is
+    ``(qi [B, T, Hi, Di], w [B, T, Hi] float32, ki_view [B, L, Di])``: a
+    query attends to the ``index_topk`` positions ``s <= qpos`` of largest
+    indexer score; without it, to every ``s <= qpos``, with ``window`` to
+    those with ``qpos - window < s`` alone. ``band`` (a prefill under a
+    window: the views are the sequence itself) hands a chunk of queries
+    only the keys its band can reach, so the keys outside cost nothing;
+    ``turn(q chunk, its positions)`` gives the queries their positions a
+    chunk at a time (a 18k-token prompt's 128 query heads turned whole, in
+    float32, stood in memory three times over: 3.4 GB).
+    Computed in chunks of ``cfg.query_chunk`` queries. Numerics as
+    ``serving.kv_cache.extend_attend``: q pre-scaled in its own dtype,
+    float32 scores, -1e30 mask, float32 softmax."""
     from ..kernels.sparse_attention import topk_mask
 
     B, T, Hq, D = q.shape
     L, Hkv = k_view.shape[1], k_view.shape[2]
     rep = Hq // Hkv
     kpos = jnp.arange(L, dtype=jnp.int32)
-    qs = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+    scale = jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+    qs = q * scale if turn is None else q
 
-    def chunk(args):
+    def chunk(args, k_view=k_view, v_view=v_view, first=first):
         qc, pc, ic = args           # [B, C, Hq, D], [B, C], index
         C = qc.shape[1]
-        valid = kpos[None, None, :] <= pc[:, :, None]             # [B, C, L]
+        if turn is not None:
+            qc = turn(qc, pc) * scale
+        L = k_view.shape[1]
+        if window is None:
+            valid = kpos[None, None, :] <= pc[:, :, None]         # [B, C, L]
+        else:
+            kp = jnp.arange(L, dtype=jnp.int32)[None, None, :]
+            if first is not None:
+                kp = kp + jnp.reshape(first, (-1, 1, 1))
+            valid = (kp <= pc[:, :, None]) & (kp > pc[:, :, None] - window)
         if index is not None:
             qi, w = ic
             s = jnp.einsum("bthd,bld->bthl", qi, index[2],
@@ -452,14 +559,22 @@ def attend(cfg, q, k_view, v_view, qpos, index=None):
     idx = None if index is None else (index[0], index[1])
     if C == T:
         return chunk((qs, qpos, idx))
+    # a band's chunk reaches back window - 1 keys from its first query
+    Lb = min(L, -(-(C + window) // 128) * 128) if band else L
 
     # chunk by chunk, sliced out of (and written back into) the whole
     # arrays where they lie: stacked per-chunk copies of q and of the output
     # would each be another 0.3 GB at a 34k-token prompt
     def body(i, out):
         cut = lambda a: lax.dynamic_slice_in_dim(a, i * C, C, axis=1)
-        o = chunk((cut(qs), cut(qpos),
-                   None if idx is None else tuple(map(cut, idx))))
+        args = (cut(qs), cut(qpos),
+                None if idx is None else tuple(map(cut, idx)))
+        if Lb < L:      # the keys [k0, k0 + Lb) hold the chunk's whole band
+            k0 = jnp.clip(i * C + C - Lb, 0, L - Lb)
+            keys = lambda a: lax.dynamic_slice_in_dim(a, k0, Lb, axis=1)
+            o = chunk(args, keys(k_view), keys(v_view), k0)
+        else:
+            o = chunk(args)
         return lax.dynamic_update_slice_in_dim(out, o, i * C, axis=1)
 
     return lax.fori_loop(0, T // C, body, jnp.zeros((B, T, Hq, D), q.dtype))
@@ -492,9 +607,13 @@ def _indexer(cfg, p, pre, h, pos):
 
 
 def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
-              lengths=None, cuts=None, sparse=False):
+              lengths=None, cuts=None, sparse=False, kind="dense"):
     """The attention part of a block over ``h [B, T, hidden]`` whose
-    tokens sit at ``start[b] .. start[b] + T - 1``. Without ``cache``
+    tokens sit at ``start[b] .. start[b] + T - 1``. A layer of ``kind``
+    "sliding" sees the last ``cfg.sliding_window`` keys alone: its prefill
+    is a causal band (``attend``'s), its extend reads a view of the window
+    and the new tokens (not of the whole table), its decode walks the
+    window's pages (``kv_cache.paged_decode_attend``'s ``window``). Without ``cache``
     (prefill) the keys are the ones just computed; with ``cache`` (the
     layer's pools and the page table) they are written into the pools first
     and read back through the table. Returns (out [B, T, hidden], new):
@@ -508,6 +627,7 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     B, T, _ = h.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    window = cfg.sliding_window if kind == "sliding" else None
 
     def heads(w, n, norm=None):
         x = _mm(h, p[pre + w])
@@ -520,8 +640,9 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     if cfg.qk_norm == "head":
         q = rms_norm(q, p, pre + ".q_norm", cfg.norm_eps)
         k = rms_norm(k, p, pre + ".k_norm", cfg.norm_eps)
-    turn = POSITIONS[cfg.position]
-    q, k = turn(cfg, q, pos), turn(cfg, k, pos)
+    turn = _position_of(cfg, kind)
+    lazy = cache is None and window is not None   # q a chunk at a time
+    q, k = q if lazy else turn(cfg, q, pos), turn(cfg, k, pos)
     index = _indexer(cfg, p, pre + ".index", h, pos) if sparse else None
     head_major = cfg.kv_layout == "head"
     if head_major:
@@ -540,17 +661,34 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
             o = (o.astype(jnp.float32) * gate).astype(h.dtype)
         return _mm(o, p[pre + ".wo"])
 
+    if cache is None and window is not None:
+        with jax.named_scope("attn/window"):
+            o = attend(cfg, q, k, v, pos, window=window, band=True,
+                       turn=lambda qc, pc: turn(cfg, qc, pc))
+        return out(o), tuple(fresh)
     if cache is None:
         if flash_ok and (not sparse or T <= cfg.index_topk):
             # every position is selected: plain causal attention, through
             # the seam the flash kernel sits behind
             from ..nn import functional as F
 
+            rep = Hq // Hkv
+            flash = lambda q, k, v: F.scaled_dot_product_attention(
+                Tensor(q), Tensor(k), Tensor(v), is_causal=True,
+                training=False)._value
+            if cfg.flash_by_kv_head:
+                wide = lambda t: jnp.broadcast_to(t[:, :, None],
+                                                  (B, T, rep, D))
+                with _scope(cfg, "attn/full"):
+                    o = lax.map(
+                        lambda a: flash(a[0], wide(a[1]), wide(a[2])),
+                        (q.reshape(B, T, Hkv, rep, D).transpose(2, 0, 1, 3, 4),
+                         k.transpose(2, 0, 1, 3), v.transpose(2, 0, 1, 3)))
+                o = o.transpose(1, 2, 0, 3, 4).reshape(B, T, Hq, D)
+                return out(o), tuple(fresh)
             expand = lambda t: jnp.broadcast_to(
-                t[:, :, :, None], (B, T, Hkv, Hq // Hkv, D)).reshape(B, T, Hq, D)
-            o = F.scaled_dot_product_attention(
-                Tensor(q), Tensor(expand(k)), Tensor(expand(v)),
-                is_causal=True, training=False)._value
+                t[:, :, :, None], (B, T, Hkv, rep, D)).reshape(B, T, Hq, D)
+            o = flash(q, expand(k), expand(v))
         else:
             o = attend(cfg, q, k, v, pos, index)
         return out(o), tuple(fresh)
@@ -559,10 +697,25 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     pools = [_kvc.paged_write_kv(pool, new, table, start)
              for pool, new in zip(pools, fresh)]
     L = table.shape[1] * pools[0].shape[2]
-    if head_major and T == 1:
+    if window is not None:
+        with jax.named_scope("attn/window"):
+            if T == 1:
+                o = _kvc.paged_decode_attend(
+                    q.transpose(0, 2, 1, 3), pools[0], pools[1], table, start,
+                    window=window).transpose(0, 2, 1, 3)
+            else:
+                # the window before the first new token, and the new tokens
+                first, sub = _kvc.window_blocks(table, start, pools[0].shape[2],
+                                                window, T)
+                view = lambda pool: _kvc.paged_gather(pool, sub) \
+                    .transpose(0, 2, 1, 3)
+                o = attend(cfg, q, view(pools[0]), view(pools[1]), pos,
+                           window=window, first=first)
+    elif head_major and T == 1:
         # the paged attend, kernel or oracle as kv_cache says
-        o = _kvc.paged_decode_attend(q.transpose(0, 2, 1, 3), pools[0],
-                                     pools[1], table, start)
+        with _scope(cfg, "attn/full"):
+            o = _kvc.paged_decode_attend(q.transpose(0, 2, 1, 3), pools[0],
+                                         pools[1], table, start)
         o = o.transpose(0, 2, 1, 3)
     elif sparse and T == 1 and _kvc.default_paged_impl() == "pallas":
         from ..kernels.sparse_attention import (selected_rows,
@@ -593,8 +746,21 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     return out(o), tuple(pools)
 
 
-def _kv_pools(cfg, sparse):
-    """[(name, heads, width)] of an attention layer's paged pools."""
+def _scope(cfg, name):
+    """``jax.named_scope(name)`` in a model with sliding layers (whose
+    traces tell its window and full layers apart by it); nothing in any
+    other, whose programs stay the ones they were."""
+    return jax.named_scope(name) if "sliding" in cfg.kinds \
+        else contextlib.nullcontext()
+
+
+def _kv_pools(cfg, sparse, window=False):
+    """[(name, heads, width)] of an attention layer's paged pools; a
+    sliding layer's are named apart (they stand in a page group of their
+    own, ``DecoderLM.cache_pools``)."""
+    if window:
+        return [("k_window", cfg.num_kv_heads, cfg.head_dim),
+                ("v_window", cfg.num_kv_heads, cfg.head_dim)]
     if cfg.kv_layout == "head":
         pools = [("k", cfg.num_kv_heads, cfg.head_dim),
                  ("v", cfg.num_kv_heads, cfg.head_dim)]
@@ -968,7 +1134,12 @@ def latent_attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
 ATTENTIONS = {
     "dense": (attention, functools.partial(_attn_shapes, sparse=False),
               functools.partial(_kv_pools, sparse=False), lambda c: []),
-    "indexed_sparse": (functools.partial(attention, sparse=True),
+    "sliding": (functools.partial(attention, kind="sliding"),
+                functools.partial(_attn_shapes, sparse=False),
+                functools.partial(_kv_pools, sparse=False, window=True),
+                lambda c: []),
+    "indexed_sparse": (functools.partial(attention, sparse=True,
+                                         kind="indexed_sparse"),
                        functools.partial(_attn_shapes, sparse=True),
                        functools.partial(_kv_pools, sparse=True),
                        lambda c: []),
@@ -1015,9 +1186,22 @@ def sigmoid_group_topk(cfg, g, wr, bias):
     return pw * f32(cfg.routed_scaling_factor), e.astype(jnp.int32)
 
 
+@jax.named_scope("router/sigmoid_topk")
+def sigmoid_topk(cfg, g, wr):
+    """Router by plain sigmoid scores, float32: ``s = sigmoid(g Wr)`` over
+    ALL experts, the top-k of it (no groups, no bias, no factor), the
+    weights ``s`` of the chosen over their sum (``norm_topk_prob``)."""
+    s = jax.nn.sigmoid(jnp.dot(g, wr, preferred_element_type=jnp.float32))
+    pw, e = lax.top_k(s, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        pw = pw / jnp.sum(pw, axis=-1, keepdims=True)
+    return pw, e.astype(jnp.int32)
+
+
 #: kind -> (the router's function of (cfg, g, the ``[hidden, experts]``
 #: matrix, *its other leaves), those leaves' names behind ``.router``)
 ROUTERS = {"softmax_topk": (softmax_topk, ()),
+           "sigmoid_topk": (sigmoid_topk, ()),
            "sigmoid_group_topk": (sigmoid_group_topk, (".bias",))}
 
 
@@ -1068,9 +1252,15 @@ def step_stats(cfg) -> Tuple[str, ...]:
     those of them that slots on one document scored TOGETHER, each page
     fetched once for all of them (the sum over the step's plan,
     ``serving.kv_cache.latent_decode_plan``: 0 in the oracle tier, which
-    has none); both 0 in a layer of another kind."""
+    has none); both 0 in a layer of another kind. Of a model with sliding
+    layers, ``window_tokens_read`` / ``full_tokens_read``: the cached tokens
+    a sliding layer (``min(context, sliding_window)`` a slot) and a full
+    one (the context) attended, summed over the live slots, each 0 in a
+    layer of the other kind."""
     return ffn_stats(cfg) + (("latent_tokens_read", "shared_walk_tokens")
-                             if "latent" in cfg.kinds else ())
+                             if "latent" in cfg.kinds else ()) \
+        + (("window_tokens_read", "full_tokens_read")
+           if "sliding" in cfg.kinds else ())
 
 
 def moe_routed(cfg, p, pre, g):
@@ -1117,7 +1307,12 @@ def moe_swiglu(cfg, p, pre, g):
     part (``moe_routed``) plus, with ``shared_experts``, one dense SwiGLU
     over every token. Returns (y [N, hidden], the routing statistics)."""
     y, stats = moe_routed(cfg, p, pre, g)
-    if cfg.shared_experts:
+    if cfg.shared_experts and cfg.shared_combine == "mean":
+        # the mean of the shared experts: their sum is the ONE wide SwiGLU
+        with jax.named_scope("ffn/shared_mean"):
+            y = y + _dense_swiglu(p, pre + ".shared", g).astype(jnp.float32) \
+                * jnp.float32(1.0 / cfg.shared_experts)
+    elif cfg.shared_experts:
         y = y + _dense_swiglu(p, pre + ".shared", g).astype(jnp.float32)
     return y.astype(g.dtype), stats
 
@@ -1164,7 +1359,8 @@ def param_shapes(cfg: DecoderConfig) -> dict:
         pre = f"layers.{l}"
         s.update(_norm_shapes(cfg, pre + ".attn_norm", H))
         s.update(ATTENTIONS[kind][1](cfg, pre + ".attn"))
-        s.update(_norm_shapes(cfg, pre + ".ffn_norm", H))
+        if cfg.norm_placement != "parallel":    # which has ONE norm a block
+            s.update(_norm_shapes(cfg, pre + ".ffn_norm", H))
         kind, width = cfg.ffns[l]
         s.update(FFNS[kind][1](cfg, pre + ".ffn", width))
     s.update(_norm_shapes(cfg, "final_norm", H))
@@ -1206,7 +1402,13 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
     pre = f"layers.{l}"
     mixer = ATTENTIONS[cfg.kinds[l]][0]
     kind = cfg.ffns[l][0]
-    if cfg.norm_placement == "post":
+    if cfg.norm_placement == "parallel":
+        h = _norm(cfg, x, p, pre + ".attn_norm")
+        a, new = mixer(cfg, p, pre + ".attn", h, start, cache, flash_ok,
+                       lengths, cuts)
+        y, stats = ffn(cfg, p, pre + ".ffn", h, kind)
+        x = x + a + y
+    elif cfg.norm_placement == "post":
         a, new = mixer(cfg, p, pre + ".attn", x, start, cache, flash_ok,
                        lengths, cuts)
         x = x + _norm(cfg, a, p, pre + ".attn_norm")
@@ -1234,6 +1436,23 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
                 walk = shared_walk_tokens(plan[0], pool.shape[2])
         stats = jnp.concatenate(
             [stats, jnp.stack([read, walk]).astype(jnp.int32)])
+    if "sliding" in cfg.kinds:
+        win = full = jnp.zeros((), jnp.int32)
+        if cache is not None and cfg.kinds[l] in ("sliding", "dense"):
+            # a live slot (the block of its last token is mapped: a sliding
+            # layer's first blocks are not) attends up to its window
+            table, ps = cache[-1], cache[0].shape[2]
+            last = start + x.shape[1] - 1
+            live = jnp.take_along_axis(
+                table, jnp.minimum(last // ps, table.shape[1] - 1)[:, None],
+                axis=1)[:, 0] >= 0
+            ctx = jnp.where(live, last + 1, 0)
+            if cfg.kinds[l] == "sliding":
+                win = jnp.sum(jnp.minimum(ctx, cfg.sliding_window))
+            else:
+                full = jnp.sum(ctx)
+        stats = jnp.concatenate(
+            [stats, jnp.stack([win, full]).astype(jnp.int32)])
     return x, new, stats
 
 
@@ -1278,7 +1497,11 @@ class DecoderLM(Layer):
         the layers that hold them as a fourth entry where not every layer
         does."""
         L = self.cfg.num_layers
-        return [spec[:3] if len(spec[3]) == L else spec
+        # a sliding layer's pools stand in a page GROUP of their own: (its
+        # name, the window it keeps), the declaration's fifth entry
+        group = ("window", self.cfg.sliding_window)
+        return [spec + (group,) if spec[0].endswith("_window")
+                else spec[:3] if len(spec[3]) == L else spec
                 for spec in self._pools(2)]
 
     def state_pools(self):
@@ -1327,7 +1550,10 @@ class DecoderLM(Layer):
         h = _norm(self.cfg, h, p, "final_norm")
         w = p["embed.weight"].T if self.cfg.tie_word_embeddings \
             else p["head.weight"]
-        return jnp.dot(h, w, preferred_element_type=jnp.float32)
+        logits = jnp.dot(h, w, preferred_element_type=jnp.float32)
+        if self.cfg.logit_scale != 1.0:
+            logits = logits * jnp.float32(self.cfg.logit_scale)
+        return logits
 
     def forward(self, input_ids):
         """Logits ``[B, T, vocab]`` (float32) of a full causal pass."""

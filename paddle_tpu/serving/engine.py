@@ -171,18 +171,20 @@ def _write_prompt_dense(kc, vc, kvs):
                          (kc, vc), kvs)
 
 
-def _write_prompt_paged(cache, pools, kvs, page_row, rows=None):
+def _write_prompt_paged(cache, pools, kvs, page_rows, rows=None):
     """Each layer's prompt entries into that layer's pools: a paged pool's
     ``[1, heads, T, width]`` at positions ``[0, T)``, routed by the slot's
-    table row, one scatter of the bucket's pages per pool
-    (``paged_write_kv``; blocks past the allocated pages, sentinels, land
-    on the trash page); a state pool's ``[len(rows), ...]`` (the state at
-    each cut, then at the end) onto ``rows`` (``write_state_rows``)."""
-    table, zero = page_row[None, :], jnp.zeros((1,), jnp.int32)
+    table row in the pool's page group (``page_rows``: one a group), one
+    scatter of the bucket's pages per pool (``paged_write_kv``; blocks
+    without a page, sentinels, land on the trash page: the bucket's tail, a
+    window group's blocks behind the tails it keeps, blocks the slot
+    shares); a state pool's ``[len(rows), ...]`` (the state at each cut,
+    then at the end) onto ``rows`` (``write_state_rows``)."""
+    tables, zero = [r[None, :] for r in page_rows], jnp.zeros((1,), jnp.int32)
     paged = len(cache.pool_specs)
     return tuple(
-        tuple((paged_write_kv(c, new, table, zero) if j < paged
-               else write_state_rows(c, new, rows))
+        tuple((paged_write_kv(c, new, tables[cache.pool_group[j]], zero)
+               if j < paged else write_state_rows(c, new, rows))
               for c, new in zip(pool, news))
         for j, (pool, news) in enumerate(zip(pools, _updated(cache, kvs))))
 
@@ -310,6 +312,10 @@ class EngineConfig:
     # same (B_max, S_max) envelope
     page_size: int = 16          # tokens per KV page (shrunk to divide S_max)
     kv_pages: Optional[int] = None  # pool size; default = full budget + trash
+    # the pool size of each further page GROUP a model declares, by the
+    # group's name ({"window": pages}: a sliding layer's pools hold a
+    # window a slot, not a context); default = the full budget each
+    group_pages: Optional[Dict[str, int]] = None
     # radix prefix cache (prefix_cache.py): finished prompts' full KV
     # blocks stay indexed by token content, and a new request whose prompt
     # shares a block-aligned prefix splices the SAME physical pages into
@@ -427,8 +433,29 @@ class Engine:
         self.cache = PagedKVCache(cfg.num_layers, B, pools[0][1], S_max,
                                   pools[0][2], dt, page_size=ps,
                                   num_pages=num_pages, pools=pools,
-                                  state_pools=state, num_snapshots=snapshots)
-        self.page_alloc = PageAllocator(num_pages)
+                                  state_pools=state, num_snapshots=snapshots,
+                                  group_pages=self.config.group_pages)
+        groups = self.cache.groups
+        # one allocator a page group; ``page_alloc`` is the first group's
+        # (a cache of one group: the one allocator there always was)
+        self.page_allocs: List[PageAllocator] = [
+            PageAllocator(pages, name if len(groups) > 1 else None)
+            for name, _, pages in groups]
+        self.page_alloc = self.page_allocs[0]
+        #: [(group, window in tokens)] of the groups that keep a window
+        self._windows = [(g, w) for g, (_, w, _) in enumerate(groups) if w]
+        if self._windows and (state or self.config.speculative is not None
+                              or (groups[0][1] and self.config.prefix_cache)):
+            raise ValueError(
+                "a model with sliding-window pools is served without "
+                "recurrent state and without speculation, and with the "
+                "prefix cache only beside a group that keeps every token")
+        # the first block each slot still maps in each window group
+        self._win_from = {g: np.zeros((B,), np.int64) for g, _ in self._windows}
+        # references dropped behind windows / matched tokens run again for
+        # want of a window's pages, so far
+        self.window_pages_freed = 0
+        self.resume_cut_tokens = 0
         # snapshot ids [1, snapshots], refcounted as pages are: the trie
         # holds one reference a node that carries one, an admission one on
         # the snapshot it resumes from until its program is enqueued
@@ -468,12 +495,14 @@ class Engine:
         self.sampler_steps_draw = 0
         self.prefix_cache: Optional[PrefixCache] = None
         if self.config.prefix_cache:
-            self.prefix_cache = PrefixCache(self.cache.page_size,
-                                            self.page_alloc,
-                                            self.snapshot_alloc)
+            self.prefix_cache = PrefixCache(
+                self.cache.page_size, self.page_alloc, self.snapshot_alloc,
+                more=[(a, w) for a, (_, w, _) in zip(self.page_allocs[1:],
+                                                     groups[1:])])
             # pages can be shared from here on: have the copy-on-write
-            # program compiled now, never between two decode steps
-            self.cache.copy_page_exe()
+            # programs compiled now, never between two decode steps
+            for g in range(len(groups)):
+                self.cache.copy_page_exe(g)
         self.spec: Optional[SpeculativeConfig] = self.config.speculative
         # cumulative speculation accounting (greedy rows only — sampled
         # rows ignore drafts and always emit 1 token from position 0)
@@ -595,13 +624,14 @@ class Engine:
         the bucket tail past the allocated pages clamps to the trash
         page)."""
         model, n = self.model, len(self.cache.pools)
-        nb = self.cache.num_blocks
+        nb, G = self.cache.num_blocks, len(self.cache.groups)
 
         cache = self.cache
 
         @jax.named_scope("serving/prefill")
         def paged_prefill_fn(p, *a):
-            pools, (ids, page_row, length, *state) = a[:n], a[n:]
+            pools, (ids, *page_rows, length), state = \
+                a[:n], a[n:n + G + 2], a[n + G + 2:]
             # (a model with recurrent state hands out its state before each
             # cut too, for the rows ``_state_arg`` names)
             more = {"cuts": Tensor(state[0][None, 1:3])} if state else {}
@@ -609,10 +639,11 @@ class Engine:
                                    Tensor(ids),
                                    lengths=Tensor(length[None]), **more)
             return (logits,) + _write_prompt_paged(
-                cache, pools, kvs, page_row, *(s[3:] for s in state))
+                cache, pools, kvs, page_rows, *(s[3:] for s in state))
 
         args = (self.params, *self.cache.pools,
-                jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
+                jnp.zeros((1, T), jnp.int32),
+                *(jnp.zeros((nb,), jnp.int32) for _ in range(G)),
                 jnp.int32(1)) + self._state_arg()
         return paged_prefill_fn, args
 
@@ -652,15 +683,16 @@ class Engine:
         ``donate_argnums_of``), then the pools."""
         model, cache = self.model, self.cache
         B, nb = self.config.max_batch_size, self.cache.num_blocks
-        n = len(cache.pools)
+        n, G = len(cache.pools), len(cache.groups)
 
         @jax.named_scope("serving/decode")
         def paged_decode_fn(p, *a):
-            pools = a[:n]
-            page_table, tokens, positions, temps, top_ks, greedy, key = a[n:]
+            pools, tables = a[:n], a[n:n + G]
+            tokens, positions, temps, top_ks, greedy, key = a[n + G:]
+            page_table = tables[0]
             logits, new, stats = _call(
                 model, p, "decode_step", Tensor(tokens),
-                cache.layer_entries(pools, page_table), Tensor(positions))
+                cache.layer_entries(pools, tables), Tensor(positions))
             nxt = _sampling.sample_batched(logits, key, temps, top_ks,
                                            greedy).astype(jnp.int32)
             # the next step's tokens and positions, kept on the device: a
@@ -668,7 +700,14 @@ class Engine:
             # moves on one position; a dead slot stays at token 0, position
             # 0, where the host's mirrors have it (and where the paged
             # attend's loop over a slot's pages makes no trip)
-            live = page_table[:, 0] != PAGE_SENTINEL
+            if cache.groups[0][1] is None:
+                live = page_table[:, 0] != PAGE_SENTINEL
+            else:
+                # a window group maps no first block once a slot has moved
+                # on: the block of its position says whether it lives
+                live = jnp.take_along_axis(page_table, jnp.minimum(
+                    positions // cache.page_size, nb - 1)[:, None],
+                    axis=1)[:, 0] != PAGE_SENTINEL
             next_tokens = jnp.where(live, nxt, 0).astype(tokens.dtype)
             next_positions = positions + live.astype(positions.dtype)
             if stats is not None:
@@ -679,7 +718,7 @@ class Engine:
             return (nxt, next_tokens, next_positions) + _updated(cache, new)
 
         args = (self.params, *self.cache.pools,
-                jnp.zeros((B, nb), jnp.int32),
+                *(jnp.zeros((B, nb), jnp.int32) for _ in range(G)),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                 jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
                 jnp.ones((B,), bool), _dummy_key())
@@ -696,10 +735,12 @@ class Engine:
         real suffix token's logits come back for the first sampled token."""
         model, cache = self.model, self.cache
         nb, n = self.cache.num_blocks, len(self.cache.pools)
+        G = len(cache.groups)
 
         @jax.named_scope("serving/extend")
         def extend_fn(p, *a):
-            pools, (ids, page_row, start, length, *state) = a[:n], a[n:]
+            pools, (ids, *page_rows, start, length), state = \
+                a[:n], a[n:n + G + 3], a[n + G + 3:]
             # a model with recurrent state starts from the row it is told,
             # has to know which tokens are padding, and writes its state
             # before each cut and at the end where told (``_state_arg``)
@@ -707,7 +748,7 @@ class Engine:
                     "cuts": Tensor(state[0][None, 1:3])} if state else {}
             lv, new, _ = _call(                     # logits [1, T, V]
                 model, p, "extend_step", Tensor(ids),
-                cache.layer_entries(pools, page_row[None, :],
+                cache.layer_entries(pools, [r[None, :] for r in page_rows],
                                     *((s[0], s[3:]) for s in state)),
                 Tensor(start[None]), **more)
             idx = jnp.clip(length - 1, 0, T - 1)
@@ -715,7 +756,8 @@ class Engine:
             return (last[None],) + _updated(cache, new)  # [1, V], like prefill
 
         args = (self.params, *self.cache.pools,
-                jnp.zeros((1, T), jnp.int32), jnp.zeros((nb,), jnp.int32),
+                jnp.zeros((1, T), jnp.int32),
+                *(jnp.zeros((nb,), jnp.int32) for _ in range(G)),
                 jnp.int32(0), jnp.int32(1)) + self._state_arg()
         return extend_fn, args
 
@@ -796,8 +838,8 @@ class Engine:
         step."""
         if kind != "decode":
             return self.donate_argnums
-        n = len(self.cache.pools)
-        return self.donate_argnums + (n + 2, n + 3)
+        n = len(self.cache.pools) + len(self.cache.groups)
+        return self.donate_argnums + (n + 1, n + 2)
 
     def _held(self, *key):
         """The executable of program ``key`` (``("decode",)``,
@@ -872,11 +914,6 @@ class Engine:
             self._exe[key] = exe
         return keys
 
-    def _pages_needed(self, prompt_len: int) -> int:
-        """Pages covering positions [0, prompt_len] — prompt plus the slot
-        the first decode step writes into."""
-        return prompt_len // self.cache.page_size + 1
-
     def _admit(self) -> int:
         """Admit waiting requests while slots are free; returns how many
         (each emits its first token)."""
@@ -914,13 +951,16 @@ class Engine:
         ps = self.cache.page_size
         with _span("serving/admit", request_id=req.request_id,
                    prompt_tokens=n) as adm:
-            hit_blocks, hit_pages = 0, []
+            hit_blocks, splice, hit_pages = 0, 0, [[]]
             if self.prefix_cache is not None:
                 with _span("serving/admit/match",
                            request_id=req.request_id):
-                    hit_blocks, hit_pages = \
-                        self.prefix_cache.match(req.prompt_ids)
-            splice, source, cuts = hit_blocks, None, []
+                    # (a model with window groups resumes where their
+                    # pages still reach, ``splice``, not where the match
+                    # ends: the rest runs again)
+                    hit_blocks, splice, hit_pages = \
+                        self.prefix_cache.match_groups(req.prompt_ids)
+            source, cuts = None, []
             if self.snapshot_alloc is not None:
                 splice, source = self.prefix_cache.deepest_snapshot(
                     req.prompt_ids, hit_blocks)
@@ -937,12 +977,11 @@ class Engine:
                     # hold the snapshot to resume from through the
                     # evictions below
                     self.snapshot_alloc.retain([source], owner=owner)
-                need = self._pages_needed(n) - splice
-                pages = self.page_alloc.alloc(need, owner=owner)
-                if pages is None and self.prefix_cache is not None:
-                    # pool short: reclaim cold cached prefixes, retry
-                    evicted = self.prefix_cache.evict_lru(need)
-                    pages = self.page_alloc.alloc(need, owner=owner)
+                # per page group, the blocks spliced from the trie and
+                # the blocks mapped fresh
+                plan = self._page_plan(n, hit_blocks, splice)
+                pages, evicted = self._alloc_groups(
+                    [len(fresh) for _, _, fresh in plan], owner)
                 taken, dropped = [], 0
                 if pages is not None and cuts:
                     before = self.prefix_cache.snapshots_dropped
@@ -951,8 +990,8 @@ class Engine:
                     # snapshots that left their nodes to make room
                     dropped = self.prefix_cache.snapshots_dropped - before
                 if pages is None or taken is None:
-                    if pages is not None:
-                        self.page_alloc.free(pages, owner=owner)
+                    for page_alloc, got in zip(self.page_allocs, pages or ()):
+                        page_alloc.free(got, owner=owner)
                     if source is not None:
                         self.snapshot_alloc.free([source], owner=owner)
                     alloc.set(pages=0, evicted=evicted, blocked=1)
@@ -961,21 +1000,31 @@ class Engine:
                 self.scheduler.next_waiting()  # pops the peeked head
                 slot = self.cache.alloc_slot()
                 req.slot = slot
-                if hit_pages:
-                    req.prefix_hit_blocks = hit_blocks
-                if splice:
-                    # the SPLICE: this request becomes one more sharer of
-                    # the matched blocks' physical pages — a refcount bump
-                    # and a table-row write, no device work for the prefix
-                    self.page_alloc.retain(hit_pages[:splice], owner=owner)
-                    self.cache.assign_pages(slot, hit_pages[:splice])
-                self.cache.assign_pages(slot, pages, start_block=splice)
-                alloc.set(pages=len(pages), evicted=evicted)
+                if hit_pages[0]:
+                    req.prefix_hit_blocks = splice if self._windows \
+                        else hit_blocks
+                for g, ((lo, hi, fresh), got) in enumerate(zip(plan, pages)):
+                    if hi > lo:
+                        # the SPLICE: this request becomes one more sharer
+                        # of the matched blocks' physical pages — a refcount
+                        # bump and a table-row write, no device work for
+                        # the prefix
+                        self.page_allocs[g].retain(hit_pages[g][lo:hi],
+                                                   owner=owner)
+                        self.cache.assign_pages(slot, hit_pages[g][lo:hi],
+                                                start_block=lo, group=g)
+                    self.cache.assign_at(slot, fresh, got, group=g)
+                alloc.set(pages=len(pages[0]), evicted=evicted)
             adm.set(queued_s=req.admit_time - req.arrival_time,
                     hit_blocks=hit_blocks)
             if self._stateful:
                 adm.set(snapshot_blocks=splice,
                         recomputed_tokens=(hit_blocks - splice) * ps)
+            if self._windows:
+                cut = (hit_blocks - splice) * ps
+                adm.set(resume_blocks=splice, recomputed_tokens=cut)
+                self.resume_cut_tokens += cut
+                _metrics.counter("serving.prefix.resume_cut_tokens", cut)
             if self.prefix_cache is not None:
                 if hit_blocks:
                     _metrics.counter("serving.prefix.hits", 1)
@@ -988,7 +1037,7 @@ class Engine:
             # resumes from and whichever snapshots it takes
             snaps = list(zip(cuts, taken))
             logits = self._run_prompt(req, slot, splice * ps, n, source,
-                                      snaps)
+                                      snaps, plan[0][1])
             if source is not None:
                 # its reader is enqueued: the hold on the snapshot goes
                 with _span("serving/admit/restore",
@@ -1008,7 +1057,9 @@ class Engine:
                     # program wrote
                     self.prefix_cache.insert(
                         req.prompt_ids,
-                        self.cache.slot_pages(slot)[:n // ps])
+                        self.cache.slot_pages(slot)[:n // ps],
+                        [self.cache.page_tables[g][slot, :n // ps]
+                         for g in range(1, len(self.page_allocs))])
                     for block, snap in snaps:
                         with _span("serving/snapshot",
                                    request_id=req.request_id, blocks=block,
@@ -1022,6 +1073,10 @@ class Engine:
                 tok = int(np.asarray(_sampling.sample_static(
                     logits, key, do_sample=sp.do_sample,
                     temperature=sp.temperature, top_k=sp.top_k))[0])
+            if self._windows:
+                # what the trie was handed stays with it; the slot keeps
+                # the window before its next token
+                self._slide([(slot, n, owner)])
         # the first token's time is the end of its serving/admit span
         req.first_token_time = time.perf_counter()
         _metrics.histogram("serving.prefill.seconds", adm.seconds)
@@ -1041,9 +1096,101 @@ class Engine:
         self._maybe_finish(req, tok)
         return True
 
+    def _page_plan(self, n: int, hit: int, splice: int):
+        """Per page group ``(lo, hi, fresh)`` for a prompt of ``n`` tokens
+        that the trie matched ``hit`` blocks of and that resumes at block
+        ``splice``: the blocks ``[lo, hi)`` are spliced from the trie, the
+        blocks ``fresh`` mapped to pages of the request's own.
+
+        A group that keeps every token splices ``[0, splice)`` and maps
+        the rest up to the block of the first decode step, ``n // ps``. In
+        a model with window groups a prompt that must start over (``splice``
+        0) still SHARES such a group's matched pages, ``[0, hit)``: its
+        prefill writes nothing there (``_run_prompt``'s ``shared``).
+
+        A window group splices the window before the resume point; behind
+        it an extend maps every block it writes (it reads them back through
+        the table: they go when it has run, ``_slide``); a prefill, whose
+        keys never pass through the pool, maps only what is wanted
+        afterwards: the blocks a resume at the prompt's end looks back on
+        (the live window is among them) and, where the prompt left a
+        cached path it could not resume (``hit`` > 0), the window before
+        that point, for the trie (``insert``), so that the next prompt
+        that parts there can."""
+        ps = self.cache.page_size
+        last = n // ps
+        plan = []
+        for _, window, _ in self.cache.groups:
+            if not window:
+                hi = hit if self._windows and not splice else splice
+                plan.append((0, hi, list(range(hi, last + 1))))
+                continue
+            back = (window + ps - 2) // ps
+            if splice:
+                fresh = range(splice, last + 1)
+            else:
+                fresh = sorted(set(range(max(0, last - back), last + 1))
+                               | set(range(max(0, hit - back), hit)))
+            plan.append((max(0, splice - back), splice, list(fresh)))
+        return plan
+
+    def _alloc(self, group: int, k: int, owner: str):
+        """``k`` fresh pages of a page group: ``(pages or None, trie nodes
+        evicted)``. Where the pool is short the least recently used cached
+        prefixes are reclaimed first, and the allocation tried again."""
+        page_alloc = self.page_allocs[group]
+        pages, evicted = page_alloc.alloc(k, owner=owner), 0
+        if pages is None and self.prefix_cache is not None:
+            evicted = self.prefix_cache.evict_lru(k, group)
+            pages = page_alloc.alloc(k, owner=owner)
+        return pages, evicted
+
+    def _alloc_groups(self, need: Sequence[int], owner: str):
+        """``need[g]`` fresh pages of each page group, all or nothing:
+        ``(pages by group, trie nodes evicted)``; where a pool is short the
+        least recently used cached prefixes go first (``evict_lru``), and
+        ``(None, evicted)`` (nothing held) where that is not enough."""
+        got, evicted = [], 0
+        for g, k in enumerate(need):
+            pages, gone = self._alloc(g, k, owner)
+            evicted += gone
+            if pages is None:
+                for a, held in zip(self.page_allocs, got):
+                    a.free(held, owner=owner)
+                return None, evicted
+            got.append(pages)
+        return got, evicted
+
+    def _slide(self, slots: Sequence[Tuple[int, int, str]]):
+        """The sliding rule, the one place: for each ``(slot, position of
+        its next token, owner)``, every window group's blocks that lie
+        wholly before ``position - window + 1`` are unmapped from the slot
+        and its reference on their pages dropped (a page the trie holds
+        too lives on there). One ``serving/window/slide`` span, and only
+        where something goes."""
+        ps = self.cache.page_size
+        todo = [(slot, g, first, owner)
+                for slot, pos, owner in slots for g, w in self._windows
+                for first in [max(0, pos - w + 1) // ps]
+                if first > self._win_from[g][slot]]
+        if not todo:
+            return
+        with _span("serving/window/slide", slots=len(todo)) as sp:
+            freed = 0
+            for slot, g, first, owner in todo:
+                pages = self.cache.unmap_before(slot, first, g)
+                self.page_allocs[g].free(pages, owner=owner)
+                self._win_from[g][slot] = first
+                freed += len(pages)
+            sp.set(freed=freed)
+        self.window_pages_freed += freed
+        _metrics.counter("serving.window.pages_freed", freed)
+        _metrics.gauge("serving.window.pages_live", sum(
+            self.page_allocs[g].num_allocated for g, _ in self._windows))
+
     def _run_prompt(self, req: Request, slot: int, start: int, end: int,
                     source: Optional[int] = None,
-                    snaps: Sequence[Tuple[int, int]] = ()):
+                    snaps: Sequence[Tuple[int, int]] = (), shared: int = 0):
         """Tokens ``[start, end)`` of the request's prompt through the
         bucketed prefill program (from position 0) or, behind what the slot
         already holds, the extend program (the suffix-only prefill; >= 1
@@ -1051,7 +1198,11 @@ class Engine:
         last token's logits ``[1, V]``. A model with recurrent state starts
         from snapshot ``source`` and writes its state after ``block`` whole
         blocks to snapshot ``id``'s row, for each ``(block, id)`` of
-        ``snaps``."""
+        ``snaps``. Each page group's row of the slot's goes with it; a
+        prefill over ``shared`` blocks that the slot shares with the trie
+        (a prompt that starts over in a model with window groups) is
+        handed the first group's row WITHOUT them: it writes nothing
+        there."""
         m = end - start
         T = self._bucket(m)
         kind = "extend" if start else "prefill"
@@ -1064,15 +1215,20 @@ class Engine:
             # device (a ``convert_element_type`` run an operand)
             where = (np.int32(start), np.int32(m)) if start \
                 else (np.int32(m),)
+            rows = [t[slot] for t in self.cache.page_tables]
+            if shared and not start:
+                rows[0] = rows[0].copy()
+                rows[0][:shared] = PAGE_SENTINEL
             logits, *self.cache.pools = self._held(kind, T)(
                 self.params, *self.cache.pools, jnp.asarray(ids),
-                jnp.asarray(self.cache.page_table[slot]), *where,
+                *map(jnp.asarray, rows), *where,
                 *self._state_arg(slot, source, [
                     (block * self.cache.page_size - start, snap)
                     for block, snap in snaps]))
         return logits
 
-    def _ensure_writable(self, slot: int, block: int, owner: str) -> bool:
+    def _ensure_writable(self, slot: int, block: int, owner: str,
+                         group: int = 0) -> bool:
         """Copy-on-write guard: a slot about to WRITE ``block`` must own its
         page exclusively. By construction the engine never maps a shared
         page at a position it writes (prefix matching is capped below the
@@ -1081,20 +1237,18 @@ class Engine:
         in the write path, the slot gets a private byte-copy first and
         drops its reference on the original, so the other sharers never
         observe the write. False = no page free for the copy."""
-        page = int(self.cache.page_table[slot, block])
-        if page == PAGE_SENTINEL or not self.page_alloc.is_shared(page):
+        page_alloc = self.page_allocs[group]
+        page = int(self.cache.page_tables[group][slot, block])
+        if page == PAGE_SENTINEL or not page_alloc.is_shared(page):
             return True
-        fresh = self.page_alloc.alloc(1, owner=owner)
-        if fresh is None and self.prefix_cache is not None:
-            self.prefix_cache.evict_lru(1)
-            fresh = self.page_alloc.alloc(1, owner=owner)
+        fresh, _ = self._alloc(group, 1, owner)
         if fresh is None:
             return False
         self._launch_i += 1
-        self.cache.copy_page(page, fresh[0])
+        self.cache.copy_page(page, fresh[0], group)
         self._cow_copies += 1
-        self.cache.repoint(slot, block, fresh[0])
-        self.page_alloc.free([page], owner=owner)
+        self.cache.repoint(slot, block, fresh[0], group)
+        page_alloc.free([page], owner=owner)
         return True
 
     def _grow_pages(self, width: int = 1):
@@ -1107,6 +1261,7 @@ class Engine:
         unblock the next waiting request."""
         ps, S_max = self.cache.page_size, self.config.max_seq_len
         allocated = cache_full = 0
+        moved = []      # (slot, position, owner) of a model with windows
         cow_before = self._cow_copies
         first = self._launch_i + 1  # of the copies' launches, if any
         with _span("serving/decode/grow_pages") as sp:
@@ -1118,28 +1273,32 @@ class Engine:
                 p = int(self._positions[slot])
                 last = min(p + width - 1, S_max - 1)
                 ok = True
-                for block in range(p // ps, last // ps + 1):
-                    if self.cache.page_table[slot, block] == PAGE_SENTINEL:
-                        pages = self.page_alloc.alloc(1, owner=owner)
-                        if pages is None and self.prefix_cache is not None:
-                            self.prefix_cache.evict_lru(1)
-                            pages = self.page_alloc.alloc(1, owner=owner)
-                        if pages is None:
+                for g, table in enumerate(self.cache.page_tables):
+                    for block in range(p // ps, last // ps + 1):
+                        if table[slot, block] == PAGE_SENTINEL:
+                            pages, _ = self._alloc(g, 1, owner)
+                            if pages is None:
+                                ok = False
+                                break
+                            self.cache.assign_pages(slot, pages,
+                                                    start_block=block, group=g)
+                            allocated += 1
+                        elif not self._ensure_writable(slot, block, owner, g):
                             ok = False
                             break
-                        self.cache.assign_pages(slot, pages,
-                                                start_block=block)
-                        allocated += 1
-                    elif not self._ensure_writable(slot, block, owner):
-                        ok = False
+                    if not ok:
                         break
                 if not ok:
                     self._finish(req, "cache_full")
                     cache_full += 1
+                elif self._windows:
+                    moved.append((slot, p, owner))
             sp.set(allocated=allocated, cache_full=cache_full,
                    cow_copies=self._cow_copies - cow_before)
             if self._launch_i >= first:
                 sp.set(launch=first, launches=self._launch_i - first + 1)
+        if moved:
+            self._slide(moved)
 
     def _decode(self) -> int:
         """One batched decode step, as one ``serving/decode`` span over its
@@ -1208,6 +1367,13 @@ class Engine:
                     seen = np.zeros((self.cache.num_pages,), bool)
                     seen[rows[live]] = True
                     sp.set(distinct_pages=int(seen.sum()))
+                if self._windows:
+                    # the window groups' pages that are mapped or cached,
+                    # of those they have
+                    held = [self.page_allocs[g] for g, _ in self._windows]
+                    sp.set(window_pages_live=sum(a.num_allocated
+                                                 for a in held),
+                           window_pages=sum(a.num_allocatable for a in held))
             tokens, step_s = self._tokens, 0.0
             if spec is not None:
                 with _span("serving/decode/propose") as prop:
@@ -1232,7 +1398,7 @@ class Engine:
                 # (a put may alias host memory the mirrors go on changing);
                 # everything else is the array the device already holds
                 table_put = int(self.cache.table_changed)
-                table = self.cache.table_device()
+                tables = self.cache.tables_device()
                 stale = {name: (tokens if name == "tokens"
                                 else getattr(self, "_" + name)).copy()
                          for name in self._stale}
@@ -1241,7 +1407,8 @@ class Engine:
                     self._stale.clear()
                 if up:
                     up.set(puts=len(stale) + table_put, table_put=table_put)
-                args = (table, *(self._dev[name] for name in _OPERANDS), key)
+                args = (*tables, *(self._dev[name] for name in _OPERANDS),
+                        key)
             self._launch_i += 1
             with _span("serving/decode/dispatch",
                        launch=self._launch_i) as disp:
@@ -1341,6 +1508,9 @@ class Engine:
         # double-free (naming page ids and owners), so leaks and corruption
         # can't pass silently. clear_slot is idempotent: a second call
         # returns [] and frees nothing.
-        self.page_alloc.free(self.cache.clear_slot(slot),
-                             owner=f"req{req.request_id}")
+        for g, page_alloc in enumerate(self.page_allocs):
+            page_alloc.free(self.cache.clear_slot(slot, g),
+                            owner=f"req{req.request_id}")
+        for first in self._win_from.values():
+            first[slot] = 0
         self.cache.free_slot(slot)

@@ -81,10 +81,12 @@ def _expand_kv_heads(t, rep: int):
         B, Hkv * rep, S, D)
 
 
-def decode_attend(q, k_cache, v_cache, positions):
+def decode_attend(q, k_cache, v_cache, positions, window=None):
     """Single-position cached attention: q ``[B, H_q, T, D]`` (T=1 in
     decode) against the full static cache ``[B, H_kv, S_max, D]``, masked to
-    the valid prefix ``key_pos <= positions`` (scalar or per-row ``[B]``).
+    the valid prefix ``key_pos <= positions`` (scalar or per-row ``[B]``),
+    with ``window`` to its last ``window`` keys (``key_pos > positions -
+    window``).
 
     Matches _sdpa_ref numerics: q pre-scaled in its own dtype, f32 scores,
     f32 softmax, output cast back to v's dtype.
@@ -105,6 +107,9 @@ def decode_attend(q, k_cache, v_cache, positions):
         valid = key_pos[None, None, None, :] <= pos
     else:
         valid = key_pos[None, None, None, :] <= pos[:, None, None, None]
+    if window is not None:
+        valid = valid & (key_pos[None, None, None, :]
+                         > jnp.reshape(pos, (-1, 1, 1, 1)) - window)
     s = jnp.where(valid, s, _NEG_INF)
     probs = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
@@ -221,9 +226,28 @@ def paged_gather(pool, page_table):
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, nb * ps, D)
 
 
-def paged_decode_attend(q, k_pool, v_pool, page_table, positions):
+def window_blocks(page_table, start, page_size: int, window: int, T: int):
+    """What an extend of ``T`` tokens at ``start [B]`` reads of a sliding
+    layer's pools: ``(first [B], sub [B, n])``, the sequence position of
+    the view's first token and the table entries of the blocks from the one
+    that holds ``start - window + 1`` to the one that holds ``start + T -
+    1`` (``n`` is static: blocks past the table's end read as sentinels).
+    ``paged_gather(pool, sub)`` is then the window and the new tokens, not
+    the whole table's view."""
+    nb = page_table.shape[1]
+    back = (window + page_size - 2) // page_size
+    n = back + (T + page_size - 2) // page_size + 1
+    fb = jnp.maximum(start // page_size - back, 0)
+    blocks = fb[:, None] + jnp.arange(n, dtype=fb.dtype)[None, :]
+    sub = jnp.take_along_axis(page_table, jnp.minimum(blocks, nb - 1), axis=1)
+    return fb * page_size, jnp.where(blocks < nb, sub, PAGE_SENTINEL)
+
+
+def paged_decode_attend(q, k_pool, v_pool, page_table, positions,
+                        window=None):
     """Single-position cached attention over block-paged pools — the paged
-    twin of ``decode_attend``, in the tier ``default_paged_impl`` says.
+    twin of ``decode_attend``, in the tier ``default_paged_impl`` says
+    (``window``: a sliding layer's, both tiers the same lower bound).
     ``oracle`` reconstructs the dense caches (``paged_gather``) and runs
     the einsum oracle; ``pallas`` runs the Pallas ragged kernel
     (kernels/paged_attention.py) which touches only live pages. Both tiers
@@ -234,9 +258,13 @@ def paged_decode_attend(q, k_pool, v_pool, page_table, positions):
     if default_paged_impl() == "oracle":
         k = paged_gather(k_pool, page_table)
         v = paged_gather(v_pool, page_table)
-        return decode_attend(q, k, v, positions)
+        return decode_attend(q, k, v, positions) if window is None \
+            else decode_attend(q, k, v, positions, window)
     from ..kernels.paged_attention import paged_attention
 
+    if window is not None:
+        return paged_attention(q, k_pool, v_pool, page_table, positions,
+                               window)
     return paged_attention(q, k_pool, v_pool, page_table, positions)
 
 
@@ -367,13 +395,27 @@ class PagedKVCache:
     (``Engine._state_arg``). ``.pools`` holds the state buffers behind
     the paged ones, each pool a tuple over ITS layers; ``layer_entries`` /
     ``pools_from_layers`` go between that and what one layer is handed.
+
+    Pools stand in GROUPS: a group is the set of pools that share a page
+    count, an allocator and a page table. A pool's declaration names its
+    group as a fifth entry, ``(group name, window)``; without one it
+    stands in the group "global" (``window`` None), which is first in
+    ``.groups`` where it exists, and a model that declares no group builds
+    exactly what it always did. A group with a ``window`` belongs to layers
+    that attend the last ``window`` tokens alone: the engine unmaps and
+    frees such a group's pages behind a slot's window as the slot moves on
+    (``Engine._slide``), so its pool holds a window a slot, not a context.
+    ``num_pages`` sizes the first group, ``group_pages`` ``{name: pages}``
+    the others (default: the full budget); every table writer and reader
+    below takes ``group`` (an index into ``.groups``; default the first),
+    and ``layer_entries`` hands each layer its own group's table.
     """
 
     def __init__(self, num_layers: int, max_batch_size: int,
                  num_kv_heads: int, max_seq_len: int, head_dim: int,
                  dtype="float32", page_size: int = 16,
                  num_pages: Optional[int] = None, pools=None,
-                 state_pools=(), num_snapshots: int = 0):
+                 state_pools=(), num_snapshots: int = 0, group_pages=None):
         if max_seq_len % page_size:
             raise ValueError(
                 f"max_seq_len {max_seq_len} not divisible by page_size "
@@ -385,13 +427,24 @@ class PagedKVCache:
         self.head_dim = head_dim
         self.page_size = page_size
         self.num_blocks = max_seq_len // page_size
+        full = max_batch_size * self.num_blocks + 1
         if num_pages is None:
-            num_pages = max_batch_size * self.num_blocks + 1
+            num_pages = full
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (trash page + 1)")
-        self.num_pages = num_pages
         if pools is None:
             pools = [("k", num_kv_heads, head_dim), ("v", num_kv_heads, head_dim)]
+        # the groups, "global" first: [(name, window, pages)]
+        declared = [tuple(p[4]) if len(p) > 4 else ("global", None)
+                    for p in pools]
+        names = sorted(dict.fromkeys(declared), key=lambda g: g[0] != "global")
+        self.groups = [(str(n), None if w is None else int(w),
+                        num_pages if i == 0
+                        else int((group_pages or {}).get(n, full)))
+                       for i, (n, w) in enumerate(names)]
+        #: the group (index into ``groups``) of each paged pool
+        self.pool_group = [names.index(g) for g in declared]
+        self.num_pages = num_pages
         every = tuple(range(num_layers))
         self.pool_specs = [(str(p[0]), int(p[1]), int(p[2])) for p in pools]
         self.state_specs = [(str(n), tuple(shape), str(dt))
@@ -402,8 +455,10 @@ class PagedKVCache:
         self.num_snapshots = int(num_snapshots)
         rows = max_batch_size + self.num_snapshots
         self._pools = tuple(
-            _layer_buffers(len(layers), (num_pages, h, page_size, w), dtype)
-            for (_, h, w), layers in zip(self.pool_specs, self.pool_layers)
+            _layer_buffers(len(layers),
+                           (self.groups[g][2], h, page_size, w), dtype)
+            for (_, h, w), layers, g in zip(self.pool_specs, self.pool_layers,
+                                            self.pool_group)
         ) + tuple(
             _layer_buffers(len(layers), (rows,) + shape, dt)
             for (_, shape, dt), layers in zip(
@@ -413,15 +468,22 @@ class PagedKVCache:
         self._of_layer = [
             [(j, layers.index(l)) for j, layers in enumerate(self.pool_layers)
              if l in layers] for l in range(num_layers)]
-        self._table = np.full((max_batch_size, self.num_blocks),
-                              PAGE_SENTINEL, np.int32)
-        self.page_table = self._table.view()
-        self.page_table.flags.writeable = False
-        # the table as the device holds it; None once a writer below has
+        # the group whose table a layer is handed: its first paged pool's
+        self._layer_group = [
+            self.pool_group[held[0][0]] if held and held[0][0] < len(
+                self.pool_specs) else 0 for held in self._of_layer]
+        # one table a group; ``page_table`` is the first group's
+        self._tables = [np.full((max_batch_size, self.num_blocks),
+                                PAGE_SENTINEL, np.int32) for _ in self.groups]
+        self.page_tables = [t.view() for t in self._tables]
+        for view in self.page_tables:
+            view.flags.writeable = False
+        self.page_table = self.page_tables[0]
+        # the tables as the device holds them; None once a writer below has
         # changed the host's since it was put
-        self._table_dev: Optional[jax.Array] = None
+        self._table_devs: List[Optional[jax.Array]] = [None] * len(self.groups)
         self._free: List[int] = list(range(max_batch_size))[::-1]
-        self._copy_exe = None
+        self._copy_exes = {}
 
     @property
     def pools(self):
@@ -452,42 +514,69 @@ class PagedKVCache:
         return _tuple_nbytes(*self.pools)
 
     @property
-    def table_changed(self) -> bool:
-        """Whether the next ``table_device()`` transfers the table."""
-        return self._table_dev is None
+    def table_changed(self) -> int:
+        """How many tables the next ``tables_device()`` transfers."""
+        return sum(t is None for t in self._table_devs)
 
-    def table_device(self) -> jax.Array:
-        """The page table as the device operand the compiled decode / verify
-        executables consume: the array kept from the last put while no
-        writer has changed the host table since. The put takes a copy, so a
-        later host write never reaches an array a program may still be
-        reading."""
-        if self._table_dev is None:
-            self._table_dev = jax.device_put(self._table.copy())
-        return self._table_dev
+    def table_device(self, group: int = 0) -> jax.Array:
+        """A group's page table as the device operand the compiled decode /
+        verify executables consume: the array kept from the last put while
+        no writer has changed the host table since. The put takes a copy,
+        so a later host write never reaches an array a program may still
+        be reading."""
+        if self._table_devs[group] is None:
+            self._table_devs[group] = jax.device_put(
+                self._tables[group].copy())
+        return self._table_devs[group]
 
-    # -- host-side table bookkeeping (the scheduler's allocator owns page
+    def tables_device(self) -> Tuple[jax.Array, ...]:
+        """Every group's table, in ``groups``' order."""
+        return tuple(self.table_device(g) for g in range(len(self.groups)))
+
+    # -- host-side table bookkeeping (the scheduler's allocators own page
     #    ids; the cache only records who maps where). Every writer drops
     #    the kept device copy --
-    def assign_pages(self, slot: int, pages: List[int], start_block: int = 0):
-        self._table[slot, start_block:start_block + len(pages)] = pages
-        self._table_dev = None
+    def assign_pages(self, slot: int, pages: List[int], start_block: int = 0,
+                     group: int = 0):
+        self._tables[group][slot, start_block:start_block + len(pages)] = pages
+        self._table_devs[group] = None
 
-    def repoint(self, slot: int, block: int, page: int):
+    def assign_at(self, slot: int, blocks: List[int], pages: List[int],
+                  group: int = 0):
+        """Map ``blocks[i]`` of ``slot`` to ``pages[i]`` (blocks that need
+        not be neighbours: a window group's tails)."""
+        if len(blocks):
+            self._tables[group][slot, blocks] = pages
+            self._table_devs[group] = None
+
+    def repoint(self, slot: int, block: int, page: int, group: int = 0):
         """Map ``block`` of ``slot`` to ``page`` instead (copy-on-write: the
         slot's private copy replaces the shared page)."""
-        self._table[slot, block] = page
-        self._table_dev = None
+        self._tables[group][slot, block] = page
+        self._table_devs[group] = None
 
-    def copy_page_exe(self):
-        """The compiled copy-on-write program: ``(*pools, src, dst) ->
-        pools`` over the donated pool tuples, page ids as runtime scalars,
-        so ONE executable serves every copy and each layer's page moves
-        inside its own buffer, in every pool. Compiled on first use; a
-        caller that must not compile later (the engine, when pages can be
-        shared) asks for it up front."""
-        if self._copy_exe is None:
+    def unmap_before(self, slot: int, block: int, group: int = 0) -> List[int]:
+        """Reset ``slot``'s blocks before ``block`` to sentinels (a window
+        group's pages behind the slot's window); returns the page ids that
+        were mapped there, for the caller to hand back to the allocator."""
+        row = self._tables[group][slot, :block]
+        pages = [int(p) for p in row[row != PAGE_SENTINEL]]
+        if pages:
+            row[:] = PAGE_SENTINEL
+            self._table_devs[group] = None
+        return pages
+
+    def copy_page_exe(self, group: int = 0):
+        """The compiled copy-on-write program of a group: ``(*pools, src,
+        dst) -> pools`` over the donated pool tuples, page ids as runtime
+        scalars, so ONE executable serves every copy and each layer's page
+        moves inside its own buffer, in every pool of the group (another
+        group's pools pass through). Compiled on first use; a caller that
+        must not compile later (the engine, when pages can be shared) asks
+        for it up front."""
+        if group not in self._copy_exes:
             n = len(self.pool_specs)
+            mine = [g == group for g in self.pool_group]
 
             def copy_page_fn(*a):
                 src, dst = a[n:]
@@ -498,21 +587,22 @@ class PagedKVCache:
                         pool, (src, zero, zero, zero), (1,) + pool.shape[1:])
                     return lax.dynamic_update_slice(
                         pool, page, (dst, zero, zero, zero))
-                return tuple(tuple(map(one, pool)) for pool in a[:n])
+                return tuple(tuple(map(one, pool)) if m else tuple(pool)
+                             for pool, m in zip(a[:n], mine))
 
-            self._copy_exe = jax.jit(copy_page_fn,
-                                     donate_argnums=tuple(range(n))) \
+            self._copy_exes[group] = jax.jit(
+                copy_page_fn, donate_argnums=tuple(range(n))) \
                 .lower(*self.pools[:n], jnp.int32(0), jnp.int32(0)).compile()
-        return self._copy_exe
+        return self._copy_exes[group]
 
-    def copy_page(self, src: int, dst: int):
+    def copy_page(self, src: int, dst: int, group: int = 0):
         """Copy-on-write: duplicate page ``src``'s bytes into page ``dst``
-        in every layer of every pool. The caller then repoints its table
-        entry at ``dst`` and drops its reference on ``src`` — the sharer
-        still mapping ``src`` never observes the write that motivated the
-        copy."""
+        in every layer of every pool of the group. The caller then repoints
+        its table entry at ``dst`` and drops its reference on ``src`` — the
+        sharer still mapping ``src`` never observes the write that motivated
+        the copy."""
         n = len(self.pool_specs)
-        self.pools = tuple(self.copy_page_exe()(
+        self.pools = tuple(self.copy_page_exe(group)(
             *self.pools[:n], np.int32(src), np.int32(dst))) + self.pools[n:]
 
     # -- slot state and its snapshots --
@@ -521,17 +611,17 @@ class PagedKVCache:
         ``[1, num_snapshots]``, as a ``PageAllocator`` hands them out)."""
         return self.max_batch_size + snapshot - 1
 
-    def slot_pages(self, slot: int) -> List[int]:
-        row = self.page_table[slot]
+    def slot_pages(self, slot: int, group: int = 0) -> List[int]:
+        row = self.page_tables[group][slot]
         return [int(p) for p in row if p != PAGE_SENTINEL]
 
-    def clear_slot(self, slot: int) -> List[int]:
-        """Reset a slot's table row to sentinels; returns the page ids the
-        caller must hand back to the allocator."""
-        pages = self.slot_pages(slot)
+    def clear_slot(self, slot: int, group: int = 0) -> List[int]:
+        """Reset a slot's table row (of one group) to sentinels; returns the
+        page ids the caller must hand back to that group's allocator."""
+        pages = self.slot_pages(slot, group)
         if pages:
-            self._table[slot, :] = PAGE_SENTINEL
-            self._table_dev = None
+            self._tables[group][slot, :] = PAGE_SENTINEL
+            self._table_devs[group] = None
         return pages
 
     # -- slot free list --
@@ -556,11 +646,15 @@ class PagedKVCache:
         tuples, in the order the model declared its pools: ``where`` is the
         page ``table`` for a layer of paged pools, and for a layer of state
         the ``rows`` its state lives in (``None``: rows ``[0, B)``; else
-        ``(the row read, the rows written)`` of a one-slot extend)."""
+        ``(the row read, the rows written)`` of a one-slot extend).
+        ``table`` is one table, or a tuple of them, one a group."""
         n = len(self.pool_specs)
+        # one table for all, or one a group (in ``groups``' order)
+        of = (lambda l: table[self._layer_group[l]]) \
+            if isinstance(table, (tuple, list)) else (lambda l: table)
         return [tuple(pools[j][i] for j, i in held)
-                + ((table,) if held[0][0] < n else (rows,))
-                for held in self._of_layer]
+                + ((of(l),) if held[0][0] < n else (rows,))
+                for l, held in enumerate(self._of_layer)]
 
     def pools_from_layers(self, per_layer):
         """The pool tuples (by pool, then by its layers) of what every

@@ -37,6 +37,17 @@ snapshot pool itself is short ``reserve_snapshots`` takes them from the
 nodes whose snapshot was least recently used (the nodes and their pages
 stay: they still say where prompts part).
 
+A model whose pools stand in several page GROUPS (``PagedKVCache``: a
+group of its own for the layers that attend a sliding window) gives a node
+a page of EACH group, a window group's only where it is still live: such a
+group's pages behind a slot's window are freed as the slot moves on, and
+the trie keeps the ones it was handed at ``insert`` (the last window of a
+prompt, the window before the point where prompts parted). A cached prefix
+can be RESUMED only at a depth whose preceding window still has its pages
+in every window group (``match_groups``: a document's end, a turn's end);
+a match that reaches deeper is cut back to the deepest such depth and the
+rest recomputed. Evicting a node frees its page in every group.
+
 Flag-gated metrics: the engine counts ``serving.prefix.hits`` /
 ``serving.prefix.misses`` per ADMISSION (a blocked head request peeks the
 trie every step; counting in ``match`` would inflate hits), and this
@@ -60,12 +71,16 @@ class _Node:
     repr/debugging), the physical ``page`` holding that block's K/V, and an
     LRU stamp. Children are keyed by the NEXT block's token tuple."""
 
-    __slots__ = ("key", "page", "last_used", "children", "parent",
+    __slots__ = ("key", "page", "more", "last_used", "children", "parent",
                  "snapshot", "snapshot_used")
 
     def __init__(self, key: Tuple[int, ...], page: int, parent: "_Node"):
         self.key = key
         self.page = page
+        # the block's page in each further group (None where it has none:
+        # a window group's page the trie was never handed); None where
+        # there is one group
+        self.more: Optional[List[Optional[int]]] = None
         self.snapshot: Optional[int] = None  # id of the state as of this block
         self.snapshot_used = 0  # when it was attached or last resumed from
         self.last_used = 0
@@ -85,11 +100,14 @@ class PrefixCache:
     """
 
     def __init__(self, page_size: int, allocator: PageAllocator,
-                 snapshots: Optional[PageAllocator] = None):
+                 snapshots: Optional[PageAllocator] = None,
+                 more: Sequence[Tuple[PageAllocator, Optional[int]]] = ()):
         if page_size < 1:
             raise ValueError(f"page_size {page_size}")
         self.page_size = page_size
         self.allocator = allocator
+        #: the further page groups' (allocator, window in tokens or None)
+        self.more = list(more)
         #: the snapshot rows' allocator (a model with recurrent state), and
         #: the nodes that carry one
         self.snapshots = snapshots
@@ -127,17 +145,50 @@ class PrefixCache:
         >= 1 suffix token to run (the prefill programs produce the first
         token's logits) and never maps a shared page it would write.
         """
+        path = self._matched(prompt)
+        return len(path), [node.page for node in path]
+
+    def _matched(self, prompt: Sequence[int]) -> List[_Node]:
+        """The nodes of ``match``'s prefix, each stamped as used now."""
         cap = max(0, (len(prompt) - 1) // self.page_size)
-        node, pages = self._root, []
+        node, path = self._root, []
         stamp = next(self._clock)
         for key in self._blocks(prompt, cap):
-            child = node.children.get(key)
-            if child is None:
+            node = node.children.get(key)
+            if node is None:
                 break
-            child.last_used = stamp
-            pages.append(child.page)
-            node = child
-        return len(pages), pages
+            node.last_used = stamp
+            path.append(node)
+        return path
+
+    def match_groups(self, prompt: Sequence[int]
+                     ) -> Tuple[int, int, List[List[int]]]:
+        """``match`` for several page groups: ``(hit_blocks, resume_blocks,
+        pages)``. ``hit_blocks`` is how far the prompt's blocks are cached
+        (the first group's pages reach that far); ``resume_blocks <=
+        hit_blocks`` the deepest depth a request can RESUME at: in every
+        window group each of the blocks that hold the ``window - 1`` tokens
+        before it still has its page (depth 0 always can: nothing precedes
+        it). ``pages[g][j]`` backs block ``j`` in group ``g`` (``g = 0`` the
+        first group, as far as ``hit_blocks``; a further group as far as
+        ``resume_blocks``, -1 where the node has none: before the window)."""
+        path = self._matched(prompt)
+        hit, first = len(path), [node.page for node in path]
+        ps = self.page_size
+        resume = hit
+        for g, (_, window) in enumerate(self.more):
+            if window is None:
+                continue
+            back = (window + ps - 2) // ps    # blocks a depth looks back on
+            run, ok = 0, 0      # consecutive nodes with a page, up to here
+            for d, node in enumerate(path[:resume], 1):
+                run = run + 1 if node.more[g] is not None else 0
+                if run >= min(d, back):
+                    ok = d
+            resume = ok
+        return hit, resume, [first] + [
+            [-1 if n.more[g] is None else n.more[g] for n in path[:resume]]
+            for g in range(len(self.more))]
 
     def _path(self, prompt: Sequence[int], depth: int) -> List[_Node]:
         """The nodes of ``prompt``'s first ``depth`` blocks, as far as the
@@ -216,12 +267,16 @@ class PrefixCache:
 
     # ------------------------------------------------------------- insert
 
-    def insert(self, prompt: Sequence[int], pages: Sequence[int]) -> int:
+    def insert(self, prompt: Sequence[int], pages: Sequence[int],
+               more: Sequence[Sequence[int]] = ()) -> int:
         """Record that ``pages[j]`` holds block ``j`` of ``prompt``'s K/V.
         Blocks already present keep their existing page (the inserting
         request's duplicate stays private to it and frees at its finish);
-        new nodes take a trie-owned reference on their page. Returns the
-        number of NEW nodes created."""
+        new nodes take a trie-owned reference on their page. ``more[g][j]``
+        is the block's page in further group ``g`` (-1: the request holds
+        none there): a node, new or not, that has none yet takes it, with a
+        reference of the trie's own. Returns the number of NEW nodes
+        created."""
         blocks = self._blocks(prompt)
         n = min(len(blocks), len(pages))
         node, created = self._root, 0
@@ -233,11 +288,17 @@ class PrefixCache:
                 page = int(pages[j])
                 self.allocator.retain([page], owner=_OWNER)
                 child = _Node(key, page, node)
+                if self.more:
+                    child.more = [None] * len(self.more)
                 node.children[key] = child
                 self._leaf_set.discard(node)
                 self._leaf_set.add(child)
                 self.num_nodes += 1
                 created += 1
+            for g, row in enumerate(more):
+                if child.more[g] is None and j < len(row) and row[j] >= 0:
+                    self.more[g][0].retain([int(row[j])], owner=_OWNER)
+                    child.more[g] = int(row[j])
             child.last_used = stamp
             node = child
         self._export_gauges()
@@ -256,17 +317,22 @@ class PrefixCache:
             self._leaf_set.add(parent)
         self.num_nodes -= 1
         self.allocator.free([node.page], owner=_OWNER)
+        for (alloc, _), page in zip(self.more, node.more or ()):
+            if page is not None:
+                alloc.free([page], owner=_OWNER)
         if node.snapshot is not None:
             self._drop_snapshot(node, True)
 
-    def evict_lru(self, need_free: int) -> int:
-        """Release least-recently-used leaves until the allocator has
+    def evict_lru(self, need_free: int, group: int = 0) -> int:
+        """Release least-recently-used leaves until the allocator (of page
+        group ``group``: 0 the first, ``g + 1`` further group ``g``) has
         ``need_free`` free pages or nothing evictable remains. Evicting a
         node drops only the TRIE's reference — a page still mapped by a
         live request stays allocated until that request finishes — so this
         keeps going past still-shared pages. Returns nodes evicted."""
         evicted = 0
-        while self.allocator.num_free < need_free:
+        allocator = self.more[group - 1][0] if group else self.allocator
+        while allocator.num_free < need_free:
             leaves = self._leaves()
             if not leaves:
                 break

@@ -85,10 +85,13 @@ class PageAllocator:
     ``serving.kv.page_utilization`` when FLAGS_observability is on.
     """
 
-    def __init__(self, num_pages: int):
+    def __init__(self, num_pages: int, group: Optional[str] = None):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (trash page + 1)")
         self.num_pages = num_pages
+        #: the page group it serves, where a cache has several: its gauges
+        #: carry ``group=<name>`` (None: the one allocator there always was)
+        self.group = group
         # pop() from the tail hands out the lowest free id first
         self._free: List[int] = list(range(num_pages - 1, 0, -1))
         self._refs: Dict[int, int] = {}
@@ -173,10 +176,12 @@ class PageAllocator:
     def _export_gauges(self):
         if not _metrics.enabled():
             return
-        _metrics.gauge("serving.kv.pages.allocated", len(self._refs))
-        _metrics.gauge("serving.kv.pages.free", len(self._free))
+        labels = {} if self.group is None else {"group": self.group}
+        _metrics.gauge("serving.kv.pages.allocated", len(self._refs), **labels)
+        _metrics.gauge("serving.kv.pages.free", len(self._free), **labels)
         _metrics.gauge("serving.kv.page_utilization",
-                       len(self._refs) / max(1, self.num_allocatable))
+                       len(self._refs) / max(1, self.num_allocatable),
+                       **labels)
 
 
 class Scheduler:

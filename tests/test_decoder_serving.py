@@ -635,7 +635,7 @@ def _drive(family, m, second, eos, mark_all=False):
     while eng.has_unfinished:
         if mark_all:
             eng._stale.update(_OPERANDS)
-            eng.cache._table_dev = None
+            eng.cache._table_devs = [None] * len(eng.cache.groups)
         slot = reqs[3].slot
         if shared is None and slot is not None:
             # someone else takes a reference on the page the prefix-hit

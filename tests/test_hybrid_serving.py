@@ -706,5 +706,5 @@ class TestDescription:
         assert 1e-3 <= float(dt.min()) and float(dt.max()) <= 0.1 + 1e-6
         c = initial_value(pre + "conv.weight", (64, 4), key, 0.02)
         assert 0.4 < float(jnp.abs(c).max()) <= 0.5
-        assert set(ATTENTIONS) == {"dense", "indexed_sparse", "gated_delta",
-                                   "latent"}
+        assert set(ATTENTIONS) == {"dense", "sliding", "indexed_sparse",
+                                   "gated_delta", "latent"}

@@ -424,7 +424,7 @@ class TestPoolsUpdatedInPlace:
                                      speculative=2))
         # pages can be shared, so the copy program was compiled with the
         # engine: a copy on write never compiles between two decode steps
-        copy_exe = eng.cache._copy_exe
+        copy_exe = eng.cache._copy_exes.get(0)
         assert copy_exe is not None
         got = eng.generate(prompts[:1], sp)
         reqs = [eng.add_request(p, sp) for p in prompts[1:]]
