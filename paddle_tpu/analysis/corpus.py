@@ -175,7 +175,7 @@ def _serving_specs() -> List[ProgramSpec]:
                         donate_argnums=eng.donate_argnums_of("decode")),
                     argnames=("params", "k_pages", "v_pages", "page_table",
                               "tokens", "positions", "temps", "top_ks",
-                              "greedy", "key"),
+                              "greedy", "host_tokens", "key"),
                     sharding=eng.sharding_contract(len(dec_args))),
         ProgramSpec("serving_verify", ver_fn, ver_args, contract,
                     argnames=("params", "k_pages", "v_pages", "page_table",

@@ -23,8 +23,10 @@ entries) -> ``kv_cache.paged_write_kv`` and the attend, where
   greedy/sampled requests never triggers a recompile. Its operands stay
   on the device from one step to the next: the program puts out the next
   step's tokens and positions itself, and the host puts an operand again
-  only when something other than the step changed its host mirror
-  (``Engine._decode``).
+  only when something other than the step changed its host mirror. The
+  step is launched one step AHEAD of its fetch: ``step()`` k launches
+  program k and then fetches and settles program k - 1, so the device
+  never waits for the host between two decode steps (``Engine._decode``).
 - **verify** — decode widened to ``[B_max, k+1]``: what the engine's one
   host decode step (``Engine._decode``) runs instead under speculation.
 
@@ -82,11 +84,25 @@ from .speculative import SpeculativeConfig, accept_greedy, propose_ngram
 KV_DONATE_ARGNUMS = (1, 2)
 
 #: the decode step's per-slot operands, in the programs' argument order
-#: behind the page table. Each has a host mirror (``Engine._<name>``, the
-#: authority) and a kept device array (``Engine._dev[<name>]``); a name in
-#: ``Engine._stale`` says the mirror was changed by something other than
-#: the decode program since the array was put.
+#: behind the page table. Each has a host mirror (``Engine._<name>``) and a
+#: kept device array (``Engine._dev[<name>]``); a name in ``Engine._stale``
+#: says the mirror was changed by something other than the decode program
+#: since the array was put, and the next upload puts it whole.
+#:
+#: The mirror is the authority for the last four (``_HOST_OPERANDS``): the
+#: host knows them for the step it is about to launch, the positions
+#: because their mirror moves when a step is LAUNCHED. ``tokens`` is not
+#: the host's to put once a plain engine runs: the step before is still in
+#: flight when the next goes out (``Engine._decode``), so ``_tokens`` lags
+#: the device by a step for every row that ran in it, and the carried
+#: device array is the authority. What the host does know, the first token
+#: of a slot it admitted and the 0 of one it finished, travels as the
+#: ``host_tokens`` operand (``_tokens`` where ``Engine._from_host`` is set,
+#: -1 elsewhere) that the decode program selects from in graph. Only a
+#: speculative engine, which settles every step before the next, puts
+#: ``tokens`` (its ``[B, k+1]`` block) from the mirror every step.
 _OPERANDS = ("tokens", "positions", "temps", "top_ks", "greedy")
+_HOST_OPERANDS = _OPERANDS[1:]
 
 #: the ``jit.compile.*{site=}`` each kind of program accounts under. The
 #: verify program REPLACES the plain decode step while speculation is on,
@@ -364,6 +380,23 @@ class _SlotState:
         self.request = request
 
 
+@dataclass
+class _Flight:
+    """A decode step that was launched and not yet settled: what the host
+    fetches (``out``; ``sampled0`` and ``drafts`` beside it under
+    speculation), the number of its launch, the ``(request, slot)`` of every
+    row the program runs for, the ``serving/decode`` attributes that
+    describe it, and the seconds its launch took the host."""
+
+    launch: int
+    out: jax.Array
+    rows: List[Tuple[Request, int]]
+    attrs: Dict
+    seconds: float
+    sampled0: Optional[jax.Array] = None
+    drafts: Optional[Dict[int, List[int]]] = None
+
+
 class Engine:
     """Offline/online LLM serving engine over a cache-aware causal LM.
 
@@ -381,8 +414,10 @@ class Engine:
     Request flow: ``add_request`` queues; each ``step()`` first admits
     waiting requests into any free KV-cache slots (prefill + first token —
     continuous batching: admission happens the moment a slot frees, between
-    decode steps), then runs ONE batched decode step for every running
-    request. All serving metrics are flag-gated through
+    decode steps), then LAUNCHES one batched decode step for every running
+    request and settles the step the call before launched: ``step()``
+    returns step N - 1's tokens while step N runs (``step``, ``_decode``).
+    All serving metrics are flag-gated through
     ``paddle_tpu.observability`` (see serving/README.md for the names).
     """
 
@@ -473,15 +508,25 @@ class Engine:
         self._slots: List[_SlotState] = [_SlotState() for _ in range(B)]
         # vectorized per-slot decode state: the host mirrors of _OPERANDS,
         # the arrays the device holds of them, and which mirrors changed
-        # since (admission and finish mark all five; the plain decode step
-        # advances tokens and positions on both sides and marks nothing)
+        # since (admission and finish mark the four the host is the
+        # authority for and name the slot in ``_from_host``; the plain
+        # decode step advances tokens and positions on both sides and marks
+        # nothing)
         self._tokens = np.zeros((B,), np.int32)
         self._positions = np.zeros((B,), np.int32)
         self._temps = np.ones((B,), np.float32)
         self._top_ks = np.zeros((B,), np.int32)
         self._greedy = np.ones((B,), bool)
-        self._dev: Dict[str, jax.Array] = {}
         self._stale = set(_OPERANDS)
+        # slots whose next token the host decided (``_OPERANDS``), and the
+        # ``host_tokens`` row that says "none": what a step is handed
+        # behind no admission or finish
+        self._from_host = np.zeros((B,), bool)
+        self._no_host_tokens = jax.device_put(np.full((B,), -1, np.int32))
+        self._dev: Dict[str, jax.Array] = {
+            "host_tokens": self._no_host_tokens}
+        # the plain decode step that was launched and not yet fetched
+        self._flight: Optional[_Flight] = None
         self._exe: Dict = {}
         self._step_i = 0  # engine steps so far: the spans' ``step``
         # calls of a compiled executable so far (an eager stretch counts
@@ -493,6 +538,13 @@ class Engine:
         # row greedy) / drew as well (``sampling.sample_batched``)
         self.sampler_steps_argmax = 0
         self.sampler_steps_draw = 0
+        # decode steps launched with the step before still unfetched / with
+        # nothing in flight (the first behind an empty engine or a short
+        # pool, every speculative step); rows a step ran for a request that
+        # had finished by the time it was settled
+        self.steps_ahead = 0
+        self.steps_drained = 0
+        self.dropped_rows = 0
         self.prefix_cache: Optional[PrefixCache] = None
         if self.config.prefix_cache:
             self.prefix_cache = PrefixCache(
@@ -585,7 +637,10 @@ class Engine:
             raise ValueError("len(sampling) != len(prompts)")
         reqs = [self.add_request(p, sp) for p, sp in zip(prompts, sampling)]
         with _span("serving/generate", requests=len(reqs)) as drain:
-            while self.scheduler.has_unfinished:
+            # (one call more where the last finish was an ``eos``: the step
+            # launched beside it is fetched and dropped, nothing is left in
+            # flight for the caller)
+            while self.scheduler.has_unfinished or self._flight is not None:
                 self.step()
         total = sum(r.num_generated for r in reqs)
         if drain.seconds > 0:
@@ -595,9 +650,21 @@ class Engine:
     # -- engine loop --
     def step(self):
         """One scheduler iteration: admit waiting requests into free slots
-        (bucketed prefill + first token each), then one batched decode step
-        over every running request. The whole of it is one ``serving/step``
-        span whose children are the phases (serving/README.md lists them)."""
+        (bucketed prefill + first token each), then LAUNCH one batched
+        decode step over every running request and settle the step the call
+        before launched (``_decode``). The whole of it is one
+        ``serving/step`` span whose children are the phases
+        (serving/README.md lists them).
+
+        What a caller sees: a call returns decode step N - 1's tokens while
+        step N runs on the device. The call that admits a request shows its
+        prefill's token alone; a request that needs n decode steps is
+        finished after n + 1 calls; a token is visible when the device has
+        made it, as it always was. When ``has_unfinished`` turns false on an
+        ``eos`` a step may still be in flight: its rows belong to finished
+        requests, and the next call fetches and drops it (``generate``
+        makes that call itself). A speculative engine (``self.spec``)
+        launches, fetches and settles each verify step in one call."""
         self._step_i += 1
         with _span("serving/step", step=self._step_i,
                    running=len(self.scheduler.running),
@@ -676,6 +743,16 @@ class Engine:
         (tests pin the compile counter), and the paged attend reads each
         slot's live pages out of the pools.
 
+        Operands behind the tables: the ``_OPERANDS`` (the carried
+        ``tokens`` first), then ``host_tokens [B]`` int32, then the key. A
+        row of ``host_tokens`` that is >= 0 REPLACES the carried token, in
+        graph: the first token of a slot the host admitted, the 0 of one it
+        finished. The host may not know the other rows' tokens (the step
+        that makes them can still be in flight, ``Engine._decode``), so it
+        never puts ``tokens`` whole; on a step behind no admission or
+        finish ``host_tokens`` is the engine's one kept array of -1 and
+        nothing is put.
+
         Outputs: the array the host fetches (the sampled tokens, a model's
         ``step_stats`` behind them), then the NEXT step's ``tokens`` and
         ``positions`` (``Engine._decode`` keeps them on the device and hands
@@ -688,8 +765,11 @@ class Engine:
         @jax.named_scope("serving/decode")
         def paged_decode_fn(p, *a):
             pools, tables = a[:n], a[n:n + G]
-            tokens, positions, temps, top_ks, greedy, key = a[n + G:]
+            tokens, positions, temps, top_ks, greedy, host_tokens, key = \
+                a[n + G:]
             page_table = tables[0]
+            tokens = jnp.where(host_tokens >= 0,
+                               host_tokens.astype(tokens.dtype), tokens)
             logits, new, stats = _call(
                 model, p, "decode_step", Tensor(tokens),
                 cache.layer_entries(pools, tables), Tensor(positions))
@@ -721,7 +801,8 @@ class Engine:
                 *(jnp.zeros((B, nb), jnp.int32) for _ in range(G)),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                 jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-                jnp.ones((B,), bool), _dummy_key())
+                jnp.ones((B,), bool), jnp.full((B,), -1, jnp.int32),
+                _dummy_key())
         return paged_decode_fn, args
 
     def extend_program(self, T: int):
@@ -1086,7 +1167,8 @@ class Engine:
         if self.tracer is not None:
             self.tracer.on_prefill(req)
         self._slots[slot].request = req
-        self._stale.update(_OPERANDS)
+        self._stale.update(_HOST_OPERANDS)
+        self._from_host[slot] = True
         self._tokens[slot] = tok
         self._positions[slot] = n  # first generated token's index
         self._temps[slot] = sp.temperature
@@ -1251,20 +1333,32 @@ class Engine:
         page_alloc.free([page], owner=owner)
         return True
 
-    def _grow_pages(self, width: int = 1):
+    def _grow_pages(self, width: int = 1, wait: bool = False,
+                    ending: Sequence[Request] = ()) -> bool:
         """Before a decode step, make sure every running slot has private
         writable pages mapped for the ``width`` positions it may write
         (1 for plain decode, ``k+1`` for speculative verify — positions
         past the sequence budget route to the trash page in-graph and need
-        no mapping). A slot that can't grow finishes ``cache_full`` (its
-        generated prefix is intact) — the pages it frees may already
-        unblock the next waiting request."""
+        no mapping), from the position mirror: the position the step about
+        to be launched writes. First the requests of ``ending`` (``_ending``:
+        the step in flight makes their last token) are released, so they
+        take no page and leave theirs to the others.
+
+        A slot that can't grow finishes ``cache_full`` (its generated prefix
+        is intact) — the pages it frees may already unblock the next slot or
+        the next waiting request. Under ``wait`` (a step is in flight) it
+        does not: the first slot that can't grow ends the pass, False comes
+        back, and the caller settles the step in flight, whose finishes may
+        free the page, before it grows again."""
         ps, S_max = self.cache.page_size, self.config.max_seq_len
         allocated = cache_full = 0
+        short = False
         moved = []      # (slot, position, owner) of a model with windows
         cow_before = self._cow_copies
         first = self._launch_i + 1  # of the copies' launches, if any
         with _span("serving/decode/grow_pages") as sp:
+            for req in ending:
+                self._release(req)
             for slot, st in enumerate(self._slots):
                 req = st.request
                 if req is None:
@@ -1288,6 +1382,9 @@ class Engine:
                             break
                     if not ok:
                         break
+                if not ok and wait:
+                    short = True
+                    break
                 if not ok:
                     self._finish(req, "cache_full")
                     cache_full += 1
@@ -1295,14 +1392,66 @@ class Engine:
                     moved.append((slot, p, owner))
             sp.set(allocated=allocated, cache_full=cache_full,
                    cow_copies=self._cow_copies - cow_before)
+            if ending:
+                sp.set(released=len(ending))
             if self._launch_i >= first:
                 sp.set(launch=first, launches=self._launch_i - first + 1)
+            if short:
+                sp.set(short=1)
+                return False
         if moved:
             self._slide(moved)
+        return True
+
+    def _ending(self, flight: Optional[_Flight]) -> List[Request]:
+        """The requests that the step in flight finishes whatever token it
+        makes, which the host knows by COUNT before it has the token: the
+        token is the request's ``max_new_tokens``-th, or fills the sequence
+        budget (``_maybe_finish``)."""
+        if flight is None:
+            return []
+        S_max = self.config.max_seq_len
+        return [req for req, _ in flight.rows if req.state != FINISHED
+                and (req.num_generated + 1 >= req.sampling.max_new_tokens
+                     or len(req.prompt_ids) + req.num_generated + 1 >= S_max)]
 
     def _decode(self) -> int:
         """One batched decode step, as one ``serving/decode`` span over its
         phases; returns the tokens emitted.
+
+        **The plain step is launched one step AHEAD of its fetch.** A call
+        launches step N (``grow_pages``, ``upload``, ``dispatch``) and only
+        then fetches and settles step N - 1, which the call before left in
+        flight (``_flight``): the device has step N queued behind N - 1 and
+        starts it the moment that ends, so neither the launch nor the last
+        step's copy-out nor the host's settle is time the device waits. The
+        host knows everything about step N but the token VALUES:
+
+        - the position mirror moves when a step is LAUNCHED, as the
+          program's ``next_positions`` does, so ``_grow_pages``, ``_slide``
+          and the span's ``ctx_tokens`` read the position step N writes;
+        - ``output_ids``, ``_tokens``, ``_maybe_finish`` and the scheduler's
+          finish move at settle, one step behind. A finish the host can
+          COUNT (``_ending``: the token in flight is the request's last by
+          ``max_new_tokens`` or by the sequence budget) takes the row out of
+          step N: its slot and pages are released BEFORE the launch
+          (``_release``), so step N sees a dead slot there as it always did
+          behind a finish: no page grown, no draw, and no step at all where
+          no other row lives. An ``eos`` cannot be counted: that row runs
+          once more, its token is DROPPED at settle (``dropped``), its K/V
+          lands at its own next position, its state row is overwritten by
+          the next admission's program, and its pages may go at once because
+          every later program is queued behind the step;
+        - the tokens the host does decide reach the program as
+          ``host_tokens`` (``_OPERANDS``), never as a whole ``tokens`` put;
+        - where the pool has no page for a row while a step is in flight,
+          that step is settled FIRST (its finishes may free the page) and
+          the pass made again: a short pool runs in the old order.
+
+        What describes one launched step stays together: ``running``,
+        ``draws``, ``ctx_tokens`` and the rest are kept with the step in
+        flight and set, with the model's ``step_stats``, on the
+        ``serving/decode`` span of the call that settles it.
 
         Under speculation the step is the verify-k program: propose ``k``
         n-gram drafts per row (``serving/decode/propose``), run the ONE
@@ -1313,169 +1462,228 @@ class Engine:
         stream), sampled rows emit position 0's sampled token. Rejected
         drafts cost nothing: their K/V sits at positions the next verify
         step overwrites before attending, so rollback is just NOT advancing
-        ``_positions`` past the kept tokens.
+        ``_positions`` past the kept tokens. The host picks the next step's
+        tokens and positions from the fetched ones, so a verify step is
+        launched, fetched and settled in ONE call and nothing is in flight
+        between calls: the order follows ``self.spec``, nothing else.
 
         The step's operands (``_OPERANDS`` and the page table) stay on the
-        device between steps. The host mirrors are the authority and
-        ``settle`` updates them as it always did; the decode program makes
-        the same update on the device (its ``next_tokens`` /
+        device between steps; the decode program makes on the device the
+        update the host makes on its mirrors (its ``next_tokens`` /
         ``next_positions`` outputs, handed back as the next step's
-        arguments), so after every ``settle`` a kept array equals its
-        mirror unless the mirror's name is in ``_stale``: ``_admit_one`` and
-        ``_finish`` mark all five, the cache's table writers drop its kept
-        copy, and a verify step marks tokens and positions (the host
-        decides both from the accepted drafts). ``upload`` puts exactly
-        what is marked — on most plain steps nothing."""
-        spec = self.spec
-        k = 0 if spec is None else spec.k
-        B = len(self._slots)
+        arguments). ``_admit_one`` and ``_finish`` mark the four operands
+        the host is the authority for and the slot's ``host_tokens`` row,
+        the cache's table writers drop its kept copy, and a verify step
+        marks tokens and positions. ``upload`` puts exactly what is marked —
+        on most plain steps nothing."""
+        k = 0 if self.spec is None else self.spec.k
+        due, self._flight = self._flight, None
+        emitted = 0
         with _span("serving/decode", step=self._step_i) as sp:
-            self._grow_pages(width=k + 1)
-            running = [s.request for s in self._slots
-                       if s.request is not None]
-            sp.set(running=len(running))
-            if not running:
-                return 0
-            # live rows that are not greedy (a dead slot's row reads greedy):
-            # with none the program's sampler runs its argmax alone
-            draws = B - int(np.count_nonzero(self._greedy))
-            if draws:
-                self.sampler_steps_draw += 1
+            if not self._grow_pages(k + 1, wait=due is not None,
+                                    ending=self._ending(due)):
+                emitted += self._settle(due, sp)
+                due = None
+                self._grow_pages(k + 1)
+            flight = self._launch(sp, ahead=due is not None)
+            if self.spec is None:
+                self._flight = flight
             else:
-                self.sampler_steps_argmax += 1
-            if sp:
-                # cached tokens the step's attention may read (each running
-                # slot's context, the token it writes included) and how many
-                # of them it does read, where the model selects; pages the
-                # paged-decode kernel's loops walk (a layer) this step, of
-                # the table entries a grid over the table would
-                ctx = self._positions[[r.slot for r in running]] + 1
-                sel = getattr(self.model, "selected_tokens", None)
-                last = (ctx - 1) // self.cache.page_size   # a slot's last page
-                sp.set(draws=draws, ctx_tokens=int(ctx.sum()),
-                       selected_tokens=int((ctx if sel is None
-                                            else sel(ctx)).sum()),
-                       live_pages=int((last + 1).sum()),
-                       table_pages=B * self.cache.num_blocks)
-                if "latent_tokens_read" in getattr(self.model, "step_stats",
-                                                   ()):
-                    # the live pages counted ONCE each, however many slots
-                    # map them (sessions on one document): what a step's
-                    # attention has to bring in, a layer
-                    rows = self.cache.page_table[[r.slot for r in running]]
-                    live = np.arange(rows.shape[1])[None, :] <= last[:, None]
-                    seen = np.zeros((self.cache.num_pages,), bool)
-                    seen[rows[live]] = True
-                    sp.set(distinct_pages=int(seen.sum()))
-                if self._windows:
-                    # the window groups' pages that are mapped or cached,
-                    # of those they have
-                    held = [self.page_allocs[g] for g, _ in self._windows]
-                    sp.set(window_pages_live=sum(a.num_allocated
-                                                 for a in held),
-                           window_pages=sum(a.num_allocatable for a in held))
-            tokens, step_s = self._tokens, 0.0
-            if spec is not None:
-                with _span("serving/decode/propose") as prop:
-                    tokens = np.zeros((B, k + 1), np.int32)
-                    drafts: Dict[int, List[int]] = {}
-                    for req in running:
-                        slot = req.slot
-                        drafts[slot] = propose_ngram(
-                            req.prompt_ids + req.output_ids, k, spec.ngram)
-                        tokens[slot, 0] = self._tokens[slot]
-                        tokens[slot, 1:] = drafts[slot]
-                step_s = prop.seconds
-            with _span("serving/decode/upload") as up:
-                if not draws:
-                    key = _dummy_key()
-                else:
-                    # the key's eager ops: one launch number
-                    self._launch_i += 1
-                    up.set(launch=self._launch_i, eager=1)
-                    key = _random.next_key()
-                # put what the host changed, in one call and from copies
-                # (a put may alias host memory the mirrors go on changing);
-                # everything else is the array the device already holds
-                table_put = int(self.cache.table_changed)
-                tables = self.cache.tables_device()
-                stale = {name: (tokens if name == "tokens"
-                                else getattr(self, "_" + name)).copy()
-                         for name in self._stale}
-                if stale:
-                    self._dev.update(jax.device_put(stale))
-                    self._stale.clear()
-                if up:
-                    up.set(puts=len(stale) + table_put, table_put=table_put)
-                args = (*tables, *(self._dev[name] for name in _OPERANDS),
-                        key)
-            self._launch_i += 1
-            with _span("serving/decode/dispatch",
-                       launch=self._launch_i) as disp:
-                exe = self._decode_exe() if spec is None \
-                    else self._verify_exe()
-                out = exe(self.params, *self.cache.pools, *args)
-                if spec is None:
-                    # behind what the host fetches come the next step's
-                    # tokens and positions (their inputs were donated),
-                    # then the pools
-                    toks, self._dev["tokens"], self._dev["positions"], \
-                        *self.cache.pools = out
-                else:
-                    # the argmax targets and position 0's sample; the host
-                    # decides the next tokens and positions in settle
-                    toks, sampled0, *self.cache.pools = out
-                    self._stale.update(("tokens", "positions"))
-            with _span("serving/decode/fetch",
-                       waits_for=self._launch_i) as fetch:
-                toks = np.asarray(toks)
-                sampled0 = toks if spec is None else np.asarray(sampled0)
-            if sp and spec is None and toks.shape[0] > B:
+                due = flight
+            if due is not None:
+                emitted += self._settle(due, sp)
+            elif flight is None and not emitted:
+                sp.set(running=0)
+        return emitted
+
+    def _launch(self, sp, ahead: bool) -> Optional[_Flight]:
+        """Upload what the host changed and call the decode (verify)
+        program for every slot that holds a request; None, and nothing
+        launched, where none does. ``sp``: the ``serving/decode`` span
+        (false with tracing off: the step's attributes are then not
+        computed)."""
+        spec = self.spec
+        B = len(self._slots)
+        rows = [(s.request, slot) for slot, s in enumerate(self._slots)
+                if s.request is not None]
+        if not rows:
+            return None
+        slots = [slot for _, slot in rows]
+        # live rows that are not greedy (a dead slot's row reads greedy):
+        # with none the program's sampler runs its argmax alone
+        draws = B - int(np.count_nonzero(self._greedy))
+        if draws:
+            self.sampler_steps_draw += 1
+        else:
+            self.sampler_steps_argmax += 1
+        attrs = {"running": len(rows)}
+        if sp:
+            # cached tokens the step's attention may read (each row's
+            # context, the token it writes included) and how many of them
+            # it does read, where the model selects; pages the paged-decode
+            # kernel's loops walk (a layer) this step, of the table entries
+            # a grid over the table would
+            ctx = self._positions[slots] + 1
+            sel = getattr(self.model, "selected_tokens", None)
+            end = (ctx - 1) // self.cache.page_size     # a slot's last page
+            attrs.update(draws=draws, ctx_tokens=int(ctx.sum()),
+                         selected_tokens=int((ctx if sel is None
+                                              else sel(ctx)).sum()),
+                         live_pages=int((end + 1).sum()),
+                         table_pages=B * self.cache.num_blocks)
+            if "latent_tokens_read" in getattr(self.model, "step_stats", ()):
+                # the live pages counted ONCE each, however many slots map
+                # them (sessions on one document): what a step's attention
+                # has to bring in, a layer
+                table = self.cache.page_table[slots]
+                mapped = np.arange(table.shape[1])[None, :] <= end[:, None]
+                seen = np.zeros((self.cache.num_pages,), bool)
+                seen[table[mapped]] = True
+                attrs.update(distinct_pages=int(seen.sum()))
+            if self._windows:
+                # the window groups' pages that are mapped or cached, of
+                # those they have
+                held = [self.page_allocs[g] for g, _ in self._windows]
+                attrs.update(
+                    window_pages_live=sum(a.num_allocated for a in held),
+                    window_pages=sum(a.num_allocatable for a in held))
+        tokens, step_s, drafts = self._tokens, 0.0, None
+        if spec is not None:
+            k = spec.k
+            with _span("serving/decode/propose") as prop:
+                tokens = np.zeros((B, k + 1), np.int32)
+                drafts = {}
+                for req, slot in rows:
+                    drafts[slot] = propose_ngram(
+                        req.prompt_ids + req.output_ids, k, spec.ngram)
+                    tokens[slot, 0] = self._tokens[slot]
+                    tokens[slot, 1:] = drafts[slot]
+            step_s = prop.seconds
+        with _span("serving/decode/upload") as up:
+            if not draws:
+                key = _dummy_key()
+            else:
+                # the key's eager ops: one launch number
+                self._launch_i += 1
+                up.set(launch=self._launch_i, eager=1)
+                key = _random.next_key()
+            # put what the host changed, in one call and from copies
+            # (a put may alias host memory the mirrors go on changing);
+            # everything else is the array the device already holds
+            table_put = int(self.cache.table_changed)
+            tables = self.cache.tables_device()
+            stale = {name: (tokens if name == "tokens"
+                            else getattr(self, "_" + name)).copy()
+                     for name in self._stale}
+            if spec is None and self._from_host.any():
+                stale["host_tokens"] = np.where(
+                    self._from_host, self._tokens, -1).astype(np.int32)
+                self._from_host[:] = False
+            if stale:
+                self._dev.update(jax.device_put(stale))
+                self._stale.clear()
+            if up:
+                up.set(puts=len(stale) + table_put, table_put=table_put)
+            args = (*tables, *(self._dev[name] for name in _OPERANDS),
+                    *((self._dev["host_tokens"],) if spec is None else ()),
+                    key)
+        self._launch_i += 1
+        if ahead:
+            self.steps_ahead += 1
+        else:
+            self.steps_drained += 1
+        with _span("serving/decode/dispatch", launch=self._launch_i,
+                   ahead=int(ahead)) as disp:
+            exe = self._decode_exe() if spec is None else self._verify_exe()
+            out = exe(self.params, *self.cache.pools, *args)
+            sampled0 = None
+            if spec is None:
+                # behind what the host fetches come the next step's
+                # tokens and positions (their inputs were donated),
+                # then the pools; the mirror of the positions moves with
+                # the launch
+                toks, self._dev["tokens"], self._dev["positions"], \
+                    *self.cache.pools = out
+                self._positions[slots] += 1
+                # the host's tokens went once
+                self._dev["host_tokens"] = self._no_host_tokens
+            else:
+                # the argmax targets and position 0's sample; the host
+                # decides the next tokens and positions in settle
+                toks, sampled0, *self.cache.pools = out
+                self._stale.update(("tokens", "positions"))
+        return _Flight(self._launch_i, toks, rows, attrs,
+                       step_s + up.seconds + disp.seconds, sampled0, drafts)
+
+    def _settle(self, flight: _Flight, sp) -> int:
+        """Fetch a launched step's tokens and settle them per request:
+        append, ``_maybe_finish``; a row whose request has FINISHED since
+        the launch is dropped. The step's attributes and the model's
+        ``step_stats`` go on ``sp``, the ``serving/decode`` span of this
+        call. Returns the tokens emitted."""
+        spec = self.spec
+        B = len(self._slots)
+        with _span("serving/decode/fetch", waits_for=flight.launch) as fetch:
+            toks = np.asarray(flight.out)
+            sampled0 = toks if spec is None else np.asarray(flight.sampled0)
+        if sp:
+            sp.set(**flight.attrs)
+            if spec is None and toks.shape[0] > B:
                 # a model that counts in its step (decoder.DecoderLM: per
                 # layer the distinct experts routed to, the largest
                 # expert's rows) sent its counts behind the tokens
                 stats = toks[B:].reshape(self.model.cfg.num_layers, -1)
                 sp.set(**{name: stats[:, i].tolist() for i, name in
                           enumerate(self.model.step_stats)})
-            step_s += up.seconds + disp.seconds + fetch.seconds
-            _metrics.histogram("serving.decode.step.seconds", step_s)
-            emitted_total = drafted = accepted = 0
-            with _span("serving/decode/settle") as settle:
-                for req in running:
-                    slot = req.slot
-                    if spec is not None and self._greedy[slot]:
-                        a, emitted = accept_greedy(drafts[slot], toks[slot])
-                        req.draft_tokens += k
-                        req.accepted_tokens += a
-                        drafted += k
-                        accepted += a
-                        self._spec_slots += k + 1
-                        self._spec_emitted += len(emitted)
-                    else:
-                        emitted = [sampled0[slot]]
-                    self.scheduler.observe_decode_step(req, step_s)
-                    if self.tracer is not None:
-                        self.tracer.on_decode_step(req)
-                    for tok in emitted:
-                        tok = int(tok)
-                        req.output_ids.append(tok)
+        step_s = flight.seconds + fetch.seconds
+        _metrics.histogram("serving.decode.step.seconds", step_s)
+        emitted_total = drafted = accepted = dropped = 0
+        with _span("serving/decode/settle") as settle:
+            before = len(self.scheduler.running)
+            for req, slot in flight.rows:
+                if req.state == FINISHED:
+                    dropped += 1
+                    continue
+                if spec is not None and self._greedy[slot]:
+                    k = spec.k
+                    a, emitted = accept_greedy(flight.drafts[slot],
+                                               toks[slot])
+                    req.draft_tokens += k
+                    req.accepted_tokens += a
+                    drafted += k
+                    accepted += a
+                    self._spec_slots += k + 1
+                    self._spec_emitted += len(emitted)
+                else:
+                    emitted = [sampled0[slot]]
+                self.scheduler.observe_decode_step(req, step_s)
+                if self.tracer is not None:
+                    self.tracer.on_decode_step(req)
+                for tok in emitted:
+                    tok = int(tok)
+                    req.output_ids.append(tok)
+                    if self._slots[slot].request is req:    # not released
                         self._tokens[slot] = tok
+                    if spec is not None:
                         self._positions[slot] += 1
-                        emitted_total += 1
-                        self._maybe_finish(req, tok)
-                        if req.state == FINISHED:
-                            break
-                settle.set(finished=len(running)
-                           - len(self.scheduler.running))
-            _metrics.counter("serving.tokens.generated", emitted_total)
-            if drafted:
-                self._spec_drafted += drafted
-                self._spec_accepted += accepted
-                _metrics.counter("serving.spec.draft_tokens", drafted)
-                _metrics.counter("serving.spec.accepted_tokens", accepted)
-                _metrics.gauge("serving.spec.accept_rate",
-                               self._spec_emitted / self._spec_slots)
-            return emitted_total
+                    emitted_total += 1
+                    self._maybe_finish(req, tok)
+                    if req.state == FINISHED:
+                        break
+            settle.set(finished=before - len(self.scheduler.running),
+                       dropped=dropped)
+        _metrics.counter("serving.tokens.generated", emitted_total)
+        if dropped:
+            self.dropped_rows += dropped
+            _metrics.counter("serving.decode.dropped_rows", dropped)
+        if drafted:
+            self._spec_drafted += drafted
+            self._spec_accepted += accepted
+            _metrics.counter("serving.spec.draft_tokens", drafted)
+            _metrics.counter("serving.spec.accepted_tokens", accepted)
+            _metrics.gauge("serving.spec.accept_rate",
+                           self._spec_emitted / self._spec_slots)
+        return emitted_total
 
     def _maybe_finish(self, req: Request, tok: int):
         sp = req.sampling
@@ -1491,12 +1699,25 @@ class Engine:
         self._finish(req, reason)
 
     def _finish(self, req: Request, reason: str):
-        slot = req.slot
         self.scheduler.finish(req, reason)
         if self.tracer is not None:
             self.tracer.on_finish(req)
+        if self._slots[req.slot].request is req:
+            self._release(req)
+
+    def _release(self, req: Request):
+        """Give back what a request holds on the device: its slot, the
+        slot's rows of the decode operands (token 0, position 0, a greedy
+        row: what a dead slot reads) and its references on the pages its
+        slot mapped. At its finish or, where the host can COUNT the finish
+        before it has the token (``_ending``), before the next step is
+        launched: that step then runs without the row. The pages may go
+        while a step that reads them is still in flight, because every later
+        program is queued behind it."""
+        slot = req.slot
         self._slots[slot].request = None
-        self._stale.update(_OPERANDS)
+        self._stale.update(_HOST_OPERANDS)
+        self._from_host[slot] = True
         self._tokens[slot] = 0
         self._positions[slot] = 0
         self._temps[slot] = 1.0

@@ -611,16 +611,17 @@ class TestOneProgramAnAdmission:
 #: state operand (f556f11; jax 0.9.0, x64 on as in these tests). A PR that
 #: means to change these programs records them again: print
 #: ``_lowered(...)`` of each below. The four decode programs were recorded
-#: again at PR 39 (the sampler's conditional in place of its sort).
+#: again at PR 39 (the sampler's conditional in place of its sort) and at
+#: PR 43 (the ``host_tokens`` operand and its select: one argument more).
 WITHOUT_STATE = {
     "gpt/prefill/oracle": (6, "3baaa1868e5f7615"),
     "gpt/extend/oracle": (7, "24143a35f6d0155c"),
-    "gpt/decode/oracle": (10, "b4ef0e96c4294d47"),
-    "gpt/decode/pallas": (10, "d1c27414629100fa"),
+    "gpt/decode/oracle": (11, "3298d34fd1edf2bd"),
+    "gpt/decode/pallas": (11, "694c5b446e7a6e60"),
     "decoder/prefill/oracle": (7, "0024ab6d3c6af9d9"),
     "decoder/extend/oracle": (8, "539dd0b7ee3df441"),
-    "decoder/decode/oracle": (11, "abe326ead78fb9e8"),
-    "decoder/decode/pallas": (11, "3a2a30328dd3ee6f"),
+    "decoder/decode/oracle": (12, "1524d1bb051655c3"),
+    "decoder/decode/pallas": (12, "88177ec91bd8cd68"),
 }
 
 

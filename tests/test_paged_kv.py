@@ -510,6 +510,8 @@ class TestPagedEngine:
         r1 = eng.add_request([5, 17, 3], SamplingParams(max_new_tokens=2))
         r2 = eng.add_request([9, 2, 4], SamplingParams(max_new_tokens=2))
         eng.step()  # r1 admitted; r2 must wait for pages, not slots
+        assert r1.state == "running" and r2.state == "queued"
+        eng.step()  # r1's one decode step, launched by the call before, settles
         assert r1.state == "finished" and r1.finish_reason == "length"
         assert r2.state == "queued"
         assert eng.cache.free_slots == 2  # both slots idle: pages were the

@@ -183,8 +183,12 @@ class TestEngine:
         r1 = eng.add_request([5, 17, 3], SamplingParams(max_new_tokens=2))
         r2 = eng.add_request([9, 2, 4], SamplingParams(max_new_tokens=8))
         r3 = eng.add_request([7, 7, 7], SamplingParams(max_new_tokens=3))
-        eng.step()  # admits r1+r2 (prefill = token 1), decodes (token 2): r1 done
+        eng.step()  # admits r1+r2 (prefill = token 1), LAUNCHES decode step 1
+        assert [r.num_generated for r in (r1, r2)] == [1, 1]
+        assert r1.state == "running" and eng._flight is not None
+        eng.step()  # launches step 2 (for r2 alone), settles step 1: r1 done
         assert r1.state == "finished" and r1.finish_reason == "length"
+        assert [r.num_generated for r in (r1, r2)] == [2, 2]
         assert r3.state == "queued"
         eng.step()  # r1's slot is free -> r3 admitted this step
         assert r3.state == "running" and r3.slot == r1.slot

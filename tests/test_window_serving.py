@@ -599,22 +599,24 @@ OLDER = {
 #: lists, recorded at the parent of the PR that gave the cache its page
 #: groups (f50b1e0; jax 0.9.0, x64 on as in these tests). A PR that means
 #: to change these programs records them again: print ``_lowered(...)``.
+#: The six decode programs were recorded again at PR 43 (the ``host_tokens``
+#: operand and its select: one argument more).
 PARENTS = {
     "hybrid/params": (28, 4035424780),
     "share/params": (39, 2590006625),
     "latent/params": (34, 1586234722),
     "hybrid/prefill/oracle": (9, "b0e020062e23733c"),
     "hybrid/extend/oracle": (10, "0650b351e53e3636"),
-    "hybrid/decode/oracle": (12, "3dbc399f0f24c65a"),
-    "hybrid/decode/pallas": (12, "4cbcf4a612de6533"),
+    "hybrid/decode/oracle": (13, "68db039800b0e874"),
+    "hybrid/decode/pallas": (13, "534176be6752a5b1"),
     "share/prefill/oracle": (9, "8b4e2f98c16d6298"),
     "share/extend/oracle": (10, "e71bfd3db5f77058"),
-    "share/decode/oracle": (12, "e5fbb92112dc7a3f"),
-    "share/decode/pallas": (12, "da04e66e8337a5f1"),
+    "share/decode/oracle": (13, "2d02876efc7303a0"),
+    "share/decode/pallas": (13, "4824ba6592a7af51"),
     "latent/prefill/oracle": (5, "f73b6dbaab2847b5"),
     "latent/extend/oracle": (6, "0c206328ccf7ea76"),
-    "latent/decode/oracle": (9, "3a225333c555e538"),
-    "latent/decode/pallas": (9, "4fa648ad681833e6"),
+    "latent/decode/oracle": (10, "6a66f53c8cc512bd"),
+    "latent/decode/pallas": (10, "66b22e8cca63fa42"),
 }
 
 
