@@ -68,7 +68,23 @@ is that kernel's forward: grid (G, query blocks, key blocks), keys streamed
 through the trailing sequential dimension, float32 (max, sum, acc) in VMEM
 scratch, exp2 domain; key blocks wholly behind a query block's last position
 are neither computed nor fetched (their block index repeats the last one
-that is). Without it the expanded form is XLA einsums whose float32 score
+that is). A step walks up to 1,024 keys (``_blocks``), its queries
+``_FLASH_ROWS`` (128) at a time against ALL of them: one running maximum,
+one sum and one rescale of the accumulator a 1,024 keys, half the grid
+steps. The chunks of queries share nothing, and the body writes chunk
+r + 1's two score products down BEFORE chunk r's softmax: the step is one
+basic block, and the scheduler then runs the matrix unit's phase of one
+chunk under the vector unit's phase of the other (a whole tile's products,
+then a whole tile's softmax, leave each unit idle through the other's
+phase). Every block is masked: a second body without the mask for the
+blocks wholly behind a query block's first position (all but one or two of
+35 behind 33k cached tokens) was measured and gave nothing, the mask's
+passes ride in slots the schedule leaves empty; so were one 192-wide score
+product through a VMEM copy of ``[k_nope | k_pe]`` (slower: on a 128-deep
+matrix unit it is two passes either way) and ``log2(e)`` folded into the
+queries (PERF.md section 6, PR 44).
+
+Without the kernel the expanded form is XLA einsums whose float32 score
 tiles go through HBM three times a key block: a 34,816-token prefill took
 15.2 s of which 13.0 in those fusions (my chip run, PR 40).
 """
@@ -409,11 +425,19 @@ def _decode_call(table, pos, plan, q, pool, *, value_width: int,
 
 # ------------------------------------------------- the expanded form
 
+#: queries of a grid step whose scores are one tile of the online softmax
+_FLASH_ROWS = 128
+
+
 def _blocks(T: int, L: int):
-    """(queries, keys) a block: the largest of the listed sizes that divide
-    the length, else the whole of it (small test shapes)."""
-    pick = lambda n, sizes: next((b for b in sizes if n % b == 0), n)
-    return pick(T, (1024, 512, 256, 128)), pick(L, (512, 256, 128))
+    """(queries, keys) a grid step: the largest of the listed sizes that
+    divide the length, else the whole of it (small test shapes). A step
+    folds ALL its keys into a query's running maximum, sum and accumulator
+    at once, so those are paid once a 1,024 keys where the lengths allow
+    (35,840 = 35 x 1,024)."""
+    sizes = (1024, 512, 256, 128)
+    pick = lambda n: next((b for b in sizes if n % b == 0), n)
+    return pick(T), pick(L)
 
 
 def _last_block(start, qi, block_q: int, block_k: int, num_kb: int):
@@ -438,30 +462,49 @@ def _flash_kernel(start_ref, qn_ref, qp_ref, kn_ref, kp_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(ki < kb_hi)
-    def _compute():
-        v = v_ref[0]
+    # the step's queries go _FLASH_ROWS at a time against ALL its keys (a
+    # block they do not divide goes whole); the chunks share nothing, and
+    # chunk r + 1's products are written down BEFORE chunk r's softmax so
+    # that the scheduler (one basic block: the loop is unrolled) runs the
+    # matrix unit's phase of one under the vector unit's phase of the other
+    rows = _FLASH_ROWS if block_q % _FLASH_ROWS == 0 else block_q
+
+    def scores(r):
+        qr = pl.ds(r * rows, rows)
         nt = lambda a, b: jax.lax.dot_general(       # a b^T, float32
             a, b, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        s = (nt(qn_ref[0], kn_ref[0]) + nt(qp_ref[0], kp_ref[0])) \
+        s = (nt(qn_ref[0, qr], kn_ref[0]) + nt(qp_ref[0, qr], kp_ref[0])) \
             * jnp.float32(LOG2E)                     # q pre-scaled
-        qpos = start + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
+        qpos = start + qi * block_q + r * rows \
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        return jnp.where(qpos >= kpos, s, NEG_INF)
+
+    def fold(r, s):
+        qr, v = pl.ds(r * rows, rows), v_ref[0]
         # key 0 is behind every query and block 0 is walked first, so no
         # row's running maximum stays at the mask's value
-        m, l = m_scr[:, :1], l_scr[:, :1]
+        m, l = m_scr[qr, :1], l_scr[qr, :1]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        acc_scr[qr] = acc_scr[qr] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(
-            l * alpha + jnp.sum(p, axis=-1, keepdims=True), l_scr.shape)
+        m_scr[qr] = jnp.broadcast_to(m_new, (rows, m_scr.shape[1]))
+        l_scr[qr] = jnp.broadcast_to(
+            l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+            (rows, l_scr.shape[1]))
+
+    @pl.when(ki < kb_hi)
+    def _compute():
+        chunks = block_q // rows
+        s = scores(0)
+        for r in range(chunks):
+            ahead = scores(r + 1) if r + 1 < chunks else None
+            fold(r, s)
+            s = ahead
 
     @pl.when(ki == num_kb - 1)
     def _epilogue():

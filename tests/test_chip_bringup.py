@@ -201,6 +201,13 @@ out["latent_decode"] = sites(
     lambda q, pool, t, p: latent_paged_decode(q, pool, t, p, 512),
     sds((64, 128, 640)), sds((20481, 1, 16, 640)),
     sds((64, 2240), jnp.int32), sds((64,), jnp.int32))
+# ... and the expanded form behind 33k cached tokens: 8 heads, a bucket of
+# 1,024 queries over the whole 35,840-position view, steps of 1,024 x 1,024
+from paddle_tpu.kernels.latent_attention import latent_flash
+out["latent_flash"] = sites(
+    lambda qn, qp, kn, kp, v, s: latent_flash(qn, qp, kn, kp, v, s, 8),
+    sds((8, 1024, 128)), sds((8, 1024, 64)), sds((8, 35840, 128)),
+    sds((1, 35840, 64)), sds((8, 35840, 128)), sds((1,), jnp.int32))
 f32 = sds((64, 256), jnp.float32)
 out["prim"] = {**sites(primitive.elementwise_kernel(lambda a, b: a + 2 * b), f32, f32),
                **sites(primitive.row_reduce_kernel(lambda acc, t: acc + t.sum(-1), 0.0), f32)}
@@ -247,6 +254,7 @@ def test_kernels_and_train_step_compile_for_a_tpu_topology():
     assert out["paged_gqa"] == {"paged_decode": 1}
     assert out["gmm_held"] == {"moe_grouped_matmul": 1}
     assert out["latent_decode"] == {"latent_paged_decode": 1}
+    assert out["latent_flash"] == {"latent_flash": 1}
     assert out["prim"] == {"prim_elementwise": 1, "prim_row_reduce": 1}
     for key in ("step_1", "step_dp2mp2"):  # the mesh must not lose a kernel
         assert set(out[key]) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",

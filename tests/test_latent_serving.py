@@ -642,25 +642,46 @@ class TestLatentFlash:
         s = jnp.where(jnp.arange(k.shape[1])[None, None, :] <= qpos, s, -1e30)
         return jnp.einsum("gtl,gld->gtd", jax.nn.softmax(s, -1), v)
 
-    @pytest.mark.parametrize("T,L,starts", [
-        (16, 48, (20, 7)),       # one block of each
-        (32, 64, (0, 0)),        # a prefill: the keys are the queries' own
-        (32, 96, (64, 3)),       # the last page, and almost nothing cached
+    @staticmethod
+    def _operands(T, L, dtype=jnp.float32):
+        k = jax.random.split(jax.random.PRNGKey(T), 5)
+        draw = lambda key, shape, by=1.0: (
+            by * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+        return (draw(k[0], (4, T, 16), 0.3), draw(k[1], (4, T, 8), 0.3),
+                draw(k[2], (4, L, 16)), draw(k[3], (2, L, 8)),  # one a sequence
+                draw(k[4], (4, L, 16)))
+
+    @pytest.mark.parametrize("T,L,starts,blocks,rows", [
+        (16, 48, (20, 7), (8, 16), 8),  # one block of each
+        (32, 64, (0, 0), (8, 16), 8),   # a prefill: the keys are the queries' own
+        (32, 96, (64, 3), (8, 16), 8),  # the last page, and almost nothing cached
+        # wholly visible blocks, then a crossing one, then unread ones, the
+        # starts no multiple of the key block and not the same: sequence 0's
+        # first query block sees blocks 0-1 whole, crosses 2, leaves 3-5;
+        # sequence 1's second one sees 0-3 whole and crosses 4 and 5
+        (16, 96, (37, 70), (8, 16), 8),
+        # several crossing blocks a query block (16 queries over keys by 8),
+        # its queries in two chunks, then one query block in four chunks
+        (32, 96, (37, 61), (16, 8), 8),
+        (32, 96, (5, 64), (32, 32), 8),
+        # a query block the chunk does not divide goes whole: its last
+        # 24 % 16 rows are scored like the others
+        (48, 96, (37, 5), (24, 16), 16),
+        (24, 96, (70, 0), (24, 32), 16),
     ])
-    def test_is_plain_attention_over_blocks(self, monkeypatch, T, L, starts):
-        """Blocks of 8 queries and 16 keys, so that a query block walks
-        several key blocks and leaves the ones behind its last position
-        unread."""
+    def test_is_plain_attention_over_blocks(self, monkeypatch, T, L, starts,
+                                            blocks, rows):
+        """Small blocks, so that a query block walks several key blocks,
+        those wholly behind its first query and those that cross a position,
+        and leaves the ones behind its last position unread; a step's
+        queries go ``rows`` at a time, each chunk's products written down
+        ahead of the softmax of the one before."""
         from paddle_tpu.kernels import latent_attention as la
 
-        monkeypatch.setattr(la, "_blocks", lambda T, L: (8, 16))
+        monkeypatch.setattr(la, "_blocks", lambda T, L: blocks)
+        monkeypatch.setattr(la, "_FLASH_ROWS", rows)
         la._flash_call.clear_cache()
-        k = jax.random.split(jax.random.PRNGKey(T), 5)
-        qn = 0.3 * jax.random.normal(k[0], (4, T, 16), jnp.float32)
-        qp = 0.3 * jax.random.normal(k[1], (4, T, 8), jnp.float32)
-        kn = jax.random.normal(k[2], (4, L, 16), jnp.float32)
-        kp = jax.random.normal(k[3], (2, L, 8), jnp.float32)  # one a sequence
-        v = jax.random.normal(k[4], (4, L, 16), jnp.float32)
+        qn, qp, kn, kp, v = self._operands(T, L)
         # what lies behind a query's position must not matter: poison it
         dead = jnp.arange(L)[None, :, None] > (max(starts) + T - 1)
         got = la.latent_flash(qn, qp, jnp.where(dead, 1e4, kn), kp,
@@ -673,10 +694,33 @@ class TestLatentFlash:
     def test_block_sizes_divide_the_lengths(self):
         from paddle_tpu.kernels.latent_attention import _blocks
 
-        assert _blocks(34816, 34816) == (1024, 512)
-        assert _blocks(128, 35840) == (128, 512)
-        assert _blocks(2048, 35840) == (1024, 512)
+        assert _blocks(34816, 34816) == (1024, 1024)
+        assert _blocks(128, 35840) == (128, 1024)
+        assert _blocks(512, 35840) == (512, 1024)
+        assert _blocks(2048, 35840) == (1024, 1024)
+        assert _blocks(256, 512 * 3) == (256, 512)
         assert _blocks(48, 96) == (48, 96)
+        assert _blocks(3000, 3000) == (3000, 3000)
+
+    @pytest.mark.parametrize("T,L,start,blocks", [
+        (16, 96, 37, (8, 16)), (16, 96, 70, (8, 16)), (32, 96, 61, (16, 8)),
+        (32, 64, 0, (8, 16)), (32, 96, 64, (8, 16)), (32, 96, 5, (32, 32)),
+        (48, 96, 48, (48, 96)), (16, 48, 15, (8, 16)), (16, 48, 16, (8, 16)),
+    ])
+    def test_last_block_bounds_what_a_query_block_sees(self, T, L, start,
+                                                       blocks):
+        """Against a count over every (query, key) pair: the key blocks a
+        query block walks are those of which some query of it sees some
+        key, and they are the leading ones."""
+        from paddle_tpu.kernels.latent_attention import _last_block
+
+        bq, bk = blocks
+        sees = (start + np.arange(T))[:, None] >= np.arange(L)[None, :]
+        some = sees.reshape(T // bq, bq, L // bk, bk).any((1, 3))
+        last = np.asarray(_last_block(start, jnp.arange(T // bq), bq, bk,
+                                      L // bk))
+        np.testing.assert_array_equal(
+            some, np.arange(L // bk)[None, :] < last[:, None])
 
 
 # ---------------------------------------------------------------- (e) YaRN
