@@ -136,8 +136,9 @@ def leg_kernels(S: int = 2048, H: int = 16, D: int = 128, hidden: int = 2048,
     import paddle_tpu as paddle
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.nn import functional as F
-    from paddle_tpu.kernels.paged_attention import paged_attention
-    from paddle_tpu.serving import kv_cache as kvc
+    from paddle_tpu.kernels.paged_attention import (decode_attend,
+                                                    paged_attention)
+    from paddle_tpu.kernels.pools import paged_gather
 
     rng = np.random.RandomState(0)
     bf = lambda *shape, scale=1.0: jnp.asarray(
@@ -230,8 +231,8 @@ def leg_kernels(S: int = 2048, H: int = 16, D: int = 128, hidden: int = 2048,
         args = (qd, kp, vp, jnp.asarray(table), jnp.asarray(pos))
         got = _compiled(paged_attention, args, "paged_decode")(*args)
         want = _compiled(
-            lambda q, k, v, table, pos: kvc.decode_attend(
-                q, kvc.paged_gather(k, table), kvc.paged_gather(v, table),
+            lambda q, k, v, table, pos: decode_attend(
+                q, paged_gather(k, table), paged_gather(v, table),
                 pos), args, "paged_decode", present=False)(*args)
         errs["paged_decode"].append(close(got[live], want[live], tol))
         if np.delete(np.asarray(got, np.float32), live, axis=0).any():

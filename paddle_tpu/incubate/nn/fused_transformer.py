@@ -293,8 +293,9 @@ class FusedMultiTransformer(Layer):
         import jax.numpy as jnp
         from jax import lax
 
+        from ...kernels.paged_attention import decode_attend
+        from ...kernels.pools import write_kv
         from ...ops._dispatch import apply, as_tensor
-        from ...serving import kv_cache as _kvc
 
         if attn_mask is not None:
             raise NotImplementedError(
@@ -330,12 +331,12 @@ class FusedMultiTransformer(Layer):
             if k_layer is not None:
                 if step is not None:
                     # decode: shared static-cache write/attend
-                    # (serving.kv_cache) — the same path the GPT serving
+                    # (kernels/pools, paged_attention) — what the GPT serving
                     # engine runs, so the two cached decode implementations
                     # cannot drift
-                    k_layer = _kvc.write_kv(k_layer, k, step)
-                    v_layer = _kvc.write_kv(v_layer, v, step)
-                    o = _kvc.decode_attend(q, k_layer, v_layer, step)
+                    k_layer = write_kv(k_layer, k, step)
+                    v_layer = write_kv(v_layer, v, step)
+                    o = decode_attend(q, k_layer, v_layer, step)
                 else:
                     # prefill: causal attention; caches filled with the prefix
                     k_layer = lax.dynamic_update_slice(k_layer, k, (0, 0, 0, 0))

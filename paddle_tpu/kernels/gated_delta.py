@@ -29,8 +29,8 @@ the natural ``[H, dv, dk]`` would pad its 96 lanes to 128 in memory, a third
 more bytes held and moved). On the TPU a Pallas kernel (``gdn_decode_step``):
 one grid step a (slot, block of head groups), the state block read once and
 written once where it lies (``input_output_aliases``); elsewhere the same
-arithmetic in ``jax.numpy``. ``serving.kv_cache.default_paged_impl`` says
-which, as for the paged attend.
+arithmetic in ``jax.numpy`` (``_step_oracle``).
+``tier.default_paged_impl`` says which, as for the paged attend.
 
 Both forms take the decay either as ONE value a head (``g [..., H]``, the
 gated delta rule as published) or as one a KEY CHANNEL (``g [..., H, dk]``,
@@ -58,6 +58,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.place import pallas_interpret
 from .flash_attention import LANES
+from .tier import default_paged_impl
 
 _HI = lax.Precision.HIGHEST
 #: bytes of one state block of the step kernel (it holds four: in and out,
@@ -306,8 +307,6 @@ def gdn_step(q, k, v, g, beta, state):
     (float32) against rows ``[0, B)`` of the packed ``state [rows, H / hg,
     dk, hg * dv]``: ``(o [B, H, dv], state)`` with those rows advanced and
     every other row as it was."""
-    from ..serving.kv_cache import default_paged_impl
-
     if default_paged_impl() == "oracle":
         return _step_oracle(q, k, v, g, beta, state)
     return _step_call(q, k, v, g, beta, state, interpret=pallas_interpret())

@@ -49,12 +49,13 @@ the kernel; a row's chunks are the ones a walk of its own would have made.
 Every step then hands its own slot's rows to its output block. With nothing
 shared every tile has one member and phase one makes no trip; a dead slot is
 in no tile and yields zeros. The plan is a few dozen small XLA ops: a model
-makes it once a decode step for all its layers
-(``serving.kv_cache.latent_decode_plan``) and hands it in.
+makes it once a decode step for all its layers (``latent_decode_plan``) and
+hands it in. ``latent_decode_attend`` is the entry a layer calls: this
+kernel, or its jnp reference (written out there) where ``tier`` says so.
 
-Numerics mirror ``serving.kv_cache.latent_decode_attend``'s oracle: q
-pre-scaled in its own dtype, float32 scores (exp2 domain), float32 online
-softmax, probabilities cast to the pool's dtype for the second matmul.
+Numerics mirror that reference: q pre-scaled in its own dtype, float32
+scores (exp2 domain), float32 online softmax, probabilities cast to the
+pool's dtype for the second matmul.
 
 ``latent_flash``, causal flash attention for queries that sit BEHIND a
 cached context, with a key in two parts and a value width of its own: a
@@ -103,6 +104,8 @@ from jax.sharding import PartitionSpec as P
 from ..core.place import pallas_interpret
 from .flash_attention import LOG2E, NEG_INF
 from .mesh import shard_kernel
+from .pools import paged_gather
+from .tier import default_paged_impl
 
 #: tokens fetched and computed together
 _CHUNK_TOKENS = 512
@@ -421,6 +424,46 @@ def _decode_call(table, pos, plan, q, pool, *, value_width: int,
         interpret=interpret,
         name="latent_paged_decode",
     )(table, pos, plan, q, pool)
+
+
+def latent_decode_plan(page_table, positions, page_size: int):
+    """What a decode step's ``latent_decode_attend`` calls share, computed
+    once for all the model's layers: in the ``pallas`` tier the kernel's
+    shared-walk plan (``shared_walk_plan``: which slots map the same
+    leading pages and score them together), read from the table and the
+    positions alone; None in the ``oracle`` tier, which gathers every
+    slot's own view."""
+    if default_paged_impl() != "pallas":
+        return None
+    return shared_walk_plan(page_table, positions, page_size)
+
+
+def latent_decode_attend(q, pool, page_table, positions, value_width: int,
+                         plan=None):
+    """Single-position attention over a pool of LATENT rows (one row a
+    token, keys and values the same bytes: ``models/decoder``'s latent
+    layer in its absorbed form), in the tier ``tier.default_paged_impl``
+    says. ``q [B, H, W]`` is pre-scaled and as wide as the pool's rows ``[P,
+    1, ps, W]``; a row's first ``value_width`` lanes are what is attended:
+    ``[B, H, value_width]`` out, in the pool's dtype. ``oracle`` gathers the
+    dense view and runs the einsums below, the kernel's reference (float32
+    scores, -1e30 mask, float32 softmax); ``pallas`` is
+    ``latent_paged_decode``, which fetches the leading pages that slots
+    share ONCE for all of them and scores them in one matmul (``plan``:
+    ``latent_decode_plan``'s, where the caller has it; the kernel's wrapper
+    computes it otherwise), then each slot's own pages. An empty slot's row,
+    which no caller reads, is the trash page's first token here and zeros
+    there."""
+    if default_paged_impl() == "pallas":
+        return latent_paged_decode(q, pool, page_table, positions,
+                                   value_width, plan)
+    rows = paged_gather(pool, page_table)[:, 0]                # [B, L, W]
+    s = jnp.einsum("bhw,blw->bhl", q, rows,
+                   preferred_element_type=jnp.float32)
+    valid = jnp.arange(rows.shape[1])[None, :] <= positions[:, None]
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1).astype(rows.dtype)
+    return jnp.einsum("bhl,blv->bhv", probs, rows[..., :value_width])
 
 
 # ------------------------------------------------- the expanded form

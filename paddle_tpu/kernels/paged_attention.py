@@ -31,9 +31,21 @@ GQA runs as a static per-KV-head-group loop: each group is a
 ``[rep, D] x [D, chunk_tokens]`` dot, so K/V are read once per group instead
 of being materialized at query-head width.
 
-Numerics mirror ``serving.kv_cache.decode_attend`` (the oracle): q
+Numerics mirror ``decode_attend``, the jnp reference below (the oracle): q
 pre-scaled in its own dtype, f32 scores/softmax, output cast to v's dtype —
 parity is asserted across ragged batches by tests/test_paged_kv.py.
+
+Behind the kernel stand its references over dense ``[B, H_kv, S_max, D]``
+caches (``decode_attend``, and ``extend_attend`` for ``T`` queries a slot)
+and the entries a model's layer calls: ``paged_decode_attend`` picks kernel
+or reference as ``tier.default_paged_impl`` says, ``paged_extend_attend``
+is the reference over ``pools.paged_gather``'s view in every tier. The
+dense pair is also the lockstep decode of ``GPTForCausalLM.generate`` and
+``incubate.nn.FusedMultiTransformer``'s ``time_step``, so the cached
+attention implementations cannot drift. Their numerics deliberately mirror
+``nn.functional._sdpa_ref`` (pre-scaled q, f32 logits, -1e30 masking, f32
+softmax), so cached decode logits match the full-prefix causal forward
+within float tolerance (tests/test_serving.py).
 """
 
 from __future__ import annotations
@@ -50,6 +62,8 @@ from jax.sharding import PartitionSpec as P
 from ..core.place import pallas_interpret
 from .flash_attention import LANES, LOG2E, NEG_INF
 from .mesh import shard_kernel
+from .pools import paged_gather
+from .tier import default_paged_impl
 
 
 # Both buffers of both pools have to sit well inside Mosaic's scoped VMEM
@@ -259,3 +273,107 @@ def _decode_call(table, pos, qs, k_pool, v_pool, *, interpret: bool,
         interpret=interpret,
         name="paged_decode" if window is None else "window_decode",
     )(table, pos, qs, k_pool, v_pool)
+
+
+# ---------------------------------------------------------------------------
+# The jnp references (dense ``[B, H_kv, S, D]`` caches) and the entries a
+# model's layer calls, which pick between kernel and reference (tier.py)
+# ---------------------------------------------------------------------------
+
+
+def _expand_kv_heads(t, rep: int):
+    """GQA: broadcast [B, H_kv, S, D] -> [B, H_kv*rep, S, D]. A broadcast
+    (insert group dim + reshape), not repeat: XLA keeps it fused into the
+    attention einsums instead of materializing full-width K/V."""
+    if rep == 1:
+        return t
+    B, Hkv, S, D = t.shape
+    return jnp.broadcast_to(t[:, :, None], (B, Hkv, rep, S, D)).reshape(
+        B, Hkv * rep, S, D)
+
+
+def decode_attend(q, k_cache, v_cache, positions, window=None):
+    """Single-position cached attention: q ``[B, H_q, T, D]`` (T=1 in
+    decode) against the full static cache ``[B, H_kv, S_max, D]``, masked to
+    the valid prefix ``key_pos <= positions`` (scalar or per-row ``[B]``),
+    with ``window`` to its last ``window`` keys (``key_pos > positions -
+    window``).
+
+    Matches _sdpa_ref numerics: q pre-scaled in its own dtype, f32 scores,
+    f32 softmax, output cast back to v's dtype.
+    """
+    D = q.shape[-1]
+    rep = q.shape[1] // k_cache.shape[1]
+    k = _expand_kv_heads(k_cache, rep)
+    v = _expand_kv_heads(v_cache, rep)
+    # scale as a q-dtype scalar: np.sqrt returns a STRONG f64 scalar, and
+    # under x64 `q * f64` upcasts the whole tensor to f64 before the cast
+    # back (found by the analysis dtype-f64 rule on serving_decode)
+    qf = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+    s = jnp.einsum("bhqd,bhkd->bhqk", qf, k,
+                   preferred_element_type=jnp.float32)
+    pos = jnp.asarray(positions)
+    key_pos = jnp.arange(k_cache.shape[2])
+    if pos.ndim == 0:
+        valid = key_pos[None, None, None, :] <= pos
+    else:
+        valid = key_pos[None, None, None, :] <= pos[:, None, None, None]
+    if window is not None:
+        valid = valid & (key_pos[None, None, None, :]
+                         > jnp.reshape(pos, (-1, 1, 1, 1)) - window)
+    s = jnp.where(valid, s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def extend_attend(q, k_cache, v_cache, positions):
+    """Multi-query cached attention: q ``[B, H_q, T, D]`` where query ``t``
+    of row ``b`` sits at absolute position ``positions[b] + t`` and may
+    attend to ``key_pos <= positions[b] + t`` — the suffix-prefill /
+    speculative-verify generalization of ``decode_attend`` (T=1 reduces to
+    it exactly). Same _sdpa_ref numerics: q pre-scaled in its own dtype,
+    f32 scores, -1e30 mask, f32 softmax."""
+    D = q.shape[-1]
+    rep = q.shape[1] // k_cache.shape[1]
+    k = _expand_kv_heads(k_cache, rep)
+    v = _expand_kv_heads(v_cache, rep)
+    qf = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+    s = jnp.einsum("bhqd,bhkd->bhqk", qf, k,
+                   preferred_element_type=jnp.float32)
+    T = q.shape[2]
+    qpos = jnp.asarray(positions)[:, None] + jnp.arange(T)[None, :]  # [B, T]
+    key_pos = jnp.arange(k_cache.shape[2])
+    valid = key_pos[None, None, None, :] <= qpos[:, None, :, None]
+    s = jnp.where(valid, s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def paged_decode_attend(q, k_pool, v_pool, page_table, positions,
+                        window=None):
+    """Single-position cached attention over block-paged pools — the paged
+    twin of ``decode_attend``, in the tier ``tier.default_paged_impl`` says
+    (``window``: a sliding layer's, both tiers the same lower bound).
+    ``oracle`` reconstructs the dense caches (``pools.paged_gather``) and
+    runs the einsum reference above; ``pallas`` runs the ragged kernel
+    (``paged_attention``) which touches only live pages. Both tiers
+    read the identical pool bytes, so they agree within float tolerance on
+    ragged batches and GQA; an empty slot's row, which no caller reads, is
+    the trash page's first token here and zeros there
+    (tests/test_paged_kv.py)."""
+    if default_paged_impl() == "oracle":
+        k = paged_gather(k_pool, page_table)
+        v = paged_gather(v_pool, page_table)
+        return decode_attend(q, k, v, positions, window)
+    return paged_attention(q, k_pool, v_pool, page_table, positions, window)
+
+
+def paged_extend_attend(q, k_pool, v_pool, page_table, positions):
+    """Multi-query cached attention over block-paged pools — the paged twin
+    of ``extend_attend``. The Pallas ragged kernel is single-query, so
+    every tier reconstructs the dense view (``paged_gather``) and runs the
+    einsum path. Verify steps are rare next to decode steps (one per k+1
+    emitted tokens), so the gather cost is amortized."""
+    k = paged_gather(k_pool, page_table)
+    v = paged_gather(v_pool, page_table)
+    return extend_attend(q, k, v, positions)

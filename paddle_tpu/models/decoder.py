@@ -58,7 +58,7 @@ sliding_window)``), with a page count, an allocator and a page table that
 the engine slides (serving/README.md). Three forms of one layer: prefill a
 causal BAND (``attend``'s ``band``: a chunk of queries is handed the keys
 its band reaches, and turned a chunk at a time), extend over a view of the
-window and the new tokens (``kv_cache.window_blocks``), decode the paged
+window and the new tokens (``pools.window_blocks``), decode the paged
 kernel with a ``window`` (``kernels/paged_attention``: the walk starts at
 the window's first block).
 
@@ -127,6 +127,11 @@ layer's one row a token),
 ``state_pools()`` the slot-indexed state of the layers that keep a
 recurrence; ``prefill_with_cache`` / ``extend_step`` /
 ``decode_step`` are pure functions of (parameters, pools, page table).
+What they do to a pool is below this module, in ``kernels/``: the write and
+the view through a table (``pools.py``), the paged attends, each kernel with
+its reference (``paged_attention.py``, ``latent_attention.py``,
+``sparse_attention.py``, ``gated_delta.py``), and ``tier.py``, which says
+which of the two a trace bakes in. Nothing here imports ``serving``.
 ONE attention routine (``attend``) serves all three: queries at
 ``start .. start + T - 1`` against views of the pools, in chunks of queries
 so that no ``[T, L]`` float32 array larger than a chunk exists; prefill is
@@ -151,6 +156,9 @@ import numpy as np
 from jax import lax
 
 from ..core.tensor import Parameter, Tensor
+from ..kernels import latent_attention as _latent
+from ..kernels import pools as _pools, tier as _tier
+from ..kernels.paged_attention import paged_decode_attend
 from ..nn.layer.layers import Layer
 
 _NEG_INF = -1e30
@@ -193,7 +201,7 @@ class DecoderConfig:
     layer_types: Optional[Tuple[str, ...]] = None
     # how a dense layer's K and V pages lie: "token" [pages, 1, page,
     # H_kv * D], "head" [pages, H_kv, page, D] (what kernels/paged_attention
-    # reads: its decode goes through serving.kv_cache.paged_decode_attend)
+    # reads: its decode goes through its paged_decode_attend)
     kv_layout: str = "token"
     # a dense layer's output gated element-wise by sigmoid(h Wg) before Wo
     attn_output_gate: bool = False
@@ -510,7 +518,7 @@ def attend(cfg, q, k_view, v_view, qpos, index=None, window=None,
     chunk at a time (a 18k-token prompt's 128 query heads turned whole, in
     float32, stood in memory three times over: 3.4 GB).
     Computed in chunks of ``cfg.query_chunk`` queries. Numerics as
-    ``serving.kv_cache.extend_attend``: q pre-scaled in its own dtype,
+    ``kernels.paged_attention.extend_attend``: q pre-scaled in its own dtype,
     float32 scores, -1e30 mask, float32 softmax."""
     from ..kernels.sparse_attention import topk_mask
 
@@ -613,7 +621,7 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     "sliding" sees the last ``cfg.sliding_window`` keys alone: its prefill
     is a causal band (``attend``'s), its extend reads a view of the window
     and the new tokens (not of the whole table), its decode walks the
-    window's pages (``kv_cache.paged_decode_attend``'s ``window``). Without ``cache``
+    window's pages (``paged_decode_attend``'s ``window``). Without ``cache``
     (prefill) the keys are the ones just computed; with ``cache`` (the
     layer's pools and the page table) they are written into the pools first
     and read back through the table. Returns (out [B, T, hidden], new):
@@ -622,8 +630,6 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     pools with one. ``lengths`` and ``cuts`` are not its concern: a padded
     token's keys lie behind every real query, and its keys are a function of
     position."""
-    from ..serving import kv_cache as _kvc
-
     B, T, _ = h.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -694,35 +700,35 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
         return out(o), tuple(fresh)
 
     *pools, table = cache
-    pools = [_kvc.paged_write_kv(pool, new, table, start)
+    pools = [_pools.paged_write_kv(pool, new, table, start)
              for pool, new in zip(pools, fresh)]
     L = table.shape[1] * pools[0].shape[2]
     if window is not None:
         with jax.named_scope("attn/window"):
             if T == 1:
-                o = _kvc.paged_decode_attend(
+                o = paged_decode_attend(
                     q.transpose(0, 2, 1, 3), pools[0], pools[1], table, start,
                     window=window).transpose(0, 2, 1, 3)
             else:
                 # the window before the first new token, and the new tokens
-                first, sub = _kvc.window_blocks(table, start, pools[0].shape[2],
-                                                window, T)
-                view = lambda pool: _kvc.paged_gather(pool, sub) \
+                first, sub = _pools.window_blocks(
+                    table, start, pools[0].shape[2], window, T)
+                view = lambda pool: _pools.paged_gather(pool, sub) \
                     .transpose(0, 2, 1, 3)
                 o = attend(cfg, q, view(pools[0]), view(pools[1]), pos,
                            window=window, first=first)
     elif head_major and T == 1:
-        # the paged attend, kernel or oracle as kv_cache says
+        # the paged attend, kernel or oracle as kernels/tier says
         with _scope(cfg, "attn/full"):
-            o = _kvc.paged_decode_attend(q.transpose(0, 2, 1, 3), pools[0],
-                                         pools[1], table, start)
+            o = paged_decode_attend(q.transpose(0, 2, 1, 3), pools[0],
+                                    pools[1], table, start)
         o = o.transpose(0, 2, 1, 3)
-    elif sparse and T == 1 and _kvc.default_paged_impl() == "pallas":
+    elif sparse and T == 1 and _tier.default_paged_impl() == "pallas":
         from ..kernels.sparse_attention import (selected_rows,
                                                 sparse_paged_decode)
 
         qi, w, _ = index
-        ki_view = _kvc.paged_gather(pools[2], table)[:, 0, :, :Di]  # [B,L,Di]
+        ki_view = _pools.paged_gather(pools[2], table)[:, 0, :, :Di]  # B,L,Di
         s = jnp.einsum("bhd,bld->bhl", qi[:, 0], ki_view,
                        preferred_element_type=jnp.float32)
         score = jnp.sum(jnp.maximum(s, 0.0) * w[:, 0, :, None], axis=1)
@@ -733,14 +739,14 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
         o = o[:, None]
     else:
         if head_major:
-            view = lambda pool, heads: _kvc.paged_gather(pool, table) \
+            view = lambda pool, heads: _pools.paged_gather(pool, table) \
                 .transpose(0, 2, 1, 3)
         else:
-            view = lambda pool, heads: _kvc.paged_gather(pool, table)[:, 0] \
+            view = lambda pool, heads: _pools.paged_gather(pool, table)[:, 0] \
                 .reshape(B, L, heads, -1)
         idx_view = None if not sparse else (
             index[0], index[1],
-            _kvc.paged_gather(pools[2], table)[:, 0, :, :Di])
+            _pools.paged_gather(pools[2], table)[:, 0, :, :Di])
         o = attend(cfg, q, view(pools[0], Hkv), view(pools[1], Hkv), pos,
                    idx_view)
     return out(o), tuple(pools)
@@ -830,7 +836,6 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
     the same states and tails ``[B * (n + 1), ...]``, a row's cuts before
     its end, for the engine to install."""
     from ..kernels import gated_delta as _gdn
-    from ..serving import kv_cache as _kvc
 
     B, T, _ = h.shape
     H, dk, dv, C = _gdn_widths(cfg)
@@ -908,8 +913,8 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
             new = (S.reshape((-1,) + S.shape[2:]),
                    tails.reshape((-1,) + tails.shape[2:]))
         else:               # B = 1: the cuts' rows, then the end's
-            new = (_kvc.write_state_rows(state, S[0], where[1]),
-                   _kvc.write_state_rows(conv, tails[0], where[1]))
+            new = (_pools.write_state_rows(state, S[0], where[1]),
+                   _pools.write_state_rows(conv, tails[0], where[1]))
     o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) \
         * p[pre + ".o_norm.weight"].astype(f32)
     if channel:
@@ -967,8 +972,6 @@ def _latent_attend_flash(cfg, p, pre, cq, c_view, kpe_view, qpos):
     ``kernels/latent_attention.latent_flash`` (causal behind the cached
     context, the one rotary key shared by the group's heads, a value width
     of its own), whose score tiles never leave VMEM."""
-    from ..kernels.latent_attention import latent_flash
-
     B, T, _ = cq.shape
     L = c_view.shape[1]
     H, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
@@ -990,8 +993,8 @@ def _latent_attend_flash(cfg, p, pre, cq, c_view, kpe_view, qpos):
         with jax.named_scope("mla/expand"):
             kn = jnp.einsum("blr,rhd->bhld", c_view, cut(wk))
             v = jnp.einsum("blr,rhd->bhld", c_view, cut(wv))
-        o = latent_flash(fold(qn), fold(qp), fold(kn), kpe_view, fold(v),
-                         qpos[:, 0], G)
+        o = _latent.latent_flash(fold(qn), fold(qp), fold(kn), kpe_view,
+                                 fold(v), qpos[:, 0], G)
         o = o.reshape(B, G, T, dv).transpose(0, 2, 1, 3).reshape(B, T, G * dv)
         return lax.dynamic_update_slice_in_dim(out, o, i * G * dv, axis=2)
 
@@ -1005,7 +1008,7 @@ def latent_attend(cfg, p, pre, cq, c_view, kpe_view, qpos):
     against the views ``c_view [B, L, rkv]`` / ``kpe_view [B, L, dr]`` of
     the cached latents (view position = sequence position), through ``wo``:
     ``[B, T, hidden]`` out. On the TPU ``_latent_attend_flash``
-    (``serving.kv_cache.default_paged_impl`` says which); elsewhere, and as
+    (``kernels/tier.default_paged_impl`` says which); elsewhere, and as
     its oracle, plain ``jax.numpy``: queries go in chunks of
     ``cfg.query_chunk`` (projected from ``cq``, attended and put through
     ``wo`` chunk by chunk: a 34k-token prompt's q alone would be 1.7 GB),
@@ -1014,9 +1017,7 @@ def latent_attend(cfg, p, pre, cq, c_view, kpe_view, qpos):
     latents (``mla/expand``), scored, and folded into a float32 online
     softmax, so no expanded key outlives its block. Numerics as ``attend``:
     q pre-scaled in its own dtype, float32 scores, -1e30 mask."""
-    from ..serving import kv_cache as _kvc
-
-    if _kvc.default_paged_impl() == "pallas":
+    if _tier.default_paged_impl() == "pallas":
         return _latent_attend_flash(cfg, p, pre, cq, c_view, kpe_view, qpos)
     B, T, _ = cq.shape
     L = c_view.shape[1]
@@ -1088,11 +1089,9 @@ def latent_attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     extend run the expanded form over the rows (``latent_attend``); decode
     (``T = 1`` over a cache) never expands: the absorbed form, ``wk_b``
     folded into the query and ``wv_b`` applied to the attended latents
-    (``serving.kv_cache.latent_decode_attend``: the Pallas kernel
-    ``kernels/latent_attention.latent_paged_decode`` or its oracle).
+    (``kernels/latent_attention.latent_decode_attend``: the Pallas kernel
+    ``latent_paged_decode`` or its reference).
     Returns (out, new) as ``attention`` does."""
-    from ..serving import kv_cache as _kvc
-
     B, T, _ = h.shape
     H, rkv = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -1108,9 +1107,9 @@ def latent_attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
         return latent_attend(cfg, p, pre, cq, c, kpe, pos), (fresh,)
 
     pool, table, *plan = cache      # a decode step's shared-walk plan
-    pool = _kvc.paged_write_kv(pool, fresh, table, start)
+    pool = _pools.paged_write_kv(pool, fresh, table, start)
     if T > 1:
-        rows = _kvc.paged_gather(pool, table)[:, 0]            # [B, L, W]
+        rows = _pools.paged_gather(pool, table)[:, 0]          # [B, L, W]
         return latent_attend(cfg, p, pre, cq, rows[..., :rkv],
                              rows[..., rkv:rkv + dr], pos), (pool,)
     qn, qp = _latent_queries(cfg, p, pre, cq, pos)
@@ -1120,7 +1119,7 @@ def latent_attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     q = jnp.concatenate(
         [ql, qp[:, 0], jnp.zeros((B, H, W - rkv - dr), ql.dtype)], axis=-1)
     with jax.named_scope("mla/decode"):
-        ol = _kvc.latent_decode_attend(
+        ol = _latent.latent_decode_attend(
             q * jnp.asarray(softmax_scale(cfg, dn + dr), q.dtype), pool,
             table, start, rkv, *plan)                          # [B, H, rkv]
     with jax.named_scope("mla/absorb"):
@@ -1251,8 +1250,8 @@ def step_stats(cfg) -> Tuple[str, ...]:
     attention read, summed over the live slots, and ``shared_walk_tokens``:
     those of them that slots on one document scored TOGETHER, each page
     fetched once for all of them (the sum over the step's plan,
-    ``serving.kv_cache.latent_decode_plan``: 0 in the oracle tier, which
-    has none); both 0 in a layer of another kind. Of a model with sliding
+    ``kernels/latent_attention.latent_decode_plan``: 0 in the oracle tier,
+    which has none); both 0 in a layer of another kind. Of a model with sliding
     layers, ``window_tokens_read`` / ``full_tokens_read``: the cached tokens
     a sliding layer (``min(context, sliding_window)`` a slot) and a full
     one (the context) attended, summed over the live slots, each 0 in a
@@ -1431,9 +1430,7 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
             live = table[:, 0] >= 0
             read = jnp.sum(jnp.where(live, start + x.shape[1], 0))
             if plan:
-                from ..kernels.latent_attention import shared_walk_tokens
-
-                walk = shared_walk_tokens(plan[0], pool.shape[2])
+                walk = _latent.shared_walk_tokens(plan[0], pool.shape[2])
         stats = jnp.concatenate(
             [stats, jnp.stack([read, walk]).astype(jnp.int32)])
     if "sliding" in cfg.kinds:
@@ -1530,10 +1527,8 @@ class DecoderLM(Layer):
         if caches is not None and latent and ids.shape[1] == 1:
             # a decode step over latent pools: which slots walk which pages
             # together is read from the table ONCE, for every layer
-            from ..serving.kv_cache import latent_decode_plan
-
             pool, table = caches[latent[0]]
-            plan = latent_decode_plan(table, start, pool.shape[2])
+            plan = _latent.latent_decode_plan(table, start, pool.shape[2])
             if plan is not None:
                 caches = [e + (plan,) if l in latent else e
                           for l, e in enumerate(caches)]

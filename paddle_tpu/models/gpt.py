@@ -211,10 +211,12 @@ class GPTAttention(Layer):
         ``[B, H_kv, S, D]`` for the engine to install in its static cache.
         Decode (``kv_cache=(k, v)`` each ``[B, H_kv, S_max, D]``): write the
         incoming token's K/V at ``cache_positions`` and attend the valid
-        prefix through serving.kv_cache's shared decode helpers (the same
-        math FusedMultiTransformer's time_step path uses)."""
+        prefix through kernels/paged_attention's shared decode helpers (the
+        same math FusedMultiTransformer's time_step path uses)."""
+        from ..kernels.paged_attention import (
+            decode_attend, paged_decode_attend, paged_extend_attend)
+        from ..kernels.pools import paged_write_kv, write_kv
         from ..ops._dispatch import apply, as_tensor
-        from ..serving import kv_cache as _kvc
 
         cfg = self.cfg
         Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -242,7 +244,7 @@ class GPTAttention(Layer):
         if len(kv_cache) == 3:
             # block-paged cache: (k_pool, v_pool, page_table) — the table
             # routes this slot's token(s) to pages; the paged attend reads
-            # only live pages (serving/kv_cache.py dispatch: oracle einsum
+            # only live pages (paged_decode_attend's dispatch: oracle einsum
             # on CPU, Pallas ragged kernel on TPU). S is static: S=1 is the
             # plain decode step, S>1 the multi-token extend (suffix prefill
             # after a prefix-cache splice / speculative verify-k), where
@@ -251,14 +253,14 @@ class GPTAttention(Layer):
 
             def _decode_paged(qv, kv_, vv, kcv, vcv, tblv, posv):
                 qT = qv.transpose(0, 2, 1, 3)   # [B, Hq, S, D]
-                kc2 = _kvc.paged_write_kv(kcv, kv_.transpose(0, 2, 1, 3),
-                                          tblv, posv)
-                vc2 = _kvc.paged_write_kv(vcv, vv.transpose(0, 2, 1, 3),
-                                          tblv, posv)
+                kc2 = paged_write_kv(kcv, kv_.transpose(0, 2, 1, 3), tblv,
+                                     posv)
+                vc2 = paged_write_kv(vcv, vv.transpose(0, 2, 1, 3), tblv,
+                                     posv)
                 if S == 1:
-                    o = _kvc.paged_decode_attend(qT, kc2, vc2, tblv, posv)
+                    o = paged_decode_attend(qT, kc2, vc2, tblv, posv)
                 else:
-                    o = _kvc.paged_extend_attend(qT, kc2, vc2, tblv, posv)
+                    o = paged_extend_attend(qT, kc2, vc2, tblv, posv)
                 return o.transpose(0, 2, 1, 3), kc2, vc2
 
             o, kc2, vc2 = apply("serving_decode_attn", _decode_paged, q, k,
@@ -276,9 +278,9 @@ class GPTAttention(Layer):
 
         def _decode(qv, kv_, vv, kcv, vcv, posv):
             qT = qv.transpose(0, 2, 1, 3)   # [B, Hq, 1, D]
-            kc2 = _kvc.write_kv(kcv, kv_.transpose(0, 2, 1, 3), posv)
-            vc2 = _kvc.write_kv(vcv, vv.transpose(0, 2, 1, 3), posv)
-            o = _kvc.decode_attend(qT, kc2, vc2, posv)
+            kc2 = write_kv(kcv, kv_.transpose(0, 2, 1, 3), posv)
+            vc2 = write_kv(vcv, vv.transpose(0, 2, 1, 3), posv)
+            o = decode_attend(qT, kc2, vc2, posv)
             return o.transpose(0, 2, 1, 3), kc2, vc2
 
         o, kc2, vc2 = apply("serving_decode_attn", _decode, q, k, v,

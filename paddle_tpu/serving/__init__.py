@@ -13,8 +13,11 @@ Public surface:
   (fixed-size pages + per-slot page table) and the exact-cover free-list
   allocator the scheduler drives.
 - :func:`paged_write_kv` / :func:`paged_gather` /
-  :func:`paged_decode_attend` — the write and attend over it. The attend
-  is the Pallas kernel on a TPU and the oracle elsewhere;
+  :func:`paged_decode_attend` — the write and attend over it, which a
+  model's layers call: they live below the model (``kernels/pools.py``,
+  ``kernels/paged_attention.py``) and are re-exported here. The attend
+  is the Pallas kernel on a TPU and the oracle elsewhere
+  (``kernels/tier.py``);
   :func:`use_paged_attention_impl` is the tests' seam to pin the tier
   (``oracle`` | ``pallas``) for traces entered under it.
 - :func:`write_kv`, :func:`decode_attend` — the dense
@@ -39,19 +42,23 @@ See ``paddle_tpu/serving/README.md`` for the design and metric names.
 
 from __future__ import annotations
 
-from .engine import Engine, EngineConfig, cached_generate  # noqa: F401
-from .kv_cache import (  # noqa: F401
-    PAGE_SENTINEL,
-    PagedKVCache,
+# the device-side functions live below the model (kernels/); the package's
+# public names for them stay, re-exported downward
+from ..kernels.paged_attention import (  # noqa: F401
     decode_attend,
     extend_attend,
     paged_decode_attend,
     paged_extend_attend,
+)
+from ..kernels.pools import (  # noqa: F401
+    PAGE_SENTINEL,
     paged_gather,
     paged_write_kv,
-    use_paged_attention_impl,
     write_kv,
 )
+from ..kernels.tier import use_paged_attention_impl  # noqa: F401
+from .engine import Engine, EngineConfig, cached_generate  # noqa: F401
+from .kv_cache import PagedKVCache  # noqa: F401
 from .prefix_cache import PrefixCache  # noqa: F401
 from .request_trace import (  # noqa: F401
     RequestTracer,

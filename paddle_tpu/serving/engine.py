@@ -1,13 +1,15 @@
 """TPU-native LLM serving engine: static-shape decode + continuous batching.
 
-One path, drawn in four boxes whose arrows point one way:
+One path, drawn in four boxes whose arrows point one way, which the imports
+follow (``serving`` -> ``models`` -> ``kernels``; tests/test_layering.py):
 
 ``Engine`` (host: scheduler, ``PageAllocator``, ``PrefixCache``, the page
-table) -> four static-shape programs ``(params, *pools, ...)`` over the
-block-paged pools of ``kv_cache.PagedKVCache`` -> the model's protocol
+tables of ``kv_cache.PagedKVCache``) -> four static-shape programs
+``(params, *pools, ...)`` over its block-paged pools -> the model's protocol
 (``prefill_with_cache`` / ``decode_step`` / ``extend_step`` on paged
-entries) -> ``kv_cache.paged_write_kv`` and the attend, where
-``kv_cache.default_paged_impl`` alone says kernel or oracle.
+entries) -> ``kernels``: ``pools.paged_write_kv`` and the attend, each
+kernel beside its reference, where ``kernels/tier.default_paged_impl`` alone
+says which of the two runs.
 
 - **prefill/T** — one AOT-compiled executable per prompt-length bucket
   (powers of two up to ``max_seq_len``): the padded prompt runs the causal
@@ -57,13 +59,13 @@ from jax import lax
 from ..core import random as _random
 from ..core.autograd import no_grad
 from ..core.tensor import Tensor
+from ..kernels.pools import paged_write_kv, write_kv, write_state_rows
 from ..observability import instrument as _obs
 from ..observability import memory as _obs_memory
 from ..observability import metrics as _metrics
 from ..observability.tracing import span as _span
 from . import sampling as _sampling
-from .kv_cache import (PAGE_SENTINEL, PagedKVCache, _layer_buffers,
-                       paged_write_kv, write_kv, write_state_rows)
+from .kv_cache import PAGE_SENTINEL, PagedKVCache, _layer_buffers
 from .prefix_cache import PrefixCache
 from .request_trace import RequestTracer, SLOConfig
 from .sampling import SamplingParams

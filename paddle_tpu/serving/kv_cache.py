@@ -1,30 +1,23 @@
-"""Static-shape KV cache: the serving engine's HBM-resident decode state.
+"""The host's page manager: ``PagedKVCache``, the serving engine's
+HBM-resident decode state and who maps what of it.
 
-The engine's cache is ``PagedKVCache``: per layer and pool one
-``[num_pages, heads, page_size, width]`` buffer preallocated at engine
-construction, plus a host page table, so every prefill and every decode
-step runs at a FIXED shape: XLA compiles the prefill once per prompt bucket
-and the decode step exactly once, no matter how many tokens or requests
-flow through. ``paged_write_kv`` writes it a page at a time; the attend
-over it is the Pallas kernel or the oracle, and ``default_paged_impl`` is
-the one function that says which.
+Per layer and pool one ``[num_pages, heads, page_size, width]`` buffer
+preallocated at engine construction, plus a host page table a group, so
+every prefill and every decode step runs at a FIXED shape: XLA compiles the
+prefill once per prompt bucket and the decode step exactly once, no matter
+how many tokens or requests flow through. Only the engine holds one.
 
-The dense helpers (``write_kv`` / ``decode_attend`` / ``extend_attend``
-over ``[B, H_kv, S_max, D]`` buffers) are the oracle of the paged attends
-(over ``paged_gather``) and the SHARED lockstep decode path: both
-``GPTForCausalLM.generate`` (serving/engine.py ``cached_generate``) and
-``incubate.nn.FusedMultiTransformer``'s ``time_step`` decode route through
-them, so the two cached-attention implementations cannot drift.
-
-Numerics deliberately mirror ``nn.functional._sdpa_ref`` (pre-scaled q,
-f32 logits, -1e30 masking, f32 softmax) so cached decode logits match the
-full-prefix causal forward within float tolerance — asserted by
-tests/test_serving.py.
+What runs on the device over these pools is below the model, in
+``kernels/``: a layer writes a pool and views it through a table with
+``kernels/pools.py`` (``paged_write_kv``, ``paged_gather``), and attends
+over it with ``kernels/paged_attention.py`` / ``latent_attention.py``, each
+kernel beside its jnp reference; ``kernels/tier.py`` says which of the two a
+trace bakes in. The one program built here is the copy-on-write page copy
+(``copy_page_exe``).
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import List, Optional, Tuple
 
 import jax
@@ -32,87 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..core.place import on_tpu
-
-_NEG_INF = -1e30  # a plain float: a jnp constant here would initialize the backend at import
-
-#: page-table entry marking an unallocated block. Device code never branches
-#: on it — lookups clamp sentinels to page 0, the reserved TRASH page the
-#: allocator never hands out, so gathers/scatters stay in-bounds and the
-#: decode mask (``key_pos <= position``) keeps trash bytes out of the math.
-PAGE_SENTINEL = -1
-
-
-def write_kv(cache, new, positions):
-    """Write new K (or V) entries into a ``[B, H_kv, S_max, D]`` cache.
-
-    ``positions`` scalar: contiguous write of ``new [B, H_kv, T, D]``
-    starting at that sequence index (the prefill / shared-step case —
-    ``lax.dynamic_update_slice``, batch must match the cache's).
-    ``positions`` ``[B]``: per-row single-token scatter of
-    ``new [B, H_kv, 1, D]`` at each row's own index (the continuous-batching
-    decode case, where slots sit at different sequence positions).
-    """
-    new = new.astype(cache.dtype)
-    positions = jnp.asarray(positions)
-    if positions.ndim == 0:
-        zero = jnp.zeros((), positions.dtype)
-        return lax.dynamic_update_slice(cache, new, (zero, zero, positions, zero))
-    # one row per (slot, head), indexed on the two LEADING dimensions of the
-    # [B*H_kv, S_max, D] view: the form XLA scatters into a donated cache
-    # where it lies. Indexed on (slot, position) of the 4-D cache, around
-    # the head dimension, XLA transposes the whole cache to put the indexed
-    # dimensions first, and back, every step.
-    B, Hkv, S, D = cache.shape
-    flat = cache.reshape(B * Hkv, S, D)
-    flat = flat.at[jnp.arange(B * Hkv), jnp.repeat(positions, Hkv), :].set(
-        new[:, :, 0, :].reshape(B * Hkv, D))
-    return flat.reshape(B, Hkv, S, D)
-
-
-def _expand_kv_heads(t, rep: int):
-    """GQA: broadcast [B, H_kv, S, D] -> [B, H_kv*rep, S, D]. A broadcast
-    (insert group dim + reshape), not repeat: XLA keeps it fused into the
-    attention einsums instead of materializing full-width K/V."""
-    if rep == 1:
-        return t
-    B, Hkv, S, D = t.shape
-    return jnp.broadcast_to(t[:, :, None], (B, Hkv, rep, S, D)).reshape(
-        B, Hkv * rep, S, D)
-
-
-def decode_attend(q, k_cache, v_cache, positions, window=None):
-    """Single-position cached attention: q ``[B, H_q, T, D]`` (T=1 in
-    decode) against the full static cache ``[B, H_kv, S_max, D]``, masked to
-    the valid prefix ``key_pos <= positions`` (scalar or per-row ``[B]``),
-    with ``window`` to its last ``window`` keys (``key_pos > positions -
-    window``).
-
-    Matches _sdpa_ref numerics: q pre-scaled in its own dtype, f32 scores,
-    f32 softmax, output cast back to v's dtype.
-    """
-    D = q.shape[-1]
-    rep = q.shape[1] // k_cache.shape[1]
-    k = _expand_kv_heads(k_cache, rep)
-    v = _expand_kv_heads(v_cache, rep)
-    # scale as a q-dtype scalar: np.sqrt returns a STRONG f64 scalar, and
-    # under x64 `q * f64` upcasts the whole tensor to f64 before the cast
-    # back (found by the analysis dtype-f64 rule on serving_decode)
-    qf = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qf, k,
-                   preferred_element_type=jnp.float32)
-    pos = jnp.asarray(positions)
-    key_pos = jnp.arange(k_cache.shape[2])
-    if pos.ndim == 0:
-        valid = key_pos[None, None, None, :] <= pos
-    else:
-        valid = key_pos[None, None, None, :] <= pos[:, None, None, None]
-    if window is not None:
-        valid = valid & (key_pos[None, None, None, :]
-                         > jnp.reshape(pos, (-1, 1, 1, 1)) - window)
-    s = jnp.where(valid, s, _NEG_INF)
-    probs = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+from ..kernels.pools import PAGE_SENTINEL
 
 
 def _layer_buffers(num_layers: int, shape, dtype) -> Tuple[jax.Array, ...]:
@@ -126,223 +39,6 @@ def _layer_buffers(num_layers: int, shape, dtype) -> Tuple[jax.Array, ...]:
 
 def _tuple_nbytes(*pools) -> int:
     return int(sum(a.size * a.dtype.itemsize for p in pools for a in p))
-
-
-# ---------------------------------------------------------------------------
-# Block-paged cache (vLLM PagedAttention layout, static-shape edition)
-# ---------------------------------------------------------------------------
-
-_PAGED_IMPL = None  # the tier a test pinned (use_paged_attention_impl)
-_PAGED_IMPLS = ("oracle", "pallas")
-
-
-def default_paged_impl() -> str:
-    """Which paged-attend implementation a trace bakes in — the ONE place
-    that says: ``pallas`` (the ragged kernels — compiled Mosaic on TPU, the
-    Pallas interpreter on cpu) on TPU, the ``oracle`` (gather + dense
-    ``decode_attend`` einsum) elsewhere, unless a test pinned the tier with
-    ``use_paged_attention_impl``."""
-    if _PAGED_IMPL is not None:
-        return _PAGED_IMPL
-    return "pallas" if on_tpu() else "oracle"
-
-
-@contextlib.contextmanager
-def use_paged_attention_impl(impl: str):
-    """Pin the paged-attend implementation for traces entered under the
-    context: the seam by which a CPU test runs the kernels under the
-    interpreter and ``chip_smoke.py`` runs the oracle on the chip. The
-    choice is baked in at TRACE time, so wrap the engine's construction
-    and its first ``generate`` / ``compile_programs`` (programs already
-    compiled are unaffected)."""
-    global _PAGED_IMPL
-    if impl not in _PAGED_IMPLS:
-        raise ValueError(f"paged impl {impl!r}; want one of {_PAGED_IMPLS}")
-    prev, _PAGED_IMPL = _PAGED_IMPL, impl
-    try:
-        yield
-    finally:
-        _PAGED_IMPL = prev
-
-
-def paged_write_kv(pool, new, page_table, positions):
-    """Write ``T`` tokens' K (or V) per slot into a ``[P, H_kv, ps, D]``
-    page pool: token ``t`` of row ``b`` of ``new [B, H_kv, T, D]`` lands in
-    page ``page_table[b, (positions[b]+t) // ps]`` at offset
-    ``(positions[b]+t) % ps``. ``T`` is static (1 for plain decode, ``k+1``
-    for speculative verify, a bucket for suffix prefill).
-
-    The update is made a PAGE at a time: gather the pages the ``T``
-    positions of each row can touch, lay the new rows into them, scatter
-    whole pages back — one gather and one scatter whatever ``T`` is, both
-    indexed on the pool's leading dimension only. That is the form XLA
-    applies in place to a donated pool in the layout the pool is stored in
-    (a scatter indexed on page AND offset makes the TPU compiler transpose
-    the whole pool to a layout of its own and back, every step).
-
-    Sentinel entries clamp to the trash page (slots without a live request
-    all write identical token-0 state there, so the race is benign), and
-    writes past the table's capacity ``num_blocks * ps`` route to the trash
-    page too — a verify step near the end of a sequence can draft past
-    ``S_max`` without going out of bounds; the host caps how many of those
-    tokens it accepts. A touched page in which no token lands is written
-    back as it was read."""
-    ps = pool.shape[2]
-    nb = page_table.shape[1]
-    pos = jnp.asarray(positions)
-    T = new.shape[2]
-    new = new.astype(pool.dtype)
-    nblk = (T + ps - 2) // ps + 1  # pages T consecutive positions can span
-    block = (pos // ps)[:, None] + jnp.arange(nblk)            # [B, nblk]
-    pages = jnp.take_along_axis(page_table, jnp.minimum(block, nb - 1),
-                                axis=1)
-    pages = jnp.where(block < nb, jnp.maximum(pages, 0), 0)
-    # which token, if any, lands in offset s of touched block j of row b
-    t = block[:, :, None] * ps + jnp.arange(ps) - pos[:, None, None]
-    rows = jnp.take_along_axis(                       # [B, nblk, H_kv, ps, D]
-        new[:, None], jnp.clip(t, 0, T - 1)[:, :, None, :, None], axis=3)
-    lands = ((t >= 0) & (t < T))[:, :, None, :, None]
-    merged = jnp.where(lands, rows, pool[pages])
-    return pool.at[pages].set(merged)
-
-
-def write_state_rows(buf, new, rows):
-    """``new [n, ...]`` onto rows ``rows [n]`` (run-time values) of the state
-    buffer ``buf [rows, ...]``, one after the other where it lies: where two
-    name the same row, the later one stands."""
-    for i in range(new.shape[0]):
-        buf = lax.dynamic_update_slice_in_dim(
-            buf, new[i:i + 1].astype(buf.dtype), rows[i], axis=0)
-    return buf
-
-
-def paged_gather(pool, page_table):
-    """Materialize the dense ``[B, H_kv, num_blocks*ps, D]`` view of a page
-    pool under a table — the oracle path's cache reconstruction (sentinels
-    clamp to trash, so dense position ``j`` of an unallocated block holds
-    trash bytes that the decode mask never admits)."""
-    g = pool[jnp.maximum(page_table, 0)]        # [B, nb, Hkv, ps, D]
-    B, nb, Hkv, ps, D = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, nb * ps, D)
-
-
-def window_blocks(page_table, start, page_size: int, window: int, T: int):
-    """What an extend of ``T`` tokens at ``start [B]`` reads of a sliding
-    layer's pools: ``(first [B], sub [B, n])``, the sequence position of
-    the view's first token and the table entries of the blocks from the one
-    that holds ``start - window + 1`` to the one that holds ``start + T -
-    1`` (``n`` is static: blocks past the table's end read as sentinels).
-    ``paged_gather(pool, sub)`` is then the window and the new tokens, not
-    the whole table's view."""
-    nb = page_table.shape[1]
-    back = (window + page_size - 2) // page_size
-    n = back + (T + page_size - 2) // page_size + 1
-    fb = jnp.maximum(start // page_size - back, 0)
-    blocks = fb[:, None] + jnp.arange(n, dtype=fb.dtype)[None, :]
-    sub = jnp.take_along_axis(page_table, jnp.minimum(blocks, nb - 1), axis=1)
-    return fb * page_size, jnp.where(blocks < nb, sub, PAGE_SENTINEL)
-
-
-def paged_decode_attend(q, k_pool, v_pool, page_table, positions,
-                        window=None):
-    """Single-position cached attention over block-paged pools — the paged
-    twin of ``decode_attend``, in the tier ``default_paged_impl`` says
-    (``window``: a sliding layer's, both tiers the same lower bound).
-    ``oracle`` reconstructs the dense caches (``paged_gather``) and runs
-    the einsum oracle; ``pallas`` runs the Pallas ragged kernel
-    (kernels/paged_attention.py) which touches only live pages. Both tiers
-    read the identical pool bytes, so they agree within float tolerance on
-    ragged batches and GQA; an empty slot's row, which no caller reads, is
-    the trash page's first token here and zeros there
-    (tests/test_paged_kv.py)."""
-    if default_paged_impl() == "oracle":
-        k = paged_gather(k_pool, page_table)
-        v = paged_gather(v_pool, page_table)
-        return decode_attend(q, k, v, positions) if window is None \
-            else decode_attend(q, k, v, positions, window)
-    from ..kernels.paged_attention import paged_attention
-
-    if window is not None:
-        return paged_attention(q, k_pool, v_pool, page_table, positions,
-                               window)
-    return paged_attention(q, k_pool, v_pool, page_table, positions)
-
-
-def latent_decode_plan(page_table, positions, page_size: int):
-    """What a decode step's ``latent_decode_attend`` calls share, computed
-    once for all the model's layers: in the ``pallas`` tier the kernel's
-    shared-walk plan (``kernels/latent_attention.shared_walk_plan``: which
-    slots map the same leading pages and score them together), read from
-    the table and the positions alone; None in the ``oracle`` tier, which
-    gathers every slot's own view."""
-    if default_paged_impl() != "pallas":
-        return None
-    from ..kernels.latent_attention import shared_walk_plan
-
-    return shared_walk_plan(page_table, positions, page_size)
-
-
-def latent_decode_attend(q, pool, page_table, positions, value_width: int,
-                         plan=None):
-    """Single-position attention over a pool of LATENT rows (one row a
-    token, keys and values the same bytes: ``models/decoder``'s latent
-    layer in its absorbed form), in the tier ``default_paged_impl`` says.
-    ``q [B, H, W]`` is pre-scaled and as wide as the pool's rows ``[P, 1,
-    ps, W]``; a row's first ``value_width`` lanes are what is attended:
-    ``[B, H, value_width]`` out, in the pool's dtype. ``oracle`` gathers the
-    dense view and runs the einsums (float32 scores, -1e30 mask, float32
-    softmax); ``pallas`` is ``kernels/latent_attention.latent_paged_decode``,
-    which fetches the leading pages that slots share ONCE for all of them
-    and scores them in one matmul (``plan``: ``latent_decode_plan``'s, where
-    the caller has it; the kernel's wrapper computes it otherwise), then each
-    slot's own pages. An empty slot's row, which no caller reads, is the
-    trash page's first token here and zeros there."""
-    if default_paged_impl() == "pallas":
-        from ..kernels.latent_attention import latent_paged_decode
-
-        return latent_paged_decode(q, pool, page_table, positions,
-                                   value_width, plan)
-    rows = paged_gather(pool, page_table)[:, 0]                # [B, L, W]
-    s = jnp.einsum("bhw,blw->bhl", q, rows,
-                   preferred_element_type=jnp.float32)
-    valid = jnp.arange(rows.shape[1])[None, :] <= positions[:, None]
-    s = jnp.where(valid[:, None, :], s, _NEG_INF)
-    probs = jax.nn.softmax(s, axis=-1).astype(rows.dtype)
-    return jnp.einsum("bhl,blv->bhv", probs, rows[..., :value_width])
-
-
-def extend_attend(q, k_cache, v_cache, positions):
-    """Multi-query cached attention: q ``[B, H_q, T, D]`` where query ``t``
-    of row ``b`` sits at absolute position ``positions[b] + t`` and may
-    attend to ``key_pos <= positions[b] + t`` — the suffix-prefill /
-    speculative-verify generalization of ``decode_attend`` (T=1 reduces to
-    it exactly). Same _sdpa_ref numerics: q pre-scaled in its own dtype,
-    f32 scores, -1e30 mask, f32 softmax."""
-    D = q.shape[-1]
-    rep = q.shape[1] // k_cache.shape[1]
-    k = _expand_kv_heads(k_cache, rep)
-    v = _expand_kv_heads(v_cache, rep)
-    qf = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qf, k,
-                   preferred_element_type=jnp.float32)
-    T = q.shape[2]
-    qpos = jnp.asarray(positions)[:, None] + jnp.arange(T)[None, :]  # [B, T]
-    key_pos = jnp.arange(k_cache.shape[2])
-    valid = key_pos[None, None, None, :] <= qpos[:, None, :, None]
-    s = jnp.where(valid, s, _NEG_INF)
-    probs = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-
-
-def paged_extend_attend(q, k_pool, v_pool, page_table, positions):
-    """Multi-query cached attention over block-paged pools — the paged twin
-    of ``extend_attend``. The Pallas ragged kernel is single-query, so
-    every tier reconstructs the dense view (``paged_gather``) and runs the
-    einsum path. Verify steps are rare next to decode steps (one per k+1
-    emitted tokens), so the gather cost is amortized."""
-    k = paged_gather(k_pool, page_table)
-    v = paged_gather(v_pool, page_table)
-    return extend_attend(q, k, v, positions)
 
 
 class PagedKVCache:

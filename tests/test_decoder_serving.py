@@ -26,11 +26,14 @@ from jax import lax
 from paddle_tpu import observability as obs
 from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import sparse_attention as sa
+from paddle_tpu.kernels.pools import (PAGE_SENTINEL, paged_gather,
+                                      paged_write_kv)
+from paddle_tpu.kernels.tier import use_paged_attention_impl
 from paddle_tpu.models.decoder import (DecoderConfig, DecoderLM, moe_swiglu,
                                        param_shapes)
 from paddle_tpu.models.gpt import gpt_tiny
 from paddle_tpu.serving import Engine, EngineConfig, SamplingParams
-from paddle_tpu.serving import kv_cache as kvc
+from paddle_tpu.serving.kv_cache import PagedKVCache
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -161,7 +164,7 @@ class TestSelection:
         one element a slice, which costs this chip what a 1 KB row costs
         (PERF.md section 7)."""
         cfg = DecoderConfig(**SIZES)
-        with kvc.use_paged_attention_impl("pallas"):
+        with use_paged_attention_impl("pallas"):
             eng = Engine(DecoderLM(cfg), EngineConfig(
                 max_batch_size=2, max_seq_len=96, page_size=8))
             fn, args = eng.decode_program()
@@ -205,7 +208,7 @@ class TestSelection:
         rows, n = sa.selected_rows(score, valid, table, ps, 16)
         got = sa.sparse_paged_decode(q, kp, vp, rows, n)
         mask = sa.topk_mask(score, valid, 16)
-        view = lambda pool: kvc.paged_gather(pool, table)[:, 0].reshape(
+        view = lambda pool: paged_gather(pool, table)[:, 0].reshape(
             B, nb * ps, Hkv, D)
         s = jnp.einsum("bgrd,blgd->bgrl", q.reshape(B, Hkv, 2, D) / 4.0,
                        view(kp))
@@ -292,8 +295,8 @@ class TestAgainstReference:
         position 16 on)."""
         text = _ids(90, seed=2)
         n0, ps = 70, 8
-        cache = kvc.PagedKVCache(2, 1, 1, 128, 32, "float32", page_size=ps,
-                                 pools=model.cache_pools())
+        cache = PagedKVCache(2, 1, 1, 128, 32, "float32", page_size=ps,
+                             pools=model.cache_pools())
         cache.assign_pages(0, list(range(1, 17)))
         table = cache.table_device()
         want = _ref_rows(model, text, n0 - 1)
@@ -302,9 +305,9 @@ class TestAgainstReference:
         pools = [list(pool) for pool in cache.pools]
         for l, entry in enumerate(kvs):
             for i, t in enumerate(entry):
-                pools[i][l] = kvc.paged_write_kv(
+                pools[i][l] = paged_write_kv(
                     pools[i][l], t._value, table, jnp.zeros((1,), jnp.int32))
-        with kvc.use_paged_attention_impl(impl):
+        with use_paged_attention_impl(impl):
             for j, tok in enumerate(text[n0:]):
                 entries = [tuple(pool[l] for pool in pools) + (table,)
                            for l in range(2)]
@@ -327,7 +330,7 @@ class TestAgainstReference:
         prompts = [shared + _ids(17, seed=6), _ids(70, seed=7)]
         p3 = shared + _ids(9, seed=8)
         # the tier is baked in as each program is traced, on first use
-        with kvc.use_paged_attention_impl(impl):
+        with use_paged_attention_impl(impl):
             eng = Engine(model, EngineConfig(
                 max_batch_size=3, max_seq_len=128, page_size=8,
                 prefill_buckets=(32, 64, 128), prefix_cache=True))
@@ -665,7 +668,7 @@ def _drive(family, m, second, eos, mark_all=False):
             # copy on write
             page = int(eng.cache.page_table[
                 slot, eng._positions[slot] // eng.cache.page_size])
-            if page != kvc.PAGE_SENTINEL:
+            if page != PAGE_SENTINEL:
                 shared = page
                 eng.page_alloc.retain([shared], owner="test")
         eng.step()

@@ -30,12 +30,12 @@ from jax import lax
 
 from paddle_tpu import observability as obs
 from paddle_tpu.kernels import gated_delta as gd
+from paddle_tpu.kernels.tier import use_paged_attention_impl
 from paddle_tpu.models.decoder import (ATTENTIONS, DecoderConfig, DecoderLM,
                                        gated_delta, initial_value,
                                        is_norm_scale, param_shapes)
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import Engine, EngineConfig, SamplingParams
-from paddle_tpu.serving import kv_cache as kvc
 from paddle_tpu.serving.prefix_cache import PrefixCache
 from paddle_tpu.serving.scheduler import PageAllocator
 
@@ -277,7 +277,7 @@ class TestTwoFormsOfOneRecurrence:
         assert gd.packed_shape(2, 96, 192) == (1, 96, 384)   # whole lane rows
         np.testing.assert_array_equal(
             gd.unpack_state(gd.pack_state(S0), H), S0)
-        with kvc.use_paged_attention_impl(impl):
+        with use_paged_attention_impl(impl):
             o, new = gd.gdn_step(q, k, v, g, beta, state)
         for b in range(B):
             want_o, want_S = _recurrence(q[b:b + 1], k[b:b + 1], v[b:b + 1],
@@ -310,7 +310,7 @@ class TestAgainstReference:
         the snapshot between), then 20 decode steps over the engine's
         pools: every position's logits are the reference's full forward."""
         text = _ids(65, seed=2)
-        with kvc.use_paged_attention_impl(impl):
+        with use_paged_attention_impl(impl):
             eng = _engine(model)
             _, got = _serve_logits(eng, text[:45], text[45:])
         np.testing.assert_allclose(got, _ref_rows(model, text, 44), atol=TOL)
@@ -634,7 +634,7 @@ def _lowered(name):
     from paddle_tpu.models.gpt import gpt_tiny
 
     which, kind, impl = name.split("/")
-    with kvc.use_paged_attention_impl(impl):
+    with use_paged_attention_impl(impl):
         model = gpt_tiny(dropout=0.0, num_layers=2) if which == "gpt" \
             else DecoderLM(DecoderConfig())
         eng = Engine(model, EngineConfig(max_batch_size=2, max_seq_len=64,

@@ -32,11 +32,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.kernels.latent_attention import latent_decode_attend
+from paddle_tpu.kernels.tier import use_paged_attention_impl
 from paddle_tpu.models import decoder as dec
 from paddle_tpu.models.decoder import (DecoderConfig, DecoderLM,
                                        is_norm_scale, param_shapes)
 from paddle_tpu.serving import Engine, EngineConfig, SamplingParams
-from paddle_tpu.serving import kv_cache as kvc
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -258,7 +259,7 @@ class TestAgainstReference:
         logits are the reference's ONE forward, and every layer counts the
         context it read."""
         text = _ids(65, seed=2)
-        with kvc.use_paged_attention_impl(impl):
+        with use_paged_attention_impl(impl):
             eng = _engine(model)
             _, got, reads = _serve_logits(eng, text[:45], text[45:])
         np.testing.assert_allclose(got, _ref_rows(model, text, 44), atol=TOL)
@@ -275,7 +276,7 @@ class TestAgainstReference:
         at a time."""
         shared, tail = _ids(40, seed=5), _ids(10, seed=8)
         text = shared + _ids(9, seed=7) + tail
-        with kvc.use_paged_attention_impl(impl):
+        with use_paged_attention_impl(impl):
             warm = _engine(model)
             warm.generate([shared + _ids(17, seed=6)],
                           SamplingParams(max_new_tokens=3))
@@ -330,10 +331,10 @@ def _pool_case(seed=0, B=5, H=4, W=128, value=64, pages=24, nb=6):
 
 class TestLatentPagedDecode:
     def _both(self, *case):
-        with kvc.use_paged_attention_impl("oracle"):
-            want = kvc.latent_decode_attend(*case)
-        with kvc.use_paged_attention_impl("pallas"):
-            got = kvc.latent_decode_attend(*case)
+        with use_paged_attention_impl("oracle"):
+            want = latent_decode_attend(*case)
+        with use_paged_attention_impl("pallas"):
+            got = latent_decode_attend(*case)
         return np.asarray(got), np.asarray(want)
 
     def test_kernel_is_the_oracle_on_ragged_slots(self):
@@ -349,8 +350,8 @@ class TestLatentPagedDecode:
         s = np.asarray(q[0]) @ rows.T
         w = np.exp(s - s.max(-1, keepdims=True))
         want = (w / w.sum(-1, keepdims=True)) @ rows[:, :value]
-        with kvc.use_paged_attention_impl("oracle"):
-            got = kvc.latent_decode_attend(q, pool, table, pos, value)
+        with use_paged_attention_impl("oracle"):
+            got = latent_decode_attend(q, pool, table, pos, value)
         np.testing.assert_allclose(got[0], want, atol=2e-5)
 
     def test_several_chunks_and_a_page_past_the_table(self, monkeypatch):
@@ -466,12 +467,12 @@ class TestSharedWalk:
     @pytest.mark.parametrize("name", WALKS)
     def test_kernel_is_the_oracle(self, small_walk, name):
         q, pool, table, pos, value, _ = _walk_case(name)
-        with kvc.use_paged_attention_impl("oracle"):
-            want = np.asarray(kvc.latent_decode_attend(q, pool, table, pos,
-                                                       value))
-        with kvc.use_paged_attention_impl("pallas"):
-            got = np.asarray(kvc.latent_decode_attend(q, pool, table, pos,
-                                                      value))
+        with use_paged_attention_impl("oracle"):
+            want = np.asarray(latent_decode_attend(q, pool, table, pos,
+                                                   value))
+        with use_paged_attention_impl("pallas"):
+            got = np.asarray(latent_decode_attend(q, pool, table, pos,
+                                                  value))
         live = np.asarray(table[:, 0]) >= 0
         np.testing.assert_allclose(got[live], want[live], atol=2e-5)
         assert not got[~live].any()
@@ -520,9 +521,9 @@ class TestSharedWalk:
 
         def run(name):
             q, pool, table, pos, value, _ = _walk_case(name)
-            with kvc.use_paged_attention_impl("pallas"):
-                return np.asarray(kvc.latent_decode_attend(q, pool, table,
-                                                           pos, value))
+            with use_paged_attention_impl("pallas"):
+                return np.asarray(latent_decode_attend(q, pool, table,
+                                                       pos, value))
 
         apart, together = run("nothing_shared"), run("two_groups_scattered")
         monkeypatch.setattr(la, "_TILE_MEMBERS", 1)
@@ -546,7 +547,7 @@ class TestSharedWalkThroughTheEngine:
         obs.reset()
         obs.clear_spans()
         try:
-            with kvc.use_paged_attention_impl(impl):
+            with use_paged_attention_impl(impl):
                 eng = _engine(m, max_batch_size=6)
                 reqs = []
                 for s in range(6):

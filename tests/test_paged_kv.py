@@ -18,10 +18,15 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import observability as obs
-from paddle_tpu.kernels.paged_attention import paged_attention
+from paddle_tpu.kernels.paged_attention import (decode_attend,
+                                                paged_attention,
+                                                paged_decode_attend)
+from paddle_tpu.kernels.pools import (PAGE_SENTINEL, paged_gather,
+                                      paged_write_kv, write_kv)
+from paddle_tpu.kernels.tier import (default_paged_impl,
+                                     use_paged_attention_impl)
 from paddle_tpu.models.gpt import gpt_tiny
 from paddle_tpu.serving import Engine, EngineConfig, SamplingParams
-from paddle_tpu.serving import kv_cache as kvc
 from paddle_tpu.serving.scheduler import PageAllocator
 
 
@@ -48,8 +53,8 @@ def _prompt(b, t, seed=0):
 def _oracle_attend(q, k_pool, v_pool, table, positions):
     """The paged attend's oracle, by name: the dense attend over the
     gathered pools."""
-    return kvc.decode_attend(q, kvc.paged_gather(k_pool, table),
-                             kvc.paged_gather(v_pool, table), positions)
+    return decode_attend(q, paged_gather(k_pool, table),
+                         paged_gather(v_pool, table), positions)
 
 
 # ---------------- allocator invariants ------------------------------------
@@ -103,13 +108,13 @@ class TestPagedPrimitives:
         P = B * nb + 1
         kp = jnp.asarray(rng.randn(P, Hkv, ps, D).astype(np.float32))
         vp = jnp.asarray(rng.randn(P, Hkv, ps, D).astype(np.float32))
-        table = np.full((B, nb), kvc.PAGE_SENTINEL, np.int32)
+        table = np.full((B, nb), PAGE_SENTINEL, np.int32)
         table[0, :2] = [1, 2]      # 2 live pages
         table[1, :1] = [5]         # 1 live page
         # row 2 stays all-sentinel: an empty slot
         tbl = jnp.asarray(table)
-        kd = kvc.paged_gather(kp, tbl)
-        vd = kvc.paged_gather(vp, tbl)
+        kd = paged_gather(kp, tbl)
+        vd = paged_gather(vp, tbl)
         return kp, vp, tbl, kd, vd
 
     def test_paged_gather_reconstructs_dense_layout(self):
@@ -129,9 +134,9 @@ class TestPagedPrimitives:
         rng = np.random.RandomState(7)
         new = jnp.asarray(rng.randn(B, Hkv, 1, D).astype(np.float32))
         pos = jnp.asarray([5, 2, 0], jnp.int32)  # ragged, row 2 empty slot
-        kp2 = kvc.paged_write_kv(kp, new, tbl, pos)
-        kd2 = kvc.write_kv(kd, new, pos)
-        got = np.asarray(kvc.paged_gather(kp2, tbl))
+        kp2 = paged_write_kv(kp, new, tbl, pos)
+        kd2 = write_kv(kd, new, pos)
+        got = np.asarray(paged_gather(kp2, tbl))
         want = np.asarray(kd2)
         # compare the LIVE prefix of each row (row 0 has 2 pages, row 1 has
         # 1): past it the paged view re-gathers the shared trash page, which
@@ -168,8 +173,8 @@ class TestPagedPrimitives:
             return want
 
         for pos in ([3, 1, 0], [9, 2, 0]):  # row 0: pages 1->2, then past
-            got = kvc.paged_write_kv(kp, new, tbl,
-                                     jnp.asarray(pos, jnp.int32))
+            got = paged_write_kv(kp, new, tbl,
+                                 jnp.asarray(pos, jnp.int32))
             # page 0 is the trash page: several rows race there, by design
             np.testing.assert_array_equal(np.asarray(got)[1:],
                                           per_token(pos)[1:])
@@ -194,7 +199,7 @@ class TestPagedPrimitives:
         # neither
         assert not np.asarray(got)[2].any()
         # oracle == the dense decode_attend it wraps
-        ref = kvc.decode_attend(q, kd, vd, pos)
+        ref = decode_attend(q, kd, vd, pos)
         assert np.allclose(np.asarray(want), np.asarray(ref), atol=1e-6)
 
     # positions in units of the kernel's chunk (``ct`` tokens); None = a
@@ -220,7 +225,7 @@ class TestPagedPrimitives:
         kp = rng.randn(P, Hkv, ps, D).astype(np.float32)
         vp = rng.randn(P, Hkv, ps, D).astype(np.float32)
         ids = 1 + rng.permutation(P - 1)
-        table = np.full((B, nb), kvc.PAGE_SENTINEL, np.int32)
+        table = np.full((B, nb), PAGE_SENTINEL, np.int32)
         pos = np.zeros(B, np.int32)
         used = 0
         for b, p in enumerate(positions):
@@ -325,17 +330,17 @@ class TestPagedPrimitives:
             # a new function object per call: traces are cached by function
             # identity, and the tier is chosen while tracing
             return "pallas_call" in str(jax.make_jaxpr(
-                lambda *a: kvc.paged_decode_attend(*a))(q, kp, vp, tbl, pos))
+                lambda *a: paged_decode_attend(*a))(q, kp, vp, tbl, pos))
 
-        assert kvc.default_paged_impl() == "oracle" and not traces_kernel()
-        with kvc.use_paged_attention_impl("pallas"):
-            assert kvc.default_paged_impl() == "pallas" and traces_kernel()
-            with kvc.use_paged_attention_impl("oracle"):
+        assert default_paged_impl() == "oracle" and not traces_kernel()
+        with use_paged_attention_impl("pallas"):
+            assert default_paged_impl() == "pallas" and traces_kernel()
+            with use_paged_attention_impl("oracle"):
                 assert not traces_kernel()
-            assert kvc.default_paged_impl() == "pallas"
-        assert kvc.default_paged_impl() == "oracle"
+            assert default_paged_impl() == "pallas"
+        assert default_paged_impl() == "oracle"
         with pytest.raises(ValueError):
-            kvc.use_paged_attention_impl("nope").__enter__()
+            use_paged_attention_impl("nope").__enter__()
 
 
 # ---------------- the programs update the pools in place ------------------
@@ -473,9 +478,9 @@ class TestPagedEngine:
         prompts = [[5, 17, 3, 9, 2]]
         sp = SamplingParams(max_new_tokens=4)
         cfg = EngineConfig(max_batch_size=2, max_seq_len=32)
-        with kvc.use_paged_attention_impl("oracle"):
+        with use_paged_attention_impl("oracle"):
             oracle = Engine(m, cfg).generate(prompts, sp)
-        with kvc.use_paged_attention_impl("pallas"):
+        with use_paged_attention_impl("pallas"):
             eng = Engine(m, cfg)
             kern = eng.generate(prompts, sp)
         assert kern == oracle
@@ -523,7 +528,7 @@ class TestPagedEngine:
         # exact cover restored
         assert eng.page_alloc.num_allocated == 0
         assert eng.page_alloc.num_free == eng.page_alloc.num_allocatable
-        assert (eng.cache.page_table == kvc.PAGE_SENTINEL).all()
+        assert (eng.cache.page_table == PAGE_SENTINEL).all()
         g = obs.snapshot()["gauges"]
         assert g["serving.kv.pages.allocated"] == 0
         assert g["serving.kv.pages.free"] == 1

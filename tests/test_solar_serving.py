@@ -31,12 +31,12 @@ from jax import lax
 from paddle_tpu import observability as obs
 from paddle_tpu.kernels import gated_delta as gd
 from paddle_tpu.kernels import grouped_matmul as gm
+from paddle_tpu.kernels.tier import use_paged_attention_impl
 from paddle_tpu.models import decoder as dec
 from paddle_tpu.models.decoder import (DecoderConfig, DecoderLM, initial_value,
                                        is_norm_scale, param_shapes)
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import Engine, EngineConfig, SamplingParams
-from paddle_tpu.serving import kv_cache as kvc
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -318,7 +318,7 @@ class TestTwoFormsOfOneRecurrence:
         state = jnp.concatenate([gd.pack_state(S0), jnp.full(
             (2,) + gd.packed_shape(H, dk, dv), 7.0)])
         assert gd.packed_shape(64, 128, 128) == (64, 128, 128)
-        with kvc.use_paged_attention_impl(impl):
+        with use_paged_attention_impl(impl):
             o, new = gd.gdn_step(q, k, v, g, beta, state)
         for b in range(B):
             want_o, want_S = _recurrence(q[b:b + 1], k[b:b + 1], v[b:b + 1],
@@ -372,7 +372,7 @@ class TestAgainstReference:
         the tail), then 20 decode steps over the engine's pools: every
         position's logits are the reference's full forward."""
         text = _ids(65, seed=2)
-        with kvc.use_paged_attention_impl(impl):
+        with use_paged_attention_impl(impl):
             eng = _engine(model)
             _, got = _serve_logits(eng, text[:45], text[45:])
         np.testing.assert_allclose(got, _ref_rows(model, text, 44), atol=TOL)

@@ -24,10 +24,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.kernels.paged_attention import (decode_attend,
+                                                paged_decode_attend)
+from paddle_tpu.kernels.pools import paged_gather
+from paddle_tpu.kernels.tier import use_paged_attention_impl
 from paddle_tpu.models import decoder as dec
 from paddle_tpu.models.decoder import DecoderConfig, DecoderLM, param_shapes
-from paddle_tpu.serving import (Engine, EngineConfig, SamplingParams,
-                                kv_cache as kvc)
+from paddle_tpu.serving import Engine, EngineConfig, SamplingParams
 from paddle_tpu.serving.kv_cache import PAGE_SENTINEL, PagedKVCache
 from paddle_tpu.serving.prefix_cache import PrefixCache
 from paddle_tpu.serving.scheduler import PageAllocator
@@ -108,12 +111,12 @@ def _engine(m, impl="oracle", **kw):
                 prefix_cache=True, prefill_buckets=(8, 16, 32, 64, 128),
                 group_pages={"window": 40})
     conf.update(kw)
-    with kvc.use_paged_attention_impl(impl):
+    with use_paged_attention_impl(impl):
         return Engine(m, EngineConfig(**conf))
 
 
 def _generate(eng, prompts, n, impl="oracle", each_step=None):
-    with kvc.use_paged_attention_impl(impl):
+    with use_paged_attention_impl(impl):
         reqs = [eng.add_request(p, SamplingParams(max_new_tokens=n))
                 for p in prompts]
         while eng.has_unfinished:
@@ -298,19 +301,19 @@ def test_window_kernel_matches_the_oracle(window):
     for b in (2, 3, 4):                               # freed behind the window
         table[b, :max(0, pos[b] - window + 1) // PS] = PAGE_SENTINEL
     q = jnp.asarray(np.random.RandomState(1).randn(B, 4, 1, 8), jnp.float32)
-    with kvc.use_paged_attention_impl("pallas"):
-        got = kvc.paged_decode_attend(q, k, v, jnp.asarray(table),
-                                      jnp.asarray(pos), window=window)
-    with kvc.use_paged_attention_impl("oracle"):
-        want = kvc.paged_decode_attend(q, k, v, jnp.asarray(table),
-                                       jnp.asarray(pos), window=window)
+    with use_paged_attention_impl("pallas"):
+        got = paged_decode_attend(q, k, v, jnp.asarray(table),
+                                  jnp.asarray(pos), window=window)
+    with use_paged_attention_impl("oracle"):
+        want = paged_decode_attend(q, k, v, jnp.asarray(table),
+                                   jnp.asarray(pos), window=window)
     live = np.array([0, 2, 3, 4])
     got, want = np.asarray(got), np.asarray(want)
     np.testing.assert_allclose(got[live], want[live], atol=1e-5)
     assert not got[1].any()                           # zeros, read by no one
     # the oracle's lower bound is the mask of a dense softmax
-    kd, vd = kvc.paged_gather(k, jnp.asarray(table)), \
-        kvc.paged_gather(v, jnp.asarray(table))
+    kd, vd = paged_gather(k, jnp.asarray(table)), \
+        paged_gather(v, jnp.asarray(table))
     s = jnp.einsum("bhd,bhkd->bhk", q[:, :, 0] / np.sqrt(8.0),
                    jnp.repeat(kd, 2, axis=1))
     kp = np.arange(nb * PS)[None, None, :]
@@ -334,12 +337,12 @@ def test_no_window_is_the_kernel_it_was():
     k, v, table = _pools(B, nb, seed=4)
     pos = jnp.asarray(np.array([5, 30, 17], np.int32))
     q = jnp.asarray(np.random.RandomState(2).randn(B, 4, 1, 8), jnp.float32)
-    with kvc.use_paged_attention_impl("pallas"):
+    with use_paged_attention_impl("pallas"):
         none = pa.paged_attention(q, k, v, jnp.asarray(table), pos)
         wide = pa.paged_attention(q, k, v, jnp.asarray(table), pos, 10 ** 6)
     np.testing.assert_allclose(
-        none, kvc.decode_attend(q, kvc.paged_gather(k, jnp.asarray(table)),
-                                kvc.paged_gather(v, jnp.asarray(table)), pos),
+        none, decode_attend(q, paged_gather(k, jnp.asarray(table)),
+                            paged_gather(v, jnp.asarray(table)), pos),
         atol=1e-5)
     assert np.array_equal(np.asarray(none), np.asarray(wide))
     text = lambda w: str(jax.make_jaxpr(
@@ -626,7 +629,7 @@ def _lowered(name):
     if kind == "params":
         shapes = param_shapes(cfg)
         return len(shapes), zlib.crc32(repr(list(shapes.items())).encode())
-    with kvc.use_paged_attention_impl(impl):
+    with use_paged_attention_impl(impl):
         eng = Engine(DecoderLM(cfg), EngineConfig(
             max_batch_size=2, max_seq_len=64, page_size=8, prefix_cache=True))
         assert len(eng.page_allocs) == 1 and not eng._windows
