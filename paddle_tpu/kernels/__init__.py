@@ -10,9 +10,11 @@ Pallas/Mosaic. Every kernel here:
   one predicate decides (core/place.py); no other platform, no flag,
 - is wired behind the op-registry variant seam (ops use it when
   FLAGS_use_pallas_kernels and the platform is TPU); the serving kernels
-  (paged_attention, latent_attention, sparse_attention, gated_delta) are
+  (paged_attention, latent_attention, sparse_attention, gated_delta,
+  mamba2) are
   picked by ``tier.default_paged_impl`` instead, which their own entries
-  (``paged_decode_attend``, ``latent_decode_attend``, ``gdn_step``) ask,
+  (``paged_decode_attend``, ``latent_decode_attend``, ``gdn_step``,
+  ``mamba2_step``) ask,
 - carries a stable ``name=`` and, under a mesh, runs inside a shard_map
   over all mesh axes (mesh.py: shard_kernel; kernel_sites reads back which
   kernels a compiled program holds).

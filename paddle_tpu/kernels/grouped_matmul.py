@@ -19,6 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -78,26 +79,30 @@ def plan_groups(expert, G: int, tm: int):
     return src, dest, tile_group.astype(jnp.int32), n_tiles, counts
 
 
-def _gmm_kernel(grp_ref, nt_ref, x_ref, w_ref, o_ref):
+def _gmm_kernel(grp_ref, nt_ref, x_ref, w_ref, o_ref, *, transposed: bool):
     del grp_ref
 
     @pl.when(pl.program_id(0) < nt_ref[0])
     def _():
-        o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
-                             preferred_element_type=jnp.float32
-                             ).astype(o_ref.dtype)
+        # ``w [K, N]``, or ``[N, K]`` contracted over its last dimension
+        dims = (((1,), (1 if transposed else 0,)), ((), ()))
+        o_ref[...] = lax.dot_general(x_ref[...], w_ref[0], dims,
+                                     preferred_element_type=jnp.float32
+                                     ).astype(o_ref.dtype)
 
 
-def _gmm_call(tile_group, n_tiles, x, w, *, tm: int):
+def _gmm_call(tile_group, n_tiles, x, w, *, tm: int, transposed: bool):
     M_pad, K = x.shape
-    N = w.shape[2]
+    N = w.shape[1 if transposed else 2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(M_pad // tm,),
         in_specs=[pl.BlockSpec((tm, K), lambda t, grp, nt: (t, 0)),
-                  pl.BlockSpec((1, K, N), lambda t, grp, nt: (grp[t], 0, 0))],
+                  pl.BlockSpec((1,) + w.shape[1:],
+                               lambda t, grp, nt: (grp[t], 0, 0))],
         out_specs=pl.BlockSpec((tm, N), lambda t, grp, nt: (t, 0)))
     return pl.pallas_call(
-        _gmm_kernel, grid_spec=grid_spec,
+        functools.partial(_gmm_kernel, transposed=transposed),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M_pad, N), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -107,12 +112,15 @@ def _gmm_call(tile_group, n_tiles, x, w, *, tm: int):
     )(tile_group, n_tiles, x, w)
 
 
-def grouped_matmul(x, w, tile_group, n_tiles, tm: int):
+def grouped_matmul(x, w, tile_group, n_tiles, tm: int,
+                   transposed: bool = False):
     """``x [M_pad, K]`` (rows laid out by ``plan_groups``) times each row
-    tile's expert matrix ``w[tile_group[t]]`` of ``w [G, K, N]``:
-    ``[M_pad, N]`` in x's dtype (float32 accumulation). Rows of tiles past
-    ``n_tiles`` are left unwritten."""
+    tile's expert matrix ``w[tile_group[t]]`` of ``w [G, K, N]`` (with
+    ``transposed``: of ``w [G, N, K]``, a matrix stored ``[out, in]``,
+    contracted over its last dimension): ``[M_pad, N]`` in x's dtype
+    (float32 accumulation). Rows of tiles past ``n_tiles`` are left
+    unwritten."""
     return shard_kernel(
-        functools.partial(_gmm_call, tm=tm),
+        functools.partial(_gmm_call, tm=tm, transposed=transposed),
         (tile_group, jnp.reshape(n_tiles, (1,)), x, w),
         (P(), P(), P(), P()), lambda f: P())

@@ -5,10 +5,11 @@
 ``post`` | ``parallel``), positions (``rope`` | ``rope_gptj`` |
 ``rope_yarn`` | ``none``; ``position_by_kind`` where layers of one kind
 have others), the token mixer (``dense`` | ``sliding`` | ``indexed_sparse``
-| ``gated_delta`` | ``latent``; one kind for all layers, or
-``layer_types``, one a layer), FFN (``swiglu`` | ``moe_swiglu``; the
-first ``first_dense_layers`` layers a dense swiglu of a width of their
-own), router (``softmax_topk`` | ``sigmoid_topk`` |
+| ``gated_delta`` | ``latent`` | ``mamba2`` | ``none``; one kind for all
+layers, or ``layer_types``, one a layer), FFN (``swiglu`` | ``moe_swiglu``
+| ``relu2`` | ``moe_relu2`` | ``none``; the first ``first_dense_layers``
+layers a dense swiglu of a width of their own, or ``ffn_types``, one kind
+a layer), router (``softmax_topk`` | ``sigmoid_topk`` |
 ``sigmoid_group_topk``) — with
 their widths and what varies within a kind (a dense layer's output gate,
 a sliding layer's window, the width of a gated_delta layer's decay, which
@@ -117,6 +118,44 @@ score is the sum of its two largest, the ``topk_group`` best of ``n_group``
 groups are kept, the top-k taken inside them; the WEIGHTS are ``s`` of the
 chosen over their sum, times ``routed_scaling_factor``.
 
+A layer may have ONE part (Nemotron-H, arXiv:2504.03624: the published
+``hybrid_override_pattern`` ``MEMEM*EMEMEM*...`` gives each layer a Mamba-2
+mixer ``M``, an expert layer ``E`` or an attention layer ``*`` ALONE):
+``layer_types[l]`` or ``ffn_types[l]`` is ``"none"``, and the layer is ``x
+= x + part(norm(x))`` with ONE norm. A part that is absent declares no
+parameters and no norm and is not traced; such a layer hands back no pool
+entries (an ``E`` layer reads no cache) or zero FFN statistics (an ``M``
+layer routes nothing).
+
+A ``mamba2`` layer (Mamba-2, arXiv:2405.21060; ``H`` heads of width ``P``,
+``G`` groups, state ``N``, convolution ``K``), with ``h`` the layer's
+input::
+
+    [z | xBC | dt] = h W_in                widths H P | H P + 2 G N | H
+    xBC = silu(conv(xBC) + b_conv)         causal, depthwise, width K, over
+                                           ALL H P + 2 G N channels
+    x_h [H x P], B_g [G x N], C_g [G x N] = split(xBC);  head h reads group
+                                           h // (H / G)
+    dt_h = softplus(dt_h + dt_bias_h)      float32; no clamp
+    a_h  = exp(dt_h A_h),  A_h = -exp(A_log_h)     ONE decay a head
+    S_h  <- a_h S_h + dt_h x_h (x) B_g(h)          S_h in R^{P x N}, float32
+    y_h  = S_h C_g(h) + D_h x_h            (the state AFTER this token)
+    y    = RMSNorm_groups(y * silu(z))     the gate FIRST, then a norm over
+                                           each of G groups, weight [H P]
+    out  = y W_out
+
+It keeps per slot the float32 state ``S`` (packed as a gated_delta layer's,
+``kernels/gated_delta.pack_state``) and the last ``K - 1`` inputs of the
+convolution, and speaks ``gated_delta``'s cache protocol to the letter;
+prefill and extend run the chunked form in matrix products from a given
+state and tail, decode the recurrent step (``kernels/mamba2``).
+
+``relu2`` / ``moe_relu2`` (Nemotron-H's ``mlp_hidden_act``): an UNGATED FFN
+of two matrices, ``FFN(h) = relu(h W_up)^2 W_down``; the routed body
+(``moe_routed``) then runs two grouped matmuls, not three, and a shared
+expert may have a width of its own (``shared_intermediate_size``). With
+``n_group`` 1 ``sigmoid_group_topk`` is a plain top-k of ``s + bias``.
+
 It speaks the serving engine's whole protocol (serving/README.md):
 ``cache_pools()`` declares the paged pools of the layers that keep keys (K
 and V token-major, one
@@ -125,13 +164,15 @@ sparse read, or head-major ``[pages, H_kv, page, D]`` for the paged-decode
 kernel, ``kv_layout``; the indexer's keys, in whole 128-lane rows; a latent
 layer's one row a token),
 ``state_pools()`` the slot-indexed state of the layers that keep a
-recurrence; ``prefill_with_cache`` / ``extend_step`` /
-``decode_step`` are pure functions of (parameters, pools, page table).
+recurrence (``gated_delta``, ``mamba2``); ``prefill_with_cache`` /
+``extend_step`` / ``decode_step`` are pure functions of (parameters, pools,
+page table).
 What they do to a pool is below this module, in ``kernels/``: the write and
 the view through a table (``pools.py``), the paged attends, each kernel with
 its reference (``paged_attention.py``, ``latent_attention.py``,
-``sparse_attention.py``, ``gated_delta.py``), and ``tier.py``, which says
-which of the two a trace bakes in. Nothing here imports ``serving``.
+``sparse_attention.py``, ``gated_delta.py``, ``mamba2.py``), and
+``tier.py``, which says which of the two a trace bakes in. Nothing here
+imports ``serving``.
 ONE attention routine (``attend``) serves all three: queries at
 ``start .. start + T - 1`` against views of the pools, in chunks of queries
 so that no ``[T, L]`` float32 array larger than a chunk exists; prefill is
@@ -197,7 +238,8 @@ class DecoderConfig:
     # t - sliding_window < s <= t (its own among them), and keeps pages of
     # that many tokens only (a page group of its own: ``cache_pools``)
     sliding_window: Optional[int] = None
-    # one kind a layer (keys of ATTENTIONS); None: ``attention`` for all
+    # one kind a layer (keys of ATTENTIONS; "none": a layer WITHOUT a token
+    # mixer, an FFN alone); None: ``attention`` for all
     layer_types: Optional[Tuple[str, ...]] = None
     # how a dense layer's K and V pages lie: "token" [pages, 1, page,
     # H_kv * D], "head" [pages, H_kv, page, D] (what kernels/paged_attention
@@ -221,6 +263,15 @@ class DecoderConfig:
     linear_gate: str = "head"
     linear_gate_rank: int = 8
     gdn_chunk: int = 64           # tokens per chunk of the chunked form
+    # a mamba2 layer's widths (arXiv:2405.21060): heads of ``ssm_head_dim``
+    # (their product is the inner width), ``ssm_groups`` groups that share
+    # B and C, the state's width, the convolution's, tokens a chunk
+    ssm_heads: int = 4
+    ssm_head_dim: int = 8
+    ssm_groups: int = 2
+    ssm_state: int = 16
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
     # a latent layer's widths (arXiv:2412.19437 section 2.1): the ranks of
     # the query's and the keys-and-values' latents, a head's query/key lanes
     # without and with positions, a head's value lanes
@@ -229,8 +280,11 @@ class DecoderConfig:
     qk_nope_head_dim: int = 16
     qk_rope_head_dim: int = 8
     v_head_dim: int = 16
-    ffn: str = "moe_swiglu"       # swiglu | moe_swiglu
-    intermediate_size: int = 128  # swiglu's width; an expert's in moe_swiglu
+    ffn: str = "moe_swiglu"       # swiglu | moe_swiglu | relu2 | moe_relu2
+    intermediate_size: int = 128  # a dense FFN's width; an expert's in moe_*
+    # the FFN's kind, one a layer (keys of FFNS; "none": a layer WITHOUT an
+    # FFN, a token mixer alone); None: the rule below
+    ffn_types: Optional[Tuple[str, ...]] = None
     # the FFN by layer: the first ``first_dense_layers`` layers are a dense
     # swiglu of width ``dense_intermediate_size`` whatever ``ffn`` says
     first_dense_layers: int = 0
@@ -250,9 +304,12 @@ class DecoderConfig:
     # keeps its width, the rows routed to the others are left out (no
     # exchange, nothing standing in for it). None: all of them
     experts_held: Optional[Tuple[int, int]] = None
-    # shared experts: one dense SwiGLU of width ``intermediate_size`` x this
-    # beside the routed ones, ungated, on every token
+    # shared experts: one dense FFN (of the routed experts' activation) of
+    # width ``intermediate_size`` x this beside the routed ones, ungated, on
+    # every token; ``shared_intermediate_size`` where it has a width of its
+    # own
     shared_experts: int = 0
+    shared_intermediate_size: Optional[int] = None
     # how the shared experts' outputs join the routed sum: "sum", or "mean"
     # (their mean: the one wide SwiGLU times 1 / shared_experts)
     shared_combine: str = "sum"
@@ -285,16 +342,22 @@ class DecoderConfig:
             if getattr(self, field) not in table:
                 raise ValueError(f"{field} {getattr(self, field)!r}; "
                                  f"want one of {sorted(table, key=str)}")
-        if self.layer_types is not None:
-            self.layer_types = tuple(self.layer_types)
-            unknown = sorted(set(self.layer_types) - set(ATTENTIONS))
+        for field, table in (("layer_types", ATTENTIONS),
+                             ("ffn_types", FFNS)):
+            types = getattr(self, field)
+            if types is None:
+                continue
+            setattr(self, field, tuple(types))
+            unknown = sorted(set(types) - set(table))
             if unknown:
-                raise ValueError(f"layer_types has {unknown}; want entries "
-                                 f"of {sorted(ATTENTIONS)}")
-            if len(self.layer_types) != self.num_layers:
+                raise ValueError(f"{field} has {unknown}; want entries "
+                                 f"of {sorted(table)}")
+            if len(types) != self.num_layers:
                 raise ValueError(
-                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"{field} names {len(types)} layers, "
                     f"num_layers is {self.num_layers}")
+        if any(k == "none" == f for k, (f, _) in zip(self.kinds, self.ffns)):
+            raise ValueError("a layer of no mixer and no FFN")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         for kind, pos in (self.position_by_kind or {}).items():
@@ -332,6 +395,9 @@ class DecoderConfig:
     @property
     def ffns(self) -> Tuple[Tuple[str, int], ...]:
         """The FFN's (kind, width), one a layer."""
+        if self.ffn_types is not None:
+            return tuple((kind, self.intermediate_size)
+                         for kind in self.ffn_types)
         d = self.first_dense_layers
         return (("swiglu", self.dense_intermediate_size),) * d \
             + ((self.ffn, self.intermediate_size),) * (self.num_layers - d)
@@ -817,6 +883,66 @@ def _gdn_state_pools(cfg):
             ("gdn_conv", (cfg.linear_conv_kernel - 1, C), cfg.dtype)]
 
 
+def _state_start(name, cache, B, T, shape, dtype):
+    """What a layer with slot state starts from, of its ``cache`` entry
+    ``(state, conv, where)`` (None: a prefill, from zero): (state, conv,
+    where, the convolution's tail ``[B, *shape]`` this call starts with).
+    ``where`` None means rows ``[0, B)``, one token each."""
+    if cache is None:
+        return None, None, None, jnp.zeros((B,) + shape, dtype)
+    state, conv, where = cache
+    if where is None and T != 1:
+        raise NotImplementedError(
+            f"{name}: several tokens a slot over every slot (the "
+            "speculative verify step) would need the state of each "
+            "position kept to roll a rejected draft back")
+    tail = conv[:B] if where is None else \
+        lax.dynamic_index_in_dim(conv, where[0], keepdims=True)
+    return state, conv, where, tail
+
+
+def _conv_tails(x, tail, w, lengths, cuts, barrier, bias=None):
+    """The causal depthwise convolution of a layer with slot state over ``x
+    [B, T, C]`` behind ``tail [B, K - 1, C]`` (weights ``w [C, K]``), then
+    SiLU, float32; the tails ``[B, cuts + 1, K - 1, C]``: the last ``K - 1``
+    real inputs before each cut and before the end; and ``n [B]``, the real
+    tokens a row (``lengths``, or all ``T``)."""
+    (B, T, _), Kc = x.shape, w.shape[1]
+    f32 = jnp.float32
+    win = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # [B, T+Kc-1, C]
+    n = jnp.full((B,), T, jnp.int32) if lengths is None else lengths
+    # real token t is row t + Kc - 1 of ``win``
+    ends = n[:, None] if cuts is None else jnp.concatenate(
+        [jnp.minimum(cuts, n[:, None]), n[:, None]], axis=1)
+    tails = jnp.stack([jax.vmap(
+        lambda a, i: lax.dynamic_slice_in_dim(a, i, Kc - 1))(win, ends[:, j])
+        for j in range(ends.shape[1])], axis=1)
+    w = w.astype(f32)
+    y = sum(win[:, j:j + T].astype(f32) * w[:, j] for j in range(Kc))
+    y = jax.nn.silu(y if bias is None else y + bias.astype(f32))
+    if barrier:
+        # an extend's tails are ready when its convolution is, and ``win``
+        # (T x C) dies there: left to itself the compiler takes the cuts'
+        # tails with the row writes at the program's end and keeps every
+        # layer's ``win`` until then (0.34 GiB more of extend/3328's
+        # temporaries at the hybrid cell's widths; TPU compiler, PR 35)
+        y, tails = lax.optimization_barrier((y, tails))
+    return y, tails, n
+
+
+def _state_new(cache, S, tails):
+    """What a prefill or a one-slot extend hands back of the packed states
+    ``S [B, cuts + 1, ...]`` and tails: without a cache the rows themselves
+    ``[B * (cuts + 1), ...]`` for the engine to install, else (``B = 1``)
+    the buffers with the cuts' rows, then the end's, written."""
+    if cache is None:
+        return (S.reshape((-1,) + S.shape[2:]),
+                tails.reshape((-1,) + tails.shape[2:]))
+    state, conv, where = cache
+    return (_pools.write_state_rows(state, S[0], where[1]),
+            _pools.write_state_rows(conv, tails[0], where[1]))
+
+
 def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
                 lengths=None, cuts=None):
     """A gated delta-rule layer over ``h [B, T, hidden]`` (the module's
@@ -839,40 +965,13 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
 
     B, T, _ = h.shape
     H, dk, dv, C = _gdn_widths(cfg)
-    Kc = cfg.linear_conv_kernel
     f32 = jnp.float32
-    if cache is None:
-        where, tail = None, jnp.zeros((B, Kc - 1, C), h.dtype)
-    else:
-        state, conv, where = cache
-        if where is None and T != 1:
-            raise NotImplementedError(
-                "gated_delta: several tokens a slot over every slot (the "
-                "speculative verify step) would need the state of each "
-                "position kept to roll a rejected draft back")
-        tail = conv[:B] if where is None else \
-            lax.dynamic_index_in_dim(conv, where[0], keepdims=True)
+    state, conv, where, tail = _state_start(
+        "gated_delta", cache, B, T, (cfg.linear_conv_kernel - 1, C), h.dtype)
     x = jnp.concatenate([_mm(h, p[pre + w]) for w in (".wq", ".wk", ".wv")],
                         axis=-1)
-    win = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # [B, T+Kc-1, C]
-    n = jnp.full((B,), T, jnp.int32) if lengths is None else lengths
-    # the last Kc - 1 real inputs before each cut and before the end: real
-    # token t is row t + Kc - 1 of ``win``
-    ends = n[:, None] if cuts is None else jnp.concatenate(
-        [jnp.minimum(cuts, n[:, None]), n[:, None]], axis=1)
-    tails = jnp.stack([jax.vmap(
-        lambda a, i: lax.dynamic_slice_in_dim(a, i, Kc - 1))(win, ends[:, j])
-        for j in range(ends.shape[1])], axis=1)
-    w = p[pre + ".conv.weight"].astype(f32)
-    y = jax.nn.silu(sum(win[:, j:j + T].astype(f32) * w[:, j]
-                        for j in range(Kc)))
-    if cache is not None and cuts is not None:
-        # an extend's tails are ready when its convolution is, and ``win``
-        # (T x C) dies there: left to itself the compiler takes the cuts'
-        # tails with the row writes at the program's end and keeps every
-        # layer's ``win`` until then (0.34 GiB more of extend/3328's
-        # temporaries at the hybrid cell's widths; TPU compiler, PR 35)
-        y, tails = lax.optimization_barrier((y, tails))
+    y, tails, n = _conv_tails(x, tail, p[pre + ".conv.weight"], lengths,
+                              cuts, cache is not None and cuts is not None)
     unit = lambda a: a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
     q = unit(y[..., :H * dk].reshape(B, T, H, dk)) * f32(dk ** -0.5)
     k = unit(y[..., H * dk:2 * H * dk].reshape(B, T, H, dk))
@@ -908,13 +1007,8 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
         o, S, *at_cuts = jax.vmap(functools.partial(
             _gdn.gdn_chunked, chunk=cfg.gdn_chunk))(q, k, v, g, beta, S0,
                                                     cuts=cuts)
-        S = _gdn.pack_state(jnp.concatenate(at_cuts + [S[:, None]], axis=1))
-        if cache is None:
-            new = (S.reshape((-1,) + S.shape[2:]),
-                   tails.reshape((-1,) + tails.shape[2:]))
-        else:               # B = 1: the cuts' rows, then the end's
-            new = (_pools.write_state_rows(state, S[0], where[1]),
-                   _pools.write_state_rows(conv, tails[0], where[1]))
+        new = _state_new(cache, _gdn.pack_state(
+            jnp.concatenate(at_cuts + [S[:, None]], axis=1)), tails)
     o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) \
         * p[pre + ".o_norm.weight"].astype(f32)
     if channel:
@@ -924,6 +1018,107 @@ def gated_delta(cfg, p, pre, h, start, cache=None, flash_ok=False,
         gate = jax.nn.silu(_mm(h, p[pre + ".wg"]).astype(f32))
     y = (o.reshape(B, T, H * dv) * gate).astype(h.dtype)
     return _mm(y, p[pre + ".wo"]), new
+
+
+# ------------------------------------------------- Mamba-2 (state space)
+
+def _ssm_widths(cfg):
+    """(heads, a head's width, groups, state width, inner width, channels
+    of the convolution)."""
+    H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state)
+    return H, P, G, N, H * P, H * P + 2 * G * N
+
+
+def _ssm_shapes(cfg, pre):
+    Hd = cfg.hidden_size
+    H, P, G, N, inner, C = _ssm_widths(cfg)
+    return {pre + ".w_in": (Hd, inner + C + H),      # [z | x B C | dt]
+            pre + ".conv.weight": (C, cfg.ssm_conv_kernel),
+            pre + ".conv.bias": (C,),
+            pre + ".A_log": (H,), pre + ".dt_bias": (H,), pre + ".D": (H,),
+            pre + ".norm.weight": (inner,),
+            pre + ".w_out": (inner, Hd)}
+
+
+def _ssm_state_pools(cfg):
+    """[(name, per-slot shape, dtype)] of a mamba2 layer's state: the packed
+    float32 ``S`` and the convolution's last inputs."""
+    from ..kernels.mamba2 import packed_shape
+
+    H, P, G, N, _, C = _ssm_widths(cfg)
+    return [("ssm_state", packed_shape(H, N, P), "float32"),
+            ("ssm_conv", (cfg.ssm_conv_kernel - 1, C), cfg.dtype)]
+
+
+def mamba2(cfg, p, pre, h, start, cache=None, flash_ok=False, lengths=None,
+           cuts=None):
+    """A Mamba-2 layer over ``h [B, T, hidden]`` (the module's docstring has
+    the equations). It speaks ``gated_delta``'s cache protocol to the
+    letter: only the first ``lengths[b]`` tokens of a row are real, ``cuts
+    [B, n]`` asks for the state and tail BEFORE each cut's token (from
+    inside the chunked form's scan), ``cache`` is ``(state, conv, where)``
+    with ``where`` ``None`` for rows ``[0, B)`` (decode, ``T = 1``: a slot at
+    position 0 runs no request, and its state stays as it is) or ``(source,
+    rows)`` of a ``B = 1`` extend; without ``cache`` the state starts at
+    zero (prefill). Returns (out [B, T, hidden], new) as ``gated_delta``
+    does."""
+    from ..kernels import mamba2 as _ssm
+
+    B, T, _ = h.shape
+    H, P, G, N, inner, C = _ssm_widths(cfg)
+    f32 = jnp.float32
+    state, conv, where, tail = _state_start(
+        "mamba2", cache, B, T, (cfg.ssm_conv_kernel - 1, C), h.dtype)
+    with jax.named_scope("ssm/in_proj"):
+        zxd = _mm(h, p[pre + ".w_in"])
+    z, x = zxd[..., :inner], zxd[..., inner:inner + C]
+    dt = zxd[..., inner + C:]
+    with jax.named_scope("ssm/conv"):
+        y, tails, n = _conv_tails(
+            x, tail, p[pre + ".conv.weight"], lengths, cuts,
+            cache is not None and cuts is not None, p[pre + ".conv.bias"])
+    xs = y[..., :inner].reshape(B, T, H, P)
+    Bm = y[..., inner:inner + G * N].reshape(B, T, G, N)
+    Cm = y[..., inner + G * N:].reshape(B, T, G, N)
+    dt = jax.nn.softplus(dt.astype(f32) + p[pre + ".dt_bias"].astype(f32))
+    real = jnp.arange(T)[None, :] < n[:, None]
+    if cache is not None and where is None:
+        real = real & (start > 0)[:, None]      # a slot that runs a request
+    dt = jnp.where(real[..., None], dt, 0.0)
+    A = -jnp.exp(p[pre + ".A_log"].astype(f32))
+    D = p[pre + ".D"].astype(f32)
+
+    if cache is not None and where is None:
+        with jax.named_scope("ssm/step"):
+            o, state = _ssm.mamba2_step(xs[:, 0], dt[:, 0], A, Bm[:, 0],
+                                        Cm[:, 0], D, state)
+        o = o[:, None]
+        new = (state, lax.dynamic_update_slice_in_dim(
+            conv, jnp.where(real[:, :, None], tails[:, 0],
+                            conv[:B]).astype(conv.dtype), 0, axis=0))
+    else:
+        with jax.named_scope("ssm/chunk"):
+            S0 = jnp.zeros((B, H, P, N), f32) if cache is None else \
+                _ssm.unpack_state(
+                    lax.dynamic_index_in_dim(state, where[0], keepdims=True),
+                    H)
+            o, S, *at_cuts = jax.vmap(
+                functools.partial(_ssm.mamba2_chunked, chunk=cfg.ssm_chunk),
+                in_axes=(0, 0, None, 0, 0, None, 0))(
+                    xs, dt, A, Bm, Cm, D, S0, cuts=cuts)
+        new = _state_new(cache, _ssm.pack_state(
+            jnp.concatenate(at_cuts + [S[:, None]], axis=1)), tails)
+    with jax.named_scope("ssm/gated_norm"):
+        # the gate FIRST, then a norm over each group's share of the inner
+        # width
+        o = o.reshape(B, T, inner) * jax.nn.silu(z.astype(f32))
+        o = o.reshape(B, T, G, inner // G)
+        o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+        o = (o.reshape(B, T, inner)
+             * p[pre + ".norm.weight"].astype(f32)).astype(h.dtype)
+    return _mm(o, p[pre + ".w_out"]), new
 
 
 # ------------------------------------------------ latent (MLA) attention
@@ -1145,6 +1340,9 @@ ATTENTIONS = {
     "gated_delta": (gated_delta, _gdn_shapes, lambda c: [], _gdn_state_pools),
     "latent": (latent_attention, _latent_shapes,
                lambda c: [("latent", 1, latent_pool_width(c))], lambda c: []),
+    "mamba2": (mamba2, _ssm_shapes, lambda c: [], _ssm_state_pools),
+    # a layer without a token mixer: nothing declared, nothing traced
+    "none": (None, lambda c, pre: {}, lambda c: [], lambda c: []),
 }
 
 
@@ -1174,11 +1372,15 @@ def sigmoid_group_topk(cfg, g, wr, bias):
     s = jax.nn.sigmoid(jnp.dot(g, wr, preferred_element_type=f32))
     N, E = s.shape
     G = cfg.n_group
-    choose = (s + bias.astype(f32)).reshape(N, G, E // G)
-    _, keep = lax.top_k(lax.top_k(choose, 2)[0].sum(-1), cfg.topk_group)
-    kept = jnp.any(keep[:, :, None] == jnp.arange(G)[None, None, :], axis=1)
-    _, e = lax.top_k(jnp.where(kept[:, :, None], choose, _NEG_INF)
-                     .reshape(N, E), cfg.experts_per_token)
+    if G == 1:      # one group keeps every expert: the plain top-k
+        _, e = lax.top_k(s + bias.astype(f32), cfg.experts_per_token)
+    else:
+        choose = (s + bias.astype(f32)).reshape(N, G, E // G)
+        _, keep = lax.top_k(lax.top_k(choose, 2)[0].sum(-1), cfg.topk_group)
+        kept = jnp.any(keep[:, :, None] == jnp.arange(G)[None, None, :],
+                       axis=1)
+        _, e = lax.top_k(jnp.where(kept[:, :, None], choose, _NEG_INF)
+                         .reshape(N, E), cfg.experts_per_token)
     pw = jnp.take_along_axis(s, e, axis=1)
     if cfg.norm_topk_prob:
         pw = pw / (jnp.sum(pw, axis=-1, keepdims=True) + 1e-20)
@@ -1204,33 +1406,61 @@ ROUTERS = {"softmax_topk": (softmax_topk, ()),
            "sigmoid_group_topk": (sigmoid_group_topk, (".bias",))}
 
 
-def _swiglu_shapes(cfg, pre, F):
+def _relu2(a):
+    return jnp.square(jax.nn.relu(a))
+
+
+#: an FFN's activation by name -> (the function; whether a third matrix
+#: ``w3`` gates it: ``act(g w1) * g w3``, else ``act(g w1)`` alone; whether a
+#: ROUTED expert's ``w1`` is kept ``[G, out, in]``, as the public checkpoints
+#: keep a Linear, and contracted over its last dimension). The layout is a
+#: fact of its own, not the gate's: what asks for it is a width that is no
+#: multiple of 128 lanes (relu2's 1,856 = 14.5 x 128: the chip stores a
+#: ``[.., hidden, width]`` array width-major at rest, and a kernel that
+#: wants it otherwise re-lays ALL the experts every step, 609 MiB a layer;
+#: TPU compiler, PR 46). A gated expert of such a width still pays that.
+ACTIVATIONS = {"swiglu": (jax.nn.silu, True, False),
+               "relu2": (_relu2, False, True)}
+
+
+def _dense_shapes(cfg, pre, F, act="swiglu"):
     H = cfg.hidden_size
-    return {pre + ".w1": (H, F), pre + ".w3": (H, F), pre + ".w2": (F, H)}
+    s = {pre + ".w1": (H, F), pre + ".w3": (H, F), pre + ".w2": (F, H)}
+    if not ACTIVATIONS[act][1]:
+        del s[pre + ".w3"]
+    return s
 
 
-def _dense_swiglu(p, pre, g):
-    a = jax.nn.silu(_mm(g, p[pre + ".w1"])) * _mm(g, p[pre + ".w3"])
+def _dense_ffn(p, pre, g, act="swiglu"):
+    fn, gated, _ = ACTIVATIONS[act]
+    a = fn(_mm(g, p[pre + ".w1"]))
+    if gated:
+        a = a * _mm(g, p[pre + ".w3"])
     return _mm(a, p[pre + ".w2"])
 
 
-def swiglu(cfg, p, pre, g):
-    """Dense gated FFN over ``g [N, hidden]``; no routing statistics."""
-    return _dense_swiglu(p, pre, g), jnp.zeros((len(ffn_stats(cfg)),),
-                                               jnp.int32)
+def dense_ffn(cfg, p, pre, g, act="swiglu"):
+    """Dense FFN over ``g [N, hidden]`` (``swiglu``: gated, three matrices;
+    ``relu2``: ``relu(g w1)^2 w2``, two); no routing statistics."""
+    return _dense_ffn(p, pre, g, act), jnp.zeros((len(ffn_stats(cfg)),),
+                                                 jnp.int32)
 
 
-def _moe_shapes(cfg, pre, F):
+def _moe_shapes(cfg, pre, F, act="swiglu"):
     H, E = cfg.hidden_size, cfg.num_experts
     G = E if cfg.experts_held is None else cfg.experts_held[0]
     s = {pre + ".router": (H, E), pre + ".w1": (G, H, F),
          pre + ".w3": (G, H, F), pre + ".w2": (G, F, H)}
+    _, gated, out_in = ACTIVATIONS[act]
+    if not gated:
+        del s[pre + ".w3"]
+    if out_in:
+        s[pre + ".w1"] = (G, F, H)
     s.update({pre + ".router" + leaf: (E,)          # one value an expert
               for leaf in ROUTERS[cfg.router][1]})
     if cfg.shared_experts:
-        Fs = F * cfg.shared_experts
-        s.update({pre + ".shared.w1": (H, Fs), pre + ".shared.w3": (H, Fs),
-                  pre + ".shared.w2": (Fs, H)})
+        Fs = cfg.shared_intermediate_size or F * cfg.shared_experts
+        s.update(_dense_shapes(cfg, pre + ".shared", Fs, act))
     return s
 
 
@@ -1255,17 +1485,22 @@ def step_stats(cfg) -> Tuple[str, ...]:
     layers, ``window_tokens_read`` / ``full_tokens_read``: the cached tokens
     a sliding layer (``min(context, sliding_window)`` a slot) and a full
     one (the context) attended, summed over the live slots, each 0 in a
-    layer of the other kind."""
+    layer of the other kind. Of a model with mamba2 layers,
+    ``ssm_slots_stepped``: the running slots a mamba2 layer's recurrent
+    step advanced (0 in a layer of another kind). A layer without an FFN
+    counts 0 in the FFN's columns."""
     return ffn_stats(cfg) + (("latent_tokens_read", "shared_walk_tokens")
                              if "latent" in cfg.kinds else ()) \
         + (("window_tokens_read", "full_tokens_read")
-           if "sliding" in cfg.kinds else ())
+           if "sliding" in cfg.kinds else ()) \
+        + (("ssm_slots_stepped",) if "mamba2" in cfg.kinds else ())
 
 
-def moe_routed(cfg, p, pre, g):
-    """The routed experts' part of the layer over ``g [N, hidden]``,
-    drop-free: every (token, chosen expert) row of an expert held here is
-    computed, whatever the distribution. With ``experts_held`` the router
+def moe_routed(cfg, p, pre, g, act="swiglu"):
+    """The routed experts' part of the layer over ``g [N, hidden]`` (an
+    expert ``act``'s FFN: three matrices and three grouped matmuls where it
+    is gated, two where not), drop-free: every (token, chosen expert) row of
+    an expert held here is computed, whatever the distribution. With ``experts_held`` the router
     still chooses among ALL experts; a row routed to an expert that is not
     held sorts behind the held groups, in no tile of the grouped matmul (no
     matmul, no weight read), and adds nothing: what the chips that hold it
@@ -1287,8 +1522,12 @@ def moe_routed(cfg, p, pre, g):
     tm = row_tile(N * k * G // E, G)     # by the rows expected HERE
     src, dest, tile_group, n_tiles, counts = plan_groups(e.reshape(-1), G, tm)
     x = g[src // k]                                        # [M_pad, H]
-    gmm = lambda a, w: grouped_matmul(a, w, tile_group, n_tiles, tm)
-    a = jax.nn.silu(gmm(x, p[pre + ".w1"])) * gmm(x, p[pre + ".w3"])
+    gmm = lambda a, w, **kw: grouped_matmul(a, w, tile_group, n_tiles, tm,
+                                            **kw)
+    fn, gated, out_in = ACTIVATIONS[act]
+    a = fn(gmm(x, p[pre + ".w1"], transposed=out_in))
+    if gated:
+        a = a * gmm(x, p[pre + ".w3"])
     if local is not None:   # an absent expert's row has no place in the
         dest = jnp.where(local.reshape(-1), dest, 0)    # layout: read none
     y = gmm(a, p[pre + ".w2"])[dest].reshape(N, k, H)
@@ -1301,23 +1540,42 @@ def moe_routed(cfg, p, pre, g):
     return y, jnp.stack(stats).astype(jnp.int32)
 
 
-def moe_swiglu(cfg, p, pre, g):
+def moe_ffn(cfg, p, pre, g, act="swiglu"):
     """The expert layer over ``g [N, hidden]``: the routed experts'
-    part (``moe_routed``) plus, with ``shared_experts``, one dense SwiGLU
-    over every token. Returns (y [N, hidden], the routing statistics)."""
-    y, stats = moe_routed(cfg, p, pre, g)
+    part (``moe_routed``) plus, with ``shared_experts``, one dense FFN of
+    the same activation over every token. Returns (y [N, hidden], the
+    routing statistics)."""
+    # (the gated kind's programs carry no scope of this: they stay the
+    # ones they were)
+    scope = lambda name: contextlib.nullcontext() if act == "swiglu" \
+        else jax.named_scope(f"ffn/{act}_{name}")
+    with scope("experts"):
+        y, stats = moe_routed(cfg, p, pre, g, act)
     if cfg.shared_experts and cfg.shared_combine == "mean":
-        # the mean of the shared experts: their sum is the ONE wide SwiGLU
+        # the mean of the shared experts: their sum is the ONE wide FFN
         with jax.named_scope("ffn/shared_mean"):
-            y = y + _dense_swiglu(p, pre + ".shared", g).astype(jnp.float32) \
-                * jnp.float32(1.0 / cfg.shared_experts)
+            y = y + _dense_ffn(p, pre + ".shared", g, act) \
+                .astype(jnp.float32) * jnp.float32(1.0 / cfg.shared_experts)
     elif cfg.shared_experts:
-        y = y + _dense_swiglu(p, pre + ".shared", g).astype(jnp.float32)
+        with scope("shared"):
+            y = y + _dense_ffn(p, pre + ".shared", g, act) \
+                .astype(jnp.float32)
     return y.astype(g.dtype), stats
 
 
-FFNS = {"swiglu": (swiglu, _swiglu_shapes),
-        "moe_swiglu": (moe_swiglu, _moe_shapes)}
+def _ffn_kind(fn, shapes, act):
+    return (functools.partial(fn, act=act), functools.partial(shapes, act=act))
+
+
+#: kind -> (the FFN's function of (cfg, p, pre, g [N, hidden]), its
+#: parameters' shapes of (cfg, pre, width))
+FFNS = {"swiglu": _ffn_kind(dense_ffn, _dense_shapes, "swiglu"),
+        "moe_swiglu": _ffn_kind(moe_ffn, _moe_shapes, "swiglu"),
+        "relu2": _ffn_kind(dense_ffn, _dense_shapes, "relu2"),
+        "moe_relu2": _ffn_kind(moe_ffn, _moe_shapes, "relu2"),
+        # a layer without an FFN: nothing declared, nothing traced
+        "none": (None, lambda cfg, pre, F: {})}
+swiglu, moe_swiglu = FFNS["swiglu"][0], FFNS["moe_swiglu"][0]
 
 
 #: tokens per pass of the FFN over a long sequence
@@ -1356,12 +1614,15 @@ def param_shapes(cfg: DecoderConfig) -> dict:
     s = {"embed.weight": (cfg.vocab_size, H)}
     for l, kind in enumerate(cfg.kinds):
         pre = f"layers.{l}"
-        s.update(_norm_shapes(cfg, pre + ".attn_norm", H))
+        parallel = cfg.norm_placement == "parallel"  # ONE norm a block
+        fkind, width = cfg.ffns[l]
+        # a part that is absent declares nothing, its norm neither
+        if kind != "none" or parallel:
+            s.update(_norm_shapes(cfg, pre + ".attn_norm", H))
         s.update(ATTENTIONS[kind][1](cfg, pre + ".attn"))
-        if cfg.norm_placement != "parallel":    # which has ONE norm a block
+        if fkind != "none" and not parallel:
             s.update(_norm_shapes(cfg, pre + ".ffn_norm", H))
-        kind, width = cfg.ffns[l]
-        s.update(FFNS[kind][1](cfg, pre + ".ffn", width))
+        s.update(FFNS[fkind][1](cfg, pre + ".ffn", width))
     s.update(_norm_shapes(cfg, "final_norm", H))
     if not cfg.tie_word_embeddings:
         s["head.weight"] = (H, cfg.vocab_size)
@@ -1374,7 +1635,8 @@ def is_norm_scale(name: str) -> bool:
 
 def initial_value(name: str, shape, key, std: float):
     """A parameter's initial float32 value by the kind its name states:
-    norm scales 1, biases 0, a gated_delta layer's ``A_log`` = log U(0, 16),
+    norm scales 1, biases 0, a mamba2 layer's skip ``D`` 1, a gated_delta or
+    mamba2 layer's ``A_log`` = log U(0, 16),
     ``dt_bias`` = softplus^-1 of a step drawn log-uniform in [0.001, 0.1]
     and convolution U(-k^-1/2, k^-1/2) (the public implementation's), every
     other leaf N(0, std)."""
@@ -1388,6 +1650,8 @@ def initial_value(name: str, shape, key, std: float):
         return jnp.zeros(shape, jnp.float32)
     if name.endswith(".A_log"):
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0))
+    if name.endswith(".D"):
+        return jnp.ones(shape, jnp.float32)
     if name.endswith(".conv.weight"):
         r = shape[-1] ** -0.5
         return jax.random.uniform(key, shape, jnp.float32, -r, r)
@@ -1399,28 +1663,40 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
     """One block over the residual stream ``x [B, T, hidden]``: returns
     (x, the layer's new pool entries, its routing statistics)."""
     pre = f"layers.{l}"
-    mixer = ATTENTIONS[cfg.kinds[l]][0]
     kind = cfg.ffns[l][0]
+    # a part that is absent (kind "none") is not traced: no identity pass,
+    # no norm; it hands back no pool entries / zero statistics
+    mix = None if cfg.kinds[l] == "none" else functools.partial(
+        ATTENTIONS[cfg.kinds[l]][0], cfg, p, pre + ".attn", start=start,
+        cache=cache, flash_ok=flash_ok, lengths=lengths, cuts=cuts)
+    feed = None if kind == "none" else functools.partial(
+        ffn, cfg, p, pre + ".ffn", kind=kind)
+    new, stats = (), jnp.zeros((len(ffn_stats(cfg)),), jnp.int32)
     if cfg.norm_placement == "parallel":
         h = _norm(cfg, x, p, pre + ".attn_norm")
-        a, new = mixer(cfg, p, pre + ".attn", h, start, cache, flash_ok,
-                       lengths, cuts)
-        y, stats = ffn(cfg, p, pre + ".ffn", h, kind)
-        x = x + a + y
+        parts = []
+        if mix:
+            a, new = mix(h=h)
+            parts.append(a)
+        if feed:
+            y, stats = feed(g=h)
+            parts.append(y)
+        for part in parts:      # both from the ONE norm, then the sums
+            x = x + part
     elif cfg.norm_placement == "post":
-        a, new = mixer(cfg, p, pre + ".attn", x, start, cache, flash_ok,
-                       lengths, cuts)
-        x = x + _norm(cfg, a, p, pre + ".attn_norm")
-        y, stats = ffn(cfg, p, pre + ".ffn", x, kind)
-        x = x + _norm(cfg, y, p, pre + ".ffn_norm")
+        if mix:
+            a, new = mix(h=x)
+            x = x + _norm(cfg, a, p, pre + ".attn_norm")
+        if feed:
+            y, stats = feed(g=x)
+            x = x + _norm(cfg, y, p, pre + ".ffn_norm")
     else:
-        a, new = mixer(cfg, p, pre + ".attn",
-                       _norm(cfg, x, p, pre + ".attn_norm"), start, cache,
-                       flash_ok, lengths, cuts)
-        x = x + a
-        y, stats = ffn(cfg, p, pre + ".ffn",
-                       _norm(cfg, x, p, pre + ".ffn_norm"), kind)
-        x = x + y
+        if mix:
+            a, new = mix(h=_norm(cfg, x, p, pre + ".attn_norm"))
+            x = x + a
+        if feed:
+            y, stats = feed(g=_norm(cfg, x, p, pre + ".ffn_norm"))
+            x = x + y
     if "latent" in cfg.kinds:
         read = walk = jnp.zeros((), jnp.int32)
         if cfg.kinds[l] == "latent" and cache is not None:
@@ -1450,6 +1726,14 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
                 full = jnp.sum(ctx)
         stats = jnp.concatenate(
             [stats, jnp.stack([win, full]).astype(jnp.int32)])
+    if "mamba2" in cfg.kinds:
+        stepped = jnp.zeros((), jnp.int32)
+        if cfg.kinds[l] == "mamba2" and cache is not None \
+                and cache[2] is None:
+            # the slots whose state the recurrent step advanced: those that
+            # run a request (a dead slot sits at position 0)
+            stepped = jnp.sum(start > 0)
+        stats = jnp.concatenate([stats, stepped[None].astype(jnp.int32)])
     return x, new, stats
 
 
@@ -1503,7 +1787,8 @@ class DecoderLM(Layer):
 
     def state_pools(self):
         """[(name, per-slot shape, dtype, layers)] of the slot-indexed
-        state: a gated_delta layer's packed ``S`` and convolution tail."""
+        state: a gated_delta or mamba2 layer's packed ``S`` and convolution
+        tail."""
         return self._pools(3)
 
     def selected_tokens(self, ctx):

@@ -342,14 +342,17 @@ class PagedKVCache:
         tuples, in the order the model declared its pools: ``where`` is the
         page ``table`` for a layer of paged pools, and for a layer of state
         the ``rows`` its state lives in (``None``: rows ``[0, B)``; else
-        ``(the row read, the rows written)`` of a one-slot extend).
-        ``table`` is one table, or a tuple of them, one a group."""
+        ``(the row read, the rows written)`` of a one-slot extend); a layer
+        that holds no pool at all gets ``()``. ``table`` is one table, or a
+        tuple of them, one a group."""
         n = len(self.pool_specs)
         # one table for all, or one a group (in ``groups``' order)
         of = (lambda l: table[self._layer_group[l]]) \
             if isinstance(table, (tuple, list)) else (lambda l: table)
+        # (a layer that holds no pool of either kind, an FFN alone, is
+        # handed an empty entry)
         return [tuple(pools[j][i] for j, i in held)
-                + ((of(l),) if held[0][0] < n else (rows,))
+                + ((of(l),) if held[0][0] < n else (rows,)) if held else ()
                 for l, held in enumerate(self._of_layer)]
 
     def pools_from_layers(self, per_layer):

@@ -193,6 +193,21 @@ def held(ids, x, w):
     src, dest, tg, nt, counts = plan_groups(ids, 40, 16)
     return grouped_matmul(x[src // 8], w, tg, nt, 16)
 out["gmm_held"] = sites(held, sds((1024,), jnp.int32), sds((128, 4096)), sds((40, 4096, 1280)))
+# the Nemotron 3 Nano share's widths: the Mamba-2 step over rows [0, 256) of
+# 280 (64 heads of 64 x 128, two a packed row, 8 groups), paged_decode at 32
+# query heads on 2 K/V heads over a [256, 272] table, and the grouped matmul
+# over 64 HELD experts whose first matrix is kept [out, in] (1,856 x 2,688)
+from paddle_tpu.kernels import mamba2
+out["mamba2_step"] = sites(
+    lambda *a: mamba2._step_call(*a, interpret=False),
+    fs(256, 64, 64), fs(256, 64), fs(64), fs(256, 8, 128), fs(256, 8, 128),
+    fs(64), fs(280, *mamba2.packed_shape(64, 128, 64)))
+out["paged_32_2"] = sites(paged_attention, sds((256, 32, 1, 128)), sds((36865, 2, 16, 128)),
+                          sds((36865, 2, 16, 128)), sds((256, 272), jnp.int32), sds((256,), jnp.int32))
+def held_t(ids, x, w):
+    src, dest, tg, nt, counts = plan_groups(ids, 64, 16)
+    return grouped_matmul(x[src // 6], w, tg, nt, 16, transposed=True)
+out["gmm_out_in"] = sites(held_t, sds((1536,), jnp.int32), sds((256, 2688)), sds((64, 1856, 2688)))
 # the latent cell's widths: 64 slots of 128 heads over rows of 640 lanes, a
 # [64, 2240] table: the absorbed decode's shared walk (tiles of 16, 4 x 128
 # rows a matmul, 64 MiB of VMEM asked for)
@@ -252,6 +267,9 @@ def test_kernels_and_train_step_compile_for_a_tpu_topology():
         == {"paged_decode": 1}
     assert out["gdn_step"] == out["kda_step"] == {"gdn_decode_step": 1}
     assert out["paged_gqa"] == {"paged_decode": 1}
+    assert out["mamba2_step"] == {"mamba2_decode_step": 1}
+    assert out["paged_32_2"] == {"paged_decode": 1}
+    assert out["gmm_out_in"] == {"moe_grouped_matmul": 1}
     assert out["gmm_held"] == {"moe_grouped_matmul": 1}
     assert out["latent_decode"] == {"latent_paged_decode": 1}
     assert out["latent_flash"] == {"latent_flash": 1}

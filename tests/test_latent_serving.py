@@ -871,7 +871,7 @@ def test_shares_add_up_to_the_uncut_layer():
     want = ref.routed_experts(g, lp, {**RCFG, "experts_held": (E, 0)},
                               ref.mm_highest) \
         + ref.swiglu(g, lp, "ffn.shared.", ref.mm_highest)
-    total = dec._dense_swiglu(pw, "layers.1.ffn.shared", g)
+    total = dec._dense_ffn(pw, "layers.1.ffn.shared", g)
     rows = 0
     for chip in range(4):
         cfg = DecoderConfig(**{**SIZES, "experts_held": (8, 8 * chip)})
