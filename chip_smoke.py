@@ -214,21 +214,32 @@ def leg_kernels(S: int = 2048, H: int = 16, D: int = 128, hidden: int = 2048,
     # the kernel's own page walk can get wrong: a dead slot between live
     # ones, contexts of one chunk (128 tokens), one chunk and a token, and
     # several chunks with a partial last one
-    B, ps, nb = 8, 16, S // 16
-    pool = lambda: bf(B * nb + 1, H, ps, D)
-    kp, vp = pool(), pool()
-    qd = bf(B, H, 1, D)
-    errs["paged_decode"] = []
-    for ctx in ([5, 17, 100, 511, 700, 1023, 1500, S - 1],
-                [300, None, 127, 128, None, 0, 1029, S - 1]):
+    B, ps = 8, 16
+
+    def paged_case(ctx, Hq, Hkv, nb):
+        """Operands of a ragged batch (None = a dead slot) and its live
+        rows; every slot's pages lie in a stretch of the pool of its own."""
         table = np.full((B, nb), -1, np.int32)
         pos = np.zeros(B, np.int32)
         live = np.asarray([i for i, c in enumerate(ctx) if c is not None])
         for i in live:
-            pos[i] = min(ctx[i], S - 1)
+            pos[i] = min(ctx[i], nb * ps - 1)
             n = pos[i] // ps + 1
             table[i, :n] = 1 + i * nb + np.arange(n)
-        args = (qd, kp, vp, jnp.asarray(table), jnp.asarray(pos))
+        return (bf(B, Hq, 1, D), bf(B * nb + 1, Hkv, ps, D),
+                bf(B * nb + 1, Hkv, ps, D), jnp.asarray(table),
+                jnp.asarray(pos)), live
+
+    # the third is GQA at 32 / 2 heads, where a chunk is 64 pages (1,024
+    # tokens): contexts of several chunks with a partial last one, exactly
+    # one, one and a token, under one, and a dead slot
+    errs["paged_decode"] = []
+    for ctx, Hq, Hkv, nb in (
+            ([5, 17, 100, 511, 700, 1023, 1500, S - 1], H, H, S // ps),
+            ([300, None, 127, 128, None, 0, 1029, S - 1], H, H, S // ps),
+            ([3700, None, 1023, 1024, 2048 + 700, 4351, 40, 2047], 32, 2,
+             272)):
+        args, live = paged_case(ctx, Hq, Hkv, nb)
         got = _compiled(paged_attention, args, "paged_decode")(*args)
         want = _compiled(
             lambda q, k, v, table, pos: decode_attend(
@@ -237,9 +248,12 @@ def leg_kernels(S: int = 2048, H: int = 16, D: int = 128, hidden: int = 2048,
         errs["paged_decode"].append(close(got[live], want[live], tol))
         if np.delete(np.asarray(got, np.float32), live, axis=0).any():
             raise AssertionError("paged decode wrote into a dead slot's row")
-    say(f"ok: paged decode B={B} H={H} D={D} page {ps} x{nb} bf16 vs "
+    say(f"ok: paged decode B={B} H={H} D={D} page {ps} x{S // ps} bf16 vs "
         f"oracle, ragged and ragged with dead slots: rel err "
-        f"{errs['paged_decode']}")
+        f"{errs['paged_decode'][:2]}")
+    say(f"ok: paged decode GQA B={B} 32 / 2 heads D={D} page {ps} x272 bf16 "
+        f"vs oracle, contexts of several 64-page chunks and a dead slot: "
+        f"rel err {errs['paged_decode'][2]}")
     return errs
 
 

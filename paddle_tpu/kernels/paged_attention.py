@@ -11,15 +11,23 @@ HBM (``memory_space=pl.ANY``).
 The grid is one step a slot, whatever the table's width. Inside a step the
 kernel walks the slot's own live pages (``positions[b] // page_size + 1`` of
 them; none for a slot whose first table entry is the sentinel) in a
-``fori_loop`` over CHUNKS of several pages: each page of a chunk — a
-contiguous ``[H_kv, page_size, D]`` block of its pool — is fetched by the
-kernel's own ``make_async_copy`` into one of two VMEM buffers a pool, so
-chunk ``c + 1`` is in flight while chunk ``c`` is computed. Pages of a
-chunk past the live count are not fetched (their V rows in the buffer are
-zeroed, so whatever a buffer held before never reaches the sum); pages of
-finished requests and the rest of the table are never touched. The chunk
-width comes from the shapes alone (``_pages_per_chunk``): at least a full
-lane width of tokens, so the score tile is ``[H_q, >= 128]``.
+``fori_loop`` over CHUNKS of pages: each page of a chunk — a contiguous
+``[H_kv, page_size, D]`` block of its pool — is fetched by the kernel's own
+``make_async_copy`` into one of two VMEM buffers a pool, so chunk ``c + 1``
+is in flight while chunk ``c`` is computed. What the walk costs beside the
+copies themselves is paid once a CHUNK: a chunk wholly inside the live range
+(every chunk but a slot's last) starts its copies with no condition a page
+and is awaited by ONE wait a pool, for the bytes of the whole buffer; only a
+slot's last chunk counts its live pages, starts and awaits those a page at a
+time and zeroes the V rows of the others, which are not fetched, so whatever
+a buffer held before never reaches the sum. Pages of finished requests and
+the rest of the table are never touched. Every copy is in bounds by
+construction (the page id clamped into the pool, the destination a buffer's
+own page), so the call turns off Mosaic's check of each one, which was two
+thirds of a page's scalar work. A chunk's width comes from the shapes alone
+(``_pages_per_chunk``): about half a MiB a buffer, at most 1,024 tokens and a
+128 KiB score tile, whole lane widths of tokens: 64 pages of 16 tokens at 2
+K/V heads, 16 at 8, 8 at 16 and more.
 
 Per chunk a flash-style online softmax (exp2 domain, f32 statistics carried
 through the loop — same scheme as flash_attention.py) masks the tail of the
@@ -51,6 +59,7 @@ within float tolerance (tests/test_serving.py).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -69,15 +78,28 @@ from .tier import default_paged_impl
 # Both buffers of both pools have to sit well inside Mosaic's scoped VMEM
 # (16 MiB on the smallest generation this runs on).
 _CHUNK_VMEM_BYTES = 8 * 1024 * 1024
+# What one buffer holds where the walk runs near its roofline (the chat
+# shape's 8 pages of 64 KiB), the most tokens a chunk folds at once, and the
+# most a chunk's [H_q, chunk_tokens] float32 score tile may take.
+_CHUNK_BUFFER_BYTES = 512 * 1024
+_CHUNK_TOKENS = 1024
+_SCORE_TILE_BYTES = 128 * 1024
+# A whole chunk's copies are issued this many pages a turn of a rolled loop.
+_PAGES_A_TURN = 8
 
 
-def _pages_per_chunk(num_kv_heads: int, page_size: int, head_dim: int,
-                     itemsize: int) -> int:
-    """Pages fetched and computed together: enough for a full lane width of
-    tokens, fewer only where four buffers of that many pages (two a pool)
-    would not fit the VMEM budget."""
-    pages = -(-LANES // page_size)
+def _pages_per_chunk(num_kv_heads: int, num_q_heads: int, page_size: int,
+                     head_dim: int, itemsize: int) -> int:
+    """Pages fetched and computed together, from the shapes alone: as many
+    as fill one buffer of ``_CHUNK_BUFFER_BYTES``, at most ``_CHUNK_TOKENS``
+    tokens and a score tile of ``_SCORE_TILE_BYTES``, in whole lane widths
+    of tokens and never under one; fewer only where four buffers of that
+    many pages (two a pool) would not fit the VMEM budget."""
     page_bytes = num_kv_heads * page_size * head_dim * itemsize
+    lane_pages = -(-LANES // page_size)
+    tokens = min(_CHUNK_TOKENS, _SCORE_TILE_BYTES // (4 * num_q_heads))
+    pages = min(_CHUNK_BUFFER_BYTES // page_bytes, tokens // page_size)
+    pages = max(pages // lane_pages, 1) * lane_pages
     return max(1, min(pages, _CHUNK_VMEM_BYTES // (4 * page_bytes)))
 
 
@@ -97,6 +119,7 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         # pages [0, pos // page_size] hold written tokens (position pos is
         # written before the attend — see paged_write_kv); a slot with no
         # first page is dead
+        first = 0
         live = jnp.where(tbl_ref[b, 0] < 0, 0,
                          jnp.minimum(pos // page_size + 1, num_blocks))
     else:
@@ -108,38 +131,75 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         live = jnp.where(tbl_ref[b, last] < 0, 0, last + 1 - first)
     chunk_tokens = chunk * page_size
     Hq, D = q_ref.shape[1:]
+    last_page = k_hbm.shape[0] - 1
 
-    def page_copies(i, buf, j):
-        # a sentinel inside the live range clamps to the reserved trash
-        # page, so the fetch stays in-bounds whatever the table holds
-        page = jnp.maximum(
-            tbl_ref[b, i if window is None else first + i], 0)
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, j],
-                                      sems.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, j],
-                                      sems.at[1, buf]))
+    def start_page(i, buf, j):
+        # the page id is held inside the pool whatever the table holds (a
+        # sentinel reads the reserved trash page), and the destination is a
+        # buffer's own page: the copies are in bounds by construction, which
+        # is what lets the call leave out Mosaic's check of each one
+        page = jnp.clip(tbl_ref[b, first + i], 0, last_page)
+        pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, j],
+                              sems.at[0, buf]).start()
+        pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, j],
+                              sems.at[1, buf]).start()
+
+    def wait_pages(buf, pages):
+        # a wait takes the semaphore and the destination's size, not the
+        # source: ``pages`` of a buffer (a slice of it, or all of it) wait
+        # for as many page copies as were signalled on its semaphore
+        for pool_buf, sem in ((k_buf, sems.at[0, buf]),
+                              (v_buf, sems.at[1, buf])):
+            dst = pool_buf.at[buf] if pages is None else pool_buf.at[buf, pages]
+            pltpu.make_async_copy(dst, dst, sem).wait()
+
+    turn = math.gcd(chunk, _PAGES_A_TURN)
+
+    def live_in(c):
+        """Live pages of chunk ``c``: ``chunk`` for every chunk of a slot
+        but its last."""
+        return jnp.clip(live - c * chunk, 0, chunk)
 
     def fetch(c, buf):
-        for j in range(chunk):
-            i = c * chunk + j
+        """Start chunk ``c``'s copies into buffer ``buf``. A chunk wholly
+        inside the live range (every chunk but a slot's last) issues them
+        with no condition a page; the last one issues its live pages and
+        zeroes the V rows of the others, which are never fetched: p is
+        exactly 0 there, but 0 x whatever the buffer held (it starts
+        uninitialised) need not be."""
+        n = live_in(c)
 
-            @pl.when(i < live)
-            def _start():
-                for copy in page_copies(i, buf, j):
-                    copy.start()
+        @pl.when(n == chunk)
+        def _whole():
+            @pl.loop(0, chunk // turn)
+            def _(t):
+                for j in range(turn):
+                    start_page(c * chunk + t * turn + j, buf, t * turn + j)
 
-            @pl.when(i >= live)
-            def _blank():
-                # never fetched: p is exactly 0 there, but 0 x whatever the
-                # buffer held (it starts uninitialised) need not be
+        @pl.when(n < chunk)
+        def _partial():
+            @pl.loop(0, n)
+            def _(j):
+                start_page(c * chunk + j, buf, j)
+
+            @pl.loop(n, chunk)
+            def _(j):
                 v_buf[buf, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
 
     def wait(c, buf):
-        for j in range(chunk):
-            @pl.when(c * chunk + j < live)
-            def _wait():
-                for copy in page_copies(c * chunk + j, buf, j):
-                    copy.wait()
+        """One wait a pool for a whole chunk; a page at a time for the live
+        pages of a partial one (a copy's size is static)."""
+        n = live_in(c)
+
+        @pl.when(n == chunk)
+        def _whole():
+            wait_pages(buf, None)
+
+        @pl.when(n < chunk)
+        def _partial():
+            @pl.loop(0, n)
+            def _(j):
+                wait_pages(buf, j)
 
     q = q_ref[0]  # [Hq, D], pre-scaled by 1/sqrt(D) in q's dtype
 
@@ -161,12 +221,11 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             for g in range(num_kv_heads)
         ], axis=0) * jnp.float32(LOG2E)
-        tok = c * chunk_tokens + jax.lax.broadcasted_iota(
+        tok = (c * chunk + first) * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (Hq, chunk_tokens), 1)
         if window is None:
             s = jnp.where(tok <= pos, s, NEG_INF)  # [Hq, chunk_tokens], log2
         else:
-            tok = tok + first * page_size
             s = jnp.where((tok <= pos) & (tok >= lo), s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp2(s - m_new)
@@ -243,7 +302,7 @@ def _decode_call(table, pos, qs, k_pool, v_pool, *, interpret: bool,
                  window=None):
     B, Hq, D = qs.shape
     _, Hkv, page_size, _ = k_pool.shape
-    chunk = _pages_per_chunk(Hkv, page_size, D, k_pool.dtype.itemsize)
+    chunk = _pages_per_chunk(Hkv, Hq, page_size, D, k_pool.dtype.itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
@@ -268,8 +327,9 @@ def _decode_call(table, pos, qs, k_pool, v_pool, *, interpret: bool,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), v_pool.dtype),
+        # the kernel holds every copy in bounds itself (``start_page``)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel",), disable_bounds_checks=True),
         interpret=interpret,
         name="paged_decode" if window is None else "window_decode",
     )(table, pos, qs, k_pool, v_pool)

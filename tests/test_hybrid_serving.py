@@ -613,11 +613,13 @@ class TestOneProgramAnAdmission:
 #: ``_lowered(...)`` of each below. The four decode programs were recorded
 #: again at PR 39 (the sampler's conditional in place of its sort) and at
 #: PR 43 (the ``host_tokens`` operand and its select: one argument more).
+#: ``gpt/decode/pallas`` was recorded again at PR 47 (the paged-decode
+#: kernel's page walk; 694c5b446e7a6e60 before it).
 WITHOUT_STATE = {
     "gpt/prefill/oracle": (6, "3baaa1868e5f7615"),
     "gpt/extend/oracle": (7, "24143a35f6d0155c"),
     "gpt/decode/oracle": (11, "3298d34fd1edf2bd"),
-    "gpt/decode/pallas": (11, "694c5b446e7a6e60"),
+    "gpt/decode/pallas": (11, "efa21588867f80f4"),
     "decoder/prefill/oracle": (7, "0024ab6d3c6af9d9"),
     "decoder/extend/oracle": (8, "539dd0b7ee3df441"),
     "decoder/decode/oracle": (12, "1524d1bb051655c3"),

@@ -619,6 +619,8 @@ def test_engine_builds_over_a_first_pool_of_some_layers(model):
 #: descriptions and the GPT's programs and train step are pinned in
 #: tests/test_window_serving.py and tests/test_hybrid_serving.py, which this
 #: PR leaves as they were.
+#: ``window/decode/pallas`` was recorded again at PR 47 (the paged-decode
+#: kernel's page walk; 86fdb634458456b2 before it).
 WINDOW = dict(layer_types=("sliding",) * 3 + ("dense",), sliding_window=16,
               kv_layout="head", norm="layer_nobias",
               norm_placement="parallel", position="rope_gptj",
@@ -630,7 +632,7 @@ PARENT = {
     "window/prefill/oracle": (9, "b71831046b432b1b"),
     "window/extend/oracle": (10, "b5e0fbca39d3f079"),
     "window/decode/oracle": (14, "b81bda4484256679"),
-    "window/decode/pallas": (14, "86fdb634458456b2"),
+    "window/decode/pallas": (14, "451242c0bd563bf7"),
 }
 
 

@@ -18,7 +18,8 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import observability as obs
-from paddle_tpu.kernels.paged_attention import (decode_attend,
+from paddle_tpu.kernels.paged_attention import (_pages_per_chunk,
+                                                decode_attend,
                                                 paged_attention,
                                                 paged_decode_attend)
 from paddle_tpu.kernels.pools import (PAGE_SENTINEL, paged_gather,
@@ -262,33 +263,72 @@ class TestPagedPrimitives:
         its copies can go wrong: a context of exactly one chunk, one chunk
         plus a token, several chunks with a partial last one, the table's
         full width, and a dead slot between two live ones."""
-        from paddle_tpu.kernels.paged_attention import _pages_per_chunk
-
         Hkv, ps, D = 2, 16, 8
-        ct = _pages_per_chunk(Hkv, ps, D, 4) * ps
+        ct = _pages_per_chunk(Hkv, Hkv * rep, ps, D, 4) * ps
         nb = 3 * ct // ps
         positions = self._WALKS[walk](ct, nb * ps)
         self._assert_walk_matches_oracle(self._walk_case(
             positions, Hkv=Hkv, rep=rep, ps=ps, nb=nb, D=D,
             dtype=jnp.float32), tol=1e-5)
 
-    @pytest.mark.parametrize("rep", [1, 2])
-    def test_kernel_walk_at_serving_tile_shapes(self, rep):
-        """Page 16, D 128, bf16 pools (the serving cells' tiles): a chunk
-        is 8 pages, a [H_q, 128] score tile; tolerance as chip_smoke's for
-        bf16."""
+    @pytest.mark.parametrize("Hkv,rep", [(2, 16), (8, 8), (8, 16), (16, 1)])
+    def test_kernel_walk_at_serving_tile_shapes(self, Hkv, rep):
+        """Page 16, D 128, bf16 pools at the cells' head counts (reasoning,
+        agents, rag, chat), each walked at ITS chunk width: under one
+        chunk, exactly one, one plus a token, three and a half, the
+        table's full width, and a dead slot between live ones; tolerance
+        as chip_smoke's for bf16."""
+        ps, D = 16, 128
+        ct = _pages_per_chunk(Hkv, Hkv * rep, ps, D, 2) * ps
+        nb = 4 * ct // ps
         self._assert_walk_matches_oracle(self._walk_case(
-            [300, None, 127, 128, 15], Hkv=2, rep=rep, ps=16, nb=24, D=128,
-            dtype=jnp.bfloat16), tol=5e-2)
+            [ct // 2, ct - 1, ct, None, 3 * ct + ct // 2, nb * ps - 1, 15],
+            Hkv=Hkv, rep=rep, ps=ps, nb=nb, D=D, dtype=jnp.bfloat16),
+            tol=5e-2)
 
-    def test_kernel_reads_only_live_pages(self):
+    @pytest.mark.parametrize("shape", ["tiny_pages", "two_kv_heads"])
+    def test_kernel_reads_only_live_pages(self, shape):
         """Every pool page outside the slots' live sets is NaN (tails of
         live pages stay finite, as the allocator leaves them): the output
         is finite and the oracle's on the live rows, so no dead page, no
-        trash page and no stale buffer reached the sum."""
-        self._assert_walk_matches_oracle(self._walk_case(
-            [140, None, 3, 400, None, 256], Hkv=2, rep=2, ps=8, nb=56, D=8,
-            dtype=jnp.float32, poison=True), tol=1e-5)
+        trash page and no stale buffer reached the sum. At 2 K/V heads
+        the contexts end in a partial chunk behind whole ones, so both of
+        the walk's bodies read poisoned pools."""
+        if shape == "tiny_pages":
+            case = self._walk_case(
+                [140, None, 3, 400, None, 256], Hkv=2, rep=2, ps=8, nb=56,
+                D=8, dtype=jnp.float32, poison=True)
+            tol = 1e-5
+        else:
+            ct = _pages_per_chunk(2, 32, 16, 128, 2) * 16
+            case = self._walk_case(
+                [ct + ct // 2 + 3, None, 3, 2 * ct + 17, None, ct - 1],
+                Hkv=2, rep=16, ps=16, nb=3 * ct // 16, D=128,
+                dtype=jnp.bfloat16, poison=True)
+            tol = 5e-2
+        self._assert_walk_matches_oracle(case, tol)
+
+    # (H_kv, H_q) of the six serving configurations (chat, Keye, hybrid,
+    # agents, rag, reasoning; page 16, D 128, bf16) -> pages a chunk
+    @pytest.mark.parametrize("Hkv,Hq,pages", [
+        (16, 16, 8), (4, 32, 32), (30, 30, 8), (8, 64, 16), (8, 128, 16),
+        (2, 32, 64)])
+    def test_chunk_width_follows_the_shapes(self, Hkv, Hq, pages):
+        """The chunk's width is a function of the operands' shapes: as
+        many pages as fill a buffer of about half a MiB, at most 1,024
+        tokens and a [H_q, chunk] float32 score tile of 128 KiB, a whole
+        number of lane widths of tokens, four buffers inside the VMEM
+        budget."""
+        from paddle_tpu.kernels.flash_attention import LANES
+        from paddle_tpu.kernels.paged_attention import _CHUNK_VMEM_BYTES
+
+        ps, D, itemsize = 16, 128, 2
+        got = _pages_per_chunk(Hkv, Hq, ps, D, itemsize)
+        assert got == pages
+        assert got * ps % LANES == 0 and got * ps <= 1024
+        assert 4 * got * Hkv * ps * D * itemsize <= _CHUNK_VMEM_BYTES
+        # the tests' tiny pages do not make a chunk of thousands of pages
+        assert _pages_per_chunk(2, 4, 4, 8, 4) * 4 <= 1024
 
     @pytest.mark.parametrize("nb", [8, 128])
     def test_kernel_grid_does_not_depend_on_table_width(self, nb):

@@ -289,14 +289,25 @@ def _pools(B, nb, Hkv=2, D=8, seed=0):
     return k, v, table
 
 
-@pytest.mark.parametrize("window", [6, 16, 17])
+@pytest.mark.parametrize("window", [6, 16, 17, "three_chunks"])
 def test_window_kernel_matches_the_oracle(window):
     """Ragged lengths, an empty slot, a context shorter than the window,
     windows that start mid-page (6 and 17 over pages of 4), and the pages
-    behind the window unmapped, as the engine leaves them."""
-    B, nb = 5, 16
+    behind the window unmapped, as the engine leaves them. The last case
+    is a window of three chunks of the kernel's walk."""
+    if window == "three_chunks":
+        # two and a half chunks and two tokens: from a position that ends a
+        # page the walk starts mid-page and ends in a partial chunk
+        from paddle_tpu.kernels.paged_attention import _pages_per_chunk
+
+        ct = _pages_per_chunk(2, 4, PS, 8, 4) * PS
+        window = 2 * ct + ct // 2 + 2
+        B, nb = 5, (window + window // 2) // PS
+        pos = np.array([3, 0, window + 37, nb * PS - 1, window - 9], np.int32)
+    else:
+        B, nb = 5, 16
+        pos = np.array([3, 0, 37, 63, 21], np.int32)
     k, v, table = _pools(B, nb)
-    pos = np.array([3, 0, 37, 63, 21], np.int32)
     table[1] = PAGE_SENTINEL                          # an empty slot
     for b in (2, 3, 4):                               # freed behind the window
         table[b, :max(0, pos[b] - window + 1) // PS] = PAGE_SENTINEL
@@ -604,6 +615,8 @@ OLDER = {
 #: to change these programs records them again: print ``_lowered(...)``.
 #: The six decode programs were recorded again at PR 43 (the ``host_tokens``
 #: operand and its select: one argument more).
+#: ``hybrid/decode/pallas`` was recorded again at PR 47 (the paged-decode
+#: kernel's page walk; 534176be6752a5b1 before it).
 PARENTS = {
     "hybrid/params": (28, 4035424780),
     "share/params": (39, 2590006625),
@@ -611,7 +624,7 @@ PARENTS = {
     "hybrid/prefill/oracle": (9, "b0e020062e23733c"),
     "hybrid/extend/oracle": (10, "0650b351e53e3636"),
     "hybrid/decode/oracle": (13, "68db039800b0e874"),
-    "hybrid/decode/pallas": (13, "534176be6752a5b1"),
+    "hybrid/decode/pallas": (13, "4a5157101a4727ff"),
     "share/prefill/oracle": (9, "8b4e2f98c16d6298"),
     "share/extend/oracle": (10, "e71bfd3db5f77058"),
     "share/decode/oracle": (13, "2d02876efc7303a0"),
