@@ -13,8 +13,11 @@ Pallas/Mosaic. Every kernel here:
   (paged_attention, latent_attention, sparse_attention, gated_delta,
   mamba2) are
   picked by ``tier.default_paged_impl`` instead, which their own entries
-  (``paged_decode_attend``, ``latent_decode_attend``, ``gdn_step``,
-  ``mamba2_step``) ask,
+  (``paged_decode_attend``, ``paged_extend_attend``,
+  ``latent_decode_attend``, ``gdn_step``, ``mamba2_step``) ask; the flash
+  kernels behind a cached context (``latent_attention.latent_flash``,
+  ``paged_attention.extend_flash``) share one grid step
+  (``flash_attention.online_softmax_step``),
 - carries a stable ``name=`` and, under a mesh, runs inside a shard_map
   over all mesh axes (mesh.py: shard_kernel; kernel_sites reads back which
   kernels a compiled program holds).

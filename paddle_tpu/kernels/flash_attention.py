@@ -33,6 +33,59 @@ LOG2E = 1.4426950408889634  # softmax runs in the exp2 domain (see _fwd_kernel)
 LANES = 128
 
 
+
+# ------- one grid step of a flash kernel behind a cached context -------
+def last_key_block(start, qi, block_q: int, block_k: int, num_kb: int):
+    """Key blocks [0, this) hold a position some query of block ``qi``
+    sees, where the first query stands at key position ``start``."""
+    return jnp.minimum((start + (qi + 1) * block_q + block_k - 1) // block_k,
+                       num_kb)
+
+
+def online_softmax_init(m_scr, l_scr, acc_scr):
+    """The running maximum, sum and accumulator before a row's first key."""
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def online_softmax_step(scores, values, m_scr, l_scr, acc_scr, *, rows: int,
+                        chunks: int):
+    """Fold ALL the keys of a grid step into the running maximum, sum and
+    accumulator (float32 VMEM scratch, row for row) of its ``chunks * rows``
+    score rows: the step of ``latent_attention.latent_flash`` and of
+    ``paged_attention.extend_flash``, which differ in how a chunk's scores
+    are made. ``scores(r)`` is chunk ``r``'s masked float32 tile ``[rows,
+    keys]`` in the exp2 domain, ``values()`` the step's ``[keys, Dv]``. The
+    chunks share nothing, and chunk r + 1's products are written down
+    BEFORE chunk r's softmax so that the scheduler (one basic block: the
+    loop is unrolled) runs the matrix unit's phase of one under the vector
+    unit's phase of the other (PERF.md section 6, PR 44). A row whose keys
+    so far are all masked carries the mask's value as its maximum and sums
+    garbage; the first visible key rescales that to nothing, and every row
+    a caller reads has one (its own position)."""
+
+    def fold(r, s):
+        qr, v = pl.ds(r * rows, rows), values()
+        m, l = m_scr[qr, :1], l_scr[qr, :1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        alpha = jnp.exp2(m - m_new)
+        acc_scr[qr] = acc_scr[qr] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[qr] = jnp.broadcast_to(m_new, (rows, m_scr.shape[1]))
+        l_scr[qr] = jnp.broadcast_to(
+            l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+            (rows, l_scr.shape[1]))
+
+    s = scores(0)
+    for r in range(chunks):
+        ahead = scores(r + 1) if r + 1 < chunks else None
+        fold(r, s)
+        s = ahead
+
+
 # ---------------- forward ----------------
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, num_kb: int, block_q: int, block_k: int, causal: bool, scale: float):

@@ -102,7 +102,8 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ..core.place import pallas_interpret
-from .flash_attention import LOG2E, NEG_INF
+from .flash_attention import (LOG2E, NEG_INF, last_key_block,
+                              online_softmax_init, online_softmax_step)
 from .mesh import shard_kernel
 from .pools import paged_gather
 from .tier import default_paged_impl
@@ -483,13 +484,6 @@ def _blocks(T: int, L: int):
     return pick(T), pick(L)
 
 
-def _last_block(start, qi, block_q: int, block_k: int, num_kb: int):
-    """Key blocks [0, this) hold a position some query of block ``qi``
-    sees."""
-    return jnp.minimum((start + (qi + 1) * block_q + block_k - 1) // block_k,
-                       num_kb)
-
-
 def _flash_kernel(start_ref, qn_ref, qp_ref, kn_ref, kp_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, heads: int, num_kb: int,
                   block_q: int, block_k: int):
@@ -497,19 +491,12 @@ def _flash_kernel(start_ref, qn_ref, qp_ref, kn_ref, kp_ref, v_ref, o_ref,
     position of each sequence's first query."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     start = start_ref[pl.program_id(0) // heads]
-    kb_hi = _last_block(start, qi, block_q, block_k, num_kb)
+    kb_hi = last_key_block(start, qi, block_q, block_k, num_kb)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    pl.when(ki == 0)(lambda: online_softmax_init(m_scr, l_scr, acc_scr))
 
     # the step's queries go _FLASH_ROWS at a time against ALL its keys (a
-    # block they do not divide goes whole); the chunks share nothing, and
-    # chunk r + 1's products are written down BEFORE chunk r's softmax so
-    # that the scheduler (one basic block: the loop is unrolled) runs the
-    # matrix unit's phase of one under the vector unit's phase of the other
+    # block they do not divide goes whole), in ``online_softmax_step``'s order
     rows = _FLASH_ROWS if block_q % _FLASH_ROWS == 0 else block_q
 
     def scores(r):
@@ -524,30 +511,12 @@ def _flash_kernel(start_ref, qn_ref, qp_ref, kn_ref, kp_ref, v_ref, o_ref,
         kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         return jnp.where(qpos >= kpos, s, NEG_INF)
 
-    def fold(r, s):
-        qr, v = pl.ds(r * rows, rows), v_ref[0]
-        # key 0 is behind every query and block 0 is walked first, so no
-        # row's running maximum stays at the mask's value
-        m, l = m_scr[qr, :1], l_scr[qr, :1]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp2(s - m_new)
-        alpha = jnp.exp2(m - m_new)
-        acc_scr[qr] = acc_scr[qr] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[qr] = jnp.broadcast_to(m_new, (rows, m_scr.shape[1]))
-        l_scr[qr] = jnp.broadcast_to(
-            l * alpha + jnp.sum(p, axis=-1, keepdims=True),
-            (rows, l_scr.shape[1]))
-
     @pl.when(ki < kb_hi)
     def _compute():
-        chunks = block_q // rows
-        s = scores(0)
-        for r in range(chunks):
-            ahead = scores(r + 1) if r + 1 < chunks else None
-            fold(r, s)
-            s = ahead
+        # key 0 is behind every query and block 0 is walked first, so no
+        # row's running maximum stays at the mask's value
+        online_softmax_step(scores, lambda: v_ref[0], m_scr, l_scr, acc_scr,
+                            rows=rows, chunks=block_q // rows)
 
     @pl.when(ki == num_kb - 1)
     def _epilogue():
@@ -576,7 +545,7 @@ def _flash_call(starts, qn, qp, kn, kp, v, *, heads: int, interpret: bool):
 
     def block(g, qi, ki, start_ref):
         # a block past the last one computed repeats it: nothing is fetched
-        last = _last_block(start_ref[g // heads], qi, bq, bk, num_kb) - 1
+        last = last_key_block(start_ref[g // heads], qi, bq, bk, num_kb) - 1
         return jnp.minimum(ki, last)
 
     q_map = lambda g, qi, ki, _s: (g, qi, 0)

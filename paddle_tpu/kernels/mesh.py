@@ -91,3 +91,22 @@ def kernel_sites(program) -> Dict[str, int]:
         name = _IDENT.findall(scope.group(1))[-1] if scope else "unnamed"
         out[name] = out.get(name, 0) + 1
     return dict(sorted(out.items()))
+
+
+def traced_kernels(fn, *args) -> Dict[str, int]:
+    """{kernel name: ``pallas_call`` equations} in the jaxpr of ``fn(*args)``:
+    what ``kernel_sites`` would read off the compiled program, where there
+    is no Mosaic call to read (on the CPU a kernel runs interpreted, as plain
+    HLO)."""
+    out: Dict[str, int] = {}
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                name = _IDENT.findall(str(e.params["name"]))[-1]
+                out[name] = out.get(name, 0) + 1
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return dict(sorted(out.items()))

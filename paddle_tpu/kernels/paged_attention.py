@@ -43,12 +43,18 @@ Numerics mirror ``decode_attend``, the jnp reference below (the oracle): q
 pre-scaled in its own dtype, f32 scores/softmax, output cast to v's dtype —
 parity is asserted across ragged batches by tests/test_paged_kv.py.
 
-Behind the kernel stand its references over dense ``[B, H_kv, S_max, D]``
+``T`` queries a slot (a prefix hit's suffix, a verify's drafts) have a
+kernel of their own, ``extend_flash`` (``window_extend_flash`` under a
+window): a causal flash kernel over the GATHERED view of a slot's pages,
+one K/V head's query heads side by side as the rows of a score matmul, the
+scores in VMEM alone, key blocks no query sees neither fetched nor computed.
+
+Behind the kernels stand their references over dense ``[B, H_kv, S_max, D]``
 caches (``decode_attend``, and ``extend_attend`` for ``T`` queries a slot)
-and the entries a model's layer calls: ``paged_decode_attend`` picks kernel
-or reference as ``tier.default_paged_impl`` says, ``paged_extend_attend``
-is the reference over ``pools.paged_gather``'s view in every tier. The
-dense pair is also the lockstep decode of ``GPTForCausalLM.generate`` and
+and the entries a model's layer calls, ``paged_decode_attend`` and
+``paged_extend_attend``: each picks kernel or reference as
+``tier.default_paged_impl`` says. The dense pair is also the lockstep
+decode of ``GPTForCausalLM.generate`` and
 ``incubate.nn.FusedMultiTransformer``'s ``time_step``, so the cached
 attention implementations cannot drift. Their numerics deliberately mirror
 ``nn.functional._sdpa_ref`` (pre-scaled q, f32 logits, -1e30 masking, f32
@@ -69,9 +75,10 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ..core.place import pallas_interpret
-from .flash_attention import LANES, LOG2E, NEG_INF
+from .flash_attention import (LANES, LOG2E, NEG_INF, last_key_block,
+                              online_softmax_init, online_softmax_step)
 from .mesh import shard_kernel
-from .pools import paged_gather
+from .pools import PAGE_SENTINEL, paged_gather
 from .tier import default_paged_impl
 
 
@@ -336,6 +343,203 @@ def _decode_call(table, pos, qs, k_pool, v_pool, *, interpret: bool,
 
 
 # ---------------------------------------------------------------------------
+# The extend: T queries a slot behind its cached context, over the gathered
+# head-major view (``extend_flash`` / ``window_extend_flash``)
+# ---------------------------------------------------------------------------
+
+#: score rows of a grid step whose scores are one tile of the online softmax
+#: (``latent_attention._FLASH_ROWS``'s twin: PERF.md section 6, PR 44), the
+#: rows a grid step fills where the shapes allow (one K/V block is fetched
+#: for all of them), and the keys a step folds at once
+_EXTEND_CHUNK_ROWS = 128
+_EXTEND_STEP_ROWS = 1024
+_EXTEND_KEYS = 1024
+#: a query block is whole sublane tiles of every dtype the pools take
+_EXTEND_QUERY_TILE = 16
+
+
+def _extend_blocks(rep: int, T: int, L: int):
+    """(queries, keys) a grid step, from the shapes the call sees. Queries:
+    the largest power of two that divides ``T`` (a multiple of
+    ``_EXTEND_QUERY_TILE``) with ``rep`` times as many score rows inside
+    ``_EXTEND_STEP_ROWS``: 64 at 16 query heads a K/V head, 1,024 at one.
+    Keys: ``_EXTEND_KEYS`` or the largest halving of it down to 128 that
+    divides ``L``, else the whole view (small test shapes;
+    ``paged_extend_attend`` pads its table so that the first holds)."""
+    bq = _EXTEND_QUERY_TILE
+    while bq * 2 * rep <= _EXTEND_STEP_ROWS and T % (bq * 2) == 0:
+        bq *= 2
+    bk = _EXTEND_KEYS
+    while bk > LANES and L % bk:
+        bk //= 2
+    return bq, bk if L % bk == 0 else L
+
+
+def _key_blocks(rel, qi, block_q: int, block_k: int, num_kb: int, window):
+    """Key blocks [lo, hi) of the view hold a row some query of block
+    ``qi`` sees; ``rel`` is the view row of the sequence's first query.
+    Under a window the walk starts at the block of the first query's
+    oldest key."""
+    hi = last_key_block(rel, qi, block_q, block_k, num_kb)
+    if window is None:
+        return 0, hi
+    lo = jnp.maximum(rel + qi * block_q - (window - 1), 0) // block_k
+    return jnp.minimum(lo, num_kb - 1), hi
+
+
+def _extend_kernel(rel_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                   acc_scr, *, num_kb: int, steps: int, block_q: int,
+                   block_k: int, window=None):
+    """Grid (B, H_kv, T / block_q, steps): one K/V head's ``rep`` query
+    heads x ``block_q`` queries stand side by side as the score rows of a
+    step (``q_ref [1, rep, block_q, D]``; row ``h * block_q + t``), against
+    key block ``lo + step`` of the view. ``rel_ref [B]``: the view row at
+    which each sequence's first query stands (row s of the view is visible
+    to query t where ``s <= rel + t``, and under a window where also ``s >
+    rel + t - window``)."""
+    b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    rep, D = q_ref.shape[1], q_ref.shape[3]
+    rel = rel_ref[b]
+    lo, hi = _key_blocks(rel, qi, block_q, block_k, num_kb, window)
+
+    pl.when(ki == 0)(lambda: online_softmax_init(m_scr, l_scr, acc_scr))
+
+    # a chunk of the step's rows is whole heads' queries, or a part of one
+    # head's; a block neither fits goes whole
+    rows = rep * block_q
+    if rows % _EXTEND_CHUNK_ROWS == 0 and (
+            _EXTEND_CHUNK_ROWS % block_q == 0
+            or block_q % _EXTEND_CHUNK_ROWS == 0):
+        rows = _EXTEND_CHUNK_ROWS
+    heads, parts = max(rows // block_q, 1), max(block_q // rows, 1)
+
+    def scores(r):
+        if parts == 1:
+            q = q_ref[0, pl.ds(r * heads, heads)].reshape(rows, D)
+            t = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0)
+            t = t & (block_q - 1) if heads > 1 else t   # a power of two
+        else:
+            q = q_ref[0, r // parts, pl.ds(r % parts * rows, rows)]
+            t = r % parts * rows \
+                + jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0)
+        s = jax.lax.dot_general(                     # q k^T, float32
+            q, k_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.float32(LOG2E)
+        # how far behind its query a key stands: none before it, and under
+        # a window none ``window`` or more behind
+        behind = rel + qi * block_q - (lo + ki) * block_k + t \
+            - jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
+        seen = behind >= 0 if window is None \
+            else (behind >= 0) & (behind < window)
+        return jnp.where(seen, s, NEG_INF)
+
+    @pl.when(lo + ki < hi)
+    def _compute():
+        online_softmax_step(scores, lambda: v_ref[0, 0], m_scr, l_scr,
+                            acc_scr, rows=rows,
+                            chunks=rep * block_q // rows)
+
+    @pl.when(ki == steps - 1)
+    def _epilogue():
+        o_ref[0] = (acc_scr[...] / l_scr[:, :1]).reshape(
+            rep, block_q, D).astype(o_ref.dtype)
+
+
+def extend_flash(q, k_view, v_view, starts, window=None, first=None):
+    """Causal attention of ``T`` queries a sequence behind its cached
+    context, over gathered head-major views: the Pallas tier of
+    ``paged_extend_attend`` and the kernel ``extend_attend`` is the oracle
+    of.
+
+    q            ``[B, H_q, T, D]``, PRE-SCALED; query ``t`` of row ``b``
+                 at position ``starts[b] + t``
+    k/v_view     ``[B, H_kv, L, D]`` as ``pools.paged_gather`` returns
+                 them: row ``s`` at position ``first[b] + s`` (``first``
+                 None: position ``s``). A K/V head is read ONCE for its
+                 ``H_q / H_kv`` query heads, whose rows share a score
+                 matmul
+    window       static; None: every key up to the query's own position.
+                 An int: those less than ``window`` behind it alone (a
+                 sliding layer's view of its window and the new tokens,
+                 ``pools.window_blocks``). A kernel of its own by NAME
+                 (``window_extend_flash``; ``extend_flash`` is the full
+                 one's), as ``window_decode`` beside ``paged_decode``
+
+    Returns ``[B, H_q, T, D]`` in v's dtype. float32 scores and online
+    softmax in VMEM (``flash_attention.online_softmax_step``: 1,024 keys a
+    fold, 128 score rows a tile); key blocks no query of a block sees are
+    neither fetched nor computed (before the window's first, past the
+    block's last query). ``T`` is padded to whole sublane tiles here; the
+    views go in as they are (``_extend_blocks``)."""
+    B, Hq, T, D = q.shape
+    starts = jnp.asarray(starts, jnp.int32)
+    rel = starts if first is None else starts - jnp.asarray(first, jnp.int32)
+    pad = -T % _EXTEND_QUERY_TILE
+    if pad:     # rows behind the real ones: they see more, and are dropped
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    mp = dict(jax.sharding.get_abstract_mesh().shape).get("mp", 1)
+    heads = P(None, "mp") if k_view.shape[1] % mp == 0 else P()
+    call = functools.partial(_extend_call, interpret=pallas_interpret())
+    if window is not None:
+        call = functools.partial(call, window=int(window))
+    out = shard_kernel(call, (rel, q, k_view, v_view),
+                       (P(), heads, heads, heads), lambda f: f[1])
+    return out[:, :, :T] if pad else out
+
+
+# jitted for the reason ``_decode_call`` is: a model's layers share ONE trace
+# and ONE Mosaic lowering in the program they are traced into
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+def _extend_call(rel, q, k_view, v_view, *, interpret: bool, window=None):
+    B, Hq, T, D = q.shape
+    Hkv, L = k_view.shape[1], k_view.shape[2]
+    rep = Hq // Hkv
+    bq, bk = _extend_blocks(rep, T, L)
+    num_kb = L // bk
+    # the blocks a query block's window and its own keys can lie across
+    steps = num_kb if window is None else min(
+        num_kb, (window + bq + bk - 3) // bk + 1)
+
+    def block(b, qi, ki, rel_ref):
+        # a step past the last block computed repeats it: nothing is fetched
+        lo, hi = _key_blocks(rel_ref[b], qi, bq, bk, num_kb, window)
+        return jnp.minimum(lo + ki, hi - 1)
+
+    q_map = lambda b, g, qi, ki, _r: (b, g, qi, 0)
+    k_map = lambda b, g, qi, ki, r: (b, g, block(b, qi, ki, r), 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, Hkv, T // bq, steps),
+        in_specs=[
+            pl.BlockSpec((1, rep, bq, D), q_map),
+            pl.BlockSpec((1, 1, bk, D), k_map),
+            pl.BlockSpec((1, 1, bk, D), k_map),
+        ],
+        out_specs=pl.BlockSpec((1, rep, bq, D), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((rep * bq, LANES), jnp.float32),
+            pltpu.VMEM((rep * bq, LANES), jnp.float32),
+            pltpu.VMEM((rep * bq, D), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_extend_kernel, num_kb=num_kb, steps=steps,
+                               block_q=bq, block_k=bk)
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, T, D), v_view.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="extend_flash" if window is None else "window_extend_flash",
+    )(rel, q, k_view, v_view)
+
+
+# ---------------------------------------------------------------------------
 # The jnp references (dense ``[B, H_kv, S, D]`` caches) and the entries a
 # model's layer calls, which pick between kernel and reference (tier.py)
 # ---------------------------------------------------------------------------
@@ -386,13 +590,17 @@ def decode_attend(q, k_cache, v_cache, positions, window=None):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def extend_attend(q, k_cache, v_cache, positions):
+def extend_attend(q, k_cache, v_cache, positions, window=None, first=None):
     """Multi-query cached attention: q ``[B, H_q, T, D]`` where query ``t``
     of row ``b`` sits at absolute position ``positions[b] + t`` and may
-    attend to ``key_pos <= positions[b] + t`` — the suffix-prefill /
-    speculative-verify generalization of ``decode_attend`` (T=1 reduces to
-    it exactly). Same _sdpa_ref numerics: q pre-scaled in its own dtype,
-    f32 scores, -1e30 mask, f32 softmax."""
+    attend to ``key_pos <= positions[b] + t``, with ``window`` to those
+    with ``key_pos > positions[b] + t - window`` alone — the suffix-prefill
+    / speculative-verify generalization of ``decode_attend`` (T=1 reduces
+    to it exactly), and the oracle of ``extend_flash`` /
+    ``window_extend_flash``. Row ``s`` of the caches is key position ``s``,
+    or ``first[b] + s`` where ``first [B]`` is given (a view of a slot's
+    window). Same _sdpa_ref numerics: q pre-scaled in its own dtype, f32
+    scores, -1e30 mask, f32 softmax."""
     D = q.shape[-1]
     rep = q.shape[1] // k_cache.shape[1]
     k = _expand_kv_heads(k_cache, rep)
@@ -402,8 +610,12 @@ def extend_attend(q, k_cache, v_cache, positions):
                    preferred_element_type=jnp.float32)
     T = q.shape[2]
     qpos = jnp.asarray(positions)[:, None] + jnp.arange(T)[None, :]  # [B, T]
-    key_pos = jnp.arange(k_cache.shape[2])
-    valid = key_pos[None, None, None, :] <= qpos[:, None, :, None]
+    key_pos = jnp.arange(k_cache.shape[2])[None, None, None, :]
+    if first is not None:
+        key_pos = key_pos + jnp.asarray(first)[:, None, None, None]
+    valid = key_pos <= qpos[:, None, :, None]
+    if window is not None:
+        valid = valid & (key_pos > qpos[:, None, :, None] - window)
     s = jnp.where(valid, s, NEG_INF)
     probs = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
@@ -428,12 +640,33 @@ def paged_decode_attend(q, k_pool, v_pool, page_table, positions,
     return paged_attention(q, k_pool, v_pool, page_table, positions, window)
 
 
-def paged_extend_attend(q, k_pool, v_pool, page_table, positions):
+def paged_extend_attend(q, k_pool, v_pool, page_table, positions,
+                        window=None, first=None):
     """Multi-query cached attention over block-paged pools — the paged twin
-    of ``extend_attend``. The Pallas ragged kernel is single-query, so
-    every tier reconstructs the dense view (``paged_gather``) and runs the
-    einsum path. Verify steps are rare next to decode steps (one per k+1
-    emitted tokens), so the gather cost is amortized."""
-    k = paged_gather(k_pool, page_table)
-    v = paged_gather(v_pool, page_table)
-    return extend_attend(q, k, v, positions)
+    of ``extend_attend`` (an admission's new tokens behind a prefix hit, a
+    speculative verify), in the tier ``tier.default_paged_impl`` says.
+    ``q [B, H_q, T, D]`` not yet scaled; ``window`` a sliding layer's, with
+    ``page_table`` and ``first`` then ``pools.window_blocks``'s: the table
+    entries of the window and the new tokens, and the position of the
+    view's first row. Both tiers reconstruct the dense view
+    (``pools.paged_gather``: the gather is a small part of an extend);
+    ``oracle`` runs the einsum reference over it, whose float32 scores
+    stand in memory whole; ``pallas`` runs ``extend_flash``, whose scores
+    never leave VMEM and which neither fetches nor computes the key blocks
+    no query sees. For it the table is first widened with sentinels (the
+    trash page, behind every query) to whole key blocks."""
+    if default_paged_impl() == "oracle":
+        k = paged_gather(k_pool, page_table)
+        v = paged_gather(v_pool, page_table)
+        return extend_attend(q, k, v, positions, window, first)
+    ps = k_pool.shape[2]
+    L = page_table.shape[1] * ps
+    unit = _EXTEND_KEYS if L > _EXTEND_KEYS else LANES
+    more = (-L % unit) // ps if unit % ps == 0 else 0
+    if more:
+        page_table = jnp.pad(page_table, ((0, 0), (0, more)),
+                             constant_values=PAGE_SENTINEL)
+    qs = q * jnp.asarray(1.0 / np.sqrt(q.shape[-1]), q.dtype)
+    return extend_flash(qs, paged_gather(k_pool, page_table),
+                        paged_gather(v_pool, page_table), positions, window,
+                        first)

@@ -178,7 +178,10 @@ ONE attention routine (``attend``) serves all three: queries at
 so that no ``[T, L]`` float32 array larger than a chunk exists; prefill is
 the case ``start = 0`` on the keys just computed, decode the case ``T = 1``
 (on the TPU its read of the selected rows is the Pallas kernel
-``kernels/sparse_attention.sparse_paged_decode``). The expert layer is
+``kernels/sparse_attention.sparse_paged_decode``). A head-major model's
+extend and decode go through the paged attends instead
+(``paged_extend_attend`` / ``paged_decode_attend``: kernel or reference, as
+``kernels/tier`` says). The expert layer is
 drop-free (``kernels/grouped_matmul``): rows sorted by expert, a grouped
 matmul over the sorted rows, routing weights applied in float32 at the
 combine.
@@ -199,7 +202,8 @@ from jax import lax
 from ..core.tensor import Parameter, Tensor
 from ..kernels import latent_attention as _latent
 from ..kernels import pools as _pools, tier as _tier
-from ..kernels.paged_attention import paged_decode_attend
+from ..kernels.paged_attention import (paged_decode_attend,
+                                       paged_extend_attend)
 from ..nn.layer.layers import Layer
 
 _NEG_INF = -1e30
@@ -686,8 +690,9 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     tokens sit at ``start[b] .. start[b] + T - 1``. A layer of ``kind``
     "sliding" sees the last ``cfg.sliding_window`` keys alone: its prefill
     is a causal band (``attend``'s), its extend reads a view of the window
-    and the new tokens (not of the whole table), its decode walks the
-    window's pages (``paged_decode_attend``'s ``window``). Without ``cache``
+    and the new tokens (not of the whole table; ``paged_extend_attend``'s
+    ``window``), its decode walks the window's pages
+    (``paged_decode_attend``'s ``window``). Without ``cache``
     (prefill) the keys are the ones just computed; with ``cache`` (the
     layer's pools and the page table) they are written into the pools first
     and read back through the table. Returns (out [B, T, hidden], new):
@@ -779,10 +784,9 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
                 # the window before the first new token, and the new tokens
                 first, sub = _pools.window_blocks(
                     table, start, pools[0].shape[2], window, T)
-                view = lambda pool: _pools.paged_gather(pool, sub) \
-                    .transpose(0, 2, 1, 3)
-                o = attend(cfg, q, view(pools[0]), view(pools[1]), pos,
-                           window=window, first=first)
+                o = paged_extend_attend(
+                    q.transpose(0, 2, 1, 3), pools[0], pools[1], sub, start,
+                    window=window, first=first).transpose(0, 2, 1, 3)
     elif head_major and T == 1:
         # the paged attend, kernel or oracle as kernels/tier says
         with _scope(cfg, "attn/full"):
@@ -803,6 +807,10 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
                                 cfg.index_topk)
         o = sparse_paged_decode(q[:, 0], pools[0], pools[1], rows, n)
         o = o[:, None]
+    elif head_major and not sparse:
+        # an extend behind the cached context, kernel or oracle likewise
+        o = paged_extend_attend(q.transpose(0, 2, 1, 3), pools[0], pools[1],
+                                table, start).transpose(0, 2, 1, 3)
     else:
         if head_major:
             view = lambda pool, heads: _pools.paged_gather(pool, table) \

@@ -35,7 +35,9 @@ Public surface:
   :func:`accept_greedy` — n-gram-draft speculative decoding over the
   one-compile verify-k program (``EngineConfig(speculative=k)``).
 - :func:`extend_attend` / :func:`paged_extend_attend` — the multi-query
-  cached-attention primitives suffix prefill and verify ride on.
+  cached-attention primitives suffix prefill and verify ride on (the
+  second picks the Pallas kernel ``extend_flash`` or the first, its
+  oracle, as ``kernels/tier`` says).
 
 See ``paddle_tpu/serving/README.md`` for the design and metric names.
 """

@@ -223,6 +223,19 @@ out["latent_flash"] = sites(
     lambda qn, qp, kn, kp, v, s: latent_flash(qn, qp, kn, kp, v, s, 8),
     sds((8, 1024, 128)), sds((8, 1024, 64)), sds((8, 35840, 128)),
     sds((1, 35840, 64)), sds((8, 35840, 128)), sds((1,), jnp.int32))
+# the extends behind a cached context over gathered head-major views: the
+# rag cell's full layer (128 / 8 heads, a bucket of 512 over the 19,456-row
+# view) and a sliding layer's window view (window 4,096); the hybrid cell's
+# smallest bucket at 30 MHA heads; the reasoning cell's view, which 1,024
+# does not divide, as it is; a verify's five queries (padded to a tile)
+from paddle_tpu.kernels.paged_attention import extend_flash
+ext = lambda Hq, Hkv, T, L, w=None: sites(
+    lambda q, k, v, s, f: extend_flash(q, k, v, s, w, f if w else None),
+    sds((1, Hq, T, 128)), sds((1, Hkv, L, 128)), sds((1, Hkv, L, 128)),
+    sds((1,), jnp.int32), sds((1,), jnp.int32))
+out["extend_flash"] = [ext(128, 8, 512, 19456), ext(128, 8, 512, 5120, 4096),
+                       ext(30, 30, 16, 4096), ext(32, 2, 128, 4352),
+                       ext(16, 16, 5, 2048)]
 f32 = sds((64, 256), jnp.float32)
 out["prim"] = {**sites(primitive.elementwise_kernel(lambda a, b: a + 2 * b), f32, f32),
                **sites(primitive.row_reduce_kernel(lambda acc, t: acc + t.sum(-1), 0.0), f32)}
@@ -273,6 +286,9 @@ def test_kernels_and_train_step_compile_for_a_tpu_topology():
     assert out["gmm_held"] == {"moe_grouped_matmul": 1}
     assert out["latent_decode"] == {"latent_paged_decode": 1}
     assert out["latent_flash"] == {"latent_flash": 1}
+    assert out["extend_flash"] == [
+        {"extend_flash": 1}, {"window_extend_flash": 1}] \
+        + [{"extend_flash": 1}] * 3
     assert out["prim"] == {"prim_elementwise": 1, "prim_row_reduce": 1}
     for key in ("step_1", "step_dp2mp2"):  # the mesh must not lose a kernel
         assert set(out[key]) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",

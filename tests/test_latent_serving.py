@@ -713,13 +713,13 @@ class TestLatentFlash:
         """Against a count over every (query, key) pair: the key blocks a
         query block walks are those of which some query of it sees some
         key, and they are the leading ones."""
-        from paddle_tpu.kernels.latent_attention import _last_block
+        from paddle_tpu.kernels.flash_attention import last_key_block
 
         bq, bk = blocks
         sees = (start + np.arange(T))[:, None] >= np.arange(L)[None, :]
         some = sees.reshape(T // bq, bq, L // bk, bk).any((1, 3))
-        last = np.asarray(_last_block(start, jnp.arange(T // bq), bq, bk,
-                                      L // bk))
+        last = np.asarray(last_key_block(start, jnp.arange(T // bq), bq, bk,
+                                         L // bk))
         np.testing.assert_array_equal(
             some, np.arange(L // bk)[None, :] < last[:, None])
 

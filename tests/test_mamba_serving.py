@@ -620,7 +620,10 @@ def test_engine_builds_over_a_first_pool_of_some_layers(model):
 #: tests/test_window_serving.py and tests/test_hybrid_serving.py, which this
 #: PR leaves as they were.
 #: ``window/decode/pallas`` was recorded again at PR 47 (the paged-decode
-#: kernel's page walk; 86fdb634458456b2 before it).
+#: kernel's page walk; 86fdb634458456b2 before it), ``window/extend/oracle``
+#: at PR 48 (a head-major extend's attention is ``paged_extend_attend``, whose
+#: oracle is ``extend_attend`` over the gathered view, where it was
+#: ``decoder.attend``; b5e0fbca39d3f079 before it).
 WINDOW = dict(layer_types=("sliding",) * 3 + ("dense",), sliding_window=16,
               kv_layout="head", norm="layer_nobias",
               norm_placement="parallel", position="rope_gptj",
@@ -630,7 +633,7 @@ WINDOW = dict(layer_types=("sliding",) * 3 + ("dense",), sliding_window=16,
 PARENT = {
     "window/params": (50, 4142470600),
     "window/prefill/oracle": (9, "b71831046b432b1b"),
-    "window/extend/oracle": (10, "b5e0fbca39d3f079"),
+    "window/extend/oracle": (10, "c58614a66b366087"),
     "window/decode/oracle": (14, "b81bda4484256679"),
     "window/decode/pallas": (14, "451242c0bd563bf7"),
 }

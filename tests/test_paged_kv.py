@@ -528,6 +528,38 @@ class TestPagedEngine:
         # go on running the kernel outside the context
         assert eng.generate(prompts, sp) == oracle
 
+    def test_interpret_kernel_extend_matches_oracle_engine(self):
+        """A prefix hit's suffix goes through the extend program: with 4
+        MHA heads (one query head a K/V head) its attention is
+        ``extend_flash`` in the ``pallas`` tier, the einsum reference over
+        the gathered view in the ``oracle`` tier, and the served tokens are
+        the same. The decode program names the decode kernel alone, the
+        prefill program no paged kernel."""
+        from paddle_tpu.kernels.mesh import traced_kernels
+
+        paddle.seed(0)
+        m = _tiny()
+        warm = [int(t) for t in _prompt(1, 20, seed=5)[0]]
+        prompts = [warm, warm[:16] + [7, 9, 11, 2, 4]]
+        sp = SamplingParams(max_new_tokens=6)
+        cfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=8,
+                           prefix_cache=True)
+        outs, names = {}, {}
+        for impl in ("oracle", "pallas"):
+            with use_paged_attention_impl(impl):
+                eng = Engine(m, cfg)
+                outs[impl] = [eng.generate([p], sp)[0] for p in prompts]
+                assert [k for k in eng._exe if k[0] == "extend"]
+                for kind, a in (("extend", (8,)), ("decode", ()),
+                                ("prefill", (32,))):
+                    fn, args = getattr(eng, kind + "_program")(*a)
+                    names[impl, kind] = set(traced_kernels(fn, *args))
+        assert outs["pallas"] == outs["oracle"]
+        assert names["pallas", "extend"] == {"extend_flash"}
+        assert names["pallas", "decode"] == {"paged_decode"}
+        assert "extend_flash" not in names["pallas", "prefill"]
+        assert not any(names["oracle", k] for k in ("extend", "decode"))
+
     def test_paged_decode_compiles_once(self, telemetry):
         """The page table is runtime data: admissions, finishes, and table
         rewrites between steps never change the decode signature — ONE

@@ -363,6 +363,48 @@ def test_no_window_is_the_kernel_it_was():
     assert "window_decode" in text(16) and "paged_decode" not in text(16)
 
 
+def _kernels_of(eng, kind, *a):
+    """Names of the Pallas calls in a program of ``eng`` as traced (on the
+    CPU a kernel lowers to plain HLO, which ``kernel_sites`` cannot read)."""
+    from paddle_tpu.kernels.mesh import traced_kernels
+
+    fn, args = getattr(eng, kind + "_program")(*a)
+    return set(traced_kernels(fn, *args))
+
+
+def test_extends_behind_a_prefix_hit_serve_the_same_tokens_in_both_tiers(
+        model):
+    """Three prompts on one document, one after the other: the third
+    resumes at the document's end and its suffix goes through an extend
+    program, whose sliding layers attend a view of the window and its full
+    layer the whole table's. In the ``pallas`` tier those are
+    ``window_extend_flash`` and ``extend_flash`` and nothing else of the
+    extend is a kernel's; the decode program names the decode kernels and
+    the prefill program none. Greedy tokens are the oracle tier's."""
+    doc = _ids(44, seed=100)
+    prompts = [doc + _ids(n, seed=n) for n in (5, 9, 13)]
+    outs = {}
+    for impl in ("oracle", "pallas"):
+        eng = _engine(model, impl)
+        outs[impl] = [_generate(eng, [p], 8, impl)[0] for p in prompts]
+        assert [k for k in eng._exe if k[0] == "extend"], "no extend ran"
+        with use_paged_attention_impl(impl):
+            ext = _kernels_of(eng, "extend", 16)
+            dec = _kernels_of(eng, "decode")
+            pre = _kernels_of(eng, "prefill", 64)
+        attends = {"extend_flash", "window_extend_flash", "paged_decode",
+                   "window_decode"}
+        if impl == "oracle":
+            assert not attends & (ext | dec | pre)
+            continue
+        assert attends & ext == {"extend_flash", "window_extend_flash"}
+        assert attends & dec == {"paged_decode", "window_decode"}
+        assert not attends & pre
+    assert outs["pallas"] == outs["oracle"]
+    for p, o in zip(prompts, outs["pallas"]):
+        assert _greedy_gap(model, p, o) < TOL
+
+
 # -------------------------------------- the cache's groups, the trie's depths
 
 def test_cache_groups_and_their_tables(model):
@@ -616,13 +658,16 @@ OLDER = {
 #: The six decode programs were recorded again at PR 43 (the ``host_tokens``
 #: operand and its select: one argument more).
 #: ``hybrid/decode/pallas`` was recorded again at PR 47 (the paged-decode
-#: kernel's page walk; 534176be6752a5b1 before it).
+#: kernel's page walk; 534176be6752a5b1 before it), ``hybrid/extend/oracle``
+#: at PR 48 (a head-major extend's attention is ``paged_extend_attend``, whose
+#: oracle is ``extend_attend`` over the gathered view, where it was
+#: ``decoder.attend``; 0650b351e53e3636 before it).
 PARENTS = {
     "hybrid/params": (28, 4035424780),
     "share/params": (39, 2590006625),
     "latent/params": (34, 1586234722),
     "hybrid/prefill/oracle": (9, "b0e020062e23733c"),
-    "hybrid/extend/oracle": (10, "0650b351e53e3636"),
+    "hybrid/extend/oracle": (10, "0ef1fa47175c5fb2"),
     "hybrid/decode/oracle": (13, "68db039800b0e874"),
     "hybrid/decode/pallas": (13, "4a5157101a4727ff"),
     "share/prefill/oracle": (9, "8b4e2f98c16d6298"),
