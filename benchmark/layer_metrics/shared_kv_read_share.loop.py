@@ -1,0 +1,4 @@
+"""Per-layer metric ``shared_kv_read_share.loop`` (layer, unit, source, moves and cells: its
+entry in BENCHMARK.json). Returns None where it finds nothing to read."""
+
+from harness.readers_phi4 import shared_kv_read_share as read  # noqa: F401

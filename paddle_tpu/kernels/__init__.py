@@ -11,10 +11,12 @@ Pallas/Mosaic. Every kernel here:
 - is wired behind the op-registry variant seam (ops use it when
   FLAGS_use_pallas_kernels and the platform is TPU); the serving kernels
   (paged_attention, latent_attention, sparse_attention, gated_delta,
-  mamba2) are
+  mamba2, mamba1) are
   picked by ``tier.default_paged_impl`` instead, which their own entries
   (``paged_decode_attend``, ``paged_extend_attend``,
-  ``latent_decode_attend``, ``gdn_step``, ``mamba2_step``) ask; the flash
+  ``latent_decode_attend``, ``gdn_step``, ``mamba2_step``, ``mamba1_step``,
+  ``mamba1_scan``; a differential layer's ``diff_decode_attend`` /
+  ``diff_extend_attend`` go through the first two) ask; the flash
   kernels behind a cached context (``latent_attention.latent_flash``,
   ``paged_attention.extend_flash``) share one grid step
   (``flash_attention.online_softmax_step``),

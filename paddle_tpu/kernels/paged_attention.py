@@ -258,7 +258,8 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
 
 
-def paged_attention(q, k_pool, v_pool, page_table, positions, window=None):
+def paged_attention(q, k_pool, v_pool, page_table, positions, window=None,
+                    scale=None):
     """Ragged paged-decode attention over block-paged KV pools.
 
     q            ``[B, H_q, 1, D]`` — one query token per slot
@@ -273,6 +274,10 @@ def paged_attention(q, k_pool, v_pool, page_table, positions, window=None):
                  freed those pages). A kernel of its own by NAME
                  (``window_decode``; ``paged_decode`` is the full one's)
 
+    scale        the softmax scale; None: ``D^-1/2`` (a differential layer's
+                 widened queries, ``diff_widen``, keep the scale of their
+                 own head's width)
+
     Returns ``[B, H_q, 1, D]`` in v's dtype — drop-in for
     ``decode_attend(q, dense_k, dense_v, positions)`` when the dense caches
     hold the same bytes the table maps (tests pin this parity).
@@ -285,7 +290,7 @@ def paged_attention(q, k_pool, v_pool, page_table, positions, window=None):
     if T != 1:
         raise ValueError(f"paged_attention decodes one token per slot, got T={T}")
     Hkv = k_pool.shape[1]
-    qs = (q[:, :, 0, :] * jnp.asarray(1.0 / np.sqrt(D), q.dtype))  # [B, Hq, D]
+    qs = q[:, :, 0, :] * _scale(scale, q)                       # [B, Hq, D]
     table = page_table.astype(jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     if pos.ndim == 0:
@@ -556,7 +561,16 @@ def _expand_kv_heads(t, rep: int):
         B, Hkv * rep, S, D)
 
 
-def decode_attend(q, k_cache, v_cache, positions, window=None):
+def _scale(scale, q):
+    """The softmax scale as a scalar of q's dtype: ``scale``, or ``D^-1/2``.
+    (np.sqrt returns a STRONG f64 scalar, and under x64 ``q * f64`` upcasts
+    the whole tensor to f64 before the cast back: found by the analysis
+    dtype-f64 rule on serving_decode.)"""
+    return jnp.asarray(1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
+                       q.dtype)
+
+
+def decode_attend(q, k_cache, v_cache, positions, window=None, scale=None):
     """Single-position cached attention: q ``[B, H_q, T, D]`` (T=1 in
     decode) against the full static cache ``[B, H_kv, S_max, D]``, masked to
     the valid prefix ``key_pos <= positions`` (scalar or per-row ``[B]``),
@@ -566,14 +580,10 @@ def decode_attend(q, k_cache, v_cache, positions, window=None):
     Matches _sdpa_ref numerics: q pre-scaled in its own dtype, f32 scores,
     f32 softmax, output cast back to v's dtype.
     """
-    D = q.shape[-1]
     rep = q.shape[1] // k_cache.shape[1]
     k = _expand_kv_heads(k_cache, rep)
     v = _expand_kv_heads(v_cache, rep)
-    # scale as a q-dtype scalar: np.sqrt returns a STRONG f64 scalar, and
-    # under x64 `q * f64` upcasts the whole tensor to f64 before the cast
-    # back (found by the analysis dtype-f64 rule on serving_decode)
-    qf = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+    qf = q * _scale(scale, q)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, k,
                    preferred_element_type=jnp.float32)
     pos = jnp.asarray(positions)
@@ -590,7 +600,8 @@ def decode_attend(q, k_cache, v_cache, positions, window=None):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def extend_attend(q, k_cache, v_cache, positions, window=None, first=None):
+def extend_attend(q, k_cache, v_cache, positions, window=None, first=None,
+                  scale=None):
     """Multi-query cached attention: q ``[B, H_q, T, D]`` where query ``t``
     of row ``b`` sits at absolute position ``positions[b] + t`` and may
     attend to ``key_pos <= positions[b] + t``, with ``window`` to those
@@ -601,11 +612,10 @@ def extend_attend(q, k_cache, v_cache, positions, window=None, first=None):
     or ``first[b] + s`` where ``first [B]`` is given (a view of a slot's
     window). Same _sdpa_ref numerics: q pre-scaled in its own dtype, f32
     scores, -1e30 mask, f32 softmax."""
-    D = q.shape[-1]
     rep = q.shape[1] // k_cache.shape[1]
     k = _expand_kv_heads(k_cache, rep)
     v = _expand_kv_heads(v_cache, rep)
-    qf = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+    qf = q * _scale(scale, q)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, k,
                    preferred_element_type=jnp.float32)
     T = q.shape[2]
@@ -622,7 +632,7 @@ def extend_attend(q, k_cache, v_cache, positions, window=None, first=None):
 
 
 def paged_decode_attend(q, k_pool, v_pool, page_table, positions,
-                        window=None):
+                        window=None, scale=None):
     """Single-position cached attention over block-paged pools — the paged
     twin of ``decode_attend``, in the tier ``tier.default_paged_impl`` says
     (``window``: a sliding layer's, both tiers the same lower bound).
@@ -636,12 +646,13 @@ def paged_decode_attend(q, k_pool, v_pool, page_table, positions,
     if default_paged_impl() == "oracle":
         k = paged_gather(k_pool, page_table)
         v = paged_gather(v_pool, page_table)
-        return decode_attend(q, k, v, positions, window)
-    return paged_attention(q, k_pool, v_pool, page_table, positions, window)
+        return decode_attend(q, k, v, positions, window, scale)
+    return paged_attention(q, k_pool, v_pool, page_table, positions, window,
+                           scale)
 
 
 def paged_extend_attend(q, k_pool, v_pool, page_table, positions,
-                        window=None, first=None):
+                        window=None, first=None, scale=None):
     """Multi-query cached attention over block-paged pools — the paged twin
     of ``extend_attend`` (an admission's new tokens behind a prefix hit, a
     speculative verify), in the tier ``tier.default_paged_impl`` says.
@@ -658,7 +669,7 @@ def paged_extend_attend(q, k_pool, v_pool, page_table, positions,
     if default_paged_impl() == "oracle":
         k = paged_gather(k_pool, page_table)
         v = paged_gather(v_pool, page_table)
-        return extend_attend(q, k, v, positions, window, first)
+        return extend_attend(q, k, v, positions, window, first, scale)
     ps = k_pool.shape[2]
     L = page_table.shape[1] * ps
     unit = _EXTEND_KEYS if L > _EXTEND_KEYS else LANES
@@ -666,7 +677,60 @@ def paged_extend_attend(q, k_pool, v_pool, page_table, positions,
     if more:
         page_table = jnp.pad(page_table, ((0, 0), (0, more)),
                              constant_values=PAGE_SENTINEL)
-    qs = q * jnp.asarray(1.0 / np.sqrt(q.shape[-1]), q.dtype)
+    qs = q * _scale(scale, q)
     return extend_flash(qs, paged_gather(k_pool, page_table),
                         paged_gather(v_pool, page_table), positions, window,
                         first)
+
+
+# ---------------------------------------------------------------------------
+# Differential attention (arXiv:2410.05258) over PAIR-head pools: K pair r is
+# ``[k_2r | k_2r+1]`` and V pair r ``[v_2r | v_2r+1]``, 2 D lanes a pair, so a
+# pool of H_kv / 2 pair-heads is read by the kernels above as they are
+# ---------------------------------------------------------------------------
+
+
+def diff_widen(q):
+    """Queries ``[B, H_q, T, D]`` as rows over a K PAIR's ``2 D`` lanes: head
+    ``2 p`` keeps its lanes and is zero over the pair's second key, head ``2
+    p + 1`` the other way round, so ``q_wide . [k_1 | k_2]`` is the head's
+    own ``q . k`` and ``softmax(.) [v_1 | v_2]`` its attention over the
+    pair's whole value: ``[B, H_q, T, 2 D]``. The softmax scale stays
+    ``D^-1/2`` (the entries' ``scale``)."""
+    B, Hq, T, D = q.shape
+    half = jnp.eye(2, dtype=q.dtype)[None, None, :, None, :, None]
+    return (q.reshape(B, Hq // 2, 2, T, 1, D) * half).reshape(B, Hq, T, 2 * D)
+
+
+def diff_combine(o, lam, gamma, lam_init: float, eps: float):
+    """What a pair of query heads gives: ``o [B, H_q, T, 2 D]`` (head ``2 p``
+    then ``2 p + 1`` of pair ``p``), ``lam`` the layer's float32 scalar:
+    ``RMSNorm_2D(o_1 - lam o_2; gamma) (1 - lam_init)``, ``[B, H_q / 2, T,
+    2 D]`` float32 (the caller casts)."""
+    B, Hq, T, W = o.shape
+    o = o.astype(jnp.float32).reshape(B, Hq // 2, 2, T, W)
+    d = o[:, :, 0] - lam * o[:, :, 1]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True) + eps)
+    return d * gamma.astype(jnp.float32) * jnp.float32(1.0 - lam_init)
+
+
+def diff_decode_attend(q, k_pool, v_pool, page_table, positions, window=None):
+    """One query a slot of a differential layer over its PAIR-head pools
+    (``[P, H_kv / 2, page, 2 D]``): ``q [B, H_q, 1, D]`` not yet scaled, ``[B,
+    H_q, 1, 2 D]`` out, each head's attention over its pair's whole value
+    (``diff_combine`` takes the difference). Kernel (``paged_decode`` /
+    ``window_decode``) or oracle as ``tier.default_paged_impl`` says."""
+    return paged_decode_attend(diff_widen(q), k_pool, v_pool, page_table,
+                               positions, window,
+                               scale=float(q.shape[-1]) ** -0.5)
+
+
+def diff_extend_attend(q, k_pool, v_pool, page_table, positions, window=None,
+                       first=None):
+    """``T`` queries a slot of a differential layer behind its cached
+    context, over the pair-head pools (``paged_extend_attend``'s operands):
+    ``[B, H_q, T, 2 D]`` out. Kernel (``extend_flash`` /
+    ``window_extend_flash``) or oracle likewise."""
+    return paged_extend_attend(diff_widen(q), k_pool, v_pool, page_table,
+                               positions, window, first,
+                               scale=float(q.shape[-1]) ** -0.5)

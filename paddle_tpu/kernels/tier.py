@@ -1,6 +1,6 @@
 """Mosaic kernel or jnp reference: the ONE definition of which body a
 serving trace bakes in. Every module that has both (``paged_attention``,
-``latent_attention``, ``gated_delta``, ``mamba2``, and the model's sparse decode and
+``latent_attention``, ``gated_delta``, ``mamba2``, ``mamba1``, and the model's sparse decode and
 latent layers) asks here."""
 
 import contextlib

@@ -5,8 +5,9 @@
 ``post`` | ``parallel``), positions (``rope`` | ``rope_gptj`` |
 ``rope_yarn`` | ``none``; ``position_by_kind`` where layers of one kind
 have others), the token mixer (``dense`` | ``sliding`` | ``indexed_sparse``
-| ``gated_delta`` | ``latent`` | ``mamba2`` | ``none``; one kind for all
-layers, or ``layer_types``, one a layer), FFN (``swiglu`` | ``moe_swiglu``
+| ``gated_delta`` | ``latent`` | ``mamba2`` | ``mamba1`` | ``gmu`` |
+``cross`` | ``none``; one kind for all layers, or ``layer_types``, one a
+layer; ``layer_sources`` where a layer reads an earlier one), FFN (``swiglu`` | ``moe_swiglu``
 | ``relu2`` | ``moe_relu2`` | ``none``; the first ``first_dense_layers``
 layers a dense swiglu of a width of their own, or ``ffn_types``, one kind
 a layer), router (``softmax_topk`` | ``sigmoid_topk`` |
@@ -156,6 +157,53 @@ of two matrices, ``FFN(h) = relu(h W_up)^2 W_down``; the routed body
 expert may have a width of its own (``shared_intermediate_size``). With
 ``n_group`` 1 ``sigmoid_group_topk`` is a plain top-k of ``s + bias``.
 
+A DECODER-HYBRID-DECODER (SambaY, arXiv:2507.06607; Phi-4-mini-flash) is
+three more kinds, a flag and one per-layer field. ``mamba1`` (Mamba-1,
+arXiv:2312.00752; inner width ``E``, state ``N``, step rank ``R``,
+convolution ``K``), with ``h`` the layer's input::
+
+    [x | z] = h W_in                              widths E | E
+    x = silu(conv(x) + b_conv)                    causal, depthwise, width K
+    [delta | B | C] = x W_x                       widths R | N | N
+    dt = softplus(delta W_dt + dt_bias)           float32, a step a CHANNEL
+    S[n, e] <- exp(dt[e] A[n, e]) S[n, e] + dt[e] x[e] B[n],  A = -exp(A_log)
+                                  ONE decay a channel AND state lane: S in
+                                  R^{N x E} float32, kept N-major
+    y[e] = sum_n S[n, e] C[n] + D[e] x[e]         (the state AFTER this token)
+    out = (y * silu(z)) W_out;   m = y            (the memory: BEFORE the gate)
+
+It speaks ``gated_delta``'s cache protocol as ``mamba2`` does; both its forms
+walk the tokens (``kernels/mamba1``: no chunked form in matrix products).
+``gmu`` (a gated memory unit): ``out = (silu(h W1) * m) W2`` with ``m`` the
+memory of the SAME token that the ``mamba1`` layer ``layer_sources[l]`` left;
+no state, no cache. ``differential`` (arXiv:2410.05258) on every ``dense`` /
+``sliding`` / ``cross`` layer: query heads 2p, 2p + 1 form pair p, K heads
+2r, 2r + 1 K pair r with ``v_r = [v_2r | v_2r+1]``; pair p reads pair ``r = p
+// (H_q / H_kv)``::
+
+    a_{p,j} = softmax_s(q_{p,j} . k_{r,j,s} D^-1/2)   over the keys the layer
+                                  sees (sliding: t - window < s <= t)
+    o_p = sum_s (a_{p,1,s} - lambda a_{p,2,s}) v_{r,s}
+    lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init,
+    lambda_init = 0.8 - 0.6 exp(-0.3 l)           l the 0-based layer index
+    o_p = RMSNorm_2D(o_p; gamma, eps) (1 - lambda_init);  out = [o_p] Wo + bo
+
+(``attn_bias``: q, k, v and the output carry a bias.) The pools hold the
+PAIRS (``[pages, H_kv / 2, page, 2 D]``), and the paged attends read them as
+they are with the queries widened over a pair's lanes
+(``kernels/paged_attention.diff_widen``). ``cross``: a differential layer
+with its own queries and NO K/V projection, over the K and V of the ``dense``
+layer ``layer_sources[l]``; it declares no pool: ``cache_pools()`` names that
+layer's pool once, with every layer that READS it as a sixth entry, and
+``_forward`` carries the written pool (and ``m``) from layer to layer. Where
+every layer behind that dense layer is a ``gmu`` or a ``cross`` layer
+(``cut_layer``), an ADMISSION's prefill or extend (one that is told
+``lengths``) runs the layers before it and its K/V projection on all tokens,
+its queries and every layer behind it on the LAST REAL token alone: nothing
+else of them is ever read (they write no cache), an extend's matmul work is
+halved and no ``[T, V]`` logits exist. A forward that is told no ``lengths``
+(``forward``, a test's) runs every layer on every token.
+
 It speaks the serving engine's whole protocol (serving/README.md):
 ``cache_pools()`` declares the paged pools of the layers that keep keys (K
 and V token-major, one
@@ -164,7 +212,7 @@ sparse read, or head-major ``[pages, H_kv, page, D]`` for the paged-decode
 kernel, ``kv_layout``; the indexer's keys, in whole 128-lane rows; a latent
 layer's one row a token),
 ``state_pools()`` the slot-indexed state of the layers that keep a
-recurrence (``gated_delta``, ``mamba2``); ``prefill_with_cache`` /
+recurrence (``gated_delta``, ``mamba2``, ``mamba1``); ``prefill_with_cache`` /
 ``extend_step`` / ``decode_step`` are pure functions of (parameters, pools,
 page table).
 What they do to a pool is below this module, in ``kernels/``: the write and
@@ -202,7 +250,9 @@ from jax import lax
 from ..core.tensor import Parameter, Tensor
 from ..kernels import latent_attention as _latent
 from ..kernels import pools as _pools, tier as _tier
-from ..kernels.paged_attention import (paged_decode_attend,
+from ..kernels.paged_attention import (diff_combine, diff_decode_attend,
+                                       diff_extend_attend, diff_widen,
+                                       paged_decode_attend,
                                        paged_extend_attend)
 from ..nn.layer.layers import Layer
 
@@ -251,6 +301,20 @@ class DecoderConfig:
     kv_layout: str = "token"
     # a dense layer's output gated element-wise by sigmoid(h Wg) before Wo
     attn_output_gate: bool = False
+    # differential attention (arXiv:2410.05258) in every dense / sliding /
+    # cross layer: query heads 2p, 2p + 1 form a pair that reads K/V pair
+    # p // (num_heads / num_kv_heads) and gives softmax(q1 k1) - lambda
+    # softmax(q2 k2) over the pair's two value heads side by side, then an
+    # RMSNorm over those 2 D lanes; the pools hold PAIRS (num_kv_heads / 2
+    # heads of 2 D lanes). ``attn_bias``: q, k, v and the output projection
+    # carry a bias
+    differential: bool = False
+    attn_bias: bool = False
+    # per layer, the EARLIER layer whose output a layer reads besides its
+    # own input: a "gmu" layer the scan output (memory) of a "mamba1" layer,
+    # a "cross" layer the K/V pool of a "dense" layer (it has no K/V
+    # projection and declares no pool); None for every other layer
+    layer_sources: Optional[Tuple[Optional[int], ...]] = None
     index_heads: int = 4
     index_head_dim: int = 8
     index_topk: int = 16
@@ -276,6 +340,12 @@ class DecoderConfig:
     ssm_state: int = 16
     ssm_conv_kernel: int = 4
     ssm_chunk: int = 128
+    # a mamba1 layer's widths (arXiv:2312.00752; its state's width and
+    # convolution are ``ssm_state`` / ``ssm_conv_kernel``): the inner width
+    # (None: 2 x hidden) and the rank of the step's projection (None:
+    # ceil(hidden / 16))
+    ssm1_inner: Optional[int] = None
+    ssm1_dt_rank: Optional[int] = None
     # a latent layer's widths (arXiv:2412.19437 section 2.1): the ranks of
     # the query's and the keys-and-values' latents, a head's query/key lanes
     # without and with positions, a head's value lanes
@@ -384,6 +454,7 @@ class DecoderConfig:
         if not 0 <= self.first_dense_layers <= self.num_layers:
             raise ValueError(f"first_dense_layers {self.first_dense_layers} "
                              f"of {self.num_layers} layers")
+        self._check_sources()
         if self.experts_held is not None:
             n, first = self.experts_held = tuple(self.experts_held)
             if not (n >= 1 and first >= 0 and first + n <= self.num_experts):
@@ -391,10 +462,73 @@ class DecoderConfig:
                     f"experts_held {self.experts_held}: want (how many, "
                     f"from which index) within {self.num_experts} experts")
 
+    def _check_sources(self):
+        """``layer_sources`` against the kinds: a gmu layer names a mamba1
+        layer before it, a cross layer a dense one (all cross layers the
+        same), no other layer names any; what ``differential`` asks of the
+        attention layers."""
+        kinds, L = self.kinds, self.num_layers
+        if self.layer_sources is not None:
+            self.layer_sources = tuple(self.layer_sources)
+        src = self.sources
+        if len(src) != L:
+            raise ValueError(f"layer_sources names {len(src)} layers, "
+                             f"num_layers is {L}")
+        wants = {"gmu": "mamba1", "cross": "dense"}
+        for l, (kind, k) in enumerate(zip(kinds, src)):
+            if kind not in wants:
+                if k is not None:
+                    raise ValueError(f"layer {l} ({kind}) reads no other "
+                                     f"layer, layer_sources names {k}")
+            elif not (isinstance(k, int) and 0 <= k < l
+                      and kinds[k] == wants[kind]):
+                raise ValueError(
+                    f"layer {l} ({kind}) wants an EARLIER {wants[kind]} "
+                    f"layer in layer_sources, got {k!r}")
+        cross = {k for kind, k in zip(kinds, src) if kind == "cross"}
+        if len(cross) > 1:
+            raise ValueError(f"cross layers read ONE layer's pool, not "
+                             f"{sorted(cross)}")
+        attn = {"dense", "sliding", "cross"} & set(kinds)
+        if (cross or self.attn_bias) and not self.differential:
+            raise ValueError("cross layers and attn_bias are the "
+                             "differential layers'")
+        if cross and self.norm_placement != "pre":
+            raise ValueError("cross layers want norm_placement 'pre'")
+        if self.differential and attn and not (
+                self.kv_layout == "head" and self.num_kv_heads % 2 == 0
+                and not self.qk_norm and not self.attn_output_gate
+                and "indexed_sparse" not in kinds
+                and all(_position_of(self, k) is POSITIONS["none"]
+                        for k in attn)):
+            raise ValueError(
+                "differential attention wants kv_layout 'head', an even "
+                "num_kv_heads, no qk_norm, no output gate, no positions "
+                "and no indexed_sparse layer")
+
     @property
     def kinds(self) -> Tuple[str, ...]:
         """The token mixer's kind, one a layer."""
         return self.layer_types or (self.attention,) * self.num_layers
+
+    @property
+    def sources(self) -> Tuple[Optional[int], ...]:
+        """The earlier layer each layer reads (``layer_sources``), None
+        where it reads none."""
+        return self.layer_sources or (None,) * self.num_layers
+
+    @property
+    def cut_layer(self) -> Optional[int]:
+        """The layer from whose QUERIES on an admission's prefill or extend
+        runs on the last real token alone: the dense layer the cross layers
+        read, where every layer behind it keeps neither pool nor state (gmu
+        and cross layers: nothing else of them is ever read). None in a
+        model without cross layers."""
+        cross = [k for kind, k in zip(self.kinds, self.sources)
+                 if kind == "cross"]
+        if not cross or set(self.kinds[cross[0] + 1:]) - {"gmu", "cross"}:
+            return None
+        return cross[0]
 
     @property
     def ffns(self) -> Tuple[Tuple[str, int], ...]:
@@ -550,12 +684,22 @@ def _mm(x, w):
     return jnp.dot(x, w)
 
 
-def _attn_shapes(cfg, pre, sparse):
+def _attn_shapes(cfg, pre, sparse, cross=False):
     H, D = cfg.hidden_size, cfg.head_dim
     s = {pre + ".wq": (H, cfg.num_heads * D),
          pre + ".wk": (H, cfg.num_kv_heads * D),
          pre + ".wv": (H, cfg.num_kv_heads * D),
          pre + ".wo": (cfg.num_heads * D, H)}
+    if cfg.attn_bias:
+        s.update({pre + ".bq": (cfg.num_heads * D,),
+                  pre + ".bk": (cfg.num_kv_heads * D,),
+                  pre + ".bv": (cfg.num_kv_heads * D,), pre + ".bo": (H,)})
+    if cross:       # a cross layer has no K/V projection
+        for leaf in (".wk", ".wv", ".bk", ".bv"):
+            s.pop(pre + leaf, None)
+    if cfg.differential:
+        s.update({pre + f".lambda_{a}{i}": (D,) for a in "qk" for i in "12"})
+        s[pre + ".o_norm.weight"] = (2 * D,)
     if cfg.qk_norm:
         full = cfg.qk_norm == "full"
         s[pre + ".q_norm.weight"] = (cfg.num_heads * D if full else D,)
@@ -573,7 +717,7 @@ def _attn_shapes(cfg, pre, sparse):
 
 
 def attend(cfg, q, k_view, v_view, qpos, index=None, window=None,
-           first=None, band=False, turn=None):
+           first=None, band=False, turn=None, scale=None):
     """Queries ``q [B, T, Hq, D]`` at positions ``qpos [B, T]`` against key
     and value views ``[B, L, Hkv, D]`` (view position = sequence position,
     or ``first[b]`` + view position where ``first [B]`` is given: a view of
@@ -596,7 +740,7 @@ def attend(cfg, q, k_view, v_view, qpos, index=None, window=None,
     L, Hkv = k_view.shape[1], k_view.shape[2]
     rep = Hq // Hkv
     kpos = jnp.arange(L, dtype=jnp.int32)
-    scale = jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+    scale = jnp.asarray(1.0 / np.sqrt(D) if scale is None else scale, q.dtype)
     qs = q * scale if turn is None else q
 
     def chunk(args, k_view=k_view, v_view=v_view, first=first):
@@ -685,7 +829,8 @@ def _indexer(cfg, p, pre, h, pos):
 
 
 def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
-              lengths=None, cuts=None, sparse=False, kind="dense"):
+              lengths=None, cuts=None, sparse=False, kind="dense", layer=None,
+              carry=None):
     """The attention part of a block over ``h [B, T, hidden]`` whose
     tokens sit at ``start[b] .. start[b] + T - 1``. A layer of ``kind``
     "sliding" sees the last ``cfg.sliding_window`` keys alone: its prefill
@@ -701,6 +846,9 @@ def attention(cfg, p, pre, h, start, cache=None, flash_ok=False,
     pools with one. ``lengths`` and ``cuts`` are not its concern: a padded
     token's keys lie behind every real query, and its keys are a function of
     position."""
+    if cfg.differential:
+        return differential_attention(cfg, p, pre, h, start, cache, kind,
+                                      layer, carry)
     B, T, _ = h.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -838,18 +986,211 @@ def _kv_pools(cfg, sparse, window=False):
     """[(name, heads, width)] of an attention layer's paged pools; a
     sliding layer's are named apart (they stand in a page group of their
     own, ``DecoderLM.cache_pools``)."""
+    # (a differential layer keeps PAIRS: half the heads, twice the lanes)
+    heads, width = (cfg.num_kv_heads // 2, 2 * cfg.head_dim) \
+        if cfg.differential else (cfg.num_kv_heads, cfg.head_dim)
     if window:
-        return [("k_window", cfg.num_kv_heads, cfg.head_dim),
-                ("v_window", cfg.num_kv_heads, cfg.head_dim)]
+        return [("k_window", heads, width), ("v_window", heads, width)]
     if cfg.kv_layout == "head":
-        pools = [("k", cfg.num_kv_heads, cfg.head_dim),
-                 ("v", cfg.num_kv_heads, cfg.head_dim)]
+        pools = [("k", heads, width), ("v", heads, width)]
     else:
         kv = cfg.num_kv_heads * cfg.head_dim
         pools = [("k", 1, kv), ("v", 1, kv)]
     if sparse:
         pools.append(("index_k", 1, index_pool_width(cfg)))
     return pools
+
+
+# ------------------------------------------- differential attention layers
+
+def diff_lambda_init(layer: int) -> float:
+    """A differential layer's ``lambda_init`` by its 0-based index."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * layer))
+
+
+def _last_rows(x, idx):
+    """Row ``idx[b]`` of ``x [B, T, ...]``, kept as ``[B, 1, ...]``."""
+    return jnp.take_along_axis(
+        x, idx.reshape((-1,) + (1,) * (x.ndim - 1)), axis=1)
+
+
+def differential_attention(cfg, p, pre, h, start, cache, kind, layer, carry):
+    """A differential attention layer (the module's docstring has the
+    equations) of ``kind`` "dense" (full), "sliding" (the window) or "cross"
+    (its own queries, the K/V of layer ``cfg.sources[layer]``) over ``h [B,
+    T, hidden]``. The pools hold PAIRS of K/V heads (``[pages, H_kv / 2,
+    page, 2 D]``), and every read goes through the paged attends as they are
+    with the queries widened over a pair's lanes
+    (``kernels/paged_attention.diff_widen``).
+
+    ``carry`` is what ``_forward`` hands from layer to layer: ``qpos [B,
+    Tq]``, the positions of the rows the residual stream still holds;
+    ``last [B]`` (an admission's prefill or extend, at ``cfg.cut_layer``):
+    the row of the last real token, from whose queries on the batch is cut
+    to that row (this layer still computes K and V of ALL rows; it hands
+    back ``[B, 1, hidden]``, and ``block`` cuts the stream); ``("kv",
+    layer)``: the (K, V, table) a source layer wrote, which its cross layers
+    read (``table`` None in a prefill: the keys just computed)."""
+    B, T, _ = h.shape
+    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    Hp = Hkv // 2
+    window = cfg.sliding_window if kind == "sliding" else None
+    scope = {"dense": "diff/self", "sliding": "diff/window",
+             "cross": "diff/cross"}[kind]
+    bias = (lambda x, b: x + p[pre + b]) if cfg.attn_bias else (lambda x, b: x)
+    cut = carry.get("last") if layer == cfg.cut_layer else None
+    hq = h if cut is None else _last_rows(h, cut)
+    if cut is not None:
+        carry["qpos"] = (start + cut)[:, None]
+    qpos = carry["qpos"]                                        # [B, Tq]
+    Tq = hq.shape[1]
+    q = bias(_mm(hq, p[pre + ".wq"]), ".bq").reshape(B, Tq, Hq, D) \
+        .transpose(0, 2, 1, 3)                                  # [B, Hq, Tq, D]
+    new = ()
+    if kind == "cross":
+        k, v, table = carry[("kv", cfg.sources[layer])]
+    else:
+        # pairs: K/V heads 2r, 2r + 1 side by side in 2 D lanes
+        pairs = lambda w, b: bias(_mm(h, p[pre + w]), b) \
+            .reshape(B, T, Hp, 2 * D).transpose(0, 2, 1, 3)
+        k, v = pairs(".wk", ".bk"), pairs(".wv", ".bv")
+        table = None
+        new = (k, v)
+        if cache is not None:
+            *pools, table = cache
+            k, v = new = tuple(_pools.paged_write_kv(pool, fresh, table, start)
+                               for pool, fresh in zip(pools, new))
+        if layer in cfg.sources:
+            carry[("kv", layer)] = (k, v, table)
+    with jax.named_scope(scope):
+        if table is None:
+            # a prefill: the keys just computed, [B, Hp, T, 2 D]
+            o = attend(cfg, diff_widen(q).transpose(0, 2, 1, 3),
+                       k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                       qpos, window=window,
+                       band=window is not None and Tq == T,
+                       scale=float(D) ** -0.5).transpose(0, 2, 1, 3)
+        elif Tq == 1:
+            o = diff_decode_attend(q, k, v, table, qpos[:, 0], window)
+        elif window is not None:
+            # the window before the first new token, and the new tokens
+            first, sub = _pools.window_blocks(table, start, k.shape[2],
+                                              window, T)
+            o = diff_extend_attend(q, k, v, sub, start, window, first)
+        else:
+            o = diff_extend_attend(q, k, v, table, qpos[:, 0])
+    with jax.named_scope("diff/combine"):
+        f32 = jnp.float32
+        lam = lambda a: jnp.exp(jnp.sum(
+            p[pre + f".lambda_q{a}"].astype(f32)
+            * p[pre + f".lambda_k{a}"].astype(f32)))
+        init = diff_lambda_init(layer)
+        o = diff_combine(o, lam(1) - lam(2) + f32(init),
+                             p[pre + ".o_norm.weight"], init, cfg.norm_eps)
+        o = o.astype(h.dtype).transpose(0, 2, 1, 3).reshape(B, Tq, Hq * D)
+    return bias(_mm(o, p[pre + ".wo"]), ".bo"), new
+
+
+# --------------------------------------------- Mamba-1 and the memory unit
+
+def _ssm1_widths(cfg):
+    """(inner width, state width, rank of the step's projection, the
+    convolution's width)."""
+    return (cfg.ssm1_inner or 2 * cfg.hidden_size, cfg.ssm_state,
+            cfg.ssm1_dt_rank or -(-cfg.hidden_size // 16),
+            cfg.ssm_conv_kernel)
+
+
+def _ssm1_shapes(cfg, pre):
+    Hd = cfg.hidden_size
+    E, N, R, K = _ssm1_widths(cfg)
+    return {pre + ".w_in": (Hd, 2 * E),              # [x | z]
+            pre + ".conv.weight": (E, K), pre + ".conv.bias": (E,),
+            pre + ".w_x": (E, R + 2 * N),            # [delta | B | C]
+            pre + ".w_dt": (R, E), pre + ".dt_bias": (E,),
+            # kept N-major: the channels along the lanes, as the state is
+            pre + ".A_log": (N, E), pre + ".D": (E,),
+            pre + ".w_out": (E, Hd)}
+
+
+def _ssm1_state_pools(cfg):
+    """[(name, per-slot shape, dtype)] of a mamba1 layer's state: the
+    float32 ``S [N, E]`` and the convolution's last inputs."""
+    E, N, _, K = _ssm1_widths(cfg)
+    return [("ssm1_state", (N, E), "float32"),
+            ("ssm1_conv", (K - 1, E), cfg.dtype)]
+
+
+def mamba1(cfg, p, pre, h, start, cache=None, flash_ok=False, lengths=None,
+           cuts=None, layer=None, carry=None):
+    """A Mamba-1 layer over ``h [B, T, hidden]`` (the module's docstring has
+    the equations). It speaks ``gated_delta``'s cache protocol to the letter
+    (``mamba2`` has it in words); where a gmu layer reads this one
+    (``cfg.sources``) it leaves its memory, the scan's output BEFORE the
+    gate ``[B, T, E]`` float32, in ``carry[("m", layer)]``."""
+    from ..kernels import mamba1 as _m1
+
+    B, T, _ = h.shape
+    E, N, R, K = _ssm1_widths(cfg)
+    f32 = jnp.float32
+    state, conv, where, tail = _state_start("mamba1", cache, B, T, (K - 1, E),
+                                            h.dtype)
+    with jax.named_scope("ssm1/in_proj"):
+        xz = _mm(h, p[pre + ".w_in"])
+    with jax.named_scope("ssm1/conv"):
+        x, tails, n = _conv_tails(
+            xz[..., :E], tail, p[pre + ".conv.weight"], lengths, cuts,
+            cache is not None and cuts is not None, p[pre + ".conv.bias"])
+    with jax.named_scope("ssm1/x_proj"):
+        dbc = jnp.dot(x.astype(h.dtype), p[pre + ".w_x"],
+                      preferred_element_type=f32)
+        dt = jax.nn.softplus(
+            jnp.dot(dbc[..., :R].astype(h.dtype), p[pre + ".w_dt"],
+                    preferred_element_type=f32)
+            + p[pre + ".dt_bias"].astype(f32))
+    Bm, Cm = dbc[..., R:R + N], dbc[..., R + N:]
+    real = jnp.arange(T)[None, :] < n[:, None]
+    if cache is not None and where is None:
+        real = real & (start > 0)[:, None]      # a slot that runs a request
+    dt = jnp.where(real[..., None], dt, 0.0)
+    A = -jnp.exp(p[pre + ".A_log"].astype(f32))
+    D = p[pre + ".D"].astype(f32)
+    if cache is not None and where is None:
+        with jax.named_scope("ssm1/step"):
+            y, state = _m1.mamba1_step(x[:, 0], dt[:, 0], A, Bm[:, 0],
+                                       Cm[:, 0], D, state)
+        y = y[:, None]
+        new = (state, lax.dynamic_update_slice_in_dim(
+            conv, jnp.where(real[:, :, None], tails[:, 0],
+                            conv[:B]).astype(conv.dtype), 0, axis=0))
+    else:
+        with jax.named_scope("ssm1/scan"):
+            S0 = jnp.zeros((B, N, E), f32) if cache is None else \
+                lax.dynamic_index_in_dim(state, where[0], keepdims=True)
+            y, S, Sc = _m1.mamba1_scan(x, dt, A, Bm, Cm, D, S0, cuts)
+        new = _state_new(cache, jnp.concatenate([Sc, S[:, None]], axis=1),
+                         tails)
+    if layer in cfg.sources:
+        carry[("m", layer)] = y
+    with jax.named_scope("ssm1/out_proj"):
+        y = (y * jax.nn.silu(xz[..., E:].astype(f32))).astype(h.dtype)
+        return _mm(y, p[pre + ".w_out"]), new
+
+
+def _gmu_shapes(cfg, pre):
+    E = _ssm1_widths(cfg)[0]
+    return {pre + ".w1": (cfg.hidden_size, E), pre + ".w2": (E, cfg.hidden_size)}
+
+
+@jax.named_scope("gmu")
+def gmu(cfg, p, pre, h, start, cache=None, flash_ok=False, lengths=None,
+        cuts=None, layer=None, carry=None):
+    """A gated memory unit (arXiv:2507.06607 section 2): ``(silu(h W1) * m)
+    W2`` with ``m`` the memory of the SAME token that mamba1 layer
+    ``cfg.sources[layer]`` left in ``carry``. No state, no cache."""
+    m = carry[("m", cfg.sources[layer])]
+    g = jax.nn.silu(_mm(h, p[pre + ".w1"]).astype(jnp.float32))
+    return _mm((g * m).astype(h.dtype), p[pre + ".w2"]), ()
 
 
 # ------------------------------------------------- gated delta-rule layers
@@ -1349,6 +1690,13 @@ ATTENTIONS = {
     "latent": (latent_attention, _latent_shapes,
                lambda c: [("latent", 1, latent_pool_width(c))], lambda c: []),
     "mamba2": (mamba2, _ssm_shapes, lambda c: [], _ssm_state_pools),
+    "mamba1": (mamba1, _ssm1_shapes, lambda c: [], _ssm1_state_pools),
+    # a gated memory unit over an earlier mamba1 layer's scan output, and
+    # an attention layer over an earlier dense layer's K/V: no pool, no state
+    "gmu": (gmu, _gmu_shapes, lambda c: [], lambda c: []),
+    "cross": (functools.partial(attention, kind="cross"),
+              functools.partial(_attn_shapes, sparse=False, cross=True),
+              lambda c: [], lambda c: []),
     # a layer without a token mixer: nothing declared, nothing traced
     "none": (None, lambda c, pre: {}, lambda c: [], lambda c: []),
 }
@@ -1495,13 +1843,18 @@ def step_stats(cfg) -> Tuple[str, ...]:
     one (the context) attended, summed over the live slots, each 0 in a
     layer of the other kind. Of a model with mamba2 layers,
     ``ssm_slots_stepped``: the running slots a mamba2 layer's recurrent
-    step advanced (0 in a layer of another kind). A layer without an FFN
-    counts 0 in the FFN's columns."""
+    step advanced (0 in a layer of another kind; a mamba1 layer's likewise).
+    Of a model with cross layers, ``shared_read``: the cached tokens a layer
+    read from the ONE pool the cross layers share (the layer that writes it
+    and each cross layer: the context a live slot; 0 in any other layer). A
+    layer without an FFN counts 0 in the FFN's columns."""
     return ffn_stats(cfg) + (("latent_tokens_read", "shared_walk_tokens")
                              if "latent" in cfg.kinds else ()) \
         + (("window_tokens_read", "full_tokens_read")
            if "sliding" in cfg.kinds else ()) \
-        + (("ssm_slots_stepped",) if "mamba2" in cfg.kinds else ())
+        + (("ssm_slots_stepped",)
+           if {"mamba2", "mamba1"} & set(cfg.kinds) else ()) \
+        + (("shared_read",) if "cross" in cfg.kinds else ())
 
 
 def moe_routed(cfg, p, pre, g, act="swiglu"):
@@ -1656,6 +2009,10 @@ def initial_value(name: str, shape, key, std: float):
         return dt + jnp.log(-jnp.expm1(-dt))
     if name.endswith(".bias"):
         return jnp.zeros(shape, jnp.float32)
+    if name.endswith(".A_log") and len(shape) == 2:
+        # a mamba1 layer's [N, E]: log(1 .. N) along the state's lanes
+        return jnp.broadcast_to(jnp.log(jnp.arange(
+            1, shape[0] + 1, dtype=jnp.float32))[:, None], shape)
     if name.endswith(".A_log"):
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0))
     if name.endswith(".D"):
@@ -1666,17 +2023,26 @@ def initial_value(name: str, shape, key, std: float):
     return std * jax.random.normal(key, shape, jnp.float32)
 
 
+#: the kinds whose function is told its layer and handed ``_forward``'s carry
+_CARRIED = ("dense", "sliding", "cross", "mamba1", "gmu")
+
+
 def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
-          cuts=None):
+          cuts=None, carry=None):
     """One block over the residual stream ``x [B, T, hidden]``: returns
-    (x, the layer's new pool entries, its routing statistics)."""
+    (x, the layer's new pool entries, its routing statistics). ``carry``:
+    what ``_forward`` hands from layer to layer (``differential_attention``
+    has its entries); where the mixer cut the batch to the last real token
+    (``cfg.cut_layer``), the stream and the carried memories are cut with
+    it."""
     pre = f"layers.{l}"
     kind = cfg.ffns[l][0]
+    more = {"layer": l, "carry": carry} if cfg.kinds[l] in _CARRIED else {}
     # a part that is absent (kind "none") is not traced: no identity pass,
     # no norm; it hands back no pool entries / zero statistics
     mix = None if cfg.kinds[l] == "none" else functools.partial(
         ATTENTIONS[cfg.kinds[l]][0], cfg, p, pre + ".attn", start=start,
-        cache=cache, flash_ok=flash_ok, lengths=lengths, cuts=cuts)
+        cache=cache, flash_ok=flash_ok, lengths=lengths, cuts=cuts, **more)
     feed = None if kind == "none" else functools.partial(
         ffn, cfg, p, pre + ".ffn", kind=kind)
     new, stats = (), jnp.zeros((len(ffn_stats(cfg)),), jnp.int32)
@@ -1701,6 +2067,10 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
     else:
         if mix:
             a, new = mix(h=_norm(cfg, x, p, pre + ".attn_norm"))
+            if a.shape[1] != x.shape[1]:    # cut to the last real token
+                x = _last_rows(x, carry["last"])
+                for key in [k for k in carry if k[0] == "m"]:
+                    carry[key] = _last_rows(carry[key], carry["last"])
             x = x + a
         if feed:
             y, stats = feed(g=_norm(cfg, x, p, pre + ".ffn_norm"))
@@ -1734,14 +2104,23 @@ def block(cfg, p, l, x, start, cache=None, flash_ok=False, lengths=None,
                 full = jnp.sum(ctx)
         stats = jnp.concatenate(
             [stats, jnp.stack([win, full]).astype(jnp.int32)])
-    if "mamba2" in cfg.kinds:
+    if {"mamba2", "mamba1"} & set(cfg.kinds):
         stepped = jnp.zeros((), jnp.int32)
-        if cfg.kinds[l] == "mamba2" and cache is not None \
+        if cfg.kinds[l] in ("mamba2", "mamba1") and cache is not None \
                 and cache[2] is None:
             # the slots whose state the recurrent step advanced: those that
             # run a request (a dead slot sits at position 0)
             stepped = jnp.sum(start > 0)
         stats = jnp.concatenate([stats, stepped[None].astype(jnp.int32)])
+    if "cross" in cfg.kinds:
+        read = jnp.zeros((), jnp.int32)
+        src = l if cfg.kinds[l] == "dense" else cfg.sources[l]
+        if cache is not None and ("kv", src) in carry:
+            # a live slot (its first block is mapped) reads its context
+            table = carry[("kv", src)][2]
+            read = jnp.sum(jnp.where(table[:, 0] >= 0,
+                                     carry["qpos"][:, -1] + 1, 0))
+        stats = jnp.concatenate([stats, read[None].astype(jnp.int32)])
     return x, new, stats
 
 
@@ -1785,13 +2164,25 @@ class DecoderLM(Layer):
         heads side by side, or head-major), and the indexer's keys; with
         the layers that hold them as a fourth entry where not every layer
         does."""
-        L = self.cfg.num_layers
+        cfg, L = self.cfg, self.cfg.num_layers
         # a sliding layer's pools stand in a page GROUP of their own: (its
         # name, the window it keeps), the declaration's fifth entry
-        group = ("window", self.cfg.sliding_window)
-        return [spec + (group,) if spec[0].endswith("_window")
-                else spec[:3] if len(spec[3]) == L else spec
-                for spec in self._pools(2)]
+        group = ("window", cfg.sliding_window)
+        pools = [spec + (group,) if spec[0].endswith("_window")
+                 else spec[:3] if len(spec[3]) == L else spec
+                 for spec in self._pools(2)]
+        if "cross" not in cfg.kinds:
+            return pools
+        # the ONE pool the cross layers share is declared once, held by the
+        # layer that writes it; the sixth entry names every layer that
+        # READS it (the holder and its cross layers): a token is charged
+        # one page entry, whatever the number of readers
+        readers = lambda held: tuple(
+            l for l, (k, src) in enumerate(zip(cfg.kinds, cfg.sources))
+            if l in held or (k == "cross" and src in held))
+        return [spec if len(spec) > 4
+                else spec + (("global", None), readers(spec[3]))
+                for spec in pools]
 
     def state_pools(self):
         """[(name, per-slot shape, dtype, layers)] of the slot-indexed
@@ -1825,10 +2216,25 @@ class DecoderLM(Layer):
             if plan is not None:
                 caches = [e + (plan,) if l in latent else e
                           for l, e in enumerate(caches)]
+        # what a layer hands to later ones: the positions of the rows the
+        # stream holds, a mamba1 layer's memory, a dense layer's written
+        # K/V, and for an admission's prefill or extend the row of the last
+        # real token, to which ``cfg.cut_layer`` cuts the batch
+        T = ids.shape[1]
+        carry = {"qpos": start[:, None] + jnp.arange(T, dtype=jnp.int32)}
+        if lengths is not None and T > 1 and cfg.cut_layer is not None:
+            carry["last"] = jnp.clip(lengths - 1, 0, T - 1)
         for l in range(cfg.num_layers):
             x, new, st = block(cfg, p, l, x, start,
                                None if caches is None else caches[l], flash_ok,
-                               lengths, cuts)
+                               lengths, cuts, carry)
+            if T > 1 and cfg.cut_layer is not None:
+                # the stream stands in memory ONCE: left to itself the
+                # compiler folds the chain of residual adds into each
+                # consumer and keeps every layer's branch outputs to the
+                # program's end (17 x 70 MB at 14,336 tokens, and the
+                # program past the chip's memory; TPU compiler, PR 49)
+                x = lax.optimization_barrier(x)
             news.append(tuple(Tensor(a) for a in new))
             stats.append(st)
         return x, news, jnp.stack(stats)
@@ -1860,7 +2266,9 @@ class DecoderLM(Layer):
         x, news, _ = self._forward(ids, jnp.zeros((B,), jnp.int32),
                                    flash_ok=True, lengths=lengths,
                                    cuts=None if cuts is None else _ids(cuts))
-        if lengths is None:
+        if x.shape[1] == 1:     # the model cut the batch itself
+            last = x[:, 0]
+        elif lengths is None:
             last = x[:, T - 1]
         else:
             idx = jnp.clip(lengths - 1, 0, T - 1)
@@ -1872,7 +2280,9 @@ class DecoderLM(Layer):
         (logits ``[B, T, V]``, per layer the updated pools). ``lengths``
         says how many of a row's tokens are real, which a layer with
         recurrent state has to know; ``cuts [B, n]`` before which tokens it
-        writes its state to the rows its cache entry names besides."""
+        writes its state to the rows its cache entry names besides. A model
+        with a ``cut_layer`` that is told ``lengths`` (an admission) hands
+        back the last real token's logits alone, ``[B, 1, V]``."""
         ids = _ids(tokens)
         ids = ids[:, None] if ids.ndim == 1 else ids
         start = jnp.broadcast_to(_ids(positions), (ids.shape[0],))

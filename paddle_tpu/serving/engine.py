@@ -481,12 +481,20 @@ class Engine:
         self.page_alloc = self.page_allocs[0]
         #: [(group, window in tokens)] of the groups that keep a window
         self._windows = [(g, w) for g, (_, w, _) in enumerate(groups) if w]
-        if self._windows and (state or self.config.speculative is not None
+        if self._windows and (self.config.speculative is not None
                               or (groups[0][1] and self.config.prefix_cache)):
             raise ValueError(
                 "a model with sliding-window pools is served without "
-                "recurrent state and without speculation, and with the "
-                "prefix cache only beside a group that keeps every token")
+                "speculation, and with the prefix cache only beside a "
+                "group that keeps every token")
+        # a pool that more layers read than hold it (``PagedKVCache
+        # .pool_readers``): how many read the first such pool
+        shared = [r for r, held in zip(self.cache.pool_readers,
+                                       self.cache.pool_layers) if r != held]
+        self._shared_readers = len(shared[0]) if shared else 0
+        if shared:
+            _metrics.gauge("serving.shared_pool.readers",
+                           self._shared_readers)
         # the first block each slot still maps in each window group
         self._win_from = {g: np.zeros((B,), np.int64) for g, _ in self._windows}
         # references dropped behind windows / matched tokens run again for
@@ -834,6 +842,8 @@ class Engine:
                 cache.layer_entries(pools, [r[None, :] for r in page_rows],
                                     *((s[0], s[3:]) for s in state)),
                 Tensor(start[None]), **more)
+            if lv.shape[1] == 1:    # the model cut the batch to that row
+                return (lv[0],) + _updated(cache, new)
             idx = jnp.clip(length - 1, 0, T - 1)
             last = lax.dynamic_index_in_dim(lv[0], idx, keepdims=False)
             return (last[None],) + _updated(cache, new)  # [1, V], like prefill
@@ -1045,8 +1055,11 @@ class Engine:
                         self.prefix_cache.match_groups(req.prompt_ids)
             source, cuts = None, []
             if self.snapshot_alloc is not None:
+                # (with window groups beside the state ``match_groups`` has
+                # cut the match back to the deepest depth that has BOTH the
+                # windows' pages and a snapshot)
                 splice, source = self.prefix_cache.deepest_snapshot(
-                    req.prompt_ids, hit_blocks)
+                    req.prompt_ids, splice if self._windows else hit_blocks)
                 # (the deepest first to go where the whole pool is smaller
                 # than one admission's two)
                 cuts = sorted({hit_blocks, n // ps} - {0, splice})[
@@ -1100,6 +1113,12 @@ class Engine:
                 alloc.set(pages=len(pages[0]), evicted=evicted)
             adm.set(queued_s=req.admit_time - req.arrival_time,
                     hit_blocks=hit_blocks)
+            if self._shared_readers:
+                # rows of the run that entered the layers behind the
+                # model's cut (its cross-decoder): the last real token's
+                # alone, where the model cuts
+                cut = getattr(self.model.cfg, "cut_layer", None) is not None
+                adm.set(cross_rows=1 if cut else n - splice * ps)
             if self._stateful:
                 adm.set(snapshot_blocks=splice,
                         recomputed_tokens=(hit_blocks - splice) * ps)
@@ -1141,8 +1160,8 @@ class Engine:
                     self.prefix_cache.insert(
                         req.prompt_ids,
                         self.cache.slot_pages(slot)[:n // ps],
-                        [self.cache.page_tables[g][slot, :n // ps]
-                         for g in range(1, len(self.page_allocs))])
+                        self._rows_for_trie(slot, n // ps,
+                                            [b for b, _ in snaps]))
                     for block, snap in snaps:
                         with _span("serving/snapshot",
                                    request_id=req.request_id, blocks=block,
@@ -1179,6 +1198,26 @@ class Engine:
         req.output_ids.append(tok)
         self._maybe_finish(req, tok)
         return True
+
+    def _rows_for_trie(self, slot: int, blocks: int, snapshot_blocks):
+        """What ``insert`` is handed of the further page groups: the slot's
+        table rows as far as the prompt's whole ``blocks``. Beside recurrent
+        state a depth can be resumed only where a snapshot lies, so of a
+        window group the trie is handed the windows before the snapshots
+        this admission took alone (-1 elsewhere): every other page would be
+        held for nothing until its node is evicted."""
+        rows = [self.cache.page_tables[g][slot, :blocks]
+                for g in range(1, len(self.page_allocs))]
+        if not (self._stateful and self._windows):
+            return rows
+        ps = self.cache.page_size
+        for g, window in self._windows:
+            back = (window + ps - 2) // ps
+            keep = np.zeros((blocks,), bool)
+            for b in snapshot_blocks:
+                keep[max(0, b - back):b] = True
+            rows[g - 1] = np.where(keep, rows[g - 1], PAGE_SENTINEL)
+        return rows
 
     def _page_plan(self, n: int, hit: int, splice: int):
         """Per page group ``(lo, hi, fresh)`` for a prompt of ``n`` tokens

@@ -101,6 +101,10 @@ class PagedKVCache:
     that attend the last ``window`` tokens alone: the engine unmaps and
     frees such a group's pages behind a slot's window as the slot moves on
     (``Engine._slide``), so its pool holds a window a slot, not a context.
+    A pool may be READ by layers that hold none (a sixth entry names the
+    readers: a decoder-hybrid-decoder's cross layers over one layer's K/V);
+    that changes nothing here but ``pool_readers``: such a layer is handed
+    an empty entry, and the model carries the holder's written pool to it.
     ``num_pages`` sizes the first group, ``group_pages`` ``{name: pages}``
     the others (default: the full budget); every table writer and reader
     below takes ``group`` (an index into ``.groups``; default the first),
@@ -148,6 +152,12 @@ class PagedKVCache:
         #: the layers that hold each pool, paged pools first
         self.pool_layers = [tuple(p[3]) if len(p) > 3 else every
                             for p in list(pools) + list(state_pools)]
+        #: the layers that READ each paged pool: its holders, or what the
+        #: declaration's sixth entry names (a pool that layers without one
+        #: of their own attend to: ONE buffer, one page a block, however
+        #: many read it)
+        self.pool_readers = [tuple(p[5]) if len(p) > 5 else layers
+                             for p, layers in zip(pools, self.pool_layers)]
         self.num_snapshots = int(num_snapshots)
         rows = max_batch_size + self.num_snapshots
         self._pools = tuple(

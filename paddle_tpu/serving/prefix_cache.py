@@ -169,23 +169,29 @@ class PrefixCache:
         hit_blocks`` the deepest depth a request can RESUME at: in every
         window group each of the blocks that hold the ``window - 1`` tokens
         before it still has its page (depth 0 always can: nothing precedes
-        it). ``pages[g][j]`` backs block ``j`` in group ``g`` (``g = 0`` the
+        it). Where the model keeps recurrent state too (``snapshots``), a
+        depth can resume only if its node carries a snapshot BESIDES.
+        ``pages[g][j]`` backs block ``j`` in group ``g`` (``g = 0`` the
         first group, as far as ``hit_blocks``; a further group as far as
         ``resume_blocks``, -1 where the node has none: before the window)."""
         path = self._matched(prompt)
         hit, first = len(path), [node.page for node in path]
         ps = self.page_size
-        resume = hit
+        # the depths that can resume: all, or beside recurrent state those
+        # that carry a snapshot
+        can = [self.snapshots is None or node.snapshot is not None
+               for node in path]
         for g, (_, window) in enumerate(self.more):
             if window is None:
                 continue
             back = (window + ps - 2) // ps    # blocks a depth looks back on
-            run, ok = 0, 0      # consecutive nodes with a page, up to here
-            for d, node in enumerate(path[:resume], 1):
+            run = 0         # consecutive nodes with a page, up to here
+            for d, node in enumerate(path, 1):
                 run = run + 1 if node.more[g] is not None else 0
-                if run >= min(d, back):
-                    ok = d
-            resume = ok
+                can[d - 1] = can[d - 1] and run >= min(d, back)
+        windows = any(w is not None for _, w in self.more)
+        resume = max((d for d, ok in enumerate(can, 1) if ok), default=0) \
+            if windows else hit
         return hit, resume, [first] + [
             [-1 if n.more[g] is None else n.more[g] for n in path[:resume]]
             for g in range(len(self.more))]
@@ -251,13 +257,36 @@ class PrefixCache:
             # (a chat's earlier prompt end). Where prompts part (a shared
             # system prompt's last block) the node has several children
             # and its snapshot stays
-            for above in reversed(path[:-1]):
+            for j in range(depth - 2, -1, -1):
+                above = path[j]
                 if above.snapshot is not None:
                     if len(above.children) == 1:
                         self._drop_snapshot(above)
+                        self._free_windows(path, j)
                     break
         self.snapshots.free([snapshot], owner=owner)
         return ok
+
+    def _free_windows(self, path: List[_Node], j: int):
+        """``path[j]`` has lost its snapshot to the deeper end of ``path``
+        on an unbranched chain: the window groups' pages that only a resume
+        THERE looked back on go (beside recurrent state no other depth can
+        resume): those of ``path[j]`` and the unbranched nodes before it
+        without a snapshot, as far as a window reaches, but for the ones the
+        new depth's window still holds."""
+        ps = self.page_size
+        for g, (alloc, window) in enumerate(self.more):
+            if window is None:
+                continue
+            back = (window + ps - 2) // ps
+            for i in range(j, max(j - back, -1), -1):
+                node = path[i]
+                if i != j and (node.snapshot is not None
+                               or len(node.children) > 1):
+                    break
+                if i < len(path) - back and node.more[g] is not None:
+                    alloc.free([node.more[g]], owner=_OWNER)
+                    node.more[g] = None
 
     def _drop_snapshot(self, node: _Node, evicted: bool = False):
         self.snapshots.free([node.snapshot], owner=_OWNER)

@@ -236,6 +236,33 @@ ext = lambda Hq, Hkv, T, L, w=None: sites(
 out["extend_flash"] = [ext(128, 8, 512, 19456), ext(128, 8, 512, 5120, 4096),
                        ext(30, 30, 16, 4096), ext(32, 2, 128, 4352),
                        ext(16, 16, 5, 2048)]
+# the decoder-hybrid-decoder's widths (Phi-4-mini-flash whole, 48 slots):
+# the Mamba-1 step over rows [0, 48) of 112 (a state of 16 x 5,120 a slot),
+# its scan over an extend's 1,792 tokens with two cuts, and the differential
+# reads over PAIR-head pools (40 widened query heads on 10 pairs of 128
+# lanes): the shared pool's decode over a [48, 968] table, a sliding layer's
+# window 512 in decode and behind an extend's 1,792 new tokens
+from paddle_tpu.kernels import mamba1
+from paddle_tpu.kernels.paged_attention import (diff_decode_attend,
+                                                diff_extend_attend)
+out["mamba1_step"] = sites(
+    lambda *a: mamba1._step_call(*a, interpret=False),
+    fs(48, 5120), fs(48, 5120), fs(16, 5120), fs(48, 16), fs(48, 16),
+    fs(5120), fs(112, 16, 5120))
+out["mamba1_scan"] = sites(
+    lambda *a: mamba1._scan_call(*a, interpret=False),
+    fs(1, 1792, 5120), fs(1, 1792, 5120), fs(16, 5120), fs(1, 1792, 16),
+    fs(1, 1792, 16), fs(5120), fs(1, 16, 5120), sds((1, 2), jnp.int32))
+pair_pool = sds((3329, 10, 16, 128))
+out["diff_decode"] = [
+    sites(lambda q, k, v, t, p: diff_decode_attend(q, k, v, t, p, w),
+          sds((48, 40, 1, 64)), pair_pool, pair_pool,
+          sds((48, 968), jnp.int32), sds((48,), jnp.int32))
+    for w in (None, 512)]
+out["diff_extend"] = sites(
+    lambda q, k, v, t, p, f: diff_extend_attend(q, k, v, t, p, 512, f),
+    sds((1, 40, 1792, 64)), pair_pool, pair_pool, sds((1, 145), jnp.int32),
+    sds((1,), jnp.int32), sds((1,), jnp.int32))
 f32 = sds((64, 256), jnp.float32)
 out["prim"] = {**sites(primitive.elementwise_kernel(lambda a, b: a + 2 * b), f32, f32),
                **sites(primitive.row_reduce_kernel(lambda acc, t: acc + t.sum(-1), 0.0), f32)}
@@ -289,6 +316,10 @@ def test_kernels_and_train_step_compile_for_a_tpu_topology():
     assert out["extend_flash"] == [
         {"extend_flash": 1}, {"window_extend_flash": 1}] \
         + [{"extend_flash": 1}] * 3
+    assert out["mamba1_step"] == {"mamba1_decode_step": 1}
+    assert out["mamba1_scan"] == {"mamba1_scan": 1}
+    assert out["diff_decode"] == [{"paged_decode": 1}, {"window_decode": 1}]
+    assert out["diff_extend"] == {"window_extend_flash": 1}
     assert out["prim"] == {"prim_elementwise": 1, "prim_row_reduce": 1}
     for key in ("step_1", "step_dp2mp2"):  # the mesh must not lose a kernel
         assert set(out[key]) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",

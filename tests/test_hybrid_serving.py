@@ -710,4 +710,5 @@ class TestDescription:
         c = initial_value(pre + "conv.weight", (64, 4), key, 0.02)
         assert 0.4 < float(jnp.abs(c).max()) <= 0.5
         assert set(ATTENTIONS) == {"dense", "sliding", "indexed_sparse",
-                                   "gated_delta", "latent", "mamba2", "none"}
+                                   "gated_delta", "latent", "mamba2", "mamba1",
+                                   "gmu", "cross", "none"}
